@@ -125,9 +125,6 @@ async def amain(args: argparse.Namespace) -> None:
 
 
 def main(argv: List[str] | None = None) -> None:
-    from ..utils.platform import pin_platform_from_env
-
-    pin_platform_from_env()
     args = build_parser().parse_args(argv)
     logging.basicConfig(
         level=args.log_level.upper(),

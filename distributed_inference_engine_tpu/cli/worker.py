@@ -139,24 +139,40 @@ async def amain(args: argparse.Namespace) -> None:
         print(f"loading model {m.name} ({m.architecture})...", flush=True)
         await worker.load_model_async(m)
         load_s = worker._last_load_s.get(m.name, 0.0)
+        warm_s = worker._last_warmup_s.get(m.name, 0.0)
         hit = getattr(worker.engines.get(m.name), "artifact_manifest",
                       None) is not None
-        print(f"loaded model {m.name} in {load_s:.2f}s"
+        print(f"loaded model {m.name} in {load_s:.2f}s "
+              f"(warm-up compile {warm_s:.2f}s)"
               f"{' [artifact cold-start]' if hit else ''}", flush=True)
     host, port = await worker.start(install_signal_handlers=True)
-    print(f"worker {worker.worker_id} listening on {host}:{port}", flush=True)
+    # the device goes BEFORE the address: scripts read the port as the
+    # text after the line's last colon
+    dev = worker.device_report()
+    where = ""
+    if dev:
+        ids = sorted({i for p in dev["models"].values()
+                      for i in p["device_ids"]})
+        chips = dev["visible_chips"]
+        where = (f" [platform={dev['platform']} "
+                 f"device_kind={dev['device_kind']!r} "
+                 f"n_devices={dev['n_devices']} device_ids={ids}"
+                 f"{f' visible_chips={chips}' if chips else ''}]")
+    print(f"worker {worker.worker_id}{where} listening on {host}:{port}",
+          flush=True)
     await worker.serve_forever()
 
 
 def main(argv: List[str] | None = None) -> None:
-    from ..utils.platform import pin_platform_from_env
-
-    pin_platform_from_env()
     args = build_parser().parse_args(argv)
     logging.basicConfig(
         level=args.log_level.upper(),
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
     )
+    from ..utils.compile_cache import configure_compile_cache
+
+    logging.getLogger(__name__).info(
+        "compile cache: %s", configure_compile_cache())
     asyncio.run(amain(args))
 
 

@@ -30,6 +30,7 @@ from __future__ import annotations
 import asyncio
 import concurrent.futures
 import logging
+import os
 import signal
 import time
 from typing import Any, Awaitable, Callable, Dict, List, Optional, Tuple
@@ -177,6 +178,33 @@ def _engine_features(cfg: ModelConfig) -> frozenset:
     return frozenset({"generate"})
 
 
+def _engine_placement(engine) -> Dict[str, Any]:
+    """Where an engine's params live — device ids (with the chips' mesh
+    coordinates where the backend has them), the bytes resident on each,
+    the tree's logical bytes — and its int4 kernel paths. Empty for
+    engines that hold no params (the fakes)."""
+    params = getattr(engine, "params", None)
+    if params is None:
+        return {}
+    import jax
+
+    from ..ops.quant import int4_kernel_paths, param_bytes
+
+    by_device: Dict[int, int] = {}
+    coords: Dict[int, Any] = {}
+    for leaf in jax.tree_util.tree_leaves(params):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            d = shard.device
+            by_device[d.id] = by_device.get(d.id, 0) + shard.data.nbytes
+            coords[d.id] = getattr(d, "coords", None)
+    ids = sorted(by_device)
+    return {"device_ids": ids,
+            "coords": [coords[i] for i in ids],
+            "param_bytes": param_bytes(params),
+            "param_bytes_by_device": {str(i): by_device[i] for i in ids},
+            "int4_paths": int4_kernel_paths(params)}
+
+
 # --------------------------------------------------------------------------
 # server
 
@@ -237,6 +265,11 @@ class WorkerServer(FramedServerMixin):
         # from-scratch init (miss) — the respawn-latency receipts
         self.model_load_stats = LatencyStats()
         self._last_load_s: Dict[str, float] = {}
+        self._last_warmup_s: Dict[str, float] = {}
+        # where each real (jax) engine's params landed — platform, device
+        # kind, device ids, int4 kernel paths — so a deploy can ASSERT its
+        # placement instead of inferring it (see device_report)
+        self._placements: Dict[str, Dict[str, Any]] = {}
         self._artifact_hits = 0
         self._artifact_misses = 0
         # KV fabric (engine/kv_fabric.py): pages migrated in/out of this
@@ -369,12 +402,15 @@ class WorkerServer(FramedServerMixin):
             # An artifact cold-start warms only the bucket shapes its
             # writer recorded — the respawn path compiles what the dead
             # worker actually served, not the full grid.
+            t0 = time.perf_counter()
             if artifact_hit and hasattr(engine, "warmup_from_manifest"):
                 n = engine.warmup_from_manifest()
             else:
                 n = engine.warmup()
-            logger.info("worker %s warmed %s (%d rounds)",
-                        self.worker_id, cfg.name, n)
+            self._last_warmup_s[cfg.name] = time.perf_counter() - t0
+            logger.info("worker %s warmed %s (%d rounds, %.2fs)",
+                        self.worker_id, cfg.name, n,
+                        self._last_warmup_s[cfg.name])
         return engine
 
     def _model_busy(self, name: str) -> bool:
@@ -400,6 +436,7 @@ class WorkerServer(FramedServerMixin):
         """Admit a built engine into the resident set (budget-evicting idle
         LRU models) and give continuous engines their rolling-batch pump."""
         self.model_manager.admit(cfg, engine)
+        self._placements[cfg.name] = _engine_placement(engine)
         if hasattr(engine, "submit") and hasattr(engine, "step"):
             from ..serving.pump import EnginePump
 
@@ -496,6 +533,7 @@ class WorkerServer(FramedServerMixin):
         if not receipt.get("already_resident"):
             engine = self.engines[name]
             cfg = self.model_configs[name]
+            self._placements[name] = _engine_placement(engine)
             if hasattr(engine, "submit") and hasattr(engine, "step"):
                 from ..serving.pump import EnginePump
 
@@ -578,7 +616,8 @@ class WorkerServer(FramedServerMixin):
                 "mono": time.perf_counter(),
                 "models": sorted(self.engines),
                 "staged": self.model_manager.staged_names(),
-                "draining": self._draining}
+                "draining": self._draining,
+                "device": self.device_report()}
 
     def _admit(self) -> None:
         """Admission gate for work-carrying verbs (generate/prefill family):
@@ -1247,6 +1286,36 @@ class WorkerServer(FramedServerMixin):
 
     # -- metrics (reference src/worker.py:186-209) ----------------------------
 
+    def device_report(self, memory: bool = False) -> Optional[Dict[str, Any]]:
+        """Where this worker's engines run, as JAX reports it: platform,
+        device kind, visible device count, and per resident model the ids
+        of the devices its params live on plus its int4 kernel paths.
+        ``None`` until a real (jax) engine is resident — a fake-engine
+        worker never touches a backend. ``memory`` adds each used device's
+        live ``memory_stats()`` (``None`` where the backend has none)."""
+        models = {name: p for name, p in self._placements.items()
+                  if p and name in self.engines}
+        if not models:
+            return None
+        import jax
+
+        devices = jax.devices()
+        report: Dict[str, Any] = {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "n_devices": len(devices),
+            # the chip(s) libtpu was told to show this process (README
+            # "One worker per chip"): device ids restart at 0 inside a
+            # confined process, so this is what tells replicas apart
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS"),
+            "models": models,
+        }
+        if memory:
+            used = {i for p in models.values() for i in p["device_ids"]}
+            report["memory"] = {
+                str(d.id): d.memory_stats() for d in devices if d.id in used}
+        return report
+
     def get_metrics(self) -> Dict[str, Any]:
         process: Dict[str, Any] = {}
         try:
@@ -1283,6 +1352,12 @@ class WorkerServer(FramedServerMixin):
             "active_connections": self._active_connections,
             "latency": self.latency.snapshot(),
             "model_load": self.model_load_stats.snapshot(),
+            # per-model set-up split: engine build + warm-up compile
+            "model_setup": {
+                name: {"load_s": self._last_load_s.get(name, 0.0),
+                       "warmup_s": self._last_warmup_s.get(name, 0.0)}
+                for name in self.engines},
+            "device": self.device_report(memory=True),
             "artifact_hits": self._artifact_hits,
             "artifact_misses": self._artifact_misses,
             # multi-model residency (cluster/model_manager.py): resident/

@@ -360,9 +360,9 @@ class ContinuousEngine:
         self._min_p = jnp.zeros((n,), jnp.float32)
         # deferred admission (r4): per-slot [token; logprob-bits] of the
         # prefill-sampled first token, harvested from the NEXT chunk's
-        # packed output instead of a dedicated blocking read (~a full
-        # round trip per admission round on tunnelled devices, paid while
-        # the device sat idle). Deferral engages only under decode
+        # packed output instead of a dedicated blocking read (a full
+        # host round trip per admission round, paid while the device
+        # sits idle). Deferral engages only under decode
         # pressure — see _admit_batch.
         self._firsts_dev = jnp.zeros((2, n), jnp.int32)
         # host cache of the firsts buffer (ISSUE 5 satellite): retire-path
@@ -387,8 +387,8 @@ class ContinuousEngine:
         # engines that never see stop_ids never pay the extra compile
         self._stop_slots: set = set()
         # host mirror of per-slot lengths: the capacity loop consults it
-        # every step, and a device readback costs a full round trip
-        # (~100 ms on tunnelled/remote devices). Updated on admission and
+        # every step, and a device readback costs a blocking host round
+        # trip. Updated on admission and
         # from each chunk's packed output row. (Active flags need no
         # mirror — each chunk's packed row is consumed immediately.)
         self._lengths_host = np.zeros((n,), np.int32)
@@ -426,8 +426,8 @@ class ContinuousEngine:
             token + logprob, packed into ONE [2, B] int32 buffer (the
             deferred-admission harvest contract — change it here and
             BOTH admission programs stay in sync). Sampling happens
-            in-program because eager sampling is a dispatch chain that
-            wrecks TTFT on remote/tunnelled devices."""
+            in-program because eager sampling is a chain of separate
+            dispatches whose launch latencies all land in TTFT."""
             last = hidden[jnp.arange(hidden.shape[0]), seq_lens - 1]
             logits = unembed(spec_, params, last)
             first, lp = sample_tokens_with_logprobs(logits, sampling, key)
@@ -840,8 +840,8 @@ class ContinuousEngine:
         def _install(lengths, last, active, produced, max_new, eos,
                      temps, top_k, top_p, min_p, stops, slots, vals):
             """All per-slot state writes of a WHOLE admission round in ONE
-            dispatch (eager .at[].set chains are device round-trips —
-            ruinous on remote/tunnelled devices). ``slots`` is a padded
+            dispatch (an eager .at[].set chain is one dispatch per
+            write). ``slots`` is a padded
             int32 vector; pad entries hold ``max_slots`` and fall out of
             range (``mode="drop"``)."""
             i = slots
@@ -1374,8 +1374,8 @@ class ContinuousEngine:
 
         Cache-miss admissions are BATCHED: every admittable waiting request
         shares one prefill program, one page write, and one state install
-        (N serial admissions are N× the fixed dispatch cost — the dominant
-        admission cost on remote/tunnelled devices). Prefix-cache hits run
+        (N serial admissions are N× the fixed dispatch cost).
+        Prefix-cache hits run
         their suffix programs individually (per-hit context shapes).
         """
         self._shed_expired()

@@ -187,8 +187,8 @@ class PrefillEngine:
             last = hidden[jnp.arange(b), seq_lens - 1]
             logits = unembed(spec_, params, last)
             # first token + its logprob sampled in-program (eager sampling
-            # costs a chain of device dispatches — ruinous on
-            # remote/tunnelled devices), packed into one [2, B] buffer
+            # costs a chain of device dispatches, each adding its launch
+            # latency), packed into one [2, B] buffer
             first, lp = sample_tokens_with_logprobs(logits, sampling, key)
             first = jnp.stack(
                 [first, jax.lax.bitcast_convert_type(lp, jnp.int32)])

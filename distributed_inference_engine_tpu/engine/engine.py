@@ -183,8 +183,8 @@ class Engine:
             last = hidden[jnp.arange(b), seq_lens - 1]        # [B, D]
             logits = unembed(spec_, params, last)             # [B, V] fp32
             # sample INSIDE the program: an eager sample after prefill is
-            # a chain of separate device dispatches — ruinous TTFT on a
-            # remote/tunnelled device. Token + its logprob pack into one
+            # a chain of separate device dispatches, each adding its
+            # launch latency to TTFT. Token + its logprob pack into one
             # [2, B] int32 buffer (logprob bitcast) = one blocking read.
             first, lp = sample_tokens_with_logprobs(logits, sampling, key)
             packed = jnp.stack(
@@ -227,8 +227,8 @@ class Engine:
             )
             # pack emitted tokens + their logprobs (bitcast) + live flags
             # into ONE buffer: the host then makes exactly one blocking
-            # read per chunk. Each sync is a full round trip — ~100 ms on
-            # a tunnelled/remote device.
+            # read per chunk (each sync is a full host<->device round
+            # trip).
             packed = jnp.concatenate(
                 [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
                  carry[4][None].astype(jnp.int32)], axis=0)

@@ -71,10 +71,6 @@ from .paged_attention import paged_attention_xla
 
 NEG_INF = -1e30
 
-# renamed across jax versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or \
-    pltpu.CompilerParams
-
 # pages DMA'd per compute block, keyed by (page_size, fused). Populated by
 # examples/flash_decode_tune.py on hardware; unlisted shapes fall back to
 # the ~512-token-block heuristic below (4 pages at the flagship P=128).
@@ -484,8 +480,8 @@ def flash_decode_attention_pallas(
             pl.BlockSpec((1, 1, h * dh), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, w, fused), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, w, fused), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, 1, h * dh), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[
@@ -506,7 +502,7 @@ def flash_decode_attention_pallas(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, h * dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # the grid walks rows sequentially on purpose: the double-
             # buffer/step state crosses grid steps (cross-row prefetch)
             dimension_semantics=("arbitrary",)),
@@ -567,15 +563,15 @@ def flash_decode_attention_fw_pallas(
             pl.BlockSpec((1, 1, h * dh), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, 1, fused), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, 1, fused), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, h * dh), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, bp, page_size, fused), k_pages.dtype),
@@ -603,7 +599,7 @@ def flash_decode_attention_fw_pallas(
         # aliasing indices COUNT the 8 scalar-prefetch operands (probed on
         # this jax version): side_k/side_v are call args 13/14
         input_output_aliases={13: 1, 14: 2},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         cost_estimate=pl.CostEstimate(
             flops=4 * b * (mp * page_size + w) * h * dh,

@@ -14,10 +14,13 @@ weights:
 Numerics contract — BIT-PARITY with the unfused path. The kernel bodies
 execute the exact op sequence of ``ops.norms.rms_norm`` (fp32 mean of
 squares, ``x * (1/sqrt(ms+eps))``, scale multiply in fp32, cast back to
-the activation dtype) followed by a plain ``jnp.dot`` with NO
-``preferred_element_type`` — matching ``matmul_any``'s plain-ndarray
-branch (``jnp.einsum``) so the fused and unfused engines produce the same
-tokens greedily and under fixed sampling keys (tests/test_fused_decode.py).
+the activation dtype) followed by a dot accumulated in fp32 and rounded
+once to the activation dtype (``_dot``) — what ``matmul_any``'s
+plain-ndarray branch (``jnp.einsum``) computes, spelled the only way
+Mosaic accepts: a bf16-typed accumulator is rejected on the chip
+("'tpu.matmul' op Expected matmul acc to be 32-bit", libtpu 0.0.34). The
+fused and unfused engines produce the same tokens greedily and under fixed
+sampling keys (tests/test_fused_decode.py).
 
 Grid: 1-D over N output blocks. The [B, D] activation block uses a
 constant index map, so it is DMA'd into VMEM once and stays resident
@@ -35,8 +38,9 @@ RoPE likewise stays outside (it permutes per-head lanes *after* the
 split of the fused QKV projection; folding it in would burn a transpose
 inside the kernel to save ~0.1% of the byte stream).
 
-Like ``ops/int4_matmul.py``, ``interpret`` defaults to on for non-TPU
-backends so the same code path is testable on CPU.
+Like ``ops/int4_matmul.py``, ``interpret`` defaults to on for the CPU
+backend only (the parity tests); on any other backend the kernels compile
+or the program fails.
 """
 
 from __future__ import annotations
@@ -60,7 +64,7 @@ _VMEM_BUDGET = 8 * 1024 * 1024
 
 def _interpret_default(interpret: Optional[bool]) -> bool:
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return jax.default_backend() == "cpu"
     return interpret
 
 
@@ -117,6 +121,12 @@ def matmul_residual_wants(x, w) -> bool:
     return norm_matmul_wants(x, w)
 
 
+def _dot(a, b):
+    """``a @ b`` with the fp32 accumulator Mosaic requires, rounded once
+    to the operand dtype."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32).astype(a.dtype)
+
+
 def _norm_matmul_kernel(x_ref, g_ref, w_ref, o_ref, *, eps, plus_one):
     # exact rms_norm op sequence (ops/norms.py) — do not "simplify" to
     # rsqrt or fold the gain into the scale: bit-parity is the contract
@@ -127,7 +137,7 @@ def _norm_matmul_kernel(x_ref, g_ref, w_ref, o_ref, *, eps, plus_one):
     if plus_one:
         g = g + 1.0
     h = (y * g).astype(x_ref.dtype)
-    o_ref[...] = jnp.dot(h, w_ref[...])
+    o_ref[...] = _dot(h, w_ref[...])
 
 
 def norm_matmul(
@@ -163,7 +173,7 @@ def norm_matmul(
 
 
 def _matmul_residual_kernel(x_ref, w_ref, r_ref, o_ref):
-    o_ref[...] = r_ref[...] + jnp.dot(x_ref[...], w_ref[...])
+    o_ref[...] = r_ref[...] + _dot(x_ref[...], w_ref[...])
 
 
 def matmul_residual(

@@ -62,19 +62,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed across jax versions (TPUCompilerParams -> CompilerParams)
-_CompilerParams = getattr(pltpu, "TPUCompilerParams", None) or \
-    pltpu.CompilerParams
-
 # kernel dispatch mode (read at TRACE time):
-#   auto      — use the kernel on a single-device TPU process (the bench /
-#               single-chip serving deploys); XLA einsum path elsewhere.
-#   cp        — multi-device (tp) path: the kernel rides a
-#               ``custom_partitioning`` op with a Shardy rule, so GSPMD
-#               partitions the opaque pallas_call instead of gathering
-#               around it (r5; engines select this automatically when
-#               their int4 params land sharded across devices).
-#   on        — always, direct (interpreted off-TPU: CPU kernel tests)
+#   auto      — use the kernel whenever the backend is an accelerator,
+#               however many devices the process can see; on the CPU
+#               backend (tests) take the XLA einsum path. Which FORM of
+#               the kernel runs follows where the weight lives: engines
+#               stamp "cp" onto int4 params that landed sharded
+#               (``ops.quant.resolve_kernel_modes``); unstamped
+#               (single-device or replicated) weights take the direct
+#               call.
+#   cp        — sharded (tp) path: the kernel rides a
+#               ``custom_partitioning`` op with a Shardy rule, so the
+#               partitioner splits the opaque pallas_call instead of
+#               gathering around it.
+#   on        — always, direct (interpreted on the CPU backend: kernel
+#               tests)
 #   off       — never
 _MODE = os.environ.get("INT4_MATMUL_KERNEL", "auto")
 
@@ -106,19 +108,26 @@ def _tensor_mode(w) -> str:
     return getattr(w, "kernel_mode", "") or _MODE
 
 
+def _interpret() -> bool:
+    """Pallas interpret mode is for the CPU backend only (the parity
+    tests). On any other backend the kernel compiles or the program
+    fails — it never interprets silently."""
+    return jax.default_backend() == "cpu"
+
+
 def _mode_engaged(mode: str = "") -> bool:
     """Mode/backend half of kernel eligibility (shared by the per-layer
-    and stacked predicates): "on"/"cp" always, "auto" only on a
-    single-device TPU process. ("cp" wraps the kernel in a
-    custom_partitioning op so GSPMD can partition it — without that a
-    pallas_call is opaque and tp-sharded weights would force a gather;
-    engines stamp "cp" onto their int4 params when placement lands them
-    multi-device.)"""
+    and stacked predicates): "on"/"cp" always, "auto" on every backend
+    but CPU. The number of visible devices plays no part: a tp=1 deploy
+    on a four-chip host holds its weights on one chip and takes the
+    direct kernel like a one-chip host does; weights that landed sharded
+    carry the "cp" stamp (``ops.quant.resolve_kernel_modes``), which
+    wraps the kernel in a custom_partitioning op so the partitioner can
+    split it — a bare pallas_call is opaque and would force a gather."""
     mode = mode or _MODE
     if mode == "off":
         return False
-    return mode in ("on", "cp") or (jax.default_backend() == "tpu"
-                                    and len(jax.devices()) == 1)
+    return mode in ("on", "cp") or not _interpret()
 
 
 def pattern_fits(pattern: str, x, k2: int) -> bool:
@@ -135,20 +144,34 @@ def pattern_fits(pattern: str, x, k2: int) -> bool:
     return x.shape[-1] == 2 * k2
 
 
-def kernel_wants(pattern: str, x, w) -> bool:
-    """True when the Mosaic kernel should take this einsum: mode allows
-    it, the weight is an unstacked ``[K/2, N]`` payload contracted on its
-    packed axis, and the shapes tile cleanly (K/2 and N divisible by the
-    block candidates). Everything else falls back to the XLA path."""
+def _payload_wants(w) -> bool:
+    """Weight half of kernel eligibility for an unstacked ``[K/2, N]``
+    payload: mode allows it, packed on axis 0, and K/2 and N divide the
+    block candidates."""
     if not _mode_engaged(_tensor_mode(w)):
         return False
     if w.q.ndim != 2 or w.pack_axis % w.q.ndim != 0:
         return False                    # payload must be packed on axis 0
     k2, n = w.q.shape
-    if not pattern_fits(pattern, x, k2):
-        return False
     return (_block_of(k2, _K_BLOCKS) is not None
             and _block_of(n, _N_BLOCKS) is not None)
+
+
+def kernel_wants(pattern: str, x, w) -> bool:
+    """True when the Mosaic kernel should take this einsum: the payload
+    is eligible (``_payload_wants``) and contracted on its packed axis.
+    Everything else takes the XLA path."""
+    return _payload_wants(w) and pattern_fits(pattern, x, w.q.shape[0])
+
+
+def kernel_path(w) -> str:
+    """"direct" | "cp" | "xla" — how an int4 ``QuantizedTensor`` of a
+    prepared tree reaches the MXU (``ops.quant.int4_kernel_paths``)."""
+    wants = stacked_kernel_wants(w) if w.q.ndim == 3 else \
+        w.q.ndim == 2 and _payload_wants(w)
+    if not wants:
+        return "xla"
+    return "cp" if _tensor_mode(w) == "cp" else "direct"
 
 
 # preference order measured on v5e at the 8B decode shape ([64,4096] @
@@ -203,7 +226,7 @@ def int4_einsum_kernel(pattern: str, x, w):
     k2, n = w.q.shape
     lead = x.shape[:-1]
     xm = x.reshape(-1, x.shape[-1])
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret()
     if _tensor_mode(w) == "cp":
         y = _cp_stacked(interpret)(xm[:, :k2], xm[:, k2:], w.q[None],
                                    w.s.astype(jnp.float32).reshape(1, 1, n),
@@ -311,7 +334,7 @@ def _int4_matmul_stacked(x, packed, scale, layer, *, interpret: bool = False,
         _kernel_stacked,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             # the int32 nibble-widening temporaries ([bk, bn] lo+hi) top
             # 16 MB at the prefill tile (bm=128, bn=2048) — past the
@@ -339,7 +362,7 @@ def int4_einsum_kernel_stacked(pattern: str, x, w, layer):
     _l, k2, n = w.q.shape
     lead = x.shape[:-1]
     xm = x.reshape(-1, x.shape[-1])
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret()
     if _tensor_mode(w) == "cp":
         y = _cp_stacked(interpret)(xm[:, :k2], xm[:, k2:], w.q,
                                    w.s.astype(jnp.float32),
@@ -373,6 +396,16 @@ def int4_einsum_kernel_stacked(pattern: str, x, w, layer):
 # shard whose K2/N no longer divides the block candidates falls back to
 # the XLA dequant einsum LOCALLY (correct, slower) rather than failing
 # to lower.
+#
+# STATUS ON REAL CHIPS (PR 21, v5e 2x2 host, jax 0.9.0 / libtpu 0.0.34):
+# any program containing this op fails to compile — "INVALID_ARGUMENT:
+# Custom emitter for CustomSPMDPartitioning not found". jax creates the
+# TPU client through ``make_tpu_client``, which never passes libtpu's PJRT
+# C API to the plug-in callbacks that register the Python partitioner, so
+# the op reaches the TPU compiler unpartitioned. The virtual CPU mesh
+# partitions in-process and passes. No catch here: a tp>1 int4 deploy
+# fails at its first compile until this is rebuilt on shard_map
+# (ROADMAP S9).
 
 
 def _cp_local_fallback(xlo, xhi, packed, scale):
@@ -388,12 +421,10 @@ def _cp_local_fallback(xlo, xhi, packed, scale):
 
 @functools.lru_cache(maxsize=2)
 def _cp_stacked(interpret: bool):
-    from jax.experimental.custom_partitioning import custom_partitioning
-
-    try:  # Shardy rule (jax with the Sdy partitioner); else GSPMD callbacks
-        from jax.experimental.custom_partitioning import SdyShardingRule
-    except ImportError:                               # pragma: no cover
-        SdyShardingRule = None
+    from jax.experimental.custom_partitioning import (
+        SdyShardingRule,
+        custom_partitioning,
+    )
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def _impl(xlo, xhi, packed, scale, layer):
@@ -443,27 +474,11 @@ def _cp_stacked(interpret: bool):
 
         return mesh, lower_fn, out_sharding, arg_shardings
 
-    if SdyShardingRule is not None:
-        rule = SdyShardingRule(
-            operand_mappings=(("m", "j"), ("m", "j"), ("l", "j", "n"),
-                              ("l", "z", "n"), ("o",)),
-            result_mappings=(("m", "n"),),
-            reduction_factors=("j",),
-        )
-        cp.def_partition(partition=_partition, sharding_rule=rule)
-    else:
-        # pre-Shardy jax: express the same rule through the GSPMD
-        # callbacks — output inherits (m from x, n from the payload); the
-        # j (reduction) factor is handled by _partition's psum
-        def _infer(mesh, arg_infos, result_infos):
-            xs = (arg_infos[0].sharding.spec if arg_infos[0].sharding
-                  else P())
-            ps = (arg_infos[2].sharding.spec if arg_infos[2].sharding
-                  else P(None, None, None))
-            m_ax = xs[0] if len(xs) > 0 else None
-            n_ax = ps[2] if len(ps) > 2 else None
-            return NamedSharding(mesh, P(m_ax, n_ax))
-
-        cp.def_partition(partition=_partition,
-                         infer_sharding_from_operands=_infer)
+    rule = SdyShardingRule(
+        operand_mappings=(("m", "j"), ("m", "j"), ("l", "j", "n"),
+                          ("l", "z", "n"), ("o",)),
+        result_mappings=(("m", "n"),),
+        reduction_factors=("j",),
+    )
+    cp.def_partition(partition=_partition, sharding_rule=rule)
     return cp

@@ -24,15 +24,15 @@ Scheme: symmetric per-output-channel int8.
 
 int4 (packed nibbles, ``bits=4``) — the FASTEST measured single-chip
 config since r4: 4,254 tok/s vs int8's 3,661 at the 8B bs64 rung, via
-the Mosaic in-register-unpack matmul (``ops/int4_matmul.py``), which on
-single-device TPU processes takes the layer-STACKED payload whole and
-selects the layer inside the pallas grid (``split_indexed_blocks`` +
-``IndexedQuant`` below keep those payloads out of the layer-scan xs — a
-scanned slice feeding an opaque custom call would be materialized as a
-real HBM copy, the r3→r4 1,584→3,308 cliff). The pure-XLA fallback
-(multi-device / CPU) fuses the nibble shifts into the dot operand
-(``_einsum_int4``) but XLA still materializes the unpacked operand —
-its measured 1,584 tok/s is why the kernel exists.
+the Mosaic in-register-unpack matmul (``ops/int4_matmul.py``), which
+takes the layer-STACKED payload whole and selects the layer inside the
+pallas grid (``split_indexed_blocks`` + ``IndexedQuant`` below keep those
+payloads out of the layer-scan xs — a scanned slice feeding an opaque
+custom call would be materialized as a real HBM copy, the r3→r4
+1,584→3,308 cliff). The pure-XLA path (CPU backend, kernel mode "off",
+shapes the kernel cannot tile) fuses the nibble shifts into the dot
+operand (``_einsum_int4``) but XLA still materializes the unpacked
+operand — its measured 1,584 tok/s is why the kernel exists.
 """
 
 from __future__ import annotations
@@ -234,8 +234,8 @@ def split_indexed_blocks(blocks: Dict[str, Any]):
     """Split a stacked blocks tree for a layer scan: kernel-eligible
     int4 payloads leave the scan xs (returned tree) and are re-attached
     per-iteration as ``IndexedQuant`` by ``rebuild(xs_slice, idx)``.
-    Identity when the stacked kernel is not engaged (multi-device, CPU,
-    int8, …) — the XLA paths fuse scanned slices for free."""
+    Identity when the stacked kernel is not engaged (CPU backend, int8,
+    …) — the XLA paths fuse scanned slices for free."""
     from .int4_matmul import stacked_kernel_wants
 
     static = {name: w for name, w in blocks.items()
@@ -310,6 +310,23 @@ def resolve_kernel_modes(params: Dict[str, Any]) -> Dict[str, Any]:
         params, is_leaf=_is_qt)
 
 
+def int4_kernel_paths(params: Dict[str, Any]) -> Dict[str, int]:
+    """How the int4 tensors of a PREPARED tree reach the MXU, by count:
+    ``direct`` (bare Mosaic call), ``cp`` (Mosaic call inside the
+    custom_partitioning wrapper) or ``xla`` (dequant einsum). Read off the
+    same predicates the traced matmuls use, so a deploy can assert that
+    its weight stream rides the kernel instead of inferring it from
+    throughput."""
+    from .int4_matmul import kernel_path
+
+    counts = {"direct": 0, "cp": 0, "xla": 0}
+    for leaf in jax.tree_util.tree_leaves(
+            params, is_leaf=lambda x: isinstance(x, QuantizedTensor)):
+        if isinstance(leaf, QuantizedTensor) and leaf.bits == 4:
+            counts[kernel_path(leaf)] += 1
+    return counts
+
+
 def prepare_params(params: Dict[str, Any]) -> Dict[str, Any]:
     """Engine-init param preparation, one entry point for every engine:
     (1) stamp the int4 tensors with kernel mode "cp" if placement left
@@ -327,7 +344,7 @@ def fuse_block_weights(params: Dict[str, Any]) -> Dict[str, Any]:
     arguments, so a trace-time concat would re-copy ~1 GB every call).
 
     The fused entry is an ordinary stacked ``QuantizedTensor``: every
-    consumer path (Mosaic kernel, XLA int4 einsum on CPU/multi-device,
+    consumer path (Mosaic kernel, XLA int4 einsum on the CPU backend,
     checkpoint round-trip, ``truncated_draft`` layer slicing) handles it
     unchanged. Identity when a group's members are absent, not int4
     stacked payloads, shape-mismatched, or bias-carrying. NOT applied

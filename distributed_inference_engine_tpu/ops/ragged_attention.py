@@ -67,7 +67,6 @@ from jax.experimental.pallas import tpu as pltpu
 from .attention import suffix_attention
 from .flash_decode import (
     NEG_INF,
-    _CompilerParams,
     _default_pages_per_block,
     _layer_scalar,
     _next_live,
@@ -393,13 +392,13 @@ def ragged_attention_pallas(
             pl.BlockSpec((1, qmax, h * dh), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, qmax, fused), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, qmax, fused), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((1, qmax, h * dh), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, bp, page_size, fused), k_pages.dtype),
@@ -427,7 +426,7 @@ def ragged_attention_pallas(
         # alias the pools through: operand indices COUNT the 7 scalar-
         # prefetch args, so q=7, fresh=8/9, pools=10/11 -> outputs 1/2
         input_output_aliases={10: 1, 11: 2},
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # sequential rows on purpose: the double-buffer/step state
             # crosses grid steps (cross-row prefetch)
             dimension_semantics=("arbitrary",)),

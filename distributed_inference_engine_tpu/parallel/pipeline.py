@@ -34,20 +34,8 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.7 public API
-except ImportError:                                   # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# the replication-check kwarg was renamed check_rep -> check_vma across jax
-# versions; pass whichever this jax spells
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma" if "check_vma"
-             in _inspect.signature(_shard_map).parameters else "check_rep")
 
 from ..models.base import (
     ModelSpec,
@@ -134,10 +122,10 @@ def pipeline_hidden(
     blocks_spec = jax.tree.map(lambda _: P("pp"), params["blocks"])
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        shard_map, mesh=mesh,
         in_specs=(blocks_spec, P(None, "dp"), P(None, "dp")),
         out_specs=P(None, "dp"),
-        **{_CHECK_KW: False},
+        check_vma=False,
     )
     def run(blocks, xs, lens):
         stage = lax.axis_index("pp")

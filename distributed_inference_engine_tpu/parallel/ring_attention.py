@@ -21,15 +21,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import NEG_INF
-
-try:
-    from jax import shard_map as _shard_map          # jax >= 0.7 public API
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def _ring_body(q, k, v, seq_lens, *, axis: str, n_kv_heads: int,
@@ -37,8 +32,7 @@ def _ring_body(q, k, v, seq_lens, *, axis: str, n_kv_heads: int,
     """Per-device body: q/k/v are LOCAL blocks [B, Tl, H|Hkv, Dh]."""
     b, tl, h, dh = q.shape
     g = h // n_kv_heads
-    n = (lax.axis_size(axis) if hasattr(lax, "axis_size")
-         else lax.psum(1, axis))           # psum(1): pre-0.5 jax spelling
+    n = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     scale = 1.0 / jnp.sqrt(dh).astype(jnp.float32)
 
@@ -49,11 +43,7 @@ def _ring_body(q, k, v, seq_lens, *, axis: str, n_kv_heads: int,
     # device-varying over the ring axis so the loop carry types match (the
     # accumulators genuinely diverge per device from step 0)
     def _vary(x):
-        if hasattr(lax, "pcast"):
-            return lax.pcast(x, axis, to="varying")
-        if hasattr(lax, "pvary"):
-            return lax.pvary(x, axis)                 # older jax
-        return x          # pre-varying-types jax: carries already match
+        return lax.pcast(x, axis, to="varying")
 
     m = _vary(jnp.full((b, n_kv_heads, g, tl), NEG_INF, dtype=jnp.float32))
     l = _vary(jnp.zeros((b, n_kv_heads, g, tl), dtype=jnp.float32))
@@ -131,6 +121,6 @@ def ring_attention(
     else:
         args = (q, k, v)
         fn = lambda q_, k_, v_: body(q_, k_, v_, None)
-    return _shard_map(
+    return shard_map(
         fn, mesh=mesh, in_specs=in_specs, out_specs=seq_spec,
     )(*args)

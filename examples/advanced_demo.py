@@ -5,6 +5,11 @@ disaggregated prefill/decode pair — each exercised end-to-end in process.
 Scripted like the reference's ``examples/batcher_demo.py`` (assertions in
 prose, printed outcomes), but every section drives the real serving path.
 
+CPU demo: every in-process worker builds its engine on JAX's default
+device, so on a multi-chip host all replicas would share chip 0. For one
+replica per chip start ``cli.worker`` processes confined by libtpu's
+chip-visibility variables (README "One worker per chip").
+
     JAX_PLATFORMS=cpu python examples/advanced_demo.py
 """
 
@@ -14,12 +19,6 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-from distributed_inference_engine_tpu.utils.platform import (  # noqa: E402
-    pin_platform_from_env,
-)
-
-pin_platform_from_env()
 
 from distributed_inference_engine_tpu.api.coordinator import (  # noqa: E402
     Coordinator,

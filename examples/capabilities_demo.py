@@ -22,12 +22,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8").strip()
 
-from distributed_inference_engine_tpu.utils.platform import (  # noqa: E402
-    pin_platform_from_env,
-)
-
-pin_platform_from_env()
-
 import jax  # noqa: E402
 
 from distributed_inference_engine_tpu.config import (  # noqa: E402

@@ -7,6 +7,11 @@ requests to the balanced worker — it slept instead
 the coordinator's full path (cache -> batcher -> router/LB -> framed RPC ->
 real JAX engine) and a worker is killed mid-run to show failover.
 
+CPU demo: every in-process worker builds its engine on JAX's default
+device, so on a multi-chip host all replicas would share chip 0. For one
+replica per chip start ``cli.worker`` processes confined by libtpu's
+chip-visibility variables (README "One worker per chip").
+
     JAX_PLATFORMS=cpu python examples/fleet_demo.py --workers 3 --requests 24
 """
 
@@ -18,12 +23,6 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-
-from distributed_inference_engine_tpu.utils.platform import (  # noqa: E402
-    pin_platform_from_env,
-)
-
-pin_platform_from_env()
 
 from distributed_inference_engine_tpu.api.coordinator import (  # noqa: E402
     Coordinator, CoordinatorConfig,

@@ -88,7 +88,10 @@ after a failover — must produce the same stream):
               decode worker disaggregated vs a plain continuous reference
               worker, same seeded random-init weights (init key 0), same
               prompts — the disagg path must be token-exact against the
-              single-engine answer THROUGH the coordinator.
+              single-engine answer THROUGH the coordinator. A CPU leg:
+              its three in-process workers all build on JAX's default
+              device (one worker per chip needs ``cli.worker`` processes,
+              README "One worker per chip").
 
 Knobs: BENCH_FLEET_* (read by bench.py — see its docstring) size the
 fleet and load; SWEEP_LEGS=replicated,disagg,... runs a subset. One JSON

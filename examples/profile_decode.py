@@ -16,7 +16,12 @@ import os
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
+
+from distributed_inference_engine_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache,
+)
+
+configure_compile_cache()
 
 import bench  # noqa: E402
 from bench import log  # noqa: E402

@@ -21,7 +21,12 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
+
+from distributed_inference_engine_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache,
+)
+
+configure_compile_cache()
 
 
 def log(msg: str) -> None:

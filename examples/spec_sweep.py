@@ -23,7 +23,12 @@ import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
+
+from distributed_inference_engine_tpu.utils.compile_cache import (  # noqa: E402
+    configure_compile_cache,
+)
+
+configure_compile_cache()
 os.environ.setdefault("BENCH_BATCH", "32")   # bs64 OOMs a 16 GB chip here
 # bench.py defaults 8B-class to int4 since r4; the documented r4 sweep
 # (and the hard-coded AR baselines below) were measured on the int8

@@ -23,12 +23,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-from distributed_inference_engine_tpu.utils.platform import (  # noqa: E402
-    pin_platform_from_env,
-)
-
-pin_platform_from_env()
-
 from distributed_inference_engine_tpu.cluster.worker import (  # noqa: E402
     WorkerClient, WorkerServer,
 )

@@ -1,0 +1,420 @@
+"""Compile and run every Pallas kernel in ``ops/`` once, non-interpreted, at
+the mistral-7b serving shapes, each against its XLA path.
+
+    python chip_smoke.py --kernels            # the one command, on the chip
+    python -m scripts.chip_kernels --tiny     # CPU debug: interpreted, tiny
+
+Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; M=128 against
+the five int4 payload shapes of ``ops.int4_matmul._TUNED_BLOCKS`` with
+N=32,768 for the lm_head. Tolerances are the ones the CPU parity tests use
+for the same dtypes (``tests/test_int4_matmul.py``, ``test_flash_decode.py``,
+``test_ragged_attention.py``, ``test_paged.py``, ``test_fused_decode.py``).
+
+Each kernel gets one outcome line — ``ok`` (compiled, ran, matched XLA),
+``mismatch`` or ``refused`` with the compiler's message — and the table is
+written to ``chiprun_out/chip_kernels.json`` after every kernel, so a crash
+keeps what was learned. Exit 0 only when every kernel is ``ok``. The catch
+around each check belongs to this harness: the kernels themselves have no
+fallback, so a refusal here is a refusal in an engine that selects them.
+Not part of the tier-1 CPU suite.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+import traceback
+
+FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
+            D=4096, fused_N=(6144, 4096),
+            int4_shapes=((2048, 6144), (2048, 4096), (2048, 28672),
+                         (7168, 4096), (2048, 32768)))
+TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
+            D=256, fused_N=(512, 256),
+            int4_shapes=((128, 256), (256, 128)))
+OUT = os.path.join("chiprun_out", "chip_kernels.json")
+
+
+def _close(got, ref, tol: float) -> float:
+    import numpy as np
+
+    got = np.asarray(got, np.float32)
+    ref = np.asarray(ref, np.float32)
+    err = float(np.max(np.abs(got - ref)))
+    np.testing.assert_allclose(got, ref, rtol=tol, atol=tol)
+    return err
+
+
+def _int4_inputs(cfg, k2, n, layers):
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.key(k2 + n), 3)
+    packed = jax.random.randint(ks[0], (layers, k2, n), -128, 128,
+                                dtype=jnp.int8)
+    scale = jnp.full((layers, 1, n), 0.02 / 4.3, jnp.float32)
+    x = jax.random.normal(ks[1], (cfg["M"], 2 * k2),
+                          jnp.float32).astype(jnp.bfloat16)
+    return x, packed, scale
+
+
+def _int4_ref(x, packed, scale, layer):
+    """XLA path: ``ops.quant._einsum_int4`` on the same packed bytes."""
+    from distributed_inference_engine_tpu.ops.quant import (
+        QuantizedTensor,
+        _einsum_int4,
+    )
+
+    w = QuantizedTensor(q=packed[layer], s=scale[layer], bits=4,
+                        pack_axis=-2)
+    return _einsum_int4("md,df->mf", x, w)
+
+
+def check_int4_2d(cfg, interpret):
+    from distributed_inference_engine_tpu.ops.int4_matmul import (
+        _int4_matmul_2d,
+    )
+
+    errs = []
+    for k2, n in cfg["int4_shapes"]:
+        x, packed, scale = _int4_inputs(cfg, k2, n, 1)
+        got = _int4_matmul_2d(x, packed[0], scale[0, 0],
+                              interpret=interpret)
+        errs.append(_close(got, _int4_ref(x, packed, scale, 0), 1e-2))
+    return f"{len(errs)} shapes, max|err| {max(errs):.2e}"
+
+
+def check_int4_stacked(cfg, interpret):
+    import jax
+
+    from distributed_inference_engine_tpu.ops.int4_matmul import (
+        _int4_matmul_stacked,
+    )
+
+    errs = []
+    for k2, n in cfg["int4_shapes"]:
+        x, packed, scale = _int4_inputs(cfg, k2, n, cfg["L"])
+        layer = cfg["L"] - 1
+        fn = jax.jit(lambda x, p, s, l: _int4_matmul_stacked(
+            x, p, s, l, interpret=interpret))
+        if not interpret:
+            text = fn.lower(x, packed, scale, layer).as_text()
+            assert "tpu_custom_call" in text, \
+                "stacked int4 matmul did not lower to the Mosaic custom call"
+        got = fn(x, packed, scale, layer)
+        errs.append(_close(got, _int4_ref(x, packed, scale, layer), 1e-2))
+    return f"{len(errs)} shapes, max|err| {max(errs):.2e}"
+
+
+def check_int4_cp(cfg, interpret):
+    """The custom_partitioning wrapper under Shardy on a tp mesh over every
+    visible device (up to 4): column-parallel (N sharded) and row-parallel
+    (packed K sharded, psum) placements of each payload shape."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distributed_inference_engine_tpu.ops.int4_matmul import _cp_stacked
+
+    tp = min(4, len(jax.devices()))
+    mesh = Mesh(np.array(jax.devices()[:tp]), ("tp",))
+    cp = _cp_stacked(interpret)
+    errs = []
+    for k2, n in cfg["int4_shapes"]:
+        x, packed, scale = _int4_inputs(cfg, k2, n, cfg["L"])
+        layer = jnp.full((1,), cfg["L"] - 1, jnp.int32)
+        ref = _int4_ref(x, packed, scale, cfg["L"] - 1)
+        for name, pspec, sspec in (
+                ("col", P(None, None, "tp"), P(None, None, "tp")),
+                ("row", P(None, "tp", None), P())):
+            pk = jax.device_put(packed, NamedSharding(mesh, pspec))
+            sc = jax.device_put(scale, NamedSharding(mesh, sspec))
+            got = jax.jit(cp)(x[:, :k2], x[:, k2:], pk, sc, layer)
+            errs.append(_close(got, ref, 2e-2 if name == "row" else 1e-2))
+    return (f"tp={tp}, {len(errs)} placements, "
+            f"max|err| {max(errs):.2e}")
+
+
+def _paged_inputs(cfg):
+    import jax
+    import jax.numpy as jnp
+
+    b, h, hkv, dh, p = (cfg[k] for k in ("B", "H", "Hkv", "Dh", "P"))
+    mp = cfg["ctx"] // p
+    n = b * mp + 8
+    ks = jax.random.split(jax.random.key(7), 8)
+    bf = jnp.bfloat16
+    q = jax.random.normal(ks[0], (b, h, dh), jnp.float32).astype(bf)
+    kp = jax.random.normal(ks[1], (n, p, hkv * dh), jnp.float32).astype(bf)
+    vp = jax.random.normal(ks[2], (n, p, hkv * dh), jnp.float32).astype(bf)
+    # rows own DISJOINT pages (the engine invariant)
+    pt = jax.random.permutation(ks[3], n)[: b * mp].reshape(b, mp)
+    return q, kp, vp, pt.astype(jnp.int32), ks[4:], n
+
+
+def check_paged_attention(cfg, interpret):
+    import jax
+
+    from distributed_inference_engine_tpu.ops.paged_attention import (
+        paged_attention_pallas,
+        paged_attention_xla,
+    )
+
+    q, kp, vp, pt, ks, n = _paged_inputs(cfg)
+    lengths = jax.random.randint(ks[0], (cfg["B"],), 1, cfg["ctx"] + 1)
+    ref = paged_attention_xla(q, kp, vp, pt, lengths,
+                              n_kv_heads=cfg["Hkv"])
+    got = jax.jit(lambda *a: paged_attention_pallas(
+        *a, n_kv_heads=cfg["Hkv"], interpret=interpret, layer=0,
+        n_pages_per_layer=n))(q, kp, vp, pt, lengths)
+    return f"max|err| {_close(got, ref, 2e-2):.2e}"
+
+
+def _side_inputs(cfg, ks):
+    import jax
+    import jax.numpy as jnp
+
+    b, hkv, dh, w = cfg["B"], cfg["Hkv"], cfg["Dh"], cfg["W"]
+    sk = jax.random.normal(ks[0], (b, w, hkv, dh),
+                           jnp.float32).astype(jnp.bfloat16)
+    sv = jax.random.normal(ks[1], (b, w, hkv, dh),
+                           jnp.float32).astype(jnp.bfloat16)
+    plen = jax.random.randint(ks[2], (b,), 0, cfg["ctx"] - w + 1)
+    return sk, sv, plen
+
+
+def check_flash_decode(cfg, interpret):
+    import jax
+
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        flash_decode_attention_pallas,
+        flash_decode_attention_xla,
+    )
+
+    q, kp, vp, pt, ks, n = _paged_inputs(cfg)
+    sk, sv, plen = _side_inputs(cfg, ks)
+    n_side = jax.random.randint(ks[3], (cfg["B"],), 0, cfg["W"] + 1)
+    ref = flash_decode_attention_xla(q, kp, vp, pt, plen, sk, sv, n_side,
+                                     n_kv_heads=cfg["Hkv"])
+    got = jax.jit(lambda *a: flash_decode_attention_pallas(
+        *a, n_kv_heads=cfg["Hkv"], interpret=interpret, layer=0,
+        n_pages_per_layer=n))(q, kp, vp, pt, plen, sk, sv, n_side)
+    return f"max|err| {_close(got, ref, 2e-2):.2e}"
+
+
+def check_flash_decode_fw(cfg, interpret):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        flash_decode_attention_fw_pallas,
+        flash_decode_attention_xla,
+    )
+
+    q, kp, vp, pt, ks, n = _paged_inputs(cfg)
+    sk, sv, plen = _side_inputs(cfg, ks)
+    b, hkv, dh, w = cfg["B"], cfg["Hkv"], cfg["Dh"], cfg["W"]
+    fks = jax.random.split(ks[3], 4)
+    fk = jax.random.normal(fks[0], (b, 1, hkv, dh),
+                           jnp.float32).astype(jnp.bfloat16)
+    fv = jax.random.normal(fks[1], (b, 1, hkv, dh),
+                           jnp.float32).astype(jnp.bfloat16)
+    idx = jax.random.randint(fks[2], (b,), 0, w)
+    active = jax.random.randint(fks[3], (b,), 0, 2)
+    onehot = (jnp.arange(w)[None, :] == idx[:, None]) & (active[:, None] > 0)
+    sk_ref = jnp.where(onehot[:, :, None, None], fk[:, 0][:, None], sk)
+    sv_ref = jnp.where(onehot[:, :, None, None], fv[:, 0][:, None], sv)
+    ref = flash_decode_attention_xla(q, kp, vp, pt, plen, sk_ref, sv_ref,
+                                     idx + active, n_kv_heads=hkv)
+    got, sk_new, sv_new = jax.jit(lambda *a: flash_decode_attention_fw_pallas(
+        *a, n_kv_heads=hkv, interpret=interpret, layer=0,
+        n_pages_per_layer=n))(q, kp, vp, pt, plen, sk, sv, fk, fv, idx,
+                              active)
+    err = _close(got, ref, 2e-2)
+    np.testing.assert_array_equal(np.asarray(sk_new, np.float32),
+                                  np.asarray(sk_ref, np.float32))
+    np.testing.assert_array_equal(np.asarray(sv_new, np.float32),
+                                  np.asarray(sv_ref, np.float32))
+    return f"max|err| {err:.2e}, side writeback bit-exact"
+
+
+def check_ragged_attention(cfg, interpret):
+    """Mixed batch at the engine's shape: one page-sized chunk bucket
+    (Qmax = page) shared by decode rows (q=1) and prefill-chunk rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_inference_engine_tpu.ops.ragged_attention import (
+        ragged_attention_pallas,
+        ragged_attention_xla,
+    )
+
+    _q, kp, vp, pt, ks, n = _paged_inputs(cfg)
+    b, h, hkv, dh, p = (cfg[k] for k in ("B", "H", "Hkv", "Dh", "P"))
+    qmax = p
+    bf = jnp.bfloat16
+    q = jax.random.normal(ks[0], (b, qmax, h, dh), jnp.float32).astype(bf)
+    fk = jax.random.normal(ks[1], (b, qmax, hkv, dh), jnp.float32).astype(bf)
+    fv = jax.random.normal(ks[2], (b, qmax, hkv, dh), jnp.float32).astype(bf)
+    # even rows decode (q=1, any context), odd rows carry a chunk
+    rows = jnp.arange(b)
+    q_lens = jnp.where(rows % 2 == 0, 1, 1 + (rows * 7) % qmax)
+    ctx_lens = jnp.minimum((rows * 13) % cfg["ctx"], cfg["ctx"] - q_lens)
+    args = (q, kp, vp, pt, ctx_lens.astype(jnp.int32),
+            q_lens.astype(jnp.int32), fk, fv)
+    out_r, kp_r, vp_r = ragged_attention_xla(*args, n_kv_heads=hkv)
+    out, kp_n, vp_n = jax.jit(lambda *a: ragged_attention_pallas(
+        *a, n_kv_heads=hkv, interpret=interpret, layer=0,
+        n_pages_per_layer=n))(*args)
+    err = _close(out, out_r, 2e-2)
+    np.testing.assert_array_equal(np.asarray(kp_n, np.float32),
+                                  np.asarray(kp_r, np.float32))
+    np.testing.assert_array_equal(np.asarray(vp_n, np.float32),
+                                  np.asarray(vp_r, np.float32))
+    return f"max|err| {err:.2e}, page writeback bit-exact"
+
+
+def _fused_inputs(cfg, n):
+    import jax
+    import jax.numpy as jnp
+
+    ks = jax.random.split(jax.random.key(n), 4)
+    bf = jnp.bfloat16
+    x = jax.random.normal(ks[0], (cfg["M"], cfg["D"]), jnp.float32).astype(bf)
+    g = (1.0 + 0.1 * jax.random.normal(ks[1], (cfg["D"],),
+                                       jnp.float32)).astype(bf)
+    w = (0.02 * jax.random.normal(ks[2], (cfg["D"], n),
+                                  jnp.float32)).astype(bf)
+    res = jax.random.normal(ks[3], (cfg["M"], n), jnp.float32).astype(bf)
+    return x, g, w, res
+
+
+def check_fused_norm_matmul(cfg, interpret):
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_engine_tpu.ops.fused_decode import (
+        norm_matmul,
+        norm_matmul_wants,
+    )
+    from distributed_inference_engine_tpu.ops.norms import rms_norm
+
+    x, g, w, _res = _fused_inputs(cfg, cfg["fused_N"][0])
+    assert norm_matmul_wants(x, w)
+    ref = jax.jit(lambda x, g, w: jnp.dot(rms_norm(x, g, 1e-5), w))(x, g, w)
+    got = jax.jit(lambda x, g, w: norm_matmul(
+        x, g, w, eps=1e-5, interpret=interpret))(x, g, w)
+    err = _close(got, ref, 2e-2)
+    exact = bool(jnp.all(got == ref))
+    return f"max|err| {err:.2e}, bit-exact vs XLA: {exact}"
+
+
+def check_fused_matmul_residual(cfg, interpret):
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_engine_tpu.ops.fused_decode import (
+        matmul_residual,
+        matmul_residual_wants,
+    )
+
+    x, _g, w, res = _fused_inputs(cfg, cfg["fused_N"][1])
+    assert matmul_residual_wants(x, w)
+    ref = jax.jit(lambda x, w, r: r + jnp.dot(x, w))(x, w, res)
+    got = jax.jit(lambda x, w, r: matmul_residual(
+        x, w, r, interpret=interpret))(x, w, res)
+    err = _close(got, ref, 2e-2)
+    exact = bool(jnp.all(got == ref))
+    return f"max|err| {err:.2e}, bit-exact vs XLA: {exact}"
+
+
+# name -> (check, on the default serving path?)
+CHECKS = {
+    "int4_matmul_2d": (check_int4_2d, True),
+    "int4_matmul_stacked": (check_int4_stacked, True),
+    "int4_matmul_cp": (check_int4_cp, True),
+    "paged_attention_pallas": (check_paged_attention, False),
+    "fused_decode_norm_matmul": (check_fused_norm_matmul, False),
+    "fused_decode_matmul_residual": (check_fused_matmul_residual, False),
+    "flash_decode": (check_flash_decode, False),
+    "flash_decode_fw": (check_flash_decode_fw, False),
+    "ragged_attention": (check_ragged_attention, False),
+}
+
+
+def _message(exc: BaseException) -> str:
+    """The compiler's own words: the first lines of the exception text
+    (Mosaic errors lead with the failing op and reason), capped."""
+    lines = [ln for ln in str(exc).splitlines() if ln.strip()]
+    return f"{type(exc).__name__}: " + " | ".join(lines[:6])[:1200]
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tiny", action="store_true",
+                    help="tiny shapes for a CPU (interpret-mode) debug run")
+    ap.add_argument("--only", default="",
+                    help="comma-separated subset of: " + ",".join(CHECKS))
+    args = ap.parse_args(argv)
+
+    from distributed_inference_engine_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
+    import jax
+
+    dev = jax.devices()
+    platform = dev[0].platform
+    interpret = platform == "cpu"
+    cfg = TINY if args.tiny else FULL
+    if interpret and not args.tiny:
+        print("chip_kernels: the full shapes compile for the chip; on the "
+              "cpu backend pass --tiny", flush=True)
+        return 2
+    print(f"chip_kernels: platform={platform} "
+          f"device_kind={dev[0].device_kind!r} n_devices={len(dev)} "
+          f"interpret={interpret} shapes={'tiny' if args.tiny else 'full'}",
+          flush=True)
+    names = [n for n in args.only.split(",") if n] or list(CHECKS)
+    unknown = [n for n in names if n not in CHECKS]
+    if unknown:
+        print(f"chip_kernels: unknown kernel(s) {unknown}", flush=True)
+        return 2
+    rows = []
+    os.makedirs(os.path.dirname(OUT), exist_ok=True)
+    for name in names:
+        fn, default_path = CHECKS[name]
+        t0 = time.perf_counter()
+        try:
+            detail = fn(cfg, interpret)
+            outcome = "ok"
+        except AssertionError as e:
+            outcome, detail = "mismatch", _message(e)
+        except Exception as e:   # harness boundary: record, go on, fail below
+            outcome, detail = "refused", _message(e)
+            traceback.print_exc()
+        row = {"kernel": name, "outcome": outcome, "detail": detail,
+               "default_path": default_path,
+               "seconds": round(time.perf_counter() - t0, 1),
+               "platform": platform, "device_kind": dev[0].device_kind,
+               "n_devices": len(dev), "interpret": interpret}
+        rows.append(row)
+        print(f"kernel {name}: {outcome} ({row['seconds']}s) {detail}",
+              flush=True)
+        with open(OUT, "w") as f:
+            json.dump(rows, f, indent=1)
+    bad = [r["kernel"] for r in rows if r["outcome"] != "ok"]
+    print(f"chip_kernels: {len(rows) - len(bad)}/{len(rows)} ok"
+          + (f"; not ok: {bad}" if bad else ""), flush=True)
+    return 1 if bad else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -32,10 +32,7 @@ DIST_NAMES = {
 # distributions whose import name differs (normalized, reverse direction)
 _IMPORT_OF_DIST = {v: k for k, v in DIST_NAMES.items()}
 
-_STDLIB: Set[str] = set(getattr(sys, "stdlib_module_names", ())) | {
-    "__future__",
-    "tomllib",   # stdlib from 3.11; config.py falls back to tomli below
-}
+_STDLIB: Set[str] = set(sys.stdlib_module_names) | {"__future__"}
 _REQ_LINE = re.compile(r"^([A-Za-z0-9_.\-]+)")
 
 

