@@ -1,0 +1,17 @@
+"""Share of the traced slice in which no PROGRAM was running on the device: the
+host's part of the idle time (the rest of ``device.idle_share`` is gaps
+between ops inside programs).
+"""
+
+from perfbench.lib import readers
+
+NAME = "device.between_programs_idle_share.overload"
+LAYER = "device"
+UNIT = "%"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "out_tok_s"
+
+
+def read(run):
+    return readers.between_programs_pct(run)

@@ -1,0 +1,112 @@
+"""The generator fixes the work of a run; the seed only reorders it."""
+
+import os
+import sys
+import statistics
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))))
+
+from perfbench.lib import traffic  # noqa: E402
+
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+MIXES = sorted(f[:-5] for f in os.listdir(os.path.join(HERE, "traffic"))
+               if f.endswith(".json"))
+
+
+def mix_for(name):
+    return traffic.load_mix(name)
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_same_seed_same_schedule(name):
+    mix = mix_for(name)
+    a = traffic.schedule(mix, 3000000019, 20.0, 1000)
+    b = traffic.schedule(mix, 3000000019, 20.0, 1000)
+    assert [(r.due_s, r.prompt, r.output_len) for r in a] == \
+        [(r.due_s, r.prompt, r.output_len) for r in b]
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_any_seed_same_work(name):
+    mix = mix_for(name)
+    want = traffic.totals(traffic.schedule(mix, 1, 20.0, 1000))
+    assert want["window"]["requests"] == round(mix["rate_rps"] * 20.0)
+    for seed in (2, 77, 2 ** 31 + 11):
+        assert traffic.totals(traffic.schedule(mix, seed, 20.0, 1000)) == want
+
+
+def test_seed_changes_order_and_tokens():
+    mix = mix_for("chat-steady")
+    a = traffic.schedule(mix, 1, 20.0, 1000)
+    b = traffic.schedule(mix, 2, 20.0, 1000)
+    assert [len(r.prompt) for r in a] != [len(r.prompt) for r in b]
+    assert a[0].prompt != b[0].prompt
+
+
+def test_phases_and_clipping():
+    mix = mix_for("chat-steady")
+    reqs = traffic.schedule(mix, 5, 30.0, 32768)
+    for r in reqs:
+        lo, length = traffic.phase_seconds(mix, 30.0)[r.phase]
+        assert lo <= r.due_s < lo + length
+        assert mix["prompt"]["min"] <= len(r.prompt) <= mix["prompt"]["max"]
+        assert mix["output"]["min"] <= r.output_len <= mix["output"]["max"]
+        assert all(1 <= t < 32768 for t in r.prompt)
+    assert [r.index for r in reqs] == list(range(len(reqs)))
+    assert reqs == sorted(reqs, key=lambda r: r.due_s)
+
+
+def test_every_prompt_unique():
+    mix = mix_for("chat-steady")
+    reqs = traffic.schedule(mix, 9, 30.0, 32768)
+    assert len({tuple(r.prompt[:32]) for r in reqs}) == len(reqs)
+
+
+def test_arrivals_are_poisson_given_their_count():
+    """Sorted uniforms: counts in equal bins are as dispersed as a Poisson
+    process's (variance / mean near 1, less the 1/bins the fixed total
+    takes), and no two seeds begin with the same requests."""
+    mix = mix_for("chat-steady")
+    bins, ratios, heads = 12, [], set()
+    for seed in range(40):
+        w = [r for r in traffic.schedule(mix, seed, 48.0, 1000, rate_rps=2.5)
+             if r.phase == "window"]
+        assert len(w) == 120
+        counts = [0] * bins
+        for r in w:
+            counts[int(r.due_s / 4.0)] += 1
+        ratios.append(statistics.pvariance(counts) / statistics.mean(counts))
+        heads.add(tuple((len(r.prompt), r.output_len) for r in w[:4]))
+    assert 0.75 < statistics.mean(ratios) < 1.1
+    assert len(heads) == 40
+
+
+def test_rate_override_scales_the_count():
+    mix = mix_for("chat-steady")
+    reqs = traffic.schedule(mix, 3, 10.0, 1000, rate_rps=5.0)
+    assert sum(r.phase == "window" for r in reqs) == 50
+
+
+def test_quantiles():
+    q = traffic.lognormal_quantiles(
+        {"median": 256, "sigma": 0.8, "min": 32, "max": 768}, 101)
+    assert q[50] == 256 and q[0] >= 32 and q[-1] == 768
+    assert q == sorted(q)
+
+
+def test_prime_parity_and_schedule_share_no_prefix():
+    """Generators seeded alike give one sequence at an offset; prompts that
+    began on the same word would share a prefix and hit the prefix cache.
+    Each purpose therefore has a stream of its own."""
+    mix = mix_for("chat-steady")
+    for seed in (1, 11, 101, 3000000019):
+        heads = [tuple(r.prompt[:16])
+                 for r in traffic.schedule(mix, seed, 30.0, 32768)]
+        for purpose in ("prime", "parity"):
+            rng = traffic.token_rng(purpose, seed)
+            heads += [tuple(rng.randrange(1, 32768) for _ in range(16))
+                      for _ in range(8)]
+        assert len(set(heads)) == len(heads)
