@@ -212,6 +212,10 @@ class Coordinator:
         # never span a failover: the timer resets per dispatch attempt.
         self.stream_itl_stats = LatencyStats()
         self._stream_frames = 0         # frames relayed to consumers
+        self._streams_in_flight = 0     # stream dispatches not yet done
+        # a stream's wait for a pooled connection to its worker: the
+        # queue between this process and the worker's slots
+        self.pool_wait_stats = LatencyStats()
         # worker_id -> last observed inter-frame gap (emit lag): a
         # worker whose gauge grows is buffering frames somewhere
         self._stream_emit_lag: Dict[str, float] = {}
@@ -1289,7 +1293,7 @@ class Coordinator:
             try:
                 _last_frame[0] = 0.0     # new attempt: no cross-attempt gap
                 result = await self._stream_once(model, worker_id, run_req,
-                                                 counting_cb)
+                                                 counting_cb, trace)
             except TRANSPORT_ERRORS as e:
                 alt = (None if attempt >= self.config.max_dispatch_retries
                        else self._pick_alternate(model, version, worker_id,
@@ -1384,7 +1388,8 @@ class Coordinator:
                     worker_id = alt
                     _last_frame[0] = 0.0
                     result = await self._stream_once(model, worker_id,
-                                                     run_req, counting_cb)
+                                                     run_req, counting_cb,
+                                                     trace)
                 except WorkerRPCError as e2:
                     if getattr(e2, "kind", "") != "overloaded":
                         raise
@@ -1415,18 +1420,27 @@ class Coordinator:
         return out
 
     async def _stream_once(self, model: str, worker_id: str, req,
-                           on_tokens) -> Any:
+                           on_tokens, trace: RequestTrace) -> Any:
         """One streaming dispatch with the same health accounting as
-        ``_dispatch_once``."""
+        ``_dispatch_once``. Marks ``conn_acquired`` on ``trace`` when the
+        dispatch holds its pooled connection to the worker (first attempt
+        only: marks are first-wins) and records the wait."""
         client = (self.router.client_for(worker_id)
                   if worker_id in self.router.workers
                   else self.lb.client_for(worker_id))
+
+        def acquired(wait_s: float) -> None:
+            trace.mark("conn_acquired")
+            self.pool_wait_stats.add(wait_s)
+
         self.lb.acquire(worker_id)
+        self._streams_in_flight += 1
         t0 = time.perf_counter()
         try:
             result = await client.generate_stream(
                 model, req, on_tokens,
                 timeout=self.config.dispatch_timeout_s,
+                on_acquired=acquired,
             )
         except Exception as e:
             # overloaded: neither an LB failure nor a health event (see
@@ -1439,6 +1453,7 @@ class Coordinator:
                 self.router.mark_worker_failure(worker_id)
             raise
         finally:
+            self._streams_in_flight -= 1
             self.lb.release(worker_id)
         self.lb.update_stats(worker_id, success=True,
                              latency_s=time.perf_counter() - t0)
@@ -1999,7 +2014,9 @@ class Coordinator:
     def _merge_worker_trace(inp: Any, out: Any) -> None:
         """Anchor the worker-reported phase offsets (attached by the worker
         as ``metadata['worker_trace']``) onto the request's local trace as
-        ``worker.*`` marks, pinned at the ``dispatched`` mark."""
+        ``worker.*`` marks, pinned at ``conn_acquired`` where the trace has
+        it (a stream: the wait for a pooled connection lies before the
+        worker saw anything), else at ``dispatched``."""
         if not isinstance(inp, dict) or not isinstance(out, dict):
             return
         tr = inp.get("trace")
@@ -2270,6 +2287,9 @@ class Coordinator:
             "dispatch_retries": self._dispatch_retries,
             "stream_resumes": self._stream_resumes,
             "stream_frames": self._stream_frames,
+            "streams_in_flight": self._streams_in_flight,
+            **self._pool_gauges(),
+            "pool_wait": self.pool_wait_stats.snapshot(),
             "stream_itl": self.stream_itl_stats.snapshot(),
             "stream_emit_lag": dict(self._stream_emit_lag),
             "deadline_expired": self._deadline_expired,
@@ -2306,6 +2326,13 @@ class Coordinator:
             },
             "worker_roles": self._worker_roles(),
         }
+
+    def _pool_gauges(self) -> Dict[str, int]:
+        """Connection-pool gauges over the router's and the load balancer's
+        worker clients."""
+        pools = (self.router.pool_stats(), self.lb.pool_stats())
+        return {"pool_in_use": sum(p["in_use"] for p in pools),
+                "pool_waiting": sum(p["waiting"] for p in pools)}
 
     def _worker_roles(self) -> Dict[str, str]:
         """Fleet role per registered worker for the scrape: pool membership

@@ -122,6 +122,13 @@ class Router:
             await client.close()
         self._clients.clear()
 
+    def pool_stats(self) -> Dict[str, int]:
+        """Connection-pool gauges summed over this router's worker clients:
+        calls holding a connection, callers waiting for one."""
+        pools = [c.pool_stats() for c in self._clients.values()]
+        return {"in_use": sum(p["in_use"] for p in pools),
+                "waiting": sum(p["waiting"] for p in pools)}
+
     # -- membership (reference src/router.py:109-138) -----------------------
 
     def register_worker(self, worker_id: str, host: str, port: int,

@@ -47,6 +47,7 @@ from ..utils.rpc import (
 )
 from ..obs import collectors as obs_collectors
 from ..obs.events import EventLog
+from ..obs.timeline import clock_anchor
 from ..obs.registry import OPENMETRICS_CONTENT_TYPE, MetricsRegistry
 from ..utils.tracing import LatencyStats
 
@@ -318,6 +319,7 @@ class WorkerServer(FramedServerMixin):
             "generate_stream": self._rpc_generate_stream,
         }
         self._profiling_dir: Optional[str] = None
+        self._profile_lock = asyncio.Lock()     # one start/stop at a time
         # prefill-pool side: persistent clients to decode-pool peers,
         # keyed by (host, port) — the KV handoff goes peer-to-peer over
         # DCN, not back through the coordinator
@@ -566,9 +568,11 @@ class WorkerServer(FramedServerMixin):
         # generate/load_model legitimately run for minutes (first-call XLA
         # compile, checkpoint load) — their deadline belongs to the caller.
         # The server-side timeout only guards the cheap control methods.
-        # drain carries its own timeout_s in the message.
+        # drain carries its own timeout_s in the message; profile's stop
+        # writes a trace out (tens of seconds under load).
         if method in ("generate", "load_model", "swap_model", "prefill",
-                      "generate_prefilled", "prefill_generate", "drain"):
+                      "generate_prefilled", "prefill_generate", "drain",
+                      "profile"):
             return await handler(msg)
         return await asyncio.wait_for(
             handler(msg), timeout=self.config.request_timeout
@@ -627,24 +631,30 @@ class WorkerServer(FramedServerMixin):
                 f"worker {self.worker_id} is draining — retry on another "
                 "replica")
 
-    def _attach_worker_trace(self, result: GenerationResult,
-                             t_recv: float) -> None:
+    def _attach_worker_trace(self, result: GenerationResult, t_recv: float,
+                             t_first_frame_sent: Optional[float] = None
+                             ) -> None:
         """Worker-side phase marks, riding the result's metadata back to
         the coordinator (cross-process tracing: ISSUE 4 leg 3). Offsets
-        are seconds RELATIVE TO THIS WORKER'S RECEIVE TIME — the two
-        processes share no clock, so the coordinator anchors them at its
-        own ``dispatched`` mark (``RequestTrace.add_offsets``).
-        ``first_token`` is the engine-measured TTFT (admission-relative,
-        ≈ receive-relative; exact for pumped continuous engines, which
-        stamp it from submit)."""
+        are seconds RELATIVE TO THIS WORKER'S RECEIVE TIME, all from this
+        process's ``perf_counter`` — the two processes share no clock, so
+        the coordinator anchors them at the moment it had a connection
+        (``RequestTrace.add_offsets``). A continuous engine hands its own
+        stamps up on the result: ``submitted`` (pump thread, at
+        ``engine.submit()``), ``admitted`` (slot held, prefill dispatched)
+        and ``first_token`` (first token on the host). An engine that
+        stamps nothing (static, fake) reports ``first_token`` as its
+        ``ttft_s``, which it counts from the dispatch it ran at receive."""
+        offsets = {"received": 0.0}
+        for phase in ("submitted", "admitted", "first_token"):
+            if phase in result.stamps:
+                offsets[phase] = result.stamps[phase] - t_recv
+        offsets.setdefault("first_token", float(result.ttft_s))
+        if t_first_frame_sent is not None:
+            offsets["first_frame_sent"] = t_first_frame_sent - t_recv
+        offsets["done"] = time.perf_counter() - t_recv
         result.metadata.setdefault("worker_trace", {
-            "worker_id": self.worker_id,
-            "offsets": {
-                "received": 0.0,
-                "first_token": float(result.ttft_s),
-                "done": time.perf_counter() - t_recv,
-            },
-        })
+            "worker_id": self.worker_id, "offsets": offsets})
 
     async def _rpc_generate(self, msg: Dict[str, Any]) -> Dict[str, Any]:
         t_recv = time.perf_counter()
@@ -688,6 +698,7 @@ class WorkerServer(FramedServerMixin):
         result envelope. Continuous engines only (the rolling batch emits
         per-chunk; a static engine runs to completion in one call — use
         ``generate`` there)."""
+        t_recv = time.perf_counter()
         self._admit()
         name, _engine = self._engine_for(msg, "generate")
         pump = self._pumps.get(name)
@@ -696,17 +707,24 @@ class WorkerServer(FramedServerMixin):
                 f"model {name!r} is not a continuous engine — streaming "
                 "needs metadata.continuous=1")
         req = request_from_dict(msg.get("request") or {})
-        t_recv = time.perf_counter()
         self._request_count += 1
         self._busy += 1
+        sent_at: List[float] = []       # when the first frame's send returned
+
+        async def send_stamped(obj: Dict[str, Any]) -> None:
+            await send(obj)
+            if not sent_at:
+                sent_at.append(time.perf_counter())
+
         try:
             queue: asyncio.Queue = asyncio.Queue()
             fut = asyncio.ensure_future(
                 pump.generate_streaming(req, queue.put_nowait))
-            result = await relay_stream(fut, queue, send)
+            result = await relay_stream(fut, queue, send_stamped)
         finally:
             self._busy -= 1
-        self._attach_worker_trace(result, t_recv)
+        self._attach_worker_trace(result, t_recv,
+                                  sent_at[0] if sent_at else None)
         return {"model": name, "result": result_to_dict(result)}
 
     # -- profiling (SURVEY.md §5 tracing plan: XLA/TPU timeline capture) ----
@@ -715,48 +733,77 @@ class WorkerServer(FramedServerMixin):
         """Start/stop a ``jax.profiler`` trace on this worker. The trace
         directory is loadable in TensorBoard/XProf for XLA timelines —
         the real-engine upgrade of the reference's wall-clock-only
-        "tracing" (``src/worker.py:126-133``)."""
+        "tracing" (``src/worker.py:126-133``).
+
+        ``start`` takes ``python_tracer`` (default on, as ever): off, the
+        host side of the trace is the program's own spans
+        (``obs.timeline.host_span``) and the host runs at its own pace.
+        ``start`` and ``stop`` each write one ``clock.anchor`` event
+        carrying this process's ``perf_counter_ns`` into the trace, and
+        the step-timeline dumps carry the same anchors: ring records and
+        ``worker_trace`` offsets map onto the trace's clock through them.
+        Writing the trace out takes over a minute on the chip; it runs off
+        the event loop, so the worker's streams keep flowing."""
+        action = msg.get("action")
+        if action not in ("start", "stop"):
+            raise ValueError(f"unknown profile action {action!r} "
+                             "(use 'start' or 'stop')")
+        async with self._profile_lock:
+            if action == "start":
+                return self._profile_start(msg)
+            return await self._profile_stop()
+
+    def _profile_start(self, msg: Dict[str, Any]) -> Dict[str, Any]:
         import jax
 
-        action = msg.get("action")
-        if action == "start":
-            if self._profiling_dir is not None:
-                raise ValueError(
-                    f"profiling already active -> {self._profiling_dir}")
-            trace_dir = msg.get("trace_dir") or f"/tmp/{self.worker_id}-trace"
-            jax.profiler.start_trace(trace_dir)
-            self._profiling_dir = trace_dir
-            # bracket the engine step timelines to the same window: the
-            # jax trace shows the XLA/device side, the step timeline the
-            # engine's dispatch-level view of the SAME interval
-            for engine in self.engines.values():
-                tl = getattr(engine, "timeline", None)
-                if tl is not None:
-                    tl.start_capture()
-            return {"profiling": True, "trace_dir": trace_dir}
-        if action == "stop":
-            if self._profiling_dir is None:
-                raise ValueError("profiling is not active")
-            jax.profiler.stop_trace()
-            out, self._profiling_dir = self._profiling_dir, None
-            written: List[str] = []
-            for name, engine in self.engines.items():
-                tl = getattr(engine, "timeline", None)
-                if tl is None:
-                    continue
-                try:
-                    import os
+        if self._profiling_dir is not None:
+            raise ValueError(
+                f"profiling already active -> {self._profiling_dir}")
+        trace_dir = msg.get("trace_dir") or f"/tmp/{self.worker_id}-trace"
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = int(bool(msg.get("python_tracer", True)))
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        self._profiling_dir = trace_dir
+        anchor = clock_anchor("start")
+        # bracket the engine step timelines to the same window: the
+        # jax trace shows the XLA/device side, the step timeline the
+        # engine thread's view of the SAME interval
+        for engine in self.engines.values():
+            tl = getattr(engine, "timeline", None)
+            if tl is not None:
+                tl.start_capture()
+                tl.add_anchor(anchor)
+        return {"profiling": True, "trace_dir": trace_dir,
+                "python_tracer": bool(options.python_tracer_level)}
 
-                    os.makedirs(out, exist_ok=True)
-                    path = os.path.join(out, f"step_timeline_{name}.json")
-                    written.append(tl.dump(path, tl.stop_capture()))
-                except Exception as e:  # timeline dump must not fail stop
-                    logger.warning("worker %s: step-timeline dump for %s "
-                                   "failed: %s", self.worker_id, name, e)
-            return {"profiling": False, "trace_dir": out,
-                    "step_timelines": written}
-        raise ValueError(f"unknown profile action {action!r} "
-                         "(use 'start' or 'stop')")
+    async def _profile_stop(self) -> Dict[str, Any]:
+        import jax
+
+        if self._profiling_dir is None:
+            raise ValueError("profiling is not active")
+        anchor = clock_anchor("stop")
+        t0 = time.perf_counter()
+        await asyncio.get_running_loop().run_in_executor(
+            None, jax.profiler.stop_trace)
+        stop_s = time.perf_counter() - t0
+        out, self._profiling_dir = self._profiling_dir, None
+        written: List[str] = []
+        for name, engine in self.engines.items():
+            tl = getattr(engine, "timeline", None)
+            if tl is None:
+                continue
+            tl.add_anchor(anchor)
+            try:
+                os.makedirs(out, exist_ok=True)
+                path = os.path.join(out, f"step_timeline_{name}.json")
+                written.append(tl.dump(path, tl.stop_capture()))
+            except Exception as e:  # timeline dump must not fail stop
+                logger.warning("worker %s: step-timeline dump for %s "
+                               "failed: %s", self.worker_id, name, e)
+        logger.info("worker %s: profile written to %s in %.1fs",
+                    self.worker_id, out, stop_s)
+        return {"profiling": False, "trace_dir": out, "stop_s": stop_s,
+                "step_timelines": written}
 
     # -- disaggregated prefill/decode (engine/disagg.py; SURVEY.md §2.3) ----
 
@@ -1292,7 +1339,8 @@ class WorkerServer(FramedServerMixin):
         of the devices its params live on plus its int4 kernel paths.
         ``None`` until a real (jax) engine is resident — a fake-engine
         worker never touches a backend. ``memory`` adds each used device's
-        live ``memory_stats()`` (``None`` where the backend has none)."""
+        live ``memory_stats()`` (``None`` where the backend has none) and
+        the process's compile counters (``utils.compile_cache``)."""
         models = {name: p for name, p in self._placements.items()
                   if p and name in self.engines}
         if not models:
@@ -1314,6 +1362,11 @@ class WorkerServer(FramedServerMixin):
             used = {i for p in models.values() for i in p["device_ids"]}
             report["memory"] = {
                 str(d.id): d.memory_stats() for d in devices if d.id in used}
+            # the compiler's own count since process start (the metrics
+            # RPC's view; ping stays light)
+            from ..utils.compile_cache import compile_counters
+
+            report["compile"] = compile_counters()
         return report
 
     def get_metrics(self) -> Dict[str, Any]:
@@ -1410,15 +1463,17 @@ class WorkerClient(FramedRPCClient):
     async def generate_stream(
         self, model: str, request: GenerationRequest, on_tokens,
         timeout: Optional[float] = None,
+        on_acquired: Optional[Callable[[float], None]] = None,
     ) -> GenerationResult:
         """Stream one request: ``on_tokens(tokens)`` fires per decoded
         chunk; returns the final (authoritative) result. ``timeout``
-        bounds the gap between frames, not the whole generation."""
+        bounds the gap between frames, not the whole generation;
+        ``on_acquired`` is ``call_stream``'s."""
         result = await self.call_stream(
             "generate_stream",
             lambda frame: on_tokens(list(frame.get("tokens", []))),
             model=model, request=request_to_dict(request),
-            timeout=timeout,
+            timeout=timeout, on_acquired=on_acquired,
         )
         return result_from_dict(result["result"])
 

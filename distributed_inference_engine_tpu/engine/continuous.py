@@ -58,7 +58,7 @@ from ..ops.sampling import (
     sample_tokens,
     sample_tokens_with_logprobs,
 )
-from ..obs.timeline import StepTimeline
+from ..obs.timeline import HostSpan, StepTimeline, host_span
 from ..utils.hotpath import hot_path
 from ..utils.tracing import LatencyStats
 from .engine import _next_bucket, _pow2_buckets
@@ -83,18 +83,24 @@ class _Slot:
     """Host-side bookkeeping for one live sequence."""
 
     __slots__ = ("request", "slot_id", "prompt_len", "produced", "tokens",
-                 "logprobs", "admitted_at", "first_token_at", "on_tokens",
-                 "streamed", "stop_cut", "first_pending")
+                 "logprobs", "submitted_at", "admitted_at", "first_token_at",
+                 "on_tokens", "streamed", "stop_cut", "first_pending")
 
     def __init__(self, request: GenerationRequest, slot_id: int,
-                 prompt_len: int, on_tokens=None) -> None:
+                 prompt_len: int, t_submit: float, t_admit: float,
+                 on_tokens=None) -> None:
         self.request = request
         self.slot_id = slot_id
         self.prompt_len = prompt_len
         self.produced = 0
         self.tokens: List[int] = []
         self.logprobs: List[float] = []
-        self.admitted_at = time.perf_counter()
+        # perf_counter stamps, handed up on the result (``stamps``): the
+        # TTFT clock starts at SUBMIT (queue wait while slots/pages were
+        # busy is exactly the latency a loaded engine must report);
+        # admitted = slot held and prefill dispatched
+        self.submitted_at = t_submit
+        self.admitted_at = t_admit
         self.first_token_at = 0.0
         self.on_tokens = on_tokens      # streaming: cb(new_tokens: List[int])
         self.streamed = 0               # tokens already emitted to the cb
@@ -109,15 +115,17 @@ class _PrefillProgress:
     """A long prompt mid-way through chunked prefill: its slot and pages are
     allocated, but it is not yet decoding (not in ``_slots``)."""
 
-    __slots__ = ("request", "prompt", "done", "on_tokens", "t_submit")
+    __slots__ = ("request", "prompt", "done", "on_tokens", "t_submit",
+                 "t_admit")
 
     def __init__(self, request: GenerationRequest, prompt: List[int],
-                 on_tokens, t_submit: float) -> None:
+                 on_tokens, t_submit: float, t_admit: float) -> None:
         self.request = request
         self.prompt = prompt
         self.done = 0                   # tokens already prefilled (page-aligned)
         self.on_tokens = on_tokens
         self.t_submit = t_submit
+        self.t_admit = t_admit          # slot held, first chunk dispatched
 
 
 class _ChunkEntry:
@@ -587,11 +595,13 @@ class ContinuousEngine:
                 # full context bucket would read s_ctx + n_steps wide when
                 # s_ctx already covers every reachable position
                 s_buf = min(s_ctx + n_steps, max(self.max_seq_len, s_ctx))
-                ctx_k = kp[:, pt].reshape(L, b, s_ctx, Hkv, Dh)
-                ctx_v = vp[:, pt].reshape(L, b, s_ctx, Hkv, Dh)
-                zpad = jnp.zeros((L, b, s_buf - s_ctx, Hkv, Dh), ctx_k.dtype)
-                ctx_k = jnp.concatenate([ctx_k, zpad], axis=2)
-                ctx_v = jnp.concatenate([ctx_v, zpad], axis=2)
+                with jax.named_scope("attn.kv_gather"):
+                    ctx_k = kp[:, pt].reshape(L, b, s_ctx, Hkv, Dh)
+                    ctx_v = vp[:, pt].reshape(L, b, s_ctx, Hkv, Dh)
+                    zpad = jnp.zeros((L, b, s_buf - s_ctx, Hkv, Dh),
+                                     ctx_k.dtype)
+                    ctx_k = jnp.concatenate([ctx_k, zpad], axis=2)
+                    ctx_v = jnp.concatenate([ctx_v, zpad], axis=2)
 
                 def step(carry, step_key):
                     ctx_k, ctx_v, lengths, last, active, produced = carry
@@ -623,10 +633,12 @@ class ContinuousEngine:
                 # the count mask drops everything past it
                 bi = jnp.arange(b)[:, None]
                 idx = start_lengths[:, None] + jnp.arange(n_steps)[None, :]
-                kp, vp = write_prefill_pages(
-                    kp, vp, ctx_k[:, bi, idx], ctx_v[:, bi, idx],
-                    page_table, lengths - start_lengths, start=start_lengths,
-                )
+                with jax.named_scope("attn.kv_update"):
+                    kp, vp = write_prefill_pages(
+                        kp, vp, ctx_k[:, bi, idx], ctx_v[:, bi, idx],
+                        page_table, lengths - start_lengths,
+                        start=start_lengths,
+                    )
             else:
                 def step(carry, step_key):
                     kp, vp, side_k, side_v, lengths, last, active, produced \
@@ -939,13 +951,14 @@ class ContinuousEngine:
         self._mixed_programs: set = set()
         self._occupancy_sum = 0     # Σ live slots per step (occupancy)
         self.ttft_stats = LatencyStats()   # per-request, from submit
-        # step timeline (obs/timeline.py): one record per device dispatch,
-        # exported as a Perfetto-loadable Chrome trace. Program-shape keys
-        # seen so far let records flag first-dispatch (compile) steps.
+        self.queue_wait_stats = LatencyStats()   # submit -> admitted
+        # step timeline (obs/timeline.py): one record per host span of the
+        # engine thread (device dispatches among them), exported as a
+        # Perfetto-loadable Chrome trace. A dispatch record is flagged
+        # ``compile`` when the compiler's own counter grew across it.
         cap = int(getattr(config, "timeline_capacity", 4096) or 0)
         self.timeline: Optional[StepTimeline] = (
             StepTimeline(capacity=cap, name="continuous") if cap else None)
-        self._tl_programs: set = set()
         # host-gap split (ISSUE 5 satellite): dispatch-bracket seconds vs
         # the host-side gap BETWEEN consecutive dispatch brackets, so an
         # hbm_util regression is attributable at a glance — kernel-side
@@ -1129,6 +1142,7 @@ class ContinuousEngine:
                         ttft_s=now - t,
                         decode_s=0.0,
                         metadata={"overload_reason": "deadline"},
+                        stamps={"submitted": t},
                     ))
                 elif req.deadline_s is not None and now - t >= req.deadline_s:
                     self._deadline_expired += 1
@@ -1140,6 +1154,7 @@ class ContinuousEngine:
                         ttft_s=now - t,
                         decode_s=0.0,
                         metadata={"deadline_s": req.deadline_s},
+                        stamps={"submitted": t},
                     ))
                 else:
                     keep.append(item)
@@ -1196,7 +1211,11 @@ class ContinuousEngine:
                     break
             self._waiting_prefilled.popleft()
             admitted += 1
-            t0 = time.perf_counter()
+            admit = self._span("engine.admit", rows=1,
+                               prompt_tokens=prompt_len,
+                               request_ids=req.request_id)
+            sp = self._dispatch_span("engine.prefill.dispatch", rows=1,
+                                     prefill_tokens=prompt_len)
             # write only [n_cached, prompt_len) — the cached head pages are
             # shared; pad the tail to a prefill bucket so the scatter
             # reuses the same compiled shapes as local admission
@@ -1223,25 +1242,26 @@ class ContinuousEngine:
                     self._prefix_hit_admissions += 1
             self._total_prompt_tokens += prompt_len
             self._install_slot(req, slot, prompt_len, handoff.first_token,
-                               t0, on_tok, t_submit=t_submit,
+                               sp, on_tok, t_submit=t_submit,
+                               t_admit=time.perf_counter(),
                                first_lp=getattr(handoff, "first_logprob",
                                                 0.0))
+            admit.close()
         return admitted
 
     def _register_slot_host(self, req: GenerationRequest, slot: int,
                             prompt_len: int, first: int, t_submit: float,
-                            on_tokens=None, first_lp: float = 0.0) -> bool:
+                            t_admit: float, on_tokens=None,
+                            first_lp: float = 0.0) -> bool:
         """Host bookkeeping of one admission; returns True when the slot
         stays live (i.e. needs its device state installed)."""
-        state = _Slot(req, slot, prompt_len, on_tokens)
+        state = _Slot(req, slot, prompt_len, t_submit, t_admit, on_tokens)
         state.tokens.append(first)
         state.logprobs.append(first_lp)
         state.produced = 1
-        # the TTFT clock starts at SUBMIT: queue wait while slots/pages
-        # were busy is exactly the latency a loaded engine must report
-        state.admitted_at = t_submit
         state.first_token_at = time.perf_counter()
         self.ttft_stats.add(state.first_token_at - t_submit)
+        self.queue_wait_stats.add(t_admit - t_submit)
         self._slots[slot] = state
         # prefill_stats is recorded once per DISPATCH by the caller
         # (batched admission would otherwise count one wall time N times)
@@ -1325,18 +1345,19 @@ class ContinuousEngine:
                 "stops": list(req.stop_ids or ())[:_DEVICE_STOP_K]}
 
     def _install_slot(self, req: GenerationRequest, slot: int,
-                      prompt_len: int, first: int, t_dispatch: float,
-                      on_tokens, t_submit: float,
+                      prompt_len: int, first: int, dispatch: HostSpan,
+                      on_tokens, t_submit: float, t_admit: float,
                       first_lp: float = 0.0) -> None:
         """Single-admission tail (suffix / disaggregated paths); batched
-        admissions go through ``_admit_batch``. ``t_dispatch`` feeds the
-        prefill-latency histogram; ``t_submit`` starts the request's
-        TTFT clock (queue wait included)."""
-        self.prefill_stats.add(time.perf_counter() - t_dispatch)
-        self._tl_record("prefill", t_dispatch, rows=1,
-                        prefill_tokens=prompt_len)
+        admissions go through ``_admit_batch``. ``dispatch`` is the open
+        prefill bracket (it feeds the prefill-latency histogram);
+        ``t_submit`` starts the request's TTFT clock (queue wait
+        included); ``t_admit`` is when its prefill was dispatched."""
+        self.prefill_stats.add(time.perf_counter() - dispatch.t0)
+        self._tl_record(dispatch)
         if self._register_slot_host(req, slot, prompt_len, first,
-                                    t_submit, on_tokens, first_lp=first_lp):
+                                    t_submit, t_admit, on_tokens,
+                                    first_lp=first_lp):
             self._install_device(
                 [self._slot_row(req, slot, prompt_len, first)])
 
@@ -1437,7 +1458,7 @@ class ContinuousEngine:
                 if n_cached > 0:
                     self._prefix_hit_admissions += 1
                     self._start_chunked(req, on_tok, slot, prompt, t_submit,
-                                        done=n_cached)
+                                        time.perf_counter(), done=n_cached)
                 else:
                     # first chunk joins the batched admission prefill; the
                     # chunk advance takes over from there (done > 0 always)
@@ -1448,19 +1469,25 @@ class ContinuousEngine:
                         batch = []
                         pending_hashes.clear()
             elif n_cached > 0:
-                t0 = time.perf_counter()
+                admit = self._span("engine.admit", rows=1,
+                                   prompt_tokens=len(prompt),
+                                   request_ids=req.request_id)
+                sp = self._dispatch_span("engine.prefill.dispatch", rows=1,
+                                         prefill_tokens=len(prompt) - n_cached)
                 self._rng, k0 = jax.random.split(self._rng)
                 first_dev = self._prefill_cached_suffix(
                     prompt, slot, n_cached, req, k0)
+                t_admit = time.perf_counter()
                 self.kv.register_prefix(slot, prompt)
                 # graftlint: ok[host-sync-hot-path] sync cached-suffix admission needs its first token now; [2,1] elements, once per admission
                 fp = np.asarray(first_dev)           # [2, 1]: token; lp bits
                 first = int(fp[0, 0])
                 first_lp = float(fp[1].view(np.float32)[0])
                 self._total_prompt_tokens += len(prompt)
-                self._install_slot(req, slot, len(prompt), first, t0,
+                self._install_slot(req, slot, len(prompt), first, sp,
                                    on_tok, t_submit=t_submit,
-                                   first_lp=first_lp)
+                                   t_admit=t_admit, first_lp=first_lp)
+                admit.close()
             else:
                 batch.append((req, on_tok, slot, prompt, t_submit, None))
                 if len(batch) >= self._admit_row_cap():
@@ -1479,12 +1506,21 @@ class ContinuousEngine:
         rows carry seq_len 0, so neither the page write nor the install
         touches anything (their page-table row points at page 0 but the
         valid mask drops every position)."""
-        t0 = time.perf_counter()
-        self._prefill_calls += 1
         n = len(batch)
         bb = 1 << (n - 1).bit_length()                     # pow2 bucket
         tb = _next_bucket(max(len(p) for _, _, _, p, _, _ in batch),
                           self.prefill_buckets)
+        with self._span("engine.admit", rows=n,
+                        prompt_tokens=sum(len(b[3]) for b in batch),
+                        request_ids=";".join(b[0].request_id
+                                             for b in batch)):
+            self._admit_rows(batch, n, bb, tb)
+
+    def _admit_rows(self, batch, n: int, bb: int, tb: int) -> None:
+        sp = self._dispatch_span("engine.prefill.dispatch", rows=n,
+                                 prefill_tokens=sum(len(b[3])
+                                                    for b in batch))
+        self._prefill_calls += 1
         tokens = np.zeros((bb, tb), np.int32)
         seq_lens = np.zeros((bb,), np.int32)
         temps = np.zeros((bb,), np.float32)
@@ -1523,6 +1559,7 @@ class ContinuousEngine:
                 jnp.asarray(table_rows), seq_dev,
             )
         self.kv.swap(kp, vp)
+        t_admit = time.perf_counter()    # slots held, prefill dispatched
         # deferred admission: under decode pressure (≥1/4 of slots live),
         # skip the blocking first-token read — install the firsts device-
         # side and let the host harvest them from the NEXT chunk's packed
@@ -1534,10 +1571,8 @@ class ContinuousEngine:
                  and len(self._slots) * 4 >= self.max_slots
                  and all(r.max_new_tokens > 1 for r, *_ in batch))
         if defer:
-            self.prefill_stats.add(time.perf_counter() - t0)  # dispatch only
-            self._tl_record("prefill", t0, program=("prefill", bb, tb),
-                            rows=n, prefill_tokens=int(seq_lens.sum()),
-                            deferred=True)
+            self.prefill_stats.add(time.perf_counter() - sp.t0)  # dispatch only
+            self._tl_record(sp, program=("prefill", bb, tb), deferred=True)
             rows: List[Dict[str, Any]] = []
             cols: List[int] = []
             for i, (req, cb, slot, prompt, t_submit, full) in enumerate(batch):
@@ -1546,14 +1581,14 @@ class ContinuousEngine:
                     # either way (their sample is discarded) — they are
                     # not deferred admissions
                     self._start_chunked(req, cb, slot, full, t_submit,
-                                        done=len(prompt))
+                                        t_admit, done=len(prompt))
                     continue
                 if self.prefix_cache:
                     self.kv.register_prefix(slot, prompt)
                 self._total_prompt_tokens += len(prompt)
-                state = _Slot(req, slot, len(prompt), cb)
+                state = _Slot(req, slot, len(prompt), t_submit, t_admit, cb)
                 state.first_pending = True
-                state.admitted_at = t_submit
+                self.queue_wait_stats.add(t_admit - t_submit)
                 self._slots[slot] = state
                 rows.append(self._slot_row(req, slot, len(prompt), 0))
                 cols.append(i)
@@ -1564,9 +1599,8 @@ class ContinuousEngine:
         fp = np.asarray(first_dev)                 # [2, bb]: tokens; lp bits
         firsts = fp[0]
         first_lps = fp[1].view(np.float32)
-        self.prefill_stats.add(time.perf_counter() - t0)   # once per dispatch
-        self._tl_record("prefill", t0, program=("prefill", bb, tb),
-                        rows=n, prefill_tokens=int(seq_lens.sum()))
+        self.prefill_stats.add(time.perf_counter() - sp.t0)  # once per dispatch
+        self._tl_record(sp, program=("prefill", bb, tb))
         rows = []
         for i, (req, cb, slot, prompt, t_submit, full) in enumerate(batch):
             if full is not None:
@@ -1576,14 +1610,14 @@ class ContinuousEngine:
                 # over. Prompt tokens/prefix registration are counted on
                 # the LAST chunk.
                 self._start_chunked(req, cb, slot, full, t_submit,
-                                    done=len(prompt))
+                                    t_admit, done=len(prompt))
                 continue
             if self.prefix_cache:
                 self.kv.register_prefix(slot, prompt)
             self._total_prompt_tokens += len(prompt)
             first = int(firsts[i])
             if self._register_slot_host(req, slot, len(prompt), first,
-                                        t_submit, cb,
+                                        t_submit, t_admit, cb,
                                         first_lp=float(first_lps[i])):
                 rows.append(self._slot_row(req, slot, len(prompt), first))
         self._install_device(rows)
@@ -1654,13 +1688,13 @@ class ContinuousEngine:
     # ----------------------------------------------------- chunked prefill
 
     def _start_chunked(self, req: GenerationRequest, on_tokens, slot: int,
-                       prompt: List[int], t_submit: float,
+                       prompt: List[int], t_submit: float, t_admit: float,
                        done: int = 0) -> None:
         """Begin incremental prefill of a long prompt: the slot and its
         pages are reserved now; chunks run one per engine step. ``done``
         > 0 resumes after a prefix-cache hit (page-aligned)."""
         self._chunked_admissions += 1
-        prog = _PrefillProgress(req, prompt, on_tokens, t_submit)
+        prog = _PrefillProgress(req, prompt, on_tokens, t_submit, t_admit)
         prog.done = done
         self._prefilling[slot] = prog
 
@@ -1700,18 +1734,19 @@ class ContinuousEngine:
     def _advance_group(self, items) -> None:
         """One batched suffix dispatch advancing ``items`` (same ctx-page
         bucket) by one chunk each; finishing rows become live slots."""
-        t0 = time.perf_counter()
         suffixes = [prog.prompt[prog.done: prog.done + self._chunk]
                     for _, prog in items]
+        sp = self._dispatch_span("engine.prefill.dispatch", rows=len(items),
+                                 prefill_tokens=sum(len(s) for s in suffixes),
+                                 chunk=True)
         self._rng, k0 = jax.random.split(self._rng)
         first_dev = self._run_suffix_prefill(
             suffixes, [slot for slot, _ in items],
             [prog.done for _, prog in items],
             [prog.request for _, prog in items], k0)
         self._prefill_calls += 1
-        self.prefill_stats.add(time.perf_counter() - t0)
-        self._tl_record("prefill_chunk", t0, rows=len(items),
-                        prefill_tokens=sum(len(s) for s in suffixes))
+        self.prefill_stats.add(time.perf_counter() - sp.t0)
+        self._tl_record(sp)
         fp = None                         # read back only if someone finished
         rows: List[Dict[str, Any]] = []
         for i, (slot, prog) in enumerate(items):
@@ -1732,7 +1767,8 @@ class ContinuousEngine:
             first_lp = float(fp[1].view(np.float32)[i])
             if self._register_slot_host(prog.request, slot,
                                         len(prog.prompt), first,
-                                        prog.t_submit, prog.on_tokens,
+                                        prog.t_submit, prog.t_admit,
+                                        prog.on_tokens,
                                         first_lp=first_lp):
                 rows.append(self._slot_row(prog.request, slot,
                                            len(prog.prompt), first))
@@ -1756,7 +1792,10 @@ class ContinuousEngine:
         per dispatch there is no chunk-deep pipeline for ``defer_sync``
         to overlap, so a pending deferred chunk from a preceding
         pure-decode step is flushed first."""
-        t0 = time.perf_counter()
+        sp = self._dispatch_span("engine.mixed.dispatch",
+                                 live_slots=len(self._slots),
+                                 prefilling=len(self._prefilling))
+        t0 = sp.t0
         if self._pending is not None:
             # selection + capacity below need CURRENT host state
             prev, self._pending = self._pending, None
@@ -1878,12 +1917,13 @@ class ContinuousEngine:
             first_lp = float(fp[1].view(np.float32)[i])
             if self._register_slot_host(prog.request, slot,
                                         len(prog.prompt), first,
-                                        prog.t_submit, prog.on_tokens,
+                                        prog.t_submit, prog.t_admit,
+                                        prog.on_tokens,
                                         first_lp=first_lp):
                 rows.append(self._slot_row(prog.request, slot,
                                            len(prog.prompt), first))
         self._install_device(rows)
-        self._tl_record("mixed", t0, program=("mixed", rpb, qb),
+        self._tl_record(sp, program=("mixed", rpb, qb),
                         prefill_rows=len(sel), prefill_tokens=spent)
 
     # ---------------------------------------------------------- streaming
@@ -1946,7 +1986,7 @@ class ContinuousEngine:
         state.logprobs.insert(
             0, float(fp[1:2, slot].copy().view(np.float32)[0]))
         state.first_token_at = time.perf_counter()
-        self.ttft_stats.add(state.first_token_at - state.admitted_at)
+        self.ttft_stats.add(state.first_token_at - state.submitted_at)
 
     def _finish(self, slot: int, reason: str) -> None:
         state = self._slots.pop(slot)
@@ -1968,8 +2008,11 @@ class ContinuousEngine:
             finish_reason=reason,
             prompt_tokens=state.prompt_len,
             logprobs=state.logprobs[: len(toks)],
-            ttft_s=state.first_token_at - state.admitted_at,
+            ttft_s=state.first_token_at - state.submitted_at,
             decode_s=time.perf_counter() - state.first_token_at,
+            stamps={"submitted": state.submitted_at,
+                    "admitted": state.admitted_at,
+                    "first_token": state.first_token_at},
         ))
 
     # ------------------------------------------------- swap-based preempt
@@ -2076,8 +2119,11 @@ class ContinuousEngine:
             finish_reason=reason,
             prompt_tokens=state.prompt_len,
             logprobs=state.logprobs[: len(toks)],
-            ttft_s=state.first_token_at - state.admitted_at,
+            ttft_s=state.first_token_at - state.submitted_at,
             decode_s=time.perf_counter() - state.first_token_at,
+            stamps={"submitted": state.submitted_at,
+                    "admitted": state.admitted_at,
+                    "first_token": state.first_token_at},
         ))
 
     def prefetch_probe(self, request: GenerationRequest) -> int:
@@ -2138,38 +2184,28 @@ class ContinuousEngine:
         except Exception:
             logger.exception("overlap hook failed")
 
-    def _tl_record(self, kind: str, t0: float, program: Any = None,
-                   **args: Any) -> None:
-        """Append one step-timeline record (no-op when disabled).
+    def _span(self, name: str, **args: Any) -> HostSpan:
+        """Open a host span of the engine thread (``obs.timeline``)."""
+        return host_span(self.timeline, name, **args)
 
-        ``program`` is a hashable program-shape key; its first appearance
-        flags the record ``compile=True`` — on a real backend that step
-        paid an XLA compile (or compile-cache load). Occupancy args are
-        read from cheap host mirrors so the hot path stays unmetered
-        between scrapes."""
-        now = time.perf_counter()
-        # dispatch/gap accounting runs even with the ring disabled: the
-        # roofline split (bench.py) and the engine_host_* metric families
-        # depend on it, and it is two float adds per dispatch
-        self._dispatch_s += now - t0
-        if self._last_dispatch_end is not None:
-            gap = t0 - self._last_dispatch_end
-            if gap > 0:
-                self._host_gap_s += gap
-        self._last_dispatch_end = now
-        tl = self.timeline
-        if tl is None:
-            return
-        if program is not None and program not in self._tl_programs:
-            self._tl_programs.add(program)
-            args["compile"] = True
+    def _dispatch_span(self, name: str, **args: Any) -> HostSpan:
+        """Open a device-dispatch bracket; ``_tl_record`` closes it."""
+        return host_span(self.timeline, name, dispatch=True, **args)
+
+    def _tl_record(self, span: HostSpan, **args: Any) -> None:
+        """Close a dispatch bracket: its annotation, its ring record (none
+        when the ring is disabled) and the dispatch/gap accounting.
+
+        ``program`` (optional) is the program-shape key it ran. Occupancy
+        args are read from cheap host mirrors so the hot path stays
+        unmetered between scrapes."""
         args["live_slots"] = len(self._slots)
         args["waiting"] = len(self._waiting)
         if self._prefilling:
             args["prefilling"] = len(self._prefilling)
         if self._swapped:
             args["swapped"] = len(self._swapped)
-        try:
+        if self.timeline is not None:
             kv = self.kv
             args["kv_pages_used"] = (kv.num_pages - len(kv._free)
                                      - len(kv._reclaimable))
@@ -2177,9 +2213,18 @@ class ContinuousEngine:
             if kv.offload is not None:
                 args["host_pages"] = kv.offload.get_stats().get(
                     "host_pages", 0)
-        except Exception:
-            pass
-        tl.record(kind, t0, now - t0, **args)
+        t0 = span.t0
+        now = span.close(**args)
+        # dispatch/gap accounting runs even with the ring disabled: the
+        # roofline split (bench.py), the engine_host_* metric families and
+        # the async speculator's bubble estimate depend on it, and it is
+        # two float adds per dispatch
+        self._dispatch_s += now - t0
+        if self._last_dispatch_end is not None:
+            gap = t0 - self._last_dispatch_end
+            if gap > 0:
+                self._host_gap_s += gap
+        self._last_dispatch_end = now
 
     @hot_path
     def step(self) -> int:
@@ -2194,6 +2239,13 @@ class ContinuousEngine:
         flight, the step routes to ``_step_mixed`` instead: prefill
         chunks and decode share one ragged dispatch rather than
         alternating."""
+        # one span over the whole iteration: the admission scan and the
+        # capacity loop run before any bracket below opens
+        with self._span("engine.step"):
+            return self._step()
+
+    @hot_path
+    def _step(self) -> int:
         self._try_admit()
         if self._mixed and self._prefilling:
             self._step_mixed()
@@ -2295,7 +2347,9 @@ class ContinuousEngine:
                 return (len(self._slots) + len(self._prefilling)
                         + len(self._swapped))
 
-        t0 = time.perf_counter()
+        sp = self._dispatch_span("engine.decode.dispatch", steps=n_steps,
+                                 live_slots=len(self._slots))
+        t0 = sp.t0
         cap_list = [min(self.kv.slot_capacity(s), self.max_seq_len)
                     if s in self._slots else 0
                     for s in range(self.max_slots)]
@@ -2360,7 +2414,7 @@ class ContinuousEngine:
         else:
             self._process_packed(_ChunkEntry(packed, n_steps, snapshot,
                                              t0, cap_list, True))
-        self._tl_record("decode", t0, program=("decode", n_steps, mpb),
+        self._tl_record(sp, program=("decode", n_steps, mpb),
                         rows=len(snapshot), n_steps=n_steps)
         return (len(self._slots) + len(self._prefilling)
                 + len(self._swapped))
@@ -2374,7 +2428,9 @@ class ContinuousEngine:
         trailing ``n_acc`` row rides the same blocking read, so the
         acceptance metrics cost zero extra syncs."""
         kd = self.speculator.k
-        t0 = time.perf_counter()
+        sp = self._dispatch_span("engine.verify.dispatch", steps=kd + 1,
+                                 live_slots=len(self._slots))
+        t0 = sp.t0
         cap_list = [min(self.kv.slot_capacity(s), self.max_seq_len)
                     if s in self._slots else 0
                     for s in range(self.max_slots)]
@@ -2399,7 +2455,7 @@ class ContinuousEngine:
         self._process_packed(entry)
         self._spec_verify_steps += 1
         self.speculator.note_verified(entry, verified)
-        self._tl_record("verify", t0,
+        self._tl_record(sp,
                         program=("verify", kd, bool(self._stop_slots)),
                         rows=len(snapshot), n_steps=kd + 1)
 
@@ -2417,15 +2473,16 @@ class ContinuousEngine:
             return 0
         self._ring_polls += 1
         frames = 0
-        while self._ring:
-            entry = self._ring[0]
-            if entry.harvested:
-                self._ring.popleft()
-                continue
-            if not entry.ready():
-                break
-            self._ring_ready_polls += 1
-            frames += self._harvest_chunk(entry)
+        with self._span("engine.poll_stream"):
+            while self._ring:
+                entry = self._ring[0]
+                if entry.harvested:
+                    self._ring.popleft()
+                    continue
+                if not entry.ready():
+                    break
+                self._ring_ready_polls += 1
+                frames += self._harvest_chunk(entry)
         return frames
 
     def _harvest_chunk(self, entry: _ChunkEntry) -> int:
@@ -2445,9 +2502,11 @@ class ContinuousEngine:
         except ValueError:
             pass
         n_steps = entry.n_steps
-        t_read = time.perf_counter()
+        wait = self._span("engine.harvest.wait")     # the blocking read alone
+        t_read = wait.t0
         # graftlint: ok[host-sync-hot-path] THE designed sync point: ONE packed read per decode chunk carries tokens+lps+active+lengths+firsts
         packed_np = np.asarray(entry.packed)   # ONE blocking read per chunk
+        wait.close()
         entry.host = packed_np
         toks_np = packed_np[:n_steps]                    # [n_steps, max_slots]
         lps_np = packed_np[n_steps:2 * n_steps].view(np.float32)
@@ -2467,6 +2526,7 @@ class ContinuousEngine:
                              - (t_read if self._defer else entry.t0))
 
         frames = 0
+        emit = self._span("engine.harvest.emit")  # bookkeeping + stream cbs
         for slot, state in entry.snapshot.items():
             if self._slots.get(slot) is not state:
                 continue                 # finished earlier (or slot reused)
@@ -2493,7 +2553,7 @@ class ContinuousEngine:
                 state.tokens.append(int(firsts_tok[slot]))
                 state.logprobs.append(float(firsts_lp[slot]))
                 state.first_token_at = time.perf_counter()
-                self.ttft_stats.add(state.first_token_at - state.admitted_at)
+                self.ttft_stats.add(state.first_token_at - state.submitted_at)
             for si in range(col.shape[0]):
                 if col[si] >= 0:
                     state.tokens.append(int(col[si]))
@@ -2507,6 +2567,7 @@ class ContinuousEngine:
                 # a generation, shared with the streaming emit below
                 state.stop_cut = find_stop_cut(state.tokens, req, start=prev)
             frames += self._emit_stream(state)
+        emit.close()
         return frames
 
     def _process_packed(self, entry: _ChunkEntry) -> None:
@@ -2522,7 +2583,12 @@ class ContinuousEngine:
         and the read — the packed firsts rows are then current and
         refresh the host cache for free (deferred processing runs a
         chunk behind admissions, so its rows may be stale)."""
-        self._harvest_chunk(entry)
+        with self._span("engine.process_packed"):
+            self._harvest_chunk(entry)
+            self._judge_packed(entry)
+
+    def _judge_packed(self, entry: _ChunkEntry) -> None:
+        """The judgments of ``_process_packed``, on a harvested entry."""
         # counted at dispatch; processed exactly once per entry
         self._inflight_chunks = max(0, self._inflight_chunks - 1)
         packed_np = entry.host
@@ -2647,6 +2713,9 @@ class ContinuousEngine:
             self._prefilling.pop(slot)
             self.kv.free_slot(slot)
         self._active = jnp.zeros_like(self._active)
+        if self.timeline is not None:
+            # the failed step never closed its spans
+            self.timeline._open.clear()
         return n
 
     @property
@@ -2802,6 +2871,8 @@ class ContinuousEngine:
                     "pending": 0}).items()},
             "spec_async_verify_steps": self._spec_verify_steps,
             "ttft": self.ttft_stats.snapshot(),
+            # submit -> slot held and prefill dispatched
+            "queue_wait": self.queue_wait_stats.snapshot(),
             "batch_occupancy": (self._occupancy_sum
                                 / (self._steps * self.max_slots)
                                 if self._steps else 0.0),

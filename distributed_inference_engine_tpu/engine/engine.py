@@ -44,7 +44,7 @@ from ..ops.sampling import (
     sample_tokens,
     sample_tokens_with_logprobs,
 )
-from ..obs.timeline import StepTimeline
+from ..obs.timeline import StepTimeline, host_span
 from ..utils.hotpath import hot_path
 from ..utils.tracing import LatencyStats
 from .types import (  # noqa: F401  (re-export)
@@ -243,7 +243,6 @@ class Engine:
         cap = int(getattr(config, "timeline_capacity", 4096) or 0)
         self.timeline: Optional[StepTimeline] = (
             StepTimeline(capacity=cap, name="static") if cap else None)
-        self._tl_programs: set = set()
         self._total_requests = 0
         self._total_prompt_tokens = 0
         self._total_generated_tokens = 0
@@ -306,7 +305,9 @@ class Engine:
             jnp.asarray(min_p),
         )
 
-        t0 = time.perf_counter()
+        sp = host_span(self.timeline, "engine.prefill.dispatch",
+                       dispatch=True, rows=n)
+        t0 = sp.t0
         self._rng, k0 = jax.random.split(self._rng)
         first_packed, ks, vs = self._prefill(
             self.params, jnp.asarray(tokens), jnp.asarray(seq_lens),
@@ -344,15 +345,9 @@ class Engine:
         active_np = is_real & ~hit & (produced_np < max_new_arr)
         first_np = np.where(is_real, first_np, -1)
 
-        ttft = time.perf_counter() - t0
+        ttft = sp.close(prefill_tokens=int(seq_lens[:n].sum()),
+                        program=("prefill", bb, tb)) - t0
         self.prefill_stats.add(ttft)
-        if self.timeline is not None:
-            prog = ("prefill", bb, tb)
-            first_seen = prog not in self._tl_programs
-            self._tl_programs.add(prog)
-            self.timeline.record("prefill", t0, ttft, rows=n,
-                                 prefill_tokens=int(seq_lens[:n].sum()),
-                                 **({"compile": True} if first_seen else {}))
 
         out_tokens: List[List[int]] = [[int(first_np[i])] for i in range(n)]
         out_lps: List[List[float]] = [[float(first_lp_np[i])]
@@ -364,7 +359,9 @@ class Engine:
         max_new_j = jnp.asarray(max_new_arr)
         eos_j = jnp.asarray(eos)
 
-        t1 = time.perf_counter()
+        sp = host_span(self.timeline, "engine.decode.dispatch",
+                       dispatch=True, rows=n)
+        t1 = sp.t0
         n_steps = self.config.decode_steps_per_call
         # loop condition runs on the HOST mirror of the active flags (seeded
         # from the prefill sample, updated from each chunk's packed row) —
@@ -407,15 +404,8 @@ class Engine:
             if stopped_rows and act_host.any():
                 active = active.at[
                     jnp.asarray(stopped_rows, jnp.int32)].set(False)
-        decode_t = time.perf_counter() - t1
+        decode_t = sp.close(n_steps=n_steps, program=("decode", bb, n_steps)) - t1
         self.decode_stats.add(decode_t)
-        if self.timeline is not None:
-            prog = ("decode", bb, n_steps)
-            first_seen = prog not in self._tl_programs
-            self._tl_programs.add(prog)
-            self.timeline.record("decode", t1, decode_t, rows=n,
-                                 n_steps=n_steps,
-                                 **({"compile": True} if first_seen else {}))
 
         results = []
         for i, r in enumerate(requests):

@@ -248,7 +248,7 @@ class AsyncSpeculator:
         eng = self.engine
         tl = eng.timeline
         if tl is not None:
-            ev = tl.events()
+            ev = [e for e in tl.events() if e.get("dispatch")]
             if len(ev) < 2:
                 return 0.0
             split = busy_gap_split(ev[-32:])

@@ -60,7 +60,7 @@ from ..ops.sampling import (
     masked_sampling_probs,
     sample_tokens_with_logprobs,
 )
-from ..obs.timeline import StepTimeline
+from ..obs.timeline import StepTimeline, host_span
 from ..utils.hotpath import hot_path
 from ..utils.tracing import LatencyStats
 from .engine import _next_bucket, _pow2_buckets
@@ -392,7 +392,6 @@ class SpeculativeEngine:
         cap = int(getattr(config, "timeline_capacity", 4096) or 0)
         self.timeline: Optional[StepTimeline] = (
             StepTimeline(capacity=cap, name="speculative") if cap else None)
-        self._tl_programs: set = set()
         self._total_requests = 0
         self._total_prompt_tokens = 0
         self._total_generated = 0
@@ -444,7 +443,9 @@ class SpeculativeEngine:
             jnp.asarray(min_p),
         )
 
-        t0 = time.perf_counter()
+        sp = host_span(self.timeline, "engine.prefill.dispatch",
+                       dispatch=True, rows=n, spec=True)
+        t0 = sp.t0
         self._rng, k0 = jax.random.split(self._rng)
         first_dev, tks, tvs, dks, dvs = self._prefill_both(
             self.params, self.draft_params,
@@ -485,15 +486,9 @@ class SpeculativeEngine:
         active_np = is_real & ~hit & (produced_np < max_new_arr)
         out_tokens: List[List[int]] = [[int(first[i])] for i in range(n)]
         out_lps: List[List[float]] = [[float(first_lp[i])] for i in range(n)]
-        ttft = time.perf_counter() - t0
+        ttft = sp.close(prefill_tokens=int(sum(seq_lens[:n])),
+                        program=("spec_prefill", bb, tb)) - t0
         self.prefill_stats.add(ttft)
-        if self.timeline is not None:
-            prog = ("spec_prefill", bb, tb)
-            first_seen = prog not in self._tl_programs
-            self._tl_programs.add(prog)
-            self.timeline.record("spec_prefill", t0, ttft, rows=n,
-                                 prefill_tokens=int(sum(seq_lens[:n])),
-                                 **({"compile": True} if first_seen else {}))
 
         lengths = jnp.asarray(seq_lens)
         last = jnp.asarray(np.where(first >= 0, first, 0).astype(np.int32))
@@ -502,7 +497,10 @@ class SpeculativeEngine:
         max_new_j = jnp.asarray(max_new_arr)
         eos_j = jnp.asarray(eos)
 
-        t1 = time.perf_counter()
+        sp = host_span(self.timeline, "engine.verify.dispatch",
+                       dispatch=True, rows=n,
+                       rounds_per_call=self.rounds_per_call, k=self.k)
+        t1 = sp.t0
         act_host = active_np
         scanned = [0] * n        # host-stop scan resume offsets
         # the prefill-sampled FIRST token can itself match stop_ids/
@@ -575,15 +573,8 @@ class SpeculativeEngine:
                     state[6].at[jnp.asarray(stopped_rows,
                                             jnp.int32)].set(False),
                     state[7])
-        decode_t = time.perf_counter() - t1
+        decode_t = sp.close(program=("spec_rounds", bb, R)) - t1
         self.round_stats.add(decode_t)
-        if self.timeline is not None:
-            prog = ("spec_rounds", bb, R)
-            first_seen = prog not in self._tl_programs
-            self._tl_programs.add(prog)
-            self.timeline.record("spec_rounds", t1, decode_t, rows=n,
-                                 rounds_per_call=R, k=self.k,
-                                 **({"compile": True} if first_seen else {}))
 
         results = []
         for i, r in enumerate(requests):

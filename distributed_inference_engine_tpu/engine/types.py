@@ -170,3 +170,8 @@ class GenerationResult:
     ttft_s: float = 0.0
     decode_s: float = 0.0
     metadata: Dict[str, Any] = field(default_factory=dict)
+    # the producing engine's own ``time.perf_counter`` stamps ("submitted",
+    # "admitted", "first_token"): meaningful only inside its process, so
+    # they never ride the wire — the worker turns them into offsets from
+    # its receive time (``worker_trace``)
+    stamps: Dict[str, float] = field(default_factory=dict)

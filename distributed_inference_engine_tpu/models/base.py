@@ -536,11 +536,12 @@ def forward_prefill_into_pages(
         xs_blk, l = per_layer
         blk = rebuild(xs_blk, l)
         x, k, v, _aux = transformer_block(spec, blk, x, positions, attn)
-        idx = jnp.where(valid, l * (n * p) + base_idx, L * n * p)
-        kpf = kpf.at[idx].set(k.reshape(b, t, fused).astype(kpf.dtype),
-                              mode="drop")
-        vpf = vpf.at[idx].set(v.reshape(b, t, fused).astype(vpf.dtype),
-                              mode="drop")
+        with jax.named_scope("attn.kv_update"):
+            idx = jnp.where(valid, l * (n * p) + base_idx, L * n * p)
+            kpf = kpf.at[idx].set(k.reshape(b, t, fused).astype(kpf.dtype),
+                                  mode="drop")
+            vpf = vpf.at[idx].set(v.reshape(b, t, fused).astype(vpf.dtype),
+                                  mode="drop")
         return (x, kpf, vpf), None
 
     (x, kp_flat, vp_flat), _ = lax.scan(
@@ -822,12 +823,14 @@ def forward_decode(
         blk = rebuild(xs_blk, l)
         q, k, v = _qkv_norm(spec, blk, x, positions,
                             fused=fused)             # k,v: [B, 1, Hkv, Dh]
-        ck_full = ck_full.at[l, batch_idx, lengths].set(
-            k[:, 0].astype(ck_full.dtype))
-        cv_full = cv_full.at[l, batch_idx, lengths].set(
-            v[:, 0].astype(cv_full.dtype))
-        ck = lax.dynamic_index_in_dim(ck_full, l, axis=0, keepdims=False)
-        cv = lax.dynamic_index_in_dim(cv_full, l, axis=0, keepdims=False)
+        with jax.named_scope("attn.kv_update"):
+            ck_full = ck_full.at[l, batch_idx, lengths].set(
+                k[:, 0].astype(ck_full.dtype))
+            cv_full = cv_full.at[l, batch_idx, lengths].set(
+                v[:, 0].astype(cv_full.dtype))
+        with jax.named_scope("attn.kv_gather"):
+            ck = lax.dynamic_index_in_dim(ck_full, l, axis=0, keepdims=False)
+            cv = lax.dynamic_index_in_dim(cv_full, l, axis=0, keepdims=False)
         attn = cached_attention(q, ck, cv, lengths + 1,
                                 window=spec.sliding_window)
         x = _out_residual(spec, blk, attn, x, fused=fused)
@@ -1027,12 +1030,16 @@ def forward_decode_paged(
         q, k, v = _qkv_norm(spec, blk, x, positions,
                             fused=fused)             # k,v: [B, 1, Hkv, Dh]
         kv_fused = k.shape[2] * k.shape[3]
-        kp_full = kp_full.at[l, phys, offset].set(
-            k[:, 0].reshape(b, kv_fused).astype(kp_full.dtype), mode="drop")
-        vp_full = vp_full.at[l, phys, offset].set(
-            v[:, 0].reshape(b, kv_fused).astype(vp_full.dtype), mode="drop")
-        kp = lax.dynamic_index_in_dim(kp_full, l, axis=0, keepdims=False)
-        vp = lax.dynamic_index_in_dim(vp_full, l, axis=0, keepdims=False)
+        with jax.named_scope("attn.kv_update"):
+            kp_full = kp_full.at[l, phys, offset].set(
+                k[:, 0].reshape(b, kv_fused).astype(kp_full.dtype),
+                mode="drop")
+            vp_full = vp_full.at[l, phys, offset].set(
+                v[:, 0].reshape(b, kv_fused).astype(vp_full.dtype),
+                mode="drop")
+        with jax.named_scope("attn.kv_gather"):
+            kp = lax.dynamic_index_in_dim(kp_full, l, axis=0, keepdims=False)
+            vp = lax.dynamic_index_in_dim(vp_full, l, axis=0, keepdims=False)
         attn = paged_attention(
             q[:, 0], kp, vp, page_table, lengths + 1,
             n_kv_heads=spec.n_kv_heads, impl=attn_impl,

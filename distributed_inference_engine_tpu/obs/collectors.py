@@ -127,6 +127,8 @@ ENGINE_TABLE = [
      "Whole-buffer deferred-firsts readbacks (one per invalidation)"),
     ("ttft", "engine_ttft_seconds", "h",
      "Time to first token (continuous: from submit, incl. queue wait)"),
+    ("queue_wait", "engine_queue_wait_seconds", "h",
+     "Submit to admitted: the wait for a slot, until the prefill is dispatched"),
     ("prefill", "engine_prefill_seconds", "h", "Prefill dispatch wall time"),
     ("decode_chunk", "engine_decode_chunk_seconds", "h",
      "Decode-chunk wall time (defer_sync: residual blocking wait)"),
@@ -222,6 +224,8 @@ PUMP_TABLE = [                     # EnginePump.get_stats() (sans "engine")
      "Engine steps that raised (backed off and continued)"),
     ("inbox_depth", "pump_inbox_depth", "g",
      "Requests enqueued but not yet admitted"),
+    ("inbox_wait", "pump_inbox_wait_seconds", "h",
+     "Enqueue by an RPC handler to engine.submit() on the pump thread"),
 ]
 
 BATCHER_TABLE = [                  # Batcher.get_stats()
@@ -311,6 +315,14 @@ COORDINATOR_TABLE = [              # Coordinator.get_stats() top level
      "Streamed token frames relayed to consumers"),
     ("stream_itl", "coordinator_stream_itl_seconds", "h",
      "Inter-frame gap at stream delivery (resets across failover)"),
+    ("streams_in_flight", "coordinator_streams_in_flight", "g",
+     "Stream dispatches holding or waiting for a worker connection"),
+    ("pool_waiting", "coordinator_pool_waiting", "g",
+     "Stream dispatches waiting for a pooled worker connection"),
+    ("pool_in_use", "coordinator_pool_in_use", "g",
+     "Worker connections held by calls in flight"),
+    ("pool_wait", "coordinator_pool_wait_seconds", "h",
+     "Wait of a stream dispatch for a pooled worker connection"),
     ("deadline_expired", "coordinator_deadline_expired", "c",
      "Requests answered with the typed deadline outcome"),
     ("drains", "coordinator_drains", "c",
@@ -456,6 +468,17 @@ EXTRA_FAMILIES = [
      "Burn-breach on/off transitions for this objective"),
 ]
 
+WORKER_COMPILE_TABLE = [           # get_metrics()["device"]["compile"]
+    ("backend_compiles", "worker_backend_compiles", "c",
+     "Programs the backend was asked for (XLA compiles and compile-cache loads)"),
+    ("backend_compile_s", "worker_backend_compile_seconds", "c",
+     "Seconds spent in those backend compile requests"),
+    ("cache_hits", "worker_compile_cache_hits", "c",
+     "Programs supplied by the persistent compile cache"),
+    ("cache_misses", "worker_compile_cache_misses", "c",
+     "Programs XLA compiled and the persistent cache then stored"),
+]
+
 _GROUPS: List[Tuple[List, Tuple[str, ...]]] = [
     (ENGINE_TABLE, MODEL_LABELS),
     (ENGINE_OFFLOAD_TABLE, MODEL_LABELS),
@@ -470,6 +493,7 @@ _GROUPS: List[Tuple[List, Tuple[str, ...]]] = [
     (REGISTRY_TABLE, ()),
     (COORDINATOR_TABLE, ()),
     (WORKER_TABLE, WORKER_LABELS),
+    (WORKER_COMPILE_TABLE, WORKER_LABELS),
     (AUTOSCALER_TABLE, ()),
     (UPGRADE_TABLE, ()),
 ]
@@ -745,6 +769,10 @@ def apply_worker(reg: MetricsRegistry, wm: Optional[Mapping[str, Any]],
         reg.gauge("worker_rss_bytes", CATALOG["worker_rss_bytes"][2],
                   WORKER_LABELS).labels(worker_id=wid).set(
                       float(proc["rss_bytes"]))
+    compile_counts = (wm.get("device") or {}).get("compile")
+    if isinstance(compile_counts, Mapping):
+        _apply_table(reg, WORKER_COMPILE_TABLE, compile_counts,
+                     WORKER_LABELS, {"worker_id": wid})
     models = wm.get("models")
     if isinstance(models, Mapping):
         for model, em in models.items():

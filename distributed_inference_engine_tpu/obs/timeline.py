@@ -16,14 +16,48 @@ microsecond ``ts``/``dur`` relative to the timeline's epoch; markers are
 instant events (``"ph": "i"``). Event ``args`` carry the per-step payload
 (rows, prefill tokens, pool occupancy, ``compile``) and show up in the
 Perfetto slice-details pane.
+
+Host spans (``host_span``) are the one way the program opens a span on
+the engine thread: each is a ``jax.profiler.TraceAnnotation`` — so while a
+profile runs it sits in the ``.xplane.pb`` on the device planes' clock —
+and, when the engine keeps a ring, one record of that ring. ``clock_anchor``
+ties the ring's ``perf_counter`` clock to the profiler's.
 """
 
 from __future__ import annotations
 
 import json
+import sys
 import time
 from collections import deque
 from typing import Any, Dict, List, Optional
+
+_annotation_cls: Any = None
+# a TraceAnnotation's args travel as ``#k=v,k=v#`` text behind its name
+_ANNOTATION_UNSAFE = str.maketrans({",": ";", "#": "~"})
+
+
+def _trace_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation`` once this process has imported jax
+    (``obs`` never does: a process without jax has no profiler to write
+    to), else ``None``."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _annotation_cls = jax.profiler.TraceAnnotation
+    return _annotation_cls
+
+
+def _backend_compiles() -> int:
+    """The compiler's own count of the programs it was asked for
+    (``utils.compile_cache``); 0 in a process without jax."""
+    if "jax" not in sys.modules:
+        return 0
+    from ..utils.compile_cache import compile_counters
+
+    return compile_counters()["backend_compiles"]
 
 
 class StepTimeline:
@@ -36,6 +70,8 @@ class StepTimeline:
         self._epoch = time.perf_counter()
         self._capture_from: Optional[float] = None
         self._dropped = 0
+        self._open: List[str] = []       # names of the spans open now
+        self._anchors: List[Dict[str, Any]] = []
 
     def __len__(self) -> int:
         return len(self._events)
@@ -43,13 +79,17 @@ class StepTimeline:
     # -- recording ---------------------------------------------------------
 
     def record(self, kind: str, t_start: float, dur_s: float,
+               parent: Optional[str] = None, dispatch: bool = True,
                **args: Any) -> None:
-        """One complete dispatch: ``t_start`` is a ``time.perf_counter()``
-        stamp, ``dur_s`` its wall duration."""
+        """One complete span: ``t_start`` is a ``time.perf_counter()``
+        stamp, ``dur_s`` its wall duration, ``parent`` the span it was
+        opened inside. ``dispatch`` marks a device-dispatch bracket: the
+        records ``busy_gap_split`` reads."""
         if len(self._events) == self._events.maxlen:
             self._dropped += 1
         self._events.append({"name": kind, "t": float(t_start),
-                             "dur": float(dur_s), "args": args})
+                             "dur": float(dur_s), "parent": parent,
+                             "dispatch": dispatch, "args": args})
 
     def instant(self, kind: str, **args: Any) -> None:
         if len(self._events) == self._events.maxlen:
@@ -61,6 +101,11 @@ class StepTimeline:
 
     def start_capture(self) -> None:
         self._capture_from = time.perf_counter()
+        self._anchors = []
+
+    def add_anchor(self, anchor: Dict[str, Any]) -> None:
+        """Keep a ``clock_anchor`` for the next dump's metadata."""
+        self._anchors.append(dict(anchor))
 
     def stop_capture(self) -> List[Dict[str, Any]]:
         """Events recorded since ``start_capture()`` (all events if the
@@ -93,14 +138,22 @@ class StepTimeline:
                             "ts": ts, "pid": pid, "tid": tid,
                             "args": dict(e["args"])})
             else:
+                args = dict(e["args"])
+                if e.get("parent"):
+                    args["parent"] = e["parent"]
                 out.append({"name": e["name"], "ph": "X", "ts": ts,
                             "dur": e["dur"] * 1e6, "pid": pid, "tid": tid,
-                            "args": dict(e["args"])})
+                            "args": args})
         return {
             "traceEvents": out,
             "displayTimeUnit": "ms",
+            # ts 0 is ``epoch_perf_counter_ns`` on this process's
+            # perf_counter; each anchor is the same instant on that clock
+            # and, as a ``clock.anchor`` event, in the profiler's trace
             "metadata": {"timeline": self.name,
-                         "dropped_events": self._dropped},
+                         "dropped_events": self._dropped,
+                         "epoch_perf_counter_ns": int(self._epoch * 1e9),
+                         "clock_anchors": list(self._anchors)},
         }
 
     def dump(self, path: str,
@@ -112,18 +165,99 @@ class StepTimeline:
         return atomic_write(path, lambda f: json.dump(trace, f))
 
 
+class HostSpan:
+    """One open host span; ``close()`` (or leaving the ``with``) ends it.
+    Open at construction, so a dispatch site can open it where its bracket
+    starts and close it where it ends without re-indenting what lies
+    between. ``close(**more)`` adds what is only known at the end to the
+    ring record (the annotation keeps what it was opened with). A dispatch
+    bracket's record is flagged ``compile=True`` when the compiler's
+    counter grew across it: that dispatch paid an XLA compile or a
+    compile-cache load."""
+
+    __slots__ = ("name", "t0", "args", "_tl", "_ann", "_parent", "_dispatch",
+                 "_compiles")
+
+    def __init__(self, timeline: Optional[StepTimeline], name: str,
+                 dispatch: bool, args: Dict[str, Any]) -> None:
+        self.name = name
+        self.args = args
+        self._tl = timeline
+        self._dispatch = dispatch
+        self._parent: Optional[str] = None
+        self._compiles = (_backend_compiles()
+                          if dispatch and timeline is not None else 0)
+        if timeline is not None:
+            if timeline._open:
+                self._parent = timeline._open[-1]
+            timeline._open.append(name)
+        cls = _trace_annotation()
+        self._ann = None
+        if cls is not None:
+            self._ann = cls(name, **{
+                k: v.translate(_ANNOTATION_UNSAFE) if isinstance(v, str)
+                else v for k, v in args.items()})
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def close(self, **more: Any) -> float:
+        """End the span; returns the ``perf_counter`` stamp of its end."""
+        now = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        tl, self._tl = self._tl, None
+        if tl is not None:
+            # tolerate a span an exception skipped: pop down to this one
+            while tl._open and tl._open.pop() != self.name:
+                pass
+            if self._dispatch and _backend_compiles() != self._compiles:
+                more["compile"] = True
+            tl.record(self.name, self.t0, now - self.t0,
+                      parent=self._parent, dispatch=self._dispatch,
+                      **{**self.args, **more})
+        return now
+
+    def __enter__(self) -> "HostSpan":
+        return self
+
+    def __exit__(self, *exc: Any) -> None:
+        self.close()
+
+
+def host_span(timeline: Optional[StepTimeline], name: str,
+              dispatch: bool = False, **args: Any) -> HostSpan:
+    """Open a host span on the calling thread (see the module docstring).
+    ``timeline`` may be ``None`` (ring disabled): the annotation alone.
+    Annotation values travel as ``k=v`` text: ``,`` and ``#`` in a string
+    value (a caller's request id) are replaced there; the ring record
+    keeps the value as given."""
+    return HostSpan(timeline, name, dispatch, args)
+
+
+def clock_anchor(at: str) -> Dict[str, Any]:
+    """One ``clock.anchor`` annotation carrying this process's
+    ``perf_counter_ns``: the same instant on the profiler's clock (the
+    event's start) and on the clock of ``RequestTrace`` offsets and ring
+    records (its stat). Returns the anchor for the ring dump."""
+    ns = time.perf_counter_ns()
+    host_span(None, "clock.anchor", perf_counter_ns=ns, at=at).close()
+    return {"at": at, "perf_counter_ns": ns}
+
+
 def busy_gap_split(events: List[Dict[str, Any]]) -> Dict[str, float]:
     """Decompose a window of dispatch events into busy (inside a dispatch
     bracket) vs gap (host time BETWEEN consecutive brackets) seconds —
     the roofline split (ISSUE 5): ``hbm_util`` regressions attribute to
     the kernel side when busy grew, to the scheduler/host side when gap
-    grew. Instant markers (``dur is None``) are skipped; overlapping
-    brackets clamp the gap at zero rather than going negative.
+    grew. Instant markers (``dur is None``) and spans that are not
+    dispatch brackets are skipped; overlapping brackets clamp the gap at
+    zero rather than going negative.
 
     Returns busy_s, gap_s, bubble_frac = gap / (busy + gap), and the
     event count the split was computed over."""
     spans = sorted((e["t"], e["t"] + e["dur"]) for e in events
-                   if e.get("dur") is not None)
+                   if e.get("dur") is not None and e.get("dispatch", True))
     busy = 0.0
     gap = 0.0
     prev_end: Optional[float] = None

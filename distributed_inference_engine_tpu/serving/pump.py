@@ -34,6 +34,8 @@ from ..engine.types import (
     GenerationRequest,
     GenerationResult,
 )
+from ..obs.timeline import HostSpan, host_span
+from ..utils.tracing import LatencyStats
 
 logger = logging.getLogger(__name__)
 
@@ -97,13 +99,18 @@ class EnginePump:
                     self._spec_rounds += spec.schedule()
 
             engine.overlap_hook = _overlap
-        # (request, optional handoff, optional stream cb, future, loop)
+        # (request, optional handoff, optional stream cb, future, loop,
+        #  perf_counter stamp of the enqueue)
         self._inbox: List[Tuple[GenerationRequest, Any, Any, asyncio.Future,
-                                asyncio.AbstractEventLoop]] = []
+                                asyncio.AbstractEventLoop, float]] = []
         self._inbox_lock = threading.Lock()
-        # pump id -> (future, loop, caller's original request id)
+        # engine-side id -> (future, loop, caller's original request id)
         self._futures: Dict[str, Tuple[asyncio.Future,
                                        asyncio.AbstractEventLoop, str]] = {}
+        self._renamed = 0               # ids the pump had to make unique
+        # enqueue (an RPC handler, any loop) -> engine.submit() on the pump
+        # thread: the wait for the engine thread to come back to the inbox
+        self.inbox_wait = LatencyStats()
         self._wake = threading.Event()
         self._stop = threading.Event()
         self._step_errors = 0
@@ -166,10 +173,11 @@ class EnginePump:
             def cb(tokens, _loop=loop, _cb=on_tokens):
                 _loop.call_soon_threadsafe(_cb, tokens)
         futs: List[asyncio.Future] = []
+        t_in = time.perf_counter()
         with self._inbox_lock:
             for r, handoff in pairs:
                 fut: asyncio.Future = loop.create_future()
-                self._inbox.append((r, handoff, cb, fut, loop))
+                self._inbox.append((r, handoff, cb, fut, loop, t_in))
                 futs.append(fut)
         self._wake.set()
         results = await asyncio.gather(*futs)
@@ -206,7 +214,7 @@ class EnginePump:
         exc = RuntimeError("engine pump shut down")
         with self._inbox_lock:
             pending, self._inbox = self._inbox, []
-        for _req, _handoff, _cb, fut, loop in pending:
+        for _req, _handoff, _cb, fut, loop, _t in pending:
             loop.call_soon_threadsafe(self._set_exc, fut, exc)
         self._fail_all(exc)
 
@@ -229,8 +237,11 @@ class EnginePump:
                 if admitted or self.engine.n_live or self.engine.n_waiting:
                     self._steps += 1
                     live = self.engine.step()
-                    for res in self.engine.drain_finished():
-                        self._resolve(res)
+                    finished = self.engine.drain_finished()
+                    if finished:
+                        with self._span("pump.resolve", results=len(finished)):
+                            for res in finished:
+                                self._resolve(res)
                     # between-steps half of the host bubble: the chunk
                     # dispatched by step() may already be host-side
                     if self._poll_stream is not None:
@@ -251,18 +262,38 @@ class EnginePump:
                 continue
             if not live and not self.engine.n_waiting:
                 # idle: block until new work arrives
-                self._wake.wait(timeout=self.idle_wait_s)
+                with self._span("pump.idle_wait"):
+                    self._wake.wait(timeout=self.idle_wait_s)
                 self._wake.clear()
         # fail anything still in flight so no caller hangs on shutdown
         self._fail_all(RuntimeError("engine pump shut down"))
         logger.info("engine pump stopped")
 
+    def _span(self, name: str, **args: Any) -> HostSpan:
+        """A host span of the engine thread, in the engine's own ring
+        (``obs.timeline.host_span``)."""
+        return host_span(getattr(self.engine, "timeline", None), name,
+                         **args)
+
     def _drain_inbox(self) -> int:
         with self._inbox_lock:
             batch, self._inbox = self._inbox, []
-        for req, handoff, cb, fut, loop in batch:
-            pump_id = f"pump-{id(self):x}-{len(self._futures)}-{time.monotonic_ns()}"
+        if not batch:
+            return 0
+        with self._span("pump.drain_inbox", requests=len(batch)):
+            self._submit_batch(batch)
+        return len(batch)
+
+    def _submit_batch(self, batch) -> None:
+        for req, handoff, cb, fut, loop, t_in in batch:
+            # the engine keys its results by request id, so two in flight
+            # may not share one; the caller's id is kept wherever it is
+            # unique, so that spans and marks below the pump carry it
             original_id = req.request_id
+            pump_id = original_id
+            if not pump_id or pump_id in self._futures:
+                self._renamed += 1
+                pump_id = f"{original_id or 'anon'}~{self._renamed}"
             req.request_id = pump_id
             self._futures[pump_id] = (fut, loop, original_id)
             try:
@@ -277,6 +308,7 @@ class EnginePump:
                     prefetch = getattr(self.engine, "prefetch_probe", None)
                     if prefetch is not None:
                         prefetch(req)
+                self.inbox_wait.add(time.perf_counter() - t_in)
                 if self._events is not None:
                     self._events.emit("admission.accept", model=self._model,
                                       request_id=original_id or pump_id)
@@ -299,7 +331,6 @@ class EnginePump:
             except Exception as e:
                 del self._futures[pump_id]
                 loop.call_soon_threadsafe(self._set_exc, fut, e)
-        return len(batch)
 
     def _resolve(self, res: GenerationResult) -> None:
         entry = self._futures.pop(res.request_id, None)
@@ -336,6 +367,7 @@ class EnginePump:
             "steps": self._steps,
             "step_errors": self._step_errors,
             "inbox_depth": inbox_depth,
+            "inbox_wait": self.inbox_wait.snapshot(),
             # requests admitted INSIDE a device step's shadow via the
             # engine's overlap hook (vs the top-of-loop drain)
             "overlap_admitted": self._overlap_admitted,

@@ -79,6 +79,7 @@ class FramedRPCClient:
         self._free: list = []   # [(reader, writer)]
         self._total = 0
         self._inuse: set = set()  # (reader, writer) with a call in flight
+        self._waiting = 0         # callers blocked on a full pool
         self._cond = asyncio.Condition()
         self._seq = 0
         self._closed = False
@@ -89,6 +90,11 @@ class FramedRPCClient:
     @property
     def address(self) -> str:
         return f"{self.host}:{self.port}"
+
+    def pool_stats(self) -> Dict[str, int]:
+        """Gauges of the connection pool: calls holding a connection,
+        callers blocked because all ``max_connections`` are held."""
+        return {"in_use": len(self._inuse), "waiting": self._waiting}
 
     async def _acquire(
         self, timeout: float
@@ -105,7 +111,11 @@ class FramedRPCClient:
                     if self._total < self.max_connections:
                         self._total += 1  # reserve before the await below
                         break
-                    await self._cond.wait()
+                    self._waiting += 1
+                    try:
+                        await self._cond.wait()
+                    finally:
+                        self._waiting -= 1
             try:
                 return await asyncio.open_connection(self.host, self.port)
             except BaseException:
@@ -201,6 +211,7 @@ class FramedRPCClient:
 
     async def call_stream(self, method: str, on_chunk: Callable[[Dict], None],
                           *, timeout: Optional[float] = None,
+                          on_acquired: Optional[Callable[[float], None]] = None,
                           **params: Any) -> Any:
         """Send one request, consume a stream of chunk frames, return the
         final result.
@@ -208,14 +219,20 @@ class FramedRPCClient:
         The server interleaves ``{"stream": true, ...}`` frames (each passed
         to ``on_chunk``) before the usual success/error envelope. ``timeout``
         bounds each individual frame read — a live stream keeps resetting
-        it — not the total call.
+        it — not the total call. ``on_acquired(wait_s)`` fires once the
+        call holds its pooled connection, with the seconds it waited for
+        one: a stream holds its connection for its life, so under load
+        this wait is a queue.
         """
-        return await self._roundtrip(method, on_chunk, timeout, params)
+        return await self._roundtrip(method, on_chunk, timeout, params,
+                                     on_acquired)
 
     async def _roundtrip(self, method: str,
                          on_chunk: Optional[Callable[[Dict], None]],
                          timeout: Optional[float],
-                         params: Dict[str, Any]) -> Any:
+                         params: Dict[str, Any],
+                         on_acquired: Optional[Callable[[float], None]] = None
+                         ) -> Any:
         """One shared request/response cycle for ``call`` and
         ``call_stream`` — a single copy of the acquire/discard discipline
         and envelope validation (two copies drifted once before; see the
@@ -232,9 +249,12 @@ class FramedRPCClient:
             if fault is not None and fault.kind == "slow":
                 await asyncio.sleep(fault.delay_s)
         self._closed = False          # calling a closed client reopens it
+        t_wait = time.perf_counter()
         conn = await self._acquire(effective)
         self._inuse.add(conn)
         try:
+            if on_acquired is not None:
+                on_acquired(time.perf_counter() - t_wait)
             await write_frame(conn[1], msg)
             if fault is not None and fault.kind == "stall":
                 # the request frame is on the wire; tear the connection

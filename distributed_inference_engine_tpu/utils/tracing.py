@@ -13,11 +13,10 @@ BASELINE.json metrics.
 from __future__ import annotations
 
 import bisect
-import contextlib
 import time
 import uuid
 from dataclasses import dataclass, field
-from typing import Any, Dict, Iterator, Optional
+from typing import Any, Dict, Optional
 
 
 def new_request_id() -> str:
@@ -48,8 +47,12 @@ def format_bucket_bound(bound: float) -> str:
 class RequestTrace:
     """Monotonic per-phase marks for one request's lifetime.
 
-    Canonical phases: received, queued, batched, prefill_start, prefill_end,
-    first_token, decode_end, responded.
+    Coordinator marks, in order: received, routed, dispatched,
+    conn_acquired (streams), first_frame (streams), done; a batched
+    request has queued / batched on its way to routed. The
+    worker's marks arrive as offsets and land as ``worker.*``
+    (``add_offsets``): received, submitted, admitted, first_token,
+    first_frame_sent, done. ``docs/observability.md`` has the glossary.
     """
 
     request_id: str = field(default_factory=new_request_id)
@@ -69,29 +72,23 @@ class RequestTrace:
             return self.marks[end] - self.marks[start]
         return None
 
-    @property
-    def ttft(self) -> Optional[float]:
-        """Time to first token: received → first_token."""
-        return self.span("received", "first_token")
-
-    @property
-    def total(self) -> Optional[float]:
-        return self.span("received", "responded")
-
     def add_offsets(self, prefix: str, offsets: Dict[str, float],
                     anchor: Optional[float] = None) -> None:
         """Merge REMOTE phase marks recorded as offsets on another clock.
 
         A worker cannot share this trace's ``time.monotonic`` epoch, so it
         reports phases as offsets from its own receive time; anchoring
-        them at this trace's ``dispatched`` mark (network transit folds
-        into the remote ``received``≈0 offset) lands them on the local
-        timeline. ``mark()``'s first-wins semantics are preserved via
-        ``setdefault``. ``anchor`` is an absolute local monotonic stamp;
-        defaults to the ``dispatched`` (else ``received``) mark."""
+        them at the moment this side had a connection to it (network
+        transit folds into the remote ``received``≈0 offset) lands them
+        on the local timeline. ``mark()``'s first-wins semantics are
+        preserved via ``setdefault``. ``anchor`` is an absolute local
+        monotonic stamp; defaults to the ``conn_acquired`` mark, else
+        ``dispatched``, else ``received``."""
         if anchor is None:
-            anchor = self.marks.get("dispatched",
-                                    self.marks.get("received", 0.0))
+            anchor = self.marks.get(
+                "conn_acquired",
+                self.marks.get("dispatched",
+                               self.marks.get("received", 0.0)))
         for phase, off in offsets.items():
             if isinstance(off, (int, float)):
                 self.marks.setdefault(f"{prefix}{phase}",
@@ -102,17 +99,6 @@ class RequestTrace:
         d = {k: v - base for k, v in self.marks.items()}
         d["request_id"] = self.request_id  # type: ignore[assignment]
         return d
-
-
-@contextlib.contextmanager
-def trace_span(trace: Optional[RequestTrace], start: str, end: str) -> Iterator[None]:
-    if trace is not None:
-        trace.mark(start)
-    try:
-        yield
-    finally:
-        if trace is not None:
-            trace.mark(end)
 
 
 class LatencyStats:

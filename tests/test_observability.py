@@ -231,9 +231,12 @@ def test_continuous_engine_records_timeline():
                               request_id=f"r{i}") for i in range(2)]
     eng.generate(reqs)
     kinds = {e["name"] for e in eng.timeline.events()}
-    assert "prefill" in kinds and "decode" in kinds
-    decodes = [e for e in eng.timeline.events() if e["name"] == "decode"]
-    assert decodes[0]["args"].get("compile") is True  # first program shape
+    assert {"engine.admit", "engine.prefill.dispatch",
+            "engine.decode.dispatch", "engine.harvest.wait",
+            "engine.harvest.emit", "engine.process_packed"} <= kinds
+    decodes = [e for e in eng.timeline.events()
+               if e["name"] == "engine.decode.dispatch"]
+    assert decodes[0]["args"].get("compile") is True  # the compiler ran
     assert all(e["args"]["kv_pages_total"] == 32 for e in decodes)
     doc = eng.timeline.to_chrome_trace()
     assert any(e.get("ph") == "X" for e in doc["traceEvents"])

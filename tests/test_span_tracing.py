@@ -1,0 +1,375 @@
+"""Where a request waits and what the engine thread does (ISSUE 24): the
+request marks that tile a stream's time to its first frame, the pool /
+inbox / queue waits and their gauges, the engine thread's host spans in the
+step ring, and the compile flag that follows the compiler's own counter."""
+
+import asyncio
+import subprocess
+import sys
+
+import jax.monitoring
+import numpy as np
+import pytest
+
+from distributed_inference_engine_tpu.api.coordinator import (
+    Coordinator,
+    CoordinatorConfig,
+)
+from distributed_inference_engine_tpu.cluster.worker import WorkerServer
+from distributed_inference_engine_tpu.config import ModelConfig, ServerConfig
+from distributed_inference_engine_tpu.engine.continuous import ContinuousEngine
+from distributed_inference_engine_tpu.engine.types import GenerationRequest
+from distributed_inference_engine_tpu.obs.timeline import (
+    StepTimeline,
+    busy_gap_split,
+    clock_anchor,
+    host_span,
+)
+from distributed_inference_engine_tpu.serving.pump import EnginePump
+from distributed_inference_engine_tpu.utils.compile_cache import (
+    compile_counters,
+)
+from tests.test_continuous import SPEC, _cfg
+
+pytestmark = pytest.mark.obs
+
+STREAM_MARKS = ["received", "routed", "dispatched", "conn_acquired",
+                "worker.received", "worker.submitted", "worker.admitted",
+                "worker.first_token", "worker.first_frame_sent",
+                "first_frame", "worker.done", "done"]
+
+
+async def _fleet(model: ModelConfig):
+    coord = Coordinator(CoordinatorConfig())
+    await coord.start()
+    w = WorkerServer(ServerConfig(worker_id="w0", host="127.0.0.1", port=0))
+    host, port = await w.start()
+    coord.add_worker("w0", host, port)
+    await coord.deploy_model(model)
+    return coord, w
+
+
+def _tiny_llama() -> ModelConfig:
+    return ModelConfig(
+        name="m", architecture="llama", dtype="float32", max_seq_len=64,
+        max_batch_size=4,
+        metadata={"size": "llama-tiny", "page_size": 16, "num_pages": 64,
+                  "attention_impl": "xla", "kv_dtype": "float32",
+                  "decode_steps_per_call": 3, "continuous": 1})
+
+
+def _fake(step_latency_s: float) -> ModelConfig:
+    return ModelConfig(name="m", architecture="fake", metadata={
+        "continuous": 1, "max_slots": 4, "step_latency_s": step_latency_s})
+
+
+# ------------------------------------------------------ request-path marks
+
+
+async def test_stream_marks_tile_the_time_to_first_frame():
+    coord, w = await _fleet(_tiny_llama())
+    try:
+        out = await coord.submit_stream(
+            "m", prompt=[5, 6, 7], on_tokens=lambda t: None,
+            max_new_tokens=5, request_id="tile-1")
+        tr = out["trace"]
+        for phase in STREAM_MARKS:
+            assert phase in tr, phase
+        # every mark in order, up to first_frame (worker.done and done
+        # follow the stream's end)
+        chain = [tr[p] for p in STREAM_MARKS[:10]]
+        assert chain == sorted(chain), dict(zip(STREAM_MARKS, chain))
+        assert tr["worker.first_frame_sent"] <= tr["worker.done"] <= tr["done"]
+        # worker marks are anchored where the coordinator had a connection
+        assert tr["worker.received"] == pytest.approx(tr["conn_acquired"])
+        # the five spans account for received -> first_frame up to routing
+        # (before dispatched) and transit (after first_frame_sent)
+        spans = (
+            (tr["conn_acquired"] - tr["dispatched"])                 # pool
+            + (tr["worker.submitted"] - tr["worker.received"])       # inbox
+            + (tr["worker.admitted"] - tr["worker.submitted"])       # queue
+            + (tr["worker.first_token"] - tr["worker.admitted"])     # prefill
+            + (tr["worker.first_frame_sent"] - tr["worker.first_token"]))
+        outside = tr["first_frame"] - tr["received"] - spans
+        assert 0.0 <= outside < 0.05, (outside, tr)
+        # the same waits, as the components' own histograms and gauges
+        stats = coord.get_stats()
+        assert stats["pool_wait"]["count"] == 1
+        assert stats["streams_in_flight"] == 0
+        assert stats["pool_waiting"] == 0 and stats["pool_in_use"] == 0
+        pump = w._pumps["m"].get_stats()
+        assert pump["inbox_wait"]["count"] >= 1
+        assert pump["engine"]["queue_wait"]["count"] >= 1
+    finally:
+        await coord.stop()
+        await w.stop()
+
+
+async def test_inbox_wait_is_not_charged_to_the_pool():
+    """A request that arrives while the engine thread is inside a step
+    waits in the pump's inbox: ``inbox_wait`` shows it, and the
+    coordinator's ``dispatched -> conn_acquired`` does not."""
+    step_s = 0.08
+    coord, w = await _fleet(_fake(step_s))
+    try:
+        first_seen = asyncio.Event()
+        a = asyncio.ensure_future(coord.submit_stream(
+            "m", prompt=[1, 2, 3], on_tokens=lambda t: first_seen.set(),
+            max_new_tokens=6, request_id="a"))
+        await first_seen.wait()              # the engine thread is stepping
+        await asyncio.sleep(step_s / 4)
+        b = await coord.submit_stream(
+            "m", prompt=[4, 5, 6], on_tokens=lambda t: None,
+            max_new_tokens=2, request_id="b")
+        await a
+        tr = b["trace"]
+        assert tr["conn_acquired"] - tr["dispatched"] < step_s / 4
+        inbox = w._pumps["m"].get_stats()["inbox_wait"]
+        assert inbox["count"] == 2
+        assert inbox["p99_s"] > step_s / 4   # b waited out a's step
+    finally:
+        await coord.stop()
+        await w.stop()
+
+
+async def test_pool_of_one_makes_two_streams_wait():
+    step_s = 0.05
+    coord, w = await _fleet(_fake(step_s))
+    try:
+        for client in (coord.router.client_for("w0"),
+                       coord.lb.client_for("w0")):
+            client.max_connections = 1
+        streams = [asyncio.ensure_future(coord.submit_stream(
+            "m", prompt=[i + 1, 2, 3], on_tokens=lambda t: None,
+            max_new_tokens=3, request_id=f"s{i}")) for i in range(3)]
+        await asyncio.sleep(step_s)          # the first is being served
+        stats = coord.get_stats()
+        assert stats["streams_in_flight"] == 3
+        assert stats["pool_in_use"] == 1
+        assert stats["pool_waiting"] == 2
+        outs = await asyncio.gather(*streams)
+        waits = sorted(o["trace"]["conn_acquired"] - o["trace"]["dispatched"]
+                       for o in outs)
+        assert waits[0] < step_s and waits[1] > step_s and waits[2] > step_s
+        stats = coord.get_stats()
+        assert stats["pool_wait"]["count"] == 3
+        assert stats["pool_waiting"] == 0 and stats["streams_in_flight"] == 0
+    finally:
+        await coord.stop()
+        await w.stop()
+
+
+# ------------------------------------------------------ engine-thread spans
+
+
+def _req(i: int, n_new: int = 6) -> GenerationRequest:
+    rs = np.random.RandomState(i)
+    return GenerationRequest(
+        prompt=rs.randint(1, SPEC.vocab_size, size=8).tolist(),
+        max_new_tokens=n_new, temperature=0.0, request_id=f"caller-{i}")
+
+
+async def test_spans_carry_the_callers_request_id():
+    engine = ContinuousEngine(SPEC, config=_cfg(max_slots=4), seed=0)
+    pump = EnginePump(engine)
+    try:
+        outs = await asyncio.gather(*(pump.generate([_req(i)])
+                                      for i in range(3)))
+        # the same id twice at once: both served, both returned under it
+        twins = await asyncio.gather(pump.generate([_req(7)]),
+                                     pump.generate([_req(7)]))
+        assert [r[0].request_id for r in twins] == ["caller-7", "caller-7"]
+        assert twins[0][0].tokens == twins[1][0].tokens
+    finally:
+        await pump.stop()
+    assert sorted(r[0].request_id for r in outs) == [
+        "caller-0", "caller-1", "caller-2"]
+    admits = [e for e in engine.timeline.events()
+              if e["name"] == "engine.admit"]
+    ids = ";".join(e["args"]["request_ids"] for e in admits).split(";")
+    assert {"caller-0", "caller-1", "caller-2", "caller-7"} <= set(ids)
+    # the duplicate's suffix holds neither of the characters the profiler's
+    # ``#k=v,k=v#`` encoding of a span's args uses
+    assert sorted(i for i in ids if i.startswith("caller-7")) == [
+        "caller-7", "caller-7~1"], ids
+    names = {e["name"] for e in engine.timeline.events()}
+    assert {"pump.drain_inbox", "pump.resolve", "engine.admit",
+            "engine.prefill.dispatch", "engine.decode.dispatch",
+            "engine.harvest.wait", "engine.harvest.emit",
+            "engine.process_packed"} <= names
+    # a result hands the engine's stamps up, in order
+    st = outs[0][0].stamps
+    assert st["submitted"] <= st["admitted"] <= st["first_token"]
+
+
+@pytest.mark.parametrize("slots,steps,n_new", [(2, 2, 8), (8, 8, 24)])
+def test_a_decode_chunk_adds_a_bounded_number_of_records(slots, steps, n_new):
+    engine = ContinuousEngine(
+        SPEC, config=_cfg(max_slots=slots, decode_steps_per_call=steps),
+        seed=0)
+    engine.generate([_req(i, n_new) for i in range(slots)])
+    events = engine.timeline.events()
+    chunks = [e for e in events if e["name"] == "engine.decode.dispatch"]
+    inside = [e for e in events if e["parent"] == "engine.decode.dispatch"
+              or e["parent"] == "engine.process_packed"]
+    assert len(chunks) >= 2
+    # per chunk: its bracket, process_packed, harvest.wait, harvest.emit
+    assert len(inside) == 3 * len(chunks)
+    admits = [e for e in events if e["name"] in (
+        "engine.admit", "engine.prefill.dispatch")]
+    steps = [e for e in events if e["name"] == "engine.step"]
+    assert len(chunks) <= len(steps) <= len(chunks) + 1   # one per step()
+    assert all(e["parent"] == "engine.step" for e in chunks)
+    assert len(events) == 4 * len(chunks) + len(admits) + len(steps)
+    # only the dispatch brackets count as busy time
+    split = busy_gap_split(events)
+    assert split["n_events"] == sum(1 for e in events if e["dispatch"])
+
+
+def test_compile_flag_follows_the_compilers_counter():
+    engine = ContinuousEngine(SPEC, config=_cfg(max_slots=2), seed=0)
+    engine.generate([_req(0)])
+    before = compile_counters()["backend_compiles"]
+    first = [e for e in engine.timeline.events() if e["dispatch"]]
+    assert before > 0 and first[0]["args"].get("compile") is True
+    mark = len(engine.timeline.events())
+    engine.generate([_req(1)])               # same shapes: nothing compiles
+    assert compile_counters()["backend_compiles"] == before
+    again = [e for e in engine.timeline.events()[mark:] if e["dispatch"]]
+    assert again and not any(e["args"].get("compile") for e in again)
+    # the compiler reports a compile while a chunk is in flight: that
+    # chunk's record, and no other, carries the flag
+    fired = []
+
+    def hook():
+        if not fired:
+            fired.append(1)
+            jax.monitoring.record_event_duration_secs(
+                "/jax/core/compile/backend_compile_duration", 0.25)
+
+    engine.overlap_hook = hook
+    mark = len(engine.timeline.events())
+    engine.generate([_req(2)])
+    flagged = [e["name"] for e in engine.timeline.events()[mark:]
+               if e["args"].get("compile")]
+    assert flagged == ["engine.decode.dispatch"]
+    after = compile_counters()
+    assert after["backend_compiles"] == before + 1
+    assert after["backend_compile_s"] >= 0.25
+
+
+def test_host_span_nests_and_survives_a_disabled_ring():
+    tl = StepTimeline(capacity=8)
+    with host_span(tl, "outer", rows=2):
+        inner = host_span(tl, "inner", dispatch=True, steps=4)
+        inner.close(steps=5, compile=True)   # close() args win
+    host_span(None, "ringless", a=1).close()   # the annotation alone
+    inner_ev, outer_ev = tl.events()
+    assert (inner_ev["name"], inner_ev["parent"]) == ("inner", "outer")
+    assert inner_ev["args"] == {"steps": 5, "compile": True}
+    assert inner_ev["dispatch"] and not outer_ev["dispatch"]
+    assert outer_ev["parent"] is None and outer_ev["args"] == {"rows": 2}
+    assert outer_ev["t"] <= inner_ev["t"]
+    assert outer_ev["dur"] >= inner_ev["dur"]
+    anchor = clock_anchor("start")
+    tl.add_anchor(anchor)
+    meta = tl.to_chrome_trace()["metadata"]
+    assert meta["clock_anchors"] == [anchor]
+    assert anchor["at"] == "start" and anchor["perf_counter_ns"] > 0
+    assert meta["epoch_perf_counter_ns"] <= anchor["perf_counter_ns"]
+
+
+def test_annotation_values_are_cleaned_of_the_encodings_characters(
+        monkeypatch):
+    """A caller's request id may hold ``,`` or ``#``: the annotation gets
+    it cleaned, the ring record as given."""
+    from distributed_inference_engine_tpu.obs import timeline
+
+    seen = []
+
+    class Annotation:
+        def __init__(self, name, **kw):
+            seen.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setattr(timeline, "_annotation_cls", Annotation)
+    tl = StepTimeline(capacity=4)
+    host_span(tl, "engine.admit", rows=2, request_ids="a,b#1;c").close()
+    assert seen == [("engine.admit", {"rows": 2, "request_ids": "a;b~1;c"})]
+    assert tl.events()[0]["args"]["request_ids"] == "a,b#1;c"
+
+
+def test_obs_imports_and_opens_spans_without_jax():
+    code = (
+        "import sys\n"
+        "from distributed_inference_engine_tpu.obs import timeline\n"
+        "tl = timeline.StepTimeline(capacity=4)\n"
+        "with timeline.host_span(tl, 'pump.idle_wait'):\n"
+        "    pass\n"
+        "timeline.clock_anchor('start')\n"
+        "assert [e['name'] for e in tl.events()] == ['pump.idle_wait']\n"
+        "assert 'jax' not in sys.modules, 'obs imported jax'\n")
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120)
+    assert res.returncode == 0, res.stderr
+
+
+# ------------------------------------------------------------ profile RPC
+
+
+async def test_profile_rpc_anchors_the_ring_to_the_trace(tmp_path):
+    from distributed_inference_engine_tpu.cluster.worker import WorkerClient
+
+    w = WorkerServer(ServerConfig(worker_id="wp", host="127.0.0.1", port=0))
+    host, port = await w.start()
+    client = WorkerClient(host, port)
+    try:
+        await client.load_model(_tiny_llama())
+        trace_dir = str(tmp_path / "trace")
+        started = await client.call("profile", action="start",
+                                    trace_dir=trace_dir, python_tracer=False)
+        assert started["python_tracer"] is False
+        chunks = []
+        res = await client.generate_stream(
+            "m", GenerationRequest(prompt=[5, 6, 7], max_new_tokens=5,
+                                   request_id="prof-1"), chunks.append)
+        assert [t for c in chunks for t in c] == res.tokens
+        stopped = await client.call("profile", action="stop", timeout=120.0)
+        (dump,) = stopped["step_timelines"]
+    finally:
+        await client.close()
+        await w.stop()
+    import json
+
+    with open(dump) as f:
+        doc = json.load(f)
+    anchors = doc["metadata"]["clock_anchors"]
+    assert [a["at"] for a in anchors] == ["start", "stop"]
+    assert anchors[0]["perf_counter_ns"] < anchors[1]["perf_counter_ns"]
+    ring = {e["name"] for e in doc["traceEvents"]}
+    assert {"engine.admit", "engine.decode.dispatch",
+            "engine.harvest.wait"} <= ring
+    # the same spans and anchors are in the profiler's trace, with no
+    # Python-tracer event beside them
+    from jax.profiler import ProfileData
+    import glob
+
+    (pb,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    host_events = [(ev.name, dict(ev.stats))
+                   for plane in ProfileData.from_file(pb).planes
+                   if plane.name.startswith("/host:")
+                   for line in plane.lines for ev in line.events]
+    names = {n for n, _s in host_events}
+    assert {"clock.anchor", "engine.admit", "engine.decode.dispatch",
+            "engine.harvest.wait", "pump.drain_inbox"} <= names
+    assert not any(n.startswith("$") for n in names)
+    in_trace = sorted(s["perf_counter_ns"] for n, s in host_events
+                      if n == "clock.anchor")
+    assert in_trace == [a["perf_counter_ns"] for a in anchors]
+    admit = next(s for n, s in host_events if n == "engine.admit")
+    assert admit["request_ids"] == "prof-1"

@@ -176,8 +176,9 @@ def test_greedy_exact_f32(params, base_off):
     assert m["spec_async_verify_steps"] > 0, "verify path never ran"
     # compile-count guard: the verify program buckets only on the stop
     # mask — one fixed [B, k+1] window shape, at most two programs
-    verify_programs = {p for p in eng._tl_programs
-                       if isinstance(p, tuple) and p and p[0] == "verify"}
+    assert eng.timeline.to_chrome_trace()["metadata"]["dropped_events"] == 0
+    verify_programs = {e["args"]["program"] for e in eng.timeline.events()
+                       if e["name"] == "engine.verify.dispatch"}
     assert 0 < len(verify_programs) <= 2, verify_programs
 
 

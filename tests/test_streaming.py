@@ -294,6 +294,14 @@ def test_subchunk_stream_trims_stops_identically():
         assert streamed == res.tokens       # no post-stop leakage
 
 
+def _programs(eng):
+    """Program-shape keys of the dispatches in the engine's step ring,
+    which must not have forgotten any."""
+    assert eng.timeline.to_chrome_trace()["metadata"]["dropped_events"] == 0
+    return {e["args"]["program"] for e in eng.timeline.events()
+            if "program" in e["args"]}
+
+
 def test_adaptive_chunk_compile_count_guard():
     """The streaming clamp is pow2-bucketed: a mixed streaming+batch run
     adds at most ONE new decode chunk length beyond the configured
@@ -303,7 +311,7 @@ def test_adaptive_chunk_compile_count_guard():
     # pure-batch wave first: full 4-step decode program only
     eng.generate([GenerationRequest(prompt=[1, 2], max_new_tokens=8,
                                     temperature=0.0)])
-    batch_steps = {p[1] for p in eng._tl_programs if p[0] == "decode"}
+    batch_steps = {p[1] for p in _programs(eng) if p[0] == "decode"}
     assert batch_steps == {4}
     assert eng.get_metrics()["stream_clamped_chunks"] == 0
     # streaming + batch mix: clamp engages, ONE extra length appears
@@ -313,7 +321,7 @@ def test_adaptive_chunk_compile_count_guard():
     eng.submit(GenerationRequest(prompt=[4, 5], max_new_tokens=8,
                                  temperature=0.0))
     eng.run_until_idle()
-    decode_steps = {p[1] for p in eng._tl_programs if p[0] == "decode"}
+    decode_steps = {p[1] for p in _programs(eng) if p[0] == "decode"}
     assert decode_steps == {4, 1}, \
         "clamp must add exactly one pow2 decode length"
     assert eng.get_metrics()["stream_clamped_chunks"] >= 1
