@@ -402,7 +402,8 @@ def describe_worker(report: Dict[str, Any], preset: Dict[str, Any],
     say(f"  {label}: model={preset['size']} quant=int4 "
         f"batch={preset['max_batch_size']} max_seq_len="
         f"{preset['max_seq_len']} param_bytes={place['param_bytes']} "
-        f"int4_paths={place['int4_paths']}")
+        f"int4_paths={place['int4_paths']} "
+        f"decode_attention={place.get('decode_attention')}")
     say(f"  {label}: set-up load {setup['load_s']:.1f}s of which warm-up "
         f"compile {setup['warmup_s']:.1f}s (information)")
     check(dev["platform"] == preset["platform"],
@@ -415,7 +416,32 @@ def describe_worker(report: Dict[str, Any], preset: Dict[str, Any],
         check(paths["xla"] == 0 and paths["direct"] + paths["cp"] > 0,
               f"{label}: every int4 matmul rides the Mosaic kernel "
               f"(paths {paths})")
+    # attention_impl "auto" as the engine resolved it: on the chip, with
+    # the pool on one device, decode attention reads K/V in place from
+    # the pages (the flash-decode kernel); on the CPU and over a mesh the
+    # dense XLA path. A fallback to the dense path on the chip fails here.
+    one_device = len(place["device_ids"]) == 1
+    want = ("pallas-decode" if dev["platform"] == "tpu" and one_device
+            else "xla")
+    check(place.get("decode_attention") == want,
+          f"{label}: decode attention resolved to {want!r} "
+          f"(reports {place.get('decode_attention')!r})")
     return dev
+
+
+def check_decode_path(report: Dict[str, Any], label: str) -> None:
+    """The decode chunks the engine dispatched, by how attention reached
+    the context: all of them on the path the worker reported."""
+    m = report["metrics"]["models"][MODEL]
+    in_place, dense = m["decode_chunks_in_place"], m["decode_chunks_dense"]
+    say(f"  {label}: decode chunks in place {in_place}, dense {dense} "
+        f"(attn_impl {m['attn_impl']})")
+    if m["attn_impl"].startswith("pallas"):
+        check(in_place > 0 and dense == 0,
+              f"{label}: every decode chunk read K/V in place")
+    else:
+        check(dense > 0 and in_place == 0,
+              f"{label}: every decode chunk ran the dense XLA path")
 
 
 def decoded(report: Dict[str, Any]) -> int:
@@ -517,6 +543,7 @@ def leg_server(children: Children, preset: Dict[str, Any],
 
     after = asyncio.run(worker_report(wport))
     check_no_errors(after, "w0")
+    check_decode_path(after, "w0")
     served = decoded(after) - decoded(before)
     check(served == 2 * n + 3,
           f"w0 engine decoded {served} requests since warm-up (two batches, "

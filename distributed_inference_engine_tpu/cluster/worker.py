@@ -203,7 +203,9 @@ def _engine_placement(engine) -> Dict[str, Any]:
             "coords": [coords[i] for i in ids],
             "param_bytes": param_bytes(params),
             "param_bytes_by_device": {str(i): by_device[i] for i in ids},
-            "int4_paths": int4_kernel_paths(params)}
+            "int4_paths": int4_kernel_paths(params),
+            # the attention path the engine resolved "auto" to
+            "decode_attention": getattr(engine, "attn_impl", None)}
 
 
 # --------------------------------------------------------------------------

@@ -96,6 +96,13 @@ class EngineConfig:
     decode_steps_per_call: int = 8     # tokens generated per jit dispatch (lax.scan)
     use_paged_kv: bool = False
     attention_impl: str = "auto"       # "auto" | "xla" | "pallas" |
+    # "auto" resolves from what the engine can observe
+    # (engine.continuous.resolve_attention_impl): on a TPU backend, with
+    # decode_mode "window", no sliding window, an unsharded pool and a
+    # fused Hkv*Dh that is a multiple of 128 lanes -> "pallas-decode"
+    # (K/V read in place from the page pool); anything else -> "xla".
+    # The resolved string is get_metrics()["attn_impl"] and the worker's
+    # device report (models.<name>.decode_attention).
     # "pallas-decode" (fused flash-decode kernel: paged prefix + side
     # window in ONE pallas_call per layer, ops/flash_decode.py) |
     # "pallas-decode-fw" (same + fresh-KV side writeback in the kernel
@@ -116,11 +123,14 @@ class EngineConfig:
                                        # layers keep their Mosaic kernels
                                        # (dequant already fused there).
     decode_mode: str = "window"        # continuous engine: "window" freezes
-                                       # the page pools per chunk, gathers
-                                       # the live prefix ONCE into a dense
-                                       # working buffer, and decodes the
-                                       # whole chunk against it in place
-                                       # (fastest at 8B scale: 3623 tok/s
+                                       # the page pools per chunk; fresh
+                                       # K/V goes to a side window (kernel
+                                       # attention paths, which read the
+                                       # prefix in place from the pages)
+                                       # or the live prefix is gathered
+                                       # ONCE into a dense working buffer
+                                       # and the chunk decodes against it
+                                       # (attention_impl "xla": 3623 tok/s
                                        # bs64 r3, vs 1038 for per-step page
                                        # scatter); "inline" scatters fresh
                                        # KV into the pages per step (faster

@@ -899,7 +899,10 @@ def forward_decode_window(
 
     impl = attn_impl
     if impl == "auto":
-        impl = "xla"     # measured fastest (see ops.paged_attention)
+        # the engine resolves "auto" from backend, spec and decode mode
+        # (engine.continuous.resolve_attention_impl); a direct caller
+        # gets the reference composition
+        impl = "xla"
     # fused flash-decode (ops.flash_decode): ONE kernel per layer streams
     # the paged prefix, folds the side window into the same online-softmax
     # accumulators, and skips the separate window/merge fusions. The "-fw"
@@ -908,6 +911,12 @@ def forward_decode_window(
     fd = impl.startswith("pallas-decode")
     fd_fw = impl.startswith("pallas-decode-fw")
     fd_interpret = impl.endswith("_interpret")
+    if fd and not fd_fw:
+        # a row that is not live (never admitted, or finished earlier in
+        # this chunk) has its output discarded: the kernel gets length 0
+        # for it and moves none of its pages
+        live_prefix = jnp.where(active, start_lengths, 0)
+        live_side = jnp.where(active, n_side, 0)
     if impl.startswith("pallas"):
         # stacked view: the kernel indexes pages as layer·N + table[i, p],
         # so the scan hands it the WHOLE pool — slicing a layer out per
@@ -924,8 +933,9 @@ def forward_decode_window(
         blk = rebuild(xs_blk, l)
         q, k, v = _qkv_norm(spec, blk, x, positions,
                             fused=fused)             # k,v: [B, 1, Hkv, Dh]
-        sk = lax.dynamic_index_in_dim(side_k, l, 0, keepdims=False)
-        sv = lax.dynamic_index_in_dim(side_v, l, 0, keepdims=False)
+        with jax.named_scope("attn.kv_gather"):
+            sk = lax.dynamic_index_in_dim(side_k, l, 0, keepdims=False)
+            sv = lax.dynamic_index_in_dim(side_v, l, 0, keepdims=False)
         if fd_fw:
             # fresh K/V goes in as its own operand; the kernel attends to
             # it and DMAs it into the aliased side row in its epilogue
@@ -936,13 +946,14 @@ def forward_decode_window(
                 layer=l, n_pages_per_layer=n_pages,
             )
         else:
-            sk = jnp.where(onehot[:, :, None, None], k[:, 0][:, None], sk)
-            sv = jnp.where(onehot[:, :, None, None], v[:, 0][:, None], sv)
+            with jax.named_scope("attn.kv_update"):
+                sk = jnp.where(onehot[:, :, None, None], k[:, 0][:, None], sk)
+                sv = jnp.where(onehot[:, :, None, None], v[:, 0][:, None], sv)
             if fd:
                 attn = flash_decode_attention(
-                    q[:, 0], kp_flat, vp_flat, page_table, start_lengths,
-                    sk, sv, n_side, n_kv_heads=spec.n_kv_heads, impl=impl,
-                    layer=l, n_pages_per_layer=n_pages,
+                    q[:, 0], kp_flat, vp_flat, page_table, live_prefix,
+                    sk, sv, live_side, n_kv_heads=spec.n_kv_heads,
+                    impl=impl, layer=l, n_pages_per_layer=n_pages,
                 )
             else:
                 if impl.startswith("pallas"):
@@ -963,8 +974,9 @@ def forward_decode_window(
                     )
                 window_part = window_decode_attention(q[:, 0], sk, sv, n_side)
                 attn = merge_attention([prefix, window_part], dtype=q.dtype)
-        side_k = lax.dynamic_update_index_in_dim(side_k, sk, l, 0)
-        side_v = lax.dynamic_update_index_in_dim(side_v, sv, l, 0)
+        with jax.named_scope("attn.kv_update"):
+            side_k = lax.dynamic_update_index_in_dim(side_k, sk, l, 0)
+            side_v = lax.dynamic_update_index_in_dim(side_v, sv, l, 0)
         x = _out_residual(spec, blk, attn[:, None], x, fused=fused)
         x, _ = _mlp_residual(spec, blk, x, fused=fused)
         return (x, side_k, side_v), None

@@ -29,12 +29,24 @@ grid steps via mutable scalar-prefetch state — are already in flight.
 This is the jax.experimental paged-attention DMA pattern grafted onto
 this repo's Mosaic idioms.
 
-Mosaic idioms (hard-won on hardware, see ops/paged_attention.py): every
-in-kernel tensor stays RANK-2 with the fused head·dim axis on lanes;
-per-head segment sums/broadcasts are matmuls against constant 0/1 ``seg``
-matrices; GQA expands K/V to query heads via STATIC lane-slice concats;
-q/out blocks carry a singleton sublane axis so trailing block dims EQUAL
-the array dims; the fused KV dim must be a multiple of 128 (TPU lanes).
+Kernel math: the standard flash layout, queries on sublanes and keys on
+lanes, two MXU matmuls per page against the page AS STORED
+(``[P, Hkv·Dh]``, fused KV lanes): the row's query is laid out
+block-diagonally over those lanes, so GQA needs no expansion of K/V, no
+transpose and no per-head loop (see "shared kernel math" below). The
+first version of this kernel expanded K/V to query heads in float32 and
+reduced per head through 0/1 segment matmuls at HIGHEST precision; it
+was written for bs64-128 and never timed. The fused KV dim must be a
+multiple of 128 (TPU lanes); query rows are padded to whole sublane
+tiles by the launcher.
+
+Only live K/V moves: a page is DMA'd iff it holds a token below its
+row's prefix length, a dead row (length 0) starts no DMA, and its side
+window is skipped. Measured on one v5e chip at the served shape (8 rows,
+32:8 heads x 128, 1-8 bf16 pages a row, PERF.md §6 PR 25): 16-24 us a
+layer, 60-72 % of the HBM peak over the live pages, against 120 us for
+the dense-context path's slice + attention + update at the 8-page
+bucket.
 
 Two kernels:
 
@@ -71,10 +83,14 @@ from .paged_attention import paged_attention_xla
 
 NEG_INF = -1e30
 
-# pages DMA'd per compute block, keyed by (page_size, fused). Populated by
-# examples/flash_decode_tune.py on hardware; unlisted shapes fall back to
-# the ~512-token-block heuristic below (4 pages at the flagship P=128).
-_TUNED_PAGES_PER_BLOCK: dict = {}
+# pages DMA'd per double-buffered block, keyed by (page_size, fused).
+# Populated by examples/flash_decode_tune.py on hardware; unlisted shapes
+# fall back to the ~512-token-block heuristic below (4 pages at the
+# flagship P=128). (128, 1024), one v5e chip, 8 rows of 1-8 bf16 pages with
+# 2/4/6/8 rows live (PERF.md §6, PR 25), us per layer: bp 1 18.3/19.7/20.7/
+# 30.4, bp 2 15.7/18.3/17.4/25.3, bp 4 15.5/17.3/17.0/24.1, bp 8 15.6/
+# 19.1/17.8/24.5.
+_TUNED_PAGES_PER_BLOCK: dict = {(128, 1024): 4}
 
 
 def _default_pages_per_block(page_size: int, fused: int, mp: int) -> int:
@@ -111,69 +127,117 @@ def flash_decode_attention_xla(
 
 
 # ------------------------------------------------------- shared kernel math
+#
+# One row's attention is two MXU matmuls per page, both in the standard
+# flash layout (queries on sublanes, keys on lanes):
+#
+#     s   = Qbd [Hp, Hkv·Dh] · K_page[P, Hkv·Dh]ᵀ  -> [Hp, P]
+#     acc += p [Hp, P] · V_page [P, Hkv·Dh]         -> [Hp, Hkv·Dh]
+#
+# ``Qbd`` is the row's query laid out block-diagonally over the fused KV
+# lanes: head h keeps its Dh values in the lane range of ITS kv head and
+# zeros elsewhere, so one matmul against the page as stored gives every
+# head's scores (GQA costs nothing: K is the MXU's stationary operand and
+# each of its tiles is loaded once whatever the group size). ``acc`` holds
+# p·V against every kv head's lanes; the epilogue keeps each head's own
+# block. Nothing is expanded, transposed or copied: a page goes from HBM
+# to VMEM once and is read there by the two matmuls. K/V stay in the pool
+# dtype (bf16 products are exact in the float32 accumulators); scores,
+# softmax and accumulators are float32.
 
 
-def _seg(H: int, dh: int):
-    """Constant 0/1 [H·Dh, H] map: X @ seg segment-sums each head's Dh
-    lanes; Y @ seg.T broadcasts per-head scalars back across lanes."""
-    lane_head = lax.broadcasted_iota(jnp.int32, (H * dh, H), 0) // dh
-    head_idx = lax.broadcasted_iota(jnp.int32, (H * dh, H), 1)
-    return (lane_head == head_idx).astype(jnp.float32)
+def _precision(dtype):
+    return (lax.Precision.HIGHEST if jnp.dtype(dtype) == jnp.float32
+            else lax.Precision.DEFAULT)
 
 
-def _expand_gqa(xf: jnp.ndarray, H: int, g: int, dh: int) -> jnp.ndarray:
-    """[S, Hkv·Dh] -> [S, H·Dh] via static lane-slice concats (a dense 0/1
-    expander matmul would cost O(S·HkvDh·HDh) MACs and a VMEM constant
-    that blows up at 8B-class GQA shapes)."""
-    if g == 1:
-        return xf
-    return jnp.concatenate(
-        [xf[:, (h // g) * dh: (h // g + 1) * dh] for h in range(H)], axis=1)
+def _block_diag_q(q, n_heads: int, n_kv_heads: int):
+    """[Hp, Dh] -> [Hp, Hkv·Dh]: head h's query in kv head h // g's lanes,
+    zeros elsewhere (rows past ``n_heads`` are padding: all zero)."""
+    hp, dh = q.shape
+    fused = n_kv_heads * dh
+    g = n_heads // n_kv_heads
+    qt = jnp.concatenate([q] * n_kv_heads, axis=1)
+    row_kv = lax.broadcasted_iota(jnp.int32, (hp, fused), 0) // g
+    lane_kv = lax.broadcasted_iota(jnp.int32, (hp, fused), 1) // dh
+    return jnp.where(row_kv == lane_kv, qt, jnp.zeros_like(qt))
 
 
-def _flash_block(qf, kf, vf, valid, seg, m_scr, l_scr, acc_scr, scale):
+def _init_acc(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _attend(qbd, k, v, first_tok, n_valid, m_scr, l_scr, acc_scr, scale):
     """One online-softmax update over a key block.
 
-    qf [1, H·Dh] f32, kf/vf [S, H·Dh] f32 (GQA-expanded), valid [S, H]
-    bool. Invalid probs are explicitly zeroed (not just NEG_INF-masked):
-    a block may be ENTIRELY masked (empty side window, fresh prefix), and
-    with m still at NEG_INF exp(NEG_INF - NEG_INF) = 1 would sum stale
-    buffer contents into the accumulator.
+    qbd [Hp, F], k/v [S, F] in the pool/side dtype; key j of the block is
+    valid iff ``first_tok + j < n_valid``. Invalid probs are explicitly
+    zeroed (not just NEG_INF-masked): a block may be ENTIRELY masked
+    (empty side window), and with m still at NEG_INF
+    exp(NEG_INF - NEG_INF) = 1 would sum stale buffer contents into the
+    accumulator.
     """
-    prod = kf * qf                                            # [S, H*Dh]
-    scores = jnp.dot(prod, seg,                               # [S, H]
+    # fp8 pools have no promotion path: they upcast to the query dtype
+    cdt = (qbd.dtype if jnp.dtype(k.dtype).itemsize < 2
+           else jnp.promote_types(qbd.dtype, k.dtype))
+    qbd, k, v = qbd.astype(cdt), k.astype(cdt), v.astype(cdt)
+    one_key = k.shape[0] == 1          # a matmul with N = 1 has no MXU form
+    if one_key:
+        s = (qbd.astype(jnp.float32) * k.astype(jnp.float32)).sum(
+            axis=1, keepdims=True) * scale
+    else:
+        s = lax.dot_general(
+            qbd, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=_precision(cdt)) * scale                # [Hp, S]
+    tok = first_tok + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    valid = tok < n_valid
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_scr[...]                                       # [Hp, 1]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+    l_scr[...] = l_scr[...] * alpha + p.sum(axis=1, keepdims=True)
+    if one_key:
+        pv = p.astype(cdt).astype(jnp.float32) * v.astype(jnp.float32)
+    else:
+        pv = jnp.dot(p.astype(cdt), v,
                      preferred_element_type=jnp.float32,
-                     precision=lax.Precision.HIGHEST) * scale
-    scores = jnp.where(valid, scores, NEG_INF)
-    m_prev = m_scr[:]                                         # [1, H]
-    l_prev = l_scr[:]
-    m_new = jnp.maximum(m_prev, scores.max(axis=0, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)                           # [1, H]
-    probs = jnp.exp(scores - m_new[0][None, :])               # [S, H]
-    probs = jnp.where(valid, probs, 0.0)
-    l_new = l_prev * alpha + probs.sum(axis=0, keepdims=True)
-    pe = jnp.dot(probs, seg.T,                                # [S, H*Dh]
-                 preferred_element_type=jnp.float32,
-                 precision=lax.Precision.HIGHEST)
-    pv = (pe * vf).sum(axis=0, keepdims=True)                 # [1, H*Dh]
-    alpha_e = jnp.dot(alpha, seg.T,
-                      preferred_element_type=jnp.float32,
-                      precision=lax.Precision.HIGHEST)
-    acc_scr[:] = acc_scr[:] * alpha_e + pv
-    m_scr[:] = m_new
-    l_scr[:] = l_new
+                     precision=_precision(cdt))               # [Hp, F]
+    acc_scr[...] = acc_scr[...] * alpha + pv
+    m_scr[...] = m_new
+
+
+def _finish(out_ref, l_scr, acc_scr, *, g, dh, n_kv_heads):
+    """Each head's own kv block of the accumulator, normalised."""
+    acc = acc_scr[...]
+    hp = acc.shape[0]
+    row_kv = lax.broadcasted_iota(jnp.int32, (hp, dh), 0) // g
+    out = jnp.zeros((hp, dh), jnp.float32)
+    for j in range(n_kv_heads):
+        out = out + jnp.where(row_kv == j, acc[:, j * dh:(j + 1) * dh], 0.0)
+    out = out / jnp.maximum(l_scr[...], 1e-30)
+    out_ref[0] = out.astype(out_ref.dtype)
 
 
 def _prefix_loop(
     b, page_table_ref, prefix_lens_ref, next_live_ref, layer_ref,
-    buffer_index_ref, step_ref, qf, k_pages_hbm, v_pages_hbm, k_vmem,
-    v_vmem, sem, seg, m_scr, l_scr, acc_scr,
-    *, bp, page_size, fused, n_pages_per_layer, H, g, dh, scale,
+    buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
+    v_vmem, sem, m_scr, l_scr, acc_scr,
+    *, bp, page_size, n_pages_per_layer, scale,
 ):
     """Flash loop over row ``b``'s live prefix pages: ``bp`` pages per
     block, double-buffered manual DMA, next block (possibly the first
     block of the NEXT live row — the cross-grid-step prefetch that hides
     the per-row pipeline bubble) issued before waiting on the current.
+
+    Only LIVE pages move: a page is copied (and waited for, and attended)
+    iff it holds a token below the row's prefix length, so a row shorter
+    than a block pays for its own pages only and a dead row (length 0)
+    starts no DMA at all. Each buffer slot has its own DMA semaphore: the
+    other slot's prefetch is in flight while this one is waited on.
 
     ``next_live_ref[b]`` holds the next row after ``b`` with a non-empty
     prefix (or B): rows that never enter this loop must not be prefetched
@@ -182,25 +246,26 @@ def _prefix_loop(
     an in-kernel while_loop over the lengths ref also defeats the
     interpret-mode state discharge the parity tests run under."""
     batch = pl.num_programs(0)
-    mp = page_table_ref.shape[1]
     blk_tokens = bp * page_size
     base = layer_ref[0] * n_pages_per_layer
 
-    def issue(row, blk, slot):
-        for j in range(bp):
-            col = jnp.minimum(blk * bp + j, mp - 1)
-            page = base + page_table_ref[row, col]
-            pltpu.make_async_copy(
-                k_pages_hbm.at[page], k_vmem.at[slot, j], sem).start()
-            pltpu.make_async_copy(
-                v_pages_hbm.at[page], v_vmem.at[slot, j], sem).start()
+    def copies(row, blk, slot, j):
+        page = base + page_table_ref[row, blk * bp + j]
+        return (pltpu.make_async_copy(k_pages_hbm.at[page],
+                                      k_vmem.at[slot, j], sem.at[slot]),
+                pltpu.make_async_copy(v_pages_hbm.at[page],
+                                      v_vmem.at[slot, j], sem.at[slot]))
 
-    def wait(slot):
+    def for_live_pages(row, blk, fn):
+        n_live = lax.div(prefix_lens_ref[row] + page_size - 1, page_size)
         for j in range(bp):
-            pltpu.make_async_copy(
-                k_pages_hbm.at[0], k_vmem.at[slot, j], sem).wait()
-            pltpu.make_async_copy(
-                v_pages_hbm.at[0], v_vmem.at[slot, j], sem).wait()
+            pl.when(blk * bp + j < n_live)(functools.partial(fn, j))
+
+    def issue(row, blk, slot):
+        def go(j):
+            for c in copies(row, blk, slot, j):
+                c.start()
+        for_live_pages(row, blk, go)
 
     length = prefix_lens_ref[b]
     nblk = lax.div(length + blk_tokens - 1, blk_tokens)
@@ -220,15 +285,14 @@ def _prefix_loop(
         def _prefetch():
             issue(nb, ni, 1 - slot)
 
-        wait(slot)
-        kf = k_vmem[slot].reshape(blk_tokens, fused).astype(jnp.float32)
-        vf = v_vmem[slot].reshape(blk_tokens, fused).astype(jnp.float32)
-        kf = _expand_gqa(kf, H, g, dh)
-        vf = _expand_gqa(vf, H, g, dh)
-        tok = i * blk_tokens + lax.broadcasted_iota(
-            jnp.int32, (blk_tokens, H), 0)
-        valid = tok < length
-        _flash_block(qf, kf, vf, valid, seg, m_scr, l_scr, acc_scr, scale)
+        def page(j):
+            for c in copies(b, i, slot, j):
+                c.wait()
+            _attend(qbd, k_vmem[slot, j], v_vmem[slot, j],
+                    (i * bp + j) * page_size, length,
+                    m_scr, l_scr, acc_scr, scale)
+
+        for_live_pages(b, i, page)
         buffer_index_ref[0] = 1 - slot
         step_ref[0] = step_ref[0] + 1
         return ()
@@ -249,20 +313,20 @@ def _flash_decode_kernel(
     buffer_index_ref,          # [1] MUTABLE: double-buffer slot
     step_ref,                  # [1] MUTABLE: global processed-block count
     # inputs
-    q_ref,                     # [1, 1, H*Dh] VMEM (auto-pipelined)
+    q_ref,                     # [1, Hp, Dh] VMEM (auto-pipelined)
     side_k_ref,                # [1, W, Hkv*Dh] VMEM (auto-pipelined)
     side_v_ref,
     k_pages_hbm,               # [L*N, P, Hkv*Dh] ANY (stays in HBM)
     v_pages_hbm,
     # outputs
-    out_ref,                   # [1, 1, H*Dh] VMEM
+    out_ref,                   # [1, Hp, Dh] VMEM
     # scratch
     k_vmem,                    # [2, bp, P, Hkv*Dh] double-buffered blocks
     v_vmem,
-    m_scr,                     # [1, H] f32 running max
-    l_scr,                     # [1, H] f32 running denominator
-    acc_scr,                   # [1, H*Dh] f32 running numerator
-    sem,                       # DMA semaphore
+    m_scr,                     # [Hp, 1] f32 running max
+    l_scr,                     # [Hp, 1] f32 running denominator
+    acc_scr,                   # [Hp, Hkv*Dh] f32 running numerator
+    sem,                       # DMA semaphores, one per buffer slot
     *,
     n_kv_heads: int,
     head_dim: int,
@@ -272,36 +336,29 @@ def _flash_decode_kernel(
     n_pages_per_layer: int,
 ):
     b = pl.program_id(0)
-    H, dh, g = n_heads, head_dim, n_heads // n_kv_heads
-    fused = n_kv_heads * dh
+    dh, g = head_dim, n_heads // n_kv_heads
     scale = 1.0 / (dh ** 0.5)
-    seg = _seg(H, dh)
 
-    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
-    qf = q_ref[0, 0, :].astype(jnp.float32)[None, :]          # [1, H*Dh]
+    _init_acc(m_scr, l_scr, acc_scr)
+    qbd = _block_diag_q(q_ref[0], n_heads, n_kv_heads)        # [Hp, F]
 
     _prefix_loop(
         b, page_table_ref, prefix_lens_ref, next_live_ref, layer_ref,
-        buffer_index_ref, step_ref, qf, k_pages_hbm, v_pages_hbm, k_vmem,
-        v_vmem, sem, seg, m_scr, l_scr, acc_scr,
-        bp=pages_per_block, page_size=page_size, fused=fused,
-        n_pages_per_layer=n_pages_per_layer, H=H, g=g, dh=dh, scale=scale)
+        buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
+        v_vmem, sem, m_scr, l_scr, acc_scr,
+        bp=pages_per_block, page_size=page_size,
+        n_pages_per_layer=n_pages_per_layer, scale=scale)
 
     # final block: the chunk side window (auto-pipelined into VMEM — its
     # DMA overlaps the previous grid step's compute)
-    w = side_k_ref.shape[1]
-    kf = _expand_gqa(side_k_ref[0].astype(jnp.float32), H, g, dh)
-    vf = _expand_gqa(side_v_ref[0].astype(jnp.float32), H, g, dh)
-    col = lax.broadcasted_iota(jnp.int32, (w, H), 0)
-    _flash_block(qf, kf, vf, col < n_side_ref[b], seg,
-                 m_scr, l_scr, acc_scr, scale)
+    n_side = n_side_ref[b]
 
-    le = jnp.dot(jnp.maximum(l_scr[:], 1e-30), seg.T,
-                 preferred_element_type=jnp.float32,
-                 precision=lax.Precision.HIGHEST)
-    out_ref[:] = (acc_scr[:] / le).reshape(1, 1, H * dh).astype(out_ref.dtype)
+    @pl.when(n_side > 0)
+    def _side():
+        _attend(qbd, side_k_ref[0], side_v_ref[0], 0, n_side,
+                m_scr, l_scr, acc_scr, scale)
+
+    _finish(out_ref, l_scr, acc_scr, g=g, dh=dh, n_kv_heads=n_kv_heads)
 
 
 # ------------------------------------- kernel: fused side-write epilogue
@@ -318,7 +375,7 @@ def _flash_decode_fw_kernel(
     buffer_index_ref,          # [1] MUTABLE
     step_ref,                  # [1] MUTABLE
     # inputs
-    q_ref,                     # [1, 1, H*Dh] VMEM
+    q_ref,                     # [1, Hp, Dh] VMEM
     fresh_k_ref,               # [1, 1, Hkv*Dh] VMEM: this step's K
     fresh_v_ref,
     k_pages_hbm,               # [L*N, P, Hkv*Dh] ANY
@@ -326,7 +383,7 @@ def _flash_decode_fw_kernel(
     side_k_in,                 # [B, W, Hkv*Dh] ANY (aliased to outputs;
     side_v_in,                 #   unused — all access via the out refs)
     # outputs
-    out_ref,                   # [1, 1, H*Dh] VMEM
+    out_ref,                   # [1, Hp, Dh] VMEM
     side_k_out,                # [B, W, Hkv*Dh] ANY, aliased to side_k_in
     side_v_out,
     # scratch
@@ -346,28 +403,24 @@ def _flash_decode_fw_kernel(
     n_pages_per_layer: int,
 ):
     b = pl.program_id(0)
-    H, dh, g = n_heads, head_dim, n_heads // n_kv_heads
-    fused = n_kv_heads * dh
+    dh, g = head_dim, n_heads // n_kv_heads
     w = side_k_vmem.shape[0]
     scale = 1.0 / (dh ** 0.5)
-    seg = _seg(H, dh)
 
     # side row read starts NOW so it rides under the whole prefix loop
     # (aliased buffers: reads go through the out refs — same memory)
     pltpu.make_async_copy(side_k_out.at[b], side_k_vmem, side_sem).start()
     pltpu.make_async_copy(side_v_out.at[b], side_v_vmem, side_sem).start()
 
-    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
-    qf = q_ref[0, 0, :].astype(jnp.float32)[None, :]
+    _init_acc(m_scr, l_scr, acc_scr)
+    qbd = _block_diag_q(q_ref[0], n_heads, n_kv_heads)
 
     _prefix_loop(
         b, page_table_ref, prefix_lens_ref, next_live_ref, layer_ref,
-        buffer_index_ref, step_ref, qf, k_pages_hbm, v_pages_hbm, k_vmem,
-        v_vmem, sem, seg, m_scr, l_scr, acc_scr,
-        bp=pages_per_block, page_size=page_size, fused=fused,
-        n_pages_per_layer=n_pages_per_layer, H=H, g=g, dh=dh, scale=scale)
+        buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
+        v_vmem, sem, m_scr, l_scr, acc_scr,
+        bp=pages_per_block, page_size=page_size,
+        n_pages_per_layer=n_pages_per_layer, scale=scale)
 
     pltpu.make_async_copy(side_k_out.at[b], side_k_vmem, side_sem).wait()
     pltpu.make_async_copy(side_v_out.at[b], side_v_vmem, side_sem).wait()
@@ -387,22 +440,13 @@ def _flash_decode_fw_kernel(
             fresh_v_ref.at[0, 0], side_v_out.at[b, i_side], side_sem).start()
 
     # side window: entries BEFORE this step's column are valid
-    kf = _expand_gqa(side_k_vmem[:].astype(jnp.float32), H, g, dh)
-    vf = _expand_gqa(side_v_vmem[:].astype(jnp.float32), H, g, dh)
-    col = lax.broadcasted_iota(jnp.int32, (w, H), 0)
-    _flash_block(qf, kf, vf, col < jnp.minimum(i_side, w), seg,
-                 m_scr, l_scr, acc_scr, scale)
-
+    _attend(qbd, side_k_vmem[...], side_v_vmem[...], 0,
+            jnp.minimum(i_side, w), m_scr, l_scr, acc_scr, scale)
     # this step's token as one extra key (it never reached the buffers)
-    kf1 = _expand_gqa(fresh_k_ref[0].astype(jnp.float32), H, g, dh)
-    vf1 = _expand_gqa(fresh_v_ref[0].astype(jnp.float32), H, g, dh)
-    valid1 = jnp.broadcast_to(act > 0, (1, H))
-    _flash_block(qf, kf1, vf1, valid1, seg, m_scr, l_scr, acc_scr, scale)
+    _attend(qbd, fresh_k_ref[0], fresh_v_ref[0], 0, act,
+            m_scr, l_scr, acc_scr, scale)
 
-    le = jnp.dot(jnp.maximum(l_scr[:], 1e-30), seg.T,
-                 preferred_element_type=jnp.float32,
-                 precision=lax.Precision.HIGHEST)
-    out_ref[:] = (acc_scr[:] / le).reshape(1, 1, H * dh).astype(out_ref.dtype)
+    _finish(out_ref, l_scr, acc_scr, g=g, dh=dh, n_kv_heads=n_kv_heads)
 
     @pl.when(do_write)
     def _drain():
@@ -448,6 +492,50 @@ def _next_live(prefix_lens: jnp.ndarray) -> jnp.ndarray:
         [sufmin[1:], jnp.full((1,), batch, jnp.int32)])
 
 
+def _pad_heads(q: jnp.ndarray) -> jnp.ndarray:
+    """Query rows padded to a whole number of sublane tiles (8 rows of 32
+    bits, 16 of 16): the padding rows are zero and sliced off the output."""
+    tile = 8 * max(1, 4 // q.dtype.itemsize)
+    pad = -q.shape[1] % tile
+    return jnp.pad(q, ((0, 0), (0, pad), (0, 0))) if pad else q
+
+
+def _scratch(hp, fused, bp, page_size, dtype):
+    return [
+        pltpu.VMEM((2, bp, page_size, fused), dtype),
+        pltpu.VMEM((2, bp, page_size, fused), dtype),
+    ], [
+        pltpu.VMEM((hp, 1), jnp.float32),
+        pltpu.VMEM((hp, 1), jnp.float32),
+        pltpu.VMEM((hp, fused), jnp.float32),
+    ]
+
+
+# The HLO name of the kernel's op. perfbench/lib/tracered.py classes device
+# ops by that name: "custom_call" puts this kernel's seconds under
+# ``other_kernels`` (a functools.partial kernel is otherwise named after
+# its caller, e.g. ``closed_call.13``, and lands in ``other``), and a name
+# with "int4" in it would be counted as the weight kernel.
+_OP_NAME = "flash_decode_custom_call"
+
+
+def _compiler_params(bp, page_size, fused, itemsize):
+    # the grid walks rows sequentially on purpose: the double-buffer/step
+    # state crosses grid steps (cross-row prefetch)
+    blocks = 4 * bp * page_size * fused * itemsize
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=min(blocks + (24 << 20), 100 << 20))
+
+
+def _cost(b, h, dh, mp, page_size, w, fused, kv_itemsize, side_itemsize):
+    return pl.CostEstimate(
+        flops=4 * b * (mp * page_size + w) * h * dh,
+        bytes_accessed=(b * mp * page_size * fused * kv_itemsize * 2
+                        + b * w * fused * side_itemsize * 2),
+        transcendentals=b * (mp * page_size + w) * h)
+
+
 def flash_decode_attention_pallas(
     q: jnp.ndarray,            # [B, H, Dh]
     k_pages: jnp.ndarray,      # [N, P, fused] or stacked [L*N, P, fused]
@@ -472,26 +560,24 @@ def flash_decode_attention_pallas(
     w = side_k.shape[1]
     bp = pages_per_block or _default_pages_per_block(page_size, fused, mp)
     bp = min(bp, mp)
+    qp = _pad_heads(q)
+    hp = qp.shape[1]
+    kv_scratch, acc_scratch = _scratch(hp, fused, bp, page_size,
+                                       k_pages.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, h * dh), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, w, fused), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, w, fused), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, h * dh), lambda i, *_: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, bp, page_size, fused), k_pages.dtype),
-            pltpu.VMEM((2, bp, page_size, fused), v_pages.dtype),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h * dh), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-        ],
+        out_specs=pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=kv_scratch + acc_scratch + [
+            pltpu.SemaphoreType.DMA((2,))],
     )
     kernel = functools.partial(
         _flash_decode_kernel,
@@ -501,25 +587,19 @@ def flash_decode_attention_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, h * dh), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            # the grid walks rows sequentially on purpose: the double-
-            # buffer/step state crosses grid steps (cross-row prefetch)
-            dimension_semantics=("arbitrary",)),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * b * (mp * page_size + w) * h * dh,
-            bytes_accessed=(b * mp * page_size * fused
-                            * k_pages.dtype.itemsize * 2
-                            + b * w * fused * side_k.dtype.itemsize * 2),
-            transcendentals=b * (mp * page_size + w) * h),
+        out_shape=jax.ShapeDtypeStruct((b, hp, dh), q.dtype),
+        compiler_params=_compiler_params(bp, page_size, fused,
+                                         k_pages.dtype.itemsize),
+        cost_estimate=_cost(b, h, dh, mp, page_size, w, fused,
+                            k_pages.dtype.itemsize, side_k.dtype.itemsize),
         interpret=interpret,
+        name=_OP_NAME,
     )(page_table, prefix_lens, _next_live(prefix_lens), n_side,
       _layer_scalar(layer),
       jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-      q.reshape(b, 1, h * dh),
-      side_k.reshape(b, w, fused), side_v.reshape(b, w, fused),
+      qp, side_k.reshape(b, w, fused), side_v.reshape(b, w, fused),
       k_pages, v_pages)
-    return out.reshape(b, h, dh)
+    return out[:, :h]
 
 
 def flash_decode_attention_fw_pallas(
@@ -555,12 +635,16 @@ def flash_decode_attention_fw_pallas(
     sv = side_v.reshape(b, w, fused)
     fk = fresh_k.reshape(b, 1, fused).astype(sk.dtype)
     fv = fresh_v.reshape(b, 1, fused).astype(sv.dtype)
+    qp = _pad_heads(q)
+    hp = qp.shape[1]
+    kv_scratch, acc_scratch = _scratch(hp, fused, bp, page_size,
+                                       k_pages.dtype)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, h * dh), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, 1, fused), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec((1, 1, fused), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -569,19 +653,15 @@ def flash_decode_attention_fw_pallas(
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, h * dh), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((2, bp, page_size, fused), k_pages.dtype),
-            pltpu.VMEM((2, bp, page_size, fused), v_pages.dtype),
+        scratch_shapes=kv_scratch + [
             pltpu.VMEM((w, fused), sk.dtype),
             pltpu.VMEM((w, fused), sv.dtype),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h * dh), jnp.float32),
-            pltpu.SemaphoreType.DMA,
+        ] + acc_scratch + [
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA,
         ],
     )
@@ -593,27 +673,24 @@ def flash_decode_attention_fw_pallas(
     out, sk_new, sv_new = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, 1, h * dh), q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((b, hp, dh), q.dtype),
                    jax.ShapeDtypeStruct((b, w, fused), sk.dtype),
                    jax.ShapeDtypeStruct((b, w, fused), sv.dtype)],
         # aliasing indices COUNT the 8 scalar-prefetch operands (probed on
         # this jax version): side_k/side_v are call args 13/14
         input_output_aliases={13: 1, 14: 2},
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        cost_estimate=pl.CostEstimate(
-            flops=4 * b * (mp * page_size + w) * h * dh,
-            bytes_accessed=(b * mp * page_size * fused
-                            * k_pages.dtype.itemsize * 2
-                            + b * w * fused * sk.dtype.itemsize * 2),
-            transcendentals=b * (mp * page_size + w) * h),
+        compiler_params=_compiler_params(bp, page_size, fused,
+                                         k_pages.dtype.itemsize),
+        cost_estimate=_cost(b, h, dh, mp, page_size, w, fused,
+                            k_pages.dtype.itemsize, sk.dtype.itemsize),
         interpret=interpret,
+        name=_OP_NAME + "_fw",
     )(page_table, prefix_lens, _next_live(prefix_lens),
       jnp.asarray(side_idx, jnp.int32),
       jnp.asarray(active, jnp.int32), _layer_scalar(layer),
       jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-      q.reshape(b, 1, h * dh), fk, fv, k_pages, v_pages, sk, sv)
-    return (out.reshape(b, h, dh),
+      qp, fk, fv, k_pages, v_pages, sk, sv)
+    return (out[:, :h],
             sk_new.reshape(side_shape), sv_new.reshape(side_shape))
 
 

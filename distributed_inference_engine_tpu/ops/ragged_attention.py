@@ -70,8 +70,15 @@ from .flash_decode import (
     _default_pages_per_block,
     _layer_scalar,
     _next_live,
-    _seg,
 )
+
+def _seg(H: int, dh: int):
+    """Constant 0/1 [H·Dh, H] map: X @ seg segment-sums each head's Dh
+    lanes; Y @ seg.T broadcasts per-head scalars back across lanes."""
+    lane_head = lax.broadcasted_iota(jnp.int32, (H * dh, H), 0) // dh
+    head_idx = lax.broadcasted_iota(jnp.int32, (H * dh, H), 1)
+    return (lane_head == head_idx).astype(jnp.float32)
+
 
 __all__ = [
     "ragged_attention",

@@ -206,6 +206,40 @@ def check_flash_decode(cfg, interpret):
     return f"max|err| {_close(got, ref, 2e-2):.2e}"
 
 
+def check_flash_decode_served(cfg, interpret):
+    """The served cells' row shape (8 rows, up to 8 pages a row, layer 1 of
+    a stacked pool): dead rows, a one-token row, a row one short of a page,
+    exactly a page, every page; each ``pages_per_block``."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        flash_decode_attention_pallas,
+        flash_decode_attention_xla,
+    )
+
+    p, w, hkv = cfg["P"], cfg["W"], cfg["Hkv"]
+    served = dict(cfg, B=8, ctx=8 * p)
+    q, kp, vp, pt, ks, n = _paged_inputs(served)
+    sk, sv, _ = _side_inputs(served, ks)
+    kp2, vp2 = jnp.concatenate([vp, kp]), jnp.concatenate([kp, vp])
+    plen = jnp.array([0, 1, p - 1, 0, p, 8 * p - w, 0, 3 * p + 5], jnp.int32)
+    n_side = jnp.array([0, 1, w, 0, 2, w, 0, 5], jnp.int32)
+    ref = flash_decode_attention_xla(q, kp, vp, pt, plen, sk, sv, n_side,
+                                     n_kv_heads=hkv)
+    errs = []
+    for bp in (0, 1, 2, 8):
+        got = jax.jit(lambda *a: flash_decode_attention_pallas(
+            *a, n_kv_heads=hkv, interpret=interpret, layer=1,
+            n_pages_per_layer=n, pages_per_block=bp))(
+                q, kp2, vp2, pt, plen, sk, sv, n_side)
+        errs.append(_close(got, ref, 2e-2))
+        dead = (plen + n_side) == 0
+        assert not bool(jnp.any(jnp.where(dead[:, None, None], got, 0))), \
+            "a dead row's output is not zero"
+    return f"4 block sizes, max|err| {max(errs):.2e}"
+
+
 def check_flash_decode_fw(cfg, interpret):
     import jax
     import jax.numpy as jnp
@@ -342,7 +376,8 @@ CHECKS = {
     "paged_attention_pallas": (check_paged_attention, False),
     "fused_decode_norm_matmul": (check_fused_norm_matmul, False),
     "fused_decode_matmul_residual": (check_fused_matmul_residual, False),
-    "flash_decode": (check_flash_decode, False),
+    "flash_decode": (check_flash_decode, True),
+    "flash_decode_served": (check_flash_decode_served, True),
     "flash_decode_fw": (check_flash_decode_fw, False),
     "ragged_attention": (check_ragged_attention, False),
 }

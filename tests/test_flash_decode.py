@@ -280,3 +280,213 @@ def test_engine_generate_parity_pallas_decode():
     a = {r.request_id: r.tokens for r in xla.generate(reqs())}
     b = {r.request_id: r.tokens for r in fd.generate(reqs())}
     assert a == b
+
+
+# ------------------------------------- the served row shape (B = 8, GQA 4:1)
+
+
+def _dense_truth(q, kp, vp, pt, plen, sk, sv, n_side, hkv):
+    """Ground truth independent of the paged/side split: every row's
+    prefix pages and side window laid out as ONE dense context, its live
+    keys packed at the front, through ``cached_attention``."""
+    from distributed_inference_engine_tpu.ops.attention import (
+        cached_attention)
+
+    b, h, dh = q.shape
+    p = kp.shape[1]
+    mp, w = pt.shape[1], sk.shape[1]
+    ks, vs = [], []
+    for i in range(b):
+        n = int(plen[i])
+        rows_k = kp[pt[i]].reshape(mp * p, hkv, dh)[:n]
+        rows_v = vp[pt[i]].reshape(mp * p, hkv, dh)[:n]
+        pad = jnp.zeros((mp * p - n, hkv, dh), rows_k.dtype)
+        ks.append(jnp.concatenate(
+            [rows_k, sk[i].astype(rows_k.dtype), pad], axis=0))
+        vs.append(jnp.concatenate(
+            [rows_v, sv[i].astype(rows_v.dtype), pad], axis=0))
+    out = cached_attention(q[:, None], jnp.stack(ks), jnp.stack(vs),
+                           plen + n_side)
+    return out[:, 0]
+
+
+@pytest.mark.parametrize("bp", [1, 4, 8])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+def test_served_row_shape_matches_cached_attention(bp, dtype, tol):
+    """The cells' row shape: 8 rows, four query heads a KV head, up to 8
+    pages a row; lengths 0, 1, one short of a page, exactly a page, all 8
+    pages, with dead rows (no prefix, no side entries) mixed in. Dead rows
+    give exact zeros; live rows match ``cached_attention`` over the same
+    keys laid out densely."""
+    p, mp, hkv, h, dh, w = 16, 8, 2, 8, 64, 8
+    q, kp, vp, pt, sk, sv = _inputs(
+        jax.random.key(11), b=8, h=h, hkv=hkv, dh=dh, n=80, p=p, mp=mp,
+        w=w, q_dtype=dtype, kv_dtype=dtype)
+    plen = jnp.array([0, 1, p - 1, 0, p, mp * p, 0, 3 * p + 5], jnp.int32)
+    n_side = jnp.array([0, 3, w, 0, 1, 2, 0, 5], jnp.int32)
+    out = flash_decode_attention_pallas(
+        q, kp, vp, pt, plen, sk, sv, n_side, n_kv_heads=hkv,
+        interpret=True, layer=0, n_pages_per_layer=80, pages_per_block=bp)
+    ref = _dense_truth(q, kp, vp, pt, plen, sk, sv, n_side, hkv)
+    dead = np.asarray(plen + n_side) == 0
+    got = np.asarray(out, np.float32)
+    assert not got[dead].any()
+    np.testing.assert_allclose(got[~dead], np.asarray(ref, np.float32)[~dead],
+                               rtol=tol, atol=tol)
+
+
+def test_dead_rows_and_short_rows_start_no_dma(monkeypatch):
+    """Only live pages move: the pool pages the kernel starts copies from
+    are exactly those holding a token below their row's length — none for
+    a dead row, one page for a one-token row in a 4-page block, none past
+    a row's last page. (Which pages, not how often: the interpreter
+    resets the scalar-prefetch state at each grid step, so a row's first
+    block is issued again there; on the chip the state persists.)"""
+    from jax.experimental.pallas import tpu as pltpu
+
+    started = []
+    real = pltpu.make_async_copy
+
+    class Recording:
+        def __init__(self, src, dst, sem):
+            self._page = src.transforms[-1].indices[0]
+            self._c = real(src, dst, sem)
+
+        def start(self):
+            jax.debug.callback(lambda pg: started.append(int(pg)),
+                               self._page)
+            self._c.start()
+
+        def wait(self):
+            self._c.wait()
+
+    monkeypatch.setattr(pltpu, "make_async_copy", Recording)
+    p, mp, n = 16, 8, 80
+    q, kp, vp, _, sk, sv = _inputs(jax.random.key(12), b=8, h=8, hkv=2,
+                                   dh=64, n=n, p=p, mp=mp, w=4, layers=2)
+    pt = jax.random.permutation(jax.random.key(13), n)[:8 * mp].reshape(
+        8, mp).astype(jnp.int32)
+    plen = jnp.array([0, 1, p - 1, 0, p, mp * p, 0, 3 * p + 5], jnp.int32)
+    n_side = jnp.array([0, 1, 1, 0, 1, 1, 0, 1], jnp.int32)
+    out = flash_decode_attention_pallas(
+        q, kp, vp, pt, plen, sk, sv, n_side, n_kv_heads=2, interpret=True,
+        layer=1, n_pages_per_layer=n, pages_per_block=4)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    live = {n + int(pt[i, c]) for i in range(8)
+            for c in range(-(-int(plen[i]) // p))}
+    assert len(live) == 1 + 1 + 1 + 8 + 4
+    assert set(started) == live
+
+
+# --------------------------------------------- what "auto" resolves to
+
+
+def _resolve_spec(**kw):
+    from distributed_inference_engine_tpu.models.base import ModelSpec
+
+    base = dict(vocab_size=256, d_model=256, n_layers=2, n_heads=4,
+                n_kv_heads=2, d_ff=256, max_seq_len=128)    # Hkv*Dh = 128
+    base.update(kw)
+    return ModelSpec(**base)
+
+
+@pytest.mark.parametrize("impl,backend,spec_kw,mode,sharded,want", [
+    ("auto", "tpu", {}, "window", False, "pallas-decode"),
+    ("auto", "cpu", {}, "window", False, "xla"),
+    ("auto", "gpu", {}, "window", False, "xla"),
+    ("auto", "tpu", {"sliding_window": 64}, "window", False, "xla"),
+    ("auto", "tpu", {}, "inline", False, "xla"),
+    ("auto", "tpu", {}, "window", True, "xla"),
+    # Hkv*Dh = 2 * 48 = 96 lanes: not whole 128-lane tiles
+    ("auto", "tpu", {"d_model": 192}, "window", False, "xla"),
+    ("xla", "tpu", {}, "window", False, "xla"),
+    ("pallas-decode", "cpu", {}, "window", False, "pallas-decode"),
+    ("pallas-decode_interpret", "cpu", {"sliding_window": 64}, "inline",
+     True, "pallas-decode_interpret"),
+])
+def test_auto_resolution_is_a_pure_function(impl, backend, spec_kw, mode,
+                                            sharded, want):
+    """``attention_impl="auto"`` resolves from (backend, spec, decode
+    mode, pool sharding) alone; explicit strings pass through."""
+    from distributed_inference_engine_tpu.engine.continuous import (
+        resolve_attention_impl)
+
+    spec = _resolve_spec(**spec_kw)
+    for _ in range(2):
+        assert resolve_attention_impl(impl, backend, spec, mode,
+                                      sharded=sharded) == want
+
+
+# ------------------------------------------- engine level, tier-1 sized
+
+
+def _tiny_engines(impl, **cfg_kw):
+    from distributed_inference_engine_tpu.config import EngineConfig
+    from distributed_inference_engine_tpu.engine.continuous import (
+        ContinuousEngine)
+    from distributed_inference_engine_tpu.models.base import ModelSpec
+
+    spec = ModelSpec(
+        vocab_size=128, d_model=256, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=128, max_seq_len=128, dtype="float32",
+    )                                                   # Hkv*Dh = 128 lanes
+    base = dict(max_slots=2, max_seq_len=96, prefill_buckets=[16, 64],
+                page_size=16, num_pages=12, decode_steps_per_call=4,
+                prefix_cache=False)
+    base.update(cfg_kw)
+    ref = ContinuousEngine(spec, config=EngineConfig(**base), seed=0)
+    fd = ContinuousEngine(spec, params=ref.params, config=EngineConfig(
+        attention_impl=impl, **base), seed=0)
+    return ref, fd
+
+
+def _requests(shapes):
+    from distributed_inference_engine_tpu.engine.types import (
+        GenerationRequest)
+
+    return [GenerationRequest(prompt=[3 + i] + [7, 11, 5] * (n // 3),
+                              max_new_tokens=m, temperature=0.0,
+                              request_id=f"r{i}")
+            for i, (n, m) in enumerate(shapes)]
+
+
+@pytest.mark.parametrize("impl", ["pallas-decode_interpret"])
+def test_engine_greedy_parity_through_admission_and_slot_reuse(impl):
+    """A default (``auto``) engine on the CPU runs the dense XLA path; the
+    same engine on the kernel path emits its greedy tokens exactly —
+    through batched admission, deferred first tokens (admissions while a
+    slot is live) and a slot freed and re-admitted mid-run. The counters
+    say which path each engine's decode chunks took."""
+    ref, fd = _tiny_engines(impl)
+    assert ref.attn_impl == "xla"            # auto, on the CPU backend
+    # five requests over two slots: every finish frees a slot that the
+    # next request takes while the other slot is mid-decode
+    shapes = [(4, 5), (19, 11), (7, 3), (33, 9), (16, 6)]
+    a = {r.request_id: r.tokens for r in ref.generate(_requests(shapes))}
+    b = {r.request_id: r.tokens for r in fd.generate(_requests(shapes))}
+    assert len(a) == len(shapes) and a == b
+    assert [len(a[f"r{i}"]) for i in range(5)] == [m for _, m in shapes]
+    ma, mb = ref.get_metrics(), fd.get_metrics()
+    assert ma["deferred_admissions"] > 0
+    assert mb["deferred_admissions"] == ma["deferred_admissions"]
+    assert ma["attn_impl"] == "xla" and mb["attn_impl"] == impl
+    assert ma["decode_chunks_dense"] > 0 and ma["decode_chunks_in_place"] == 0
+    assert mb["decode_chunks_in_place"] > 0 and mb["decode_chunks_dense"] == 0
+    assert mb["decode_chunks_in_place"] == ma["decode_chunks_dense"]
+
+
+@pytest.mark.parametrize("impl", ["pallas-decode_interpret"])
+def test_decode_programs_do_not_grow_with_context_bucket(impl):
+    """The dense path compiles one decode program per (steps x pow2
+    context-page bucket); the kernel path reads the pages in place, so one
+    program per ``n_steps`` serves every context length."""
+    ref, fd = _tiny_engines(impl, max_slots=1, num_pages=6)
+    # contexts of 1, 2 and 4+ pages of 16, the same chunk length each
+    shapes = [(4, 4), (22, 4), (55, 4)]
+    for eng in (ref, fd):
+        for req in _requests(shapes):
+            eng.generate([req])
+    assert ref._decode_chunk._cache_size() >= 3
+    assert fd._decode_chunk._cache_size() == 1
