@@ -126,15 +126,58 @@ def child_env(platform: str, **extra: str) -> Dict[str, str]:
     return env
 
 
+def is_quantized(serve: Dict[str, Any]) -> bool:
+    """``serve.quantized`` from the file; a file that gives only
+    ``weight_bits`` (the dense int4 family's) means a quantized tree."""
+    return bool(serve.get("quantized", "weight_bits" in serve))
+
+
 def model_dict(serve: Dict[str, Any], weight_seed: int) -> Dict[str, Any]:
+    """The worker's model entry: the ``serve`` block whole. ``quantized``,
+    ``weight_bits`` and ``dtype`` are the file's; ``serve.metadata`` is
+    passed through as it is, over the keys the harness sets (a cut's kept
+    layers or held experts, a KV dtype, an attention path)."""
     meta = {"size": serve["size"], "continuous": 1,
-            "weight_bits": serve["weight_bits"],
             "page_size": serve["page_size"], "num_pages": serve["num_pages"],
             "prefill_buckets": serve["prefill_buckets"], "warmup": 1,
             "seed": weight_seed}
-    return {"name": MODEL, "architecture": serve["architecture"],
-            "quantized": True, "max_batch_size": serve["max_batch_size"],
-            "max_seq_len": serve["max_seq_len"], "metadata": meta}
+    if "weight_bits" in serve:
+        meta["weight_bits"] = serve["weight_bits"]
+    meta.update(serve.get("metadata") or {})
+    out = {"name": MODEL, "architecture": serve["architecture"],
+           "quantized": is_quantized(serve),
+           "max_batch_size": serve["max_batch_size"],
+           "max_seq_len": serve["max_seq_len"], "metadata": meta}
+    if "dtype" in serve:
+        out["dtype"] = serve["dtype"]
+    return out
+
+
+def deploy_spec(serve: Dict[str, Any]) -> str:
+    """The coordinator's ``--deploy`` string for the same model: what
+    decides WHICH model a worker serves (``_model_identity``: architecture,
+    size, dtype, quantized) and every scalar of ``serve.metadata``. Its
+    ``k=v,k=v`` grammar has no list or object: those reach the worker, which
+    holds the model before the coordinator deploys it, and no further."""
+    parts = [f"name={MODEL}", f"architecture={serve['architecture']}",
+             f"size={serve['size']}",
+             f"quantized={int(is_quantized(serve))}", "continuous=1"]
+    if "weight_bits" in serve:
+        parts.append(f"weight_bits={serve['weight_bits']}")
+    if "dtype" in serve:
+        parts.append(f"dtype={serve['dtype']}")
+    parts += [f"max_batch_size={serve['max_batch_size']}",
+              f"max_seq_len={serve['max_seq_len']}"]
+    for key, val in (serve.get("metadata") or {}).items():
+        if isinstance(val, (list, dict)):
+            continue
+        if isinstance(val, bool):
+            val = int(val)
+        if any(c in f"{key}{val}" for c in ",="):
+            raise BenchFailure(f"serve.metadata {key}={val!r} cannot go "
+                               f"into a --deploy string")
+        parts.append(f"{key}={val}")
+    return ",".join(parts)
 
 
 def start_worker(children: Children, serve: Dict[str, Any], worker_id: str,
@@ -159,13 +202,8 @@ def start_coordinator(children: Children, serve: Dict[str, Any],
                       platform: str, ports: Dict[str, int]) -> int:
     """The coordinator with every ``CoordinatorConfig`` default (default
     load-balancing strategy included); returns its port."""
-    spec = (f"name={MODEL},architecture={serve['architecture']},"
-            f"size={serve['size']},quantized=1,continuous=1,"
-            f"weight_bits={serve['weight_bits']},"
-            f"max_batch_size={serve['max_batch_size']},"
-            f"max_seq_len={serve['max_seq_len']}")
     argv = [sys.executable, "-m", f"{PKG}.cli.coordinator", "--port", "0",
-            "--deploy", spec, "--log-level", "WARNING"]
+            "--deploy", deploy_spec(serve), "--log-level", "WARNING"]
     for wid, port in ports.items():
         argv += ["--worker", f"{wid}=127.0.0.1:{port}"]
     coord = children.start("coordinator", argv, child_env(platform))

@@ -7,7 +7,6 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional
 
 from . import opcount, peaks
-from .procs import MODEL
 from .stats import mean, percentile, tpot_s
 
 
@@ -41,20 +40,6 @@ def engine_ttfts_ms(run) -> List[float]:
         base = r.trace.get("worker.received")
         if off is not None and base is not None:
             out.append((off - base) * 1e3)
-    return out
-
-
-def coord_overheads_ms(run) -> List[float]:
-    """What the coordinator hop adds before the first token: its
-    received -> first_frame span less the engine's own time to first token
-    (routing, the wait for a pooled connection to the worker, the relay)."""
-    out = []
-    for r in good(run):
-        first = r.trace.get("first_frame")
-        off = r.trace.get("worker.first_token")
-        base = r.trace.get("worker.received")
-        if None not in (first, off, base):
-            out.append((first - (off - base)) * 1e3)
     return out
 
 
@@ -101,22 +86,6 @@ def decode_step_ms(run) -> Optional[float]:
     if not t or not t.get("decode_steps"):
         return None
     return 1e3 * t["program_s"].get("decode", 0.0) / t["decode_steps"]
-
-
-def prefill_ms_per_ktok(run) -> Optional[float]:
-    """Device time of prefill programs in the traced slice over the prompt
-    tokens the engines admitted in it (engine counter read at the slice's
-    two edges), per worker."""
-    t = run.trace
-    if not t or not t["program_s"].get("prefill") or not run.trace_edges:
-        return None
-    before, after = run.trace_edges
-    dtok = sum(after[w]["models"][MODEL]["total_prompt_tokens"]
-               - before[w]["models"][MODEL]["total_prompt_tokens"]
-               for w in after)
-    if dtok <= 0:
-        return None
-    return 1e3 * t["program_s"]["prefill"] / (dtok / len(after) / 1e3)
 
 
 def int4_roofline_pct(run) -> Optional[float]:

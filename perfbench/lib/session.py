@@ -16,11 +16,10 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
-from . import opcount, procs, traffic
+from . import families, procs, traffic
 from .loadgen import Record, run_schedule, send_one
 from .procs import BenchFailure, MODEL
 
-ROOT = procs.ROOT
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SAMPLE_EVERY_S = 0.5
 TRACE_S = 4.0          # traced slice of the window (``--trace 1`` only)
@@ -36,19 +35,6 @@ def load_config(name: str) -> Dict[str, Any]:
             raise ValueError(f"config {name}: missing {key!r}")
     cfg["name"] = name
     return cfg
-
-
-def compile_cache_dir() -> str:
-    """Where the workers keep JAX's persistent compile cache: the exported
-    directory if there is one, else the program's fixed one in the checkout
-    (``utils/compile_cache.py``)."""
-    return (os.environ.get("JAX_COMPILATION_CACHE_DIR")
-            or os.path.join(ROOT, ".jax_cache"))
-
-
-def cache_entries() -> int:
-    d = compile_cache_dir()
-    return len(os.listdir(d)) if os.path.isdir(d) else 0
 
 
 @dataclass
@@ -68,7 +54,6 @@ class RunData:
     coord_after: Dict[str, Any] = field(default_factory=dict)
     samples: List[Dict[str, Any]] = field(default_factory=list)
     trace_dirs: Dict[str, str] = field(default_factory=dict)
-    trace_edges: List[Dict[str, Any]] = field(default_factory=list)
     trace: Optional[Dict[str, Any]] = None      # reduced, see tracered.py
 
     @property
@@ -146,8 +131,11 @@ class Session:
         """The device as the workers' JAX reports it; a worker on another
         platform, or fewer chips than the configuration asks, is fatal. The
         parameter bytes each worker holds must be what the configuration
-        file's widths give (``opcount.param_bytes``)."""
+        file's widths give by its family's count (``counts/<family>.py``
+        ``param_bytes``). A tree that holds int4 tensors runs every one on
+        the Mosaic kernel; one that holds none is not asked."""
         kinds, load_s, warm_s = set(), [], []
+        want = families.counts(self.config).param_bytes(self.config)
         for wid, wc in self.worker_clients.items():
             m = await wc.metrics()
             dev = m.get("device") or {}
@@ -156,14 +144,14 @@ class Session:
                                    f"the cell needs {self.platform!r}")
             kinds.add(dev["device_kind"])
             place = dev["models"][MODEL]
-            want = opcount.param_bytes(self.config)
             if abs(place["param_bytes"] - want) > 0.001 * want:
                 raise BenchFailure(
                     f"{wid} holds {place['param_bytes']} parameter bytes; "
                     f"the configuration file's widths give {want}")
-            if self.platform == "tpu" and place["int4_paths"]["xla"]:
+            int4 = place.get("int4_paths") or {}
+            if self.platform == "tpu" and int4.get("xla"):
                 raise BenchFailure(f"{wid}: int4 matmuls off the Mosaic "
-                                   f"kernel: {place['int4_paths']}")
+                                   f"kernel: {int4}")
             setup = m["model_setup"][MODEL]
             load_s.append(setup["load_s"] - setup["warmup_s"])
             warm_s.append(setup["warmup_s"])
@@ -243,8 +231,7 @@ class Session:
                 for wid, wc in self.worker_clients.items()}
 
     async def _trace_slice(self, t_open: float, window_s: float,
-                           dirs: Dict[str, str],
-                           edges: List[Dict[str, Any]]) -> None:
+                           dirs: Dict[str, str]) -> None:
         """Profile every worker for the window's last TRACE_S seconds (only
         the process that holds a chip can trace it). At the end, because
         tracing slows the host and writing the trace out stalls the worker
@@ -252,7 +239,6 @@ class Session:
         judge (``RunData.judged``)."""
         start = t_open + max(0.0, window_s - TRACE_S)
         await asyncio.sleep(max(0.0, start - time.monotonic()))
-        edges.append(await self._worker_metrics())
         for wid, wc in self.worker_clients.items():
             d = os.path.join(self.work_dir, f"trace-{wid}")
             await wc.call("profile", action="start", trace_dir=d)
@@ -260,7 +246,6 @@ class Session:
         await asyncio.sleep(min(TRACE_S, window_s))
         for wc in self.worker_clients.values():
             await wc.call("profile", action="stop", timeout=120.0)
-        edges.append(await self._worker_metrics())
 
     async def measure(self, mix: Dict[str, Any], seed: int, window_s: float,
                       trace: bool, rate_rps: float = 0.0,
@@ -272,22 +257,20 @@ class Session:
         t_close = t_open + window_s
         samples: List[Dict[str, Any]] = []
         dirs: Dict[str, str] = {}
-        trace_edges: List[Dict[str, Any]] = []
         side: List["asyncio.Task[None]"] = []
         if trace or sample:
             side.append(asyncio.ensure_future(
                 self._sample_loop(t_open, t_close, samples)))
         if trace:
             side.append(asyncio.ensure_future(
-                self._trace_slice(t_open, window_s, dirs, trace_edges)))
+                self._trace_slice(t_open, window_s, dirs)))
 
         async def bracket() -> Dict[str, Any]:
-            """Counters and compile-cache entries at the window's edges."""
+            """Counters at the window's edges."""
             await asyncio.sleep(max(0.0, t_open - time.monotonic()))
-            before, n0 = await self._snapshot(), cache_entries()
+            before = await self._snapshot()
             await asyncio.sleep(max(0.0, t_close - time.monotonic()))
-            after, n1 = await self._snapshot(), cache_entries()
-            return {"before": before, "after": after, "compiles": n1 - n0}
+            return {"before": before, "after": await self._snapshot()}
 
         edge = asyncio.ensure_future(bracket())
         records = await run_schedule(self.client, reqs, t_open, tag,
@@ -296,7 +279,6 @@ class Session:
         for t in side:
             await t
         setup = dict(self.setup, ramp_s=ramp_s,
-                     compiles_in_window=float(edges["compiles"]),
                      setup_s=t_open - self.t_start)
         return RunData(
             config=self.config, mix=mix, records=records, t_open=t_open,
@@ -305,7 +287,7 @@ class Session:
             workers_after=edges["after"]["workers"],
             coord_before=edges["before"]["coord"],
             coord_after=edges["after"]["coord"],
-            samples=samples, trace_dirs=dirs, trace_edges=trace_edges)
+            samples=samples, trace_dirs=dirs)
 
     async def peak_memory_bytes(self) -> int:
         """``peak_bytes_in_use`` on the fullest chip, over all workers."""

@@ -293,13 +293,13 @@ def main(argv: Sequence[str]) -> int:
     trace_dir, config_path, out_path = argv[:3]
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))))
-    from perfbench.lib import opcount
+    from perfbench.lib import families
 
     with open(config_path) as f:
         cfg = json.load(f)
     trace = load_xplane(trace_dir)
-    calls = int(cfg.get("_int4_calls_per_step", 0)) or sum(
-        times for *_x, times in opcount.int4_matmuls(cfg))
+    calls = (int(cfg.get("_int4_calls_per_step", 0))
+             or families.int4_calls_per_pass(cfg))
     with open(out_path, "w") as f:
         json.dump(reduce_trace(trace, calls,
                                cfg.get("_program_files", ()), cfg), f)

@@ -6,10 +6,13 @@ needs the chip)::
     python perfbench/reference/check.py <job.json>
 
 ``job.json``: ``{"config": <configuration file's content>, "weight_seed",
-"cases": [{"label", "prompt", "tokens"}]}``. The weights are rebuilt from
-the seed the way the worker built them (``random_quantized_params`` — the
-weights are data, not the reference); every served token must be the
-reference's argmax or lie inside its numeric tie set.
+"cases": [{"label", "prompt", "tokens"}]}``. What belongs to the
+configuration's architecture comes from its family's module
+(``reference/<family>.py``, found by ``lib/families.py``): the float32
+``logits``, the configuration keys that must agree with the program's
+``ModelSpec``, and the served tree rebuilt from the seed the way the worker
+built it (the weights are data, not the reference). Every served token must
+be the reference's argmax or lie inside its numeric tie set.
 
 The tolerance is ``scripts/chip_parity.py``'s, set from chip runs (PR 21,
 mistral-7b, 19 served chains x 64 steps): 70-92 % of the served tokens are
@@ -31,7 +34,8 @@ TIE_FRACTION = 2.0 ** -3
 MIN_STRICT_SHARE = 0.5
 
 
-def judge(lg_seq, n_prompt: int, tokens) -> dict:
+def judge(lg_seq, n_prompt: int, tokens, tie_fraction: float = TIE_FRACTION,
+          min_strict_share: float = MIN_STRICT_SHARE) -> dict:
     """Compare one chain with reference logits ``lg_seq`` [T, V] (numpy)."""
     import numpy as np
 
@@ -39,7 +43,7 @@ def judge(lg_seq, n_prompt: int, tokens) -> dict:
     worst = 0.0
     for i, tok in enumerate(tokens):
         lg = lg_seq[n_prompt - 1 + i]
-        eps = TIE_FRACTION * float(np.max(np.abs(lg)))
+        eps = tie_fraction * float(np.max(np.abs(lg)))
         gap = float(lg.max() - lg[tok])
         if int(lg.argmax()) == tok:
             strict += 1
@@ -51,7 +55,7 @@ def judge(lg_seq, n_prompt: int, tokens) -> dict:
     n = len(tokens)
     return {"strict": strict, "ties": ties, "outside": bad, "n": n,
             "worst_tie": worst,
-            "ok": bad == 0 and strict >= MIN_STRICT_SHARE * n}
+            "ok": bad == 0 and strict >= min_strict_share * n}
 
 
 def main(argv) -> int:
@@ -70,41 +74,31 @@ def main(argv) -> int:
     import numpy as np
 
     from distributed_inference_engine_tpu.models import spec_for_architecture
-    from distributed_inference_engine_tpu.ops.quant import (
-        random_quantized_params,
-    )
-    from perfbench.reference import decoder
+    from perfbench.lib import families
 
     cfg = job["config"]
     serve = cfg["serve"]
+    ref = families.reference(cfg)
+    tie = float(getattr(ref, "TIE_FRACTION", TIE_FRACTION))
+    share = float(getattr(ref, "MIN_STRICT_SHARE", MIN_STRICT_SHARE))
     dev = jax.devices()[0]
-    print(f"reference: platform={dev.platform} kind={dev.device_kind!r}",
-          flush=True)
+    print(f"reference: platform={dev.platform} kind={dev.device_kind!r} "
+          f"family={families.family_name(cfg)} tie_fraction={tie} "
+          f"min_strict_share={share}", flush=True)
     spec = spec_for_architecture(serve["architecture"], size=serve["size"],
                                  max_seq_len=serve["max_seq_len"])
-    for key, have in (("hidden_size", spec.d_model),
-                      ("num_hidden_layers", spec.n_layers),
-                      ("num_attention_heads", spec.n_heads),
-                      ("num_key_value_heads", spec.n_kv_heads),
-                      ("intermediate_size", spec.d_ff),
-                      ("vocab_size", spec.vocab_size),
-                      ("head_dim", spec.head_dim),
-                      ("rope_theta", spec.rope_theta),
-                      ("rms_norm_eps", spec.norm_eps),
-                      ("qkv_bias", spec.qkv_bias)):
+    for key, field in ref.SPEC_PAIRS:
+        have = getattr(spec, field)
         if cfg[key] != have:
             print(f"reference: the program runs {key}={have}, the "
                   f"configuration file says {cfg[key]}", flush=True)
             return 1
-    params = random_quantized_params(
-        spec.replace(dtype="bfloat16"),
-        jax.random.key(int(job["weight_seed"])),
-        bits=int(serve["weight_bits"]))
+    params = ref.build_params(cfg, spec, int(job["weight_seed"]))
     failed = 0
     for case in job["cases"]:
         seq = jnp.asarray(case["prompt"] + case["tokens"], jnp.int32)
-        lg = np.asarray(decoder.logits(cfg, params, seq))
-        res = judge(lg, len(case["prompt"]), case["tokens"])
+        lg = np.asarray(ref.logits(cfg, params, seq))
+        res = judge(lg, len(case["prompt"]), case["tokens"], tie, share)
         failed += not res["ok"]
         print(f"  {case['label']}: {json.dumps(res)}", flush=True)
     print(f"reference: {len(job['cases']) - failed}/{len(job['cases'])} "

@@ -49,8 +49,9 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-def layer(cfg: Dict[str, Any], blk: Dict[str, Any], x: jnp.ndarray):
-    """One decoder block over a whole sequence x [T, D]."""
+def attention(cfg: Dict[str, Any], blk: Dict[str, Any], x: jnp.ndarray):
+    """The attention half of a block over a whole sequence x [T, D], its
+    residual added."""
     h, hkv = cfg["num_attention_heads"], cfg["num_key_value_heads"]
     dh, eps = cfg["head_dim"], cfg["rms_norm_eps"]
     t = x.shape[0]
@@ -68,17 +69,24 @@ def layer(cfg: Dict[str, Any], blk: Dict[str, Any], x: jnp.ndarray):
     causal = jnp.tril(jnp.ones((t, t), bool))
     probs = jax.nn.softmax(jnp.where(causal[None], scores, -jnp.inf), -1)
     att = jnp.einsum("hqk,khd->qhd", probs, v).reshape(t, h * dh)
-    x = x + att @ weight(blk["wo"])
-    y = rms_norm(x, blk["ln2_scale"], eps)
+    return x + att @ weight(blk["wo"])
+
+
+def layer(cfg: Dict[str, Any], blk: Dict[str, Any], x: jnp.ndarray):
+    """One decoder block over a whole sequence x [T, D]."""
+    x = attention(cfg, blk, x)
+    y = rms_norm(x, blk["ln2_scale"], cfg["rms_norm_eps"])
     gate = jax.nn.silu(y @ weight(blk["w_gate"]))
     return x + (gate * (y @ weight(blk["w_up"]))) @ weight(blk["w_down"])
 
 
 def logits(cfg: Dict[str, Any], params: Dict[str, Any],
-           tokens: jnp.ndarray) -> jnp.ndarray:
-    """Full-sequence logits [T, vocab_size] of one token sequence [T]."""
+           tokens: jnp.ndarray, layer_fn=layer) -> jnp.ndarray:
+    """Full-sequence logits [T, vocab_size] of one token sequence [T].
+    ``layer_fn`` is the block: a family whose block differs only past the
+    attention (``reference/<family>.py``) passes its own."""
     with jax.default_matmul_precision("highest"):
-        step = jax.jit(lambda blk, x: layer(cfg, blk, x))
+        step = jax.jit(lambda blk, x: layer_fn(cfg, blk, x))
         x = params["tok_emb"][tokens].astype(jnp.float32)
         n_layers = cfg["num_hidden_layers"]
         for i in range(n_layers):

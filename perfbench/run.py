@@ -33,6 +33,7 @@ sys.path.insert(0, ROOT)
 from perfbench.lib import (  # noqa: E402
     procs, readers, session, tracered, traffic,
 )
+from perfbench.lib.loadgen import in_flight_at  # noqa: E402
 from perfbench.lib.stats import tokens_in_window  # noqa: E402
 
 CHAIN_PROMPT, CHAIN_OUT = 48, 24     # the two chains the reference judges
@@ -120,9 +121,12 @@ def after_servers(sess: session.Session, out: Dict[str, Any], seed: int
     the served chains against the plain reference. Returns its verdict."""
     run: session.RunData = out["run"]
     cfg = dict(sess.config)
-    # the worker's count of int4 tensors, fused or not as it runs them
-    n_int4 = sum(next(iter(run.workers_after.values()))["device"]["models"][
-        procs.MODEL]["int4_paths"].values())
+    # the worker's count of int4 tensors, fused or not as it runs them; a
+    # tree that reports none is asked no int4 question (the reduction then
+    # takes the family's count, 0 for a family that stores none)
+    place = next(iter(run.workers_after.values()))["device"]["models"][
+        procs.MODEL]
+    n_int4 = sum((place.get("int4_paths") or {}).values())
     if n_int4 > 1:
         # stacked tensors run once per layer, the head once
         cfg["_int4_calls_per_step"] = \
@@ -200,6 +204,12 @@ def main(argv: Optional[List[str]] = None) -> int:
         # every prompt of a mix is unique: a prefix-cache hit means the
         # traffic shared what it must not, and work was removed
         failures.append(f"{int(hits)} prefix-cache hits on unique prompts")
+    drained = max((r.done for r in run.records
+                   if r.req.phase != "tail" and r.done), default=run.t_close)
+    print(f"window: in flight {in_flight_at(run.records, run.t_open)} at "
+          f"the open, {in_flight_at(run.records, run.t_close)} at the "
+          f"close; drained {max(0.0, drained - run.t_close):.1f}s after it",
+          file=sys.stderr)
     print(f"set-up: {json.dumps(run.setup)} hbm_in_use_gb "
           f"{readers.hbm_in_use_gb(run)} total "
           f"{time.monotonic() - T_START:.1f}s", file=sys.stderr)

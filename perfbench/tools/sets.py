@@ -67,7 +67,9 @@ def main() -> int:
                 f.flush()
                 print(f"set {s} seed {seed}: " + json.dumps(
                     {k: v["value"] for k, v in res.get(
-                        "metrics", {}).items()} or res), flush=True)
+                        "metrics", {}).items()} or res) + " " + " ".join(
+                    ln for ln in res.get("stderr_tail", [])
+                    if ln.startswith("window:")), flush=True)
                 if "metrics" in res:
                     runs.append(res)
             sets.append(runs)
