@@ -637,6 +637,24 @@ class RollingUpgrade:
         # no traffic until the probe passes
         self.coord.lb.quarantine(worker_id)
 
+    async def _await_peer_capacity(self, wid: str,
+                                   timeout_s: float = 10.0) -> None:
+        """Hold the rollout until a worker other than ``wid`` takes
+        traffic. The worker upgraded last rejoined HALF_OPEN and serves
+        nothing until the LB's probe closes its trial; draining the next
+        one inside that window leaves the model with no healthy replica,
+        and live requests fail with ``NoHealthyWorkerError`` whenever the
+        probe loop lags (a loaded host). Bounded: a fleet whose peers never
+        recover rolls on as before."""
+        if not any(w != wid for w in self.coord.lb.workers):
+            return
+        deadline = asyncio.get_running_loop().time() + timeout_s
+        while asyncio.get_running_loop().time() < deadline:
+            if any(s.worker_id != wid
+                   for s in self.coord.lb.healthy_workers()):
+                return
+            await asyncio.sleep(0.01)
+
     async def run(self, worker_ids: Optional[Sequence[str]] = None
                   ) -> Dict[str, Any]:
         targets = list(worker_ids if worker_ids is not None
@@ -649,6 +667,7 @@ class RollingUpgrade:
                 info = self.coord.router.workers.get(wid)
                 if info is None:
                     continue
+                await self._await_peer_capacity(wid)
                 await self.coord.drain_worker(
                     wid, timeout_s=self.drain_timeout_s, remove=True)
                 await self._swap(wid, info, self.swap_hook)

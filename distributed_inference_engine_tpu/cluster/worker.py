@@ -220,7 +220,11 @@ def _engine_placement(engine) -> Dict[str, Any]:
         return {}
     import jax
 
-    from ..ops.quant import int4_kernel_paths, param_bytes
+    from ..ops.quant import (
+        int4_kernel_blocks,
+        int4_kernel_paths,
+        param_bytes,
+    )
 
     by_device: Dict[int, int] = {}
     coords: Dict[int, Any] = {}
@@ -235,6 +239,8 @@ def _engine_placement(engine) -> Dict[str, Any]:
             "param_bytes": param_bytes(params),
             "param_bytes_by_device": {str(i): by_device[i] for i in ids},
             "int4_paths": int4_kernel_paths(params),
+            # the (bk, bn) each kernel-borne int4 shape streams in
+            "int4_blocks": int4_kernel_blocks(params),
             # the attention path the engine resolved "auto" to
             "decode_attention": getattr(engine, "attn_impl", None)}
 

@@ -1,13 +1,11 @@
 """Mosaic (Pallas-TPU) matmul with in-register int4 unpack.
 
-Closes the one SURVEY §2.2 "Pallas where XLA is insufficient" obligation
-left open in round 3: packed-int4 weights through XLA's einsum decode at
-1,584 tok/s vs int8's 3,661 at the 8B bs64 rung, because XLA materializes
-the unpacked int8 operand in HBM — the decode step then streams the 2-byte
-traffic AND the packed read. This kernel keeps the weight packed in HBM
-and VMEM and unpacks nibbles in registers on the way into the MXU feed, so
-HBM sees only the 0.5-byte/weight stream. (The reference has no analogue:
-its "model" is an asyncio sleep, ``src/mock_models/fake_model.py:47``.)
+XLA's einsum on packed-int4 weights materializes the unpacked int8 operand
+in HBM, so a decode step streams the 2-byte traffic AND the packed read.
+This kernel keeps the weight packed in HBM and VMEM and unpacks nibbles in
+registers on the way into the MXU feed, so HBM sees only the
+0.5-byte/weight stream. (The reference has no analogue: its "model" is an
+asyncio sleep, ``src/mock_models/fake_model.py:47``.)
 
 Layout contract (``ops.quant.quantize_weight``): a ``[K, N]`` weight packs
 SPLIT-HALF along the contraction axis into ``[K/2, N]`` int8 — source row
@@ -19,36 +17,42 @@ high nibble. The matmul then decomposes into two contiguous-slice dots,
 with no stride-2 gather anywhere (an interleaved layout would need one on
 either the activations or the unpacked weight — both Mosaic-hostile).
 
-Grid: ``(M/bm, N/bn, K2/bk)``, k innermost ("arbitrary"), accumulating in
-a VMEM f32 scratch; weight blocks stream exactly once per (m, n) tile, so
-a bs64 decode step streams each weight byte exactly once. Nibble unpack is
-3 VPU int32 ops + 2 converts per byte, overlapped with the MXU by Mosaic's
-usual software pipeline.
-
 Inside a layer scan the kernel must NOT take the scanned per-layer slice:
 a pallas_call is an opaque custom call, so XLA materializes the slice as
-a real HBM copy first (the r4 profile showed ~25% of the int4 step in
-s8 dynamic-slice fusions — the 3,308 tok/s plateau). The stacked variant
-(``_int4_matmul_stacked``) takes the whole ``[L, K/2, N]`` payload plus
-the layer index as a scalar-prefetch argument; the grid's index_maps pick
-block ``(layer, k, j)`` straight from the stacked array in HBM. Measured:
-1,584 (XLA) → 3,308 (sliced kernel) → 4,254 tok/s (stacked kernel) vs
-int8's 3,661 at the 8B bs64 rung.
+a real HBM copy first. The kernel takes the whole ``[L, K/2, N]`` payload
+plus the layer index as a scalar-prefetch argument and picks the layer's
+bytes straight from the stacked array in HBM
+(``_int4_matmul_stacked``; a 2-D payload is its L=1 case). Engine init
+fuses qkv and gate+up payloads (``ops.quant.fuse_block_weights``), so a
+dense 7B layer makes four calls.
 
-r5 added (a) per-shape tuned blocks + engine-init payload fusion
-(``ops.quant.fuse_block_weights``): 4,254 → 4,639 at bs64, and the
-flagship moved to bs128 (5,315 tok/s — int4's freed HBM fits bs128 with
-bf16 KV); and (b) tensor-parallel composition (mode "cp"): the kernel
-rides a ``custom_partitioning`` op whose Shardy rule passes x pre-split
-as (xlo, xhi) so both halves' K/2 axis and the payload's packed axis
-share one reduction factor — the split-half layout then shards
-COHERENTLY for row-parallel weights (each device's packed rows hold the
-lo nibbles of exactly its xlo shard's columns and the hi nibbles of its
-xhi shard's) and trivially for column-parallel, with no repacking and
-no gather. Engines stamp "cp" onto their OWN int4 tensors when params
-land sharded (``ops.quant.resolve_kernel_modes`` — per-engine scope;
-the module-level mode below is only the process default / env
-override).
+One pallas_call, two schedules, chosen from the ROWS of the activation it
+is handed (``_DECODE_ROWS``) and the payload's ``(K/2, N)``
+(``blocks_for``) — never from a model's name or an option:
+
+  decode rows (<= 16)  ``_kernel_stream``: HBM-bound, every weight byte
+      once. The payload stays in HBM; the kernel's own DMAs bring
+      ``[bk, bn]`` chunks two ahead of the unpack and across grid steps.
+  prefill rows         ``_kernel_stacked``: MXU-bound. Grid
+      ``(M/bm, N/bn, K2/bk)``, k innermost, f32 accumulator in VMEM,
+      weight blocks by the grid's double-buffered pipeline.
+
+Measured on one v5e chip at the mistral-7b shapes the benchmark's cells
+serve (8 rows; PERF.md section 6, PR 28): 5.05 ms for the 129 calls of a
+forward pass against 5.93 ms on the one-schedule kernel before it, 87 %
+of the pass's 3.6 GB at 819 GB/s; per shape in ``_TUNED_BLOCKS``. With the
+unpack cut out the same DMAs take 4.94 ms: what is left is the stream.
+
+Tensor-parallel composition (mode "cp"): the kernel rides a
+``custom_partitioning`` op whose Shardy rule passes x pre-split as
+(xlo, xhi) so both halves' K/2 axis and the payload's packed axis share
+one reduction factor — the split-half layout then shards COHERENTLY for
+row-parallel weights (each device's packed rows hold the lo nibbles of
+exactly its xlo shard's columns and the hi nibbles of its xhi shard's) and
+trivially for column-parallel, with no repacking and no gather. Engines
+stamp "cp" onto their OWN int4 tensors when params land sharded
+(``ops.quant.resolve_kernel_modes`` — per-engine scope; the module-level
+mode below is only the process default / env override).
 """
 
 from __future__ import annotations
@@ -152,9 +156,7 @@ def _payload_wants(w) -> bool:
         return False
     if w.q.ndim != 2 or w.pack_axis % w.q.ndim != 0:
         return False                    # payload must be packed on axis 0
-    k2, n = w.q.shape
-    return (_block_of(k2, _K_BLOCKS) is not None
-            and _block_of(n, _N_BLOCKS) is not None)
+    return _tileable(*w.q.shape)
 
 
 def kernel_wants(pattern: str, x, w) -> bool:
@@ -174,46 +176,82 @@ def kernel_path(w) -> str:
     return "cp" if _tensor_mode(w) == "cp" else "direct"
 
 
-# preference order measured on v5e at the 8B decode shape ([64,4096] @
-# [4096,14336]): bk1024/bn2048 runs 24.9 us/iter vs 82.5 at bk512/bn512 —
-# bigger blocks amortize the per-block VPU unpack + loop overhead; the
-# unpack STYLE (int32 shifts vs xor-bias) measured within noise of itself.
-# int8-typed shifts don't compile on this Mosaic — keep the int32 widen.
-_K_BLOCKS = (1024, 512, 256, 128)
+# An activation of at most this many rows is a decode step's (one 16-row
+# bf16 tile): the payload then arrives through the kernel's own DMAs
+# (``_kernel_stream``). Anything taller is a prefill's and takes the
+# pipelined grid (``_kernel_stacked``). The bucket is read off the
+# activation the kernel is handed, nothing else.
+_DECODE_ROWS = 16
+
+# Blocks for a payload shape the table below does not hold, per bucket:
+# the first candidates that divide it. Decode chunks are short and wide:
+# (512, 2048) lies within 4 % of the best chunk on all five swept shapes,
+# (1024, 2048) 13 % behind on the K/2 = 2048 ones. int8-typed shifts don't
+# compile on this Mosaic — keep the int32 widen.
+_K_BLOCKS = {True: (512, 256, 128), False: (1024, 512, 256, 128)}
 _N_BLOCKS = (2048, 1024, 512, 256, 128)
 
-# measured per-shape winners, (K/2, N) -> (bk, bn): the r5 tuning sweep
-# (examples/int4_kernel_tune.py, v5e, M=64 decode tile, median of 5
-# device-side timed passes) found no single block pair wins every shape —
-# the 8B fused gate+up stream runs 601 GB/s at bk2048/bn1024 vs ~495 at
-# the table default, and the fused-qkv shape actively pathologies at
-# bn=2048 (168-336 GB/s vs 461 at bk1024/bn1024). Shapes not listed fall
-# back to the preference tables above.
+# How many chunks the decode kernel's DMAs run ahead of the unpack: one
+# chunk ahead leaves the DMA queue empty at every wait (gate+up 653 GB/s
+# against 680 at two, v5e); three measures the same as two.
+_STREAM_AHEAD = 2
+
+# (K/2, N) -> ((bk, bn) at decode rows, (bk, bn) at prefill rows); None =
+# not swept in that bucket, resolved like a shape the table does not hold.
+# Measured on one v5e chip (examples/int4_kernel_tune.py, PERF.md section 6,
+# PR 28); the comments give us a call and GB/s of the packed stream at 8
+# rows:
+#   decode: chunks of the streaming kernel; the best and the worst chunk of
+#     a shape lie 20-40 % apart (wo 13.8 us at (256, 4096), 18.9 at
+#     (1024, 4096)).
+#   prefill (256 and 768 rows, bm 128): MXU-bound (gate+up 188 TFLOP/s at
+#     768 rows, 95 % of the bf16 peak), so the blocks move it little:
+#     (2048, 1024) takes 6 % off qkv and 5 % off the head at 768 rows,
+#     1.5 % off wo; gate+up and w_down keep the r5 sweep's.
 _TUNED_BLOCKS = {
-    (2048, 6144): (1024, 1024),     # qkv fused     461 GB/s
-    (2048, 4096): (512, 4096),      # wo / wq       449 GB/s
-    (2048, 28672): (2048, 1024),    # gate+up fused 601 GB/s
-    (7168, 4096): (512, 4096),      # w_down        532 GB/s
-    (2048, 129024): (2048, 2048),   # padded lm_head 619 GB/s (vs 551 at
-                                    # the table default; measured with a
-                                    # 4x-stacked payload — a single-layer
-                                    # stack is loop-INVARIANT in the tune
-                                    # scan and XLA hoists the call)
+    (2048, 6144): ((256, 3072), (2048, 1024)),      # qkv     19.3 us  651
+    (2048, 4096): ((256, 4096), (2048, 1024)),      # wo      13.8 us  607
+    (2048, 28672): ((256, 4096), (2048, 1024)),     # gate+up 80.5 us  729
+    (7168, 4096): ((256, 4096), (512, 4096)),       # w_down  41.8 us  703
+    (2048, 32768): ((256, 4096), (2048, 1024)),     # mistral lm_head
+                                                    #         91.7 us  732
+    (2048, 129024): (None, (2048, 2048)),           # llama lm_head, padded
 }
 
 
-def _blocks_for(k2: int, n: int) -> Tuple[Optional[int], Optional[int]]:
-    bk, bn = _TUNED_BLOCKS.get((k2, n), (None, None))
-    return (bk or _block_of(k2, _K_BLOCKS), bn or _block_of(n, _N_BLOCKS))
+def blocks_for(rows: int, k2: int, n: int
+               ) -> Tuple[Optional[int], Optional[int]]:
+    """``(bk, bn)`` the kernel streams a ``[K/2, N]`` payload in when the
+    activation has ``rows`` rows: the table's entry for the row bucket, or
+    the first default candidates that divide the shape (None where none
+    does)."""
+    decode = rows <= _DECODE_ROWS
+    entry = _TUNED_BLOCKS.get((k2, n), (None, None))[0 if decode else 1]
+    return entry or (_block_of(k2, _K_BLOCKS[decode]),
+                     _block_of(n, _N_BLOCKS))
 
 
-def _int4_matmul_2d(x, packed, scale, *, interpret: bool = False):
+def block_report(k2: int, n: int) -> dict:
+    """What ``blocks_for`` answers for one payload shape in both row
+    buckets, and whether the table held both (``ops.quant
+    .int4_kernel_blocks``, the worker's ``int4_blocks``)."""
+    return {"decode": list(blocks_for(_DECODE_ROWS, k2, n)),
+            "prefill": list(blocks_for(_DECODE_ROWS + 1, k2, n)),
+            "tuned": None not in _TUNED_BLOCKS.get((k2, n), (None, None))}
+
+
+def _tileable(k2: int, n: int) -> bool:
+    return None not in blocks_for(_DECODE_ROWS, k2, n) + \
+        blocks_for(_DECODE_ROWS + 1, k2, n)
+
+
+def _int4_matmul_2d(x, packed, scale, *, interpret: bool = False, **blocks):
     """``[M, K] @ unpack([K/2, N]) * scale -> [M, N]`` (dtype of x) —
     the degenerate L=1 case of the stacked kernel (one code path, one
     set of tuning constants)."""
     k2, n = packed.shape
     return _int4_matmul_stacked(x, packed[None], scale.reshape(1, 1, n),
-                                jnp.int32(0), interpret=interpret)
+                                jnp.int32(0), interpret=interpret, **blocks)
 
 
 def int4_einsum_kernel(pattern: str, x, w):
@@ -255,12 +293,34 @@ def stacked_kernel_wants(w) -> bool:
         return False
     if w.bits != 4 or w.q.ndim != 3 or w.pack_axis % (w.q.ndim - 1) != 0:
         return False                # per-layer slice must pack on axis 0
-    _l, k2, n = w.q.shape
-    return (_block_of(k2, _K_BLOCKS) is not None
-            and _block_of(n, _N_BLOCKS) is not None)
+    return _tileable(*w.q.shape[1:])
+
+
+_LO_SHIFT = 28      # a low nibble shifted to an int32's top is lo * 2**28
+
+
+def _block_product(xlo, xhi, p):
+    """f32 ``xlo @ lo(p) + xhi @ hi(p)`` for one block of packed bytes.
+
+    The high nibble is one arithmetic shift of the widened byte. The low
+    nibble is NOT shifted back down: ``p << 28`` is ``lo * 2**28`` exactly,
+    exact again as bf16 (four significant bits), and the power of two
+    comes off the f32 product afterwards, which rounds nothing: the same
+    bits as shifting down first (checked on the chip and interpreted), at
+    one VPU op a byte less. At decode rows the unpack bounds this kernel:
+    gate+up 86.2 -> 80.5 us a call against 79.3 for its DMAs alone."""
+    dt = xlo.dtype
+    p = p.astype(jnp.int32)
+    lo = jax.lax.shift_left(p, _LO_SHIFT)
+    hi = jax.lax.shift_right_arithmetic(p, 4)
+    return (jnp.dot(xlo, lo.astype(dt), preferred_element_type=jnp.float32)
+            * 2.0 ** -_LO_SHIFT
+            + jnp.dot(xhi, hi.astype(dt), preferred_element_type=jnp.float32))
 
 
 def _kernel_stacked(l_ref, xlo_ref, xhi_ref, p_ref, s_ref, o_ref, acc_ref):
+    """Prefill rows: grid ``(M/bm, N/bn, K2/bk)``, the weight block brought
+    by the grid's own double-buffered pipeline."""
     del l_ref                       # consumed by the index_maps
     k = pl.program_id(2)
 
@@ -268,37 +328,81 @@ def _kernel_stacked(l_ref, xlo_ref, xhi_ref, p_ref, s_ref, o_ref, acc_ref):
     def _zero():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    p = p_ref[0].astype(jnp.int32)
-    lo = jax.lax.shift_right_arithmetic(jax.lax.shift_left(p, 28), 28)
-    hi = jax.lax.shift_right_arithmetic(p, 4)
-    dt = xlo_ref.dtype
-    acc_ref[...] += (
-        jnp.dot(xlo_ref[...], lo.astype(dt),
-                preferred_element_type=jnp.float32)
-        + jnp.dot(xhi_ref[...], hi.astype(dt),
-                  preferred_element_type=jnp.float32))
+    acc_ref[...] += _block_product(xlo_ref[...], xhi_ref[...], p_ref[0])
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _emit():
         o_ref[...] = (acc_ref[...] * s_ref[0]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("interpret", "bk", "bn"))
+def _kernel_stream(l_ref, x_ref, p_hbm, s_ref, o_ref, buf, sem, *, bk, bn):
+    """Decode rows: one grid step per ``bn`` output columns, its K/2 in
+    ``bk``-row chunks (a loop, so the body is one chunk's: unrolled it ran
+    0.4 % faster and took ten times as long to lower and compile, which a
+    warm start pays too). The payload stays in HBM and arrives by
+    this kernel's own DMAs, ``_STREAM_AHEAD`` chunks ahead of the unpack
+    and across grid steps, so the DMA queue is never empty between the
+    first chunk and the last (the grid's pipeline fetches one block ahead
+    and waits for it at every step: 620 against 730 GB/s on gate+up)."""
+    j = pl.program_id(0)
+    _, k2, n = p_hbm.shape
+    nj, nk = n // bn, k2 // bk
+    slots = _STREAM_AHEAD + 1
+
+    def chunk(jj, kk, slot):
+        return pltpu.make_async_copy(
+            p_hbm.at[l_ref[0], pl.ds(kk * bk, bk), pl.ds(jj * bn, bn)],
+            buf.at[slot], sem.at[slot])
+
+    @pl.when(j == 0)
+    def _prime():
+        for c in range(min(_STREAM_AHEAD, nj * nk)):
+            chunk(c // nk, c % nk, c % slots).start()
+
+    def step(kk, acc):
+        c = j * nk + kk             # this chunk's place in the whole stream
+        ahead = kk + _STREAM_AHEAD
+        ja, ka = j + ahead // nk, ahead % nk
+
+        @pl.when(ja < nj)
+        def _fetch():               # into the slot chunk c - 1 just left
+            chunk(ja, ka, (c + _STREAM_AHEAD) % slots).start()
+
+        slot = c % slots
+        chunk(j, kk, slot).wait()
+        at = pl.multiple_of(kk * bk, bk)
+        return acc + _block_product(x_ref[:, pl.ds(at, bk)],
+                                    x_ref[:, pl.ds(k2 + at, bk)], buf[slot])
+
+    acc = jax.lax.fori_loop(0, nk, step,
+                            jnp.zeros(o_ref.shape, jnp.float32))
+    o_ref[...] = (acc * s_ref[0]).astype(o_ref.dtype)
+
+
+# the int32 nibble-widening temporaries ([bk, bn] lo + hi, then bf16) top
+# 16 MB at the prefill tile (bm=128, bn=2048) — past the default
+# scoped-vmem limit but well inside v5e's 128 MB physical VMEM (measured:
+# compiles + runs at 64 MB)
+_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "bk", "bn"))
 def _int4_matmul_stacked(x, packed, scale, layer, *, interpret: bool = False,
                          bk: Optional[int] = None, bn: Optional[int] = None):
     """``[M, K] @ unpack(packed[layer]) * scale[layer] -> [M, N]``;
-    ``packed [L, K/2, N]`` stays whole in HBM — the grid's index_map
-    selects the layer via scalar prefetch, so no slice is materialized.
+    ``packed [L, K/2, N]`` stays whole in HBM — the layer is a
+    scalar-prefetch argument, so no slice is materialized.
 
-    ``bk``/``bn`` override the block-size preference tables — the tuning
-    surface ``examples/int4_kernel_tune.py`` sweeps on hardware; defaults
-    are the measured winners."""
+    ONE pallas_call whatever the rows (the benchmark counts decode steps
+    by this op's name); which kernel it holds follows the rows of ``x``:
+    ``_kernel_stream`` up to ``_DECODE_ROWS``, ``_kernel_stacked`` above.
+    ``bk``/``bn`` override ``blocks_for`` — the tuning surface
+    ``examples/int4_kernel_tune.py`` sweeps on hardware."""
     m, kdim = x.shape
     nl, k2, n = packed.shape
     if kdim != 2 * k2:
         raise ValueError(f"x K={kdim} vs packed K/2={k2}")
-    tbk, tbn = _blocks_for(k2, n)
+    tbk, tbn = blocks_for(m, k2, n)
     bk = bk or tbk
     bn = bn or tbn
     if bk is None or bn is None:
@@ -308,48 +412,71 @@ def _int4_matmul_stacked(x, packed, scale, layer, *, interpret: bool = False,
         # drop trailing K rows / leave output columns unwritten
         raise ValueError(f"blocks bk={bk} bn={bn} do not divide "
                          f"K/2={k2} N={n}")
-    # activations tile at (16, 128) for bf16 — pad M up, slice back after.
-    # bm tops out at 128 to keep the f32 accumulator block ≤1 MB alongside
-    # the 2 MB double-buffered weight blocks
-    bm = _block_of(m, (128, 64, 32, 16))
-    if bm is None:
-        bm = min(-(-m // 16) * 16, 128)
+    decode = m <= _DECODE_ROWS
+    if decode:
+        # one row tile: 8 rows where they suffice (a block that is the
+        # whole array may be shorter than bf16's 16-row tile), else 16
+        bm = 8 if m <= 8 else 16
+    else:
+        # bm tops out at 128 to keep the f32 accumulator block ≤1 MB
+        # alongside the 2 MB double-buffered weight blocks
+        bm = _block_of(m, (128, 64, 32)) or min(-(-m // 16) * 16, 128)
+    if m % bm:
         x = jnp.pad(x, ((0, -m % bm), (0, 0)))
     mp = x.shape[0]
-
-    grid = (mp // bm, n // bn, k2 // bk)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k, l: (i, k)),
-            pl.BlockSpec((bm, bk), lambda i, j, k, l: (i, k)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, k, l: (l[0], k, j)),
-            pl.BlockSpec((1, 1, bn), lambda i, j, k, l: (l[0], 0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, l: (i, j)),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-    )
+    layer = jnp.atleast_1d(layer).astype(jnp.int32)
+    scale = scale.reshape(nl, 1, n)
+    if decode:
+        kernel = functools.partial(_kernel_stream, bk=bk, bn=bn)
+        operands = (x, packed, scale)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n // bn,),
+            in_specs=[
+                pl.BlockSpec((mp, kdim), lambda j, l: (0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec((1, 1, bn), lambda j, l: (l[0], 0, j)),
+            ],
+            out_specs=pl.BlockSpec((mp, bn), lambda j, l: (0, j)),
+            scratch_shapes=[
+                pltpu.VMEM((_STREAM_AHEAD + 1, bk, bn), jnp.int8),
+                pltpu.SemaphoreType.DMA((_STREAM_AHEAD + 1,))],
+        )
+        semantics = ("arbitrary",)      # the DMAs run across grid steps
+    else:
+        kernel = _kernel_stacked
+        nk = k2 // bk
+        # x twice: its low and its high half are blocks of one array, so
+        # no slice of it is materialized either
+        operands = (x, x, packed, scale)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(mp // bm, n // bn, nk),
+            in_specs=[
+                pl.BlockSpec((bm, bk), lambda i, j, k, l: (i, k)),
+                pl.BlockSpec((bm, bk), lambda i, j, k, l: (i, k + nk)),
+                pl.BlockSpec((1, bk, bn), lambda i, j, k, l: (l[0], k, j)),
+                pl.BlockSpec((1, 1, bn), lambda i, j, k, l: (l[0], 0, j)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, l: (i, j)),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        )
+        semantics = ("parallel", "parallel", "arbitrary")
     out = pl.pallas_call(
-        _kernel_stacked,
+        kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mp, n), x.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            # the int32 nibble-widening temporaries ([bk, bn] lo+hi) top
-            # 16 MB at the prefill tile (bm=128, bn=2048) — past the
-            # default scoped-vmem limit but well inside v5e's 128 MB
-            # physical VMEM (measured: compiles + runs at 64 MB)
-            vmem_limit_bytes=64 * 1024 * 1024),
+            dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT),
         cost_estimate=pl.CostEstimate(
             flops=2 * mp * n * kdim,
-            bytes_accessed=(k2 * n) + 2 * mp * kdim * (n // bn)
+            bytes_accessed=k2 * n + mp * kdim * x.dtype.itemsize
+                           * (1 if decode else n // bn)
                            + mp * n * x.dtype.itemsize,
             transcendentals=0),
         interpret=interpret,
-    )(jnp.atleast_1d(layer).astype(jnp.int32),
-      x[:, :k2], x[:, k2:], packed,
-      scale.reshape(nl, 1, n))
+        name="_int4_matmul_stacked",
+    )(layer, *operands)
     return out[:m] if mp != m else out
 
 
@@ -459,7 +586,7 @@ def _cp_stacked(interpret: bool):
 
         def lower_fn(xlo, xhi, packed, scale, layer):
             _nl, k2l, nloc = packed.shape
-            if _block_of(k2l, _K_BLOCKS) and _block_of(nloc, _N_BLOCKS):
+            if _tileable(k2l, nloc):
                 y = _impl(xlo, xhi, packed, scale, layer)
             else:                       # untileable local shard
                 sl = jax.lax.dynamic_index_in_dim(scale, layer[0], 0,

@@ -310,6 +310,13 @@ def resolve_kernel_modes(params: Dict[str, Any]) -> Dict[str, Any]:
         params, is_leaf=_is_qt)
 
 
+def _int4_tensors(params: Dict[str, Any]):
+    for leaf in jax.tree_util.tree_leaves(
+            params, is_leaf=lambda x: isinstance(x, QuantizedTensor)):
+        if isinstance(leaf, QuantizedTensor) and leaf.bits == 4:
+            yield leaf
+
+
 def int4_kernel_paths(params: Dict[str, Any]) -> Dict[str, int]:
     """How the int4 tensors of a PREPARED tree reach the MXU, by count:
     ``direct`` (bare Mosaic call), ``cp`` (Mosaic call inside the
@@ -320,11 +327,26 @@ def int4_kernel_paths(params: Dict[str, Any]) -> Dict[str, int]:
     from .int4_matmul import kernel_path
 
     counts = {"direct": 0, "cp": 0, "xla": 0}
-    for leaf in jax.tree_util.tree_leaves(
-            params, is_leaf=lambda x: isinstance(x, QuantizedTensor)):
-        if isinstance(leaf, QuantizedTensor) and leaf.bits == 4:
-            counts[kernel_path(leaf)] += 1
+    for leaf in _int4_tensors(params):
+        counts[kernel_path(leaf)] += 1
     return counts
+
+
+def int4_kernel_blocks(params: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
+    """The schedule each int4 tensor of a PREPARED tree that rides the
+    Mosaic kernel resolves to, by payload shape: ``{"K2xN": {"decode":
+    [bk, bn], "prefill": [bk, bn], "tuned": bool}}`` from the function the
+    kernel itself calls (``int4_matmul.blocks_for``) at a decode step's
+    rows and at a prefill's. ``tuned`` false marks a shape that fell to
+    the default candidates instead of a measured table entry."""
+    from .int4_matmul import block_report, kernel_path
+
+    out: Dict[str, Dict[str, Any]] = {}
+    for leaf in _int4_tensors(params):
+        if kernel_path(leaf) != "xla":
+            k2, n = leaf.q.shape[-2:]
+            out[f"{k2}x{n}"] = block_report(k2, n)
+    return out
 
 
 def prepare_params(params: Dict[str, Any]) -> Dict[str, Any]:

@@ -4,9 +4,10 @@ the mistral-7b serving shapes, each against its XLA path.
     python chip_smoke.py --kernels            # the one command, on the chip
     python -m scripts.chip_kernels --tiny     # CPU debug: interpreted, tiny
 
-Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; M=128 against
-the five int4 payload shapes of ``ops.int4_matmul._TUNED_BLOCKS`` with
-N=32,768 for the lm_head. Tolerances are the ones the CPU parity tests use
+Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; the five int4
+payload shapes of mistral-7b (N=32,768 for the lm_head) at M=128 (the
+prefill bucket of ``ops.int4_matmul.blocks_for``) and, for the 2-D and
+stacked legs, at M=8 (the decode bucket: what a served decode step runs). Tolerances are the ones the CPU parity tests use
 for the same dtypes (``tests/test_int4_matmul.py``, ``test_flash_decode.py``,
 ``test_ragged_attention.py``, ``test_paged.py``, ``test_fused_decode.py``).
 
@@ -29,11 +30,11 @@ import time
 import traceback
 
 FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
-            D=4096, fused_N=(6144, 4096),
+            int4_rows=(128, 8), D=4096, fused_N=(6144, 4096),
             int4_shapes=((2048, 6144), (2048, 4096), (2048, 28672),
                          (7168, 4096), (2048, 32768)))
 TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
-            D=256, fused_N=(512, 256),
+            int4_rows=(32, 3), D=256, fused_N=(512, 256),
             int4_shapes=((128, 256), (256, 128)))
 OUT = os.path.join("chiprun_out", "chip_kernels.json")
 
@@ -48,7 +49,7 @@ def _close(got, ref, tol: float) -> float:
     return err
 
 
-def _int4_inputs(cfg, k2, n, layers):
+def _int4_inputs(cfg, k2, n, layers, rows=None):
     import jax
     import jax.numpy as jnp
 
@@ -56,7 +57,7 @@ def _int4_inputs(cfg, k2, n, layers):
     packed = jax.random.randint(ks[0], (layers, k2, n), -128, 128,
                                 dtype=jnp.int8)
     scale = jnp.full((layers, 1, n), 0.02 / 4.3, jnp.float32)
-    x = jax.random.normal(ks[1], (cfg["M"], 2 * k2),
+    x = jax.random.normal(ks[1], (rows or cfg["M"], 2 * k2),
                           jnp.float32).astype(jnp.bfloat16)
     return x, packed, scale
 
@@ -79,12 +80,14 @@ def check_int4_2d(cfg, interpret):
     )
 
     errs = []
-    for k2, n in cfg["int4_shapes"]:
-        x, packed, scale = _int4_inputs(cfg, k2, n, 1)
-        got = _int4_matmul_2d(x, packed[0], scale[0, 0],
-                              interpret=interpret)
-        errs.append(_close(got, _int4_ref(x, packed, scale, 0), 1e-2))
-    return f"{len(errs)} shapes, max|err| {max(errs):.2e}"
+    for rows in cfg["int4_rows"]:
+        for k2, n in cfg["int4_shapes"]:
+            x, packed, scale = _int4_inputs(cfg, k2, n, 1, rows)
+            got = _int4_matmul_2d(x, packed[0], scale[0, 0],
+                                  interpret=interpret)
+            errs.append(_close(got, _int4_ref(x, packed, scale, 0), 1e-2))
+    return (f"{len(cfg['int4_shapes'])} shapes x rows {cfg['int4_rows']}, "
+            f"max|err| {max(errs):.2e}")
 
 
 def check_int4_stacked(cfg, interpret):
@@ -95,18 +98,22 @@ def check_int4_stacked(cfg, interpret):
     )
 
     errs = []
-    for k2, n in cfg["int4_shapes"]:
-        x, packed, scale = _int4_inputs(cfg, k2, n, cfg["L"])
-        layer = cfg["L"] - 1
-        fn = jax.jit(lambda x, p, s, l: _int4_matmul_stacked(
-            x, p, s, l, interpret=interpret))
-        if not interpret:
-            text = fn.lower(x, packed, scale, layer).as_text()
-            assert "tpu_custom_call" in text, \
-                "stacked int4 matmul did not lower to the Mosaic custom call"
-        got = fn(x, packed, scale, layer)
-        errs.append(_close(got, _int4_ref(x, packed, scale, layer), 1e-2))
-    return f"{len(errs)} shapes, max|err| {max(errs):.2e}"
+    layer = cfg["L"] - 1
+    fn = jax.jit(lambda x, p, s, l: _int4_matmul_stacked(
+        x, p, s, l, interpret=interpret))
+    for rows in cfg["int4_rows"]:
+        for k2, n in cfg["int4_shapes"]:
+            x, packed, scale = _int4_inputs(cfg, k2, n, cfg["L"], rows)
+            if not interpret:
+                text = fn.lower(x, packed, scale, layer).as_text()
+                assert "tpu_custom_call" in text, \
+                    "stacked int4 matmul did not lower to the Mosaic " \
+                    "custom call"
+            got = fn(x, packed, scale, layer)
+            errs.append(_close(got, _int4_ref(x, packed, scale, layer),
+                               1e-2))
+    return (f"{len(cfg['int4_shapes'])} shapes x rows {cfg['int4_rows']}, "
+            f"max|err| {max(errs):.2e}")
 
 
 def check_int4_cp(cfg, interpret):

@@ -33,14 +33,31 @@ def _q4(rs, k, n):
     return w, quant.quantize_weight(w, (0,), bits=4)
 
 
-@pytest.mark.parametrize("m,k,n", [(5, 256, 256), (64, 512, 384),
-                                   (16, 256, 128)])
-def test_kernel_matches_dequantized_reference(m, k, n):
+# rows 1-16 take the decode bucket (the streaming kernel: chunks of
+# ``[bk, bn]`` by its own DMAs, two ahead), 17+ the prefill bucket (the
+# pipelined grid); explicit blocks cover one chunk a step, several chunks
+# a step, fewer chunks than the DMAs run ahead, and a k axis of one
+@pytest.mark.parametrize("m,k,n,blocks", [
+    (5, 256, 256, {}), (64, 512, 384, {}), (16, 256, 128, {}),
+    (1, 512, 256, {}), (3, 512, 256, {}), (8, 512, 256, {}),
+    (16, 512, 256, {}), (128, 512, 256, {}), (160, 512, 384, {}),
+    (1, 1024, 256, {"bk": 512, "bn": 128}),     # full-K chunks, 2 steps
+    (3, 1024, 256, {"bk": 512, "bn": 256}),     # ONE chunk in all
+    (8, 1024, 512, {"bk": 256, "bn": 512}),     # 2 chunks, one step
+    (16, 1024, 512, {"bk": 128, "bn": 256}),    # 4 chunks a step, 2 steps
+    (8, 1024, 384, {"bk": 128, "bn": 128}),     # ... 3 steps
+    (9, 512, 256, {"bk": 256, "bn": 256}),
+    (128, 1024, 256, {"bk": 512, "bn": 256}),   # grid, k axis of one
+    (384, 1024, 256, {"bk": 256, "bn": 128}),   # grid, 3 row tiles
+])
+def test_kernel_matches_dequantized_reference(m, k, n, blocks):
     rs = np.random.RandomState(m + k + n)
     w, qt = _q4(rs, k, n)
     x = jnp.asarray(rs.randn(m, k).astype("float32"))
     ref = jnp.einsum("md,df->mf", x, qt.dequantize(jnp.float32))
-    got = _int4_matmul_2d(x, qt.q, qt.s.astype(jnp.float32), interpret=True)
+    got = _int4_matmul_2d(x, qt.q, qt.s.astype(jnp.float32), interpret=True,
+                          **blocks)
+    assert got.shape == (m, n)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -120,11 +137,12 @@ def test_int4_engine_tokens_unchanged_by_kernel_path(kernel_on):
     assert t_kernel.tokens == t_xla.tokens
 
 
-def test_stacked_kernel_layer_indexed_matches_sliced(kernel_on):
+@pytest.mark.parametrize("m", [4, 1, 8, 16, 136])
+def test_stacked_kernel_layer_indexed_matches_sliced(kernel_on, m):
     """The scalar-prefetch stacked kernel (layer picked by the grid's
     index_map, no materialized slice) must match the per-layer 2-D
-    kernel for every layer, and matmul_any must route IndexedQuant to
-    it."""
+    kernel for every layer, at decode rows and at prefill rows, and
+    matmul_any must route IndexedQuant to it."""
     from distributed_inference_engine_tpu.ops.int4_matmul import (
         int4_einsum_kernel_stacked,
         stacked_kernel_wants,
@@ -135,7 +153,7 @@ def test_stacked_kernel_layer_indexed_matches_sliced(kernel_on):
     w = jnp.asarray(rs.randn(L, K, N).astype("float32") * 0.05)
     qt = quant.quantize_weight(w, (1,), bits=4)
     assert stacked_kernel_wants(qt)
-    x = jnp.asarray(rs.randn(4, K).astype("float32"))
+    x = jnp.asarray(rs.randn(m, K).astype("float32"))
     for l in range(L):
         per_layer = quant.QuantizedTensor(q=qt.q[l], s=qt.s[l],
                                           bits=4, pack_axis=qt.pack_axis)
@@ -146,6 +164,124 @@ def test_stacked_kernel_layer_indexed_matches_sliced(kernel_on):
         via_any = quant.matmul_any("bd,df->bf", x,
                                    quant.IndexedQuant(qt, jnp.int32(l)))
         np.testing.assert_array_equal(np.asarray(via_any), np.asarray(got))
+
+
+# the five payload shapes of mistral-7b as the engine fuses them
+MISTRAL_SHAPES = [(2048, 6144), (2048, 4096), (2048, 28672), (7168, 4096),
+                  (2048, 32768)]
+
+
+def test_every_table_entry_divides_its_shape():
+    from distributed_inference_engine_tpu.ops import int4_matmul as im
+
+    for (k2, n), buckets in im._TUNED_BLOCKS.items():
+        assert len(buckets) == 2
+        for bk, bn in filter(None, buckets):    # None: bucket not swept
+            assert k2 % bk == 0 and n % bn == 0, (k2, n, bk, bn)
+            # what Mosaic tiles: lanes of the activation and output blocks,
+            # sublanes of the int8 payload block
+            assert bn % 128 == 0 and (bk % 128 == 0 or bk == k2)
+
+
+def _launched_blocks(rows, k2, n):
+    """``(bk, bn)`` of the pallas_call ``_int4_matmul_stacked`` really
+    builds for this call, read off its jaxpr (nothing runs): the shape of
+    the int8 block the kernel body is handed — the grid's weight block at
+    prefill rows, one slot of the DMA scratch at decode rows."""
+    from distributed_inference_engine_tpu.ops.int4_matmul import (
+        _int4_matmul_stacked,
+    )
+
+    jaxpr = jax.make_jaxpr(
+        lambda x, p, s: _int4_matmul_stacked(x, p, s, jnp.int32(0),
+                                             interpret=True))(
+        jax.ShapeDtypeStruct((rows, 2 * k2), jnp.bfloat16),
+        jax.ShapeDtypeStruct((2, k2, n), jnp.int8),
+        jax.ShapeDtypeStruct((2, 1, n), jnp.float32))
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+
+    calls = list(walk(jaxpr.jaxpr))
+    assert len(calls) == 1, "one pallas_call a matmul (the trace counts them)"
+    int8 = [v.aval.shape for v in calls[0].params["jaxpr"].invars
+            if v.aval.dtype == jnp.int8 and v.aval.shape[-2:] != (k2, n)]
+    assert len(int8) == 1, int8
+    return int8[0][-2:], tuple(calls[0].params["grid_mapping"].grid)
+
+
+@pytest.mark.parametrize("k2,n", MISTRAL_SHAPES)
+@pytest.mark.parametrize("rows", [8, 256])
+def test_mistral_shapes_resolve_to_a_table_entry(kernel_on, k2, n, rows):
+    """Both row buckets of every shape the cells stream are measured table
+    entries, and the report (``int4_blocks``) names the blocks the kernel's
+    own grid is built from."""
+    from distributed_inference_engine_tpu.ops import int4_matmul as im
+
+    assert (k2, n) in im._TUNED_BLOCKS
+    bk, bn = im.blocks_for(rows, k2, n)
+    report = im.block_report(k2, n)
+    assert report["tuned"]
+    assert report["decode" if rows <= 16 else "prefill"] == [bk, bn]
+    launched, grid = _launched_blocks(rows, k2, n)
+    assert launched == (bk, bn)
+    assert grid == ((n // bn,) if rows <= 16 else (rows // 128, n // bn,
+                                                   k2 // bk))
+
+
+def test_int4_kernel_blocks_reports_the_prepared_tree(kernel_on):
+    """``ops.quant.int4_kernel_blocks`` lists each kernel-borne payload
+    shape once, with ``tuned`` false where the default candidates serve
+    it, and leaves out what ``kernel_path`` sends to XLA."""
+    from distributed_inference_engine_tpu.ops.int4_matmul import (
+        block_report,
+        blocks_for,
+        kernel_path,
+    )
+
+    rs = np.random.RandomState(11)
+    stacked = quant.quantize_weight(
+        jnp.asarray(rs.randn(2, 256, 384).astype("float32")), (1,), bits=4)
+    _, head = _q4(rs, 256, 512)
+    _, odd = _q4(rs, 256, 96)                       # untileable N -> xla
+    tree = {"blocks": {"w": stacked}, "lm_head": head, "odd": odd}
+    assert [kernel_path(t) for t in (stacked, head, odd)] == \
+        ["direct", "direct", "xla"]
+    blocks = quant.int4_kernel_blocks(tree)
+    assert set(blocks) == {"128x384", "128x512"}
+    for key, (k2, n) in (("128x384", (128, 384)), ("128x512", (128, 512))):
+        assert blocks[key] == {"decode": list(blocks_for(8, k2, n)),
+                               "prefill": list(blocks_for(512, k2, n)),
+                               "tuned": False}
+    # a shape swept in one bucket only says so
+    assert block_report(2048, 129024) == {
+        "decode": [512, 2048], "prefill": [2048, 2048], "tuned": False}
+    set_kernel_mode("off")
+    assert quant.int4_kernel_blocks(tree) == {}
+
+
+def test_worker_device_report_carries_int4_blocks(kernel_on):
+    """The worker's per-model placement (``device.models.<name>`` of
+    ``ping`` / ``metrics``) names the blocks beside the paths."""
+    from types import SimpleNamespace
+
+    from distributed_inference_engine_tpu.cluster.worker import (
+        _engine_placement,
+    )
+
+    rs = np.random.RandomState(5)
+    params = {"blocks": {"wo": quant.quantize_weight(
+        jnp.asarray(rs.randn(2, 256, 256).astype("float32")), (1,), bits=4)}}
+    place = _engine_placement(SimpleNamespace(params=params,
+                                              attn_impl="xla"))
+    assert place["int4_paths"] == {"direct": 1, "cp": 0, "xla": 0}
+    assert place["int4_blocks"] == quant.int4_kernel_blocks(params) == {
+        "128x256": {"decode": [128, 256], "prefill": [128, 256],
+                    "tuned": False}}
 
 
 def test_split_indexed_blocks_identity_when_off():

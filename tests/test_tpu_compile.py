@@ -60,3 +60,31 @@ def test_flash_decode_compiles_for_v5e(one_chip, b, h, hkv, dh, mp, w,
         sds((b, w, hkv, dh), bf), sds((b,), jnp.int32),
         sds((), jnp.int32)).compile()
     assert "flash_decode_custom_call" in compiled.as_text()
+
+
+# the five payload shapes of the mistral-7b cells, through the blocks the
+# kernel resolves itself (ops.int4_matmul.blocks_for): rows 8 = a served
+# decode step (the decode bucket), rows 256 / 768 = its prefill programs
+@pytest.mark.parametrize("rows,k2,n", [
+    (8, 2048, 6144), (8, 2048, 4096), (8, 2048, 28672), (8, 7168, 4096),
+    (8, 2048, 32768), (1, 2048, 4096), (16, 7168, 4096),
+    (256, 2048, 6144), (768, 2048, 28672), (768, 7168, 4096),
+])
+def test_int4_matmul_compiles_for_v5e(one_chip, rows, k2, n):
+    from distributed_inference_engine_tpu.ops.int4_matmul import (
+        _int4_matmul_stacked)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(_int4_matmul_stacked).lower(
+        sds((rows, 2 * k2), jnp.bfloat16), sds((2, k2, n), jnp.int8),
+        sds((2, 1, n), jnp.float32), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the benchmark counts decode steps as device ops named ``int4``: one
+    # a matmul, whatever the schedule
+    names = [ln.split(" = ")[0] for ln in text.splitlines()
+             if " = " in ln and "%" in ln.split(" = ")[0]
+             and "int4" in ln.split(" = ")[0]]
+    assert len(names) == 1, names
