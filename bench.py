@@ -36,17 +36,9 @@ Env knobs:
                    tok/s measured; 64 otherwise)
     BENCH_PROMPT / BENCH_NEW_TOKENS   lengths (default 128 / 128)
     BENCH_KV_DTYPE paged-KV dtype (continuous; default bfloat16)
-    BENCH_ATTN     attention impl: xla (default) | pallas |
-                   pallas-decode (fused flash-decode kernel: paged prefix
-                   + side window in one pallas_call per layer,
-                   ops/flash_decode.py) | pallas-decode-fw (same + fresh-KV
-                   side writeback in the kernel epilogue)
-    BENCH_DECODE_MODE  window | inline (default: window for 8B-class,
-                   inline for small-KV models — the measured crossover)
-    BENCH_FUSED    1 (default) = fused decode megastep: RMSNorm+matmul and
-                   attn-out/MLP-down+residual-add run as single Pallas
-                   kernels on the decode path (ops/fused_decode.py);
-                   0 = unfused reference path (bit-identical tokens)
+    BENCH_ATTN     attention impl: xla (default) | pallas-decode (the
+                   in-place kernel: paged prefix + side window in one
+                   pallas_call per layer, ops/flash_decode.py) | auto
     BENCH_OVERLAP  1 (default) = serving mode overlaps pump batch formation
                    with in-flight device steps (engine.overlap_hook);
                    0 = drain the inbox only at the top of the pump loop
@@ -63,10 +55,7 @@ Env knobs:
     serving mode:  BENCH_RATE (req/s Poisson, default 16),
                    BENCH_REQUESTS (default 64), BENCH_STEPS (chunk, def 16),
                    BENCH_MAX_WAITING (queue cap, default 4x slots; 0 = off),
-                   BENCH_DEADLINE_S (queue deadline shed, default 10; 0 = off),
-                   BENCH_ADMIT_MIN (hold admissions until this many waiters,
-                   default 0 = off), BENCH_ADMIT_HOLD (max admission hold
-                   seconds, default 0.25)
+                   BENCH_DEADLINE_S (queue deadline shed, default 10; 0 = off)
     BENCH_RUNS     timed repetitions, best-of reported (default 3)
     BENCH_DEFER    1 = defer_sync: overlap each chunk's packed readback
                    with the next chunk's execution (serving-mode lever)
@@ -188,7 +177,7 @@ RUNS = int(os.environ.get("BENCH_RUNS", "3"))
 # mixed workload (ISSUE 3): every BENCH_MIX_EVERY-th serving request
 # carries a BENCH_MIX_PROMPT-token prompt instead of PROMPT_LEN — a steady
 # decode stream with periodic long-prompt admissions, the shape whose ITL
-# cliff the ragged mixed step exists to flatten. 0 disables.
+# cliff chunked prefill (BENCH_PREFILL_CHUNK) bounds. 0 disables.
 MIX_EVERY = int(os.environ.get("BENCH_MIX_EVERY", "0"))
 MIX_PROMPT = int(os.environ.get("BENCH_MIX_PROMPT", "2048"))
 MAX_PROMPT = max(PROMPT_LEN, MIX_PROMPT) if MIX_EVERY else PROMPT_LEN
@@ -295,19 +284,6 @@ def _engine(spec, params, kind: str, batch: int, steps: int):
     cfg.page_size = 128
     per_seq = -(-(PROMPT_LEN + NEW_TOKENS) // cfg.page_size)  # ceil
     cfg.num_pages = max(64, batch * per_seq + 8)
-    # measured crossover (README table): dense-ctx window chunks win when
-    # weight streaming dominates (8B: 3661 r3 vs 1038 for per-step pool
-    # scatter); small-KV models keep the inline scatter (GPT-2: 10673 vs
-    # 7169)
-    if os.environ.get("BENCH_DECODE_MODE"):
-        cfg.decode_mode = os.environ["BENCH_DECODE_MODE"]
-    elif not IS_BIG:
-        cfg.decode_mode = "inline"
-    # fused decode megastep (ISSUE 5a): fold RMSNorm into the qkv /
-    # gate+up matmuls and the residual add into attn-out / MLP-down —
-    # closes the elementwise seams between the big weight streams.
-    # Token-identical to the unfused path (tests/test_fused_decode.py).
-    cfg.decode_fused = os.environ.get("BENCH_FUSED", "1") not in ("0", "")
     if os.environ.get("BENCH_PREFILL_CHUNK"):
         # chunked prefill: long prompts prefill in page-aligned chunks
         # interleaved with decode (bounds the admission stall on live
@@ -317,10 +293,6 @@ def _engine(spec, params, kind: str, batch: int, steps: int):
         cfg.prefill_chunk = raw
         chunk = max(cfg.page_size, raw // cfg.page_size * cfg.page_size)
         cfg.prefill_buckets = sorted({chunk, PROMPT_LEN, MAX_PROMPT})
-    if os.environ.get("BENCH_MIXED_TOKENS"):
-        # Sarathi-style prefill budget per mixed ragged step (takes effect
-        # with BENCH_ATTN=pallas-ragged and BENCH_PREFILL_CHUNK set)
-        cfg.mixed_step_tokens = int(os.environ["BENCH_MIXED_TOKENS"])
     if os.environ.get("BENCH_KV_OFFLOAD", "") not in ("", "0"):
         # host-RAM KV tier: evicted prefix pages offload instead of
         # dropping, admission prefetches host hits back, pool exhaustion
@@ -632,10 +604,6 @@ def serving_main() -> None:
         os.environ.get("BENCH_MAX_WAITING", str(4 * BATCH)))
     engine.config.queue_deadline_s = float(
         os.environ.get("BENCH_DEADLINE_S", "10"))
-    engine.config.admission_min_batch = int(
-        os.environ.get("BENCH_ADMIT_MIN", "0"))
-    engine.config.admission_max_hold_s = float(
-        os.environ.get("BENCH_ADMIT_HOLD", "0.25"))
     log(f"engine init ({MODEL}, serving, quant={QUANT_BITS if QUANT else 0}): "
         f"{time.perf_counter() - t0:.1f}s")
     t0 = time.perf_counter()
@@ -702,9 +670,9 @@ def serving_main() -> None:
     m = engine.get_metrics()
     toks_per_s = total_toks / wall
     ttft_p50, ttft_p99 = pct(ttfts, 0.5) * 1e3, pct(ttfts, 0.99) * 1e3
-    # p50 next to p99: the mixed-step claim is about the TAIL (admissions
-    # must not cliff p99 above ~2x the steady-state median), so both ends
-    # of the ITL distribution are first-class outputs
+    # p50 next to p99: a long-prompt admission shows in the TAIL (it must
+    # not cliff p99 above ~2x the steady-state median), so both ends of
+    # the ITL distribution are first-class outputs
     itl_p50 = pct(itls, 0.5) * 1e3
     itl_p99 = pct(itls, 0.99) * 1e3
     d_steps = m["engine_steps"] - steps0

@@ -482,10 +482,7 @@ class WorkerServer(FramedServerMixin):
             from ..serving.pump import EnginePump
 
             self._pumps[cfg.name] = EnginePump(
-                engine,
-                mixed_step_tokens=(
-                    int(cfg.metadata.get("mixed_step_tokens", 0)) or None),
-                event_log=self.events, model=cfg.name)
+                engine, event_log=self.events, model=cfg.name)
 
     def _check_idempotent(self, cfg: ModelConfig) -> bool:
         """True when ``cfg`` is already loaded with a compatible config;
@@ -579,10 +576,7 @@ class WorkerServer(FramedServerMixin):
                 from ..serving.pump import EnginePump
 
                 self._pumps[name] = EnginePump(
-                    engine,
-                    mixed_step_tokens=(
-                        int(cfg.metadata.get("mixed_step_tokens", 0)) or None),
-                    event_log=self.events, model=name)
+                    engine, event_log=self.events, model=name)
         return receipt
 
     # -- connection handling (loop + envelope in FramedServerMixin) -----------

@@ -94,49 +94,11 @@ class EngineConfig:
     dtype: str = "bfloat16"
     kv_dtype: str = "bfloat16"
     decode_steps_per_call: int = 8     # tokens generated per jit dispatch (lax.scan)
-    use_paged_kv: bool = False
-    attention_impl: str = "auto"       # "auto" | "xla" | "pallas" |
-    # "auto" resolves from what the engine can observe
-    # (engine.continuous.resolve_attention_impl): on a TPU backend, with
-    # decode_mode "window", no sliding window, an unsharded pool and a
-    # fused Hkv*Dh that is a multiple of 128 lanes -> "pallas-decode"
-    # (K/V read in place from the page pool); anything else -> "xla".
-    # The resolved string is get_metrics()["attn_impl"] and the worker's
-    # device report (models.<name>.decode_attention).
-    # "pallas-decode" (fused flash-decode kernel: paged prefix + side
-    # window in ONE pallas_call per layer, ops/flash_decode.py) |
-    # "pallas-decode-fw" (same + fresh-KV side writeback in the kernel
-    # epilogue) | "pallas-ragged" (mixed-batch ragged kernel,
-    # ops/ragged_attention.py: decode rows AND prefill-chunk rows share
-    # one dispatch when prefill_chunk > 0; pure-decode chunks fall back
-    # to the flash-decode kernel); append "_interpret" to any for CPU
-    # interpret mode
-    decode_fused: bool = False         # decode megastep (ISSUE 5): fold
-                                       # RMSNorm into the QKV / gate-up
-                                       # matmul prologue and the residual
-                                       # add into the attn-out / down-proj
-                                       # epilogue (ops/fused_decode.py) on
-                                       # PLAIN bf16/f32 weights — bit-
-                                       # identical tokens, fewer HBM
-                                       # round-trips of the [B, D]
-                                       # activation stream. Quantized
-                                       # layers keep their Mosaic kernels
-                                       # (dequant already fused there).
-    decode_mode: str = "window"        # continuous engine: "window" freezes
-                                       # the page pools per chunk; fresh
-                                       # K/V goes to a side window (kernel
-                                       # attention paths, which read the
-                                       # prefix in place from the pages)
-                                       # or the live prefix is gathered
-                                       # ONCE into a dense working buffer
-                                       # and the chunk decodes against it
-                                       # (attention_impl "xla": 3623 tok/s
-                                       # bs64 r3, vs 1038 for per-step page
-                                       # scatter); "inline" scatters fresh
-                                       # KV into the pages per step (faster
-                                       # for small KV rows, e.g. GPT-2-
-                                       # class: 10673 vs 7169). Sliding-
-                                       # window specs always run inline.
+    attention_impl: str = "auto"       # "auto" | "xla" | "pallas-decode" |
+    # "pallas-decode_interpret": which of its decode bodies a continuous
+    # engine runs (engine.continuous.resolve_decode_body). "auto" takes the
+    # in-place kernel ("window") on a TPU with an unsharded pool and Hkv*Dh
+    # % 128 == 0, else XLA ("dense"); "_interpret" runs the kernel on a CPU.
     prefix_cache: bool = True          # reuse full KV pages across shared prompt prefixes
     kv_offload: bool = False           # host-RAM second tier for the paged
                                        # cache (engine/kv_offload.py):
@@ -153,18 +115,6 @@ class EngineConfig:
                                        # this prefill in chunks interleaved with
                                        # decode (0 = whole-prompt prefill);
                                        # rounded to a multiple of page_size
-    mixed_step_tokens: int = 0         # ragged mixed steps (attention_impl
-                                       # ="pallas-ragged" + prefill_chunk):
-                                       # cap the PREFILL tokens packed into
-                                       # one mixed dispatch, a la Sarathi —
-                                       # prefill admission is throttled by
-                                       # leftover compute instead of whole-
-                                       # step preemption. Row-granular: a
-                                       # step takes whole chunks (oldest
-                                       # first) until the budget is spent,
-                                       # always at least one so prefill
-                                       # can't starve. 0 = uncapped (every
-                                       # pending chunk rides every step)
     defer_admission: bool = True       # continuous engine: under decode
                                        # pressure (>=1/4 slots live), skip
                                        # the blocking first-token read at
@@ -206,24 +156,6 @@ class EngineConfig:
                                        # finish_reason="overloaded" (pump/
                                        # RPC surface it as the typed error;
                                        # 0 = never shed)
-    # ---- admission coalescing (r5, serving-goodput lever) ----
-    admission_min_batch: int = 0       # hold waiting admissions until this
-                                       # many queue up (or the hold timer
-                                       # below fires): admission prefill at
-                                       # 4-8 rows runs far below the
-                                       # batched-prefill rate, so trading
-                                       # ~a chunk of queue wait for 2x the
-                                       # prefill batch raises goodput near
-                                       # saturation. 0 = admit immediately
-                                       # (the default; latency-optimal at
-                                       # light load). Held admissions jump
-                                       # the hold when the decode batch is
-                                       # running under half-occupied —
-                                       # stalling a hungry engine never
-                                       # wins.
-    admission_max_hold_s: float = 0.25  # cap on the coalescing hold: the
-                                       # oldest waiting request never waits
-                                       # longer than this for batch-mates
     admission_max_rows: int = 0        # cap rows per admission-prefill
                                        # dispatch (0 = whole free-slot
                                        # set, the default). Historical
@@ -245,40 +177,6 @@ class EngineConfig:
                                        # kept for the Perfetto export; the
                                        # oldest fall off. 0 disables
                                        # recording entirely.
-    # ---- bubble-scheduled async speculation (ISSUE 15 / ROADMAP 5) ----
-    spec_async: bool = False           # drafter subsystem (engine/
-                                       # spec_async.py): a small draft
-                                       # model decodes short chunks for
-                                       # streaming-flagged slots inside
-                                       # the measured host-gap window;
-                                       # drafted tokens ride the NEXT
-                                       # step as extra verify columns.
-                                       # Greedy output stays token-for-
-                                       # token identical to spec off
-                                       # (rejection sampling, engine/
-                                       # spec_accept.py). Off by default.
-    spec_draft_model: str = ""         # draft source: "layers:N" builds a
-                                       # truncated self-draft from the
-                                       # target's first N blocks (engine.
-                                       # speculative.truncated_draft — the
-                                       # zero-artifact default; "" means
-                                       # layers:2). Engines constructed
-                                       # directly may pass an explicit
-                                       # draft_spec/draft_params instead.
-    spec_max_draft: int = 4            # draft tokens proposed per round =
-                                       # extra verify columns per drafted
-                                       # slot. Static in the verify
-                                       # program (one program per
-                                       # use_stops variant — the
-                                       # compile-count guard audits this).
-    spec_bubble_floor_s: float = 5e-4  # auto-idle threshold: the drafter
-                                       # skips its round when the live
-                                       # per-step host-gap estimate (fed
-                                       # from obs.timeline.busy_gap_split,
-                                       # falling back to the engine's
-                                       # dispatch/gap accumulators) is
-                                       # below this — speculation costs
-                                       # ~zero goodput at saturation.
 
 
 def validate_prefill_compose(prefill_chunk: int, sp: int = 1) -> None:
@@ -290,9 +188,7 @@ def validate_prefill_compose(prefill_chunk: int, sp: int = 1) -> None:
     TIME (prefill in page-aligned slices), sp bounds it in SPACE (shard the
     prompt across the mesh) — and the suffix-chunk programs are not
     sequence-parallel, so enabling both buys nothing and traces programs sp
-    would never run. Note this constraint is about the SPLIT chunked path
-    AND the ragged mixed path alike: neither prefill-chunk program shards
-    the sequence axis.
+    would never run.
     """
     if int(sp) > 1 and int(prefill_chunk) > 0:
         raise ValueError(
@@ -314,14 +210,6 @@ class BatcherConfig:
     max_batch_size: int = 8
     max_latency_ms: float = 50.0
     pad_to_buckets: bool = True        # pad batches to power-of-two buckets for XLA
-    mixed_step_tokens: int = 0         # serving-layer hand-down of the
-                                       # engine's Sarathi-style prefill
-                                       # budget (EngineConfig
-                                       # .mixed_step_tokens): cluster
-                                       # workers forward it into the
-                                       # EnginePump so deploys can throttle
-                                       # admission prefill per mixed step
-                                       # without touching model metadata
 
 
 @dataclass

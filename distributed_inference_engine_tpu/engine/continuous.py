@@ -46,7 +46,6 @@ from ..models.base import (
     forward_decode,
     forward_decode_paged,
     forward_decode_window,
-    forward_mixed_step,
     forward_prefill_into_pages,
     forward_prefill_suffix,
     init_params,
@@ -56,7 +55,6 @@ from ..models.base import (
 )
 from ..ops.sampling import (
     SamplingParams,
-    masked_sampling_probs,
     sample_tokens,
     sample_tokens_with_logprobs,
 )
@@ -65,7 +63,6 @@ from ..utils.hotpath import hot_path
 from ..utils.tracing import LatencyStats
 from .engine import _next_bucket, _pow2_buckets
 from .paged_kv import PagedKVCache, page_chain_hashes
-from .spec_accept import rejection_accept
 from .types import (
     EngineOverloadedError,
     GenerationRequest,
@@ -81,19 +78,43 @@ logger = logging.getLogger(__name__)
 _DEVICE_STOP_K = 8
 
 
-def resolve_attention_impl(impl: str, backend: str, spec,
-                           decode_mode: str, sharded: bool = False) -> str:
-    """What ``attention_impl="auto"`` means for a continuous engine: a
-    pure function of what the code can observe (the explicit strings pass
-    through). On a TPU, with the windowed decode mode, a spec without a
-    sliding window and a fused ``Hkv·Dh`` that fills whole 128-lane
-    tiles, decode attention reads K/V in place from the page pool through
-    the flash-decode kernel (``ops/flash_decode.py``); a pool sharded
-    over a mesh, any other backend (the kernel only interprets on CPU), a
-    sliding window or an odd width keep the dense XLA path the kernel is
-    pinned to. Measured on one v5e chip at mistral-7b int4, 8 slots
-    (PERF.md §6, PR 25): the kernel path takes the K/V moves from 24 % of
-    the device's time to 6 % and the decode step from 10.0 to 7.3 ms."""
+# attention_impl strings a deploy may pass; anything else is refused at load
+ATTENTION_IMPLS = ("auto", "xla", "pallas-decode", "pallas-decode_interpret")
+
+
+def resolve_decode_body(impl: str, backend: str, spec,
+                        sharded: bool = False) -> Tuple[str, str]:
+    """The ONE decode body a continuous engine runs and the attention it
+    implies, ``(body, attn_impl)``: a pure function of what the code can
+    observe.
+
+    ========  ==========================================  =================
+    body      when                                        attention
+    ========  ==========================================  =================
+    hybrid    ``spec.layer_kinds``                        the family's (XLA)
+    inline    uniform spec with ``sliding_window``        XLA, per-step
+                                                          page scatter
+    window    uniform, no window, the kernel applies      ``ops/flash_
+                                                          decode.py`` in
+                                                          place from the
+                                                          page pool
+    dense     every other uniform spec                    XLA, the context
+                                                          gathered once a
+                                                          chunk
+    ========  ==========================================  =================
+
+    ``"auto"`` takes the kernel on a TPU with the pool on one device and a
+    fused ``Hkv·Dh`` of whole 128-lane tiles; ``"pallas-decode"`` /
+    ``"pallas-decode_interpret"`` ask for it by name (the tests' way to
+    run the TPU body on a CPU); ``"xla"`` refuses it. A sliding-window spec
+    runs ``inline`` whatever the string says: its prefix mask depends on the
+    growing total length. Measured on one v5e chip at mistral-7b int4, 8
+    slots (PERF.md §6, PR 25): ``window`` against ``dense`` takes the K/V
+    moves from 24 % of the device's time to 6 % and the decode step from
+    10.0 to 7.3 ms."""
+    if impl not in ATTENTION_IMPLS:
+        raise ValueError(
+            f"attention_impl {impl!r} is not one of {ATTENTION_IMPLS}")
     if spec.layer_kinds:
         # latent rows (576 wide: no multiple of 128 lanes) and recurrent
         # state: XLA's path, whatever the backend (ops/mla.py)
@@ -101,14 +122,14 @@ def resolve_attention_impl(impl: str, backend: str, spec,
             raise ValueError(
                 f"attention_impl {impl!r}: a per-layer (hybrid) spec runs "
                 "its latent attention on the XLA path only")
-        return "xla"
-    if impl != "auto":
-        return impl
-    if (backend == "tpu" and decode_mode == "window" and not sharded
-            and not spec.sliding_window
-            and (spec.n_kv_heads * spec.head_dim) % 128 == 0):
-        return "pallas-decode"
-    return "xla"
+        return "hybrid", "xla"
+    if spec.sliding_window:
+        return "inline", "xla"
+    if impl == "auto":
+        kernel = (backend == "tpu" and not sharded
+                  and (spec.n_kv_heads * spec.head_dim) % 128 == 0)
+        impl = "pallas-decode" if kernel else "xla"
+    return ("dense" if impl == "xla" else "window"), impl
 
 
 class _Slot:
@@ -161,7 +182,7 @@ class _PrefillProgress:
 
 
 class _ChunkEntry:
-    """One dispatched decode/mixed chunk's packed output in flight to the
+    """One dispatched decode chunk's packed output in flight to the
     host, plus everything needed to process it later. Sub-chunk streaming
     (ISSUE 13) splits chunk processing in two: ``_harvest_chunk`` (the
     token half — blocking read, token/logprob appends, stop scan, stream
@@ -263,18 +284,9 @@ class ContinuousEngine:
                             # admitting traffic (mismatch raises
                             # ArtifactCorruptError, never serves wrong
                             # numerics)
-        draft_spec=None,          # async speculation (cfg.spec_async):
-        draft_params=None,        # explicit drafter pair; None builds
-                            # one from cfg.spec_draft_model
-                            # (engine/spec_async.py resolve_draft)
     ) -> None:
         self.config = config or EngineConfig()
         cfg = self.config
-        if cfg.decode_mode not in ("window", "inline"):
-            # before param init/artifact restore: a typo'd mode must not
-            # pay an 8B-scale random init first
-            raise ValueError(
-                f"decode_mode {cfg.decode_mode!r} is not 'window'|'inline'")
         self.artifact_manifest: Optional[Dict[str, Any]] = None
         if (artifact_path is not None and spec is not None
                 and spec.layer_kinds):
@@ -288,6 +300,13 @@ class ContinuousEngine:
             if spec is None:
                 spec = a_spec
         self.spec = spec.validate()
+        # the ONE decode body this engine runs and the attention it implies
+        # ("auto" never survives): get_metrics()["attn_impl"] and the
+        # worker's device report. Before any weight exists: a mistyped
+        # string must not pay an 8B-scale init first.
+        self.body, self.attn_impl = resolve_decode_body(
+            cfg.attention_impl, jax.default_backend(), self.spec,
+            sharded=shard_fn is not None or kv_sharding is not None)
         # a spec with recurrent (KDA) layers keeps a per-slot state beside
         # its pages: every path that moves or shares PAGES alone would
         # resume a sequence on a state it does not have. Each fails here,
@@ -302,9 +321,6 @@ class ContinuousEngine:
                  bool(getattr(cfg, "kv_offload", False))),
                 ("prefill_chunk (chunked prefill continues from pages)",
                  bool(cfg.prefill_chunk)),
-                ("spec_async (a verify step yields several tokens)",
-                 bool(getattr(cfg, "spec_async", False))),
-                ("a draft model", draft_spec is not None),
             ) if on]
             if refused:
                 raise ValueError(
@@ -313,10 +329,9 @@ class ContinuousEngine:
         # defer_sync needs a fully backed pool: host lengths go one chunk
         # stale, and only a pool that can always grow every slot to
         # max_seq_len guarantees a chunk never writes past reserved pages.
-        # Checked here (cfg+spec only) for the same pay-nothing-first
-        # reason as decode_mode; re-asserted against the pool's own
-        # max_pages_per_seq after construction so the two formulas cannot
-        # silently diverge.
+        # Checked here (cfg+spec only), before an 8B-scale init is paid
+        # for; re-asserted against the pool's own max_pages_per_seq after
+        # construction so the two formulas cannot silently diverge.
         if cfg.defer_sync and cfg.num_pages < cfg.max_slots * (
                 -(-min(cfg.max_seq_len, spec.max_seq_len)
                   // cfg.page_size)):
@@ -371,12 +386,6 @@ class ContinuousEngine:
             {b for b in cfg.prefill_buckets if b < max_seq} | {max_seq}
         )
         self.max_seq_len = max_seq
-        # the RESOLVED path ("auto" never survives): reported by
-        # get_metrics()["attn_impl"] and the worker's device report
-        self.attn_impl = resolve_attention_impl(
-            cfg.attention_impl, jax.default_backend(), self.spec,
-            cfg.decode_mode,
-            sharded=shard_fn is not None or kv_sharding is not None)
         self.prefix_cache = bool(cfg.prefix_cache)
         # a page hit without the recurrent state AT THAT POSITION is a
         # wrong answer: prefix reuse is off for such a spec, from the spec,
@@ -576,68 +585,25 @@ class ContinuousEngine:
             return jnp.stack(
                 [first, jax.lax.bitcast_convert_type(lp, jnp.int32)]), ks, vs
 
-        # mixed ragged dispatch (ops/ragged_attention.py): decode rows
-        # (q=1) and prefill-chunk rows (q=chunk) share ONE pallas_call per
-        # step, so admitting a long prompt no longer preempts decode for a
-        # whole suffix dispatch (ISSUE 3 / Sarathi). Pure-decode chunks —
-        # no prefill in flight — fall back to the q=1-specialised
-        # flash-decode kernel (same DMA pipeline, no per-row query pad).
-        if self.attn_impl.startswith("pallas-ragged"):
-            if spec_.sliding_window:
-                raise ValueError(
-                    "attention_impl='pallas-ragged' does not support "
-                    "sliding-window models: the ragged kernel carries no "
-                    "window mask (every context page is live). Use "
-                    "attention_impl='xla' for sliding-window specs."
-                )
-            decode_impl = "pallas-decode" + (
-                "_interpret" if self.attn_impl.endswith("_interpret")
-                else "")
-        else:
-            decode_impl = self.attn_impl
-        self._mixed = (self.attn_impl.startswith("pallas-ragged")
-                       and self._chunk > 0)
-        # decode megastep (ISSUE 5a): fold RMSNorm into the QKV / gate-up
-        # matmul and the residual add into the out/down projection for
-        # plain-weight layers (ops/fused_decode.py — bit-identical by
-        # construction; quantized layers keep their Mosaic kernels)
-        decode_fused = bool(getattr(cfg, "decode_fused", False))
-        fwd = partial(forward_decode_paged, attn_impl=decode_impl,
-                      fused=decode_fused)
-        fwd_window = partial(forward_decode_window, attn_impl=decode_impl,
-                             fused=decode_fused)
-        # Windowed chunks freeze the page pools for the duration of a decode
-        # chunk — the per-step page scatter they replace held decode at ~28%
-        # of the dense engine's throughput at 8B bs64. Small-KV models
-        # (GPT-2-class) measure faster with the inline scatter
-        # (decode_mode="inline"); sliding-window specs always run inline
-        # (their prefix mask depends on the growing total length).
-        #
-        # XLA window path (round 3): the frozen prefix is gathered from the
-        # pages ONCE per chunk into a dense [L, B, Sb+W, Hkv, Dh] working
-        # buffer (Sb = a page bucket covering the longest live prefix) and
-        # the chunk runs the static engine's dense decode against it —
-        # in-place scatter at each slot's absolute position, one attention
-        # over prefix+fresh, no per-step paged gather and no flash-stats
-        # merge. Round 2 gathered the pages EVERY step (pool read +
-        # gathered-copy write + attention read ≈ 3x the KV bytes each step,
-        # every layer) and ran a second attention over a side window plus a
-        # merge — the 0.48-vs-0.64 HBM-roofline gap VERDICT r2 item 1
-        # pinned down. Fresh KV is written back to the pages once per chunk
-        # (write_prefill_pages), identically to the side-window scheme.
-        # The Pallas attention impls keep the side-window scheme (their
-        # kernel's operand is the page pool itself): no dense copy, no
-        # per-layer slice, and the decode program does not depend on the
-        # context's page bucket (n_ctx_pages stays 0: one program per
-        # n_steps). On a TPU that is what "auto" resolves to
-        # (resolve_attention_impl); the dense path below remains the
-        # reference the kernel is pinned to, and what every other
-        # backend, sliding window and odd width runs.
-        use_window = (cfg.decode_mode == "window"
-                      and not spec_.sliding_window)
-        use_dense_ctx = (use_window and not spec_.layer_kinds
-                         and not self.attn_impl.startswith("pallas"))
-        self._use_dense_ctx = use_dense_ctx
+        # The uniform bodies (resolve_decode_body). ``dense`` and ``window``
+        # freeze the page pools for a chunk and write the chunk's fresh K/V
+        # back once at its end (write_prefill_pages): the per-step page
+        # scatter they replace held decode at ~28% of the dense engine's
+        # throughput at 8B bs64.
+        # - dense: the frozen prefix is gathered from the pages ONCE per
+        #   chunk into a [L, B, Sb+W, Hkv, Dh] working buffer (Sb = a page
+        #   bucket covering the longest live prefix) and the chunk runs the
+        #   static engine's decode against it: one program per (n_steps,
+        #   context-page bucket). The reference the kernel is pinned to.
+        # - window: the kernel's operand is the page pool itself; fresh K/V
+        #   collects in a side window. No dense copy, no per-layer slice,
+        #   and the program does not depend on the context's page bucket
+        #   (n_ctx_pages stays 0: one program per n_steps).
+        # - inline: fresh K/V is scattered into the pages every step (a
+        #   sliding-window prefix mask depends on the growing length).
+        body = self.body
+        fwd_window = partial(forward_decode_window,
+                             interpret=self.attn_impl.endswith("_interpret"))
         # decode chunks dispatched; get_metrics() reports them by how
         # attention reached the context: a Pallas kernel reading the page
         # pool where it lies, or the dense per-chunk copy
@@ -685,7 +651,7 @@ class ContinuousEngine:
                               use_stops=use_stops)
 
             keys = jax.random.split(key, n_steps)
-            if use_dense_ctx:
+            if body == "dense":
                 s_ctx = n_ctx_pages * page_size
                 pt = page_table[:, :n_ctx_pages]
                 # one gather per chunk; the buffer stays in the cache dtype
@@ -713,8 +679,7 @@ class ContinuousEngine:
                     # into their OWN row (clamped in-bounds) — discarded by
                     # the zero writeback count below.
                     hidden, ctx_k, ctx_v = forward_decode(
-                        spec_, params, last, lengths, ctx_k, ctx_v,
-                        fused=decode_fused)
+                        spec_, params, last, lengths, ctx_k, ctx_v)
                     logits = unembed(spec_, params, hidden)
                     next_tok, lp = sample_tokens_with_logprobs(
                         logits, sampling, step_key)
@@ -744,13 +709,13 @@ class ContinuousEngine:
                 def step(carry, step_key):
                     kp, vp, side_k, side_v, lengths, last, active, produced \
                         = carry
-                    if use_window:
+                    if body == "window":
                         hidden, side_k, side_v = fwd_window(
                             spec_, params, last, lengths, start_lengths,
                             kp, vp, page_table, side_k, side_v, active,
                         )
                     else:
-                        hidden, kp, vp = fwd(
+                        hidden, kp, vp = forward_decode_paged(
                             spec_, params, last, lengths, kp, vp, page_table,
                             active,
                         )
@@ -762,7 +727,7 @@ class ContinuousEngine:
                     return ((kp, vp, side_k, side_v, new_len, last, active,
                              produced), (emitted, lp))
 
-                w = n_steps if use_window else 1      # dummy when unused
+                w = n_steps if body == "window" else 1    # dummy when unused
                 side_k = jnp.zeros((L, b, w, Hkv, Dh), spec_.jnp_dtype)
                 side_v = jnp.zeros_like(side_k)
                 carry, (toks, lps) = jax.lax.scan(
@@ -773,7 +738,7 @@ class ContinuousEngine:
                 )
                 kp, vp, side_k, side_v, lengths, last, active, produced = \
                     carry
-                if use_window:
+                if body == "window":
                     # one batched scatter merges the chunk's fresh KV into
                     # the pages (0.03 ms at 8B bs64 — vs ~45 ms/step for
                     # per-step writes); inactive-slot garbage past each
@@ -792,76 +757,6 @@ class ContinuousEngine:
                  active[None].astype(jnp.int32), lengths[None], firsts],
                 axis=0)
             return (kp, vp, lengths, last, active, produced), packed
-
-        @partial(jax.jit, static_argnames=("use_stops",),
-                 donate_argnums=(1, 2, 3, 4, 5, 6))
-        def _mixed_chunk(
-            params, kp, vp, lengths, last_tokens, active, produced,
-            page_table, cap, max_new, sampling, eos_ids, stop_mat, firsts,
-            pf_tokens, pf_ctx, pf_qlens, pf_tables, pf_sampling, key,
-            use_stops: bool = False,
-        ):
-            """One MIXED step: every decode slot (q<=1 rows) plus up to Rp
-            in-flight prefill chunks (q=chunk rows) run through ONE
-            forward_mixed_step dispatch — prefill rides in the decode
-            step's bandwidth shadow instead of preempting it for a whole
-            suffix program (ISSUE 3 / Sarathi). The decode batch is fixed
-            at max_slots, so compilation count is bounded by
-            (pf-row pow2 bucket) x (chunk bucket) — audited by
-            ``_mixed_programs`` and the compile-count guard test.
-
-            Decode rows advance exactly one token with the same
-            bookkeeping as ``_decode_chunk``'s per-step ``advance``; the
-            packed output row layout matches ``_process_packed`` at
-            n_steps=1. Prefill rows return their last-position sample as a
-            separate [2, Rp] buffer (token row; logprob-bits row) — the
-            chunked-prefill harvest uses it only for rows whose chunk
-            completes the prompt, mirroring ``_advance_group``."""
-            qb = pf_tokens.shape[1]
-            b = lengths.shape[0]
-            # decode rows: fresh token = last sampled, at position length.
-            # Inactive slots are inert (q_len=0, ctx=0): the kernel zeroes
-            # their output and writes no KV.
-            tokens = jnp.zeros((b, qb), jnp.int32).at[:, 0].set(last_tokens)
-            tokens = jnp.concatenate([tokens, pf_tokens], axis=0)
-            ctx = jnp.concatenate(
-                [jnp.where(active, lengths, 0), pf_ctx], axis=0)
-            qlens = jnp.concatenate(
-                [active.astype(jnp.int32), pf_qlens], axis=0)
-            table = jnp.concatenate([page_table, pf_tables], axis=0)
-            hidden, kp, vp = forward_mixed_step(
-                spec_, params, tokens, ctx, qlens, kp, vp, table,
-                attn_impl=self.attn_impl)
-            logits = unembed(spec_, params, hidden)
-            k1, k2 = jax.random.split(key)
-            next_tok, lp = sample_tokens_with_logprobs(
-                logits[:b], sampling, k1)
-            pf_tok, pf_lp = sample_tokens_with_logprobs(
-                logits[b:], pf_sampling, k2)
-            # one step of _decode_chunk's `advance` bookkeeping (kept in
-            # lockstep by the engine-equivalence test)
-            was_active = active
-            produced = produced + was_active.astype(jnp.int32)
-            hit_eos = (next_tok == eos_ids) & (eos_ids >= 0)
-            new_len = lengths + was_active.astype(jnp.int32)
-            done = (hit_eos | (produced >= max_new)
-                    | (new_len >= cap))
-            if use_stops:
-                done = done | ((next_tok[:, None] == stop_mat)
-                               & (stop_mat >= 0)).any(axis=-1)
-            active = was_active & ~done
-            last = jnp.where(was_active, next_tok, last_tokens)
-            emitted = jnp.where(was_active, next_tok, -1)
-            lp = jnp.where(was_active, lp, 0.0)
-            packed = jnp.concatenate(
-                [emitted[None],
-                 jax.lax.bitcast_convert_type(lp, jnp.int32)[None],
-                 active[None].astype(jnp.int32), new_len[None], firsts],
-                axis=0)
-            pf_first = jnp.stack(
-                [pf_tok, jax.lax.bitcast_convert_type(pf_lp, jnp.int32)])
-            return ((kp, vp, new_len, last, active, produced), packed,
-                    pf_first)
 
         if spec_.layer_kinds:
             # ---- per-layer (hybrid) spec: the SAME two programs by name,
@@ -936,93 +831,6 @@ class ContinuousEngine:
                      jnp.broadcast_to(moe[:, None], (3, b))], axis=0)
                 return (kp, vp, lengths, last, active, produced), packed
 
-        spec_k = int(getattr(cfg, "spec_max_draft", 4) or 4)
-
-        @partial(jax.jit, static_argnames=("use_stops",),
-                 donate_argnums=(1, 2, 3, 4, 5, 6))
-        def _verify_chunk(params, kp, vp, lengths, last_tokens, active,
-                          produced, page_table, cap, max_new, sampling,
-                          eos_ids, stop_mat, firsts, drafts, q_probs,
-                          n_drafts, key, use_stops: bool = False):
-            """One VERIFY step (ISSUE 15, async speculation): every slot
-            runs through one ragged ``forward_mixed_step`` dispatch —
-            drafted slots as ``1 + n_drafts`` query columns
-            ``[last, d_0..d_{m-1}]`` at positions ``[L, L+m]``, plain
-            slots as the usual q=1 decode row (``n_drafts == 0``),
-            inactive slots inert (q=0). The target distributions at all
-            window positions come out of the ONE forward; acceptance is
-            the shared rejection rule (``engine.spec_accept``), so the
-            emitted run is drafts[:n_acc] then one target-sampled
-            token — greedy rows are token-for-token the plain engine's
-            chain, and plain rows reduce to exactly the non-speculative
-            sample (zeroed q makes the residual equal p).
-
-            Emission replays ``_decode_chunk``'s per-step ``advance``
-            over the ``spec_max_draft + 1`` window positions so
-            eos/budget/cap/stop cuts land with identical ordering; the
-            packed layout matches ``_process_packed`` at that n_steps
-            with ONE extra trailing row (per-slot ``n_acc``) the
-            speculator reads off the same blocking host read."""
-            kd = spec_k
-            b = lengths.shape[0]
-            tokens = jnp.concatenate([last_tokens[:, None], drafts],
-                                     axis=1)                  # [B, kd+1]
-            ctx = jnp.where(active, lengths, 0)
-            qlens = jnp.where(active, 1 + n_drafts, 0)
-            x, kp, vp = forward_mixed_step(
-                spec_, params, tokens, ctx, qlens, kp, vp, page_table,
-                attn_impl=self.attn_impl, return_hidden_all=True)
-            logits = unembed(spec_, params, x)            # [B, kd+1, V]
-            p_probs = masked_sampling_probs(logits, sampling)
-            greedy = sampling.temperature <= 0.0
-            k_resid, k_bonus = jax.random.split(key)
-            valid = jnp.arange(kd)[None, :] < n_drafts[:, None]
-            qz = jnp.where(valid[:, :, None], q_probs, 0.0)
-            n_acc, final, _acc = rejection_accept(
-                p_probs, qz, drafts, greedy, k_resid, k_bonus,
-                valid=valid)
-            bidx = jnp.arange(b)
-            cand = jnp.concatenate(
-                [drafts, jnp.zeros((b, 1), jnp.int32)], axis=1)
-            cand = cand.at[bidx, n_acc].set(final)        # [B, kd+1]
-            # untempered logprob at each emitted position — the same
-            # convention as sample_tokens_with_logprobs
-            lp_all = jax.nn.log_softmax(logits.astype(jnp.float32),
-                                        axis=-1)
-            lp_cand = jnp.take_along_axis(
-                lp_all, cand[:, :, None], axis=-1)[..., 0]
-            in_run = jnp.arange(kd + 1)[:, None] <= n_acc[None, :]
-
-            def emit(carry, inp):
-                lengths, last, active, produced = carry
-                tok_j, lp_j, run_j = inp
-                em = active & run_j
-                produced = produced + em.astype(jnp.int32)
-                hit_eos = (tok_j == eos_ids) & (eos_ids >= 0)
-                new_len = lengths + em.astype(jnp.int32)
-                done = (hit_eos | (produced >= max_new)
-                        | (new_len >= cap))
-                if use_stops:
-                    done = done | ((tok_j[:, None] == stop_mat)
-                                   & (stop_mat >= 0)).any(axis=-1)
-                # unlike _decode_chunk, a row can be active but PAST its
-                # accepted run (run_j False): its stale done conditions
-                # must not retire it, hence the em mask
-                active = active & ~(em & done)
-                last = jnp.where(em, tok_j, last)
-                emitted = jnp.where(em, tok_j, -1)
-                lp_o = jnp.where(em, lp_j, 0.0)
-                return (new_len, last, active, produced), (emitted, lp_o)
-
-            (lengths, last, active, produced), (toks, lps) = jax.lax.scan(
-                emit, (lengths, last_tokens, active, produced),
-                (cand.T, lp_cand.T, in_run))
-            packed = jnp.concatenate(
-                [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
-                 active[None].astype(jnp.int32), lengths[None], firsts,
-                 n_acc[None]], axis=0)
-            return (kp, vp, lengths, last, active, produced), packed
-
         @partial(jax.jit, donate_argnums=tuple(range(11)))
         def _install(lengths, last, active, produced, max_new, eos,
                      temps, top_k, top_p, min_p, stops, slots, vals):
@@ -1093,15 +901,6 @@ class ContinuousEngine:
         self._prefill_pages = None if has_sp else _prefill_pages
         self._prefill_suffix = _prefill_suffix
         self._decode_chunk = _decode_chunk
-        self._mixed_chunk = _mixed_chunk if self._mixed else None
-        self._verify_chunk = _verify_chunk
-        # mixed-step chunk buckets: each prefill row pads its suffix to one
-        # of these (the ragged kernel's max_q); short tails reuse the
-        # smaller prefill buckets instead of always padding to the full
-        # chunk
-        self._mixed_q_buckets = (sorted(
-            {b for b in self.prefill_buckets if b < self._chunk}
-            | {self._chunk}) if self._chunk else [1])
 
         # ---- metrics
         self.prefill_stats = LatencyStats()
@@ -1119,11 +918,6 @@ class ContinuousEngine:
         self._swap_fallbacks = 0    # host budget refused a swap -> "length"
         self._steps = 0
         self._prefill_calls = 0     # batched-admission dispatches
-        self._mixed_steps = 0       # mixed ragged dispatches
-        self._mixed_prefill_tokens = 0  # prefill tokens they carried
-        # (pf-rows bucket, chunk bucket) keys actually dispatched — the
-        # compile-count guard test audits this against the bucket grids
-        self._mixed_programs: set = set()
         self._occupancy_sum = 0     # Σ live slots per step (occupancy)
         self.ttft_stats = LatencyStats()   # per-request, from submit
         self.queue_wait_stats = LatencyStats()   # submit -> admitted
@@ -1146,13 +940,11 @@ class ContinuousEngine:
         self._host_gap_s = 0.0
         self._last_dispatch_end: Optional[float] = None
         # overlap hook (ISSUE 5c): called on the ENGINE thread right
-        # after each chunk/mixed dispatch, while the device is busy. The
+        # after each chunk dispatch, while the device is busy. The
         # serving pump wires its inbox drain (batch formation) here so
         # admission work rides the device step's shadow instead of the
-        # gap between steps. The hook must only enqueue (engine.submit),
-        # poll the stream ring, or dispatch async draft rounds
-        # (speculator.schedule — enqueue-only device work); it must NOT
-        # call step()/install paths.
+        # gap between steps. The hook must only enqueue (engine.submit)
+        # or poll the stream ring; it must NOT call step()/install paths.
         self.overlap_hook: Optional[Any] = None
         # sub-chunk streaming counters (ISSUE 13): ring traffic, the
         # clamp engagements, and firsts-buffer device fetches (the
@@ -1164,36 +956,6 @@ class ContinuousEngine:
         self._ring_high_water = 0    # max ring depth observed
         self._stream_clamped_chunks = 0   # chunks shortened for streaming
         self._firsts_fetches = 0     # whole-buffer firsts readbacks
-
-        # ---- bubble-scheduled async speculation (ISSUE 15 / ROADMAP 5)
-        # dispatched-but-unprocessed decode/mixed/verify chunks: while
-        # nonzero the host state lags the device frontier, so the
-        # speculator restricts itself to draft-cache catch-up (proposing
-        # from a stale basis would only be wasted at verify time)
-        self._inflight_chunks = 0
-        self._spec_verify_steps = 0
-        self.speculator = None
-        if bool(getattr(cfg, "spec_async", False)):
-            if self._defer:
-                raise ValueError(
-                    "spec_async requires defer_sync=False: proposals "
-                    "need the live host frontier, which deferral keeps "
-                    "one chunk stale")
-            if self.spec.sliding_window:
-                raise ValueError(
-                    "spec_async does not support sliding-window "
-                    "attention (the ragged verify path rejects it)")
-            from .spec_async import AsyncSpeculator, resolve_draft
-
-            if draft_spec is None or draft_params is None:
-                draft_spec, draft_params = resolve_draft(
-                    self.spec, self.params,
-                    getattr(cfg, "spec_draft_model", ""))
-            self.speculator = AsyncSpeculator(
-                self, draft_spec, draft_params, k=spec_k,
-                bubble_floor_s=float(
-                    getattr(cfg, "spec_bubble_floor_s", 5e-4)),
-                seed=seed)
 
         if self.artifact_manifest is not None and artifact_selfcheck:
             # golden-token self-check BEFORE any traffic: replays the
@@ -1547,28 +1309,6 @@ class ContinuousEngine:
         cap = self.config.admission_max_rows
         return min(self.max_slots, cap) if cap else self.max_slots
 
-    def _should_hold_admissions(self) -> bool:
-        """Admission coalescing (``admission_min_batch``): near saturation
-        a 4-8-row admission prefill runs far below the batched-prefill
-        rate, so waiting ~a chunk for batch-mates trades a little queue
-        latency for MXU-shaped prefill batches. Never holds when the
-        decode batch is running under half-occupied (a hungry engine
-        beats a bigger prefill), and never past ``admission_max_hold_s``
-        for the oldest waiting request."""
-        mb = self.config.admission_min_batch
-        if not mb or not self._waiting:
-            return False
-        live = len(self._slots) + len(self._prefilling)
-        # the admission batch is capped by free slots: once the queue can
-        # already fill them, holding adds TTFT with zero batching gain
-        if len(self._waiting) >= min(mb, self.max_slots - live):
-            return False
-        if live * 2 < self.max_slots:
-            return False                       # engine hungry: admit now
-        oldest_t = self._waiting[0][2]
-        return (time.perf_counter() - oldest_t
-                < self.config.admission_max_hold_s)
-
     def _try_admit(self) -> int:
         """Prefill waiting requests into free slots; returns #admitted.
 
@@ -1584,8 +1324,6 @@ class ContinuousEngine:
             # they resume first, before new admissions drain the pool
             self._resume_swapped()
         admitted = self._admit_prefilled()
-        if self._should_hold_admissions():
-            return admitted
         # rows: (req, cb, slot, tokens-to-prefill, t_submit, full_prompt);
         # full_prompt is None for whole-prompt admissions, the complete
         # prompt for the FIRST CHUNK of a chunked admission (which rides
@@ -1964,158 +1702,6 @@ class ContinuousEngine:
                                            len(prog.prompt), first))
         self._install_device(rows)
 
-    # -------------------------------------------------------- mixed step
-
-    def _step_mixed(self) -> None:
-        """One MIXED engine iteration (``attn_impl="pallas-ragged"`` with
-        chunked prefills in flight): active decode slots and pending
-        ``_PrefillProgress`` chunks run through ONE ``_mixed_chunk``
-        dispatch instead of the alternating ``_advance_chunked()`` +
-        decode-chunk pair — decode advances exactly one token while
-        prefill chunks ride in its bandwidth shadow (ISSUE 3 / Sarathi).
-
-        ``config.mixed_step_tokens`` caps the PREFILL tokens packed per
-        step at row granularity (oldest progress first, always at least
-        one row) so a burst of long prompts throttles to leftover compute
-        instead of monopolising the dispatch. The mixed path always
-        processes its packed output synchronously — at one decode token
-        per dispatch there is no chunk-deep pipeline for ``defer_sync``
-        to overlap, so a pending deferred chunk from a preceding
-        pure-decode step is flushed first."""
-        sp = self._dispatch_span("engine.mixed.dispatch",
-                                 live_slots=len(self._slots),
-                                 prefilling=len(self._prefilling))
-        t0 = sp.t0
-        if self._pending is not None:
-            # selection + capacity below need CURRENT host state
-            prev, self._pending = self._pending, None
-            self._process_packed(prev)
-
-        # --- select prefill rows FIFO under the token budget
-        budget = int(getattr(self.config, "mixed_step_tokens", 0) or 0)
-        sel: List[Tuple[int, _PrefillProgress, List[int]]] = []
-        spent = 0
-        for slot, prog in self._prefilling.items():
-            sfx = prog.prompt[prog.done: prog.done + self._chunk]
-            if budget and sel and spent + len(sfx) > budget:
-                break
-            sel.append((slot, prog, sfx))
-            spent += len(sfx)
-
-        # --- decode capacity: one more token of page backing per active
-        # slot (the mixed program advances exactly one step)
-        retired: List[int] = []
-        for slot in list(self._slots):
-            state = self._slots.get(slot)
-            if state is None:
-                continue
-            cur = int(self._lengths_host[slot])
-            cap_tok = self.kv.ensure_capacity(slot, cur + 1)
-            if cap_tok <= cur:
-                if self._try_swap_out(slot):
-                    retired.append(slot)       # deactivate, no finish
-                else:
-                    self._capacity_finishes += 1
-                    retired.append(slot)
-                    self._finish(slot, "length")
-        self._deactivate_many(retired)
-
-        # --- prefill rows: the ragged kernel's epilogue DMAs each row's
-        # fresh KV straight into its pages, so the backing must cover the
-        # chunk BEFORE dispatch (admission reserved the prompt's pages;
-        # ensure_backed turns a violated reservation into a loud error
-        # instead of silent pool corruption)
-        for slot, prog, sfx in sel:
-            self.kv.ensure_capacity(slot, prog.done + len(sfx))
-            self.kv.ensure_backed(slot, prog.done + len(sfx))
-
-        n = len(sel)                           # >= 1: caller checked
-        rpb = 1 << (n - 1).bit_length() if n > 1 else 1
-        qb = _next_bucket(max(len(s) for _, _, s in sel),
-                          self._mixed_q_buckets)
-        self._mixed_programs.add((rpb, qb))
-        mp = self.kv.max_pages_per_seq
-        pf_tokens = np.zeros((rpb, qb), np.int32)
-        pf_ctx = np.zeros((rpb,), np.int32)
-        pf_qlens = np.zeros((rpb,), np.int32)   # pad rows q_len=0: inert
-        pf_tables = np.zeros((rpb, mp), np.int32)
-        temps = np.zeros((rpb,), np.float32)
-        top_k = np.zeros((rpb,), np.int32)
-        top_p = np.ones((rpb,), np.float32)
-        min_p = np.zeros((rpb,), np.float32)
-        for i, (slot, prog, sfx) in enumerate(sel):
-            pf_tokens[i, : len(sfx)] = sfx
-            pf_ctx[i] = prog.done
-            pf_qlens[i] = len(sfx)
-            pf_tables[i] = self.kv._table[slot]
-            req = prog.request
-            temps[i] = req.temperature
-            top_k[i] = req.top_k
-            top_p[i] = req.top_p
-            min_p[i] = req.min_p
-        pf_sampling = SamplingParams(
-            jnp.asarray(temps), jnp.asarray(top_k),
-            jnp.asarray(top_p), jnp.asarray(min_p))
-
-        self._steps += 1
-        self._mixed_steps += 1
-        self._mixed_prefill_tokens += spent
-        self._occupancy_sum += len(self._slots)
-        cap_list = [min(self.kv.slot_capacity(s), self.max_seq_len)
-                    if s in self._slots else 0
-                    for s in range(self.max_slots)]
-        cap = jnp.asarray(cap_list, jnp.int32)
-        sampling = SamplingParams(self._temps, self._top_k, self._top_p,
-                                  self._min_p)
-        self._rng, kc = jax.random.split(self._rng)
-        self.kv.sync_tiers()
-        carry, packed, pf_first = self._mixed_chunk(
-            self.params, self.kv.k_pages, self.kv.v_pages,
-            self._lengths, self._last, self._active, self._produced,
-            self.kv.page_table, cap, self._max_new, sampling, self._eos,
-            self._stops_dev, self._firsts_dev, jnp.asarray(pf_tokens),
-            jnp.asarray(pf_ctx), jnp.asarray(pf_qlens),
-            jnp.asarray(pf_tables), pf_sampling, kc,
-            use_stops=bool(self._stop_slots),
-        )
-        kp, vp, self._lengths, self._last, self._active, self._produced = \
-            carry
-        self.kv.swap(kp, vp)
-        self._inflight_chunks += 1
-        # the device is busy with the dispatched step: let the serving
-        # layer form the next batch in its shadow (ISSUE 5c)
-        self._run_overlap_hook()
-        self._process_packed(_ChunkEntry(packed, 1, dict(self._slots), t0,
-                                         cap_list, True))
-
-        # --- prefill bookkeeping, mirroring _advance_group: only the LAST
-        # chunk's sample is the real first token
-        fp = None                     # read back only if someone finished
-        rows: List[Dict[str, Any]] = []
-        for i, (slot, prog, sfx) in enumerate(sel):
-            prog.done += len(sfx)
-            if prog.done < len(prog.prompt):
-                continue
-            del self._prefilling[slot]
-            if self.prefix_cache:
-                self.kv.register_prefix(slot, prog.prompt)
-            self._total_prompt_tokens += len(prog.prompt)
-            if fp is None:
-                # graftlint: ok[host-sync-hot-path] guarded by fp is None: ONE read per mixed-step prefill wave, not per row
-                fp = np.asarray(pf_first)     # [2, rpb]: token; lp bits
-            first = int(fp[0, i])
-            first_lp = float(fp[1].view(np.float32)[i])
-            if self._register_slot_host(prog.request, slot,
-                                        len(prog.prompt), first,
-                                        prog.t_submit, prog.t_admit,
-                                        prog.on_tokens,
-                                        first_lp=first_lp):
-                rows.append(self._slot_row(prog.request, slot,
-                                           len(prog.prompt), first))
-        self._install_device(rows)
-        self._tl_record(sp, program=("mixed", rpb, qb),
-                        prefill_rows=len(sel), prefill_tokens=spent)
-
     # ---------------------------------------------------------- streaming
 
     def _emit_stream(self, state: _Slot) -> int:
@@ -2460,9 +2046,8 @@ class ContinuousEngine:
         t0 = span.t0
         now = span.close(**args)
         # dispatch/gap accounting runs even with the ring disabled: the
-        # roofline split (bench.py), the engine_host_* metric families and
-        # the async speculator's bubble estimate depend on it, and it is
-        # two float adds per dispatch
+        # roofline split (bench.py) and the engine_host_* metric families
+        # depend on it, and it is two float adds per dispatch
         self._dispatch_s += now - t0
         if self._last_dispatch_end is not None:
             gap = t0 - self._last_dispatch_end
@@ -2477,12 +2062,7 @@ class ContinuousEngine:
         iteration. With ``defer_sync``, chunk k's packed output is read
         after dispatching chunk k+1 (the round trip overlaps device
         compute); host bookkeeping — finishes, host-side stops, streaming
-        — runs one chunk behind the device.
-
-        Under ``attn_impl="pallas-ragged"`` with chunked prefills in
-        flight, the step routes to ``_step_mixed`` instead: prefill
-        chunks and decode share one ragged dispatch rather than
-        alternating."""
+        — runs one chunk behind the device."""
         # one span over the whole iteration: the admission scan and the
         # capacity loop run before any bracket below opens
         with self._span("engine.step"):
@@ -2491,10 +2071,6 @@ class ContinuousEngine:
     @hot_path
     def _step(self) -> int:
         self._try_admit()
-        if self._mixed and self._prefilling:
-            self._step_mixed()
-            return (len(self._slots) + len(self._prefilling)
-                    + len(self._swapped))
         self._advance_chunked()
         if not self._slots:
             # drop a stale deferred chunk: when processing chunk N frees
@@ -2503,19 +2079,11 @@ class ContinuousEngine:
             # processing it would be a no-op, so release its device
             # buffer and _Slot references here instead of holding them
             # across an idle period
-            if self._pending is not None:
-                self._inflight_chunks = max(0, self._inflight_chunks - 1)
             self._pending = None
             self._ring.clear()
             return len(self._prefilling) + len(self._swapped)
         self._steps += 1
         self._occupancy_sum += len(self._slots)   # batch occupancy metric
-        if self.speculator is not None:
-            # step top = the inter-dispatch host gap, the one point
-            # where the host state IS the device frontier
-            # (_inflight_chunks == 0): draft PROPOSALS happen here;
-            # the overlap-hook call mid-flight only catches caches up
-            self.speculator.schedule()
 
         # capacity: grow every active slot toward a full chunk (two chunks
         # under defer_sync: the device may already be n_steps past the
@@ -2524,11 +2092,6 @@ class ContinuousEngine:
         n_steps = self.config.decode_steps_per_call
         lengths_np = self._lengths_host
         ahead = 2 * n_steps if self._defer else n_steps
-        if self.speculator is not None:
-            # a verify window writes KV at [L, L + spec_max_draft + 1):
-            # granting less would scatter through stale page-table
-            # entries into OTHER slots' pages
-            ahead = max(ahead, self.speculator.k + 1)
         retired: List[int] = []
         for slot in list(self._slots):
             state = self._slots.get(slot)
@@ -2591,17 +2154,6 @@ class ContinuousEngine:
             return (len(self._slots) + len(self._prefilling)
                     + len(self._swapped))
 
-        if self.speculator is not None:
-            ver = self.speculator.take_verifiable()
-            if ver is not None:
-                # pending proposals survive the freshness + capacity
-                # checks: this step verifies them instead of plain
-                # decoding — drafted slots advance up to n_acc + 1
-                # tokens in the one dispatch
-                self._step_verify(*ver)
-                return (len(self._slots) + len(self._prefilling)
-                        + len(self._swapped))
-
         sp = self._dispatch_span("engine.decode.dispatch", steps=n_steps,
                                  live_slots=len(self._slots))
         t0 = sp.t0
@@ -2610,7 +2162,7 @@ class ContinuousEngine:
                     for s in range(self.max_slots)]
         cap = jnp.asarray(cap_list, jnp.int32)
         mpb = 0
-        if self._use_dense_ctx:
+        if self.body == "dense":
             # dense working buffer covers the longest LIVE prefix, padded
             # to a pow2 page bucket (one compiled chunk per bucket) — NOT
             # max_pages_per_seq, so short-context rounds read short
@@ -2638,7 +2190,6 @@ class ContinuousEngine:
         )
         kp, vp, self._lengths, self._last, self._active, self._produced = carry
         self.kv.swap(kp, vp)
-        self._inflight_chunks += 1
         self._decode_chunks += 1
         # the chunk is in flight: overlap serving-side batch formation
         # with the device step (ISSUE 5c) before the blocking read below
@@ -2674,46 +2225,6 @@ class ContinuousEngine:
                         rows=len(snapshot), n_steps=n_steps)
         return (len(self._slots) + len(self._prefilling)
                 + len(self._swapped))
-
-    def _step_verify(self, drafts, q_probs, n_drafts, verified) -> None:
-        """Decode step carrying pending draft proposals as extra verify
-        columns (ISSUE 15): one ``_verify_chunk`` dispatch advances
-        drafted slots by their accepted run + one target token and every
-        other slot by one plain token. The packed layout matches
-        ``_process_packed`` at ``n_steps = spec_max_draft + 1``; the
-        trailing ``n_acc`` row rides the same blocking read, so the
-        acceptance metrics cost zero extra syncs."""
-        kd = self.speculator.k
-        sp = self._dispatch_span("engine.verify.dispatch", steps=kd + 1,
-                                 live_slots=len(self._slots))
-        t0 = sp.t0
-        cap_list = [min(self.kv.slot_capacity(s), self.max_seq_len)
-                    if s in self._slots else 0
-                    for s in range(self.max_slots)]
-        cap = jnp.asarray(cap_list, jnp.int32)
-        sampling = SamplingParams(self._temps, self._top_k, self._top_p,
-                                  self._min_p)
-        self._rng, kc = jax.random.split(self._rng)
-        self.kv.sync_tiers()
-        carry, packed = self._verify_chunk(
-            self.params, self.kv.k_pages, self.kv.v_pages,
-            self._lengths, self._last, self._active, self._produced,
-            self.kv.page_table, cap, self._max_new, sampling, self._eos,
-            self._stops_dev, self._firsts_dev, drafts, q_probs,
-            jnp.asarray(n_drafts), kc, use_stops=bool(self._stop_slots))
-        kp, vp, self._lengths, self._last, self._active, self._produced \
-            = carry
-        self.kv.swap(kp, vp)
-        self._inflight_chunks += 1
-        self._run_overlap_hook()
-        snapshot = dict(self._slots)
-        entry = _ChunkEntry(packed, kd + 1, snapshot, t0, cap_list, True)
-        self._process_packed(entry)
-        self._spec_verify_steps += 1
-        self.speculator.note_verified(entry, verified)
-        self._tl_record(sp,
-                        program=("verify", kd, bool(self._stop_slots)),
-                        rows=len(snapshot), n_steps=kd + 1)
 
     def poll_stream(self) -> int:
         """Drain ready stream-ring entries' TOKEN halves without blocking
@@ -2854,8 +2365,6 @@ class ContinuousEngine:
 
     def _judge_packed(self, entry: _ChunkEntry) -> None:
         """The judgments of ``_process_packed``, on a harvested entry."""
-        # counted at dispatch; processed exactly once per entry
-        self._inflight_chunks = max(0, self._inflight_chunks - 1)
         packed_np = entry.host
         n_steps = entry.n_steps
         caps = entry.caps
@@ -3111,9 +2620,6 @@ class ContinuousEngine:
             "capacity_finishes": self._capacity_finishes,
             "engine_steps": self._steps,
             "prefill_calls": self._prefill_calls,
-            "mixed_steps": self._mixed_steps,
-            "mixed_prefill_tokens": self._mixed_prefill_tokens,
-            "mixed_programs": len(self._mixed_programs),
             "prefix_hit_admissions": self._prefix_hit_admissions,
             # 1 when a deploy asked for prefix reuse over a spec with
             # recurrent layers: off from the spec, never a page hit
@@ -3157,19 +2663,6 @@ class ContinuousEngine:
             "stream_ring_depth": self._ring_high_water,
             "stream_clamped_chunks": self._stream_clamped_chunks,
             "firsts_fetches": self._firsts_fetches,
-            # async speculation (ISSUE 15): zeros when the drafter is
-            # off, so the metric family — and the observability drift
-            # catalog rows over it — exist unconditionally
-            **{f"spec_async_{k}": v for k, v in (
-                self.speculator.get_metrics()
-                if self.speculator is not None else {
-                    "drafted_tokens": 0, "accepted_tokens": 0,
-                    "wasted_tokens": 0, "catchup_tokens": 0,
-                    "accept_rate": 0.0, "draft_rounds": 0,
-                    "propose_rounds": 0, "auto_idles": 0,
-                    "bubble_consumed_s": 0.0, "draft_cost_ema_s": 0.0,
-                    "pending": 0}).items()},
-            "spec_async_verify_steps": self._spec_verify_steps,
             "ttft": self.ttft_stats.snapshot(),
             # submit -> slot held and prefill dispatched
             "queue_wait": self.queue_wait_stats.snapshot(),
@@ -3185,8 +2678,7 @@ class ContinuousEngine:
             # the page pool by a kernel, or through the dense copy
             "attn_impl": self.attn_impl,
             "decode_chunks_in_place": (
-                self._decode_chunks
-                if self.attn_impl.startswith("pallas") else 0),
+                self._decode_chunks if self.body == "window" else 0),
             "decode_chunks_dense": (
-                self._decode_chunks if self._use_dense_ctx else 0),
+                self._decode_chunks if self.body == "dense" else 0),
         }

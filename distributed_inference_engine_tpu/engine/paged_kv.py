@@ -327,24 +327,6 @@ class PagedKVCache:
         self._slot_len[slot] = max(self._slot_len[slot], min(target, cap))
         return cap
 
-    def ensure_backed(self, slot: int, n_tokens: int) -> None:
-        """Assert the slot's first ``n_tokens`` token positions are BACKED
-        by allocated pages — the mixed-step precondition: the ragged
-        kernel (``ops/ragged_attention.py``) DMAs each row's fresh K/V
-        into its pages blindly, so a dispatch with an unbacked row would
-        scribble on whatever page index 0 holds. Admission allocates a
-        prefilling slot's whole-prompt pages up front, so this is a cheap
-        invariant check, not an allocator; a violation is an engine bug
-        and raises rather than degrades."""
-        if slot not in self._slot_pages:
-            raise KeyError(f"slot {slot} not live")
-        backed = len(self._slot_pages[slot]) * self.page_size
-        if backed < n_tokens:
-            raise RuntimeError(
-                f"slot {slot} backed for {backed} tokens but the mixed "
-                f"step writes through {n_tokens}: fresh-KV writeback "
-                "would land outside the slot's reserved pages")
-
     def free_slot(self, slot: int) -> None:
         pages = self._slot_pages.pop(slot, None)
         if pages is None:

@@ -1,28 +1,21 @@
-"""Shared speculative-decoding acceptance math (Leviathan et al. / Chen
-et al. rejection sampling), extracted from the r5 synchronous engine so
-the async bubble-scheduled path (``engine/spec_async.py`` + the
-continuous engine's verify chunk) accepts with BIT-IDENTICAL rules.
+"""Speculative-decoding acceptance math (Leviathan et al. / Chen et al.
+rejection sampling) of the synchronous ``SpeculativeEngine``
+(``engine/speculative.py``).
 
 Two exactness contracts hang off this module, both pinned by tests:
 
 1. **r5 parity.** ``rejection_accept`` is the r5 ``_round_core``
-   acceptance block verbatim — same op order, same key usage — so the
-   synchronous ``SpeculativeEngine``'s outputs are unchanged by the
-   refactor (tests/test_spec_async.py pins this against a frozen copy).
+   acceptance block verbatim — same op order, same key usage
+   (tests/test_speculative.py pins this against a frozen copy).
 2. **Greedy chain identity.** For greedy rows the accept rule is
    ``argmax p_j == d_j`` and the final token is ``argmax`` of the
    final distribution, so the emitted run is token-for-token the
-   target's own greedy chain regardless of WHAT the draft proposed —
-   which is why draft-side state (async drafter caches, stale
-   proposals) can never corrupt output, only acceptance rate.
+   target's own greedy chain regardless of WHAT the draft proposed.
 
-The async path adds one degree of freedom the sync engine never needed:
-per-row ``valid`` masks. A verify batch mixes drafted rows (k draft
-columns) with plain decode rows (zero draft columns riding the same
-program); plain rows pass an all-False mask plus ZERO ``q_probs``, which
-drives the residual ``max(p - q, 0)`` to exactly ``p`` — their "final"
-token is then a plain sample from the target distribution, identical to
-the non-speculative decode step.
+Per-row ``valid`` masks let a batch mix rows with fewer (or no) draft
+columns: a row with an all-False mask and ZERO ``q_probs`` drives the
+residual ``max(p - q, 0)`` to exactly ``p``, so its "final" token is a
+plain sample from the target distribution.
 """
 
 from __future__ import annotations
@@ -69,7 +62,7 @@ def rejection_accept(
     knob-modified distributions (``masked_sampling_probs``) — identical
     masking is what makes the ratio exact for the request's settings.
 
-    ``valid`` (async path) force-rejects masked columns BEFORE the
+    ``valid`` force-rejects masked columns BEFORE the
     cumulative-run product, so a row with zero valid columns lands on
     ``n_acc == 0`` with its final drawn from position 0 — the plain
     decode sample when its ``q_probs`` row is zeros (see module doc).

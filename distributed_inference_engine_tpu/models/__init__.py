@@ -64,6 +64,38 @@ def spec_for_architecture(architecture: str, size: str = "",
     raise ValueError(f"unknown architecture {architecture!r}")
 
 
+# Metadata a deploy may still carry from before PR 29. Outside input is
+# checked, never silently ignored: each raises at load, by name.
+_RETIRED_KEYS = {
+    "decode_mode": "derived: inline if and only if the spec has a sliding "
+                   "window",
+    "mixed_step_tokens": "the ragged mixed step is gone (Mosaic refused its "
+                         "kernel on the chip); prefill_chunk alone chunks",
+    "spec_async": "async speculation is gone; metadata speculative=K "
+                  "selects the speculative engine",
+    "spec_draft_model": "async speculation is gone",
+    "spec_max_draft": "async speculation is gone",
+    "spec_bubble_floor_s": "async speculation is gone",
+}
+_RETIRED_ATTENTION = {
+    "pallas": "pallas-decode reads the page pool in place",
+    "pallas-decode-fw": "Mosaic refused the epilogue write on the chip",
+    "pallas-ragged": "Mosaic refused the kernel on the chip",
+}
+
+
+def _check_retired(metadata) -> None:
+    for key, why in _RETIRED_KEYS.items():
+        if key in metadata:
+            raise ValueError(f"metadata key {key!r} is retired: {why}")
+    impl = str(metadata.get("attention_impl", ""))
+    why = _RETIRED_ATTENTION.get(impl.removesuffix("_interpret"))
+    if why:
+        raise ValueError(
+            f"metadata attention_impl {impl!r} is retired: {why}; use "
+            "'auto', 'xla', 'pallas-decode' or 'pallas-decode_interpret'")
+
+
 def engine_from_config(cfg):
     """``ModelConfig`` → engine: the worker-side factory (replaces the
     reference's hard-wired ``FakeModel(config)``, ``src/worker.py:171``).
@@ -71,6 +103,7 @@ def engine_from_config(cfg):
     init — enough for perf work and smoke tests."""
     import os
 
+    _check_retired(cfg.metadata)
     arch = cfg.architecture.lower()
     if arch == "fake":
         # load_sleep_s models the checkpoint-read + prepare cost a real
@@ -113,12 +146,6 @@ def engine_from_config(cfg):
                     cfg.metadata.get("stream_chunk_tokens", 0)),
                 stream_dispatch_overhead_s=float(
                     cfg.metadata.get("stream_dispatch_overhead_s", 0.0)),
-                spec_async=bool(cfg.metadata.get("spec_async", False)),
-                spec_max_draft=int(cfg.metadata.get("spec_max_draft", 4)),
-                spec_accept_rate=float(
-                    cfg.metadata.get("spec_accept_rate", 0.7)),
-                spec_bubble_floor_s=float(
-                    cfg.metadata.get("spec_bubble_floor_s", 0.0)),
             )
         return FakeEngine(
             latency_s=float(cfg.metadata.get("latency_s", 0.0)),
@@ -174,11 +201,10 @@ def engine_from_config(cfg):
                         max_seq_len=cfg.max_seq_len)
     for k in ("page_size", "num_pages", "decode_steps_per_call",
               "attention_impl", "kv_dtype", "prefill_buckets",
-              "prefix_cache", "prefill_chunk", "decode_mode",
+              "prefix_cache", "prefill_chunk",
               "max_waiting", "queue_deadline_s",
-              "kv_offload", "kv_offload_bytes", "mixed_step_tokens",
-              "stream_chunk_steps", "spec_async", "spec_draft_model",
-              "spec_max_draft", "spec_bubble_floor_s", "admission_max_rows"):
+              "kv_offload", "kv_offload_bytes",
+              "stream_chunk_steps", "admission_max_rows"):
         if k in cfg.metadata:
             setattr(ecfg, k, cfg.metadata[k])
     if spec.layer_kinds:
@@ -391,7 +417,7 @@ def _hybrid_engine(cfg, spec, ecfg):
     continuous engine from a random tree keyed by ``metadata.seed``. What it
     cannot do yet fails here, before any weight exists; the engine refuses
     the ``ecfg`` keys it cannot honour for such a spec (kv_offload,
-    prefill_chunk, spec_async, a Pallas attention_impl)."""
+    prefill_chunk, a Pallas attention_impl)."""
     from ..engine.continuous import ContinuousEngine
 
     md = cfg.metadata

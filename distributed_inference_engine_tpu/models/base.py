@@ -379,116 +379,22 @@ def _out_proj(spec: ModelSpec, blk: Params, attn_out):
     return out
 
 
-# ------------------------------------------------ fused decode megastep
-
-# The decode-megastep variants of the three layer seams (ISSUE 5). Each
-# checks eligibility at TRACE time (plain weight, rms norm, no bias,
-# tileable shapes — ``ops.fused_decode``) and falls back to the exact
-# unfused helper chain otherwise, so quantized layers keep riding the
-# int4/int8 kernels and every ineligible shape stays bit-identical by
-# construction. The fused kernels replicate the unfused op sequence
-# bit-for-bit (see ops/fused_decode.py docstring), so ``fused=True`` is
-# a pure traffic optimization, not a numerics mode.
+# ------------------------------------------------- decode layer seams
 
 
-def _qkv_norm(spec: ModelSpec, blk: Params, x, positions, fused: bool = False):
-    """ln1 + QKV, the norm folded into the projection when eligible.
-
-    Plain trees carry SEPARATE wq/wk/wv (``fuse_block_weights`` only
-    concatenates int4 payloads), so the common fused shape is three
-    ``norm_matmul`` launches — each recomputes the fp32 RMS scale, a
-    [B, D] VPU reduction that is noise next to its weight stream, and
-    each reproduces the unfused ``rms_norm`` bits exactly, so q/k/v
-    match the shared-norm unfused chain bit-for-bit."""
-    if fused and spec.norm != "layernorm" and blk.get("ln1_bias") is None \
-            and not (spec.use_bias or spec.qkv_bias):
-        from ..ops.fused_decode import norm_matmul, norm_matmul_wants
-
-        b, t, d = x.shape
-        x2 = x.reshape(b * t, d)
-        H, Hkv, Dh = spec.n_heads, spec.n_kv_heads, spec.head_dim
-        nm = partial(norm_matmul, x2, blk["ln1_scale"], eps=spec.norm_eps,
-                     plus_one=spec.norm_plus_one)
-        qkv = None
-        if "w_qkv" in blk:
-            # pre-fused q|k|v (a plain checkpoint that stacked them):
-            # one N = (H+2Hkv)·Dh launch
-            if norm_matmul_wants(x2, blk["w_qkv"]):
-                qkv = nm(blk["w_qkv"])
-        elif all(norm_matmul_wants(x2, blk[m]) for m in ("wq", "wk", "wv")):
-            qkv = jnp.concatenate(
-                [nm(blk["wq"]), nm(blk["wk"]), nm(blk["wv"])], axis=-1)
-        if qkv is not None:
-            qkv = qkv.reshape(b, t, -1)
-            q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
-            q = q.reshape(b, t, H, Dh)
-            k = k.reshape(b, t, Hkv, Dh)
-            v = v.reshape(b, t, Hkv, Dh)
-            if spec.pos_emb == "rope":
-                # RoPE stays OUTSIDE the kernel: it permutes per-head
-                # lanes after the QKV split, and its operand is the [B,
-                # 1, H, Dh] activation — ~0.1% of the weight stream
-                q = apply_rope(q, positions, spec.rope_theta)
-                k = apply_rope(k, positions, spec.rope_theta)
-            return q, k, v
+def _qkv_norm(spec: ModelSpec, blk: Params, x, positions):
+    """ln1 + QKV of one decode step."""
     h = _norm(spec, x, blk["ln1_scale"], blk.get("ln1_bias"))
     return _qkv(spec, blk, h, positions)
 
 
-def _out_residual(spec: ModelSpec, blk: Params, attn_out, x,
-                  fused: bool = False):
-    """x + out_proj(attn), the residual folded into the projection's
-    epilogue when eligible."""
-    if fused and not spec.use_bias:
-        from ..ops.fused_decode import matmul_residual, matmul_residual_wants
-
-        b, t, h, dh = attn_out.shape
-        a2 = attn_out.reshape(b * t, h * dh)
-        if matmul_residual_wants(a2, blk["wo"]):
-            return matmul_residual(
-                a2, blk["wo"], x.reshape(b * t, -1)).reshape(x.shape)
+def _out_residual(spec: ModelSpec, blk: Params, attn_out, x):
+    """x + out_proj(attn)."""
     return x + _out_proj(spec, blk, attn_out)
 
 
-def _mlp_residual(spec: ModelSpec, blk: Params, x, fused: bool = False):
-    """ln2 + MLP + residual -> (new_x, moe_aux). Fused: ln2 rides the
-    gate/up projection's prologue and the residual add rides the down
-    projection's epilogue — the [B, D] stream between them never
-    round-trips HBM as separate fusions."""
-    if fused and spec.norm != "layernorm" and not spec.n_experts \
-            and not spec.use_bias and spec.mlp in ("swiglu", "geglu") \
-            and blk.get("ln2_bias") is None:
-        from ..ops.fused_decode import (
-            matmul_residual,
-            matmul_residual_wants,
-            norm_matmul,
-            norm_matmul_wants,
-        )
-
-        b, t, d = x.shape
-        x2 = x.reshape(b * t, d)
-        nm = partial(norm_matmul, x2, blk["ln2_scale"], eps=spec.norm_eps,
-                     plus_one=spec.norm_plus_one)
-        gate = up = None
-        if "w_gate_up" in blk:
-            if norm_matmul_wants(x2, blk["w_gate_up"]):
-                gate, up = jnp.split(nm(blk["w_gate_up"]), 2, axis=-1)
-        elif "w_gate" in blk and norm_matmul_wants(x2, blk["w_gate"]) \
-                and norm_matmul_wants(x2, blk["w_up"]):
-            # separate gate/up (plain trees: fuse_block_weights only
-            # stacks int4 payloads) — two launches, same recomputed-norm
-            # bit-parity argument as _qkv_norm
-            gate, up = nm(blk["w_gate"]), nm(blk["w_up"])
-        if gate is not None:
-            act = (jax.nn.silu if spec.mlp == "swiglu"
-                   else partial(jax.nn.gelu, approximate=True))
-            h = act(gate.astype(jnp.float32)).astype(x.dtype) * up
-            if matmul_residual_wants(h, blk["w_down"]):
-                out = matmul_residual(h, blk["w_down"], x2)
-                return out.reshape(b, t, d), jnp.float32(0.0)
-            out = matmul_any("btf,fd->btd", h.reshape(b, t, -1),
-                             blk["w_down"])
-            return x + out, jnp.float32(0.0)
+def _mlp_residual(spec: ModelSpec, blk: Params, x):
+    """ln2 + MLP + residual -> (new_x, moe_aux)."""
     h2 = _norm(spec, x, blk["ln2_scale"], blk.get("ln2_bias"))
     m, aux = _mlp(spec, blk, h2)
     return x + m, aux
@@ -706,136 +612,6 @@ def forward_prefill_suffix(
     return x, ks, vs
 
 
-def forward_mixed_step(
-    spec: ModelSpec,
-    params: Params,
-    tokens: jnp.ndarray,      # [R, Qm] per-row fresh tokens (right-padded)
-    ctx_lens: jnp.ndarray,    # [R] tokens already in the row's pages
-    q_lens: jnp.ndarray,      # [R] 0 = inert row, 1 = decode, >1 = chunk
-    k_pages: jnp.ndarray,     # [L, N, P, Hkv*Dh] paged pools — DONATED
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,  # [R, MP] int32
-    *,
-    attn_impl: str = "xla",
-    return_hidden_all: bool = False,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """ONE ragged mixed-batch step: decode rows (one token) and prefill-
-    chunk rows (many tokens) share a single forward against the paged
-    pools, and every row's fresh K/V lands in its reserved pages
-    (``ops/ragged_attention.py``). This is the program behind the
-    continuous engine's unified ``step()`` — prefill chunks ride in the
-    decode dispatch instead of preempting it.
-
-    Row r's token i sits at absolute position ``ctx_lens[r] + i``; rows
-    ``i >= q_lens[r]`` are padding. Returns (last hidden [R, D] — the
-    hidden at each row's LAST valid token, i.e. the next-token state —
-    plus the updated pools). Rows with ``q_lens == 0`` return garbage
-    hidden; callers mask them (the engine's ``active`` lattice).
-
-    ``return_hidden_all=True`` returns the WHOLE hidden lattice
-    [R, Qm, D] instead of the last-position gather — the async
-    speculative verify chunk (``engine/spec_async.py``) scores every
-    draft column's next-token distribution from one dispatch, so it
-    needs all positions, not just the frontier. Padding positions carry
-    garbage hidden; callers mask by ``q_lens`` exactly as for rows.
-
-    The pallas path streams context pages per layer inside the kernel
-    (stacked-pool ``layer=l`` calls, flat [L*N, P, fused] carry); the xla
-    path gathers the whole table per layer and scatters fresh K/V with
-    the absolute-sentinel drop trick (``forward_prefill_into_pages``).
-    Both round-trip fresh K/V through the pool dtype before attending so
-    they agree bit-for-bit on what the pages hold.
-    """
-    from ..ops.ragged_attention import ragged_attention
-
-    if spec.sliding_window:
-        raise ValueError(
-            "forward_mixed_step does not support sliding-window specs "
-            "(the ragged kernel has no window mask); use the split "
-            "prefill/decode path")
-    b, qm = tokens.shape
-    L = spec.n_layers
-    n, p = k_pages.shape[1], k_pages.shape[2]
-    fused = spec.n_kv_heads * spec.head_dim
-    mp = page_table.shape[1]
-    ctx_lens = ctx_lens.astype(jnp.int32)
-    q_lens = q_lens.astype(jnp.int32)
-    positions = ctx_lens[:, None] + jnp.arange(qm)[None, :]
-    x = embed(spec, params, tokens, positions)
-    xs_blocks, rebuild = split_indexed_blocks(params["blocks"])
-
-    if attn_impl.startswith("pallas-ragged"):
-        kp_flat = k_pages.reshape(L * n, p, fused)
-        vp_flat = v_pages.reshape(L * n, p, fused)
-
-        def body(carry, per_layer):
-            x, kpf, vpf = carry
-            xs_blk, l = per_layer
-            blk = rebuild(xs_blk, l)
-            h = _norm(spec, x, blk["ln1_scale"], blk.get("ln1_bias"))
-            q, k, v = _qkv(spec, blk, h, positions)
-            attn, kpf, vpf = ragged_attention(
-                q, kpf, vpf, page_table, ctx_lens, q_lens, k, v,
-                n_kv_heads=spec.n_kv_heads, impl=attn_impl,
-                layer=l, n_pages_per_layer=n)
-            x = x + _out_proj(spec, blk, attn)
-            h2 = _norm(spec, x, blk["ln2_scale"], blk.get("ln2_bias"))
-            m, _ = _mlp(spec, blk, h2)
-            return (x + m, kpf, vpf), None
-
-        (x, kp_flat, vp_flat), _ = lax.scan(
-            body, (x, kp_flat, vp_flat), (xs_blocks, jnp.arange(L)))
-        k_pages = kp_flat.reshape(L, n, p, fused)
-        v_pages = vp_flat.reshape(L, n, p, fused)
-    else:
-        # reference path: whole-table gather + suffix attention per layer,
-        # pools ride the carry as flat [L·N·P, fused] views
-        local = jnp.broadcast_to(jnp.arange(qm, dtype=jnp.int32)[None, :],
-                                 (b, qm))
-        q_valid = local < q_lens[:, None]
-        logical = jnp.minimum(positions // p, mp - 1)
-        phys = jnp.take_along_axis(page_table, logical, axis=1)
-        base_idx = phys * p + positions % p                    # [R, Qm]
-        gather_idx = (page_table[:, :, None] * p
-                      + jnp.arange(p)[None, None, :]).reshape(b, mp * p)
-        kp_flat = k_pages.reshape(L * n * p, fused)
-        vp_flat = v_pages.reshape(L * n * p, fused)
-
-        def body(carry, per_layer):
-            x, kpf, vpf = carry
-            xs_blk, l = per_layer
-            blk = rebuild(xs_blk, l)
-            h = _norm(spec, x, blk["ln1_scale"], blk.get("ln1_bias"))
-            q, k, v = _qkv(spec, blk, h, positions)
-            # pool-dtype round trip BEFORE attending (see docstring)
-            kq = k.astype(kpf.dtype)
-            vq = v.astype(vpf.dtype)
-            ck = kpf[l * (n * p) + gather_idx].reshape(
-                b, mp * p, spec.n_kv_heads, spec.head_dim)
-            cv = vpf[l * (n * p) + gather_idx].reshape(
-                b, mp * p, spec.n_kv_heads, spec.head_dim)
-            attn = suffix_attention(
-                q, ck.astype(q.dtype), cv.astype(q.dtype), ctx_lens,
-                kq.astype(q.dtype), vq.astype(q.dtype), q_lens)
-            x = x + _out_proj(spec, blk, attn)
-            h2 = _norm(spec, x, blk["ln2_scale"], blk.get("ln2_bias"))
-            m, _ = _mlp(spec, blk, h2)
-            idx = jnp.where(q_valid, l * (n * p) + base_idx, L * n * p)
-            kpf = kpf.at[idx].set(kq.reshape(b, qm, fused), mode="drop")
-            vpf = vpf.at[idx].set(vq.reshape(b, qm, fused), mode="drop")
-            return (x + m, kpf, vpf), None
-
-        (x, kp_flat, vp_flat), _ = lax.scan(
-            body, (x, kp_flat, vp_flat), (xs_blocks, jnp.arange(L)))
-        k_pages = kp_flat.reshape(L, n, p, fused)
-        v_pages = vp_flat.reshape(L, n, p, fused)
-
-    if return_hidden_all:
-        return x, k_pages, v_pages                             # [R, Qm, D]
-    last = x[jnp.arange(b), jnp.maximum(q_lens - 1, 0)]        # [R, D]
-    return last, k_pages, v_pages
-
-
 def forward_window(
     spec: ModelSpec,
     params: Params,
@@ -911,8 +687,6 @@ def forward_decode(
     lengths: jnp.ndarray,    # [B] current length per slot (position of `tokens`)
     cache_k: jnp.ndarray,    # [L, B, S, Hkv, Dh]
     cache_v: jnp.ndarray,    # [L, B, S, Hkv, Dh]
-    *,
-    fused: bool = False,     # decode megastep (EngineConfig.decode_fused)
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step for every slot.
 
@@ -936,8 +710,7 @@ def forward_decode(
         x, ck_full, cv_full = carry
         xs_blk, l = per_layer
         blk = rebuild(xs_blk, l)
-        q, k, v = _qkv_norm(spec, blk, x, positions,
-                            fused=fused)             # k,v: [B, 1, Hkv, Dh]
+        q, k, v = _qkv_norm(spec, blk, x, positions)  # k,v: [B, 1, Hkv, Dh]
         with jax.named_scope("attn.kv_update"):
             ck_full = ck_full.at[l, batch_idx, lengths].set(
                 k[:, 0].astype(ck_full.dtype))
@@ -948,8 +721,8 @@ def forward_decode(
             cv = lax.dynamic_index_in_dim(cv_full, l, axis=0, keepdims=False)
         attn = cached_attention(q, ck, cv, lengths + 1,
                                 window=spec.sliding_window)
-        x = _out_residual(spec, blk, attn, x, fused=fused)
-        x, _ = _mlp_residual(spec, blk, x, fused=fused)
+        x = _out_residual(spec, blk, attn, x)
+        x, _ = _mlp_residual(spec, blk, x)
         return (x, ck_full, cv_full), None
 
     n_layers = cache_k.shape[0]
@@ -975,34 +748,27 @@ def forward_decode_window(
     side_v: jnp.ndarray,
     active: jnp.ndarray,         # [B] bool
     *,
-    attn_impl: str = "auto",
-    fused: bool = False,         # decode megastep (EngineConfig.decode_fused)
+    interpret: bool = False,     # run the kernel interpreted (CPU tests)
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step with NO pool writes: the page pools hold the frozen
-    pre-chunk prefix and fresh K/V accumulates in the dense ``side``
-    window; attention = paged(prefix) ⊕ windowed(side), merged via flash
-    stats (``ops.attention.merge_attention``). The caller scatters the
-    window into the pages ONCE per chunk (``write_prefill_pages``).
+    pre-chunk prefix, fresh K/V accumulates in the dense ``side`` window,
+    and ONE kernel per layer (``ops.flash_decode``) streams the prefix in
+    place from the pages and folds the side window into the same
+    online-softmax accumulators. The caller scatters the window into the
+    pages ONCE per chunk (``write_prefill_pages``).
 
     Why: the per-step page scatter of ``forward_decode_paged`` costs
-    ~3.8 ms/layer at 8B bs64 on v5e (XLA scatter lowering; an in-scan
-    Pallas DMA alternative either crashed the runtime or forced pool
-    copies), capping the paged engine at ~28% of dense decode. Writing a
-    per-slot side index is a [B, W] one-hot select — pure vector ops —
-    and the chunk-end batched merge measures 0.03 ms.
+    ~3.8 ms/layer at 8B bs64 on v5e (XLA scatter lowering), capping the
+    paged engine at ~28% of dense decode. Writing a per-slot side index is
+    a [B, W] one-hot select — pure vector ops — and the chunk-end batched
+    merge measures 0.03 ms.
 
-    Returns (hidden [B, D], side_k, side_v). Not used for sliding-window
-    specs (the prefix part's window mask would need the per-step total
-    length; those fall back to ``forward_decode_paged``).
+    Returns (hidden [B, D], side_k, side_v). Not for sliding-window specs
+    (the prefix part's window mask would need the per-step total length;
+    those run ``forward_decode_paged``).
     """
-    from ..ops.attention import merge_attention, window_decode_attention
-    from ..ops.flash_decode import (
-        flash_decode_attention,
-        flash_decode_attention_fw_pallas,
-    )
-    from ..ops.paged_attention import paged_attention
+    from ..ops.flash_decode import flash_decode_attention_pallas
 
-    b = tokens.shape[0]
     L, n_pages, page_size, fused = k_pages.shape
     w = side_k.shape[2]
     positions = lengths[:, None]                         # [B, 1]
@@ -1011,34 +777,17 @@ def forward_decode_window(
     idx = lengths - start_lengths
     onehot = (jnp.arange(w)[None, :] == idx[:, None]) & active[:, None]
     n_side = idx + active.astype(idx.dtype)              # valid AFTER write
-
-    impl = attn_impl
-    if impl == "auto":
-        # the engine resolves "auto" from backend, spec and decode mode
-        # (engine.continuous.resolve_attention_impl); a direct caller
-        # gets the reference composition
-        impl = "xla"
-    # fused flash-decode (ops.flash_decode): ONE kernel per layer streams
-    # the paged prefix, folds the side window into the same online-softmax
-    # accumulators, and skips the separate window/merge fusions. The "-fw"
-    # variant additionally lands the fresh K/V row in its epilogue instead
-    # of the [B, W] one-hot rewrite below.
-    fd = impl.startswith("pallas-decode")
-    fd_fw = impl.startswith("pallas-decode-fw")
-    fd_interpret = impl.endswith("_interpret")
-    if fd and not fd_fw:
-        # a row that is not live (never admitted, or finished earlier in
-        # this chunk) has its output discarded: the kernel gets length 0
-        # for it and moves none of its pages
-        live_prefix = jnp.where(active, start_lengths, 0)
-        live_side = jnp.where(active, n_side, 0)
-    if impl.startswith("pallas"):
-        # stacked view: the kernel indexes pages as layer·N + table[i, p],
-        # so the scan hands it the WHOLE pool — slicing a layer out per
-        # step would materialize a pool-sized copy (custom-call operands
-        # can't fuse a dynamic slice)
-        kp_flat = k_pages.reshape(L * n_pages, page_size, fused)
-        vp_flat = v_pages.reshape(L * n_pages, page_size, fused)
+    # a row that is not live (never admitted, or finished earlier in this
+    # chunk) has its output discarded: the kernel gets length 0 for it and
+    # moves none of its pages
+    live_prefix = jnp.where(active, start_lengths, 0)
+    live_side = jnp.where(active, n_side, 0)
+    # stacked view: the kernel indexes pages as layer·N + table[i, p], so
+    # the scan hands it the WHOLE pool — slicing a layer out per step would
+    # materialize a pool-sized copy (custom-call operands can't fuse a
+    # dynamic slice)
+    kp_flat = k_pages.reshape(L * n_pages, page_size, fused)
+    vp_flat = v_pages.reshape(L * n_pages, page_size, fused)
 
     xs_blocks, rebuild = split_indexed_blocks(params["blocks"])
 
@@ -1046,54 +795,23 @@ def forward_decode_window(
         x, side_k, side_v = carry
         xs_blk, l = per_layer
         blk = rebuild(xs_blk, l)
-        q, k, v = _qkv_norm(spec, blk, x, positions,
-                            fused=fused)             # k,v: [B, 1, Hkv, Dh]
+        q, k, v = _qkv_norm(spec, blk, x, positions)  # k,v: [B, 1, Hkv, Dh]
         with jax.named_scope("attn.kv_gather"):
             sk = lax.dynamic_index_in_dim(side_k, l, 0, keepdims=False)
             sv = lax.dynamic_index_in_dim(side_v, l, 0, keepdims=False)
-        if fd_fw:
-            # fresh K/V goes in as its own operand; the kernel attends to
-            # it and DMAs it into the aliased side row in its epilogue
-            attn, sk, sv = flash_decode_attention_fw_pallas(
-                q[:, 0], kp_flat, vp_flat, page_table, start_lengths,
-                sk, sv, k, v, idx, active.astype(jnp.int32),
-                n_kv_heads=spec.n_kv_heads, interpret=fd_interpret,
-                layer=l, n_pages_per_layer=n_pages,
-            )
-        else:
-            with jax.named_scope("attn.kv_update"):
-                sk = jnp.where(onehot[:, :, None, None], k[:, 0][:, None], sk)
-                sv = jnp.where(onehot[:, :, None, None], v[:, 0][:, None], sv)
-            if fd:
-                attn = flash_decode_attention(
-                    q[:, 0], kp_flat, vp_flat, page_table, live_prefix,
-                    sk, sv, live_side, n_kv_heads=spec.n_kv_heads,
-                    impl=impl, layer=l, n_pages_per_layer=n_pages,
-                )
-            else:
-                if impl.startswith("pallas"):
-                    prefix = paged_attention(
-                        q[:, 0], kp_flat, vp_flat, page_table, start_lengths,
-                        n_kv_heads=spec.n_kv_heads, impl=impl,
-                        with_stats=True, layer=l, n_pages_per_layer=n_pages,
-                    )
-                else:
-                    kp_l = lax.dynamic_index_in_dim(k_pages, l, 0,
-                                                    keepdims=False)
-                    vp_l = lax.dynamic_index_in_dim(v_pages, l, 0,
-                                                    keepdims=False)
-                    prefix = paged_attention(
-                        q[:, 0], kp_l, vp_l, page_table, start_lengths,
-                        n_kv_heads=spec.n_kv_heads, impl=impl,
-                        with_stats=True,
-                    )
-                window_part = window_decode_attention(q[:, 0], sk, sv, n_side)
-                attn = merge_attention([prefix, window_part], dtype=q.dtype)
+        with jax.named_scope("attn.kv_update"):
+            sk = jnp.where(onehot[:, :, None, None], k[:, 0][:, None], sk)
+            sv = jnp.where(onehot[:, :, None, None], v[:, 0][:, None], sv)
+        attn = flash_decode_attention_pallas(
+            q[:, 0], kp_flat, vp_flat, page_table, live_prefix,
+            sk, sv, live_side, n_kv_heads=spec.n_kv_heads,
+            interpret=interpret, layer=l, n_pages_per_layer=n_pages,
+        )
         with jax.named_scope("attn.kv_update"):
             side_k = lax.dynamic_update_index_in_dim(side_k, sk, l, 0)
             side_v = lax.dynamic_update_index_in_dim(side_v, sv, l, 0)
-        x = _out_residual(spec, blk, attn[:, None], x, fused=fused)
-        x, _ = _mlp_residual(spec, blk, x, fused=fused)
+        x = _out_residual(spec, blk, attn[:, None], x)
+        x, _ = _mlp_residual(spec, blk, x)
         return (x, side_k, side_v), None
 
     (x, side_k, side_v), _ = lax.scan(
@@ -1110,30 +828,22 @@ def forward_decode_paged(
     v_pages: jnp.ndarray,     # [L, N, P, Hkv*Dh]
     page_table: jnp.ndarray,  # [B, MP] int32 logical->physical pages
     write_mask: Optional[jnp.ndarray] = None,   # [B] bool: which slots write
-    *,
-    attn_impl: str = "auto",
-    fused: bool = False,      # decode megastep (EngineConfig.decode_fused)
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """One decode step against the paged HBM cache (``engine/paged_kv.py``).
 
     Each slot's fresh K/V is scattered into its page at position ``lengths``
     (page = lengths // P, offset = lengths % P — capacity must be reserved
     before the chunk, see ``PagedKVCache.reserve``), then attention runs over
-    the slot's live pages via ``ops/paged_attention.py``. Returns
-    (hidden [B, D], new k_pages, new v_pages).
+    the slot's live pages (``ops.paged_attention.paged_attention_xla``).
+    Returns (hidden [B, D], new k_pages, new v_pages).
 
     ``write_mask`` exists because decode always runs over ALL slots (static
     shapes): an inactive slot's page table points at physical page 0, which
     belongs to some live slot — its K/V write must be dropped, not landed.
     Masked-off slots get an out-of-range scatter index (``mode="drop"``).
     """
-    from ..ops.paged_attention import paged_attention
+    from ..ops.paged_attention import paged_attention_xla
 
-    if attn_impl.startswith("pallas-decode"):
-        # the fused flash-decode kernel serves only the side-window path
-        # (forward_decode_window); per-step paged decode falls back to the
-        # measured-fastest XLA gather attention
-        attn_impl = "xla"
     b = tokens.shape[0]
     n_pages = k_pages.shape[1]
     page_size = k_pages.shape[2]
@@ -1154,8 +864,7 @@ def forward_decode_paged(
         x, kp_full, vp_full = carry
         xs_blk, l = per_layer
         blk = rebuild(xs_blk, l)
-        q, k, v = _qkv_norm(spec, blk, x, positions,
-                            fused=fused)             # k,v: [B, 1, Hkv, Dh]
+        q, k, v = _qkv_norm(spec, blk, x, positions)  # k,v: [B, 1, Hkv, Dh]
         kv_fused = k.shape[2] * k.shape[3]
         with jax.named_scope("attn.kv_update"):
             kp_full = kp_full.at[l, phys, offset].set(
@@ -1167,13 +876,12 @@ def forward_decode_paged(
         with jax.named_scope("attn.kv_gather"):
             kp = lax.dynamic_index_in_dim(kp_full, l, axis=0, keepdims=False)
             vp = lax.dynamic_index_in_dim(vp_full, l, axis=0, keepdims=False)
-        attn = paged_attention(
+        attn = paged_attention_xla(
             q[:, 0], kp, vp, page_table, lengths + 1,
-            n_kv_heads=spec.n_kv_heads, impl=attn_impl,
-            window=spec.sliding_window,
+            n_kv_heads=spec.n_kv_heads, window=spec.sliding_window,
         )
-        x = _out_residual(spec, blk, attn[:, None], x, fused=fused)
-        x, _ = _mlp_residual(spec, blk, x, fused=fused)
+        x = _out_residual(spec, blk, attn[:, None], x)
+        x, _ = _mlp_residual(spec, blk, x)
         return (x, kp_full, vp_full), None
 
     n_layers = k_pages.shape[0]

@@ -102,7 +102,6 @@ class FakeEngineConfig:
 
     max_waiting: int = 0
     queue_deadline_s: float = 0.0
-    mixed_step_tokens: int = 0      # pump compat knob; unused by the fake
 
 
 class FakeContinuousEngine:
@@ -133,11 +132,7 @@ class FakeContinuousEngine:
                  prefix_cache: bool = False,
                  prefix_page_size: int = 64,
                  stream_chunk_tokens: int = 0,
-                 stream_dispatch_overhead_s: float = 0.0,
-                 spec_async: bool = False,
-                 spec_max_draft: int = 4,
-                 spec_accept_rate: float = 0.7,
-                 spec_bubble_floor_s: float = 0.0) -> None:
+                 stream_dispatch_overhead_s: float = 0.0) -> None:
         self.config = FakeEngineConfig(
             max_waiting=int(max_waiting),
             queue_deadline_s=float(queue_deadline_s))
@@ -154,29 +149,6 @@ class FakeContinuousEngine:
         self.stream_chunk_tokens = max(0, int(stream_chunk_tokens))
         self.stream_dispatch_overhead_s = float(stream_dispatch_overhead_s)
         self._stream_sub_chunks = 0
-        # async-speculation model (ISSUE 15), mirroring the real engine's
-        # AsyncSpeculator at the behavioral level: the drafter fills the
-        # step's HOST BUBBLE, modeled here as the idle-slot fraction of a
-        # step — bubble = (1 - live/max_slots) * step_latency_s. It
-        # engages only when a streaming slot exists AND the bubble clears
-        # spec_bubble_floor_s; an engaged streaming slot emits up to
-        # spec_max_draft EXTRA chain tokens per step at zero added wall
-        # time (they ride the bubble), which is exactly the streamed-ITL
-        # win the fleet sweep's spec leg measures. Acceptance is a
-        # deterministic credit accumulator (credit += k * rate per round,
-        # whole tokens emitted) so same-seed runs replay identical
-        # receipts. At saturation live == max_slots ⇒ bubble 0 ⇒ the
-        # drafter auto-idles and the step is byte-identical to spec-off.
-        self.spec_async = bool(spec_async)
-        self.spec_max_draft = max(1, int(spec_max_draft))
-        self.spec_accept_rate = min(1.0, max(0.0, float(spec_accept_rate)))
-        self.spec_bubble_floor_s = float(spec_bubble_floor_s)
-        self._spec_drafted = 0
-        self._spec_accepted = 0
-        self._spec_wasted = 0
-        self._spec_rounds = 0
-        self._spec_auto_idles = 0
-        self._spec_bubble_s = 0.0
         self.max_slots = max(1, int(max_slots))
         self.vocab_size = max(2, int(vocab_size))
         # prefix-cache TTFT model: admission costs
@@ -386,7 +358,7 @@ class FakeContinuousEngine:
                         decode_s=now0 - t, metadata={"fake": True}))
                     continue
             # trailing 0.0 = the slot's speculation accept-credit accumulator
-            self._live.append([req, cb, t, state, toks, 0.0])
+            self._live.append([req, cb, t, state, toks])
         if not self._live:
             return 0
         # sub-chunk split (ISSUE 13): engages only while a live slot is
@@ -404,29 +376,6 @@ class FakeContinuousEngine:
         t_step = time.perf_counter()
         self._steps += 1
         had = {id(s): bool(s[4]) for s in self._live}
-        # bubble-gated draft rounds: decided once per step, charged once
-        # per streaming slot. extra tokens are added to the slot's FIRST
-        # sub-chunk budget below (popped so they apply exactly once).
-        spec_extra: Dict[int, int] = {}
-        if self.spec_async:
-            bubble = ((1.0 - len(self._live) / self.max_slots)
-                      * self.step_latency_s)
-            streaming = [s for s in self._live if s[1] is not None]
-            if streaming and bubble >= self.spec_bubble_floor_s:
-                k = self.spec_max_draft
-                self._spec_bubble_s += bubble
-                for slot in streaming:
-                    slot[5] += k * self.spec_accept_rate
-                    extra = min(int(slot[5]), k)
-                    slot[5] -= extra
-                    self._spec_rounds += 1
-                    self._spec_drafted += k
-                    self._spec_accepted += extra
-                    self._spec_wasted += k - extra
-                    if extra:
-                        spec_extra[id(slot)] = extra
-            else:
-                self._spec_auto_idles += 1
         done_slots: set = set()
         now = t_step
         for si, budget in enumerate(sizes):
@@ -442,10 +391,10 @@ class FakeContinuousEngine:
                 key = id(slot)
                 if key in done_slots:
                     continue
-                req, cb, t, state, toks = slot[:5]
+                req, cb, t, state, toks = slot
                 fresh: List[int] = []
                 done = False
-                for _ in range(budget + spec_extra.pop(key, 0)):
+                for _ in range(budget):
                     nxt = state % self.vocab_size
                     state = _chain(state, nxt)
                     toks.append(nxt)
@@ -532,24 +481,6 @@ class FakeContinuousEngine:
             "fabric_imports": self._fabric_imports,
             "fabric_imported_tokens": self._fabric_imported_tokens,
             "stream_sub_chunks": self._stream_sub_chunks,
-            # same spec_async_* family (and zero-state semantics) as the
-            # real ContinuousEngine, so sweep/dashboard code reads one
-            # schema across rigs. A fake draft round IS its verify step
-            # (acceptance resolves synchronously), hence rounds==steps.
-            "spec_async_drafted_tokens": self._spec_drafted,
-            "spec_async_accepted_tokens": self._spec_accepted,
-            "spec_async_wasted_tokens": self._spec_wasted,
-            "spec_async_catchup_tokens": 0,
-            "spec_async_accept_rate": (
-                self._spec_accepted / self._spec_drafted
-                if self._spec_drafted else 0.0),
-            "spec_async_draft_rounds": self._spec_rounds,
-            "spec_async_propose_rounds": self._spec_rounds,
-            "spec_async_auto_idles": self._spec_auto_idles,
-            "spec_async_bubble_consumed_s": self._spec_bubble_s,
-            "spec_async_draft_cost_ema_s": 0.0,
-            "spec_async_pending": 0,
-            "spec_async_verify_steps": self._spec_rounds,
             "ttft": self.ttft_stats.snapshot(),
             "decode_chunk": self.step_stats.snapshot(),
             "spec": {"fake": True, "continuous": True},

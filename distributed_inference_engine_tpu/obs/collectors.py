@@ -53,13 +53,9 @@ ENGINE_TABLE = [
     ("capacity_finishes", "engine_capacity_finishes", "c",
      "Sequences force-finished (reason=length) by KV-pool exhaustion"),
     ("engine_steps", "engine_steps", "c",
-     "Engine iterations (decode or mixed dispatches)"),
+     "Engine iterations that dispatched a decode chunk"),
     ("prefill_calls", "engine_prefill_calls", "c",
      "Prefill dispatches (whole-prompt or chunk)"),
-    ("mixed_steps", "engine_mixed_steps", "c",
-     "Ragged mixed-batch dispatches (decode + prefill chunks)"),
-    ("mixed_prefill_tokens", "engine_mixed_prefill_tokens", "c",
-     "Prefill tokens carried by mixed dispatches"),
     ("prefix_hit_admissions", "engine_prefix_hit_admissions", "c",
      "Admissions that reused cached prefix KV pages"),
     ("chunked_admissions", "engine_chunked_admissions", "c",
@@ -72,8 +68,6 @@ ENGINE_TABLE = [
     ("live_slots", "engine_live_slots", "g", "Decoding slots right now"),
     ("prefilling_slots", "engine_prefilling_slots", "g",
      "Slots mid chunked prefill"),
-    ("mixed_programs", "engine_mixed_programs", "g",
-     "Distinct compiled mixed-step programs"),
     ("batch_occupancy", "engine_batch_occupancy", "g",
      "Mean live slots / max_slots per engine step"),
     ("dispatch_s_total", "engine_dispatch_seconds", "c",
@@ -87,32 +81,6 @@ ENGINE_TABLE = [
      "Accepted / proposed draft tokens"),
     ("tokens_per_round", "engine_spec_tokens_per_round", "g",
      "Mean tokens emitted per speculative round"),
-    ("spec_async_drafted_tokens", "engine_spec_async_drafted_tokens", "c",
-     "Draft tokens proposed by the async bubble drafter"),
-    ("spec_async_accepted_tokens", "engine_spec_async_accepted_tokens", "c",
-     "Async draft tokens accepted and emitted by verify"),
-    ("spec_async_wasted_tokens", "engine_spec_async_wasted_tokens", "c",
-     "Async draft tokens discarded (rejected, stale, or clipped)"),
-    ("spec_async_catchup_tokens", "engine_spec_async_catchup_tokens", "c",
-     "Tokens re-forwarded to catch the draft KV cache up"),
-    ("spec_async_accept_rate", "engine_spec_async_accept_rate", "g",
-     "Accepted / drafted async speculation tokens"),
-    ("spec_async_draft_rounds", "engine_spec_async_draft_rounds", "c",
-     "Async draft dispatches (catch-up or propose)"),
-    ("spec_async_propose_rounds", "engine_spec_async_propose_rounds", "c",
-     "Async draft dispatches that proposed draft tokens"),
-    ("spec_async_auto_idles", "engine_spec_async_auto_idles", "c",
-     "Scheduler passes skipped: bubble below spec_bubble_floor_s"),
-    ("spec_async_bubble_consumed_s", "engine_spec_async_bubble_"
-     "consumed_seconds", "c",
-     "Host seconds the drafter spent inside the megastep bubble"),
-    ("spec_async_draft_cost_ema_s", "engine_spec_async_draft_cost_"
-     "ema_seconds", "g",
-     "EMA host cost of one draft round (budget gate input)"),
-    ("spec_async_pending", "engine_spec_async_pending", "g",
-     "Draft proposals awaiting piggybacked verification"),
-    ("spec_async_verify_steps", "engine_spec_async_verify_steps", "c",
-     "Megasteps that carried extra draft verify columns"),
     ("stream_ring_pushes", "engine_stream_ring_pushes", "c",
      "Decode chunks pushed onto the device->host token ring"),
     ("stream_ring_polls", "engine_stream_ring_polls", "c",

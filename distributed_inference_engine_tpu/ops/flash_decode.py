@@ -1,5 +1,5 @@
 """Fused flash-decode attention: paged prefix + chunk side window in ONE
-Pallas kernel per layer (``attn_impl="pallas-decode"``).
+Pallas kernel per layer (the ``window`` decode body).
 
 The windowed decode scheme (``models.base.forward_decode_window``) splits
 each step's attention into three HLOs per layer: a paged/dense prefix
@@ -16,12 +16,11 @@ prefix pages, then the side window as the final block, with the merge
 falling out of the shared (m, l, acc) accumulators — no stats round-trip,
 no separate merge fusion, no gathered ctx copy.
 
-DMA architecture — why this kernel is not the retired
-``ops/paged_attention.py`` one: that kernel's (slot, page) grid DMA'd ONE
-page per sequential grid step through the auto-pipeliner, which only
-overlaps one step ahead — every scattered ~128 KB page copy stalled the
-core for its full ~µs latency (~13 µs unhidden per step; 1,380 vs 3,623
-tok/s end-to-end, round 3). Here the page pools stay HBM-resident
+DMA architecture — a (slot, page) grid that DMAs ONE page per sequential
+grid step through the auto-pipeliner only overlaps one step ahead: every
+scattered ~128 KB page copy stalls the core for its full ~µs latency
+(~13 µs unhidden per step; such a kernel measured 1,380 vs 3,623 tok/s
+end-to-end, round 3). Here the page pools stay HBM-resident
 (``memory_space=ANY``) and the kernel issues its own multi-page async
 copies, double-buffered: while block ``i`` is being computed, the copies
 for block ``i+1`` — or the FIRST block of the next live row, crossing
@@ -48,24 +47,12 @@ layer, 60-72 % of the HBM peak over the live pages, against 120 us for
 the dense-context path's slice + attention + update at the 8-page
 bucket.
 
-Two kernels:
-
-- ``_flash_decode_kernel`` (``impl="pallas-decode"``): attention only.
-  The caller still writes the step's fresh K/V into the side buffer (the
-  XLA one-hot select), and ``n_side`` counts it as valid.
-- ``_flash_decode_fw_kernel`` (``impl="pallas-decode-fw"``): additionally
-  routes the KV writeback through the kernel epilogue — fresh K/V arrive
-  as separate [B, 1, fused] operands, attend as one extra key, and are
-  DMA'd into the (input/output-aliased, HBM-resident) side buffers at
-  each slot's column, replacing the per-layer one-hot rewrite of the
-  whole [B, W] side slice with B row-sized copies. Whether that wins on
-  hardware is an open A/B (docs/decode_profile.md); both modes share the
-  flash inner loop, so parity tests pin them to the same reference.
-
-Both run under ``interpret=True`` on CPU (the parity tests) — the
-interpret mode of this jax version executes ``make_async_copy`` on
-ANY-space refs, mutable scalar-prefetch state, and input/output aliasing
-faithfully (probed; the aliasing index counts scalar-prefetch operands).
+The kernel (``_flash_decode_kernel``) is attention only: the caller
+writes the step's fresh K/V into the side buffer (the XLA one-hot select),
+and ``n_side`` counts it as valid. It runs under ``interpret=True`` on CPU
+(the parity tests) — the interpret mode of this jax version executes
+``make_async_copy`` on ANY-space refs and mutable scalar-prefetch state
+faithfully (probed).
 """
 
 from __future__ import annotations
@@ -361,101 +348,6 @@ def _flash_decode_kernel(
     _finish(out_ref, l_scr, acc_scr, g=g, dh=dh, n_kv_heads=n_kv_heads)
 
 
-# ------------------------------------- kernel: fused side-write epilogue
-
-
-def _flash_decode_fw_kernel(
-    # scalar prefetch
-    page_table_ref,            # [B, MP]
-    prefix_lens_ref,           # [B]
-    next_live_ref,             # [B]
-    side_idx_ref,              # [B] this step's side column per slot
-    active_ref,                # [B] int32 0/1
-    layer_ref,                 # [1]
-    buffer_index_ref,          # [1] MUTABLE
-    step_ref,                  # [1] MUTABLE
-    # inputs
-    q_ref,                     # [1, Hp, Dh] VMEM
-    fresh_k_ref,               # [1, 1, Hkv*Dh] VMEM: this step's K
-    fresh_v_ref,
-    k_pages_hbm,               # [L*N, P, Hkv*Dh] ANY
-    v_pages_hbm,
-    side_k_in,                 # [B, W, Hkv*Dh] ANY (aliased to outputs;
-    side_v_in,                 #   unused — all access via the out refs)
-    # outputs
-    out_ref,                   # [1, Hp, Dh] VMEM
-    side_k_out,                # [B, W, Hkv*Dh] ANY, aliased to side_k_in
-    side_v_out,
-    # scratch
-    k_vmem,                    # [2, bp, P, Hkv*Dh]
-    v_vmem,
-    side_k_vmem,               # [W, Hkv*Dh] side row staging
-    side_v_vmem,
-    m_scr, l_scr, acc_scr,
-    sem,
-    side_sem,
-    *,
-    n_kv_heads: int,
-    head_dim: int,
-    page_size: int,
-    n_heads: int,
-    pages_per_block: int,
-    n_pages_per_layer: int,
-):
-    b = pl.program_id(0)
-    dh, g = head_dim, n_heads // n_kv_heads
-    w = side_k_vmem.shape[0]
-    scale = 1.0 / (dh ** 0.5)
-
-    # side row read starts NOW so it rides under the whole prefix loop
-    # (aliased buffers: reads go through the out refs — same memory)
-    pltpu.make_async_copy(side_k_out.at[b], side_k_vmem, side_sem).start()
-    pltpu.make_async_copy(side_v_out.at[b], side_v_vmem, side_sem).start()
-
-    _init_acc(m_scr, l_scr, acc_scr)
-    qbd = _block_diag_q(q_ref[0], n_heads, n_kv_heads)
-
-    _prefix_loop(
-        b, page_table_ref, prefix_lens_ref, next_live_ref, layer_ref,
-        buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
-        v_vmem, sem, m_scr, l_scr, acc_scr,
-        bp=pages_per_block, page_size=page_size,
-        n_pages_per_layer=n_pages_per_layer, scale=scale)
-
-    pltpu.make_async_copy(side_k_out.at[b], side_k_vmem, side_sem).wait()
-    pltpu.make_async_copy(side_v_out.at[b], side_v_vmem, side_sem).wait()
-
-    # epilogue writeback issued EARLY (before the side/fresh compute) so
-    # its latency overlaps the remaining row work; B row-sized copies
-    # replace the XLA one-hot rewrite of the whole [B, W] side slice
-    act = active_ref[b]
-    i_side = side_idx_ref[b]
-    do_write = jnp.logical_and(act > 0, i_side < w)
-
-    @pl.when(do_write)
-    def _writeback():
-        pltpu.make_async_copy(
-            fresh_k_ref.at[0, 0], side_k_out.at[b, i_side], side_sem).start()
-        pltpu.make_async_copy(
-            fresh_v_ref.at[0, 0], side_v_out.at[b, i_side], side_sem).start()
-
-    # side window: entries BEFORE this step's column are valid
-    _attend(qbd, side_k_vmem[...], side_v_vmem[...], 0,
-            jnp.minimum(i_side, w), m_scr, l_scr, acc_scr, scale)
-    # this step's token as one extra key (it never reached the buffers)
-    _attend(qbd, fresh_k_ref[0], fresh_v_ref[0], 0, act,
-            m_scr, l_scr, acc_scr, scale)
-
-    _finish(out_ref, l_scr, acc_scr, g=g, dh=dh, n_kv_heads=n_kv_heads)
-
-    @pl.when(do_write)
-    def _drain():
-        pltpu.make_async_copy(
-            fresh_k_ref.at[0, 0], side_k_out.at[b, i_side], side_sem).wait()
-        pltpu.make_async_copy(
-            fresh_v_ref.at[0, 0], side_v_out.at[b, i_side], side_sem).wait()
-
-
 # ------------------------------------------------------------- launchers
 
 
@@ -602,98 +494,6 @@ def flash_decode_attention_pallas(
     return out[:, :h]
 
 
-def flash_decode_attention_fw_pallas(
-    q: jnp.ndarray,            # [B, H, Dh]
-    k_pages: jnp.ndarray,      # [N, P, fused] or stacked [L*N, P, fused]
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,   # [B, MP]
-    prefix_lens: jnp.ndarray,  # [B]
-    side_k: jnp.ndarray,       # [B, W, Hkv, Dh] — DONATED (aliased)
-    side_v: jnp.ndarray,
-    fresh_k: jnp.ndarray,      # [B, 1, Hkv, Dh] this step's K/V
-    fresh_v: jnp.ndarray,
-    side_idx: jnp.ndarray,     # [B] side column this step writes
-    active: jnp.ndarray,       # [B] bool/int — inactive slots don't write
-    *,
-    n_kv_heads: int,
-    interpret: bool = False,
-    layer=None,
-    n_pages_per_layer: int = 0,
-    pages_per_block: int = 0,
-):
-    """Fused attention + side-buffer writeback epilogue. Returns
-    (out [B, H, Dh], side_k', side_v') with the fresh K/V landed."""
-    _validate(q, k_pages, v_pages, page_table, n_kv_heads)
-    b, h, dh = q.shape
-    n, page_size, fused = k_pages.shape
-    mp = page_table.shape[1]
-    w = side_k.shape[1]
-    bp = pages_per_block or _default_pages_per_block(page_size, fused, mp)
-    bp = min(bp, mp)
-    side_shape = side_k.shape
-    sk = side_k.reshape(b, w, fused)
-    sv = side_v.reshape(b, w, fused)
-    fk = fresh_k.reshape(b, 1, fused).astype(sk.dtype)
-    fv = fresh_v.reshape(b, 1, fused).astype(sv.dtype)
-    qp = _pad_heads(q)
-    hp = qp.shape[1]
-    kv_scratch, acc_scratch = _scratch(hp, fused, bp, page_size,
-                                       k_pages.dtype)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=8,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec((1, 1, fused), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec((1, 1, fused), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=kv_scratch + [
-            pltpu.VMEM((w, fused), sk.dtype),
-            pltpu.VMEM((w, fused), sv.dtype),
-        ] + acc_scratch + [
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    kernel = functools.partial(
-        _flash_decode_fw_kernel,
-        n_kv_heads=n_kv_heads, head_dim=dh, page_size=page_size,
-        n_heads=h, pages_per_block=bp,
-        n_pages_per_layer=n_pages_per_layer or n)
-    out, sk_new, sv_new = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, hp, dh), q.dtype),
-                   jax.ShapeDtypeStruct((b, w, fused), sk.dtype),
-                   jax.ShapeDtypeStruct((b, w, fused), sv.dtype)],
-        # aliasing indices COUNT the 8 scalar-prefetch operands (probed on
-        # this jax version): side_k/side_v are call args 13/14
-        input_output_aliases={13: 1, 14: 2},
-        compiler_params=_compiler_params(bp, page_size, fused,
-                                         k_pages.dtype.itemsize),
-        cost_estimate=_cost(b, h, dh, mp, page_size, w, fused,
-                            k_pages.dtype.itemsize, sk.dtype.itemsize),
-        interpret=interpret,
-        name=_OP_NAME + "_fw",
-    )(page_table, prefix_lens, _next_live(prefix_lens),
-      jnp.asarray(side_idx, jnp.int32),
-      jnp.asarray(active, jnp.int32), _layer_scalar(layer),
-      jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-      qp, fk, fv, k_pages, v_pages, sk, sv)
-    return (out[:, :h],
-            sk_new.reshape(side_shape), sv_new.reshape(side_shape))
-
-
 # ------------------------------------------------------------- dispatcher
 
 
@@ -714,9 +514,7 @@ def flash_decode_attention(
     pages_per_block: int = 0,
 ) -> jnp.ndarray:
     """impl: "xla" (reference composition) | "pallas-decode" |
-    "pallas-decode_interpret" (CPU correctness tests). The "-fw"
-    writeback variant has its own entry point (different dataflow:
-    donated side buffers, returns them updated)."""
+    "pallas-decode_interpret" (CPU correctness tests)."""
     if impl == "xla":
         if layer is not None:
             raise ValueError(

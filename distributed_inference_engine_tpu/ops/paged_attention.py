@@ -1,53 +1,29 @@
-"""Paged decode attention: Pallas TPU kernel + XLA reference implementation.
+"""Paged decode attention over the page pool, in XLA.
 
-This is the TPU-native answer to SURVEY.md §7 hard-part #2 (paged KV cache in
-HBM) and the north-star reinterpretation of the reference's ``src/kvstore.py``
-cache: attention state lives in a pool of fixed-size HBM pages instead of one
-contiguous row per sequence, so long and short sequences share HBM without
-fragmentation and page recycling replaces whole-row eviction.
+Attention state lives in a pool of fixed-size HBM pages instead of one
+contiguous row per sequence (SURVEY.md §7 hard-part #2), so long and short
+sequences share HBM without fragmentation and page recycling replaces
+whole-row eviction.
 
 Layout (per layer):
 
-- ``k_pages`` / ``v_pages``: ``[num_pages, page_size, n_kv * head_dim]`` —
-  the trailing dim is fused so every VMEM block is lane-aligned (the kernel
-  requires ``n_kv * head_dim`` to be a multiple of 128, the TPU lane count).
+- ``k_pages`` / ``v_pages``: ``[num_pages, page_size, n_kv * head_dim]``.
 - ``page_table``: ``[batch, max_pages_per_seq]`` int32 — logical page ``p`` of
   slot ``b`` lives in physical page ``page_table[b, p]``. Unused entries must
-  hold a valid page id (0): the kernel still DMAs them (static grid) and masks
-  the scores, so the id only has to be safe to read.
+  hold a valid page id (0): they are gathered and masked.
 - ``lengths``: ``[batch]`` int32 — live tokens per slot, *including* the
   token at the current decode position.
 
-Kernel design (flash-style online softmax over pages):
-
-- Grid ``(batch, max_pages_per_seq)``; the page table and lengths ride
-  ``PrefetchScalarGridSpec`` so the index map can translate logical→physical
-  page ids before the block DMA is issued — the gather lives in the DMA
-  engine, not in compute.
-- Per grid step one K page and one V page are DMA'd to VMEM (double-buffered
-  by the Pallas pipeline across the sequential page axis); VMEM scratch
-  carries the running (max, sum, acc) across pages of the same row.
-- All in-kernel tensors stay RANK-2 with the fused head·dim axis on lanes:
-  Mosaic rejects the "natural" batched-per-head ``dot_general`` and 3-D
-  reshapes for these shapes (found the hard way on hardware — interpret
-  mode happily accepts both). Per-head segment sums and broadcasts are
-  expressed as matmuls against constant 0/1 matrices, which lower cleanly
-  to the MXU; GQA expands K/V to query heads the same way.
-- Decode attention is HBM-bandwidth-bound; the kernel's job is DMAing only
-  live pages, not MXU utilisation. Precision is bf16-grade (Mosaic's fp32
-  matmul rounds operands through bf16 passes), matching bf16 serving.
+``paged_attention_xla`` is the ``inline`` decode body's attention (sliding-
+window specs, ``models.base.forward_decode_paged``) and, with its flash
+stats, the prefix half of the reference the in-place kernel is pinned to
+(``ops.flash_decode.flash_decode_attention_xla``). The kernel that reads
+pages in place is ``ops/flash_decode.py``.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
-import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _upcast_fp8
 
@@ -104,256 +80,3 @@ def paged_attention_xla(
     if with_stats:
         return out, m.reshape(b, h), l.reshape(b, h)
     return out
-
-
-# -------------------------------------------------------------- Pallas path
-
-
-def _paged_attn_kernel(
-    # scalar prefetch
-    page_table_ref,            # [B, MP] SMEM
-    lengths_ref,               # [B] SMEM
-    layer_ref,                 # [1] SMEM: layer offset into a stacked pool
-                               # (0 when the caller passes one layer's pool)
-    # blocks — q/out carry a singleton sublane axis: Mosaic requires the
-    # last two block dims to divide (8, 128) or EQUAL the array dims, and
-    # a (1, H·Dh) block over a (B, H·Dh) array satisfies neither (the
-    # interpret-mode tests can't catch this; only a real TPU lowers it)
-    q_ref,                     # [1, 1, H * Dh] VMEM
-    k_ref,                     # [1, P, Hkv * Dh] VMEM (one physical page)
-    v_ref,                     # [1, P, Hkv * Dh] VMEM
-    out_ref,                   # [1, 1, H * Dh] VMEM
-    m_ref,                     # [1, 1, H] VMEM: final row max (flash stats)
-    l_ref,                     # [1, 1, H] VMEM: final denominator
-    # scratch
-    m_scr,                     # [1, H] f32 running max per head
-    l_scr,                     # [1, H] f32 running denominator
-    acc_scr,                   # [1, H * Dh] f32 running numerator
-    *,
-    n_kv_heads: int,
-    head_dim: int,
-    page_size: int,
-    n_heads: int,
-    window: int,
-):
-    b = pl.program_id(0)
-    p = pl.program_id(1)
-    n_pages = pl.num_programs(1)
-    length = lengths_ref[b]
-    dh = head_dim
-    H = n_heads
-    g = H // n_kv_heads
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    # pages past the live prefix contribute nothing; skip their FLOPs —
-    # and with a sliding window, so do pages wholly before the window
-    live = p * page_size < length
-    if window:
-        live &= (p + 1) * page_size > length - window
-
-    # constant 0/1 map, folded into the compiled kernel:
-    # S [H*Dh, H] segment-sums each head's Dh lanes; S.T broadcasts back
-    lane_head = lax.broadcasted_iota(jnp.int32, (H * dh, H), 0) // dh
-    head_idx = lax.broadcasted_iota(jnp.int32, (H * dh, H), 1)
-    seg = (lane_head == head_idx).astype(jnp.float32)
-
-    @pl.when(live)
-    def _page():
-        qf = q_ref[0, 0, :].astype(jnp.float32)[None, :]       # [1, H*Dh]
-        kf = k_ref[0].astype(jnp.float32)                      # [P, Hkv*Dh]
-        vf = v_ref[0].astype(jnp.float32)
-        if g > 1:
-            # GQA: replicate each kv head's Dh lanes across its query
-            # group with STATIC lane-slice concats (a dense 0/1 expander
-            # matmul would cost O(P·HkvDh·HDh) MACs and a VMEM constant
-            # that blows up at real GQA shapes, e.g. 16 MiB for 8B-class)
-            kf = jnp.concatenate(
-                [kf[:, (h // g) * dh:(h // g + 1) * dh] for h in range(H)],
-                axis=1)
-            vf = jnp.concatenate(
-                [vf[:, (h // g) * dh:(h // g + 1) * dh] for h in range(H)],
-                axis=1)
-        prod = kf * qf                                         # [P, H*Dh]
-        scores = jnp.dot(prod, seg,                            # [P, H]
-                         preferred_element_type=jnp.float32,
-                         precision=lax.Precision.HIGHEST)
-        scores = scores * (1.0 / (dh ** 0.5))
-        tok = p * page_size + lax.broadcasted_iota(
-            jnp.int32, (page_size, H), 0)
-        in_range = tok < length
-        if window:
-            in_range &= tok >= length - window
-        scores = jnp.where(in_range, scores, NEG_INF)
-
-        m_prev = m_scr[:]                                      # [1, H]
-        l_prev = l_scr[:]
-        m_new = jnp.maximum(m_prev, scores.max(axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)                        # [1, H]
-        probs = jnp.exp(scores - m_new[0][None, :])            # [P, H]
-        l_new = l_prev * alpha + probs.sum(axis=0, keepdims=True)
-
-        pe = jnp.dot(probs, seg.T,                             # [P, H*Dh]
-                     preferred_element_type=jnp.float32,
-                     precision=lax.Precision.HIGHEST)
-        pv = (pe * vf).sum(axis=0, keepdims=True)              # [1, H*Dh]
-        alpha_e = jnp.dot(alpha, seg.T,
-                          preferred_element_type=jnp.float32,
-                          precision=lax.Precision.HIGHEST)
-        acc_scr[:] = acc_scr[:] * alpha_e + pv
-        m_scr[:] = m_new
-        l_scr[:] = l_new
-
-    @pl.when(p == n_pages - 1)
-    def _finish():
-        l = jnp.maximum(l_scr[:], 1e-30)                       # [1, H]
-        le = jnp.dot(l, seg.T, preferred_element_type=jnp.float32,
-                     precision=lax.Precision.HIGHEST)
-        out = (acc_scr[:] / le).reshape(1, 1, H * dh)
-        out_ref[:] = out.astype(out_ref.dtype)
-        # flash stats for cross-source merging (zero-valid rows keep the
-        # RAW l = 0, so their merge weight vanishes)
-        m_ref[:] = m_scr[:].reshape(1, 1, H)
-        l_ref[:] = l_scr[:].reshape(1, 1, H)
-
-
-def paged_attention_pallas(
-    q: jnp.ndarray,            # [B, H, Dh]
-    k_pages: jnp.ndarray,      # [N, P, Hkv*Dh] — or [L*N, P, Hkv*Dh] stacked
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,   # [B, MP] int32
-    lengths: jnp.ndarray,      # [B] int32
-    *,
-    n_kv_heads: int,
-    window: int = 0,
-    interpret: bool = False,
-    with_stats: bool = False,
-    layer=None,                # int32 scalar: layer offset into stacked pools
-    n_pages_per_layer: int = 0,
-):
-    """One compiled program serves both pool layouts: per-layer pools
-    (``layer=None``) and the STACKED [L·N, P, fused] layout, where the
-    physical page id becomes ``layer·N + table[i, p]``. The stacked form
-    lets the decode scan hand the whole pool to the kernel — slicing one
-    layer out per step materializes a pool-sized copy per layer·step
-    (custom-call operands can't fuse a dynamic slice)."""
-    b, h, dh = q.shape
-    n, page_size, fused = k_pages.shape
-    mp = page_table.shape[1]
-    if fused != n_kv_heads * dh:
-        raise ValueError(f"fused dim {fused} != n_kv_heads*head_dim {n_kv_heads * dh}")
-    if fused % 128:
-        raise ValueError(
-            f"n_kv_heads*head_dim = {fused} must be a multiple of 128 (TPU lanes)"
-        )
-    n_per = n_pages_per_layer or n
-    if layer is None:
-        layer = jnp.zeros((1,), jnp.int32)
-    else:
-        layer = jnp.asarray(layer, jnp.int32).reshape(1)
-
-    page_idx = lambda i, p, pt, ln, ly: (ly[0] * n_per + pt[i, p], 0, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(b, mp),
-        in_specs=[
-            # q/out: (1, 1, H·Dh) blocks over a (B, 1, H·Dh) array — the
-            # trailing two block dims EQUAL the array dims, satisfying the
-            # Mosaic tiling rule for any batch size
-            pl.BlockSpec((1, 1, h * dh), lambda i, p, pt, ln, ly: (i, 0, 0)),
-            pl.BlockSpec((1, page_size, fused), page_idx),
-            pl.BlockSpec((1, page_size, fused), page_idx),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, h * dh), lambda i, p, pt, ln, ly: (i, 0, 0)),
-            pl.BlockSpec((1, 1, h), lambda i, p, pt, ln, ly: (i, 0, 0)),
-            pl.BlockSpec((1, 1, h), lambda i, p, pt, ln, ly: (i, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h), jnp.float32),
-            pltpu.VMEM((1, h * dh), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _paged_attn_kernel,
-        n_kv_heads=n_kv_heads,
-        head_dim=dh,
-        page_size=page_size,
-        n_heads=h,
-        window=window,
-    )
-    out, m, l = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, 1, h * dh), q.dtype),
-                   jax.ShapeDtypeStruct((b, 1, h), jnp.float32),
-                   jax.ShapeDtypeStruct((b, 1, h), jnp.float32)],
-        interpret=interpret,
-    )(page_table, lengths, layer, q.reshape(b, 1, h * dh), k_pages, v_pages)
-    out = out.reshape(b, h, dh)
-    if with_stats:
-        return out, m.reshape(b, h), l.reshape(b, h)
-    return out
-
-
-# ------------------------------------------------------------- dispatcher
-
-
-def paged_attention(
-    q: jnp.ndarray,
-    k_pages: jnp.ndarray,
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,
-    lengths: jnp.ndarray,
-    *,
-    n_kv_heads: int,
-    impl: str = "auto",
-    window: int = 0,
-    with_stats: bool = False,
-    layer=None,
-    n_pages_per_layer: int = 0,
-):
-    """impl: "auto" | "xla" | "pallas" | "pallas_interpret" (kernel
-    correctness tests on CPU). ``with_stats`` additionally returns the
-    flash (m, l) stats for cross-source merging; ``layer``/
-    ``n_pages_per_layer`` select a layer inside STACKED [L·N, P, fused]
-    pools (pallas path; the XLA path's callers slice the layer out — a
-    plain gather XLA fuses fine).
-
-    "auto" resolves to the XLA path on every backend — a measured, now
-    settled decision (README "Pallas status"): on a real v5e at 8B
-    serving shapes the kernel's (slot, page) grid pays ~13 µs of
-    unhidden DMA latency per step (1,380 vs 3,623 tok/s end-to-end,
-    round 3), and the dense-ctx chunk scheme (engine/continuous.py)
-    removed the per-step paged read it was built to accelerate — decode
-    now touches pages once per chunk, which stock XLA gathers at full
-    bandwidth. The kernel is RETIRED to a reference/testing role: it
-    stays correct (interpret-mode cross-checks on CPU, explicit
-    ``attention_impl="pallas"``) and is the starting point should a
-    future shape — very long contexts where live-bucket padding waste
-    overtakes DMA latency — reopen the question."""
-    if impl == "auto":
-        impl = "xla"
-    if impl == "xla":
-        if layer is not None:
-            raise ValueError(
-                "stacked-pool layer indexing is a pallas-path feature; "
-                "slice the layer before the xla path")
-        return paged_attention_xla(
-            q, k_pages, v_pages, page_table, lengths, n_kv_heads=n_kv_heads,
-            window=window, with_stats=with_stats,
-        )
-    if impl in ("pallas", "pallas_interpret"):
-        return paged_attention_pallas(
-            q, k_pages, v_pages, page_table, lengths,
-            n_kv_heads=n_kv_heads, window=window,
-            interpret=impl == "pallas_interpret",
-            with_stats=with_stats, layer=layer,
-            n_pages_per_layer=n_pages_per_layer,
-        )
-    raise ValueError(f"unknown paged-attention impl {impl!r}")

@@ -19,13 +19,9 @@ Fixed reference bugs: no duplicate ``pending_batches`` stats key
 (``src/batcher.py:263,268``), and exact result-count mismatches fan an error
 to every future rather than hanging some of them.
 
-Mixed-step budget (Sarathi): the continuous-engine path does NOT coalesce
-here — admission throttling for ragged mixed batches lives in
-``config.BatcherConfig.mixed_step_tokens``, handed down by the worker into
-``serving.pump.EnginePump(mixed_step_tokens=...)`` which writes it into the
-engine config; ``ContinuousEngine._step_mixed`` enforces it per dispatch.
-This module's size/latency flush knobs only govern the static-``Engine``
-backend path.
+The continuous-engine path does not coalesce here (``serving.pump``): this
+module's size/latency flush knobs only govern the static-``Engine`` backend
+path.
 """
 
 from __future__ import annotations

@@ -46,7 +46,6 @@ class EnginePump:
 
     def __init__(self, engine: Any, idle_wait_s: float = 0.25,
                  error_backoff_s: float = 0.05,
-                 mixed_step_tokens: Optional[int] = None,
                  overlap_forms: bool = True,
                  event_log: Any = None, model: str = "") -> None:
         self.engine = engine
@@ -57,23 +56,15 @@ class EnginePump:
         self._model = model
         self.idle_wait_s = idle_wait_s          # safety-net poll when idle
         self.error_backoff_s = error_backoff_s  # pause after a failed step
-        if mixed_step_tokens is not None:
-            # serving-layer Sarathi knob (BatcherConfig.mixed_step_tokens):
-            # cap the prefill tokens each mixed ragged step carries so
-            # admission bursts throttle to leftover compute instead of
-            # stretching live decodes' inter-token latency. Hand down into
-            # the engine config — only the engine's _step_mixed reads it.
-            engine.config.mixed_step_tokens = int(mixed_step_tokens)
         self._overlap_admitted = 0
         self._stream_frames_polled = 0
-        self._spec_rounds = 0
         # sub-chunk streaming (ISSUE 13): harvest ready token-ring
         # entries inside the measured host bubble. Engine-thread-only by
         # the same argument as the overlap hook below.
         self._poll_stream = getattr(engine, "poll_stream", None)
         if overlap_forms and hasattr(engine, "overlap_hook"):
             # batch-formation overlap (ISSUE 5c): the engine calls this
-            # right after dispatching a decode/mixed chunk, while the
+            # right after dispatching a decode chunk, while the
             # device is busy — the inbox drain (request validation,
             # submit, prefetch probes) runs in the step's shadow instead
             # of the host gap between steps. Thread-safe by construction:
@@ -87,16 +78,6 @@ class EnginePump:
                 # streaming consumers see its tokens one chunk early
                 if self._poll_stream is not None:
                     self._stream_frames_polled += self._poll_stream()
-                # async speculation (ISSUE 15): the drafter rides the
-                # SAME bubble, strictly after the stream poll — tokens
-                # already computed always beat tokens merely predicted,
-                # and the poll commits state the draft catch-up reads.
-                # Mid-flight the speculator only catches its caches up
-                # (an async dispatch, no host sync), so a draft overrun
-                # queues behind the next chunk rather than delaying it.
-                spec = getattr(self.engine, "speculator", None)
-                if spec is not None:
-                    self._spec_rounds += spec.schedule()
 
             engine.overlap_hook = _overlap
         # (request, optional handoff, optional stream cb, future, loop,
@@ -374,8 +355,5 @@ class EnginePump:
             # streamed frames delivered by host-bubble ring polls rather
             # than the deferred flush (ISSUE 13)
             "stream_frames_polled": self._stream_frames_polled,
-            # draft rounds dispatched from the overlap hook's bubble
-            # share (ISSUE 15; step-top propose rounds are the engine's)
-            "spec_overlap_rounds": self._spec_rounds,
             "engine": self.engine.get_metrics(),
         }

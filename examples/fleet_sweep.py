@@ -72,14 +72,6 @@ after a failover — must produce the same stream):
               Acceptance: per-model token-exact, staged swap >= 5x faster
               than a cold ``load_model``, per-model affinity hit rate >=
               90%, two same-seed runs emit identical receipts.
-  spec        bubble-scheduled async speculation (ISSUE 15) at two
-              operating points: the low-batch SLO knee (~25% capacity,
-              big host bubble — drafter engages, streamed mean ITL must
-              improve >= 15% with accept-rate >= 0.6) and saturation
-              (1.5x capacity, zero bubble — drafter must auto-idle with
-              goodput within 2% of spec-off). Every stream token-exact
-              (speculation never changes tokens); two same-seed spec
-              runs emit identical receipts.
   long        long-context rung: 2048-token prompts (default policy;
               SWEEP_SHAPE=long raises to 8192) through the coordinator
               with per-token admission cost. Every result token-exact vs
@@ -1202,147 +1194,6 @@ async def leg_multimodel():
     return rows
 
 
-async def _spec_run(meta, n, prompts, rate, nt, seed):
-    """One seeded streaming pass for the spec leg: like ``_stream_run``
-    but also scrapes the worker-side ``spec_async_*`` engine metric
-    family BEFORE teardown — the acceptance gates (accept-rate floor,
-    saturation auto-idle) read the drafter's own ledger, not a proxy."""
-    coord, workers = await start_fleet(n)
-    await coord.deploy_model(fake_cfg(**meta), register_shards=False)
-    rs = np.random.RandomState(seed)
-    marks = [[] for _ in prompts]
-
-    def mk_cb(rec):
-        def cb(toks):
-            rec.append((time.perf_counter(), list(toks)))
-        return cb
-
-    tasks = []
-    t0 = time.perf_counter()
-    for i, p in enumerate(prompts):
-        tasks.append(asyncio.ensure_future(coord.submit_stream(
-            "m", prompt=p, max_new_tokens=nt, on_tokens=mk_cb(marks[i]),
-            request_id=f"sp{i}")))
-        await asyncio.sleep(float(rs.exponential(1.0 / rate)))
-    results = await asyncio.gather(*tasks, return_exceptions=True)
-    wall = time.perf_counter() - t0
-    itls = []
-    for ms in marks:
-        prev = None
-        for t, toks in ms:
-            if prev is not None:
-                itls.append(t - prev)
-            itls.extend([0.0] * (len(toks) - 1))
-            prev = t
-    spec_m = {"engine_steps": 0}
-    for wid in list(coord.router.workers):
-        m = await coord.router.client_for(wid).metrics()
-        eng = m.get("models", {}).get("m", {})
-        spec_m["engine_steps"] += int(eng.get("engine_steps", 0))
-        for k, v in eng.items():
-            if (k.startswith("spec_async_") and not k.endswith("_rate")
-                    and isinstance(v, (int, float))):
-                spec_m[k] = spec_m.get(k, 0) + v
-    drafted = spec_m.get("spec_async_drafted_tokens", 0)
-    spec_m["spec_async_accept_rate"] = (
-        spec_m.get("spec_async_accepted_tokens", 0) / drafted
-        if drafted else 0.0)
-    receipt = [tuple(r["tokens"]) if isinstance(r, dict) else ("ERR",)
-               for r in results]
-    await stop_fleet(coord, workers)
-    return results, wall, itls, spec_m, receipt
-
-
-async def leg_spec():
-    """Bubble-scheduled async speculation (ISSUE 15's measurement half).
-    Calibration: 2 tokens per 40 ms fake step, so a 16-token request
-    takes 8 megasteps baseline — the drafter's bubble tokens (k=4 at
-    accept 0.7 ≈ +2.8/step) cut that roughly in half, which is the
-    streamed-ITL win the knee row must show. Two operating points:
-
-      knee        ~25% of fleet capacity: ~2 of 8 slots live, bubble =
-                  0.75x step >> floor — the drafter engages. Acceptance:
-                  streamed mean ITL improves >= 15% vs spec-off at the
-                  SAME load, accept-rate >= 0.6, every stream
-                  token-exact (speculation must never change tokens),
-                  and two same-seed spec runs emit identical receipts.
-      saturation  1.5x capacity: every slot live, bubble 0 < floor —
-                  the drafter must auto-idle. Acceptance: >= 50% of
-                  steps auto-idle and goodput holds within 2% of
-                  spec-off (speculation is free when there is no bubble
-                  to spend)."""
-    n = 1
-    nt = bench.FLEET_NEW_TOKENS
-    tps, step_s = 2, 0.04
-    base = dict(step_latency_s=step_s, tokens_per_step=tps)
-    spec = dict(base, spec_async=1, spec_max_draft=4, spec_accept_rate=0.7,
-                spec_bubble_floor_s=0.3 * step_s)
-    cap = bench.FLEET_SLOTS * tps / step_s / nt     # req/s per worker
-    knee_rate, sat_rate = 0.25 * cap * n, 1.5 * cap * n
-    n_req = max(24, bench.FLEET_REQUESTS // 4)
-    prompts = prompts_unique(n_req, bench.FLEET_SEED + 701)
-    runs = (("knee_off", base, knee_rate), ("knee_spec", spec, knee_rate),
-            ("knee_replay", spec, knee_rate), ("sat_off", base, sat_rate),
-            ("sat_spec", spec, sat_rate))
-    rows, out_rows, receipts = {}, [], {}
-    for mode, meta, rate in runs:
-        results, wall, itls, sm, receipt = await _spec_run(
-            meta, n, prompts, rate, nt, bench.FLEET_SEED + 701)
-        receipts[mode] = receipt
-        ok, toks = score(prompts, results, nt)
-        row = {
-            "leg": f"spec_{mode}", "workers": n, "requests": n_req,
-            "offered_req_s": round(rate, 1),
-            "goodput_toks": round(toks / wall, 1),
-            "token_exact": ok,
-            "token_exact_frac": round(ok / max(1, n_req), 4),
-            "itl_mean_ms": round(1e3 * sum(itls) / max(1, len(itls)), 2),
-            "itl_p99_ms": round(pct(itls, 0.99) * 1e3, 2),
-            "accept_rate": round(sm["spec_async_accept_rate"], 3),
-            "drafted": int(sm.get("spec_async_drafted_tokens", 0)),
-            "accepted": int(sm.get("spec_async_accepted_tokens", 0)),
-            "auto_idles": int(sm.get("spec_async_auto_idles", 0)),
-            "engine_steps": int(sm["engine_steps"]),
-            "wall_s": round(wall, 2),
-        }
-        out_rows.append(emit(row))
-        rows[mode] = row
-        assert ok == n_req, f"spec_{mode}: {ok}/{n_req} token-exact"
-    itl_gain = 1.0 - (rows["knee_spec"]["itl_mean_ms"]
-                      / max(rows["knee_off"]["itl_mean_ms"], 1e-9))
-    goodput_frac = (rows["sat_spec"]["goodput_toks"]
-                    / max(rows["sat_off"]["goodput_toks"], 1e-9))
-    idle_frac = (rows["sat_spec"]["auto_idles"]
-                 / max(rows["sat_spec"]["engine_steps"], 1))
-    replay_ok = receipts["knee_spec"] == receipts["knee_replay"]
-    log(f"  spec: knee mean ITL {rows['knee_off']['itl_mean_ms']:.2f} -> "
-        f"{rows['knee_spec']['itl_mean_ms']:.2f} ms "
-        f"({itl_gain:.1%} better, acceptance >= 15%), accept-rate "
-        f"{rows['knee_spec']['accept_rate']:.2f} (floor 0.6); saturation "
-        f"goodput {goodput_frac:.1%} of spec-off (floor 98%), "
-        f"{idle_frac:.1%} of steps auto-idled; same-seed receipts "
-        f"{'IDENTICAL' if replay_ok else 'DIVERGED'}")
-    assert itl_gain >= 0.15, \
-        f"knee streamed mean ITL gain {itl_gain:.1%} (floor 15%)"
-    assert rows["knee_spec"]["accept_rate"] >= 0.6, \
-        f"knee accept-rate {rows['knee_spec']['accept_rate']} (floor 0.6)"
-    assert rows["knee_spec"]["drafted"] > 0, "knee drafter never engaged"
-    assert goodput_frac >= 0.98, \
-        f"saturation goodput {goodput_frac:.1%} of spec-off (floor 98%)"
-    assert idle_frac >= 0.5, \
-        f"saturation auto-idle fraction {idle_frac:.1%} (floor 50%)"
-    assert replay_ok, "same-seed spec runs diverged"
-    out_rows.append(emit({
-        "leg": "spec", "summary": True,
-        "knee_itl_gain": round(itl_gain, 4),
-        "knee_accept_rate": rows["knee_spec"]["accept_rate"],
-        "saturation_goodput_vs_off": round(goodput_frac, 4),
-        "saturation_idle_frac": round(idle_frac, 4),
-        "receipts_identical": replay_ok}))
-    dump_leg("spec", out_rows)
-    return out_rows
-
-
 async def leg_long():
     """Long-context rung: 2k-token prompts (the DEFAULT policy; set
     SWEEP_SHAPE=long for the full 8k row) flow through the coordinator
@@ -1387,14 +1238,14 @@ LEGS = {"replicated": leg_replicated, "disagg": leg_disagg,
         "affinity": leg_affinity, "kill": leg_kill,
         "kvfabric": leg_kvfabric, "stream": leg_stream,
         "autoscale": leg_autoscale, "upgrade": leg_upgrade,
-        "multimodel": leg_multimodel, "spec": leg_spec, "long": leg_long}
+        "multimodel": leg_multimodel, "long": leg_long}
 
 
 async def main_async():
     want = [s for s in os.environ.get(
         "SWEEP_LEGS",
         "replicated,disagg,affinity,kill,kvfabric,stream,autoscale,"
-        "upgrade,multimodel,spec,long,tiny"
+        "upgrade,multimodel,long,tiny"
     ).split(",") if s]
     all_rows = []
     for name in want:

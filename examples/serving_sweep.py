@@ -19,7 +19,7 @@ Usage (defaults mirror bench.py serving mode at the 8B rung):
     SWEEP_RATES=4,8,12 SWEEP_REQUESTS=96 SWEEP_TRIALS=5 \
         python examples/serving_sweep.py
     SWEEP_SHAPE=long python examples/serving_sweep.py   # 2k-prompt rung
-    SWEEP_SHAPE=mixed python examples/serving_sweep.py  # ragged mixed rung
+    SWEEP_SHAPE=mixed python examples/serving_sweep.py  # long prompts mixed in
 Prints one JSON line per rate (the median trial, annotated with the
 band) and a final markdown table on stderr.
 """
@@ -55,12 +55,10 @@ if os.environ.get("SWEEP_SHAPE", "") == "long":
     os.environ.setdefault("BENCH_KV_OFFLOAD", "1")
 # SWEEP_SHAPE=mixed (ISSUE 3): a steady 128-token decode stream with every
 # 8th request admitting a 2k-token prompt — the workload whose decode ITL
-# p99 the ragged mixed step must keep from cliffing during admissions
-# (acceptance: no step past ~2x the steady-state ITL median). Runs the
-# ragged kernel with chunked prefill and a Sarathi-style per-step prefill
-# budget; compare against BENCH_ATTN=xla (alternating dispatch) to see the
-# cliff this shape exists to measure. fp8 KV for the same capacity reason
-# as the long rung.
+# p99 long-prompt admissions cliff (watch for steps past ~2x the
+# steady-state ITL median). Runs chunked prefill, one chunk alternating
+# with each decode chunk. fp8 KV for the same capacity reason as the long
+# rung.
 # SWEEP_SHAPE=moe (ISSUE 14 / VERDICT.md "Next" #8): the capacity-bound
 # MoE rung — mixtral-16g (12.9B params, 8 experts, top-2) is the largest
 # Mixtral shape whose int4 weights (~6.0 GiB) leave a 16 GB chip room
@@ -82,8 +80,6 @@ if os.environ.get("SWEEP_SHAPE", "") == "mixed":
     os.environ.setdefault("BENCH_MIX_EVERY", "8")
     os.environ.setdefault("BENCH_MIX_PROMPT", "2048")
     os.environ.setdefault("BENCH_PREFILL_CHUNK", "512")
-    os.environ.setdefault("BENCH_MIXED_TOKENS", "512")
-    os.environ.setdefault("BENCH_ATTN", "pallas-ragged")
     os.environ.setdefault("BENCH_KV_DTYPE", "float8_e4m3fn")
 
 import numpy as np  # noqa: E402
@@ -181,12 +177,6 @@ def main():
         os.environ.get("BENCH_MAX_WAITING", str(bench.BATCH)))
     engine.config.queue_deadline_s = float(
         os.environ.get("BENCH_DEADLINE_S", "8"))
-    # admission coalescing (r5): BENCH_ADMIT_MIN=16 holds admissions for
-    # up to BENCH_ADMIT_HOLD seconds until 16 queue up
-    engine.config.admission_min_batch = int(
-        os.environ.get("BENCH_ADMIT_MIN", "0"))
-    engine.config.admission_max_hold_s = float(
-        os.environ.get("BENCH_ADMIT_HOLD", "0.25"))
     # BENCH_DEFER_ADMIT=0: synchronous first-token reads at admission —
     # TTFT drops ~a chunk at some goodput cost (the latency-SLO knee)
     if os.environ.get("BENCH_DEFER_ADMIT", "") == "0":

@@ -181,20 +181,6 @@ if [ "$rc" -ne 0 ]; then
     exit "$rc"
 fi
 
-# --- stage 2j: fast async-speculation leg -----------------------------
-# bubble-scheduled speculation (-m spec): acceptance-math bit-parity vs
-# the frozen r5 rule, greedy spec-vs-off token exactness (f32 + int4),
-# drafter extremes, verify-program compile guard, saturation auto-idle,
-# same-seed determinism, pump hook ordering.
-echo "== async speculation (-m 'spec and not slow') =="
-timeout -k 10 600 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
-    -m 'spec and not slow' --continue-on-collection-errors \
-    -p no:cacheprovider -p no:xdist -p no:randomly || rc=1
-if [ "$rc" -ne 0 ]; then
-    echo "check.sh: async speculation leg FAILED" >&2
-    exit "$rc"
-fi
-
 # --- stage 3: tier-1 tests (verbatim ROADMAP.md verify command) -------
 set -o pipefail
 rm -f /tmp/_t1.log

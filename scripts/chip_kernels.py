@@ -8,8 +8,7 @@ Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; the five int4
 payload shapes of mistral-7b (N=32,768 for the lm_head) at M=128 (the
 prefill bucket of ``ops.int4_matmul.blocks_for``) and, for the 2-D and
 stacked legs, at M=8 (the decode bucket: what a served decode step runs). Tolerances are the ones the CPU parity tests use
-for the same dtypes (``tests/test_int4_matmul.py``, ``test_flash_decode.py``,
-``test_ragged_attention.py``, ``test_paged.py``, ``test_fused_decode.py``).
+for the same dtypes (``tests/test_int4_matmul.py``, ``test_flash_decode.py``).
 
 Each kernel gets one outcome line — ``ok`` (compiled, ran, matched XLA),
 ``mismatch`` or ``refused`` with the compiler's message — and the table is
@@ -30,11 +29,11 @@ import time
 import traceback
 
 FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
-            int4_rows=(128, 8), D=4096, fused_N=(6144, 4096),
+            int4_rows=(128, 8),
             int4_shapes=((2048, 6144), (2048, 4096), (2048, 28672),
                          (7168, 4096), (2048, 32768)))
 TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
-            int4_rows=(32, 3), D=256, fused_N=(512, 256),
+            int4_rows=(32, 3),
             int4_shapes=((128, 256), (256, 128)))
 OUT = os.path.join("chiprun_out", "chip_kernels.json")
 
@@ -163,24 +162,6 @@ def _paged_inputs(cfg):
     return q, kp, vp, pt.astype(jnp.int32), ks[4:], n
 
 
-def check_paged_attention(cfg, interpret):
-    import jax
-
-    from distributed_inference_engine_tpu.ops.paged_attention import (
-        paged_attention_pallas,
-        paged_attention_xla,
-    )
-
-    q, kp, vp, pt, ks, n = _paged_inputs(cfg)
-    lengths = jax.random.randint(ks[0], (cfg["B"],), 1, cfg["ctx"] + 1)
-    ref = paged_attention_xla(q, kp, vp, pt, lengths,
-                              n_kv_heads=cfg["Hkv"])
-    got = jax.jit(lambda *a: paged_attention_pallas(
-        *a, n_kv_heads=cfg["Hkv"], interpret=interpret, layer=0,
-        n_pages_per_layer=n))(q, kp, vp, pt, lengths)
-    return f"max|err| {_close(got, ref, 2e-2):.2e}"
-
-
 def _side_inputs(cfg, ks):
     import jax
     import jax.numpy as jnp
@@ -247,146 +228,13 @@ def check_flash_decode_served(cfg, interpret):
     return f"4 block sizes, max|err| {max(errs):.2e}"
 
 
-def check_flash_decode_fw(cfg, interpret):
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from distributed_inference_engine_tpu.ops.flash_decode import (
-        flash_decode_attention_fw_pallas,
-        flash_decode_attention_xla,
-    )
-
-    q, kp, vp, pt, ks, n = _paged_inputs(cfg)
-    sk, sv, plen = _side_inputs(cfg, ks)
-    b, hkv, dh, w = cfg["B"], cfg["Hkv"], cfg["Dh"], cfg["W"]
-    fks = jax.random.split(ks[3], 4)
-    fk = jax.random.normal(fks[0], (b, 1, hkv, dh),
-                           jnp.float32).astype(jnp.bfloat16)
-    fv = jax.random.normal(fks[1], (b, 1, hkv, dh),
-                           jnp.float32).astype(jnp.bfloat16)
-    idx = jax.random.randint(fks[2], (b,), 0, w)
-    active = jax.random.randint(fks[3], (b,), 0, 2)
-    onehot = (jnp.arange(w)[None, :] == idx[:, None]) & (active[:, None] > 0)
-    sk_ref = jnp.where(onehot[:, :, None, None], fk[:, 0][:, None], sk)
-    sv_ref = jnp.where(onehot[:, :, None, None], fv[:, 0][:, None], sv)
-    ref = flash_decode_attention_xla(q, kp, vp, pt, plen, sk_ref, sv_ref,
-                                     idx + active, n_kv_heads=hkv)
-    got, sk_new, sv_new = jax.jit(lambda *a: flash_decode_attention_fw_pallas(
-        *a, n_kv_heads=hkv, interpret=interpret, layer=0,
-        n_pages_per_layer=n))(q, kp, vp, pt, plen, sk, sv, fk, fv, idx,
-                              active)
-    err = _close(got, ref, 2e-2)
-    np.testing.assert_array_equal(np.asarray(sk_new, np.float32),
-                                  np.asarray(sk_ref, np.float32))
-    np.testing.assert_array_equal(np.asarray(sv_new, np.float32),
-                                  np.asarray(sv_ref, np.float32))
-    return f"max|err| {err:.2e}, side writeback bit-exact"
-
-
-def check_ragged_attention(cfg, interpret):
-    """Mixed batch at the engine's shape: one page-sized chunk bucket
-    (Qmax = page) shared by decode rows (q=1) and prefill-chunk rows."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from distributed_inference_engine_tpu.ops.ragged_attention import (
-        ragged_attention_pallas,
-        ragged_attention_xla,
-    )
-
-    _q, kp, vp, pt, ks, n = _paged_inputs(cfg)
-    b, h, hkv, dh, p = (cfg[k] for k in ("B", "H", "Hkv", "Dh", "P"))
-    qmax = p
-    bf = jnp.bfloat16
-    q = jax.random.normal(ks[0], (b, qmax, h, dh), jnp.float32).astype(bf)
-    fk = jax.random.normal(ks[1], (b, qmax, hkv, dh), jnp.float32).astype(bf)
-    fv = jax.random.normal(ks[2], (b, qmax, hkv, dh), jnp.float32).astype(bf)
-    # even rows decode (q=1, any context), odd rows carry a chunk
-    rows = jnp.arange(b)
-    q_lens = jnp.where(rows % 2 == 0, 1, 1 + (rows * 7) % qmax)
-    ctx_lens = jnp.minimum((rows * 13) % cfg["ctx"], cfg["ctx"] - q_lens)
-    args = (q, kp, vp, pt, ctx_lens.astype(jnp.int32),
-            q_lens.astype(jnp.int32), fk, fv)
-    out_r, kp_r, vp_r = ragged_attention_xla(*args, n_kv_heads=hkv)
-    out, kp_n, vp_n = jax.jit(lambda *a: ragged_attention_pallas(
-        *a, n_kv_heads=hkv, interpret=interpret, layer=0,
-        n_pages_per_layer=n))(*args)
-    err = _close(out, out_r, 2e-2)
-    np.testing.assert_array_equal(np.asarray(kp_n, np.float32),
-                                  np.asarray(kp_r, np.float32))
-    np.testing.assert_array_equal(np.asarray(vp_n, np.float32),
-                                  np.asarray(vp_r, np.float32))
-    return f"max|err| {err:.2e}, page writeback bit-exact"
-
-
-def _fused_inputs(cfg, n):
-    import jax
-    import jax.numpy as jnp
-
-    ks = jax.random.split(jax.random.key(n), 4)
-    bf = jnp.bfloat16
-    x = jax.random.normal(ks[0], (cfg["M"], cfg["D"]), jnp.float32).astype(bf)
-    g = (1.0 + 0.1 * jax.random.normal(ks[1], (cfg["D"],),
-                                       jnp.float32)).astype(bf)
-    w = (0.02 * jax.random.normal(ks[2], (cfg["D"], n),
-                                  jnp.float32)).astype(bf)
-    res = jax.random.normal(ks[3], (cfg["M"], n), jnp.float32).astype(bf)
-    return x, g, w, res
-
-
-def check_fused_norm_matmul(cfg, interpret):
-    import jax
-    import jax.numpy as jnp
-
-    from distributed_inference_engine_tpu.ops.fused_decode import (
-        norm_matmul,
-        norm_matmul_wants,
-    )
-    from distributed_inference_engine_tpu.ops.norms import rms_norm
-
-    x, g, w, _res = _fused_inputs(cfg, cfg["fused_N"][0])
-    assert norm_matmul_wants(x, w)
-    ref = jax.jit(lambda x, g, w: jnp.dot(rms_norm(x, g, 1e-5), w))(x, g, w)
-    got = jax.jit(lambda x, g, w: norm_matmul(
-        x, g, w, eps=1e-5, interpret=interpret))(x, g, w)
-    err = _close(got, ref, 2e-2)
-    exact = bool(jnp.all(got == ref))
-    return f"max|err| {err:.2e}, bit-exact vs XLA: {exact}"
-
-
-def check_fused_matmul_residual(cfg, interpret):
-    import jax
-    import jax.numpy as jnp
-
-    from distributed_inference_engine_tpu.ops.fused_decode import (
-        matmul_residual,
-        matmul_residual_wants,
-    )
-
-    x, _g, w, res = _fused_inputs(cfg, cfg["fused_N"][1])
-    assert matmul_residual_wants(x, w)
-    ref = jax.jit(lambda x, w, r: r + jnp.dot(x, w))(x, w, res)
-    got = jax.jit(lambda x, w, r: matmul_residual(
-        x, w, r, interpret=interpret))(x, w, res)
-    err = _close(got, ref, 2e-2)
-    exact = bool(jnp.all(got == ref))
-    return f"max|err| {err:.2e}, bit-exact vs XLA: {exact}"
-
-
 # name -> (check, on the default serving path?)
 CHECKS = {
     "int4_matmul_2d": (check_int4_2d, True),
     "int4_matmul_stacked": (check_int4_stacked, True),
     "int4_matmul_cp": (check_int4_cp, True),
-    "paged_attention_pallas": (check_paged_attention, False),
-    "fused_decode_norm_matmul": (check_fused_norm_matmul, False),
-    "fused_decode_matmul_residual": (check_fused_matmul_residual, False),
     "flash_decode": (check_flash_decode, True),
     "flash_decode_served": (check_flash_decode_served, True),
-    "flash_decode_fw": (check_flash_decode_fw, False),
-    "ragged_attention": (check_ragged_attention, False),
 }
 
 

@@ -1,0 +1,119 @@
+"""The programs the benchmark's cells run, as text: ``str(jax.make_jaxpr)`` of
+a ``ContinuousEngine``'s ``_decode_chunk`` (8 steps) and ``_prefill_pages``
+for the three specs of the cells at test size: mistral-tiny int4 (no sliding
+window) on the ``window`` body (``pallas-decode_interpret``) and on ``dense``
+(``xla``), and ``ling-tiny`` (``hybrid``).
+
+    JAX_PLATFORMS=cpu python -m scripts.decode_jaxpr dump <dir>
+    python -m scripts.decode_jaxpr compare <dir-a> <dir-b>
+
+A PR that must not change those programs dumps in a copy of its parent
+(``git archive``) and in its own tree, then compares: equal files mean equal
+programs, without the chip. ``compare`` drops equations whose only output is
+``_`` (traced, read by nothing: XLA removes them) from both sides and says how
+many each held.
+"""
+
+from __future__ import annotations
+
+import os
+import re
+import sys
+
+_DEAD = re.compile(r"^\s*_:[^\n]* = \w+\[\n(?:[^\n\]]*\n)*?\s*\] \w+\n", re.M)
+
+
+def _dump_engine(out: str, name: str, eng) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_engine_tpu.ops.sampling import SamplingParams
+
+    kv, n = eng.kv, eng.max_slots
+    sampling = SamplingParams(eng._temps, eng._top_k, eng._top_p, eng._min_p)
+    key = jax.random.key(0)
+    pages = 2 if eng.get_metrics()["attn_impl"] == "xla" else 0
+
+    def decode(*args):
+        return eng._decode_chunk(*args, n_steps=8, n_ctx_pages=pages,
+                                 use_stops=True)
+
+    text = jax.make_jaxpr(decode)(
+        eng.params, *kv.pools, eng._lengths, eng._last, eng._active,
+        eng._produced, kv.page_table, jnp.zeros((n,), jnp.int32),
+        eng._max_new, sampling, eng._eos, eng._stops_dev, eng._firsts_dev,
+        key)
+    with open(os.path.join(out, f"{name}.decode.txt"), "w") as f:
+        f.write(str(text))
+    bb, tb = 2, 32
+    args = [eng.params, jnp.zeros((bb, tb), jnp.int32),
+            jnp.ones((bb,), jnp.int32), *kv.pools,
+            jnp.zeros((bb, kv.max_pages_per_seq), jnp.int32),
+            SamplingParams(jnp.zeros((bb,)), jnp.zeros((bb,), jnp.int32),
+                           jnp.ones((bb,)), jnp.zeros((bb,))), key]
+    if eng.spec.layer_kinds:
+        args.append(jnp.zeros((bb,), jnp.int32))       # slot ids
+    text = jax.make_jaxpr(lambda *a: eng._prefill_pages(*a))(*args)
+    with open(os.path.join(out, f"{name}.prefill.txt"), "w") as f:
+        f.write(str(text))
+
+
+def dump(out: str) -> None:
+    import jax
+
+    from distributed_inference_engine_tpu.config import EngineConfig
+    from distributed_inference_engine_tpu.engine.continuous import (
+        ContinuousEngine,
+    )
+    from distributed_inference_engine_tpu.models import (
+        ling_spec,
+        mistral_spec,
+    )
+    from distributed_inference_engine_tpu.ops.quant import (
+        random_quantized_params,
+    )
+
+    os.makedirs(out, exist_ok=True)
+    spec = mistral_spec("mistral-tiny", sliding_window=0, max_seq_len=128)
+    params = random_quantized_params(spec, jax.random.key(0), bits=4)
+    for name, impl in (("mistral_int4_window", "pallas-decode_interpret"),
+                       ("mistral_int4_dense", "xla")):
+        cfg = EngineConfig(max_slots=8, max_seq_len=128, page_size=16,
+                           num_pages=72, prefill_buckets=[32, 64, 96],
+                           decode_steps_per_call=8, attention_impl=impl)
+        _dump_engine(out, name, ContinuousEngine(spec, params=params,
+                                                 config=cfg))
+    cfg = EngineConfig(max_slots=4, max_seq_len=128, page_size=16,
+                       num_pages=40, prefill_buckets=[32, 64],
+                       decode_steps_per_call=8)
+    _dump_engine(out, "ling_tiny",
+                 ContinuousEngine(ling_spec("ling-tiny"), config=cfg))
+
+
+def compare(a: str, b: str) -> bool:
+    same = True
+    for name in sorted(os.listdir(a)):
+        with open(os.path.join(a, name)) as f:
+            ta = f.read()
+        with open(os.path.join(b, name)) as f:
+            tb = f.read()
+        equal = _DEAD.sub("", ta) == _DEAD.sub("", tb)
+        same &= equal
+        print(f"{name}: byte_equal={ta == tb} equal_without_dead={equal} "
+              f"dead_equations a={len(_DEAD.findall(ta))} "
+              f"b={len(_DEAD.findall(tb))} chars={len(tb)}")
+    return same
+
+
+def main(argv) -> int:
+    if len(argv) == 2 and argv[0] == "dump":
+        dump(argv[1])
+        return 0
+    if len(argv) == 3 and argv[0] == "compare":
+        return 0 if compare(argv[1], argv[2]) else 1
+    print(__doc__, file=sys.stderr)
+    return 2
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -1,7 +1,7 @@
 """Rule family 2: jit-stability — silent-recompile and retrace hazards.
 
-The compile-count guard tests (tests/test_fused_decode.py,
-tests/test_continuous.py) exist because one stray shape or a re-wrapped
+The compile-count guard tests (tests/test_flash_decode.py,
+tests/test_streaming.py) exist because one stray shape or a re-wrapped
 ``jax.jit`` silently recompiles per step and the only symptom is a slow
 sweep. These rules catch the three static precursors:
 

@@ -80,13 +80,6 @@ def pytest_configure(config):
         "adaptive-chunk compile guard, mid-stream failover resume; fast "
         "leg: pytest -m 'streaming and not slow')")
     config.addinivalue_line(
-        "markers", "spec: bubble-scheduled async speculation tests "
-        "(acceptance-math bit-parity vs the frozen r5 rule, greedy "
-        "spec-vs-off token exactness across weight dtypes, accept-all/"
-        "reject-all drafter extremes, verify compile guard, saturation "
-        "auto-idle, same-seed determinism; fast leg: pytest -m 'spec "
-        "and not slow')")
-    config.addinivalue_line(
         "markers", "multimodel: multi-model worker tests (resident-budget "
         "LRU eviction, background stage never blocks dispatch, probe-gated "
         "hot swap, model-qualified affinity/KV isolation, respawn reloads "

@@ -181,3 +181,38 @@ def test_prefix_hit_with_long_tail_chunks_the_tail():
     assert ref.generate([GenerationRequest(prompt=list(long_tail),
                                            max_new_tokens=4)])[0].tokens \
         == out.tokens
+
+
+# ------------------------------------------------- config compose validation
+
+
+def test_validate_prefill_compose():
+    import pytest
+
+    from distributed_inference_engine_tpu.config import (
+        validate_prefill_compose,
+    )
+
+    validate_prefill_compose(0, sp=4)        # no chunking: any sp is fine
+    validate_prefill_compose(512, sp=1)      # chunking without sp is fine
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        validate_prefill_compose(512, sp=2)
+    # the message must be actionable: name both escape hatches
+    with pytest.raises(ValueError, match="prefill_chunk=0"):
+        validate_prefill_compose(512, sp=2)
+    with pytest.raises(ValueError, match="sp=1"):
+        validate_prefill_compose(512, sp=2)
+
+
+def test_metadata_loader_rejects_sp_plus_chunk():
+    """The deploy-config path fails BEFORE the checkpoint load."""
+    import pytest
+
+    from distributed_inference_engine_tpu.config import ModelConfig
+    from distributed_inference_engine_tpu.models import engine_from_config
+
+    cfg = ModelConfig(
+        name="m", architecture="gpt2", metadata={
+            "sp": 2, "prefill_chunk": 512})
+    with pytest.raises(ValueError, match="prefill_chunk"):
+        engine_from_config(cfg)

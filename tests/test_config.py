@@ -1,10 +1,16 @@
 """Config tree + file loader tests (the config file the reference README
 promised at ``README.md:39`` but never shipped)."""
 
+import dataclasses
 import json
+import pathlib
+import re
+
+import pytest
 
 from distributed_inference_engine_tpu.config import (
     Config,
+    EngineConfig,
     MeshConfig,
     ModelConfig,
     config_from_dict,
@@ -97,3 +103,64 @@ def test_multihost_config_section(tmp_path):
     p2 = tmp_path / "w2.json"
     p2.write_text(json.dumps({"server": {"worker_id": "h1"}}))
     assert load_config(str(p2)).multihost.enabled is False
+
+
+# ---------------------------------------- retired metadata, and live fields
+
+
+_RETIRED_KEYS = ["decode_mode", "mixed_step_tokens", "spec_async",
+                 "spec_draft_model", "spec_max_draft", "spec_bubble_floor_s"]
+_RETIRED_IMPLS = ["pallas", "pallas_interpret", "pallas-decode-fw",
+                  "pallas-decode-fw_interpret", "pallas-ragged",
+                  "pallas-ragged_interpret"]
+
+
+@pytest.mark.parametrize("arch", ["llama", "fake"])
+@pytest.mark.parametrize("key", _RETIRED_KEYS)
+def test_retired_key_raises_at_load(key, arch):
+    """A deploy that still carries a key PR 29 removed fails at load, by
+    the key's name, before any weight exists — never silently ignored."""
+    from distributed_inference_engine_tpu.models import engine_from_config
+
+    cfg = ModelConfig(name="m", architecture=arch, metadata={
+        "size": "llama-tiny", "continuous": 1, key: 1})
+    with pytest.raises(ValueError, match=rf"'{key}' is retired"):
+        engine_from_config(cfg)
+
+
+@pytest.mark.parametrize("impl", _RETIRED_IMPLS)
+def test_retired_attention_string_raises(impl):
+    from distributed_inference_engine_tpu.engine.continuous import (
+        resolve_decode_body)
+    from distributed_inference_engine_tpu.models import (
+        engine_from_config, llama_spec)
+
+    cfg = ModelConfig(name="m", architecture="llama", metadata={
+        "size": "llama-tiny", "continuous": 1, "attention_impl": impl})
+    with pytest.raises(ValueError, match=rf"'{impl}' is retired"):
+        engine_from_config(cfg)
+    # an engine built directly refuses the string too
+    with pytest.raises(ValueError, match="attention_impl"):
+        resolve_decode_body(impl, "tpu", llama_spec("llama-tiny"))
+
+
+def _package_sources():
+    root = pathlib.Path(__file__).resolve().parents[1] / (
+        "distributed_inference_engine_tpu")
+    return {p: p.read_text() for p in root.rglob("*.py")
+            if p.name != "config.py"}
+
+
+@pytest.mark.parametrize(
+    "field", [f.name for f in dataclasses.fields(EngineConfig)])
+def test_engine_field_is_read(field):
+    """Every ``EngineConfig`` field is read by some module of the package
+    other than ``config.py``: as an attribute (``cfg.<field>``), or by name
+    (``getattr(cfg, "<field>", ...)``, the metadata loop's key tuple)."""
+    read = re.compile(rf"\.{field}\b(?!\s*=[^=])|[\"']{field}[\"']")
+    assert any(read.search(src) for src in _package_sources().values()), (
+        f"EngineConfig.{field} is read by nothing in the package")
+
+
+def test_engine_config_has_twenty_fields():
+    assert len(dataclasses.fields(EngineConfig)) == 20

@@ -200,32 +200,6 @@ def test_serving_metrics_ttft_and_occupancy():
     assert ttfts[-1] > ttfts[0]
 
 
-def test_decode_mode_inline_matches_window():
-    """decode_mode='inline' (per-step KV scatter — measured faster for
-    small-KV models) and the default windowed chunks are the same math:
-    token-identical greedy output."""
-    from distributed_inference_engine_tpu.models.llama import llama_spec
-
-    spec = llama_spec("llama-tiny", max_seq_len=128).replace(dtype="float32")
-    base = dict(max_slots=4, max_seq_len=128, prefill_buckets=[16, 64],
-                page_size=16, num_pages=48, decode_steps_per_call=4)
-    win = ContinuousEngine(spec, config=EngineConfig(**base), seed=0)
-    inline = ContinuousEngine(spec, params=win.params,
-                              config=EngineConfig(decode_mode="inline",
-                                                  **base))
-    reqs = lambda: [GenerationRequest(prompt=[1 + i, 5, 9], request_id=f"r{i}",
-                                      max_new_tokens=10) for i in range(3)]
-    a = {r.request_id: r.tokens for r in win.generate(reqs())}
-    b = {r.request_id: r.tokens for r in inline.generate(reqs())}
-    assert a == b
-
-    import pytest
-
-    with pytest.raises(ValueError, match="decode_mode"):
-        ContinuousEngine(spec, config=EngineConfig(decode_mode="bogus",
-                                                   **base))
-
-
 def test_defer_sync_matches_synchronous_output():
     """defer_sync overlaps the packed readback with the next chunk's
     execution; outputs must be token-for-token the synchronous engine's,
@@ -404,35 +378,73 @@ def test_page_boundary_pause_revives_under_defer_sync():
     assert len(out[0].tokens) == 16, out[0].tokens
 
 
-def test_admission_coalescing_holds_then_admits():
-    """admission_min_batch holds a lone waiting request while the decode
-    batch is busy, admits once the hold expires (or batch-mates arrive),
-    and never holds a hungry engine."""
-    import time as _time
+# ---------------------------------- the firsts host cache, device stop ids
 
-    cfg = _cfg(max_slots=4)
-    cfg.admission_min_batch = 4
-    cfg.admission_max_hold_s = 0.15
-    cont = ContinuousEngine(SPEC, config=cfg, seed=0)
-    rs = np.random.RandomState(5)
-    # engine idle (0 live slots < half): hold must NOT apply
-    cont.submit(_reqs(rs, 1, max_new=30)[0])
-    cont.step()
-    assert cont.n_live == 1
-    # fill to exactly half occupancy (2 live, 2 free): not hungry, and
-    # free slots exceed the queue -> a lone request must wait for mates
-    for r in _reqs(rs, 1, max_new=30):
-        cont.submit(r)
-    cont.step()
-    assert cont.n_live == 2
-    lone = GenerationRequest(prompt=[7, 8, 9], max_new_tokens=4,
-                             temperature=0.0, request_id="lone")
-    cont.submit(lone)
-    cont.step()
-    assert cont.n_waiting == 1          # held: min_batch not reached
-    _time.sleep(0.2)                    # hold timer expires
-    cont.step()
-    assert cont.n_waiting == 0          # admitted on timeout
-    out = cont.run_until_idle()
-    lone_res = next(r for r in out if r.request_id == "lone")
-    assert len(lone_res.tokens) == 4
+
+def _plain_engine():
+    spec = ModelSpec(
+        vocab_size=256, d_model=256, n_layers=2, n_heads=4, n_kv_heads=2,
+        d_ff=256, max_seq_len=128, dtype="float32",
+    )
+    return ContinuousEngine(spec, config=EngineConfig(
+        max_slots=2, max_seq_len=64, prefill_buckets=[16], page_size=16,
+        num_pages=16, decode_steps_per_call=4), seed=0)
+
+
+def _short_reqs(n=2, new=8):
+    return [GenerationRequest(
+        prompt=[(5 * i + j) % 250 + 1 for j in range(4 + 3 * i)],
+        max_new_tokens=new, temperature=0.0, request_id=f"r{i}")
+        for i in range(n)]
+
+
+def test_firsts_snapshot_cache():
+    """The packed chunk output carries the whole firsts buffer, so sync
+    processing caches it host-side for free; rescue reads go through
+    _firsts_snapshot() — one whole-buffer transfer at most, and the cache
+    invalidates when an admission rewrites the device columns."""
+    eng = _plain_engine()
+    assert eng._firsts_host is None
+    res = eng.generate(_short_reqs())
+    assert all(r.tokens for r in res)
+    # a sync decode chunk ran -> the packed read populated the cache
+    assert eng._firsts_host is not None
+    np.testing.assert_array_equal(eng._firsts_snapshot(),
+                                  np.asarray(eng._firsts_dev))
+    # stale-path: drop the cache, the snapshot refetches the device buffer
+    eng._firsts_host = None
+    snap = eng._firsts_snapshot()
+    np.testing.assert_array_equal(snap, np.asarray(eng._firsts_dev))
+    assert eng._firsts_host is not None
+    # a second wave re-admits (install rewrites firsts columns -> cache
+    # invalidated mid-run) and must still finish with a consistent cache
+    eng.generate(_short_reqs())
+    np.testing.assert_array_equal(eng._firsts_snapshot(),
+                                  np.asarray(eng._firsts_dev))
+
+
+def test_device_stop_ids():
+    """stop_ids ride to the device as a [slots, K] matrix: the slot's row
+    holds the ids (-1 padded), the decode loop exits at a hit, and the
+    host trimmer keeps the matched stop (same contract as eos)."""
+    eng = _plain_engine()
+    base = dict(prompt=[7, 11, 13], max_new_tokens=12, temperature=0.0)
+    free = eng.generate([GenerationRequest(request_id="free", **base)])[0]
+    assert len(free.tokens) == 12
+    stop_tok = free.tokens[2]
+    cut = free.tokens.index(stop_tok) + 1          # earliest hit, inclusive
+
+    req = GenerationRequest(request_id="stopped", stop_ids=[stop_tok],
+                            **base)
+    eng.submit(req)
+    eng.step()                                     # admission installs
+    rows = np.asarray(eng._stops_dev)
+    assert (rows == stop_tok).any(), "stop id never reached the device"
+    while eng.n_live or eng.n_waiting:
+        eng.step()
+    res = eng.drain_finished()[0]
+    assert res.finish_reason == "stop"
+    assert res.tokens == free.tokens[:cut]
+    # the freed slot's row resets so a stale id cannot stop the next tenant
+    done = eng.generate([GenerationRequest(request_id="after", **base)])[0]
+    assert done.tokens == free.tokens

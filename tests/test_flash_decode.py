@@ -1,9 +1,9 @@
 """Fused flash-decode attention kernel (ops/flash_decode.py): parity of the
 Pallas kernel (interpret mode on CPU) against the XLA reference composition
-paged_attention ⊕ window_decode_attention ⊕ merge_attention, across dtypes
-(fp32 / bf16 / fp8-KV pools), GQA head groupings, masked tails, empty rows,
-stacked-pool layer indexing, and the fused-writeback ("-fw") variant's
-side-buffer epilogue. Plus model-level forward_decode_window wiring."""
+paged_attention_xla ⊕ window_decode_attention ⊕ merge_attention, across
+dtypes (fp32 / bf16 / fp8-KV pools), GQA head groupings, masked tails, empty
+rows and stacked-pool layer indexing. Plus model-level forward_decode_window
+wiring and the engine's choice of decode body."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +12,6 @@ import pytest
 
 from distributed_inference_engine_tpu.ops.flash_decode import (
     flash_decode_attention,
-    flash_decode_attention_fw_pallas,
     flash_decode_attention_pallas,
     flash_decode_attention_xla,
 )
@@ -144,66 +143,6 @@ def test_parity_pages_per_block_sweep():
                                    rtol=2e-5, atol=2e-5, err_msg=f"bp={bp}")
 
 
-# ------------------------------------------------- fused-writeback variant
-
-
-def test_fw_parity_and_side_epilogue():
-    """The "-fw" kernel attends to the fresh token AND lands it in the side
-    buffers: output matches the reference computed AFTER the one-hot write,
-    side buffers match it bit-exactly (untouched entries preserved through
-    the aliased DMA epilogue)."""
-    b, w, hkv, dh, n = 4, 5, 2, 64, 16
-    q, kp, vp, pt, sk, sv = _inputs(jax.random.key(6))
-    ks = jax.random.split(jax.random.key(7), 2)
-    fk = jax.random.normal(ks[0], (b, 1, hkv, dh), jnp.float32)
-    fv = jax.random.normal(ks[1], (b, 1, hkv, dh), jnp.float32)
-    plen = jnp.array([17, 0, 24, 5], jnp.int32)
-    idx = jnp.array([3, 0, 4, 1], jnp.int32)
-    active = jnp.array([1, 0, 1, 1], jnp.int32)
-
-    onehot = (jnp.arange(w)[None, :] == idx[:, None]) & (active[:, None] > 0)
-    sk_ref = jnp.where(onehot[:, :, None, None], fk[:, 0][:, None], sk)
-    sv_ref = jnp.where(onehot[:, :, None, None], fv[:, 0][:, None], sv)
-    ref = _ref(q, kp, vp, pt, plen, sk_ref, sv_ref, idx + active, 2)
-
-    out, sk_new, sv_new = flash_decode_attention_fw_pallas(
-        q, kp, vp, pt, plen, sk, sv, fk, fv, idx, active, n_kv_heads=2,
-        interpret=True, layer=0, n_pages_per_layer=n)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(sk_new), np.asarray(sk_ref))
-    np.testing.assert_array_equal(np.asarray(sv_new), np.asarray(sv_ref))
-
-
-def test_fw_full_window_drops_write():
-    """A slot whose side window shows side_idx == W must not DMA out of
-    range; it still attends over its full window. (Active rows always have
-    side_idx < W in the engine — W is the chunk length — so the full rows
-    here are inactive: this guards the address math, not a live state.)"""
-    b, w, hkv, dh, n = 4, 5, 2, 64, 16
-    q, kp, vp, pt, sk, sv = _inputs(jax.random.key(8))
-    ks = jax.random.split(jax.random.key(9), 2)
-    fk = jax.random.normal(ks[0], (b, 1, hkv, dh), jnp.float32)
-    fv = jax.random.normal(ks[1], (b, 1, hkv, dh), jnp.float32)
-    plen = jnp.array([17, 8, 24, 5], jnp.int32)
-    idx = jnp.array([5, 2, 5, 1], jnp.int32)       # rows 0,2 full
-    active = jnp.array([0, 1, 0, 1], jnp.int32)
-
-    onehot = (jnp.arange(w)[None, :] == idx[:, None]) & (active[:, None] > 0)
-    sk_ref = jnp.where(onehot[:, :, None, None], fk[:, 0][:, None], sk)
-    sv_ref = jnp.where(onehot[:, :, None, None], fv[:, 0][:, None], sv)
-    n_side = jnp.minimum(idx + active, w)
-    ref = _ref(q, kp, vp, pt, plen, sk_ref, sv_ref, n_side, 2)
-
-    out, sk_new, sv_new = flash_decode_attention_fw_pallas(
-        q, kp, vp, pt, plen, sk, sv, fk, fv, idx, active, n_kv_heads=2,
-        interpret=True, layer=0, n_pages_per_layer=n)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_array_equal(np.asarray(sk_new), np.asarray(sk_ref))
-    np.testing.assert_array_equal(np.asarray(sv_new), np.asarray(sv_ref))
-
-
 # --------------------------------------------------- model-level wiring
 
 
@@ -232,24 +171,50 @@ def _window_setup(seed=0):
             sk, sv, active)
 
 
-@pytest.mark.parametrize("impl", ["pallas-decode_interpret",
-                                  "pallas-decode-fw_interpret"])
-def test_forward_decode_window_parity(impl):
-    """forward_decode_window with the fused kernel matches the xla path:
-    same hidden state AND same updated side buffers (the -fw variant's
-    epilogue write must equal the one-hot write it replaces)."""
+def test_forward_decode_window_matches_dense_decode():
+    """One ``window`` step (the kernel over pages + side window) against
+    one ``dense`` step (``forward_decode`` over the same context laid out
+    as a dense cache): the same hidden state for every live row, and the
+    step's fresh K/V in the side window where the dense cache has it."""
     from distributed_inference_engine_tpu.models.base import (
-        forward_decode_window)
+        forward_decode, forward_decode_window)
 
-    args = _window_setup()
-    x_ref, sk_ref, sv_ref = forward_decode_window(*args, attn_impl="xla")
-    x, sk, sv = forward_decode_window(*args, attn_impl=impl)
-    np.testing.assert_allclose(np.asarray(x), np.asarray(x_ref),
+    (spec, params, tokens, lengths, start, kp, vp, pt, sk, sv,
+     active) = _window_setup()
+    L, b, w = sk.shape[0], sk.shape[1], sk.shape[2]
+    p, mp = kp.shape[2], pt.shape[1]
+    hkv, dh = spec.n_kv_heads, spec.head_dim
+    # dense cache: each row's prefix pages, then its side entries at
+    # [start, start + w)
+    s_tot = mp * p + w
+    ck = jnp.zeros((L, b, s_tot, hkv, dh), jnp.float32)
+    cv = jnp.zeros_like(ck)
+    ck = ck.at[:, :, : mp * p].set(kp[:, pt].reshape(L, b, mp * p, hkv, dh))
+    cv = cv.at[:, :, : mp * p].set(vp[:, pt].reshape(L, b, mp * p, hkv, dh))
+    bi = jnp.arange(b)[:, None]
+    pos = start[:, None] + jnp.arange(w)[None, :]
+    ck = ck.at[:, bi, pos].set(sk)
+    cv = cv.at[:, bi, pos].set(sv)
+    x_ref, ck, cv = forward_decode(spec, params, tokens, lengths, ck, cv)
+
+    x, sk_new, sv_new = forward_decode_window(
+        spec, params, tokens, lengths, start, kp, vp, pt, sk, sv, active,
+        interpret=True)
+    live = np.asarray(active)
+    np.testing.assert_allclose(np.asarray(x)[live], np.asarray(x_ref)[live],
                                rtol=2e-4, atol=2e-4)
-    np.testing.assert_allclose(np.asarray(sk), np.asarray(sk_ref),
-                               rtol=1e-6, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(sv), np.asarray(sv_ref),
-                               rtol=1e-6, atol=1e-6)
+    col = np.asarray(lengths - start)
+    for i in np.flatnonzero(live):
+        np.testing.assert_allclose(
+            np.asarray(sk_new)[:, i, col[i]],
+            np.asarray(ck)[:, i, int(lengths[i])], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(sv_new)[:, i, col[i]],
+            np.asarray(cv)[:, i, int(lengths[i])], rtol=1e-5, atol=1e-5)
+    # a dead row writes nothing
+    dead = np.flatnonzero(~live)
+    np.testing.assert_array_equal(np.asarray(sk_new)[:, dead],
+                                  np.asarray(sk)[:, dead])
 
 
 @pytest.mark.slow
@@ -392,31 +357,33 @@ def _resolve_spec(**kw):
     return ModelSpec(**base)
 
 
-@pytest.mark.parametrize("impl,backend,spec_kw,mode,sharded,want", [
-    ("auto", "tpu", {}, "window", False, "pallas-decode"),
-    ("auto", "cpu", {}, "window", False, "xla"),
-    ("auto", "gpu", {}, "window", False, "xla"),
-    ("auto", "tpu", {"sliding_window": 64}, "window", False, "xla"),
-    ("auto", "tpu", {}, "inline", False, "xla"),
-    ("auto", "tpu", {}, "window", True, "xla"),
+@pytest.mark.parametrize("impl,backend,spec_kw,sharded,want", [
+    ("auto", "tpu", {}, False, ("window", "pallas-decode")),
+    ("auto", "cpu", {}, False, ("dense", "xla")),
+    ("auto", "gpu", {}, False, ("dense", "xla")),
+    ("auto", "tpu", {"sliding_window": 64}, False, ("inline", "xla")),
+    ("auto", "tpu", {}, True, ("dense", "xla")),
     # Hkv*Dh = 2 * 48 = 96 lanes: not whole 128-lane tiles
-    ("auto", "tpu", {"d_model": 192}, "window", False, "xla"),
-    ("xla", "tpu", {}, "window", False, "xla"),
-    ("pallas-decode", "cpu", {}, "window", False, "pallas-decode"),
-    ("pallas-decode_interpret", "cpu", {"sliding_window": 64}, "inline",
-     True, "pallas-decode_interpret"),
+    ("auto", "tpu", {"d_model": 192}, False, ("dense", "xla")),
+    ("xla", "tpu", {}, False, ("dense", "xla")),
+    ("pallas-decode", "cpu", {}, False, ("window", "pallas-decode")),
+    ("pallas-decode_interpret", "cpu", {}, False,
+     ("window", "pallas-decode_interpret")),
+    # a sliding window has one body, whatever the string asks for
+    ("pallas-decode_interpret", "cpu", {"sliding_window": 64}, True,
+     ("inline", "xla")),
 ])
-def test_auto_resolution_is_a_pure_function(impl, backend, spec_kw, mode,
-                                            sharded, want):
-    """``attention_impl="auto"`` resolves from (backend, spec, decode
-    mode, pool sharding) alone; explicit strings pass through."""
+def test_auto_resolution_is_a_pure_function(impl, backend, spec_kw, sharded,
+                                            want):
+    """The decode body and its attention resolve from (string, backend,
+    spec, pool sharding) alone: ``(body, attn_impl)``."""
     from distributed_inference_engine_tpu.engine.continuous import (
-        resolve_attention_impl)
+        resolve_decode_body)
 
     spec = _resolve_spec(**spec_kw)
     for _ in range(2):
-        assert resolve_attention_impl(impl, backend, spec, mode,
-                                      sharded=sharded) == want
+        assert resolve_decode_body(impl, backend, spec,
+                                   sharded=sharded) == want
 
 
 # ------------------------------------------- engine level, tier-1 sized

@@ -42,29 +42,6 @@ def test_continuous_fp8_pages_half_the_bytes():
             == ref.kv.get_stats()["hbm_bytes"] // 2)
 
 
-def test_fp8_pages_pallas_interpret_matches_xla():
-    import jax
-    import jax.numpy as jnp
-
-    from distributed_inference_engine_tpu.ops.paged_attention import (
-        paged_attention_pallas,
-        paged_attention_xla,
-    )
-
-    B, H, Hkv, Dh, N, P, MP = 2, 4, 4, 32, 8, 16, 4
-    rs = np.random.RandomState(0)
-    q = jnp.asarray(rs.randn(B, H, Dh), jnp.float32)
-    kp = jnp.asarray(rs.randn(N, P, Hkv * Dh), jnp.float8_e4m3fn)
-    vp = jnp.asarray(rs.randn(N, P, Hkv * Dh), jnp.float8_e4m3fn)
-    pt = jnp.asarray(rs.randint(0, N, (B, MP)), jnp.int32)
-    lengths = jnp.asarray([20, 55], jnp.int32)
-    ref = paged_attention_xla(q, kp, vp, pt, lengths, n_kv_heads=Hkv)
-    out = paged_attention_pallas(q, kp, vp, pt, lengths, n_kv_heads=Hkv,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-2, atol=2e-2)
-
-
 def test_disagg_handoff_fp8_roundtrip():
     from distributed_inference_engine_tpu.engine.disagg import (
         PrefillEngine,

@@ -154,8 +154,9 @@ def test_existing_families_keep_their_specs():
 
 
 @pytest.mark.parametrize("kw", [
-    {"kv_offload": True}, {"prefill_chunk": 32}, {"spec_async": True},
-    {"attention_impl": "pallas-decode"}])
+    {"kv_offload": True}, {"prefill_chunk": 32},
+    {"attention_impl": "pallas-decode"},
+    {"attention_impl": "pallas-decode_interpret"}])
 def test_engine_options_a_recurrent_spec_cannot_honour_raise(kw):
     with pytest.raises(ValueError, match="hybrid"):
         tiny_engine(**kw)

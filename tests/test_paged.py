@@ -1,6 +1,6 @@
-"""Paged KV cache + paged attention: allocator accounting, XLA/Pallas kernel
-equivalence (interpret mode on CPU — SURVEY.md §4's multi-device-without-
-hardware strategy applied to kernels), and paged-vs-contiguous decode parity.
+"""Paged KV cache + paged attention (the XLA path; the in-place kernel is
+tests/test_flash_decode.py): allocator accounting, masking of stale pool
+data, flash-stats merging, and paged-vs-contiguous decode parity.
 """
 
 import jax
@@ -18,7 +18,6 @@ from distributed_inference_engine_tpu.models.base import (
     write_prefill_pages,
 )
 from distributed_inference_engine_tpu.ops.paged_attention import (
-    paged_attention_pallas,
     paged_attention_xla,
 )
 
@@ -95,7 +94,7 @@ def test_misaligned_fused_dim_rejected():
         PagedKVCache(bad, max_slots=1, page_size=8, num_pages=2)
 
 
-# ----------------------------------------------------- kernel equivalence
+# ------------------------------------------------------- paged attention
 
 
 def _random_paged_case(seed, b=3, h=4, n_kv=2, dh=64, page_size=16,
@@ -111,26 +110,6 @@ def _random_paged_case(seed, b=3, h=4, n_kv=2, dh=64, page_size=16,
     lengths = jnp.asarray(rs.randint(1, page_size * max_pages + 1, size=b),
                           dtype=jnp.int32)
     return q, k_pages, v_pages, table, lengths
-
-
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_pallas_kernel_matches_xla(seed):
-    q, kp, vp, table, lengths = _random_paged_case(seed)
-    ref = paged_attention_xla(q, kp, vp, table, lengths, n_kv_heads=2)
-    out = paged_attention_pallas(q, kp, vp, table, lengths, n_kv_heads=2,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_pallas_kernel_partial_last_page():
-    q, kp, vp, table, _ = _random_paged_case(7)
-    lengths = jnp.asarray([1, 17, 64], dtype=jnp.int32)   # 1 tok / cross-page / full
-    ref = paged_attention_xla(q, kp, vp, table, lengths, n_kv_heads=2)
-    out = paged_attention_pallas(q, kp, vp, table, lengths, n_kv_heads=2,
-                                 interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
 
 
 def test_xla_path_masks_stale_pool_data():
@@ -177,7 +156,7 @@ def test_paged_decode_matches_contiguous():
     tok = jnp.asarray(rs.randint(0, spec.vocab_size, size=B), jnp.int32)
     h_ref, _, _ = forward_decode(spec, params, tok, seq_lens, ck, cv)
     h_paged, kp2, vp2 = forward_decode_paged(
-        spec, params, tok, seq_lens, kp, vp, kv.page_table, attn_impl="xla"
+        spec, params, tok, seq_lens, kp, vp, kv.page_table
     )
     np.testing.assert_allclose(np.asarray(h_paged), np.asarray(h_ref),
                                rtol=2e-4, atol=2e-4)
@@ -189,7 +168,6 @@ def test_paged_decode_matches_contiguous():
     h_ref2, _, _ = forward_decode(spec, params, tok2, seq_lens + 1, ck2, cv2)
     h_paged2, _, _ = forward_decode_paged(
         spec, params, tok2, seq_lens + 1, kp2, vp2, kv.page_table,
-        attn_impl="xla",
     )
     np.testing.assert_allclose(np.asarray(h_paged2), np.asarray(h_ref2),
                                rtol=2e-4, atol=2e-4)
@@ -266,35 +244,6 @@ def test_stats_merge_matches_single_softmax():
     probs = jax.nn.softmax(scores, axis=-1)
     ref = jnp.einsum("bkgs,bskd->bkgd", probs, v_cat).reshape(b, h, dh)
     np.testing.assert_allclose(np.asarray(merged), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_pallas_stats_and_stacked_layer_match_xla():
-    """Kernel feature parity in interpret mode: with_stats returns the
-    same (m, l) the XLA path computes, and stacked-pool layer indexing
-    reads layer l's pages exactly."""
-    q, kp, vp, table, lengths = _random_paged_case(11)
-    ref_out, ref_m, ref_l = paged_attention_xla(
-        q, kp, vp, table, lengths, n_kv_heads=2, with_stats=True)
-    out, m, l = paged_attention_pallas(
-        q, kp, vp, table, lengths, n_kv_heads=2, interpret=True,
-        with_stats=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
-                               rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(m), np.asarray(ref_m), rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(l), np.asarray(ref_l), rtol=1e-5)
-
-    # stacked pools: layer 1 of a 3-layer stack
-    L, n = 3, kp.shape[0]
-    rs = np.random.RandomState(2)
-    big_k = jnp.asarray(rs.randn(L * n, *kp.shape[1:]), kp.dtype)
-    big_v = jnp.asarray(rs.randn(L * n, *vp.shape[1:]), vp.dtype)
-    ref2 = paged_attention_xla(q, big_k[n:2 * n], big_v[n:2 * n], table,
-                               lengths, n_kv_heads=2)
-    out2 = paged_attention_pallas(
-        q, big_k, big_v, table, lengths, n_kv_heads=2, interpret=True,
-        layer=jnp.asarray(1), n_pages_per_layer=n)
-    np.testing.assert_allclose(np.asarray(out2), np.asarray(ref2),
                                rtol=2e-5, atol=2e-5)
 
 

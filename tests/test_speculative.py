@@ -6,11 +6,15 @@ change latency, never content. Acceptance math is validated with
 draft == target (everything must be accepted)."""
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from distributed_inference_engine_tpu.config import EngineConfig, ModelConfig
 from distributed_inference_engine_tpu.engine.engine import Engine
+from distributed_inference_engine_tpu.engine.spec_accept import (
+    rejection_accept,
+)
 from distributed_inference_engine_tpu.engine.speculative import (
     SpeculativeEngine,
 )
@@ -264,3 +268,88 @@ def test_scale_top_blocks_quantized_scales_only():
     s1 = np.asarray(tp["blocks"]["wo"].s)
     np.testing.assert_allclose(s1[:1], s0[:1])
     np.testing.assert_allclose(s1[1:], s0[1:] * 0.25)
+
+
+# ---------------------------------------------------------------------------
+# acceptance math (engine/spec_accept.py): bit-parity against a frozen r5
+# reference
+# ---------------------------------------------------------------------------
+
+
+def _frozen_r5_accept(p, q, drafts, greedy, key_resid, key_bonus,
+                      valid=None):
+    """Independent numpy reimplementation of the r5 acceptance block
+    (frozen at the refactor): loop form, same key usage and op order as
+    the pre-refactor ``_round_core``. Any drift in the shared module
+    shows up as a bit mismatch here."""
+    b, k = drafts.shape
+    u = np.asarray(jax.random.uniform(key_resid, drafts.shape))
+    accept = np.zeros((b, k), bool)
+    for i in range(b):
+        for j in range(k):
+            d = int(drafts[i, j])
+            if greedy[i]:
+                accept[i, j] = int(np.argmax(p[i, j])) == d
+            else:
+                accept[i, j] = u[i, j] * q[i, j, d] < p[i, j, d]
+            if valid is not None and not valid[i, j]:
+                accept[i, j] = False
+    n_acc = np.zeros(b, np.int32)
+    for i in range(b):
+        while n_acc[i] < k and accept[i, n_acc[i]]:
+            n_acc[i] += 1
+    final_dist = np.zeros((b, p.shape[-1]))
+    for i in range(b):
+        if n_acc[i] == k:
+            final_dist[i] = p[i, k]
+        else:
+            pos = min(int(n_acc[i]), k - 1)
+            resid = np.maximum(p[i, pos] - q[i, pos], 0.0)
+            if resid.sum() <= 1e-9:
+                resid = p[i, pos]
+            final_dist[i] = resid / resid.sum()
+    f_samp = np.asarray(jax.random.categorical(
+        key_bonus, jnp.log(jnp.maximum(jnp.asarray(final_dist), 1e-30)),
+        axis=-1))
+    final = np.where(greedy, final_dist.argmax(-1), f_samp)
+    return n_acc, final.astype(np.int32), accept
+
+
+@pytest.mark.parametrize("greedy_all", [True, False])
+@pytest.mark.parametrize("masked", [False, True])
+def test_rejection_accept_bit_parity_vs_frozen_r5(greedy_all, masked):
+    b, k, v = 5, 4, 32
+    rng = np.random.RandomState(7 + masked)
+    p = rng.dirichlet(np.ones(v) * 0.3, size=(b, k + 1))
+    q = rng.dirichlet(np.ones(v) * 0.3, size=(b, k))
+    drafts = rng.randint(0, v, size=(b, k)).astype(np.int32)
+    greedy = np.full(b, greedy_all)
+    valid = (rng.rand(b, k) < 0.6) if masked else None
+    kr, kb = jax.random.split(jax.random.key(3))
+    n_ref, f_ref, a_ref = _frozen_r5_accept(p, q, drafts, greedy, kr, kb,
+                                            valid)
+    n, f, a = rejection_accept(
+        jnp.asarray(p, jnp.float32), jnp.asarray(q, jnp.float32),
+        jnp.asarray(drafts), jnp.asarray(greedy), kr, kb,
+        valid=None if valid is None else jnp.asarray(valid))
+    np.testing.assert_array_equal(np.asarray(n), n_ref)
+    np.testing.assert_array_equal(np.asarray(f), f_ref)
+    np.testing.assert_array_equal(np.asarray(a), a_ref)
+
+
+def test_plain_rows_reduce_to_plain_decode():
+    """A verify row with zero draft columns (all-False mask + zero
+    q_probs) must sample exactly the target distribution at position 0 —
+    that is what lets plain rows ride the verify program unchanged."""
+    b, k, v = 3, 4, 16
+    rng = np.random.RandomState(11)
+    p = rng.dirichlet(np.ones(v), size=(b, k + 1)).astype(np.float32)
+    q = np.zeros((b, k, v), np.float32)
+    drafts = np.zeros((b, k), np.int32)
+    kr, kb = jax.random.split(jax.random.key(5))
+    n, f, _ = rejection_accept(
+        jnp.asarray(p), jnp.asarray(q), jnp.asarray(drafts),
+        jnp.asarray(np.ones(b, bool)), kr, kb,
+        valid=jnp.zeros((b, k), bool))
+    assert np.asarray(n).tolist() == [0] * b
+    np.testing.assert_array_equal(np.asarray(f), p[:, 0].argmax(-1))
