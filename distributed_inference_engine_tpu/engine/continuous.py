@@ -183,18 +183,22 @@ class _PrefillProgress:
 
 class _ChunkEntry:
     """One dispatched decode chunk's packed output in flight to the
-    host, plus everything needed to process it later. Sub-chunk streaming
-    (ISSUE 13) splits chunk processing in two: ``_harvest_chunk`` (the
-    token half — blocking read, token/logprob appends, stop scan, stream
-    emit) and ``_process_packed`` (the control half — pause/finish/revive
-    judgments). ``defer_sync`` dispatches push the entry onto the
-    engine's stream ring and kick an async device→host copy; the pump's
+    host, plus everything needed to process it later. Chunk processing
+    has three parts: ``_harvest_chunk`` (the token half — blocking read,
+    length mirror, token/logprob appends, stop scan), ``_judge_packed``
+    (the control half — pause/finish/revive judgments; a slot that
+    finishes streams its own last frames from ``_finish``), and the
+    streaming of the slots that live on, which decides nothing and is
+    CARRIED under the next dispatch (``_carry_emit`` / ``_flush_emit``).
+    ``defer_sync`` dispatches push the entry onto the engine's stream
+    ring and kick an async device→host copy; the pump's
     ``poll_stream()`` harvests the token half early when the copy lands
     (inside the measured host bubble), and the deferred flush runs the
     control half either way — harvest is idempotent via ``harvested``."""
 
     __slots__ = ("packed", "n_steps", "snapshot", "t0", "caps",
-                 "fresh_firsts", "host", "harvested", "progressed")
+                 "fresh_firsts", "host", "harvested", "progressed",
+                 "streamed")
 
     def __init__(self, packed, n_steps: int, snapshot: Dict[int, _Slot],
                  t0: float, caps: Optional[List[int]],
@@ -210,6 +214,9 @@ class _ChunkEntry:
         # slot -> progressed flag stashed at harvest time, so control can
         # re-judge without re-deriving it from a possibly-mutated _Slot
         self.progressed: Dict[int, bool] = {}
+        # the harvest met a slot with a stream callback: the chunk counts
+        # once, as carried or as flushed (get_metrics)
+        self.streamed = False
 
     def ready(self) -> bool:
         """True when the packed buffer can be read without blocking.
@@ -420,6 +427,17 @@ class ContinuousEngine:
         # chunks, oldest first. poll_stream() drains ready heads so
         # streamed tokens reach consumers up to one chunk early.
         self._ring: Deque[_ChunkEntry] = collections.deque()
+        # the slots of the last processed chunk that live on and whose
+        # fresh tokens are not streamed yet: emitted right after the NEXT
+        # dispatch, under the running program (_flush_emit). None =
+        # nothing carried; _emit_stream is cumulative per _Slot, so a
+        # flush at any point delivers each token once, in order
+        self._carried: Optional[List[_Slot]] = None
+        self._emit_carried_chunks = 0    # streamed under a later dispatch
+        self._emit_flushed_chunks = 0    # streamed with nothing to hide it
+        # the open engine.decode.dispatch bracket and its closing args:
+        # closed at the end of the step's blocking read (_close_dispatch)
+        self._open_dispatch: Optional[Tuple[HostSpan, Dict[str, Any]]] = None
         self._ctx_page_buckets = _pow2_buckets(self.kv.max_pages_per_seq)
         self._prefix_hit_admissions = 0
         # chunked prefill: chunk must be page-aligned so every suffix chunk
@@ -932,9 +950,12 @@ class ContinuousEngine:
         # the host-side gap BETWEEN consecutive dispatch brackets, so an
         # hbm_util regression is attributable at a glance — kernel-side
         # (dispatch grew) or scheduler-side (gap grew). Counted even with
-        # the timeline ring disabled. Sync decode brackets include the
-        # blocking packed read, i.e. ≈ device-busy wall time; defer_sync
-        # brackets cover dispatch only, so its gap share reads higher —
+        # the timeline ring disabled. A decode bracket runs from the
+        # dispatch call to the end of the step's blocking packed read,
+        # i.e. ≈ device-busy wall time: everything the host does between
+        # that read and the next dispatch (appends, judgments, admission,
+        # the capacity loop) is gap. defer_sync brackets end at the read
+        # of the PREVIOUS chunk, so its gap share reads differently —
         # compare like with like.
         self._dispatch_s = 0.0
         self._host_gap_s = 0.0
@@ -1396,6 +1417,7 @@ class ContinuousEngine:
                     prompt, slot, n_cached, req, k0)
                 t_admit = time.perf_counter()
                 self.kv.register_prefix(slot, prompt)
+                self._flush_emit(True)       # streams under the prefill
                 # graftlint: ok[host-sync-hot-path] sync cached-suffix admission needs its first token now; [2,1] elements, once per admission
                 fp = np.asarray(first_dev)           # [2, 1]: token; lp bits
                 first = int(fp[0, 0])
@@ -1523,6 +1545,9 @@ class ContinuousEngine:
             self._deferred_admissions += len(rows)
             self._install_device_first(rows, cols, first_dev)
             return
+        # the sync path blocks on the prefill: what the last chunk left
+        # carried streams under it, not after it
+        self._flush_emit(True)
         # graftlint: ok[host-sync-hot-path] ONE read per admission round, amortized over the whole batch (deferred path returns above)
         fp = np.asarray(first_dev)                 # [2, bb]: tokens; lp bits
         firsts = fp[0]
@@ -1689,6 +1714,7 @@ class ContinuousEngine:
             # chunks' samples are discarded — their logits see a truncated
             # prompt)
             if fp is None:
+                self._flush_emit(True)   # streams under the suffix program
                 # graftlint: ok[host-sync-hot-path] guarded by fp is None: ONE read per finished prefill group, not per row
                 fp = np.asarray(first_dev)        # [2, bb]: token; lp bits
             first = int(fp[0, i])
@@ -1774,6 +1800,11 @@ class ContinuousEngine:
             # token (e.g. capacity-retire on the very next step): rescue
             # it from the batched snapshot — no per-slot round trip
             self._rescue_first(state, slot)
+            state.stop_cut = find_stop_cut(state.tokens, req)
+        # a finishing slot streams what it still holds at once, ahead of
+        # its result: frames in token order, the final envelope last,
+        # whether or not an earlier chunk's emit is still carried
+        self._emit_stream(state)
         toks, stopped = trim_at_stops(state.tokens, req)
         if stopped:
             reason = "stop"
@@ -2055,14 +2086,30 @@ class ContinuousEngine:
                 self._host_gap_s += gap
         self._last_dispatch_end = now
 
+    def _close_dispatch(self) -> None:
+        """Close the step's ``engine.decode.dispatch`` bracket, once: at
+        the end of its blocking packed read (or of the step, when nothing
+        was read), so ``host_gap_s_total`` counts all the host's time from
+        there to the next dispatch."""
+        if self._open_dispatch is not None:
+            (sp, args), self._open_dispatch = self._open_dispatch, None
+            self._tl_record(sp, **args)
+
     @hot_path
     def step(self) -> int:
         """One engine iteration: admit, advance one prefill chunk, then one
         decode chunk. Returns live + mid-prefill slots after the
-        iteration. With ``defer_sync``, chunk k's packed output is read
-        after dispatching chunk k+1 (the round trip overlaps device
-        compute); host bookkeeping — finishes, host-side stops, streaming
-        — runs one chunk behind the device."""
+        iteration. The order around a decode chunk: dispatch chunk k+1;
+        stream chunk k's tokens for the slots that lived on (carried
+        from the last iteration, now under the running program); block
+        on k+1's packed read; append its tokens and scan for stops; judge
+        (a finishing slot streams its own frames at once); return, with
+        k+1's streaming carried in turn. An iteration that dispatches
+        nothing streams what is carried at once (``flush_stream``). With
+        ``defer_sync``, chunk k's packed output is read after dispatching
+        chunk k+1 (the round trip overlaps device compute); host
+        bookkeeping — finishes, host-side stops, streaming — runs one
+        chunk behind the device."""
         # one span over the whole iteration: the admission scan and the
         # capacity loop run before any bracket below opens
         with self._span("engine.step"):
@@ -2081,6 +2128,7 @@ class ContinuousEngine:
             # across an idle period
             self._pending = None
             self._ring.clear()
+            self._flush_emit(False)
             return len(self._prefilling) + len(self._swapped)
         self._steps += 1
         self._occupancy_sum += len(self._slots)   # batch occupancy metric
@@ -2123,6 +2171,9 @@ class ContinuousEngine:
                 cur = int(lengths_np[slot])
                 cap_tok = self.kv.ensure_capacity(slot, cur + ahead)
             if cap_tok <= cur:
+                # retiring a slot (re-queue, swap, finish) hands its stream
+                # on: what is carried goes out first
+                self._flush_emit(False)
                 if self._recurrent and self._preempt_recompute(slot):
                     retired.append(slot)       # re-queued, no finish
                 elif self._try_swap_out(slot):
@@ -2151,6 +2202,7 @@ class ContinuousEngine:
                 self._stream_clamped_chunks += 1
 
         if not self._slots or n_steps <= 0:
+            self._flush_emit(False)
             return (len(self._slots) + len(self._prefilling)
                     + len(self._swapped))
 
@@ -2194,11 +2246,17 @@ class ContinuousEngine:
         # the chunk is in flight: overlap serving-side batch formation
         # with the device step (ISSUE 5c) before the blocking read below
         self._run_overlap_hook()
+        # and stream the LAST chunk's tokens (the slots that lived on)
+        # under this one, before blocking on it
+        self._flush_emit(True)
 
         # snapshot at dispatch: packed columns belong to THESE _Slot
         # objects — a slot freed and re-admitted before this chunk is
         # processed must not have the old chunk's column applied to it
         snapshot = dict(self._slots)
+        self._open_dispatch = (sp, {"program": ("decode", n_steps, mpb),
+                                    "rows": len(snapshot),
+                                    "n_steps": n_steps})
         if self._defer:
             entry = _ChunkEntry(packed, n_steps, snapshot, t0, cap_list,
                                 False)
@@ -2221,8 +2279,7 @@ class ContinuousEngine:
         else:
             self._process_packed(_ChunkEntry(packed, n_steps, snapshot,
                                              t0, cap_list, True))
-        self._tl_record(sp, program=("decode", n_steps, mpb),
-                        rows=len(snapshot), n_steps=n_steps)
+        self._close_dispatch()      # nothing was read (defer's first chunk)
         return (len(self._slots) + len(self._prefilling)
                 + len(self._swapped))
 
@@ -2249,20 +2306,26 @@ class ContinuousEngine:
                 if not entry.ready():
                     break
                 self._ring_ready_polls += 1
-                frames += self._harvest_chunk(entry)
+                self._harvest_chunk(entry)
+                for slot, state in entry.snapshot.items():
+                    if self._slots.get(slot) is state:
+                        frames += self._emit_stream(state)
         return frames
 
-    def _harvest_chunk(self, entry: _ChunkEntry) -> int:
+    def _harvest_chunk(self, entry: _ChunkEntry) -> None:
         """TOKEN half of chunk processing: the blocking host read (a
-        no-op wait when the ring's async copy already landed), token and
-        logprob appends, the length-mirror refresh, the incremental stop
-        scan, and the streaming emit. Idempotent — guarded by
-        ``entry.harvested`` — so the ring poll and the deferred flush
-        compose. Snapshot-identity rules match ``_process_packed``:
-        columns apply only to the exact ``_Slot`` objects live at
-        dispatch. Returns streamed frames delivered."""
+        no-op wait when the ring's async copy already landed), the
+        length-mirror refresh, token and logprob appends and the
+        incremental stop scan — what the judgments and the next dispatch
+        depend on. Streaming is NOT here: ``_judge_packed`` /
+        ``_carry_emit`` follow. Only a slot's FIRST frame goes out at
+        once (one a request: ``first_token_at`` stays the delivery
+        time). Idempotent — guarded by ``entry.harvested`` — so the ring
+        poll and the deferred flush compose. Snapshot-identity rules
+        match ``_process_packed``: columns apply only to the exact
+        ``_Slot`` objects live at dispatch."""
         if entry.harvested:
-            return 0
+            return
         entry.harvested = True
         try:                      # pop self from the ring, wherever it is
             self._ring.remove(entry)
@@ -2274,10 +2337,11 @@ class ContinuousEngine:
         # graftlint: ok[host-sync-hot-path] THE designed sync point: ONE packed read per decode chunk carries tokens+lps+active+lengths+firsts
         packed_np = np.asarray(entry.packed)   # ONE blocking read per chunk
         wait.close()
+        self._close_dispatch()
         entry.host = packed_np
         toks_np = packed_np[:n_steps]                    # [n_steps, max_slots]
         lps_np = packed_np[n_steps:2 * n_steps].view(np.float32)
-        lengths_row = packed_np[2 * n_steps + 1].astype(np.int32)
+        lengths = packed_np[2 * n_steps + 1].tolist()
         firsts_tok = packed_np[2 * n_steps + 2]          # deferred admissions
         firsts_lp = packed_np[2 * n_steps + 3].view(np.float32)
         self._decode_steps += n_steps
@@ -2301,14 +2365,18 @@ class ContinuousEngine:
         self.chunk_stats.add(time.perf_counter()
                              - (t_read if self._defer else entry.t0))
 
-        frames = 0
-        emit = self._span("engine.harvest.emit")  # bookkeeping + stream cbs
+        book = self._span("engine.harvest.book")  # mirror, appends, stops
+        # a row emits from step 0 until it goes inactive and never again
+        # in the chunk (_advance), so its tokens are a PREFIX of its
+        # column: one count a slot, the columns as lists in one call each
+        counts = (toks_np >= 0).sum(axis=0).tolist()
+        tok_cols = toks_np.T.tolist()
+        lp_cols = lps_np.T.tolist()
         for slot, state in entry.snapshot.items():
             if self._slots.get(slot) is not state:
                 continue                 # finished earlier (or slot reused)
-            self._lengths_host[slot] = lengths_row[slot]
-            col = toks_np[:, slot]
-            lcol = lps_np[:, slot]
+            self._lengths_host[slot] = lengths[slot]
+            n = counts[slot]
             # no progress == the slot was device-INACTIVE when this chunk
             # was dispatched (an active slot always emits >=1 token per
             # chunk: the capacity loop guarantees cap > length at
@@ -2318,8 +2386,7 @@ class ContinuousEngine:
             # row is from AFTER the pool grew, so the pause test would
             # misread the pause as a finished "length"). Stashed on the
             # entry: control may run after further slot mutation.
-            entry.progressed[slot] = bool(state.first_pending
-                                          or (col >= 0).any())
+            entry.progressed[slot] = bool(state.first_pending or n)
             prev = len(state.tokens)           # first index not yet stop-checked
             if state.first_pending:
                 # harvest the deferred first token (prev stays 0: the stop
@@ -2330,38 +2397,84 @@ class ContinuousEngine:
                 state.logprobs.append(float(firsts_lp[slot]))
                 state.first_token_at = time.perf_counter()
                 self.ttft_stats.add(state.first_token_at - state.submitted_at)
-            for si in range(col.shape[0]):
-                if col[si] >= 0:
-                    state.tokens.append(int(col[si]))
-                    state.logprobs.append(float(lcol[si]))
+            state.tokens += tok_cols[slot][:n]
+            state.logprobs += lp_cols[slot][:n]
             state.produced = len(state.tokens)
             req = state.request
             has_stops = (req.eos_id >= 0 or req.stop_ids
                          or req.stop_sequences)
             if has_stops and state.stop_cut < 0:
                 # scan only the new window: O(total) stop detection across
-                # a generation, shared with the streaming emit below
+                # a generation, shared with the streaming emit
                 state.stop_cut = find_stop_cut(state.tokens, req, start=prev)
-            frames += self._emit_stream(state)
-        emit.close()
-        return frames
+            if state.on_tokens is not None:
+                entry.streamed = True
+                if not state.streamed:
+                    self._emit_stream(state)     # the request's first frame
+        book.close()
 
     def _process_packed(self, entry: _ChunkEntry) -> None:
-        """CONTROL half of chunk processing: finish retired slots, retire
-        host-side stops, revive capacity-paused slots. Harvests the token
-        half first when the ring poll has not already done so (the
-        common non-streaming case — one call does both halves, exactly
-        the pre-ring behavior). ``entry.caps`` is the per-slot
-        token-capacity array the chunk was dispatched with — needed to
-        tell a PAUSED slot (device stopped at the chunk's capacity
-        grant) from a finished one. ``entry.fresh_firsts`` marks SYNC
-        call sites, where no install can have landed between dispatch
-        and the read — the packed firsts rows are then current and
-        refresh the host cache for free (deferred processing runs a
+        """Chunk processing after the dispatch: the token half (when the
+        ring poll has not already run it), then the CONTROL half — finish
+        retired slots, retire host-side stops, revive capacity-paused
+        slots — then the streaming of the slots that live on, carried to
+        the next dispatch (``_carry_emit``). ``entry.caps`` is the
+        per-slot token-capacity array the chunk was dispatched with —
+        needed to tell a PAUSED slot (device stopped at the chunk's
+        capacity grant) from a finished one. ``entry.fresh_firsts`` marks
+        SYNC call sites, where no install can have landed between
+        dispatch and the read — the packed firsts rows are then current
+        and refresh the host cache for free (deferred processing runs a
         chunk behind admissions, so its rows may be stale)."""
+        self._harvest_chunk(entry)      # ends the dispatch bracket
         with self._span("engine.process_packed"):
-            self._harvest_chunk(entry)
             self._judge_packed(entry)
+            self._carry_emit(entry)
+
+    def _carry_emit(self, entry: _ChunkEntry) -> None:
+        """Hand the entry's streaming to the next dispatch: the slots of
+        its snapshot that live on, have a stream callback and hold tokens
+        not yet streamed. With none of them the chunk's streaming already
+        happened, unhidden (first frames, finishing slots): it counts as
+        flushed. Under ``defer_sync`` the next chunk is already on the
+        device, so the emit runs here and now, under it."""
+        if not entry.streamed:
+            return
+        carried = [st for slot, st in entry.snapshot.items()
+                   if st.on_tokens is not None
+                   and self._slots.get(slot) is st
+                   and len(st.tokens) > st.streamed]
+        if not carried:
+            self._emit_flushed_chunks += 1
+            return
+        self._carried = carried
+        if self._defer:
+            self._flush_emit(True)
+
+    def _flush_emit(self, under_dispatch: bool) -> None:
+        """Stream what is carried (nothing, for engines nobody streams
+        from). ``under_dispatch``: a program dispatched since the carry
+        is running or queued on the device, so the callbacks, and the
+        event-loop thread they wake, run in its shadow."""
+        carried = self._carried
+        if carried is None:
+            return
+        self._carried = None
+        if under_dispatch:
+            self._emit_carried_chunks += 1
+        else:
+            self._emit_flushed_chunks += 1
+        with self._span("engine.emit.carried" if under_dispatch
+                        else "engine.emit.flushed", slots=len(carried)):
+            for state in carried:
+                self._emit_stream(state)
+
+    def flush_stream(self) -> None:
+        """Stream every token the engine holds back for its next dispatch,
+        now. For callers that stop driving ``step()`` with slots live (the
+        pump on shutdown); ``step()`` itself never returns holding tokens
+        past an iteration that dispatched nothing."""
+        self._flush_emit(False)
 
     def _judge_packed(self, entry: _ChunkEntry) -> None:
         """The judgments of ``_process_packed``, on a harvested entry."""
@@ -2485,6 +2598,8 @@ class ContinuousEngine:
              + len(self._swapped))
         self._pending = None            # drop an unprocessed deferred chunk
         self._ring.clear()              # and its stream-ring entry
+        self._flush_emit(False)         # tokens already read are delivered
+        self._open_dispatch = None      # the failed step's bracket
         self._waiting.clear()
         self._waiting_prefilled.clear()
         self._resumed.clear()           # nothing is left to resume
@@ -2662,6 +2777,12 @@ class ContinuousEngine:
             "stream_ring_ready_polls": self._ring_ready_polls,
             "stream_ring_depth": self._ring_high_water,
             "stream_clamped_chunks": self._stream_clamped_chunks,
+            # decode chunks with a streamed slot, by where their tokens'
+            # callbacks ran: under a later dispatch (the carried emit),
+            # or with no program to hide them (first frames and finishing
+            # slots only, an idle engine, abort, shutdown)
+            "emit_carried_chunks": self._emit_carried_chunks,
+            "emit_flushed_chunks": self._emit_flushed_chunks,
             "firsts_fetches": self._firsts_fetches,
             "ttft": self.ttft_stats.snapshot(),
             # submit -> slot held and prefill dispatched

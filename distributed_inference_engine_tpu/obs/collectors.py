@@ -73,7 +73,7 @@ ENGINE_TABLE = [
     ("dispatch_s_total", "engine_dispatch_seconds", "c",
      "Seconds inside device dispatch brackets (host-gap split)"),
     ("host_gap_s_total", "engine_host_gap_seconds", "c",
-     "Host seconds between consecutive dispatch brackets"),
+     "Host seconds from the end of one blocking read to the next dispatch"),
     ("host_bubble_frac", "engine_host_bubble_fraction", "g",
      "Host gap share of dispatch+gap wall (roofline split)"),
     ("speculate_k", "engine_spec_k", "g", "Draft tokens proposed per round"),
@@ -91,6 +91,10 @@ ENGINE_TABLE = [
      "High-water depth of the device->host token ring"),
     ("stream_clamped_chunks", "engine_stream_clamped_chunks", "c",
      "Decode chunks shortened by the adaptive streaming clamp"),
+    ("emit_carried_chunks", "engine_emit_carried_chunks", "c",
+     "Decode chunks whose tokens were streamed under a later dispatch"),
+    ("emit_flushed_chunks", "engine_emit_flushed_chunks", "c",
+     "Decode chunks whose tokens were streamed with no program to hide it"),
     ("firsts_fetches", "engine_firsts_fetches", "c",
      "Whole-buffer deferred-firsts readbacks (one per invalidation)"),
     ("ttft", "engine_ttft_seconds", "h",

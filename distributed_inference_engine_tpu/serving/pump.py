@@ -246,6 +246,11 @@ class EnginePump:
                 with self._span("pump.idle_wait"):
                     self._wake.wait(timeout=self.idle_wait_s)
                 self._wake.clear()
+        # tokens the engine read and holds for its next dispatch reach
+        # their streams before the futures fail: no frame is lost
+        flush = getattr(self.engine, "flush_stream", None)
+        if flush is not None:
+            flush()             # a failing callback is the engine's to log
         # fail anything still in flight so no caller hangs on shutdown
         self._fail_all(RuntimeError("engine pump shut down"))
         logger.info("engine pump stopped")

@@ -232,6 +232,50 @@ def test_defer_sync_matches_synchronous_output():
     assert run(sync2) == run(defer)
 
 
+def test_streamed_run_matches_unstreamed_output():
+    """Streaming decides nothing: with every request streamed (a chunk's
+    tokens go out under the NEXT dispatch) the outputs are token-for-token
+    the unstreamed engine's, including a mid-flight admission and a
+    host-side stop sequence, and each stream splices to its result."""
+    rs = np.random.RandomState(7)
+    cfg = lambda **kw: _cfg(num_pages=32, **kw)
+    plain = ContinuousEngine(SPEC, config=cfg(), seed=0)
+    streamed = ContinuousEngine(SPEC, params=plain.params, config=cfg(),
+                                seed=0)
+    reqs = _reqs(rs, 3, max_new=14)
+    reqs[1].stop_sequences = [[int(x)] for x in
+                              plain.generate([_reqs(rs, 1)[0]])[0].tokens[:1]]
+    plain2 = ContinuousEngine(SPEC, params=plain.params, config=cfg(), seed=0)
+
+    def run(eng, stream):
+        frames = {}
+
+        def submit(r):
+            frames[r.request_id] = []
+            return eng.submit(r, on_tokens=(
+                frames[r.request_id].append if stream else None))
+
+        ids = [submit(GenerationRequest(
+            prompt=r.prompt, max_new_tokens=r.max_new_tokens,
+            stop_sequences=r.stop_sequences, request_id=r.request_id))
+            for r in reqs[:2]]
+        eng.step()                              # mid-flight admission below
+        ids.append(submit(GenerationRequest(
+            prompt=reqs[2].prompt, max_new_tokens=10, request_id="late")))
+        out = {r.request_id: (r.tokens, r.finish_reason)
+               for r in eng.run_until_idle()}
+        if stream:
+            for i in ids:
+                assert [t for f in frames[i] for t in f] == out[i][0]
+        return {i: out[i] for i in ids}
+
+    assert run(plain2, False) == run(streamed, True)
+    m = streamed.get_metrics()
+    assert m["emit_carried_chunks"] >= 1
+    assert (m["emit_carried_chunks"] + m["emit_flushed_chunks"]
+            == m["decode_chunks"])
+
+
 def test_defer_sync_requires_fully_backed_pool():
     import pytest
 

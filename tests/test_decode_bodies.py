@@ -167,3 +167,32 @@ def test_body_quantized_trees(trees, bits, body):
            for r in eng.generate(_requests(stops))}
     assert got == want
     _check_body(eng, body)
+
+
+@pytest.mark.parametrize("family,body", [
+    ("llama", "dense"), ("llama", "window"), ("moe", "dense"),
+    ("swa-short", "inline")])
+def test_body_streamed_matches_static_engine(trees, family, body):
+    """Every request streamed: a chunk's tokens go to the callbacks under
+    the NEXT chunk's dispatch (a finishing slot's at once), on every body.
+    Tokens and reasons stay the static engine's, logprobs those of the
+    same engine unstreamed; each stream splices to its result, and every
+    decode chunk is counted once, as carried or as flushed."""
+    spec, params, want, stops = trees(family, "model")
+    plain = {r.request_id: r for r in _serve(
+        spec, params, body, _KV["model"]).generate(_requests(stops))}
+    eng = _serve(spec, params, body, _KV["model"])
+    frames = {}
+    for r in _requests(stops):
+        frames[r.request_id] = []
+        eng.submit(r, on_tokens=frames[r.request_id].append)
+    got = {r.request_id: r for r in eng.run_until_idle()}
+    assert {i: (r.tokens, r.finish_reason) for i, r in got.items()} == want
+    for rid, res in got.items():
+        assert res.logprobs == plain[rid].logprobs
+        assert [t for f in frames[rid] for t in f] == res.tokens
+    _check_body(eng, body)
+    m = eng.get_metrics()
+    assert m["emit_carried_chunks"] > 0
+    assert (m["emit_carried_chunks"] + m["emit_flushed_chunks"]
+            == m["decode_chunks"])

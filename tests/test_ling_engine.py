@@ -135,6 +135,39 @@ def test_a_preempted_sequence_resumes_where_it_stopped():
         assert b.finish_reason == a.finish_reason
 
 
+@pytest.mark.parametrize("pages", [32, 7])
+def test_streamed_hybrid_matches_unstreamed(pages):
+    """The hybrid family streamed: a chunk's tokens go out under the next
+    dispatch; tokens, logprobs and reasons are those of the same engine
+    unstreamed, each stream splices to its result. With 7 pages one
+    sequence is pre-empted and re-prefilled: what is carried for it is
+    flushed before it is re-queued, and its stream goes on after."""
+    rng = np.random.default_rng(3)
+    prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in (30, 28)]
+
+    def run(stream):
+        eng = tiny_engine("float32", num_pages=pages)
+        frames = [[] for _ in prompts]
+        for i, p in enumerate(prompts):
+            eng.submit(GenerationRequest(prompt=list(p), max_new_tokens=40,
+                                         request_id=f"h{i}"),
+                       on_tokens=frames[i].append if stream else None)
+        res = {r.request_id: r for r in eng.run_until_idle()}
+        return eng, [res[f"h{i}"] for i in range(len(prompts))], frames
+
+    eng, got, frames = run(True)
+    _plain, want, _none = run(False)
+    for g, w, fr in zip(got, want, frames):
+        assert (g.tokens, g.logprobs, g.finish_reason) == (
+            w.tokens, w.logprobs, w.finish_reason)
+        assert [t for f in fr for t in f] == g.tokens and len(g.tokens) == 40
+    m = eng.get_metrics()
+    assert (m["recurrent_preemptions"] >= 1) == (pages == 7)
+    assert m["emit_carried_chunks"] >= 1
+    assert (m["emit_carried_chunks"] + m["emit_flushed_chunks"]
+            == m["decode_chunks"])
+
+
 # ------------------------------------------------------- what it cannot do
 
 

@@ -233,7 +233,7 @@ def test_continuous_engine_records_timeline():
     kinds = {e["name"] for e in eng.timeline.events()}
     assert {"engine.admit", "engine.prefill.dispatch",
             "engine.decode.dispatch", "engine.harvest.wait",
-            "engine.harvest.emit", "engine.process_packed"} <= kinds
+            "engine.harvest.book", "engine.process_packed"} <= kinds
     decodes = [e for e in eng.timeline.events()
                if e["name"] == "engine.decode.dispatch"]
     assert decodes[0]["args"].get("compile") is True  # the compiler ran

@@ -196,7 +196,7 @@ async def test_spans_carry_the_callers_request_id():
     names = {e["name"] for e in engine.timeline.events()}
     assert {"pump.drain_inbox", "pump.resolve", "engine.admit",
             "engine.prefill.dispatch", "engine.decode.dispatch",
-            "engine.harvest.wait", "engine.harvest.emit",
+            "engine.harvest.wait", "engine.harvest.book",
             "engine.process_packed"} <= names
     # a result hands the engine's stamps up, in order
     st = outs[0][0].stamps
@@ -211,11 +211,23 @@ def test_a_decode_chunk_adds_a_bounded_number_of_records(slots, steps, n_new):
     engine.generate([_req(i, n_new) for i in range(slots)])
     events = engine.timeline.events()
     chunks = [e for e in events if e["name"] == "engine.decode.dispatch"]
-    inside = [e for e in events if e["parent"] == "engine.decode.dispatch"
-              or e["parent"] == "engine.process_packed"]
     assert len(chunks) >= 2
-    # per chunk: its bracket, process_packed, harvest.wait, harvest.emit
-    assert len(inside) == 3 * len(chunks)
+    # per chunk: its bracket with the blocking read inside it, then the
+    # token half and the judgments, siblings under the step (nobody
+    # streams here: no engine.emit.* span)
+    by_name = {n: [e for e in events if e["name"] == n] for n in (
+        "engine.harvest.wait", "engine.harvest.book",
+        "engine.process_packed")}
+    assert all(len(v) == len(chunks) for v in by_name.values())
+    assert all(e["parent"] == "engine.decode.dispatch"
+               for e in by_name["engine.harvest.wait"])
+    assert all(e["parent"] == "engine.step"
+               for n in ("engine.harvest.book", "engine.process_packed")
+               for e in by_name[n])
+    # the bracket ends with its read: the token half starts after it
+    for c, w, b in zip(chunks, by_name["engine.harvest.wait"],
+                       by_name["engine.harvest.book"]):
+        assert w["t"] + w["dur"] <= c["t"] + c["dur"] <= b["t"]
     admits = [e for e in events if e["name"] in (
         "engine.admit", "engine.prefill.dispatch")]
     steps = [e for e in events if e["name"] == "engine.step"]

@@ -216,6 +216,254 @@ async def test_coordinator_stream_fails_over_before_first_chunk():
         await workers[1].stop()
 
 
+# --------------------- the carried emit: chunk k streams under chunk k+1
+
+
+class _Log:
+    """What a consumer sees, in the order it sees it: ``("frame", id,
+    tokens)`` per stream callback, ``("final", id, result)`` per result
+    handed out by ``drain_finished`` after a step."""
+
+    def __init__(self):
+        self.events = []
+
+    def cb(self, rid):
+        return lambda toks: self.events.append(("frame", rid, list(toks)))
+
+    def drive(self, eng, max_steps=10000, until=None):
+        """Step until idle (or until ``until(eng)``), as the pump does."""
+        for _ in range(max_steps):
+            live = eng.step()
+            for res in eng.drain_finished():
+                self.events.append(("final", res.request_id, res))
+            if until is not None and until(eng):
+                return
+            if live == 0 and not eng.n_waiting:
+                return
+        raise AssertionError("engine never went idle")
+
+    def frames(self, rid):
+        return [e[2] for e in self.events if e[0] == "frame" and e[1] == rid]
+
+    def streamed(self, rid):
+        return [t for f in self.frames(rid) for t in f]
+
+    def result(self, rid):
+        return next(e[2] for e in self.events
+                    if e[0] == "final" and e[1] == rid)
+
+    def check_order(self, rid):
+        """Frames in token order, none twice, the final envelope last."""
+        res = self.result(rid)
+        assert self.streamed(rid) == res.tokens
+        kinds = [e[0] for e in self.events if e[1] == rid]
+        assert kinds[-1] == "final" and kinds.count("final") == 1
+        assert all(f for f in self.frames(rid))          # no empty frame
+
+
+def _sreq(rid, n_new, prompt=(1, 2, 3), **kw):
+    return GenerationRequest(prompt=list(prompt), max_new_tokens=n_new,
+                             temperature=0.0, request_id=rid, **kw)
+
+
+def _counters(eng):
+    m = eng.get_metrics()
+    return (m["emit_carried_chunks"], m["emit_flushed_chunks"],
+            m["decode_chunks"])
+
+
+def test_a_chunks_tokens_stream_after_the_next_dispatch():
+    """The mechanism itself: when ``step()`` returns with the slot alive,
+    the chunk it just read is appended but NOT yet streamed (carried); the
+    next ``step()`` streams it, under its own dispatch, and holds the next
+    one's. A request's first frame is not carried."""
+    eng = ContinuousEngine(SPEC, config=_ecfg())
+    log = _Log()
+    eng.submit(_sreq("a", 14), on_tokens=log.cb("a"))
+    eng.step()                      # sync admission (first token) + chunk 1
+    state = next(iter(eng._slots.values()))
+    assert len(state.tokens) == 5 and log.streamed("a") == state.tokens[:1]
+    assert eng._carried == [state] and _counters(eng)[:2] == (0, 0)
+    eng.step()                      # dispatch 2, THEN chunk 1's frame
+    assert len(state.tokens) == 9 and log.streamed("a") == state.tokens[:5]
+    assert _counters(eng) == (1, 0, 2)
+    spans = [e for e in eng.timeline.events()
+             if e["name"] in ("engine.emit.carried", "engine.harvest.wait")]
+    # inside the second bracket: the carried emit, then the blocking read
+    assert [e["name"] for e in spans[-2:]] == ["engine.emit.carried",
+                                               "engine.harvest.wait"]
+    assert all(e["parent"] == "engine.decode.dispatch" for e in spans[-2:])
+    log.drive(eng)
+    log.check_order("a")
+    carried, flushed, chunks = _counters(eng)
+    # the last chunk's slot finishes in it: streamed at once, unhidden
+    assert (carried, flushed) == (chunks - 1, 1) and chunks == 4
+
+
+@pytest.mark.parametrize("news", [(6, 14), (9, 10), (13, 5), (2, 11)])
+def test_frames_in_token_order_and_the_final_frame_last(news):
+    """Two streams of unequal length (and a third nobody streams): one
+    finishes in a chunk whose predecessor's emit is still carried for the
+    other; per stream the frames splice to the result, the final envelope
+    comes last, and streaming changes no token, logprob or reason."""
+    reqs = [("a", news[0], (1, 2, 3)), ("b", news[1], (4, 5, 6, 7)),
+            ("quiet", 7, (8, 9))]
+
+    def run(stream):
+        eng = ContinuousEngine(SPEC, config=_ecfg(max_slots=4))
+        log = _Log()
+        for rid, n, prompt in reqs:
+            eng.submit(_sreq(rid, n, prompt), on_tokens=(
+                log.cb(rid) if stream and rid != "quiet" else None))
+        log.drive(eng)
+        return eng, log
+
+    eng, log = run(True)
+    _plain_eng, plain = run(False)
+    for rid, n, _p in reqs:
+        got, want = log.result(rid), plain.result(rid)
+        assert (got.tokens, got.logprobs, got.finish_reason) == (
+            want.tokens, want.logprobs, want.finish_reason)
+        assert len(got.tokens) == n
+    log.check_order("a")
+    log.check_order("b")
+    assert not log.frames("quiet") and not plain.frames("a")
+    carried, flushed, chunks = _counters(eng)
+    longest = max(news)
+    streamed_chunks = -(-(longest - 1) // 4)     # the quiet one may outlive
+    assert carried + flushed == streamed_chunks <= chunks
+    assert carried >= 1
+    assert _counters(_plain_eng)[:2] == (0, 0)
+
+
+def test_generate_carries_nothing():
+    eng = ContinuousEngine(SPEC, config=_ecfg())
+    res = eng.generate([_sreq("g", 12)])
+    assert len(res[0].tokens) == 12
+    assert eng._carried is None and _counters(eng)[:2] == (0, 0)
+    assert not [e for e in eng.timeline.events()
+                if e["name"].startswith("engine.emit.")]
+
+
+def _until_carried(eng, log, n_chunks=2):
+    log.drive(eng, until=lambda e: e._carried is not None
+              and e.get_metrics()["decode_chunks"] >= n_chunks)
+    assert eng._carried is not None
+
+
+def test_flush_stream_delivers_what_is_held_once():
+    eng = ContinuousEngine(SPEC, config=_ecfg())
+    log = _Log()
+    eng.submit(_sreq("f", 16), on_tokens=log.cb("f"))
+    _until_carried(eng, log)
+    state = next(iter(eng._slots.values()))
+    held = list(state.tokens)
+    assert len(log.streamed("f")) < len(held)
+    eng.flush_stream()
+    assert log.streamed("f") == held and eng._carried is None
+    eng.flush_stream()                          # nothing left: no frame
+    assert log.streamed("f") == held
+    log.drive(eng)
+    log.check_order("f")
+    carried, flushed, chunks = _counters(eng)
+    assert carried + flushed == chunks
+
+
+def test_abort_all_flushes_the_carried_emit():
+    eng = ContinuousEngine(SPEC, config=_ecfg())
+    log = _Log()
+    eng.submit(_sreq("x", 16), on_tokens=log.cb("x"))
+    _until_carried(eng, log)
+    held = list(next(iter(eng._slots.values())).tokens)
+    assert eng.abort_all() == 1
+    assert log.streamed("x") == held            # none lost, none twice
+    assert eng._carried is None and not eng._slots
+    assert eng.step() == 0 and log.streamed("x") == held
+
+
+def test_a_slot_retired_by_the_capacity_loop_streams_first():
+    """No dispatch follows for a slot the pool cannot grow: what is
+    carried for it goes out before its result, from the capacity loop."""
+    eng = ContinuousEngine(SPEC, config=_ecfg(num_pages=2, max_slots=2))
+    log = _Log()
+    eng.submit(_sreq("a", 40, prompt=range(1, 12)), on_tokens=log.cb("a"))
+    eng.submit(_sreq("b", 40, prompt=range(20, 31)), on_tokens=log.cb("b"))
+    log.drive(eng)
+    assert eng.get_metrics()["capacity_finishes"] >= 1
+    for rid in "ab":
+        log.check_order(rid)
+        assert log.result(rid).finish_reason == "length"
+    carried, flushed, chunks = _counters(eng)
+    assert carried + flushed == chunks and flushed >= 1
+
+
+@pytest.mark.parametrize("end", [6, 7, 9, 10, 12])
+def test_host_stop_sequence_inside_a_carried_chunk_trims(end):
+    """A two-token stop sequence (host-side: the device knows single ids
+    only) that ends at token ``end``: inside chunk 2 or 3, whose
+    predecessor's emit is carried when the stop is found. The stream is
+    the result, cut after the sequence, exactly as without streaming."""
+    probe = ContinuousEngine(SPEC, config=_ecfg()).generate(
+        [_sreq("p", 16)])[0].tokens
+    seq = probe[end - 2: end]
+    cut = next(i + 2 for i in range(len(probe) - 1)
+               if probe[i: i + 2] == seq)
+
+    def run(stream):
+        eng = ContinuousEngine(SPEC, config=_ecfg())
+        log = _Log()
+        eng.submit(_sreq("s", 16, stop_sequences=[seq]),
+                   on_tokens=log.cb("s") if stream else None)
+        log.drive(eng)
+        return log
+
+    log, plain = run(True), run(False)
+    res = log.result("s")
+    assert res.tokens == probe[:cut] == plain.result("s").tokens
+    assert res.finish_reason == plain.result("s").finish_reason == "stop"
+    log.check_order("s")
+
+
+def test_pause_and_revive_at_a_page_boundary_with_a_carried_emit():
+    """The capacity grant lands exactly on a page boundary (prompt 8 +
+    two chunks of 4 = one page of 16): the device pauses the slot, the
+    judgment revives it, and its stream carries across the pause."""
+    eng = ContinuousEngine(SPEC, config=_ecfg(max_seq_len=64))
+    log = _Log()
+    eng.submit(_sreq("edge", 16, prompt=range(1, 9)),
+               on_tokens=log.cb("edge"))
+    log.drive(eng)
+    log.check_order("edge")
+    assert len(log.result("edge").tokens) == 16
+    assert eng.get_metrics()["capacity_finishes"] == 0
+
+
+@pytest.mark.asyncio
+async def test_pump_shutdown_flushes_the_carried_emit():
+    """The pump stops with a slot alive and a chunk's emit carried: the
+    engine thread streams it before the futures fail."""
+    from distributed_inference_engine_tpu.serving.pump import EnginePump
+
+    eng = ContinuousEngine(SPEC, config=_ecfg())
+    pump = EnginePump(eng)
+    held = []
+    step = eng.step
+
+    def step_then_stop():
+        live = step()
+        if eng._carried is not None and eng._decode_chunks >= 2:
+            held[:] = next(iter(eng._slots.values())).tokens
+            pump._stop.set()                    # what shutdown_nowait sets
+        return live
+
+    eng.step = step_then_stop
+    got = []
+    with pytest.raises(RuntimeError, match="shut down"):
+        await pump.generate_streaming(_sreq("z", 40), got.extend)
+    await pump.stop()
+    assert held and got == held                 # none lost, none twice
+
+
 # ------------------------------------------- sub-chunk streaming (ISSUE 13)
 
 
