@@ -314,12 +314,17 @@ class ContinuousEngine:
         self.body, self.attn_impl = resolve_decode_body(
             cfg.attention_impl, jax.default_backend(), self.spec,
             sharded=shard_fn is not None or kv_sharding is not None)
-        # a spec with recurrent (KDA) layers keeps a per-slot state beside
-        # its pages: every path that moves or shares PAGES alone would
-        # resume a sequence on a state it does not have. Each fails here,
-        # at load, not at its first request.
+        # two facts of a per-layer spec, kept apart. ``_per_layer``: its
+        # family has ONE prefill, whole prompts from nothing, and none that
+        # continues from cached pages, so every path that would resume on
+        # pages (a prefix hit, a prefill chunk, the host tier, an imported
+        # prefix) is refused, and a sequence that cannot grow re-prefills.
+        # ``_recurrent``: some layers also keep a per-slot state that pages
+        # do not carry; it only words the messages.
+        # Each refusal fails here, at load, not at its first request.
+        self._per_layer = bool(self.spec.layer_kinds)
         self._recurrent = self.spec.recurrent
-        if self.spec.layer_kinds:
+        if self._per_layer:
             refused = [name for name, on in (
                 ("a tp/sp mesh (shard_fn / kv_sharding / sp_mesh)",
                  shard_fn is not None or kv_sharding is not None
@@ -394,12 +399,14 @@ class ContinuousEngine:
         )
         self.max_seq_len = max_seq
         self.prefix_cache = bool(cfg.prefix_cache)
-        # a page hit without the recurrent state AT THAT POSITION is a
-        # wrong answer: prefix reuse is off for such a spec, from the spec,
-        # whatever the option says (counted, so a deploy that asked sees it)
-        self._prefix_disabled_recurrent = int(
-            self._recurrent and self.prefix_cache)
-        if self._recurrent:
+        # a page hit needs a prefill that continues from the cached pages,
+        # which a per-layer family does not have (and, with recurrent
+        # layers, the state AT THAT POSITION, which nothing keeps): prefix
+        # reuse is off for such a spec, from the spec, whatever the option
+        # says (counted, so a deploy that asked sees it)
+        self._prefix_disabled_per_layer = int(
+            self._per_layer and self.prefix_cache)
+        if self._per_layer:
             self.prefix_cache = False
         # routed-expert counters of a hybrid spec, summed from the rows
         # each chunk's packed output carries (no read of their own)
@@ -412,7 +419,12 @@ class ContinuousEngine:
         # sequences pre-empted by re-prefill: request id -> what they had
         # produced before (merged into the result at the finish)
         self._resumed: Dict[str, Dict[str, Any]] = {}
-        self._recurrent_preemptions = 0
+        self._reprefill_preemptions = 0
+        # latent rows the decode steps attended to, and rows the hybrid
+        # body read for them (the whole table, every step): host sums from
+        # each chunk's packed output
+        self._mla_context_rows = 0
+        self._mla_table_rows = 0
         # defer_sync: chunk k's packed output is read AFTER dispatching
         # chunk k+1, overlapping the host round trip with device compute
         # (validated pre-init above; the pool's own bound must agree)
@@ -1836,12 +1848,13 @@ class ContinuousEngine:
     # ---------------------------------------- re-prefill pre-emption
 
     def _preempt_recompute(self, slot: int) -> bool:
-        """A sequence of a spec with recurrent layers that cannot grow (the
-        page pool is dry): give its slot and pages back and put it at the
-        FRONT of the queue as prompt + the tokens it has produced, to be
-        re-prefilled when pages free up. Its state is rebuilt from the
-        tokens; nothing ever resumes on a zero state, and swapping pages
-        alone (kv_offload) is refused for such a spec at load. False when
+        """A sequence of a per-layer spec that cannot grow (the page pool
+        is dry): give its slot and pages back and put it at the FRONT of
+        the queue as prompt + the tokens it has produced, to be
+        re-prefilled when pages free up. Pages and any recurrent state are
+        rebuilt from the tokens; nothing ever resumes on a zero state, and
+        swapping pages (kv_offload) is refused for such a spec at load.
+        False when
         it should finish as "length" instead: at the model's cap, budget
         spent, or no other sequence is live to free a page."""
         state = self._slots[slot]
@@ -1870,7 +1883,7 @@ class ContinuousEngine:
             max_new_tokens=left)
         self._waiting.appendleft((again, state.on_tokens,
                                   state.submitted_at))
-        self._recurrent_preemptions += 1
+        self._reprefill_preemptions += 1
         return True
 
     # ------------------------------------------------- swap-based preempt
@@ -2007,6 +2020,10 @@ class ContinuousEngine:
         if self._recurrent:
             raise ValueError("kv_export: pages without the recurrent state "
                              "at their end are not a prefix of this spec")
+        if self._per_layer:
+            raise ValueError("kv_export: a per-layer spec has no prefill "
+                             "that continues from cached pages, so no "
+                             "worker could use an exported prefix")
         if not self.prefix_cache:
             return None
         from .kv_fabric import export_paged_kv
@@ -2161,7 +2178,7 @@ class ContinuousEngine:
                     continue             # the flush finished this slot
                 cur = int(lengths_np[slot])
                 cap_tok = self.kv.ensure_capacity(slot, cur + ahead)
-            if cap_tok <= cur and self._recurrent and self._pending is not None:
+            if cap_tok <= cur and self._per_layer and self._pending is not None:
                 # a re-prefill decision needs the CURRENT tokens (see the
                 # offload branch above for why flushing mid-loop is safe)
                 prev, self._pending = self._pending, None
@@ -2174,7 +2191,7 @@ class ContinuousEngine:
                 # retiring a slot (re-queue, swap, finish) hands its stream
                 # on: what is carried goes out first
                 self._flush_emit(False)
-                if self._recurrent and self._preempt_recompute(slot):
+                if self._per_layer and self._preempt_recompute(slot):
                     retired.append(slot)       # re-queued, no finish
                 elif self._try_swap_out(slot):
                     retired.append(slot)       # deactivate, no finish
@@ -2369,7 +2386,19 @@ class ContinuousEngine:
         # a row emits from step 0 until it goes inactive and never again
         # in the chunk (_advance), so its tokens are a PREFIX of its
         # column: one count a slot, the columns as lists in one call each
-        counts = (toks_np >= 0).sum(axis=0).tolist()
+        counts_np = (toks_np >= 0).sum(axis=0)
+        counts = counts_np.tolist()
+        if self._per_layer:
+            # a row that emitted c tokens and ends at length e attended to
+            # e - c + 1 ... e rows (cached + the chunk's own, its new one
+            # included); the body read the whole table at every step
+            ends = packed_np[2 * n_steps + 1]
+            self._mla_context_rows += int(
+                (counts_np * (ends - counts_np)
+                 + counts_np * (counts_np + 1) // 2).sum())
+            self._mla_table_rows += (n_steps * self.max_slots
+                                     * self.kv.max_pages_per_seq
+                                     * self.kv.page_size)
         tok_cols = toks_np.T.tolist()
         lp_cols = lps_np.T.tolist()
         for slot, state in entry.snapshot.items():
@@ -2736,9 +2765,10 @@ class ContinuousEngine:
             "engine_steps": self._steps,
             "prefill_calls": self._prefill_calls,
             "prefix_hit_admissions": self._prefix_hit_admissions,
-            # 1 when a deploy asked for prefix reuse over a spec with
-            # recurrent layers: off from the spec, never a page hit
-            "prefix_disabled_recurrent": self._prefix_disabled_recurrent,
+            # 1 when a deploy asked for prefix reuse over a per-layer spec
+            # (no prefill that continues from cached pages): off from the
+            # spec, never a page hit
+            "prefix_disabled_per_layer": self._prefix_disabled_per_layer,
             # decode steps the harvested chunks ran, and the routed
             # experts' counters (zeros for a spec without them): top-k
             # choices that landed on held experts / all choices, over
@@ -2746,8 +2776,13 @@ class ContinuousEngine:
             # over expert layers and DECODE steps
             "decode_steps": self._decode_steps,
             "decode_chunks": self._decode_chunks,
-            # sequences re-queued as prompt + tokens when the pool ran dry
-            "recurrent_preemptions": self._recurrent_preemptions,
+            # sequences of a per-layer spec re-queued as prompt + tokens
+            # when the pool ran dry
+            "reprefill_preemptions": self._reprefill_preemptions,
+            # per-layer specs: latent rows the decode steps attended to
+            # (per paged layer), and rows the body read for them
+            "mla": {"decode_context_rows": self._mla_context_rows,
+                    "decode_table_rows": self._mla_table_rows},
             "moe": {
                 "assignments_held": int(self._moe_counts[0]),
                 "assignments_total": int(self._moe_counts[1]),

@@ -119,12 +119,13 @@ class PagedKVCache:
                 f"n_kv_heads*head_dim = {fused} must be a multiple of 128 "
                 "for the paged layout (TPU lane alignment)"
             )
-        if spec.recurrent and (sharding is not None or offload is not None):
+        if spec.layer_kinds and (sharding is not None
+                                 or offload is not None):
             raise ValueError(
-                "a spec with recurrent (KDA) layers keeps per-slot state "
-                "beside its pages: a sharded pool and the host tier "
-                "(kv_offload) carry pages only and would resume a "
-                "sequence on a state it does not have")
+                "a per-layer spec keeps ONE latent pool (and, with "
+                "recurrent layers, per-slot state beside it): a sharded "
+                "pool and the host tier (kv_offload) move K/V page pairs "
+                "and would resume a sequence on what they do not carry")
         self.spec = spec
         self.max_slots = max_slots
         self.page_size = page_size

@@ -63,6 +63,26 @@ class _Seq(tuple):
     __hash__ = tuple.__hash__
 
 
+class _Map(tuple):
+    """Sorted (key, value) pairs that also equal the dict (or the list of
+    pairs a JSON round trip makes of them) they describe: a nested group of
+    a configuration file as a hashable spec field."""
+
+    def __new__(cls, src=()):
+        items = src.items() if isinstance(src, dict) else src
+        return super().__new__(cls, sorted((str(k), v) for k, v in items))
+
+    def __eq__(self, other):
+        if isinstance(other, (dict, list)):
+            other = _Map(other)
+        return tuple.__eq__(self, other)
+
+    def __ne__(self, other):
+        return not self == other
+
+    __hash__ = tuple.__hash__
+
+
 @dataclass(frozen=True)
 class ModelSpec:
     """Static architecture description; hashable so it can be a jit static arg."""
@@ -119,12 +139,26 @@ class ModelSpec:
     topk_group: int = 1
     routed_scaling_factor: float = 1.0
     experts_held: Tuple[int, int] = (0, 0)   # (first expert, count)
+    # MLA with a compressed query (0 = the query projected in one matrix)
+    # and YaRN-scaled rotary frequencies (empty = plain RoPE): the
+    # published ``rope_scaling`` group, ``ops/mla.py`` ``yarn_*``
+    q_lora_rank: int = 0
+    rope_scaling: Tuple[Tuple[str, Any], ...] = ()
+    # manifold-constrained hyper-connections (``ops/mhc.py``): the residual
+    # is ``hc_mult`` streams, mixed by a map made doubly stochastic in
+    # ``hc_sinkhorn_iters`` Sinkhorn-Knopp rounds (0 = one plain residual)
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_clamp_min: float = 0.0
+    hc_clamp_max: float = 0.0
 
     def __post_init__(self) -> None:
         # hashable whatever a dict/JSON round trip handed in
         for name in ("layer_kinds", "layer_mlps", "layer_ids",
                      "experts_held"):
             object.__setattr__(self, name, _Seq(getattr(self, name)))
+        object.__setattr__(self, "rope_scaling", _Map(self.rope_scaling))
 
     @property
     def head_dim(self) -> int:
@@ -197,6 +231,13 @@ class ModelSpec:
                     1 <= self.topk_group <= self.n_group):
                 raise ValueError("n_group must divide n_experts and "
                                  "topk_group lie in [1, n_group]")
+        if self.hc_mult and (set(self.layer_kinds) != {"mla"}
+                             or self.q_lora_rank < 1
+                             or self.hc_sinkhorn_iters < 1):
+            raise ValueError(
+                "hc_mult residual streams (mHC) belong to a per-layer spec "
+                "of MLA layers with a compressed query (q_lora_rank) and "
+                "hc_sinkhorn_iters >= 1")
         if self.n_experts:
             if not 1 <= self.experts_per_token <= self.n_experts:
                 raise ValueError(
@@ -230,11 +271,18 @@ def layered_family(spec: ModelSpec):
     ``init_params`` / ``init_state`` / ``zero_state_slot`` and the programs'
     bodies (``forward_prefill_into_pages``, ``forward_decode_step``,
     ``gather_context_rows``, ``write_rows_into_pages``). ``engine/`` reaches
-    them here and names no model file; one family today (KDA + MLA layers,
-    ``models/ling.py``), imported late because it imports this module."""
+    them here and names no model file. Two families, told apart by what the
+    spec holds: ``hc_mult`` residual streams (``models/xing.py``: mHC around
+    every sublayer, MLA in every layer, no recurrent state) or one (KDA +
+    MLA layers, ``models/ling.py``); imported late because they import this
+    module."""
     if not spec.layer_kinds:
         raise ValueError("a uniform spec has no per-layer family: its "
                          "forward_* live in models/base.py")
+    if spec.hc_mult:
+        from . import xing
+
+        return xing
     from . import ling
 
     return ling

@@ -122,6 +122,13 @@ ENGINE_OFFLOAD_TABLE = [          # engine.get_metrics()["kv_offload"]
      "Estimated prefill seconds displaced by host-tier prefix hits"),
 ]
 
+ENGINE_MLA_TABLE = [              # engine.get_metrics()["mla"]
+    ("decode_context_rows", "engine_mla_decode_context_rows", "c",
+     "Latent rows the decode steps attended to, per paged layer (per-layer specs)"),
+    ("decode_table_rows", "engine_mla_decode_table_rows", "c",
+     "Latent rows the decode body read for them: the whole table, every step"),
+]
+
 KV_TABLE = [                       # PagedKVCache.get_stats()
     ("num_pages", "kv_pages", "g", "HBM page-pool size"),
     ("page_size", "kv_page_size", "g", "Tokens per KV page"),
@@ -454,6 +461,7 @@ WORKER_COMPILE_TABLE = [           # get_metrics()["device"]["compile"]
 _GROUPS: List[Tuple[List, Tuple[str, ...]]] = [
     (ENGINE_TABLE, MODEL_LABELS),
     (ENGINE_OFFLOAD_TABLE, MODEL_LABELS),
+    (ENGINE_MLA_TABLE, MODEL_LABELS),
     (KV_TABLE, MODEL_LABELS),
     (OFFLOAD_TABLE, MODEL_LABELS),
     (PUMP_TABLE, MODEL_LABELS),
@@ -542,6 +550,9 @@ def apply_engine(reg: MetricsRegistry, m: Optional[Mapping[str, Any]],
     off = m.get("kv_offload")
     if isinstance(off, Mapping):
         _apply_table(reg, ENGINE_OFFLOAD_TABLE, off, MODEL_LABELS, labels)
+    latent = m.get("mla")
+    if isinstance(latent, Mapping):
+        _apply_table(reg, ENGINE_MLA_TABLE, latent, MODEL_LABELS, labels)
     kv = m.get("kv")
     if isinstance(kv, Mapping):
         _apply_table(reg, KV_TABLE, kv, MODEL_LABELS, labels)

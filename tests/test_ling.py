@@ -64,8 +64,9 @@ class Served:
     pages and the per-slot state, collecting every position's logits."""
 
     def __init__(self, spec, params, slots=4, page=16, pages=32,
-                 state_dtype=None):
+                 state_dtype=None, family=ling):
         self.spec, self.params, self.page = spec, params, page
+        self.family = family       # the per-layer family's module
         self.kv = PagedKVCache(spec, max_slots=slots, page_size=page,
                                num_pages=pages, max_seq_len=128,
                                dtype=spec.dtype)
@@ -84,7 +85,7 @@ class Served:
             toks[i, :len(p)], lens[i], ids[i] = p, len(p), s
             table[i] = self.kv._table[s]
         hidden, kp, st, moe = jax.jit(
-            lambda *a: ling.forward_prefill_into_pages(
+            lambda *a: self.family.forward_prefill_into_pages(
                 self.spec, self.params, *a))(
             jnp.asarray(toks), jnp.asarray(lens), self.kv.k_pages,
             self.kv.state, jnp.asarray(table), jnp.asarray(ids))
@@ -99,7 +100,7 @@ class Served:
         logits after each fed token."""
         b = self.kv.max_slots
         out = {s: [] for s in feeds}
-        step = jax.jit(lambda *a: ling.forward_decode_step(
+        step = jax.jit(lambda *a: self.family.forward_decode_step(
             self.spec, self.params, *a))
         pos = dict(lengths)
         fed = {s: 0 for s in feeds}
@@ -110,7 +111,7 @@ class Served:
             start = np.zeros((b,), np.int32)
             for s in feeds:
                 start[s] = pos[s]
-            ctx = ling.gather_context_rows(self.kv.k_pages,
+            ctx = self.family.gather_context_rows(self.kv.k_pages,
                                            self.kv.page_table)
             side = jnp.zeros((self.spec.paged_layers, b, n_steps,
                               self.spec.cache_row_width), self.kv.dtype)
@@ -135,7 +136,7 @@ class Served:
                         out[s].append(logits[s])
                         fed[s] += 1
                         cur[s] += 1
-            kp = ling.write_rows_into_pages(
+            kp = self.family.write_rows_into_pages(
                 self.kv.k_pages, side, self.kv.page_table,
                 jnp.asarray(cur - start), jnp.asarray(start))
             self.kv.swap(kp, state)

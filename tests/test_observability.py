@@ -143,6 +143,26 @@ def test_catalog_families_are_valid_and_unique():
     assert set(reg.names) == set(obs_collectors.CATALOG)
 
 
+def test_a_per_layer_specs_mla_counters_reach_the_scrape():
+    """``get_metrics()["mla"]`` (latent rows the decode steps attended to,
+    and rows the body read for them) lands in two counter families; an
+    engine without the sub-dict sets neither."""
+    reg = MetricsRegistry()
+    obs_collectors.apply_engine(
+        reg, {"decode_steps": 8, "mla": {"decode_context_rows": 196,
+                                         "decode_table_rows": 4096}},
+        model="m", worker_id="w0")
+    obs_collectors.apply_engine(reg, {"total_requests": 3}, model="other")
+    text = reg.render()
+    assert ('engine_mla_decode_context_rows_total{model="m",worker_id="w0"}'
+            ' 196' in text)
+    assert ('engine_mla_decode_table_rows_total{model="m",worker_id="w0"}'
+            ' 4096' in text)
+    assert 'engine_mla_decode_table_rows_total{model="other"' not in text
+    assert obs_collectors.CATALOG["engine_mla_decode_table_rows"][0] \
+        == "counter"
+
+
 def test_latency_stats_histogram_snapshot():
     ls = LatencyStats()
     ls.add(0.0005)            # below first bound

@@ -408,3 +408,48 @@ async def test_profile_stop_writes_the_xplane_alone(tmp_path):
     written = [f for _d, _s, fs in os.walk(tmp_path) for f in fs]
     assert [f for f in written if f.endswith(".xplane.pb")]
     assert not [f for f in written if f.endswith(".json.gz")]
+
+
+def _program_texts(family, spec):
+    """The lowered text (with op paths) of a per-layer family's prefill and
+    decode-step bodies at a tiny size."""
+    import jax
+    import jax.numpy as jnp
+
+    params = jax.eval_shape(
+        lambda: family.init_params(spec, jax.random.key(0)))
+    state = family.init_state(spec, 2)
+    lm, w = spec.paged_layers, spec.cache_row_width
+    pages = jnp.zeros((lm, 4, 16, w), jnp.bfloat16)
+    prefill = jax.jit(
+        lambda p, t, n, pg, st, tb, sl: family.forward_prefill_into_pages(
+            spec, p, t, n, pg, st, tb, sl)).lower(
+        params, jnp.zeros((2, 16), jnp.int32), jnp.ones((2,), jnp.int32),
+        pages, state, jnp.zeros((2, 4), jnp.int32),
+        jnp.zeros((2,), jnp.int32)).as_text(debug_info=True)
+    decode = jax.jit(
+        lambda p, t, n, s0, cx, sd, st, ac: family.forward_decode_step(
+            spec, p, t, n, s0, cx, sd, st, ac)).lower(
+        params, jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32),
+        jnp.ones((2,), jnp.int32), jnp.zeros((lm, 2, 64, w), jnp.bfloat16),
+        jnp.zeros((lm, 2, 4, w), jnp.bfloat16), state,
+        jnp.ones((2,), bool)).as_text(debug_info=True)
+    return prefill, decode
+
+
+def test_the_residual_scope_is_on_the_mhc_familys_programs_alone():
+    """``resid.mhc`` (``perfbench/lib/scopes_mhc.py`` reads it) names ops
+    of BOTH programs of the mHC family and of neither of the hybrid
+    family's; the scopes the two share keep their names in both."""
+    from distributed_inference_engine_tpu.models import ling, xing
+
+    for text in _program_texts(xing, xing.xing_spec("xing-tiny",
+                                                    max_seq_len=64)):
+        assert "/resid.mhc/" in text
+        assert "/attn.mla/" in text and "/moe.experts/" in text
+        assert "/attn.kda" not in text and "/state.update/" not in text
+    for text in _program_texts(ling, ling.ling_spec("ling-tiny",
+                                                    max_seq_len=64)):
+        assert "resid.mhc" not in text
+        assert "/attn.mla/" in text and "/moe.experts/" in text
+        assert "/attn.kda" in text

@@ -1,8 +1,10 @@
-"""The hybrid family (``models/ling.py``) through ``ContinuousEngine`` and the
-worker's factory on the CPU: slots, pages and state under real admission,
-pre-emption by re-prefill, and every combination it cannot serve yet, which
-must raise at load or at the call. ``tests/test_ling.py`` holds the layer
-and logits comparisons against the reference."""
+"""The mHC family (``models/xing.py``) through ``ContinuousEngine`` and the
+worker's factory on the CPU: slots and latent pages under real admission, a
+zero-layer state through every program, pre-emption by re-prefill, streamed
+and unstreamed, and every combination a per-layer spec WITHOUT recurrent
+layers cannot serve, which must raise at load or at the call under a message
+that says why (no prefill continues from cached pages) and not "recurrent".
+``tests/test_xing.py`` holds the logits comparisons against the reference."""
 
 import json
 import os
@@ -20,7 +22,7 @@ from distributed_inference_engine_tpu.config import (  # noqa: E402
     EngineConfig, ModelConfig,
 )
 from distributed_inference_engine_tpu.engine.continuous import (  # noqa: E402
-    ContinuousEngine,
+    ContinuousEngine, resolve_decode_body,
 )
 from distributed_inference_engine_tpu.engine.paged_kv import (  # noqa: E402
     PagedKVCache,
@@ -29,25 +31,17 @@ from distributed_inference_engine_tpu.engine.types import (  # noqa: E402
     GenerationRequest,
 )
 from distributed_inference_engine_tpu.models import (  # noqa: E402
-    engine_from_config, ling, mistral_spec, spec_for_architecture,
+    engine_from_config, spec_for_architecture, xing,
 )
 from perfbench.lib import families  # noqa: E402
 
-with open(os.path.join(ROOT, "perfbench", "rehearse", "ling-tiny.json")) as _f:
+with open(os.path.join(ROOT, "perfbench", "rehearse", "xing-tiny.json")) as _f:
     CFG = json.load(_f)
 REF = families.reference(CFG)
 
 
 def tiny_spec(**kw):
-    return ling.ling_spec("ling-tiny", max_seq_len=128, **kw)
-
-
-@pytest.fixture(scope="module")
-def served_bf16():
-    return ling.init_params(tiny_spec(), jax.random.key(7))
-
-
-# ---------------------------------------------------------- engine, served
+    return xing.xing_spec("xing-tiny", max_seq_len=128, **kw)
 
 
 def tiny_engine(dtype="bfloat16", **cfg_kw):
@@ -59,23 +53,23 @@ def tiny_engine(dtype="bfloat16", **cfg_kw):
 
 
 def judged(engine, requests, results):
-    """Every served token the reference's argmax, or within 6 % of
-    max|logit| of it: the bound ``tests/test_ling.py`` holds the bfloat16
-    logits to (activations re-rounded through six layers at width 64, on
-    flat random-init logits)."""
+    """Every served token the reference's argmax, or within 8 % of
+    max|logit| of it: the bound ``tests/test_xing.py`` holds the bfloat16
+    logits to."""
     for req, res in zip(requests, results):
         assert len(res.tokens) == req.max_new_tokens
         lg = np.asarray(REF.logits(
             CFG, engine.params, jnp.asarray(req.prompt + res.tokens)))
         for i, tok in enumerate(res.tokens):
             row = lg[len(req.prompt) - 1 + i]
-            assert row.max() - row[tok] <= 0.06 * np.abs(row).max(), (i, tok)
+            assert row.max() - row[tok] <= 0.08 * np.abs(row).max(), (i, tok)
 
 
-def test_engine_serves_the_hybrid_through_slots_pages_and_state():
+def test_engine_serves_the_family_through_slots_and_latent_pages():
     """Six requests of unequal length over four slots: batched admission at
     padded buckets, deferred first tokens, slot reuse, counters."""
     engine = tiny_engine(prefix_cache=True)
+    assert engine.body == "hybrid" and engine.attn_impl == "xla"
     rng = np.random.default_rng(1)
     reqs = [GenerationRequest(
         prompt=[int(t) for t in rng.integers(1, 256, n)], max_new_tokens=m)
@@ -83,20 +77,40 @@ def test_engine_serves_the_hybrid_through_slots_pages_and_state():
     results = engine.generate(reqs)
     judged(engine, reqs, results)
     m = engine.get_metrics()
+    # off from the spec, and the counter says why: per-layer, not recurrent
     assert m["prefix_disabled_per_layer"] == 1
     assert m["prefix_hit_admissions"] == 0 and m["kv"]["prefix_queries"] == 0
-    assert m["attn_impl"] == "xla"
     assert m["decode_steps"] >= 12 and m["decode_chunks"] >= 3
     moe = m["moe"]
-    assert 0 < moe["assignments_held"] < moe["assignments_total"]
-    assert 0 < moe["experts_touched"] <= m["decode_steps"] * 5 * 4
+    assert 0 < moe["assignments_held"] == moe["assignments_total"]
+    assert 0 < moe["experts_touched"] <= m["decode_steps"] * 3 * 8
     kv = m["kv"]
-    assert (kv["paged_layers"], kv["state_layers"]) == (2, 4)
-    assert kv["latent_bytes_per_token"] == 2 * (32 + 8) * 2
-    assert kv["state_bytes"] == 4 * ling.state_bytes_per_slot(tiny_spec())
-    assert kv["hbm_bytes"] == 2 * 32 * 16 * 40 * 2
-    # every slot is free again, and free means zero
-    assert all(float(jnp.abs(a).max()) == 0 for a in engine.kv.state.values())
+    assert (kv["paged_layers"], kv["state_layers"]) == (4, 0)
+    assert kv["latent_bytes_per_token"] == 4 * (32 + 8) * 2
+    assert kv["state_bytes"] == 0
+    assert kv["hbm_bytes"] == 4 * 32 * 16 * 40 * 2
+    assert {a.shape for a in engine.kv.state.values()} == {(0, 4)}
+
+
+def test_mla_counters_follow_lengths_and_steps():
+    """One request alone: a prompt of 20 and 9 tokens. The first comes from
+    the prefill; the 8 decode steps attend to 21, 22, ... 28 rows each (the
+    cached rows and the step's own), and the body read the whole table (4
+    slots x 8 pages x 16) at every step of its chunks."""
+    engine = tiny_engine()
+    engine.generate([GenerationRequest(prompt=list(range(1, 21)),
+                                       max_new_tokens=9)])
+    m = engine.get_metrics()
+    assert m["mla"]["decode_context_rows"] == sum(range(21, 29))
+    assert m["mla"]["decode_table_rows"] == m["decode_steps"] * 4 * 8 * 16
+    assert m["decode_steps"] == 4 * m["decode_chunks"]
+    # a second request grows both
+    engine.generate([GenerationRequest(prompt=list(range(1, 6)),
+                                       max_new_tokens=3)])
+    m2 = engine.get_metrics()
+    assert (m2["mla"]["decode_context_rows"]
+            == m["mla"]["decode_context_rows"] + 6 + 7)
+    assert m2["mla"]["decode_table_rows"] > m["mla"]["decode_table_rows"]
 
 
 def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
@@ -111,13 +125,11 @@ def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
     assert m["prefix_hit_admissions"] == 0 and m["kv"]["prefix_hit_pages"] == 0
 
 
-def test_a_preempted_sequence_resumes_where_it_stopped():
-    """A pool too small for both requests at full length: the one that
-    cannot grow is re-queued with its tokens so far and re-prefilled (its
-    state rebuilt from the tokens, never resumed on a zero state); the
-    result equals the same request served alone. In float32: the chunked
-    prefill and the one-step form round differently, and in bfloat16 a
-    flat random-init logit row may flip its argmax on that."""
+def test_a_preempted_sequence_is_re_prefilled_and_resumes():
+    """A pool too small for both requests at full length: with no recurrent
+    layer the pre-emption is still a re-prefill (the host tier is refused
+    and no prefill continues from pages); the result equals the same
+    request served alone. In float32, as the Ling test."""
     rng = np.random.default_rng(3)
     prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in (30, 28)]
 
@@ -126,7 +138,7 @@ def test_a_preempted_sequence_resumes_where_it_stopped():
                 for p in prompts]
 
     alone = [tiny_engine("float32").generate([r])[0] for r in make()]
-    tight = tiny_engine("float32", num_pages=7)        # 2 x 2 pages at admission, 7 all
+    tight = tiny_engine("float32", num_pages=7)
     together = tight.generate(make())
     m = tight.get_metrics()
     assert m["reprefill_preemptions"] >= 1 and m["capacity_finishes"] == 0
@@ -136,12 +148,7 @@ def test_a_preempted_sequence_resumes_where_it_stopped():
 
 
 @pytest.mark.parametrize("pages", [32, 7])
-def test_streamed_hybrid_matches_unstreamed(pages):
-    """The hybrid family streamed: a chunk's tokens go out under the next
-    dispatch; tokens, logprobs and reasons are those of the same engine
-    unstreamed, each stream splices to its result. With 7 pages one
-    sequence is pre-empted and re-prefilled: what is carried for it is
-    flushed before it is re-queued, and its stream goes on after."""
+def test_streamed_matches_unstreamed(pages):
     rng = np.random.default_rng(3)
     prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in (30, 28)]
 
@@ -150,20 +157,20 @@ def test_streamed_hybrid_matches_unstreamed(pages):
         frames = [[] for _ in prompts]
         for i, p in enumerate(prompts):
             eng.submit(GenerationRequest(prompt=list(p), max_new_tokens=40,
-                                         request_id=f"h{i}"),
+                                         request_id=f"x{i}"),
                        on_tokens=frames[i].append if stream else None)
         res = {r.request_id: r for r in eng.run_until_idle()}
-        return eng, [res[f"h{i}"] for i in range(len(prompts))], frames
+        return eng, [res[f"x{i}"] for i in range(len(prompts))], frames
 
     eng, got, frames = run(True)
     _plain, want, _none = run(False)
+    assert len(got) == len(want) == 2
     for g, w, fr in zip(got, want, frames):
         assert (g.tokens, g.logprobs, g.finish_reason) == (
             w.tokens, w.logprobs, w.finish_reason)
         assert [t for f in fr for t in f] == g.tokens and len(g.tokens) == 40
     m = eng.get_metrics()
     assert (m["reprefill_preemptions"] >= 1) == (pages == 7)
-    assert m["emit_carried_chunks"] >= 1
     assert (m["emit_carried_chunks"] + m["emit_flushed_chunks"]
             == m["decode_chunks"])
 
@@ -171,41 +178,37 @@ def test_streamed_hybrid_matches_unstreamed(pages):
 # ------------------------------------------------------- what it cannot do
 
 
-def test_existing_families_keep_their_specs():
-    spec = mistral_spec("mistral-tiny")
-    assert spec.layer_kinds == () and not spec.recurrent
-    assert spec.layer_plan == tuple(("attn", "dense", i) for i in range(4))
-    assert spec.cache_row_width == spec.n_kv_heads * spec.head_dim
-    assert spec.paged_layers == spec.n_layers and spec.state_layers == 0
-    cut = spec_for_architecture("ling", size="ling-3.0-flash-ep4",
-                                max_seq_len=3072)
-    assert cut.layer_ids == [0, 2, 3, 4, 5, 6, 7]
-    assert cut.layer_kinds == ["kda"] * 4 + ["mla"] + ["kda"] * 2
-    assert cut.experts_held == (0, 128) and cut.cache_row_width == 576
-    assert hash(cut) == hash(type(cut).from_dict(
-        json.loads(json.dumps(cut.to_dict()))))
+def test_the_body_is_chosen_from_the_spec():
+    spec = spec_for_architecture("xing", size="xing4.0-pp1",
+                                 max_seq_len=8704)
+    assert resolve_decode_body("auto", "tpu", spec) == ("hybrid", "xla")
+    assert spec.max_seq_len == 8704 and not spec.recurrent
+    with pytest.raises(ValueError, match="unknown xing size"):
+        spec_for_architecture("xing", size="xing-9b")
 
 
 @pytest.mark.parametrize("kw", [
     {"kv_offload": True}, {"prefill_chunk": 32},
     {"attention_impl": "pallas-decode"},
     {"attention_impl": "pallas-decode_interpret"}])
-def test_engine_options_a_recurrent_spec_cannot_honour_raise(kw):
-    with pytest.raises(ValueError, match="hybrid"):
+def test_engine_options_a_per_layer_spec_cannot_honour_raise(kw):
+    with pytest.raises(ValueError, match="per-layer") as e:
         tiny_engine(**kw)
+    assert "recurrent" not in str(e.value)
 
 
-def test_sharding_an_artifact_and_a_quantized_tree_raise(served_bf16):
+def test_sharding_an_artifact_and_a_quantized_tree_raise():
     cfg = EngineConfig(max_slots=2, max_seq_len=64, page_size=16,
                        num_pages=8)
     for kw in ({"shard_fn": lambda p: p}, {"kv_sharding": object()},
                {"sp_mesh": object()}, {"artifact_path": "/nonexistent"}):
-        with pytest.raises(ValueError, match="hybrid"):
+        with pytest.raises(ValueError, match="per-layer"):
             ContinuousEngine(tiny_spec(), config=cfg, **kw)
     from distributed_inference_engine_tpu.ops.quant import quantize_weight
 
-    bad = dict(served_bf16, lm_head=quantize_weight(
-        served_bf16["lm_head"].astype(jnp.float32), reduce_axes=(0,)))
+    params = xing.init_params(tiny_spec(), jax.random.key(7))
+    bad = dict(params, lm_head=quantize_weight(
+        params["lm_head"].astype(jnp.float32), reduce_axes=(0,)))
     with pytest.raises(ValueError, match="unquantized"):
         ContinuousEngine(tiny_spec(), params=bad, config=cfg)
 
@@ -220,23 +223,26 @@ def test_sharding_an_artifact_and_a_quantized_tree_raise(served_bf16):
     ({"metadata": {"artifact": "/tmp/a"}}, "artifact"),
     ({"metadata": {"continuous": 0}}, "static engine"),
     ({"metadata": {"kv_offload": True}}, "kv_offload"),
+    ({"metadata": {"prefill_chunk": 32}}, "prefill_chunk"),
 ])
-def test_deploys_a_hybrid_architecture_cannot_serve_raise(change, match):
-    meta = {"size": "ling-tiny", "continuous": 1, "page_size": 16,
+def test_deploys_this_architecture_cannot_serve_raise(change, match):
+    meta = {"size": "xing-tiny", "continuous": 1, "page_size": 16,
             "num_pages": 8}
     meta.update(change.get("metadata", {}))
-    cfg = ModelConfig(name="m", architecture="ling", max_batch_size=2,
+    cfg = ModelConfig(name="m", architecture="xing", max_batch_size=2,
                       max_seq_len=64, metadata=meta,
                       **{k: v for k, v in change.items() if k != "metadata"})
     with pytest.raises(ValueError, match=match):
         engine_from_config(cfg)
 
 
-def test_calls_a_recurrent_spec_cannot_answer_raise():
+def test_calls_a_per_layer_spec_cannot_answer_raise():
     engine = tiny_engine()
-    with pytest.raises(ValueError, match="recurrent state"):
+    with pytest.raises(ValueError,
+                       match="continues from cached pages") as e:
         engine.kv_export([1, 2, 3])
-    with pytest.raises(ValueError, match="hybrid"):
+    assert "recurrent" not in str(e.value)
+    with pytest.raises(ValueError, match="per-layer"):
         engine.submit_prefilled(GenerationRequest(prompt=[1, 2]), None)
     from distributed_inference_engine_tpu.engine.kv_fabric import (
         FabricRejected,
@@ -244,7 +250,7 @@ def test_calls_a_recurrent_spec_cannot_answer_raise():
 
     with pytest.raises(FabricRejected):
         engine.kv_import({"pages": []})
-    with pytest.raises(ValueError, match="recurrent"):
+    with pytest.raises(ValueError, match="ONE latent pool"):
         PagedKVCache(tiny_spec(), max_slots=2, page_size=16, num_pages=8,
                      offload=object())
 
@@ -252,19 +258,16 @@ def test_calls_a_recurrent_spec_cannot_answer_raise():
 def test_the_worker_seeds_the_tree_from_metadata():
     def build(seed):
         return engine_from_config(ModelConfig(
-            name="m", architecture="ling", max_batch_size=2, max_seq_len=64,
+            name="m", architecture="xing", max_batch_size=2, max_seq_len=64,
             dtype="bfloat16", metadata={
-                "size": "ling-tiny", "continuous": 1, "page_size": 16,
+                "size": "xing-tiny", "continuous": 1, "page_size": 16,
                 "num_pages": 8, "seed": seed, "admission_max_rows": 1}))
 
     a, b, c = build(5), build(5), build(6)
     assert a.config.admission_max_rows == 1
-    la, lb, lc = (e.params["layers"][1]["w_router"] for e in (a, b, c))
+    la, lb, lc = (e.params["layers"][1]["hc_mlp"]["phi"] for e in (a, b, c))
     assert bool((la == lb).all()) and not bool((la == lc).all())
     assert la.dtype == jnp.float32
     assert a.params["layers"][1]["w_gate_up"].dtype == jnp.bfloat16
-    # the expert bias: uneven across groups, and the same for every seed
-    bias = np.asarray(a.params["layers"][1]["router_bias"]).reshape(4, 4)
-    assert bias.mean(axis=1).std() > 0 and bias.std(axis=1).min() > 0
-    assert (bias.reshape(-1)
+    assert (np.asarray(a.params["layers"][1]["router_bias"])
             == np.asarray(c.params["layers"][1]["router_bias"])).all()
