@@ -53,6 +53,7 @@ from ..models.base import (
     unembed,
     write_prefill_pages,
 )
+from ..ops.mla import prefill_key_blocks
 from ..ops.sampling import (
     SamplingParams,
     sample_tokens,
@@ -425,6 +426,10 @@ class ContinuousEngine:
         # each chunk's packed output
         self._mla_context_rows = 0
         self._mla_table_rows = 0
+        # key blocks the admitted prompts' prefills visited / blocks of
+        # their buckets' whole squares (ops/mla.py), per paged layer
+        self._mla_prefill_visited = 0
+        self._mla_prefill_square = 0
         # defer_sync: chunk k's packed output is read AFTER dispatching
         # chunk k+1, overlapping the host round trip with device compute
         # (validated pre-init above; the pool's own bound must agree)
@@ -1503,6 +1508,10 @@ class ContinuousEngine:
                 jnp.asarray(slot_ids),
             )
             self._prefill_moe.append(moe)
+            for row in batch:
+                visited, square = prefill_key_blocks(len(row[3]), tb)
+                self._mla_prefill_visited += visited
+                self._mla_prefill_square += square
         elif self._prefill_pages is not None:
             # fused path: per-layer KV scatters into the donated pools
             # inside the prefill scan (pad rows' seq_len 0 drops every
@@ -2780,9 +2789,15 @@ class ContinuousEngine:
             # when the pool ran dry
             "reprefill_preemptions": self._reprefill_preemptions,
             # per-layer specs: latent rows the decode steps attended to
-            # (per paged layer), and rows the body read for them
+            # (per paged layer), and rows the body read for them; key blocks
+            # their prefills visited, and blocks of the buckets' squares
             "mla": {"decode_context_rows": self._mla_context_rows,
-                    "decode_table_rows": self._mla_table_rows},
+                    "decode_table_rows": self._mla_table_rows,
+                    **({"prefill_key_blocks_visited":
+                        self._mla_prefill_visited,
+                        "prefill_key_blocks_bucket":
+                        self._mla_prefill_square}
+                       if self._per_layer else {})},
             "moe": {
                 "assignments_held": int(self._moe_counts[0]),
                 "assignments_total": int(self._moe_counts[1]),

@@ -344,9 +344,8 @@ def mla_layer_prefill(spec: ModelSpec, blk: Params, x, positions, seq_lens):
         q_nope, q_rope, rows, gate = _mla_inputs(spec, blk, h, positions)
         kv = _proj(rows[..., :r], blk["w_kvb"]).reshape(
             b, t, spec.n_heads, dn + spec.v_head_dim)
-        o = mla.mla_causal_attention(
-            q_nope, q_rope, kv[..., :dn], rows[:, :, None, r:], kv[..., dn:],
-            seq_lens)
+        o = mla.mla_causal_attention(q_nope, q_rope, kv, rows[..., r:],
+                                     seq_lens)
         return _mla_out(blk, o, gate, x.dtype), rows
 
 

@@ -248,9 +248,8 @@ def mla_layer_prefill(spec: ModelSpec, blk: Params, h, positions, seq_lens):
         q_nope, q_rope, rows = _mla_inputs(spec, blk, h, positions)
         kv = _proj(rows[..., :r], blk["w_kvb"]).reshape(
             b, t, spec.n_heads, dn + spec.v_head_dim)
-        o = mla.mla_causal_attention(
-            q_nope, q_rope, kv[..., :dn], rows[:, :, None, r:], kv[..., dn:],
-            seq_lens, scale=_softmax_scale(spec), skip_masked=True)
+        o = mla.mla_causal_attention(q_nope, q_rope, kv, rows[..., r:],
+                                     seq_lens, scale=_softmax_scale(spec))
         return _mla_out(blk, o, h.dtype), rows
 
 

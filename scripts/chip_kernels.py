@@ -4,7 +4,8 @@ the mistral-7b serving shapes, each against its XLA path.
     python chip_smoke.py --kernels            # the one command, on the chip
     python -m scripts.chip_kernels --tiny     # CPU debug: interpreted, tiny
 
-Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; the five int4
+Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; the latent
+prefill at 32 heads of 128 | 64 | 128, T 1,024 and 8,192; the five int4
 payload shapes of mistral-7b (N=32,768 for the lm_head) at M=128 (the
 prefill bucket of ``ops.int4_matmul.blocks_for``) and, for the 2-D and
 stacked legs, at M=8 (the decode bucket: what a served decode step runs). Tolerances are the ones the CPU parity tests use
@@ -31,10 +32,13 @@ import traceback
 FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
             int4_rows=(128, 8),
             int4_shapes=((2048, 6144), (2048, 4096), (2048, 28672),
-                         (7168, 4096), (2048, 32768)))
+                         (7168, 4096), (2048, 32768)),
+            mla_dims=(128, 64, 128),
+            mla_cases=((1024, 48), (1024, 1024), (8192, 48), (8192, 8192)))
 TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
             int4_rows=(32, 3),
-            int4_shapes=((128, 256), (256, 128)))
+            int4_shapes=((128, 256), (256, 128)),
+            mla_dims=(16, 8, 16), mla_cases=((1024, 48), (1024, 1024)))
 OUT = os.path.join("chiprun_out", "chip_kernels.json")
 
 
@@ -228,6 +232,33 @@ def check_flash_decode_served(cfg, interpret):
     return f"4 block sizes, max|err| {max(errs):.2e}"
 
 
+def check_mla_prefill(cfg, interpret):
+    """The latent-attention prefill kernel at the published head shape (32
+    heads of 128 | 64 | 128) against the XLA body: the smallest and the
+    largest whole-block buckets, a prompt inside the first block and one
+    that fills the bucket; rows below ``seq_lens`` compared."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_engine_tpu.ops import mla
+
+    h, (dn, dr, dv) = cfg["H"], cfg["mla_dims"]
+    errs = []
+    for t, n in cfg["mla_cases"]:
+        ks = jax.random.split(jax.random.key(t + n), 4)
+        qn, qr, kv = (jax.random.normal(k, (1, t, h, d), jnp.bfloat16)
+                      for k, d in zip(ks, (dn, dr, dn + dv)))
+        kr = jax.random.normal(ks[3], (1, t, dr), jnp.bfloat16)
+        lens = jnp.asarray([n], jnp.int32)
+        got, ref = (jax.jit(lambda *a, impl=impl: mla.mla_causal_attention(
+            *a, impl=impl))(qn, qr, kv, kr, lens)
+            for impl in ("flash_interpret" if interpret else "flash", "xla"))
+        errs.append(_close(got[:, :n], ref[:, :n], 2e-2))
+        assert not bool(jnp.any(got[:, -(-n // mla.Q_BLOCK) * mla.Q_BLOCK:])), \
+            "a query block past the prompt is not zero"
+    return f"{len(errs)} cases, max|err| {max(errs):.2e}"
+
+
 # name -> (check, on the default serving path?)
 CHECKS = {
     "int4_matmul_2d": (check_int4_2d, True),
@@ -235,6 +266,7 @@ CHECKS = {
     "int4_matmul_cp": (check_int4_cp, True),
     "flash_decode": (check_flash_decode, True),
     "flash_decode_served": (check_flash_decode_served, True),
+    "mla_prefill": (check_mla_prefill, True),
 }
 
 

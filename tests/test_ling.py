@@ -197,6 +197,24 @@ def test_served_float32_logits_are_the_references(served_f32, float32_run):
     assert sv.moe[1] > 0 and 0 < sv.moe[0] < sv.moe[1]
 
 
+def test_prefill_into_pages_through_the_kernel_is_the_reference(monkeypatch,
+                                                                served_f32):
+    """The one MLA layer's prefill with the interpreted kernel forced
+    (blocks of 16 in the bucket of 48, the plain softmax scale, the output
+    gate after it) against the float32 reference, at the XLA body's limit."""
+    from distributed_inference_engine_tpu.ops import mla
+
+    monkeypatch.setattr(mla, "Q_BLOCK", 16)
+    monkeypatch.setattr(mla, "K_BLOCK", 16)
+    monkeypatch.setattr(mla, "prefill_impl", lambda t: "flash_interpret")
+    seqs = [s[:n] for s, n in zip(sequences(), PROMPTS)]
+    with jax.default_matmul_precision("highest"):
+        _, got = Served(tiny_spec(dtype="float32"), served_f32).prefill(
+            seqs, 48)
+        worst, scale = max_diff(got, CFG, served_f32, seqs)
+    assert worst < F32_TOL and scale > 0.3, (worst, scale)
+
+
 def test_served_bfloat16_logits_are_near_the_references(served_bf16):
     seqs = sequences(1)
     got, _ = served_logits(tiny_spec(), served_bf16, seqs, (20, 37, 5))

@@ -163,6 +163,24 @@ def test_a_per_layer_specs_mla_counters_reach_the_scrape():
         == "counter"
 
 
+def test_the_latent_prefills_block_counters_reach_the_scrape():
+    reg = MetricsRegistry()
+    obs_collectors.apply_engine(
+        reg, {"mla": {"decode_context_rows": 1, "decode_table_rows": 2,
+                      "prefill_key_blocks_visited": 45,
+                      "prefill_key_blocks_bucket": 256}},
+        model="m", worker_id="w0")
+    obs_collectors.apply_engine(
+        reg, {"mla": {"decode_context_rows": 0, "decode_table_rows": 0}},
+        model="dense")
+    text = reg.render()
+    # "_bucket" is a histogram's sample suffix: the exported name avoids it
+    for name, v in (("visited", 45), ("square", 256)):
+        assert (f'engine_mla_prefill_key_blocks_{name}_total'
+                f'{{model="m",worker_id="w0"}} {v}' in text)
+        assert f'prefill_key_blocks_{name}_total{{model="dense"' not in text
+
+
 def test_latency_stats_histogram_snapshot():
     ls = LatencyStats()
     ls.add(0.0005)            # below first bound

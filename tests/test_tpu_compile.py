@@ -88,3 +88,24 @@ def test_int4_matmul_compiles_for_v5e(one_chip, rows, k2, n):
              if " = " in ln and "%" in ln.split(" = ")[0]
              and "int4" in ln.split(" = ")[0]]
     assert len(names) == 1, names
+
+
+# the latent prefill at the published head shape (32 heads of 128 | 64 |
+# 128): the smallest and largest buckets of the Ling cell (512, 2048) and
+# of the Xing cell (1024, 8192, 8704 = 17 blocks), and two rows at once
+@pytest.mark.parametrize("b,t", [(1, 512), (1, 1024), (1, 2048), (1, 8192),
+                                 (1, 8704), (2, 1024)])
+def test_mla_prefill_compiles_for_v5e(one_chip, b, t):
+    from distributed_inference_engine_tpu.ops import mla
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *a: mla.mla_causal_attention(
+        *a, impl="flash")).lower(
+            sds((b, t, 32, 128)), sds((b, t, 32, 64)), sds((b, t, 32, 256)),
+            sds((b, t, 64)), sds((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "mla_prefill_flash" in text
+    # no score tensor in HBM: nothing float32 as large as [32, 512, t]
+    assert f"f32[{b},32,512," not in text and f"f32[32,512,{t}]" not in text

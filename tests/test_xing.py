@@ -314,29 +314,129 @@ def test_the_tiny_ramp_is_crossed_inside_a_test_sequence():
     assert abs(soft - mla.yarn_softmax_scale(24, sc)) < 1e-7
 
 
+def _attention_inputs(b, t, h, dims, dtype=jnp.float32):
+    dn, dr, dv = dims
+    ks = jax.random.split(jax.random.key(t + h), 4)
+    qn, qr, kv = (jax.random.normal(k, (b, t, h, d), dtype)
+                  for k, d in zip(ks, (dn, dr, dn + dv)))
+    # values a quarter as large: outputs of magnitude <= 1, as the layers'
+    kv = kv.at[..., dn:].multiply(0.25)
+    return qn, qr, kv, jax.random.normal(ks[3], (b, t, dr), dtype)
+
+
 def test_skipping_masked_key_blocks_gives_the_same_attention():
-    """The prefill of this family reads, for a block of queries, only the
-    keys up to its last row: the sums of the one-``lax.map`` form (which
-    stays the default, the hybrid family's program) to rounding order, rows
-    past a sequence's end aside."""
-    ks = jax.random.split(jax.random.key(0), 5)
+    """The XLA body reads, for a block of queries, only the keys up to its
+    last row: the sums of the one-block form to rounding order, rows past a
+    sequence's end aside."""
     b, t, h = 2, 64, 3
-    qn, kn = (jax.random.normal(k, (b, t, h, 16)) for k in ks[:2])
-    qr = jax.random.normal(ks[2], (b, t, h, 8))
-    kr = jax.random.normal(ks[3], (b, t, 1, 8))
-    v = jax.random.normal(ks[4], (b, t, h, 16))
+    qn, qr, kv, kr = _attention_inputs(b, t, h, (16, 8, 16))
     lens = jnp.asarray([64, 37])
-    whole = mla.mla_causal_attention(qn, qr, kn, kr, v, lens)
-    mapped = mla.mla_causal_attention(qn, qr, kn, kr, v, lens, q_block=16)
-    skipped = mla.mla_causal_attention(qn, qr, kn, kr, v, lens, q_block=16,
-                                       skip_masked=True)
+    args = (qn, qr, kv[..., :16], kr, kv[..., 16:], lens, 24 ** -0.5)
+    whole = mla.mla_causal_attention_xla(*args, q_block=t)
+    blocked = mla.mla_causal_attention_xla(*args, q_block=16)
     live = (jnp.arange(t)[None, :] < lens[:, None])[..., None, None]
-    for got in (mapped, skipped):
-        assert float(jnp.abs(jnp.where(live, got - whole, 0)).max()) < 2e-6
+    assert float(jnp.abs(jnp.where(live, blocked - whole, 0)).max()) < 2e-6
     # fewer scores: the unrolled blocks' key lengths are 16, 32, 48, 64
-    text = str(jax.make_jaxpr(lambda *a: mla.mla_causal_attention(
-        *a, q_block=16, skip_masked=True))(qn, qr, kn, kr, v, lens))
+    text = str(jax.make_jaxpr(lambda *a: mla.mla_causal_attention_xla(
+        *a, q_block=16))(*args))
     assert "f32[2,3,16,16]" in text and "f32[2,3,16,48]" in text
+    assert "f32[2,3,64,64]" not in text
+    # a T of no whole blocks is one block, and the default picks it here
+    assert str(jax.make_jaxpr(lambda *a: mla.mla_causal_attention(
+        *a))(qn, qr, kv, kr, lens)).count("f32[2,3,64,64]")
+
+
+# (T, prompt length, heads, softmax scale, dtype): blocks of 512 at the
+# published head widths. Lengths at a block's edge, inside a block, a whole
+# bucket, one token, a pad row; the hybrid family's plain scale (None) and
+# this family's YaRN scale; 8 heads = two head groups, 3 = a group of three
+KERNEL_CASES = [
+    (1024, 1024, 2, None, "float32"), (1024, 512, 2, "yarn", "float32"),
+    (1024, 700, 8, None, "float32"), (1024, 1, 2, "yarn", "float32"),
+    (1024, 0, 2, None, "float32"), (2048, 2048, 3, "yarn", "float32"),
+    (2048, 1024, 2, None, "float32"), (2048, 1100, 2, "yarn", "float32"),
+    (2048, 1537, 2, None, "float32"), (1024, 700, 2, "yarn", "bfloat16"),
+    (2048, 2048, 2, None, "bfloat16"), (2048, 1100, 3, "yarn", "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("t,n,h,scale,dtype", KERNEL_CASES)
+def test_the_prefill_kernel_is_the_one_block_xla_body(t, n, h, scale, dtype):
+    """The interpreted kernel against the whole-``T`` einsum / softmax: rows
+    below ``seq_lens`` to rounding order (float32) or to the family's
+    bfloat16 tolerance; query blocks wholly past the prompt are zeros."""
+    if scale == "yarn":
+        scale = mla.yarn_softmax_scale(192, {"factor": 64.0,
+                                             "mscale_all_dim": 1.0})
+    dn = 128
+    qn, qr, kv, kr = _attention_inputs(1, t, h, (dn, 64, 128),
+                                       jnp.dtype(dtype))
+    lens = jnp.asarray([n])
+    ref = mla.mla_causal_attention_xla(
+        qn, qr, kv[..., :dn], kr, kv[..., dn:], lens,
+        scale or 192 ** -0.5, q_block=t).astype(jnp.float32)
+    got = jax.jit(lambda *a: mla.mla_causal_attention(
+        *a, scale=scale, impl="flash_interpret"))(qn, qr, kv, kr, lens)
+    assert got.shape == ref.shape and got.dtype == qn.dtype
+    got = got.astype(jnp.float32)
+    tol = 2e-6 if dtype == "float32" else BF16_TOL * float(jnp.abs(ref).max())
+    if n:
+        assert float(jnp.abs(got[:, :n] - ref[:, :n]).max()) < tol
+    assert not bool(jnp.any(got[:, -(-n // mla.Q_BLOCK) * mla.Q_BLOCK:]))
+    assert bool(jnp.isfinite(got).all())
+
+
+@pytest.mark.parametrize("bq,bk", [(16, 32), (32, 16), (64, 16)])
+def test_unequal_blocks_visit_the_same_keys(monkeypatch, bq, bk):
+    """Query and key blocks of different heights: the pair tables, the
+    diagonal and the finishing pair follow the rows, not the block index."""
+    monkeypatch.setattr(mla, "Q_BLOCK", bq)
+    monkeypatch.setattr(mla, "K_BLOCK", bk)
+    qn, qr, kv, kr = _attention_inputs(3, 128, 2, (16, 8, 16))
+    lens = jnp.asarray([128, 45, 64])
+    ref = mla.mla_causal_attention_xla(qn, qr, kv[..., :16], kr,
+                                       kv[..., 16:], lens, 0.2, q_block=128)
+    got = mla.mla_causal_attention(qn, qr, kv, kr, lens, scale=0.2,
+                                   impl="flash_interpret")
+    live = (jnp.arange(128)[None, :] < lens[:, None])[..., None, None]
+    assert float(jnp.abs(jnp.where(live, got - ref, 0)).max()) < 2e-6
+    qi, ki = mla._pairs(128, bq, bk)
+    assert len(qi) == mla.prefill_key_blocks(128, 128)[0]
+    assert all(k * bk <= q * bq + bq - 1 for q, k in zip(qi, ki))
+
+
+@pytest.mark.parametrize("backend,t,impl", [
+    ("tpu", 1024, "flash"), ("tpu", 8704, "flash"), ("tpu", 512, "flash"),
+    ("tpu", 48, "xla"), ("tpu", 1000, "xla"), ("cpu", 1024, "xla"),
+    ("gpu", 8192, "xla")])
+def test_the_kernel_is_chosen_on_a_tpu_at_whole_blocks(monkeypatch, backend,
+                                                       t, impl):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert mla.prefill_impl(t) == impl
+
+
+@pytest.mark.parametrize("length,t,visited,square", [
+    (4100, 8192, 45, 256),       # 9 query blocks: 1 + 2 + ... + 9
+    (8192, 8192, 136, 256), (512, 1024, 1, 4), (513, 1024, 3, 4),
+    (0, 1024, 0, 4), (37, 48, 1, 1)])
+def test_key_blocks_visited_by_hand(length, t, visited, square):
+    assert mla.prefill_key_blocks(length, t) == (visited, square)
+
+
+def test_prefill_into_pages_through_the_kernel_is_the_reference(monkeypatch,
+                                                                served_f32):
+    """``xing-tiny``'s prefill with the interpreted kernel forced (blocks of
+    16 in the bucket of 48: three rows of unequal length and a pad row)
+    against the float32 reference, at the limit the XLA body is held to."""
+    monkeypatch.setattr(mla, "Q_BLOCK", 16)
+    monkeypatch.setattr(mla, "K_BLOCK", 16)
+    monkeypatch.setattr(mla, "prefill_impl", lambda t: "flash_interpret")
+    seqs = [s[:n] for s, n in zip(sequences(), PROMPTS)]
+    with jax.default_matmul_precision("highest"):
+        sv = Served(tiny_spec(dtype="float32"), served_f32)
+        _, got = sv.prefill(seqs, 48)
+        worst, scale = max_diff(got, CFG, served_f32, seqs)
+    assert worst < F32_TOL, (worst, scale)
 
 
 def test_plain_rope_and_ling_trace_what_they_did():

@@ -113,6 +113,46 @@ def test_mla_counters_follow_lengths_and_steps():
     assert m2["mla"]["decode_table_rows"] > m["mla"]["decode_table_rows"]
 
 
+@pytest.fixture(scope="module")
+def counting_engine():
+    """One engine for the hand counts below: its programs compile once."""
+    return tiny_engine()
+
+
+@pytest.mark.parametrize("length,visited,square", [
+    (20, 1 + 2, 4),              # bucket 32: both query blocks live
+    (37, 1 + 2 + 3, 16),         # bucket 64: three of four
+    (50, 1 + 2 + 3 + 4, 16)])    # bucket 64: all four
+def test_prefill_key_block_counters_by_hand(monkeypatch, counting_engine,
+                                            length, visited, square):
+    """Blocks of 16 for the count: what the kernel would visit for an
+    admitted prompt (at or under the diagonal, below its length) over the
+    blocks of its bucket's whole square, per paged layer; the counters add
+    from one admission to the next."""
+    from distributed_inference_engine_tpu.ops import mla
+
+    monkeypatch.setattr(mla, "Q_BLOCK", 16)
+    monkeypatch.setattr(mla, "K_BLOCK", 16)
+    before = counting_engine.get_metrics()["mla"]
+    counting_engine.generate([GenerationRequest(
+        prompt=list(range(1, length + 1)), max_new_tokens=2)])
+    got = counting_engine.get_metrics()["mla"]
+    assert (got["prefill_key_blocks_visited"]
+            - before["prefill_key_blocks_visited"],
+            got["prefill_key_blocks_bucket"]
+            - before["prefill_key_blocks_bucket"]) == (visited, square)
+
+
+def test_a_tree_without_latent_layers_reports_no_prefill_blocks():
+    from distributed_inference_engine_tpu.models.llama import llama_spec
+
+    eng = ContinuousEngine(
+        llama_spec("llama-tiny", max_seq_len=64), config=EngineConfig(
+            max_slots=2, page_size=16, num_pages=16, max_seq_len=64))
+    assert not any(k.startswith("prefill_key_blocks")
+                   for k in eng.get_metrics().get("mla", {}))
+
+
 def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
     engine = tiny_engine(prefix_cache=True)
     prompt = [int(t) for t in np.random.default_rng(2).integers(1, 256, 40)]
