@@ -38,6 +38,7 @@ from typing import Any, Awaitable, Callable, Dict, List, Optional, Tuple
 from ..config import ModelConfig, ServerConfig
 from ..engine.types import GenerationRequest, GenerationResult
 from .model_manager import ModelManager, ModelProbeError, ModelStageError
+from ..utils.files import atomic_write_json
 from ..utils.framing import FrameError, read_frame, write_frame
 from ..utils.rpc import (
     FramedRPCClient,
@@ -358,6 +359,7 @@ class WorkerServer(FramedServerMixin):
             "generate_stream": self._rpc_generate_stream,
         }
         self._profiling_dir: Optional[str] = None
+        self._profile_counters: Dict[str, Any] = {}
         self._profile_lock = asyncio.Lock()     # one start/stop at a time
         # prefill-pool side: persistent clients to decode-pool peers,
         # keyed by (host, port) — the KV handoff goes peer-to-peer over
@@ -775,6 +777,11 @@ class WorkerServer(FramedServerMixin):
         carrying this process's ``perf_counter_ns`` into the trace, and
         the step-timeline dumps carry the same anchors: ring records and
         ``worker_trace`` offsets map onto the trace's clock through them.
+        ``stop`` also writes ``counters.json`` into the trace directory:
+        every engine's ``get_metrics()`` as it stood when the trace began
+        and when it was asked to end, so that a reader divides the slice's
+        device seconds by the SLICE's counts (a chunk's counters move at its
+        harvest, at most one chunk after its programs ran).
         Writing the trace out takes over a minute on the chip; it runs off
         the event loop, so the worker's streams keep flowing."""
         action = msg.get("action")
@@ -785,6 +792,10 @@ class WorkerServer(FramedServerMixin):
             if action == "start":
                 return self._profile_start(msg)
             return await self._profile_stop()
+
+    def _engine_counters(self) -> Dict[str, Any]:
+        return {"models": {name: eng.get_metrics()
+                           for name, eng in self.engines.items()}}
 
     def _profile_start(self, msg: Dict[str, Any]) -> Dict[str, Any]:
         import jax
@@ -797,6 +808,7 @@ class WorkerServer(FramedServerMixin):
         options.python_tracer_level = int(bool(msg.get("python_tracer", True)))
         jax.profiler.start_trace(trace_dir, profiler_options=options)
         self._profiling_dir = trace_dir
+        self._profile_counters = {"start": self._engine_counters()}
         anchor = clock_anchor("start")
         # bracket the engine step timelines to the same window: the
         # jax trace shows the XLA/device side, the step timeline the
@@ -815,11 +827,18 @@ class WorkerServer(FramedServerMixin):
         if self._profiling_dir is None:
             raise ValueError("profiling is not active")
         anchor = clock_anchor("stop")
+        counters = dict(self._profile_counters, stop=self._engine_counters())
         t0 = time.perf_counter()
         await asyncio.get_running_loop().run_in_executor(
             None, _stop_trace_xplane_only)
         stop_s = time.perf_counter() - t0
         out, self._profiling_dir = self._profiling_dir, None
+        try:
+            os.makedirs(out, exist_ok=True)
+            atomic_write_json(os.path.join(out, "counters.json"), counters)
+        except (OSError, TypeError, ValueError) as e:  # must not fail stop
+            logger.warning("worker %s: counters.json not written: %s",
+                           self.worker_id, e)
         written: List[str] = []
         for name, engine in self.engines.items():
             tl = getattr(engine, "timeline", None)

@@ -92,7 +92,15 @@ def resolve_decode_body(impl: str, backend: str, spec,
     ========  ==========================================  =================
     body      when                                        attention
     ========  ==========================================  =================
-    hybrid    ``spec.layer_kinds``                        the family's (XLA)
+    hybrid    ``spec.layer_kinds``: latent rows           the family's, XLA,
+              (``spec.kv_row_lanes`` 0)                   the table gathered
+                                                          once a chunk
+    hybrid    ``spec.layer_kinds``: K|V rows              ``ops/flash_
+              (``spec.kv_row_lanes`` > 0), the kernel     decode.py`` in
+              applies                                     place from the
+                                                          family's ONE pool;
+                                                          else XLA, a layer's
+                                                          pages a step
     inline    uniform spec with ``sliding_window``        XLA, per-step
                                                           page scatter
     window    uniform, no window, the kernel applies      ``ops/flash_
@@ -105,7 +113,8 @@ def resolve_decode_body(impl: str, backend: str, spec,
     ========  ==========================================  =================
 
     ``"auto"`` takes the kernel on a TPU with the pool on one device and a
-    fused ``Hkv·Dh`` of whole 128-lane tiles; ``"pallas-decode"`` /
+    fused ``Hkv·Dh`` of whole 128-lane tiles (a uniform spec's, or a
+    per-layer spec's K|V rows); ``"pallas-decode"`` /
     ``"pallas-decode_interpret"`` ask for it by name (the tests' way to
     run the TPU body on a CPU); ``"xla"`` refuses it. A sliding-window spec
     runs ``inline`` whatever the string says: its prefix mask depends on the
@@ -116,20 +125,31 @@ def resolve_decode_body(impl: str, backend: str, spec,
     if impl not in ATTENTION_IMPLS:
         raise ValueError(
             f"attention_impl {impl!r} is not one of {ATTENTION_IMPLS}")
-    if spec.layer_kinds:
-        # latent rows (576 wide: no multiple of 128 lanes) and recurrent
-        # state: XLA's path, whatever the backend (ops/mla.py)
+    # lanes of a K (or V) row the kernel would copy: a uniform spec's, or
+    # those of a per-layer spec whose paged layers keep K|V rows
+    lanes = (spec.kv_row_lanes if spec.layer_kinds
+             else spec.n_kv_heads * spec.head_dim)
+    if spec.layer_kinds and not lanes:
+        # latent rows (576 wide: no whole 128-lane tiles, and no K/V to
+        # read): the family's XLA attention, whatever the backend
+        # (ops/mla.py)
         if impl not in ("auto", "xla"):
             raise ValueError(
-                f"attention_impl {impl!r}: a per-layer (hybrid) spec runs "
-                "its latent attention on the XLA path only")
+                f"attention_impl {impl!r}: a per-layer (hybrid) spec whose "
+                "paged layers keep latent rows runs their attention on the "
+                "XLA path only; the kernel reads K/V rows")
         return "hybrid", "xla"
     if spec.sliding_window:
         return "inline", "xla"
     if impl == "auto":
-        kernel = (backend == "tpu" and not sharded
-                  and (spec.n_kv_heads * spec.head_dim) % 128 == 0)
+        kernel = backend == "tpu" and not sharded and lanes % 128 == 0
         impl = "pallas-decode" if kernel else "xla"
+    if spec.layer_kinds:
+        if impl != "xla" and lanes % 128:
+            raise ValueError(
+                f"attention_impl {impl!r}: the kernel copies K/V rows of "
+                f"whole 128-lane tiles, this spec's have {lanes}")
+        return "hybrid", impl
     return ("dense" if impl == "xla" else "window"), impl
 
 
@@ -426,6 +446,16 @@ class ContinuousEngine:
         # each chunk's packed output
         self._mla_context_rows = 0
         self._mla_table_rows = 0
+        # where the paged layers keep K|V rows: rows attended to (the same
+        # host sum) and rows the attention READ, which the program counts
+        # (the kernel's own count of the pages it copied, in the first of
+        # the family's three counters); and the (row, step) pairs that
+        # moved a recurrent state; all per layer
+        self._kv_rows = bool(self.spec.layer_kinds
+                             and self.spec.kv_row_lanes)
+        self._full_context_rows = 0
+        self._full_table_rows = 0
+        self._state_rows_updated = 0
         # key blocks the admitted prompts' prefills visited / blocks of
         # their buckets' whole squares (ops/mla.py), per paged layer
         self._mla_prefill_visited = 0
@@ -800,6 +830,7 @@ class ContinuousEngine:
             # (engine/paged_kv.py); admission, the chunk scan, sampling,
             # ``_advance`` and the harvest are the shared ones.
             fam = layered_family(spec_)
+            attn_impl = self.attn_impl
 
             @partial(jax.jit, donate_argnums=(3, 4))
             def _prefill_pages(params, tokens, seq_lens, kp, vp, table_rows,
@@ -823,19 +854,22 @@ class ContinuousEngine:
                 firsts, key, n_steps: int, n_ctx_pages: int = 0,
                 use_stops: bool = False,
             ):
-                """``n_steps`` tokens for every live slot. The latent pages
-                are frozen for the chunk (gathered once); fresh rows gather
+                """``n_steps`` tokens for every live slot. The pages are
+                frozen for the chunk (the family gathers its latent rows
+                once, or reads its K|V rows where they lie:
+                ``decode_context``); fresh rows gather
                 in a side window written back once at the end; the
                 recurrent state rides the scan carry and moves only for
                 rows that are ``active`` at that step. The packed output
-                gains three rows: the chunk's MoE counters."""
+                gains three rows: the family's counters for the chunk (a
+                routed family's MoE counts; a K|V family's rows read)."""
                 del n_ctx_pages        # one program: the whole table
                 start_lengths = lengths
                 b = lengths.shape[0]
                 advance = partial(_advance, cap=cap, max_new=max_new,
                                   eos_ids=eos_ids, stop_mat=stop_mat,
                                   use_stops=use_stops)
-                ctx = fam.gather_context_rows(kp, page_table)
+                ctx = fam.decode_context(kp, page_table, attn_impl)
                 side = jnp.zeros((kp.shape[0], b, n_steps, kp.shape[-1]),
                                  kp.dtype)
 
@@ -1508,10 +1542,11 @@ class ContinuousEngine:
                 jnp.asarray(slot_ids),
             )
             self._prefill_moe.append(moe)
-            for row in batch:
-                visited, square = prefill_key_blocks(len(row[3]), tb)
-                self._mla_prefill_visited += visited
-                self._mla_prefill_square += square
+            if not self._kv_rows:          # the latent prefill's blocks
+                for row in batch:
+                    visited, square = prefill_key_blocks(len(row[3]), tb)
+                    self._mla_prefill_visited += visited
+                    self._mla_prefill_square += square
         elif self._prefill_pages is not None:
             # fused path: per-layer KV scatters into the donated pools
             # inside the prefill scan (pad rows' seq_len 0 drops every
@@ -2373,8 +2408,13 @@ class ContinuousEngine:
         self._decode_steps += n_steps
         if self.spec.layer_kinds:
             moe = packed_np[2 * n_steps + 4: 2 * n_steps + 7, 0]
-            self._moe_decode_counts += moe
-            self._moe_counts += moe
+            if self._kv_rows:
+                # K|V rows: the first of the family's counters is what its
+                # attention READ, a paged layer, counted in the program
+                self._full_table_rows += int(moe[0])
+            else:
+                self._moe_decode_counts += moe
+                self._moe_counts += moe
             # prefills dispatched before this chunk have finished
             while self._prefill_moe:
                 # graftlint: ok[host-sync-hot-path] 3 ints of a program that ended before the chunk just read
@@ -2402,12 +2442,17 @@ class ContinuousEngine:
             # e - c + 1 ... e rows (cached + the chunk's own, its new one
             # included); the body read the whole table at every step
             ends = packed_np[2 * n_steps + 1]
-            self._mla_context_rows += int(
-                (counts_np * (ends - counts_np)
-                 + counts_np * (counts_np + 1) // 2).sum())
-            self._mla_table_rows += (n_steps * self.max_slots
-                                     * self.kv.max_pages_per_seq
-                                     * self.kv.page_size)
+            attended = int((counts_np * (ends - counts_np)
+                            + counts_np * (counts_np + 1) // 2).sum())
+            table = (n_steps * self.max_slots * self.kv.max_pages_per_seq
+                     * self.kv.page_size)
+            if self._kv_rows:
+                self._full_context_rows += attended
+            else:
+                self._mla_context_rows += attended
+                self._mla_table_rows += table
+            if self._recurrent:
+                self._state_rows_updated += int(counts_np.sum())
         tok_cols = toks_np.T.tolist()
         lp_cols = lps_np.T.tolist()
         for slot, state in entry.snapshot.items():
@@ -2797,7 +2842,16 @@ class ContinuousEngine:
                         self._mla_prefill_visited,
                         "prefill_key_blocks_bucket":
                         self._mla_prefill_square}
-                       if self._per_layer else {})},
+                       if self._per_layer and not self._kv_rows else {})},
+            # per-layer specs whose paged layers keep K|V rows: rows the
+            # decode steps attended to (per paged layer) and rows the body
+            # read for them; recurrent specs: (row, step) pairs that moved
+            # a state (per recurrent layer)
+            **({"attn": {"full_context_rows": self._full_context_rows,
+                         "full_table_rows": self._full_table_rows}}
+               if self._kv_rows else {}),
+            **({"state": {"rows_updated": self._state_rows_updated}}
+               if self._recurrent else {}),
             "moe": {
                 "assignments_held": int(self._moe_counts[0]),
                 "assignments_total": int(self._moe_counts[1]),

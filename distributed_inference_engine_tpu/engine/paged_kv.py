@@ -121,8 +121,9 @@ class PagedKVCache:
             )
         if spec.layer_kinds and (sharding is not None
                                  or offload is not None):
+            kind = "K|V" if spec.kv_row_lanes else "latent"
             raise ValueError(
-                "a per-layer spec keeps ONE latent pool (and, with "
+                f"a per-layer spec keeps ONE {kind} pool (and, with "
                 "recurrent layers, per-slot state beside it): a sharded "
                 "pool and the host tier (kv_offload) move K/V page pairs "
                 "and would resume a sequence on what they do not carry")
@@ -138,11 +139,11 @@ class PagedKVCache:
         self.state = None       # per-slot recurrent state (layered specs)
         if spec.layer_kinds:
             # two kinds of storage under one allocator: ``k_pages`` is the
-            # pool of the layers that grow by the token (one latent row a
-            # token, its width the layer's own, no V pool); ``state`` is
-            # the per-SLOT state of the recurrent layers (the family's
-            # ``init_state``: S [L_kda, slots, H, dk, dk] float32 and the
-            # conv tail). ``pools`` is the pair the programs donate.
+            # pool of the layers that grow by the token (one row a token,
+            # a latent row or K|V side by side, its width the layer's own,
+            # no V pool); ``state`` is the per-SLOT state of the recurrent
+            # layers (the family's ``init_state``: S float32 and the conv
+            # tail). ``pools`` is the pair the programs donate.
             self.k_pages = jnp.zeros(shape, dtype=self.dtype)
             self.v_pages = None
             self.state = layered_family(spec).init_state(spec, max_slots)

@@ -117,7 +117,9 @@ class ModelSpec:
     # Per-layer description (hybrid families, ``models/ling.py``). Empty =
     # the uniform decoder above: every layer softmax attention over K/V,
     # its MLP dense or routed by ``n_experts`` (``layer_plan`` derives it).
-    layer_kinds: Tuple[str, ...] = ()   # per layer "attn" | "kda" | "mla"
+    # per layer "kda" | "mla" (models/ling.py, models/xing.py) or "gdn" |
+    # "full" (models/olmo_hybrid.py)
+    layer_kinds: Tuple[str, ...] = ()
     layer_mlps: Tuple[str, ...] = ()    # per layer "dense" | "moe"
     layer_ids: Tuple[int, ...] = ()     # published index of each kept layer
     # latent attention (MLA): the cache row is kv_lora_rank + qk_rope_head_dim
@@ -130,6 +132,14 @@ class ModelSpec:
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_lower_bound: float = -5.0
+    # Gated DeltaNet linear attention ("gdn" layers): a [gdn_key_head_dim,
+    # gdn_value_head_dim] float32 state a head (one decay a head) and a conv
+    # tail of gdn_conv - 1 rows, per sequence; 0 = the spec has none. Its
+    # "full" layers are softmax attention over K|V rows of n_kv_heads x
+    # head_dim each, no rotary embedding.
+    gdn_key_head_dim: int = 0
+    gdn_value_head_dim: int = 0
+    gdn_conv: int = 4
     # routed experts of a hybrid spec (ops/moe_routed.py): sigmoid scores,
     # expert bias, group-limited top-k over ALL n_experts; this chip
     # computes experts [first, first + count) and one shared expert
@@ -176,12 +186,12 @@ class ModelSpec:
     @property
     def paged_layers(self) -> int:
         """Layers whose cache grows by the token (pages)."""
-        return sum(k != "kda" for k, _m, _i in self.layer_plan)
+        return sum(k not in ("kda", "gdn") for k, _m, _i in self.layer_plan)
 
     @property
     def state_layers(self) -> int:
         """Layers that keep a fixed-size recurrent state per sequence."""
-        return sum(k == "kda" for k, _m, _i in self.layer_plan)
+        return sum(k in ("kda", "gdn") for k, _m, _i in self.layer_plan)
 
     @property
     def recurrent(self) -> bool:
@@ -189,10 +199,21 @@ class ModelSpec:
 
     @property
     def cache_row_width(self) -> int:
-        """Values one token adds to ONE paged pool row of one layer."""
+        """Values one token adds to ONE paged pool row of one layer: a
+        latent row, K|V side by side (a per-layer spec has one pool), or
+        one of K and V (a uniform spec has a pool each)."""
         if self.layer_kinds:
-            return self.kv_lora_rank + self.qk_rope_head_dim
+            return (2 * self.kv_row_lanes
+                    or self.kv_lora_rank + self.qk_rope_head_dim)
         return self.n_kv_heads * self.head_dim
+
+    @property
+    def kv_row_lanes(self) -> int:
+        """Lanes of a K (or V) row of a per-layer spec whose paged layers
+        are softmax attention over K/V; 0 where they keep latent rows (and
+        for a uniform spec, whose pools are ``models/base.py``'s)."""
+        return (self.n_kv_heads * self.head_dim
+                if "full" in self.layer_kinds else 0)
 
     @property
     def jnp_dtype(self):
@@ -215,9 +236,25 @@ class ModelSpec:
                     == len(self.layer_ids) == n):
                 raise ValueError("layer_kinds / layer_mlps / layer_ids must "
                                  "each describe all n_layers layers")
-            if set(self.layer_kinds) - {"kda", "mla"}:
-                raise ValueError("a per-layer spec holds 'kda' and 'mla' "
-                                 f"layers, not {set(self.layer_kinds)}")
+            kinds = set(self.layer_kinds)
+            if kinds - {"kda", "mla"} and kinds - {"gdn", "full"}:
+                raise ValueError(
+                    "a per-layer spec holds 'kda' and 'mla' layers, or "
+                    f"'gdn' and 'full' ones, not {kinds}")
+            if kinds & {"gdn", "full"}:
+                period = self.layer_kinds[:self.layer_kinds.index("full") + 1
+                                          ] if "full" in kinds else ()
+                if (not period or self.gdn_key_head_dim < 1
+                        or self.gdn_value_head_dim < 1
+                        or "moe" in self.layer_mlps
+                        or self.layer_kinds
+                        != period * (n // len(period))):
+                    raise ValueError(
+                        "'gdn' / 'full' layers come in whole periods of "
+                        "gdn layers closed by a full one (the tree is one "
+                        "period stacked over the periods), with "
+                        "gdn_key_head_dim / gdn_value_head_dim set and a "
+                        "dense MLP in every layer")
             if set(self.layer_mlps) - {"dense", "moe"}:
                 raise ValueError(f"unknown layer_mlps {self.layer_mlps}")
             first, count = self.experts_held
@@ -270,15 +307,21 @@ def layered_family(spec: ModelSpec):
     """The module that builds and runs a spec with ``layer_kinds``: its
     ``init_params`` / ``init_state`` / ``zero_state_slot`` and the programs'
     bodies (``forward_prefill_into_pages``, ``forward_decode_step``,
-    ``gather_context_rows``, ``write_rows_into_pages``). ``engine/`` reaches
-    them here and names no model file. Two families, told apart by what the
-    spec holds: ``hc_mult`` residual streams (``models/xing.py``: mHC around
-    every sublayer, MLA in every layer, no recurrent state) or one (KDA +
-    MLA layers, ``models/ling.py``); imported late because they import this
-    module."""
+    ``decode_context``, ``write_rows_into_pages``). ``engine/`` reaches
+    them here and names no model file. Three families, told apart by what
+    the spec holds: ``gdn_key_head_dim`` (``models/olmo_hybrid.py``: Gated
+    DeltaNet layers beside full-attention layers over K|V rows, a dense MLP
+    everywhere), ``hc_mult`` residual streams (``models/xing.py``: mHC
+    around every sublayer, MLA in every layer, no recurrent state) or
+    neither (KDA + MLA layers, ``models/ling.py``); imported late because
+    they import this module."""
     if not spec.layer_kinds:
         raise ValueError("a uniform spec has no per-layer family: its "
                          "forward_* live in models/base.py")
+    if spec.gdn_key_head_dim:
+        from . import olmo_hybrid
+
+        return olmo_hybrid
     if spec.hc_mult:
         from . import xing
 
@@ -468,22 +511,24 @@ def unembed(spec: ModelSpec, params: Params, hidden: jnp.ndarray) -> jnp.ndarray
     256·501 tiles the Mosaic kernel only at bn=256, ~338 GB/s; padded to
     a 2048-multiple it rides the big-block path) — pad columns are
     zero-weight and sliced off here before softcap/sampling."""
-    h = _norm(spec, hidden, params["lnf_scale"], params.get("lnf_bias"))
-    w = params["tok_emb"].T if spec.tie_embeddings else params["lm_head"]
-    if isinstance(w, QuantizedTensor):
-        logits = matmul_any("...d,dv->...v", h.astype(jnp.float32), w)
-        if logits.shape[-1] != spec.vocab_size:
-            logits = logits[..., : spec.vocab_size]
-    else:
-        # keep the [D, V] projection in its storage dtype (bf16: half the HBM
-        # read of an fp32 upcast — this matmul streams the largest single
-        # weight every decode step) and accumulate in fp32 on the MXU
-        logits = jnp.einsum("...d,dv->...v", h.astype(w.dtype), w,
-                            preferred_element_type=jnp.float32)
-    if spec.logit_softcap:
-        cap = spec.logit_softcap
-        logits = cap * jnp.tanh(logits / cap)
-    return logits
+    with jax.named_scope("head.unembed"):
+        h = _norm(spec, hidden, params["lnf_scale"], params.get("lnf_bias"))
+        w = params["tok_emb"].T if spec.tie_embeddings else params["lm_head"]
+        if isinstance(w, QuantizedTensor):
+            logits = matmul_any("...d,dv->...v", h.astype(jnp.float32), w)
+            if logits.shape[-1] != spec.vocab_size:
+                logits = logits[..., : spec.vocab_size]
+        else:
+            # keep the [D, V] projection in its storage dtype (bf16: half
+            # the HBM read of an fp32 upcast — this matmul streams the
+            # largest single weight every decode step) and accumulate in
+            # fp32 on the MXU
+            logits = jnp.einsum("...d,dv->...v", h.astype(w.dtype), w,
+                                preferred_element_type=jnp.float32)
+        if spec.logit_softcap:
+            cap = spec.logit_softcap
+            logits = cap * jnp.tanh(logits / cap)
+        return logits
 
 
 # ------------------------------------------------------------------ prefill

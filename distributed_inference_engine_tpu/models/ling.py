@@ -179,9 +179,10 @@ def _init_layer(spec: ModelSpec, kind: str, mlp: str, layer_id: int,
     return out
 
 
-@partial(jax.jit, static_argnums=(0, 1))
-def _init_table(shape: Tuple[int, ...], dtype: str, key) -> jnp.ndarray:
-    return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+@partial(jax.jit, static_argnums=(0, 1, 3))
+def _init_table(shape: Tuple[int, ...], dtype: str, key,
+                std: float = 0.02) -> jnp.ndarray:
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
 
 def init_params(spec: ModelSpec, key: jax.Array) -> Params:
@@ -223,9 +224,12 @@ def zero_state_slot(state: State, slot: jnp.ndarray) -> State:
     return {n: a.at[:, slot].set(0) for n, a in state.items()}
 
 
-def gather_context_rows(pages: jnp.ndarray, page_table: jnp.ndarray
-                        ) -> jnp.ndarray:
-    """pages [Lm, N, P, W], table [B, MP] -> [Lm, B, MP * P, W]."""
+def decode_context(pages: jnp.ndarray, page_table: jnp.ndarray,
+                   attn_impl: str) -> jnp.ndarray:
+    """What a decode chunk's steps read the cached rows from. Latent rows
+    are gathered once a chunk (``attn_impl`` is "xla" for them): pages
+    [Lm, N, P, W], table [B, MP] -> [Lm, B, MP * P, W]."""
+    del attn_impl
     lm, _n, p, w = pages.shape
     b, mp = page_table.shape
     with jax.named_scope("attn.kv_gather"):

@@ -48,12 +48,12 @@ from .base import ModelSpec, embed
 from .ling import (  # the latent pool's views are the same code
     _init_table,
     _proj,
-    gather_context_rows,
+    decode_context,
     write_rows_into_pages,
 )
 
 __all__ = ["xing_spec", "init_params", "init_state", "zero_state_slot",
-           "gather_context_rows", "write_rows_into_pages",
+           "decode_context", "write_rows_into_pages",
            "forward_prefill_into_pages", "forward_decode_step"]
 
 Params = Dict[str, Any]

@@ -75,6 +75,39 @@ def causal_attention(
     return out.reshape(b, t, h, dh)
 
 
+def causal_attention_blocked(
+    q: jnp.ndarray,          # [B, T, H, Dh]
+    k: jnp.ndarray,          # [B, T, H, Dh]
+    v: jnp.ndarray,          # [B, T, H, Dh]
+    seq_lens: jnp.ndarray,   # [B]
+    q_block: int = 512,
+) -> jnp.ndarray:
+    """``causal_attention`` for one K/V head a query head (MHA) in query
+    blocks of ``q_block`` rows (one block where ``T`` is no whole number of
+    them), unrolled, each reading only the keys up to its own last row: no
+    [T, T] score tensor (30 heads at 4,096 positions: 2 GB in float32).
+    Returns [B, T, H, Dh]; rows past ``seq_lens`` are not specified."""
+    t, dh = q.shape[1], q.shape[-1]
+    qb = q_block if t % q_block == 0 else t
+    key_ok = jnp.arange(t)[None, :] < seq_lens[:, None]          # [B, T]
+    scale = dh ** -0.5
+
+    def block(i0):
+        n_keys = i0 + qb
+        s = jnp.einsum("bihd,bjhd->bhij", q[:, i0:n_keys], k[:, :n_keys],
+                       preferred_element_type=jnp.float32) * scale
+        rows = i0 + jnp.arange(qb)[:, None]
+        mask = (jnp.arange(n_keys)[None, :] <= rows)[None] \
+            & key_ok[:, None, :n_keys]
+        s = jnp.where(mask[:, None], s, NEG_INF)
+        p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+        p = p / p.sum(axis=-1, keepdims=True)
+        return jnp.einsum("bhij,bjhd->bihd", p.astype(v.dtype),
+                          v[:, :n_keys])
+
+    return jnp.concatenate([block(i0) for i0 in range(0, t, qb)], axis=1)
+
+
 def suffix_attention(
     q: jnp.ndarray,            # [B, Ts, H, Dh] suffix queries
     k_ctx: jnp.ndarray,        # [B, Tc, Hkv, Dh] cached-context keys (padded)

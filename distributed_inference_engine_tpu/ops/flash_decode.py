@@ -213,7 +213,7 @@ def _prefix_loop(
     b, page_table_ref, prefix_lens_ref, next_live_ref, layer_ref,
     buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
     v_vmem, sem, m_scr, l_scr, acc_scr,
-    *, bp, page_size, n_pages_per_layer, scale,
+    *, bp, page_size, n_pages_per_layer, scale, kv_lanes=0, copied_ref=None,
 ):
     """Flash loop over row ``b``'s live prefix pages: ``bp`` pages per
     block, double-buffered manual DMA, next block (possibly the first
@@ -231,17 +231,31 @@ def _prefix_loop(
     for, or their unconsumed copies leave the semaphore unbalanced. The
     scan is precomputed in the launcher (a suffix-min over live rows) —
     an in-kernel while_loop over the lengths ref also defeats the
-    interpret-mode state discharge the parity tests run under."""
+    interpret-mode state discharge the parity tests run under.
+
+    ``copied_ref`` (SMEM, [1]) counts every page whose copy is started, by
+    any row's turn: what the kernel moved, said by the kernel. (Under the
+    interpreter ``step_ref`` starts every grid step at 0 again, so a later
+    row's first block is issued, and counted, a second time by its own
+    turn; on the chip each live page is counted once:
+    ``scripts/chip_kernels.py`` ``flash_decode_kv_fused`` holds that.)"""
     batch = pl.num_programs(0)
     blk_tokens = bp * page_size
     base = layer_ref[0] * n_pages_per_layer
 
     def copies(row, blk, slot, j):
         page = base + page_table_ref[row, blk * bp + j]
-        return (pltpu.make_async_copy(k_pages_hbm.at[page],
-                                      k_vmem.at[slot, j], sem.at[slot]),
-                pltpu.make_async_copy(v_pages_hbm.at[page],
-                                      v_vmem.at[slot, j], sem.at[slot]))
+        if kv_lanes:
+            # ONE pool of K|V rows (both refs are it): a page's K is its
+            # first ``kv_lanes`` lanes, its V the rest
+            k_src = k_pages_hbm.at[page, :, pl.ds(0, kv_lanes)]
+            v_src = v_pages_hbm.at[page, :, pl.ds(kv_lanes, kv_lanes)]
+        else:
+            k_src, v_src = k_pages_hbm.at[page], v_pages_hbm.at[page]
+        return (pltpu.make_async_copy(k_src, k_vmem.at[slot, j],
+                                      sem.at[slot]),
+                pltpu.make_async_copy(v_src, v_vmem.at[slot, j],
+                                      sem.at[slot]))
 
     def for_live_pages(row, blk, fn):
         n_live = lax.div(prefix_lens_ref[row] + page_size - 1, page_size)
@@ -252,6 +266,8 @@ def _prefix_loop(
         def go(j):
             for c in copies(row, blk, slot, j):
                 c.start()
+            if copied_ref is not None:
+                copied_ref[0] = copied_ref[0] + 1
         for_live_pages(row, blk, go)
 
     length = prefix_lens_ref[b]
@@ -307,24 +323,32 @@ def _flash_decode_kernel(
     v_pages_hbm,
     # outputs
     out_ref,                   # [1, Hp, Dh] VMEM
-    # scratch
-    k_vmem,                    # [2, bp, P, Hkv*Dh] double-buffered blocks
-    v_vmem,
-    m_scr,                     # [Hp, 1] f32 running max
-    l_scr,                     # [Hp, 1] f32 running denominator
-    acc_scr,                   # [Hp, Hkv*Dh] f32 running numerator
-    sem,                       # DMA semaphores, one per buffer slot
-    *,
+    # then, with ``count_pages``, copied_ref [1] SMEM: pages copied so far;
+    # then the scratch:
+    #   k_vmem, v_vmem         [2, bp, P, Hkv*Dh] double-buffered blocks
+    #   m_scr, l_scr           [Hp, 1] f32 running max / denominator
+    #   acc_scr                [Hp, Hkv*Dh] f32 running numerator
+    #   sem                    DMA semaphores, one per buffer slot
+    *rest,
     n_kv_heads: int,
     head_dim: int,
     page_size: int,
     n_heads: int,
     pages_per_block: int,
     n_pages_per_layer: int,
+    kv_lanes: int = 0,
+    count_pages: bool = False,
 ):
+    copied_ref = rest[0] if count_pages else None
+    k_vmem, v_vmem, m_scr, l_scr, acc_scr, sem = rest[int(count_pages):]
     b = pl.program_id(0)
     dh, g = head_dim, n_heads // n_kv_heads
     scale = 1.0 / (dh ** 0.5)
+
+    if count_pages:
+        @pl.when(b == 0)
+        def _zero():
+            copied_ref[0] = 0
 
     _init_acc(m_scr, l_scr, acc_scr)
     qbd = _block_diag_q(q_ref[0], n_heads, n_kv_heads)        # [Hp, F]
@@ -334,7 +358,8 @@ def _flash_decode_kernel(
         buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
         v_vmem, sem, m_scr, l_scr, acc_scr,
         bp=pages_per_block, page_size=page_size,
-        n_pages_per_layer=n_pages_per_layer, scale=scale)
+        n_pages_per_layer=n_pages_per_layer, scale=scale,
+        kv_lanes=kv_lanes, copied_ref=copied_ref)
 
     # final block: the chunk side window (auto-pipelined into VMEM — its
     # DMA overlaps the previous grid step's compute)
@@ -351,9 +376,12 @@ def _flash_decode_kernel(
 # ------------------------------------------------------------- launchers
 
 
-def _validate(q, k_pages, v_pages, page_table, n_kv_heads):
+def _validate(q, k_pages, v_pages, page_table, n_kv_heads, kv_fused=False):
     b, h, dh = q.shape
-    fused = k_pages.shape[-1]
+    if kv_fused and v_pages is not k_pages:
+        raise ValueError("kv_fused: k_pages and v_pages are ONE pool of K|V "
+                         "rows, passed twice")
+    fused = k_pages.shape[-1] // (2 if kv_fused else 1)
     if fused != n_kv_heads * dh:
         raise ValueError(
             f"fused dim {fused} != n_kv_heads*head_dim {n_kv_heads * dh}")
@@ -443,11 +471,22 @@ def flash_decode_attention_pallas(
     layer=None,
     n_pages_per_layer: int = 0,
     pages_per_block: int = 0,
-) -> jnp.ndarray:
-    """Fused attention, side writes stay with the caller. [B, H, Dh]."""
-    _validate(q, k_pages, v_pages, page_table, n_kv_heads)
+    kv_fused: bool = False,
+    count_pages: bool = False,
+):
+    """Fused attention, side writes stay with the caller. [B, H, Dh].
+    ``kv_fused``: ``k_pages`` and ``v_pages`` are ONE pool whose rows are
+    K|V side by side (``[.., P, 2 * fused]``, a per-layer family's); the
+    kernel copies each half of a page's lanes where it lies.
+    ``count_pages``: returns ``(out, pages)``, ``pages`` the int32 number
+    of pool pages (a K and a V copy each) the kernel started a copy of,
+    counted by the kernel as it starts them; the side window's ``B * W``
+    rows come in besides, every call."""
+    _validate(q, k_pages, v_pages, page_table, n_kv_heads, kv_fused)
     b, h, dh = q.shape
     n, page_size, fused = k_pages.shape
+    if kv_fused:
+        fused //= 2
     mp = page_table.shape[1]
     w = side_k.shape[1]
     bp = pages_per_block or _default_pages_per_block(page_size, fused, mp)
@@ -467,7 +506,10 @@ def flash_decode_attention_pallas(
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
+        out_specs=(
+            [pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
+             pl.BlockSpec(memory_space=pltpu.SMEM)] if count_pages
+            else pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0))),
         scratch_shapes=kv_scratch + acc_scratch + [
             pltpu.SemaphoreType.DMA((2,))],
     )
@@ -475,11 +517,15 @@ def flash_decode_attention_pallas(
         _flash_decode_kernel,
         n_kv_heads=n_kv_heads, head_dim=dh, page_size=page_size,
         n_heads=h, pages_per_block=bp,
-        n_pages_per_layer=n_pages_per_layer or n)
+        n_pages_per_layer=n_pages_per_layer or n,
+        kv_lanes=fused if kv_fused else 0, count_pages=count_pages)
+    out_shape = jax.ShapeDtypeStruct((b, hp, dh), q.dtype)
+    if count_pages:
+        out_shape = [out_shape, jax.ShapeDtypeStruct((1,), jnp.int32)]
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hp, dh), q.dtype),
+        out_shape=out_shape,
         compiler_params=_compiler_params(bp, page_size, fused,
                                          k_pages.dtype.itemsize),
         cost_estimate=_cost(b, h, dh, mp, page_size, w, fused,
@@ -491,6 +537,8 @@ def flash_decode_attention_pallas(
       jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
       qp, side_k.reshape(b, w, fused), side_v.reshape(b, w, fused),
       k_pages, v_pages)
+    if count_pages:
+        return out[0][:, :h], out[1][0]
     return out[:, :h]
 
 

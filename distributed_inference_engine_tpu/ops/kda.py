@@ -1,8 +1,16 @@
-"""Kimi Delta Attention (KDA): a gated delta rule with one decay per head
-AND key channel, its state ``S [dk, dv]`` per head in float32.
+"""The gated delta rule, its state ``S [dk, dv]`` per head in float32:
 
     S_t = (I - b_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + b_t k_t v_t^T
     o_t = S_t^T q_t
+
+Two gates run through the SAME functions, told apart by the last axis of
+``g``: Kimi Delta Attention (KDA, ``models/ling.py``) has one decay per
+head AND key channel (``g [..., H, dk]``, ``kda_gate``); Gated DeltaNet
+(``models/olmo_hybrid.py``) one per head (``g [..., H, 1]``, ``gdn_gate``),
+which broadcasts over the key channels wherever the per-channel one
+multiplies. ``dk`` and ``dv`` need not be equal (Gated DeltaNet: 96 keys
+against 192 values) and ``b`` may lie anywhere in (0, 2): the transition's
+eigenvalue along ``k`` is ``1 - b``, down to -1.
 
 Three forms of the same recurrence:
 
@@ -23,7 +31,14 @@ sub-block of ``sub`` = 16 rows is re-based on the running sum at its first
 row R: ``exp(G_i - R) <= 1`` for the rows of the block, ``exp(R - G_j) <= 1``
 for every earlier column, and inside the block ``exp(R - G_j) <= e^75``,
 finite in float32. Columns after a row are masked (their clamped factor is
-finite too).
+finite too). That bound is KDA's (``kda_gate``'s ``lower_bound``); Gated
+DeltaNet's gate ``-exp(A_log) softplus(.)`` has none, and a head that decays
+by e^-10 a token overruns the clamp inside a sub-block: broadcast over the
+key channels through this construction it is WRONG (``tests/test_gdn.py``:
+outputs off by 0.9), not slow. So ONE decay a head takes the construction it
+allows: ``A_ij = (k_i . k_j) exp(G_i - G_j)`` is a plain matrix product
+times a [C, C] table whose exponents are <= 0 under the diagonal, exact for
+any decay.
 """
 
 from __future__ import annotations
@@ -48,14 +63,24 @@ def kda_gate(a: jnp.ndarray, a_log: jnp.ndarray, dt_bias: jnp.ndarray,
     return lower_bound * jax.nn.sigmoid(z)
 
 
+def gdn_gate(a: jnp.ndarray, a_log: jnp.ndarray, dt_bias: jnp.ndarray
+             ) -> jnp.ndarray:
+    """Log-decay ``g = -exp(A_log_h) * softplus(a + dt_bias_h)`` in
+    (-inf, 0), one a head: a [..., H] -> g [..., H, 1], float32."""
+    z = a.astype(jnp.float32) + dt_bias.astype(jnp.float32)
+    return (-jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(z))[
+        ..., None]
+
+
 def l2_normalize(x: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     x = x.astype(jnp.float32)
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
 
 
 def kda_step(S, q, k, v, g, beta) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """One token. S [..., dk, dv]; q, k, g [..., dk]; v [..., dv];
-    beta [...]; all float32. Returns (o [..., dv], S)."""
+    """One token. S [..., dk, dv]; q, k [..., dk]; g [..., dk] or
+    [..., 1]; v [..., dv]; beta [...]; all float32. Returns (o [..., dv],
+    S)."""
     S = S * jnp.exp(g)[..., None]
     k_s = jnp.sum(k[..., None] * S, axis=-2)                   # k^T S
     S = S + k[..., None] * (beta[..., None] * (v - k_s))[..., None, :]
@@ -103,7 +128,8 @@ def _unit_lower_solve(L: jnp.ndarray, beta: jnp.ndarray) -> jnp.ndarray:
 
 
 def kda_chunked(q, k, v, g, beta, S0=None, chunk: int = 64, sub: int = 16):
-    """The chunked form over [B, T, H, d] float32 inputs. T is padded here
+    """The chunked form over [B, T, H, d] float32 inputs (g [B, T, H, dk]
+    or [B, T, H, 1], beta [B, T, H]). T is padded here
     to a multiple of ``chunk`` with beta = 0, g = 0 rows, which leave the
     state as it was; a caller masks its own pad rows the same way.
     Returns (o [B, T, H, dv], S [B, H, dk, dv])."""
@@ -125,19 +151,28 @@ def kda_chunked(q, k, v, g, beta, S0=None, chunk: int = 64, sub: int = 16):
     nc, nb = q.shape[2], chunk // sub
     # exact float32 adds: a running sum lowered to a reduced-precision
     # matmul would be off by e^0.1 in the decays it feeds
-    G = lax.associative_scan(jnp.add, g, axis=3)               # [B,H,NC,C,dk]
-    Gb = G.reshape(b, h, nc, nb, sub, dk)
-    R = Gb[..., 0, :]                                          # [B,H,NC,nb,dk]
-    scale = jnp.exp(Gb - R[..., None, :])                      # <= 1
-    left_k = k.reshape(Gb.shape) * scale
-    left_q = q.reshape(Gb.shape) * scale
-    # column factor of block I's rows: k_j exp(R_I - G_j) for every j
-    right = k[:, :, :, None] * jnp.exp(jnp.minimum(
-        R[..., :, None, :] - G[:, :, :, None, :, :], 80.0))    # [..,nb,C,dk]
-    A = _mm("bhnisc,bhnijc->bhnisj", left_k, right).reshape(
-        b, h, nc, chunk, chunk)
-    Bm = _mm("bhnisc,bhnijc->bhnisj", left_q, right).reshape(
-        b, h, nc, chunk, chunk)
+    G = lax.associative_scan(jnp.add, g, axis=3)     # [B,H,NC,C,dk or 1]
+    if g.shape[-1] == 1:
+        # one decay a head, unbounded below (the re-based form would overrun
+        # its clamp): exp(G_i - G_j) is a [C, C] table, its exponents clamped
+        # at 0 (those above are columns after the row, masked below)
+        Gs = G[..., 0]
+        table = jnp.exp(jnp.minimum(Gs[..., :, None] - Gs[..., None, :], 0.0))
+        A = _mm("bhnic,bhnjc->bhnij", k, k) * table
+        Bm = _mm("bhnic,bhnjc->bhnij", q, k) * table
+    else:
+        Gb = G.reshape(b, h, nc, nb, sub, dk)
+        R = Gb[..., 0, :]                                      # [B,H,NC,nb,dk]
+        scale = jnp.exp(Gb - R[..., None, :])                  # <= 1
+        left_k = k.reshape(Gb.shape) * scale
+        left_q = q.reshape(Gb.shape) * scale
+        # column factor of block I's rows: k_j exp(R_I - G_j) for every j
+        right = k[:, :, :, None] * jnp.exp(jnp.minimum(
+            R[..., :, None, :] - G[:, :, :, None, :, :], 80.0))  # [..,nb,C,dk]
+        A = _mm("bhnisc,bhnijc->bhnisj", left_k, right).reshape(
+            b, h, nc, chunk, chunk)
+        Bm = _mm("bhnisc,bhnijc->bhnisj", left_q, right).reshape(
+            b, h, nc, chunk, chunk)
     row = jnp.arange(chunk)[:, None]
     col = jnp.arange(chunk)[None, :]
     A = jnp.where(col < row, A, 0.0)
@@ -149,7 +184,7 @@ def kda_chunked(q, k, v, g, beta, S0=None, chunk: int = 64, sub: int = 16):
     Qt = q * eG
     G_end = G[:, :, :, -1:, :]
     Kh = k * jnp.exp(G_end - G)
-    decay = jnp.exp(G_end[:, :, :, 0, :])                      # [B,H,NC,dk]
+    decay = jnp.exp(G_end[:, :, :, 0, :])                 # [B,H,NC,dk or 1]
     if S0 is None:
         S0 = jnp.zeros((b, h, dk, dv), f32)
 

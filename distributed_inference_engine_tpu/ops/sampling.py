@@ -167,8 +167,9 @@ def sample_tokens_with_logprobs(
     """``sample_tokens`` plus the chosen token's UNTEMPERED log-probability
     ([B] fp32) — the quantity scoring/confidence APIs report (log p under
     the model, independent of the sampling knobs used to pick the token)."""
-    toks = sample_tokens(logits, params, key)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    chosen = jnp.take_along_axis(logp, toks[:, None].astype(jnp.int32),
-                                 axis=-1)[:, 0]
-    return toks, chosen
+    with jax.named_scope("sample"):
+        toks = sample_tokens(logits, params, key)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        chosen = jnp.take_along_axis(logp, toks[:, None].astype(jnp.int32),
+                                     axis=-1)[:, 0]
+        return toks, chosen

@@ -5,7 +5,10 @@ the mistral-7b serving shapes, each against its XLA path.
     python -m scripts.chip_kernels --tiny     # CPU debug: interpreted, tiny
 
 Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; the latent
-prefill at 32 heads of 128 | 64 | 128, T 1,024 and 8,192; the five int4
+prefill at 32 heads of 128 | 64 | 128, T 1,024 and 8,192; the K|V-row read
+of a per-layer family (``fused``: heads, pages a row, side window, stacked
+layers: 30 MHA heads of 128, 8 rows of up to 6,144 positions, timed alone);
+the five int4
 payload shapes of mistral-7b (N=32,768 for the lm_head) at M=128 (the
 prefill bucket of ``ops.int4_matmul.blocks_for``) and, for the 2-D and
 stacked legs, at M=8 (the decode bucket: what a served decode step runs). Tolerances are the ones the CPU parity tests use
@@ -34,11 +37,13 @@ FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
             int4_shapes=((2048, 6144), (2048, 4096), (2048, 28672),
                          (7168, 4096), (2048, 32768)),
             mla_dims=(128, 64, 128),
-            mla_cases=((1024, 48), (1024, 1024), (8192, 48), (8192, 8192)))
+            mla_cases=((1024, 48), (1024, 1024), (8192, 48), (8192, 8192)),
+            fused=(30, 48, 16, 4))
 TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
             int4_rows=(32, 3),
             int4_shapes=((128, 256), (256, 128)),
-            mla_dims=(16, 8, 16), mla_cases=((1024, 48), (1024, 1024)))
+            mla_dims=(16, 8, 16), mla_cases=((1024, 48), (1024, 1024)),
+            fused=(2, 6, 4, 3))
 OUT = os.path.join("chiprun_out", "chip_kernels.json")
 
 
@@ -232,6 +237,89 @@ def check_flash_decode_served(cfg, interpret):
     return f"4 block sizes, max|err| {max(errs):.2e}"
 
 
+def check_flash_decode_kv_fused(cfg, interpret):
+    """The per-layer family's read: ONE pool of K|V rows, 4 layers stacked
+    (``kv_fused``), MHA (full: 30 heads of 128, 48 pages a row, 8 rows at
+    the cell's contexts of 1,300-2,100 and then at 3,800-6,128, one dead row
+    each time), layer 2, a side window of 16: against the XLA form on that
+    layer's halves, the kernel's own page count against the lengths, and,
+    on the chip, its time alone: a scan of calls, each fed the one before,
+    on the host's clock."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        flash_decode_attention_pallas,
+        flash_decode_attention_xla,
+    )
+
+    p, (h, mp, w, layers) = cfg["P"], cfg["fused"]
+    dh, lanes = cfg["Dh"], cfg["fused"][0] * cfg["Dh"]
+    shape = dict(cfg, B=8, H=h, Hkv=h, ctx=mp * p, W=w)
+    q, kp, vp, pt, ks, n = _paged_inputs(shape)
+    sk, sv, _ = _side_inputs(shape, ks)
+    layer = layers - 2
+    pool = jnp.zeros((layers * n, p, 2 * lanes), kp.dtype).at[
+        layer * n:(layer + 1) * n].set(jnp.concatenate([kp, vp], -1))
+    def call(q, pool, *rest):
+        # the pool (3 GB at the full size) is an argument, never a constant
+        return flash_decode_attention_pallas(
+            q, pool, pool, *rest, n_kv_heads=h,
+            interpret=interpret, layer=layer, n_pages_per_layer=n,
+            kv_fused=True, count_pages=True)
+
+    n_calls = 256
+
+    @jax.jit
+    def many(q, *args):
+        def body(q, _):
+            out, _pages = call(q, *args)
+            return q + (out * 1e-3).astype(q.dtype), None
+        return jax.lax.scan(body, q, None, length=n_calls)[0]
+
+    details = []
+    for name, shares in (
+            ("cell", (0.22, 0.30, 0.0, 0.34, 0.25, 0.28, 0.21, 0.32)),
+            ("long", (0.62, 0.81, 0.0, 1.0, 0.7, 0.9, 0.65, 0.75))):
+        plen = (jnp.array(shares) * (mp * p - w)).astype(jnp.int32)
+        n_side = jnp.where(plen > 0,
+                           jnp.array([1, 5, 0, w, 9, 2, 16, 3]) % (w + 1),
+                           0).astype(jnp.int32)
+        ref = flash_decode_attention_xla(q, kp, vp, pt, plen, sk, sv, n_side,
+                                         n_kv_heads=h)
+        args = (pool, pt, plen, sk, sv, n_side)
+        got, pages = jax.jit(call)(q, *args)
+        err = _close(got, ref, 2e-2)
+        live_pages = int(jnp.sum(-(-plen // p)))
+        # under the interpreter the kernel's mutable scalars start every
+        # grid step anew, so each later row's first block is issued (and
+        # counted) again by its own turn; the chip's count is exact
+        first_again = int(jnp.sum(jnp.minimum(-(-plen[1:] // p), 6))) \
+            if interpret else 0
+        detail = (f"{name}: max|err| {err:.2e}, {int(pages)} pages copied, "
+                  f"{live_pages} live")
+        if not interpret:
+            many(q, *args).block_until_ready()
+            best = float("inf")
+            for _ in range(5):
+                t0 = time.perf_counter()
+                many(q, *args).block_until_ready()
+                best = min(best, time.perf_counter() - t0)
+            row = 2 * lanes * kp.dtype.itemsize
+            live = row * int(jnp.sum(plen + n_side))
+            moved = row * (live_pages * p + 8 * w)
+            detail += (
+                f", {1e6 * best / n_calls:.1f} us a call, live rows "
+                f"{live / 1e6:.1f} MB = {live * n_calls / best / 1e9:.1f} "
+                f"GB/s, copied {moved / 1e6:.1f} MB = "
+                f"{moved * n_calls / best / 1e9:.1f} GB/s")
+        details.append(detail)
+        assert live_pages <= int(pages) <= live_pages + first_again, (
+            "the kernel's count of page copies is not the lengths': "
+            + "; ".join(details))
+    return "; ".join(details)
+
+
 def check_mla_prefill(cfg, interpret):
     """The latent-attention prefill kernel at the published head shape (32
     heads of 128 | 64 | 128) against the XLA body: the smallest and the
@@ -266,6 +354,7 @@ CHECKS = {
     "int4_matmul_cp": (check_int4_cp, True),
     "flash_decode": (check_flash_decode, True),
     "flash_decode_served": (check_flash_decode_served, True),
+    "flash_decode_kv_fused": (check_flash_decode_kv_fused, True),
     "mla_prefill": (check_mla_prefill, True),
 }
 

@@ -64,9 +64,10 @@ class Served:
     pages and the per-slot state, collecting every position's logits."""
 
     def __init__(self, spec, params, slots=4, page=16, pages=32,
-                 state_dtype=None, family=ling):
+                 state_dtype=None, family=ling, attn_impl="xla"):
         self.spec, self.params, self.page = spec, params, page
         self.family = family       # the per-layer family's module
+        self.attn_impl = attn_impl
         self.kv = PagedKVCache(spec, max_slots=slots, page_size=page,
                                num_pages=pages, max_seq_len=128,
                                dtype=spec.dtype)
@@ -100,8 +101,10 @@ class Served:
         logits after each fed token."""
         b = self.kv.max_slots
         out = {s: [] for s in feeds}
-        step = jax.jit(lambda *a: self.family.forward_decode_step(
-            self.spec, self.params, *a))
+        step = jax.jit(lambda kp, table, tok, cur, start, *a:
+                       self.family.forward_decode_step(
+            self.spec, self.params, tok, cur, start,
+            self.family.decode_context(kp, table, self.attn_impl), *a))
         pos = dict(lengths)
         fed = {s: 0 for s in feeds}
         while any(fed[s] < len(feeds[s]) for s in feeds):
@@ -111,8 +114,6 @@ class Served:
             start = np.zeros((b,), np.int32)
             for s in feeds:
                 start[s] = pos[s]
-            ctx = self.family.gather_context_rows(self.kv.k_pages,
-                                           self.kv.page_table)
             side = jnp.zeros((self.spec.paged_layers, b, n_steps,
                               self.spec.cache_row_width), self.kv.dtype)
             state = self.kv.state
@@ -124,8 +125,9 @@ class Served:
                     if fed[s] < len(feeds[s]):
                         tok[s], act[s] = feeds[s][fed[s]], True
                 hidden, side, state, moe = step(
+                    self.kv.k_pages, self.kv.page_table,
                     jnp.asarray(tok), jnp.asarray(cur), jnp.asarray(start),
-                    ctx, side, state, jnp.asarray(act))
+                    side, state, jnp.asarray(act))
                 if self.state_dtype is not None:
                     state = dict(state, S=state["S"].astype(
                         self.state_dtype).astype(jnp.float32))

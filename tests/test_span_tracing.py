@@ -367,6 +367,12 @@ async def test_profile_rpc_anchors_the_ring_to_the_trace(tmp_path):
     ring = {e["name"] for e in doc["traceEvents"]}
     assert {"engine.admit", "engine.decode.dispatch",
             "engine.harvest.wait"} <= ring
+    # the engine's counters as they stood at the slice's two ends
+    with open(f"{trace_dir}/counters.json") as f:
+        stamped = json.load(f)
+    steps = [stamped[at]["models"]["m"]["decode_steps"]
+             for at in ("start", "stop")]
+    assert steps[0] == 0 and steps[1] >= 4
     # the same spans and anchors are in the profiler's trace, with no
     # Python-tracer event beside them
     from jax.profiler import ProfileData

@@ -109,3 +109,96 @@ def test_mla_prefill_compiles_for_v5e(one_chip, b, t):
     assert "mla_prefill_flash" in text
     # no score tensor in HBM: nothing float32 as large as [32, 512, t]
     assert f"f32[{b},32,512," not in text and f"f32[32,512,{t}]" not in text
+
+
+# the in-place decode kernel over ONE pool of K|V rows (``kv_fused``: each
+# half of a page's lanes copied where it lies), at the Gated-DeltaNet
+# family's served shape (30 K/V heads of 128, 48 pages a row, chunks of 16)
+# and at its chunk cut to one step
+@pytest.mark.parametrize("w", [16, 1])
+def test_flash_decode_over_a_fused_kv_pool_compiles_for_v5e(one_chip, w):
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        flash_decode_attention_pallas)
+
+    b, h, dh, mp, layers, n, p = 8, 30, 128, 48, 4, 384, 128
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pool, pt, plen, sk, sv, n_side, layer):
+        return flash_decode_attention_pallas(
+            q, pool, pool, pt, plen, sk, sv, n_side, n_kv_heads=h,
+            layer=layer, n_pages_per_layer=n, kv_fused=True)
+
+    compiled = jax.jit(fn).lower(
+        sds((b, h, dh)), sds((layers * n, p, 2 * h * dh)),
+        sds((b, mp), jnp.int32), sds((b,), jnp.int32), sds((b, w, h, dh)),
+        sds((b, w, h, dh)), sds((b,), jnp.int32),
+        sds((), jnp.int32)).compile()
+    assert "flash_decode_custom_call" in compiled.as_text()
+
+
+def test_no_program_of_the_gdn_family_holds_a_copy_of_its_kv_table(one_chip):
+    """The Gated-DeltaNet family's decode chunk and its 4,096-token prefill
+    at the served size, compiled for the v5e: the decode steps read the
+    3.02 GB pool through the kernel where it lies, both programs write it
+    where it lies, and neither holds a gathered ``[L, B, S, 7680]`` (or one
+    layer's ``[B, S, 7680]``) context, nor temporaries as large as the pool
+    (a copy of it would add 3.02 GB to the prefill's 1.5 GB of activations)."""
+    from distributed_inference_engine_tpu.models import olmo_hybrid as fam
+    from distributed_inference_engine_tpu.models.base import unembed
+
+    spec = fam.olmo_hybrid_spec("olmo-hybrid-7b-pp2", max_seq_len=6144)
+    slots, n_pages, page, mp, steps = 8, 384, 128, 48, 16
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def arr(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_params(spec, jax.random.key(0))))
+    state = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_state(spec, slots)))
+    pool = arr(spec.paged_layers, n_pages, page, spec.cache_row_width,
+               dtype=jnp.bfloat16)
+    pool_bytes = 4 * n_pages * page * 7680 * 2
+
+    def decode(params, pages, state, lengths, last, active, table):
+        ctx = fam.decode_context(pages, table, "pallas-decode")
+        side = jnp.zeros((spec.paged_layers, slots, steps,
+                          spec.cache_row_width), pages.dtype)
+
+        def step(carry, _):
+            side, state, now, last = carry
+            hidden, side, state, _m = fam.forward_decode_step(
+                spec, params, last, now, lengths, ctx, side, state, active)
+            tok = jnp.argmax(unembed(spec, params, hidden), -1)
+            return (side, state, now + 1, tok.astype(jnp.int32)), tok
+
+        (side, state, now, last), toks = jax.lax.scan(
+            step, (side, state, lengths, last), None, length=steps)
+        pages = fam.write_rows_into_pages(pages, side, table, now - lengths,
+                                          lengths)
+        return pages, state, toks
+
+    def prefill(params, tokens, lens, pages, state, table, slot_ids):
+        hidden, pages, state, _ = fam.forward_prefill_into_pages(
+            spec, params, tokens, lens, pages, state, table, slot_ids)
+        return hidden[:, -1], pages, state
+
+    programs = (
+        jax.jit(decode, donate_argnums=(1, 2)).lower(
+            params, pool, state, arr(slots), arr(slots),
+            arr(slots, dtype=jnp.bool_), arr(slots, mp)),
+        jax.jit(prefill, donate_argnums=(3, 4)).lower(
+            params, arr(1, 4096), arr(1), pool, state, arr(1, mp), arr(1)))
+    for lowered in programs:
+        compiled = lowered.compile()
+        text = compiled.as_text()
+        for shape in ("[4,8,6144,7680]", "[8,6144,7680]", "[8,48,128,7680]",
+                      "[4,8,48,128,7680]"):
+            assert shape not in text, shape
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * pool_bytes
+    assert "flash_decode_custom_call" in programs[0].compile().as_text()
