@@ -243,7 +243,10 @@ def _engine_placement(engine) -> Dict[str, Any]:
             # the (bk, bn) each kernel-borne int4 shape streams in
             "int4_blocks": int4_kernel_blocks(params),
             # the attention path the engine resolved "auto" to
-            "decode_attention": getattr(engine, "attn_impl", None)}
+            "decode_attention": getattr(engine, "attn_impl", None),
+            # the body that moves a recurrent family's per-slot state in a
+            # decode step ("inplace": the kernel; None: no such state)
+            "state_step_body": getattr(engine, "state_step_body", None)}
 
 
 # --------------------------------------------------------------------------

@@ -53,6 +53,7 @@ from ..models.base import (
     unembed,
     write_prefill_pages,
 )
+from ..ops import kda
 from ..ops.mla import prefill_key_blocks
 from ..ops.sampling import (
     SamplingParams,
@@ -456,6 +457,9 @@ class ContinuousEngine:
         self._full_context_rows = 0
         self._full_table_rows = 0
         self._state_rows_updated = 0
+        # the body that moves a recurrent state in a decode step, as the
+        # family's programs will pick it when they are traced (ops/kda.py)
+        self.state_step_body = kda.step_impl() if self._recurrent else None
         # key blocks the admitted prompts' prefills visited / blocks of
         # their buckets' whole squares (ops/mla.py), per paged layer
         self._mla_prefill_visited = 0
@@ -2846,11 +2850,12 @@ class ContinuousEngine:
             # per-layer specs whose paged layers keep K|V rows: rows the
             # decode steps attended to (per paged layer) and rows the body
             # read for them; recurrent specs: (row, step) pairs that moved
-            # a state (per recurrent layer)
+            # a state (per recurrent layer) and the body that moved them
             **({"attn": {"full_context_rows": self._full_context_rows,
                          "full_table_rows": self._full_table_rows}}
                if self._kv_rows else {}),
-            **({"state": {"rows_updated": self._state_rows_updated}}
+            **({"state": {"rows_updated": self._state_rows_updated,
+                          "step_body": self.state_step_body}}
                if self._recurrent else {}),
             "moe": {
                 "assignments_held": int(self._moe_counts[0]),

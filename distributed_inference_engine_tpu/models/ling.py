@@ -303,20 +303,24 @@ def kda_layer_prefill(spec: ModelSpec, blk: Params, x, seq_lens):
         return _kda_out(spec, blk, o, gate, x.dtype), S, tail
 
 
-def kda_layer_step(spec: ModelSpec, blk: Params, x, S, tail, active):
-    """x [B, D], S [B, H, dk, dk], tail [B, conv-1, C]: one token. Rows not
-    ``active`` give back their S and tail untouched."""
+def kda_layer_step(spec: ModelSpec, blk: Params, x, S_all, layer, tail,
+                   active):
+    """x [B, D]; S_all [layers, B, H, dk, dk], every KDA layer's state as
+    the engine keeps it, of which this layer's is moved where it lies; tail
+    [B, conv-1, C]: one token. Rows not ``active`` keep their S and tail
+    untouched."""
     with jax.named_scope("attn.kda.step"):
         h = rms_norm(x, blk["ln1_scale"], spec.norm_eps)
         qkv, beta, g, gate = _kda_inputs(spec, blk, h)
         y, new_tail = kda.conv_step(tail, qkv, blk["conv_w"])
         q, k, v = _kda_heads(spec, y)
-        o, new_S = kda.kda_step(S, q, k, v, g, beta)
+        with jax.named_scope("recurrence"):
+            o, S_all = kda.kda_step_inplace(S_all, layer, q, k, v, g, beta,
+                                            active)
         out = _kda_out(spec, blk, o, gate, x.dtype)
     with jax.named_scope("state.update"):
-        S = jnp.where(active[:, None, None, None], new_S, S)
         tail = jnp.where(active[:, None, None], new_tail, tail)
-    return out, S, tail
+    return out, S_all, tail
 
 
 def _mla_inputs(spec: ModelSpec, blk: Params, h, positions):
@@ -455,10 +459,9 @@ def forward_decode_step(
     i_kda = i_mla = 0
     for blk, (kind, mlp, _i) in zip(params["layers"], spec.layer_plan):
         if kind == "kda":
-            att, S, tail = kda_layer_step(
-                spec, blk, x, S_all[i_kda], conv_all[i_kda], active)
+            att, S_all, tail = kda_layer_step(
+                spec, blk, x, S_all, i_kda, conv_all[i_kda], active)
             with jax.named_scope("state.update"):
-                S_all = S_all.at[i_kda].set(S)
                 conv_all = conv_all.at[i_kda].set(tail)
             i_kda += 1
         else:

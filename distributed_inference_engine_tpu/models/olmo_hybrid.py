@@ -200,12 +200,21 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
 # ------------------------------------------------------------- cache views
 
 
+def _lane_pack(spec: ModelSpec) -> int:
+    return kda.lane_pack(spec.n_heads, spec.gdn_value_head_dim)
+
+
 def init_state(spec: ModelSpec, max_slots: int) -> State:
     """Recurrent state of every Gated-DeltaNet layer for ``max_slots``
-    sequences, ``[n_periods, gdn layers a period, slots, ...]``."""
+    sequences, ``[n_periods, gdn layers a period, slots, ...]``. ``S`` keeps
+    ``kda.lane_pack`` heads side by side along the lanes (two of 96 x 192:
+    rows of 384 lanes, which fill whole 128-lane tiles; one head's 192 would
+    be padded to 256 in HBM and every decode step would move the padding)."""
     n, g = _n_periods(spec), len(_period(spec)) - 1
     h, dk, dv = spec.n_heads, spec.gdn_key_head_dim, spec.gdn_value_head_dim
-    return {"S": jnp.zeros((n, g, max_slots, h, dk, dv), jnp.float32),
+    m = _lane_pack(spec)
+    return {"S": jnp.zeros((n, g, max_slots, h // m, dk, m * dv),
+                           jnp.float32),
             "conv": jnp.zeros((n, g, max_slots, spec.gdn_conv - 1,
                                _conv_channels(spec)), spec.jnp_dtype)}
 
@@ -304,20 +313,23 @@ def gdn_layer_prefill(spec: ModelSpec, blk: Params, x, seq_lens):
         return _gdn_out(spec, blk, o, gate, x.dtype), S, tail
 
 
-def gdn_layer_step(spec: ModelSpec, blk: Params, x, S, tail, active):
-    """x [B, D], S [B, H, dk, dv], tail [B, conv-1, C]: one token. Rows not
-    ``active`` give back their S and tail untouched."""
+def gdn_layer_step(spec: ModelSpec, blk: Params, x, S_all, layer, tail,
+                   active):
+    """x [B, D]; S_all [layers, B, H / m, dk, m dv], every Gated-DeltaNet
+    layer's state as the engine keeps it (``init_state``), of which this
+    layer's is moved where it lies; tail [B, conv-1, C]: one token. Rows not
+    ``active`` keep their S and tail untouched."""
     with jax.named_scope("attn.gdn.step"):
         qkv, beta, g, gate = _gdn_inputs(spec, blk, x)
         y, new_tail = kda.conv_step(tail, qkv, blk["conv_w"])
         q, k, v = _gdn_heads(spec, y)
         with jax.named_scope("recurrence"):
-            o, new_S = kda.kda_step(S, q, k, v, g, beta)
+            o, S_all = kda.kda_step_inplace(S_all, layer, q, k, v, g, beta,
+                                            active)
         out = _gdn_out(spec, blk, o, gate, x.dtype)
     with jax.named_scope("state.update"):
-        S = jnp.where(active[:, None, None, None], new_S, S)
         tail = jnp.where(active[:, None, None], new_tail, tail)
-    return out, S, tail
+    return out, S_all, tail
 
 
 def _full_inputs(spec: ModelSpec, blk: Params, x):
@@ -447,7 +459,8 @@ def forward_prefill_into_pages(
     x, (S, tails, rows) = lax.scan(period, x, params["period"])
     with jax.named_scope("state.update"):
         state = {
-            "S": state["S"].at[:, :, slot_ids].set(S, mode="drop"),
+            "S": state["S"].at[:, :, slot_ids].set(
+                kda.pack_states(S, _lane_pack(spec)), mode="drop"),
             "conv": state["conv"].at[:, :, slot_ids].set(
                 tails.astype(state["conv"].dtype), mode="drop")}
     pages = write_rows_into_pages(pages, rows, page_table, seq_lens,
@@ -472,23 +485,23 @@ def forward_decode_step(
     del moe_impl
     x = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
     side_idx = lengths - start_lengths
+    # the states ride the scan as ONE list of layers: the step's kernel
+    # takes the array whole and moves layer p * n_gdn + j of it
+    S = state["S"]
+    n_gdn = S.shape[1]
 
     def period(carry, xs):
         x, side, S_all, conv_all, rows_read = carry
         blks, p = xs
-        S_p = lax.dynamic_index_in_dim(S_all, p, 0, keepdims=False)
         conv_p = lax.dynamic_index_in_dim(conv_all, p, 0, keepdims=False)
-        Ss, tails = [], []
+        tails = []
         for j, blk in enumerate(blks[:-1]):
-            att, S, tail = gdn_layer_step(spec, blk, x, S_p[j], conv_p[j],
-                                          active)
-            Ss.append(S)
+            att, S_all, tail = gdn_layer_step(
+                spec, blk, x, S_all, p * n_gdn + j, conv_p[j], active)
             tails.append(tail)
             x = x + att
             x = x + _mlp(spec, blk, x)
         with jax.named_scope("state.update"):
-            S_all = lax.dynamic_update_index_in_dim(
-                S_all, jnp.stack(Ss), p, 0)
             conv_all = lax.dynamic_update_index_in_dim(
                 conv_all, jnp.stack(tails), p, 0)
         with jax.named_scope("attn.kv_gather"):
@@ -503,8 +516,9 @@ def forward_decode_step(
         return (x, side, S_all, conv_all, rows_read + read), None
 
     n = side.shape[0]
-    (x, side, S, conv, rows_read), _ = lax.scan(
-        period, (x, side, state["S"], state["conv"], jnp.int32(0)),
+    (x, side, S_flat, conv, rows_read), _ = lax.scan(
+        period, (x, side, S.reshape(-1, *S.shape[2:]), state["conv"],
+                 jnp.int32(0)),
         (params["period"], jnp.arange(n)))
     counters = jnp.zeros((3,), jnp.int32).at[0].set(rows_read // n)
-    return x, side, {"S": S, "conv": conv}, counters
+    return x, side, {"S": S_flat.reshape(S.shape), "conv": conv}, counters

@@ -12,10 +12,35 @@ multiplies. ``dk`` and ``dv`` need not be equal (Gated DeltaNet: 96 keys
 against 192 values) and ``b`` may lie anywhere in (0, 2): the transition's
 eigenvalue along ``k`` is ``1 - b``, down to -1.
 
-Three forms of the same recurrence:
+Four forms of the same recurrence:
 
-- ``kda_step``: one token (decode). Elementwise float32 on the VPU, so the
-  state never passes through a reduced-precision matmul.
+- ``kda_step``: one token, on one layer's states ``[B, H, dk, dv]`` handed
+  in and given back. Elementwise float32 on the VPU, so the state never
+  passes through a reduced-precision matmul. Called by ``kda_scan`` and, as
+  the XLA body of the next form, wherever no TPU runs the program (the CPU
+  tests and rehearsals).
+- ``kda_step_inplace``: the served decode step (``models/olmo_hybrid.py``,
+  ``models/ling.py``). The same arithmetic over the engine's WHOLE state
+  array ``[layers, B, H, dk, dv]`` (or its lane-packed form, below), of
+  which it moves one layer's live slots where they lie. On a TPU (``step_impl``) a Mosaic kernel: the array
+  is aliased to the output, the layer's index a prefetched scalar (traced
+  inside Gated DeltaNet's scan over periods, static in KDA's unrolled
+  layers), the grid over (slot, block of whole heads); a head's ``[dk, dv]``
+  state is decayed, updated by the rank-one term and read out in registers,
+  each byte of it read from HBM once and written once. The mask is inside:
+  a dead slot's turns are mapped to the block a live neighbour already
+  holds, so nothing of a dead slot is fetched or written and its state
+  stays bit for bit (XLA's form read the state for the step, selected
+  ``where(active, new, old)`` over it and wrote a period's stack back:
+  three to four passes, PERF.md section 6, PR 34). It stays OFF the MXU for
+  the reason above: ``k^T S`` and ``S^T q`` as float32 matmuls would run as
+  bfloat16 passes at the default precision, or six of them at ``highest``,
+  for a step that is bound by the state's bytes either way. 192 value lanes
+  are no multiple of 128, and a float32 array whose last dim is 192 is
+  PADDED to 256 lanes in HBM: the engine keeps such states ``lane_pack``
+  heads side by side (``pack_states``: two of 96 x 192 a row of 384 lanes),
+  the kernel spreads each head's column over its own lanes of the row, and
+  a block takes the array's last two dims whole.
 - ``kda_scan``: token by token over a sequence. The tests' oracle.
 - ``kda_chunked``: the served prefill. Inside a chunk of ``chunk`` tokens
   the recurrence is the WY form: with ``G_i`` the running sum of ``g`` and
@@ -43,11 +68,14 @@ any decay.
 
 from __future__ import annotations
 
+from functools import partial
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 HI = lax.Precision.HIGHEST
 
@@ -102,6 +130,184 @@ def kda_scan(q, k, v, g, beta, S0=None):
                for a in (q, k, v, g, beta))
     S, o = lax.scan(body, S0.astype(jnp.float32), xs)
     return jnp.moveaxis(o, 0, 1), S
+
+
+# a block of the in-place kernel holds whole rows of states, as many as fit
+# this many bytes of VMEM (float32, padded to (8, 128) tiles); the pipeline
+# keeps two such blocks coming in and two going out. A whole slot of either
+# cell (15 x 96 x 384, 32 x 128 x 128) fits, and the largest block was the
+# fastest alone on the chip: 3 / 5 / 10 / 15 / 30 heads of 96 x 192 a block
+# read 98.7 / 89.2 / 88.1 / 85.2 / 82.7 us a call
+# (docs/sweeps/pr34-kda-step-inplace-kernel.txt)
+STEP_BLOCK_BYTES = 3 << 20
+
+
+def step_impl() -> str:
+    """Which body runs a decode step over the engine's state array:
+    "inplace", the kernel, on a TPU; "xla" elsewhere. ("inplace_interpret":
+    the kernel through the interpreter, for the CPU tests.)"""
+    return "inplace" if jax.default_backend() == "tpu" else "xla"
+
+
+def lane_pack(h: int, dv: int) -> int:
+    """How many heads' states lie side by side along the lanes of the
+    engine's state array: the fewest that fill whole 128-lane tiles. A
+    float32 ``[..., 96, 192]`` array is laid out in HBM with its 192 lanes
+    PADDED to 256, and every pass over it moves the padding (alone on the
+    chip the kernel took 82.6 us a call at 192 lanes and 82.4 at 256); two
+    heads a row are 384 lanes, no padding. 1 where ``dv`` fills its tiles
+    (KDA's 128) or no count of heads does."""
+    return next((m for m in range(1, h + 1)
+                 if h % m == 0 and m * dv % 128 == 0), 1)
+
+
+def pack_states(S: jnp.ndarray, m: int) -> jnp.ndarray:
+    """[..., H, dk, dv] -> [..., H / m, dk, m * dv]: heads ``m i .. m i + m -
+    1`` side by side along the lanes of row i."""
+    *lead, h, dk, dv = S.shape
+    S = S.reshape(*lead, h // m, m, dk, dv)
+    return jnp.swapaxes(S, -3, -2).reshape(*lead, h // m, dk, m * dv)
+
+
+def unpack_states(S: jnp.ndarray, m: int) -> jnp.ndarray:
+    """The inverse of ``pack_states``."""
+    *lead, hp, dk, w = S.shape
+    S = S.reshape(*lead, hp, dk, m, w // m)
+    return jnp.swapaxes(S, -3, -2).reshape(*lead, hp * m, dk, w // m)
+
+
+def _rows_per_block(hp: int, dk: int, w: int) -> int:
+    padded = -(-dk // 8) * 8 * -(-w // 128) * 128 * 4
+    return max(d for d in range(1, hp + 1)
+               if hp % d == 0 and (d == 1 or d * padded <= STEP_BLOCK_BYTES))
+
+
+def _step_kernel(meta_ref, blk_ref, act_ref, s_ref, qkg_ref, vb_ref,
+                 s_out, o_ref, *, dk: int, dv: int, m: int, rb: int):
+    """Grid (slot, block of rows). ``s_ref`` / ``s_out`` [1, 1, rb, dk,
+    m dv]: the SAME HBM block of the aliased state array, ``rb`` rows of
+    ``m`` heads' states side by side (``blk_ref`` maps a dead slot's turns
+    to the block its neighbour already holds, so the pipeline neither
+    fetches nor writes anything for them); ``qkg_ref`` [1, 1, 3 dk, rb m]:
+    q | k | g with the heads along the lanes, so a head's column broadcasts
+    over its state's ``dv`` lanes; ``vb_ref`` [1, 1, 2, rb, m dv]: v and beta
+    (broadcast over ``dv``), laid out as the rows are."""
+    live = act_ref[pl.program_id(0)] != 0
+
+    @pl.when(live)
+    def _():
+        qkg = qkg_ref[0, 0]
+        q, k = qkg[:dk], qkg[dk:2 * dk]
+        decay = jnp.exp(qkg[2 * dk:])
+        lane = lax.broadcasted_iota(jnp.int32, (1, m * dv), 1)
+
+        def cols(x, i):
+            """Row i's heads' columns of x [dk, rb m], each over its own
+            head's lanes: [dk, m dv] (one column's broadcast where m = 1)."""
+            out = x[:, m * i:m * i + 1]
+            for j in range(1, m):
+                out = jnp.where(lane >= j * dv, x[:, m * i + j:m * i + j + 1],
+                                out)
+            return out
+
+        for i in range(rb):
+            k_i = cols(k, i)
+            S = s_ref[0, 0, i] * cols(decay, i)
+            k_s = jnp.sum(k_i * S, axis=0, keepdims=True)   # k^T S [1, m dv]
+            S = S + k_i * (vb_ref[0, 0, 1, i:i + 1]
+                           * (vb_ref[0, 0, 0, i:i + 1] - k_s))
+            s_out[0, 0, i] = S
+            o_ref[0, 0, i:i + 1] = jnp.sum(cols(q, i) * S, axis=0,
+                                           keepdims=True)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    # no slot live: every turn maps to block 0, which goes back as it came
+    @pl.when(meta_ref[1] == 0)
+    def _():
+        s_out[...] = s_ref[...]
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def _step_kernel_call(S_all, layer, q, k, v, g, beta, active, interpret):
+    nl, b, hp, dk, w = S_all.shape
+    h, dv = v.shape[1:]
+    m = h // hp
+    rb = _rows_per_block(hp, dk, w)
+    nrb = hp // rb
+    f32 = jnp.float32
+
+    def heads_last(a):          # [B, H, c] -> [B, nrb, c, rb m]
+        return jnp.swapaxes(a.astype(f32).reshape(b, nrb, rb * m, -1), 2, 3)
+
+    # one decay a head is spread over the key channels here: Mosaic
+    # broadcasts along sublanes or lanes, not both at once
+    qkg = jnp.concatenate(
+        [heads_last(a) for a in (q, k, jnp.broadcast_to(g, k.shape))], axis=2)
+    vb = jnp.stack([v.astype(f32),
+                    jnp.broadcast_to(beta.astype(f32)[..., None], v.shape)],
+                   axis=1).reshape(b, 2, nrb, rb, w).swapaxes(1, 2)
+    # the block each grid turn holds: its own where the slot is live, else
+    # the last live turn's before it (the first live one's where none is)
+    turn = jnp.arange(b * nrb, dtype=jnp.int32)
+    on = jnp.repeat(active, nrb)
+    before = lax.cummax(jnp.where(on, turn, -1), axis=0)
+    blk = jnp.where(before >= 0, before, jnp.argmax(on).astype(jnp.int32))
+    meta = jnp.stack([jnp.asarray(layer, jnp.int32),
+                      jnp.any(active).astype(jnp.int32)])
+
+    def state_map(bi, ri, meta, blk, act):
+        at = blk[bi * nrb + ri]
+        return (meta[0], at // nrb, at % nrb, 0, 0)
+
+    def own(*tail):
+        return lambda bi, ri, meta, blk, act: (bi, ri, *tail)
+
+    state_spec = pl.BlockSpec((1, 1, rb, dk, w), state_map)
+    S_all, o = pl.pallas_call(
+        partial(_step_kernel, dk=dk, dv=dv, m=m, rb=rb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, nrb),
+            in_specs=[state_spec,
+                      pl.BlockSpec((1, 1, 3 * dk, rb * m), own(0, 0)),
+                      pl.BlockSpec((1, 1, 2, rb, w), own(0, 0, 0))],
+            out_specs=[state_spec,
+                       pl.BlockSpec((1, 1, rb, w), own(0, 0))]),
+        out_shape=[jax.ShapeDtypeStruct(S_all.shape, f32),
+                   jax.ShapeDtypeStruct((b, nrb, rb, w), f32)],
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="kda_step_inplace",
+    )(meta, blk, active.astype(jnp.int32), S_all, qkg, vb)
+    return o.reshape(b, h, dv), S_all
+
+
+def kda_step_inplace(S_all, layer, q, k, v, g, beta, active, impl: str = ""):
+    """One token for every slot, over the state array AS THE ENGINE KEEPS
+    IT: S_all [layers, B, H / m, dk, m dv] float32 (row b IS slot b; ``m``
+    heads side by side along the lanes, ``pack_states``; m = 1 is the plain
+    [layers, B, H, dk, dv]), of which this call moves layer ``layer`` (an
+    int or a traced int32); q, k [B, H, dk]; v [B, H, dv]; g [B, H, dk] or
+    [B, H, 1]; beta [B, H]; active [B] bool. Returns (o [B, H, dv], S_all):
+    a slot that is not ``active`` keeps its state bit for bit (the kernel
+    neither reads nor writes it) and its ``o`` is not specified (zeros from
+    the kernel, the step of a stale state from the XLA body: no caller
+    reads it). ``impl``: see ``step_impl``, which chooses when it is
+    empty."""
+    impl = impl or step_impl()
+    if impl == "xla":
+        m = q.shape[1] // S_all.shape[2]
+        S = lax.dynamic_index_in_dim(S_all, layer, 0, keepdims=False)
+        o, new_S = kda_step(unpack_states(S, m), q, k, v, g, beta)
+        S = jnp.where(active[:, None, None, None], pack_states(new_S, m), S)
+        return o, lax.dynamic_update_index_in_dim(S_all, S, layer, 0)
+    return _step_kernel_call(S_all, layer, q, k, v, g, beta, active,
+                             interpret=impl == "inplace_interpret")
 
 
 def _mm(spec: str, a, b):

@@ -38,12 +38,20 @@ FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
                          (7168, 4096), (2048, 32768)),
             mla_dims=(128, 64, 128),
             mla_cases=((1024, 48), (1024, 1024), (8192, 48), (8192, 8192)),
-            fused=(30, 48, 16, 4))
+            fused=(30, 48, 16, 4),
+            # (layers, heads, dk, dv, the gate's last axis): the Gated-
+            # DeltaNet cell's state, the KDA cell's, and the KDA cell's
+            # twice over (201 MB: alone in a program the cell's 101 MB can
+            # sit in the v5e's 128 MiB of VMEM, and then reads above the
+            # HBM peak)
+            delta=((12, 30, 96, 192, 1), (6, 32, 128, 128, 128),
+                   (12, 32, 128, 128, 128)))
 TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
             int4_rows=(32, 3),
             int4_shapes=((128, 256), (256, 128)),
             mla_dims=(16, 8, 16), mla_cases=((1024, 48), (1024, 1024)),
-            fused=(2, 6, 4, 3))
+            fused=(2, 6, 4, 3),
+            delta=((3, 4, 16, 32, 1), (2, 4, 16, 16, 16)))
 OUT = os.path.join("chiprun_out", "chip_kernels.json")
 
 
@@ -347,6 +355,95 @@ def check_mla_prefill(cfg, interpret):
     return f"{len(errs)} cases, max|err| {max(errs):.2e}"
 
 
+def check_kda_step_inplace(cfg, interpret):
+    """The delta rule's decode step over the engine's whole state array, in
+    place (``ops/kda.py`` ``kda_step_inplace``), at the Gated-DeltaNet
+    cell's shape (12 layers of 8 x 30 states of 96 x 192, two heads side by
+    side along the lanes as the engine keeps them, one decay a head, the
+    layer a traced index) and the KDA cell's (6 of 8 x 32 of 128 x 128,
+    one a key channel): ``o`` and the live states against ``kda_step``,
+    the dead slots' and every other layer's states bit-equal; on the chip
+    its time alone, a scan of calls that walks the layers, each fed the one
+    before, on the host's clock, all 8 slots live and then 6 of 8, beside
+    the XLA body (``kda_step`` on the layer's leaf, a select, the leaf
+    written where it lies; it un-packs and re-packs a lane-packed state,
+    which the CPU alone serves with)."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_engine_tpu.ops import kda
+
+    b, n_calls = 8, 256
+    kernel = "inplace_interpret" if interpret else "inplace"
+
+    def many(step):
+        @jax.jit
+        def run(S_all, q, k, v, g, beta, active):
+            def body(carry, i):
+                S_all, v = carry
+                o, S_all = step(S_all, i % S_all.shape[0], q, k, v, g, beta,
+                                active)
+                return (S_all, v + 1e-3 * o), None
+            return jax.lax.scan(body, (S_all, v), jnp.arange(n_calls))[0]
+        return run
+
+    details = []
+    for nl, h, dk, dv, dg in cfg["delta"]:
+        ks = jax.random.split(jax.random.key(dk), 6)
+        m = kda.lane_pack(h, dv)
+        S_all = kda.pack_states(
+            jax.random.normal(ks[0], (nl, b, h, dk, dv), jnp.float32), m)
+        q = kda.l2_normalize(jax.random.normal(ks[1], (b, h, dk))) * dk ** -.5
+        k = kda.l2_normalize(jax.random.normal(ks[2], (b, h, dk)))
+        v = jax.random.normal(ks[3], (b, h, dv), jnp.float32)
+        g = -2.0 * jax.random.uniform(ks[4], (b, h, dg), jnp.float32)
+        beta = 2.0 * jax.random.uniform(ks[5], (b, h), jnp.float32)
+        layer = nl - 2
+        detail = f"{nl} x {b} x {h} x {dk} x {dv}, {m} a row, gate {dg}"
+        for active in (jnp.ones((b,), bool),
+                       jnp.arange(b) % 4 != 2, jnp.zeros((b,), bool)):
+            n_live = int(active.sum())
+            ref_o, ref_S = kda.kda_step(kda.unpack_states(S_all[layer], m),
+                                        q, k, v, g, beta)
+            ref_S = kda.pack_states(ref_S, m)
+            got_o, got = jax.jit(
+                lambda *a: kda.kda_step_inplace(*a, impl=kernel))(
+                    S_all, jnp.int32(layer), q, k, v, g, beta, active)
+            err = max(_close(got_o[active], ref_o[active], 1e-5),
+                      _close(got[layer][active], ref_S[active], 1e-5)) \
+                if n_live else 0.0
+            others = jnp.arange(nl) != layer
+            assert bool(jnp.all(got[others] == S_all[others])), \
+                "another layer's states moved"
+            assert bool(jnp.all(got[layer][~active]
+                                == S_all[layer][~active])), \
+                "a dead slot's state moved"
+            detail += f"; {n_live} live: max|err| {err:.1e}"
+            if interpret or not n_live:
+                continue
+            moved = 2 * n_live * h * dk * dv * 4
+            for name, step in (
+                    ("kernel", lambda *a: kda.kda_step_inplace(
+                        *a, impl="inplace")),
+                    ("xla body", lambda *a: kda.kda_step_inplace(
+                        *a, impl="xla"))):
+                run = many(step)
+                args = (q, k, v, g, beta, active)
+                jax.block_until_ready(run(S_all + 0.0, *args))
+                best = float("inf")
+                for _ in range(5):
+                    fresh = S_all + 0.0        # the scan's carry is donated
+                    jax.block_until_ready(fresh)
+                    t0 = time.perf_counter()
+                    jax.block_until_ready(run(fresh, *args))
+                    best = min(best, time.perf_counter() - t0)
+                detail += (f", {name} {1e6 * best / n_calls:.1f} us a call = "
+                           f"{100 * moved * n_calls / best / 819e9:.1f} % of "
+                           f"819 GB/s for {moved / 1e6:.1f} MB")
+        details.append(detail)
+    return " | ".join(details)
+
+
 # name -> (check, on the default serving path?)
 CHECKS = {
     "int4_matmul_2d": (check_int4_2d, True),
@@ -356,6 +453,7 @@ CHECKS = {
     "flash_decode_served": (check_flash_decode_served, True),
     "flash_decode_kv_fused": (check_flash_decode_kv_fused, True),
     "mla_prefill": (check_mla_prefill, True),
+    "kda_step_inplace": (check_kda_step_inplace, True),
 }
 
 

@@ -1,4 +1,6 @@
-"""The delta rule both gates share (``ops/kda.py``): one token (``kda_step``),
+"""The delta rule both gates share (``ops/kda.py``): one token (``kda_step``;
+``kda_step_inplace``, the same step over the engine's whole state array, its
+kernel through the interpreter here),
 token by token (``kda_scan``, the oracle) and the chunked WY form
 (``kda_chunked``, the served prefill) give the same outputs and the same
 state for Gated DeltaNet's shapes (one decay a head, ``dk != dv``, beta up to
@@ -82,6 +84,109 @@ def test_steps_from_a_prefilled_state_are_the_scan(gate, dk, dv):
         outs.append(o)
     assert float(jnp.abs(jnp.stack(outs, 1) - want_o[:, 37:]).max()) < TOL
     assert float(jnp.abs(S - want_S).max()) < 4 * TOL
+
+
+def test_states_are_packed_to_whole_lane_tiles():
+    """``lane_pack``: two heads of 192 value lanes a row (384 = 3 tiles),
+    one of 128, four of 32; 1 where no count of heads fills a tile; and
+    ``pack_states`` puts head ``m i + j`` at lanes ``j dv .. (j + 1) dv`` of
+    row i, which ``unpack_states`` undoes."""
+    assert [kda.lane_pack(h, dv) for h, dv in
+            ((30, 192), (32, 128), (4, 32), (3, 48), (2, 48))] == [
+                2, 1, 4, 1, 1]
+    S = jax.random.normal(jax.random.key(0), (3, 2, 6, 8, 192))
+    P = kda.pack_states(S, 2)
+    assert P.shape == (3, 2, 3, 8, 384)
+    assert np.array_equal(np.asarray(P[:, :, 1, :, 192:]),
+                          np.asarray(S[:, :, 3]))
+    assert np.array_equal(np.asarray(kda.unpack_states(P, 2)), np.asarray(S))
+
+
+# the cells' state shapes: Gated DeltaNet's 96 x 192 under one decay a head
+# (two heads a row of 384 lanes, as the engine keeps them), KDA's 128 x 128
+# under one a key channel; 3 layers of 4 slots of 4 heads, two heads a
+# block, so every case crosses a slot's and a block's edge
+@pytest.mark.parametrize("index", ["static", "scanned"])
+@pytest.mark.parametrize("live", [(True,) * 4, (False, True, False, True),
+                                  (True, False, False, False), (False,) * 4],
+                         ids=["all", "some", "first", "none"])
+@pytest.mark.parametrize("gate,dk,dv", [("head", 96, 192),
+                                        ("channel", 128, 128)])
+def test_the_inplace_kernel_is_the_step_on_the_live_slots(
+        monkeypatch, gate, dk, dv, live, index):
+    """``kda_step_inplace`` through the interpreter against ``kda_step``: the
+    live slots' ``o`` and states within float32 rounding; the dead slots'
+    states and every OTHER layer's bit-equal; the layer a Python int or an
+    index traced inside a ``lax.scan`` (as the Gated-DeltaNet family's scan
+    over periods hands it)."""
+    nl, b, h, layer = 3, 4, 4, 1
+    m = kda.lane_pack(h, dv)
+    monkeypatch.setattr(kda, "STEP_BLOCK_BYTES", 2 * 96 * 256 * 4)
+    assert kda._rows_per_block(h // m, dk, m * dv) * m == 2
+    q, k, v, g, beta = (a[:, 0] for a in draw(7, b, 1, h, dk, dv, gate))
+    S_plain = jax.random.normal(jax.random.key(1), (nl, b, h, dk, dv))
+    S_all = kda.pack_states(S_plain, m)
+    active = jnp.array(live)
+
+    def step(S_all, at):
+        return kda.kda_step_inplace(S_all, at, q, k, v, g, beta, active,
+                                    impl="inplace_interpret")
+
+    if index == "static":
+        o, got = jax.jit(lambda S: step(S, layer))(S_all)
+    else:
+        def body(S, at):
+            o, S = step(S, at)
+            return S, o
+
+        got, o = jax.jit(lambda S: jax.lax.scan(
+            body, S, jnp.array([layer])))(S_all)
+        o = o[0]
+    want_o, want_S = kda.kda_step(S_plain[layer], q, k, v, g, beta)
+    on = np.asarray(live)
+    assert got.shape == S_all.shape
+    S_all = np.asarray(S_all)
+    plain = np.asarray(kda.unpack_states(got, m))
+    if on.any():
+        assert np.abs(np.asarray(o)[on] - np.asarray(want_o)[on]).max() < 1e-6
+        assert np.abs(plain[layer][on] - np.asarray(want_S)[on]).max() < 1e-6
+        assert np.abs(plain[layer][on] - S_plain[layer][on]).max() > 0.1
+    got = np.asarray(got)
+    assert np.array_equal(got[layer][~on], S_all[layer][~on])
+    assert np.array_equal(got[[0, 2]], S_all[[0, 2]])
+    # the XLA body, which the CPU serves with, keeps the same contract
+    o_x, got_x = kda.kda_step_inplace(S_all, layer, q, k, v, g, beta, active,
+                                      impl="xla")
+    assert np.array_equal(np.asarray(got_x)[layer][~on], S_all[layer][~on])
+    assert np.abs(np.asarray(got_x) - got).max() < 1e-6
+
+
+@pytest.mark.parametrize("gate,dk,dv", [("head", 24, 64), ("channel", 16, 16)])
+def test_sixteen_inplace_steps_in_a_carry_are_the_scan(gate, dk, dv):
+    """A decode chunk's shape: the state array rides a ``lax.scan`` carry
+    through 16 steps of the interpreted kernel on layer 2 of 3, slot 1 dead
+    throughout; live slots' outputs and final states are ``kda_scan``'s."""
+    nl, b, h, t, layer = 3, 3, 2, 16, 2
+    q, k, v, g, beta = draw(11, b, t, h, dk, dv, gate)
+    want_o, want_S = kda.kda_scan(q, k, v, g, beta)
+    active = jnp.array([True, False, True])
+    m = kda.lane_pack(h, dv)
+    S0 = jnp.zeros((nl, b, h // m, dk, m * dv)).at[:, 1].set(3.0)
+
+    def body(S_all, xs):
+        o, S_all = kda.kda_step_inplace(S_all, layer, *xs, active,
+                                        impl="inplace_interpret")
+        return S_all, o
+
+    S_all, o = jax.jit(lambda S: jax.lax.scan(
+        body, S, tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))))(
+            S0)
+    on = np.asarray(active)
+    assert float(jnp.abs(jnp.moveaxis(o, 0, 1) - want_o)[on].max()) < TOL
+    assert float(jnp.abs(kda.unpack_states(S_all[layer], m)
+                         - want_S)[on].max()) < 4 * TOL
+    assert np.array_equal(np.asarray(S_all[:, 1]), np.asarray(S0[:, 1]))
+    assert not np.asarray(S_all[:layer, on]).any()
 
 
 def test_rows_shorter_than_the_bucket_keep_their_state():

@@ -31,6 +31,7 @@ from distributed_inference_engine_tpu.engine.types import (  # noqa: E402
 from distributed_inference_engine_tpu.models import (  # noqa: E402
     engine_from_config, ling, mistral_spec, spec_for_architecture,
 )
+from distributed_inference_engine_tpu.ops import kda  # noqa: E402
 from perfbench.lib import families  # noqa: E402
 
 with open(os.path.join(ROOT, "perfbench", "rehearse", "ling-tiny.json")) as _f:
@@ -133,6 +134,37 @@ def test_a_preempted_sequence_resumes_where_it_stopped():
     for a, b in zip(alone, together):
         assert a.tokens == b.tokens and len(b.tokens) == 40
         assert b.finish_reason == a.finish_reason
+
+
+def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch):
+    """The in-place kernel (through the interpreter) against the XLA body
+    the CPU picks, under everything that touches a slot's state: five
+    requests of unequal length over 4 slots and a pool too small for them,
+    so slots finish, are zeroed (``zero_state_slot``) and taken again, and
+    one sequence is pre-empted and re-prefilled. Greedy tokens equal, and
+    the engine says which body ran."""
+    rng = np.random.default_rng(9)
+    prompts = [[int(t) for t in rng.integers(1, 256, n)]
+               for n in (30, 28, 9, 17, 24)]
+    new = (40, 36, 7, 12, 21)
+
+    def serve():
+        engine = tiny_engine("float32", num_pages=9)
+        results = engine.generate([
+            GenerationRequest(prompt=list(p), max_new_tokens=n)
+            for p, n in zip(prompts, new)])
+        return [r.tokens for r in results], engine.get_metrics()
+
+    tokens_xla, m_xla = serve()
+    monkeypatch.setattr(kda, "step_impl", lambda: "inplace_interpret")
+    tokens_kernel, m_kernel = serve()
+    assert tokens_kernel == tokens_xla
+    assert [len(t) for t in tokens_xla] == list(new)
+    assert m_xla["state"]["step_body"] == "xla"
+    assert m_kernel["state"]["step_body"] == "inplace_interpret"
+    for m in (m_xla, m_kernel):
+        assert m["reprefill_preemptions"] >= 1
+        assert m["state"]["rows_updated"] == m_xla["state"]["rows_updated"] > 0
 
 
 @pytest.mark.parametrize("pages", [32, 7])

@@ -244,7 +244,8 @@ def test_the_spec_says_what_the_family_holds(served_bf16):
     assert served_bf16["period"][0]["a_log"].dtype == jnp.float32
     sv = Served(spec, served_bf16, slots=2)
     assert sv.kv.k_pages.shape == (2, 32, 16, 256)
-    assert sv.kv.state["S"].shape == (2, 3, 2, 4, 16, 32)
+    # four heads of 16 x 32 side by side: one row of 128 lanes
+    assert sv.kv.state["S"].shape == (2, 3, 2, 1, 16, 128)
     assert sv.kv.state["S"].dtype == jnp.float32
     assert sv.kv.get_stats()["state_bytes"] == \
         2 * olmo_hybrid.state_bytes_per_slot(spec)

@@ -9,6 +9,8 @@ process may load the TPU library; see the on-chip-measurement guide), and
 every such test lives in this one file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -138,15 +140,76 @@ def test_flash_decode_over_a_fused_kv_pool_compiles_for_v5e(one_chip, w):
     assert "flash_decode_custom_call" in compiled.as_text()
 
 
-def test_no_program_of_the_gdn_family_holds_a_copy_of_its_kv_table(one_chip):
+# layers, heads, dk, dv, the gate's last axis, periods of the scan (0: the
+# layers unrolled under static indices)
+@pytest.mark.parametrize("nl,h,dk,dv,dg,periods", [
+    (12, 30, 96, 192, 1, 4),       # the Gated-DeltaNet cell: (p, j) traced
+    (6, 32, 128, 128, 128, 0),     # the KDA cell
+])
+def test_kda_step_inplace_compiles_for_v5e(one_chip, nl, h, dk, dv, dg,
+                                           periods):
+    """The delta rule's in-place decode step at both cells' state shapes (8
+    slots; the 96 x 192 states two a row of 384 lanes, as the engine keeps
+    them): Mosaic takes whole rows of states in a block, each head's column
+    spread over its own 192 lanes, a layer index traced inside a
+    ``lax.scan``, and the state array aliased to the output: the program
+    holds no second copy of it."""
+    from distributed_inference_engine_tpu.ops import kda
+
+    b, m = 8, kda.lane_pack(h, dv)
+
+    def sds(*shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(S_all, q, k, v, g, beta, active):
+        def layer(S_all, at):
+            o, S_all = kda.kda_step_inplace(S_all, at, q, k, v, g, beta,
+                                            active, impl="inplace")
+            return S_all, o
+
+        if not periods:
+            outs = []
+            for at in range(nl):
+                S_all, o = layer(S_all, at)
+                outs.append(o)
+            return S_all, jnp.stack(outs)
+
+        def period(S_all, p):
+            outs = []
+            for j in range(nl // periods):
+                S_all, o = layer(S_all, p * (nl // periods) + j)
+                outs.append(o)
+            return S_all, jnp.stack(outs)
+
+        return jax.lax.scan(period, S_all, jnp.arange(periods))
+
+    compiled = jax.jit(fn, donate_argnums=0).lower(
+        sds(nl, b, h // m, dk, m * dv), sds(b, h, dk), sds(b, h, dk),
+        sds(b, h, dv),
+        sds(b, h, dg), sds(b, h), sds(b, dtype=jnp.bool_)).compile()
+    assert "kda_step_inplace" in compiled.as_text()
+    one_layer = b * h * dk * dv * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_layer
+
+
+def test_no_program_of_the_gdn_family_holds_a_copy_of_its_kv_table(
+        one_chip, monkeypatch):
     """The Gated-DeltaNet family's decode chunk and its 4,096-token prefill
     at the served size, compiled for the v5e: the decode steps read the
     3.02 GB pool through the kernel where it lies, both programs write it
     where it lies, and neither holds a gathered ``[L, B, S, 7680]`` (or one
     layer's ``[B, S, 7680]``) context, nor temporaries as large as the pool
-    (a copy of it would add 3.02 GB to the prefill's 1.5 GB of activations)."""
+    (a copy of it would add 3.02 GB to the prefill's 1.5 GB of activations).
+    Nor, since PR 34, anything of the per-slot STATE's size but the step's
+    kernel: the chunk's scan and the scan over periods carry the 12 x 8
+    states of 15 x 96 x 384 to the kernel and back with no copy, slice,
+    select or stack of them (``kda.step_impl`` is steered to the chip's
+    answer here: this process sees the CPU)."""
     from distributed_inference_engine_tpu.models import olmo_hybrid as fam
     from distributed_inference_engine_tpu.models.base import unembed
+    from distributed_inference_engine_tpu.ops import kda
+
+    monkeypatch.setattr(kda, "step_impl", lambda: "inplace")
 
     spec = fam.olmo_hybrid_spec("olmo-hybrid-7b-pp2", max_seq_len=6144)
     slots, n_pages, page, mp, steps = 8, 384, 128, 48, 16
@@ -201,4 +264,13 @@ def test_no_program_of_the_gdn_family_holds_a_copy_of_its_kv_table(one_chip):
                       "[4,8,48,128,7680]"):
             assert shape not in text, shape
         assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * pool_bytes
-    assert "flash_decode_custom_call" in programs[0].compile().as_text()
+    text = programs[0].compile().as_text()
+    assert "flash_decode_custom_call" in text
+    state_ops = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z-]*)\(", line)
+        if m and "8,15,96,384]" in m.group(1):
+            state_ops.add(m.group(2))
+    assert "custom-call" in state_ops and state_ops <= {
+        "parameter", "tuple", "get-tuple-element", "while", "bitcast",
+        "custom-call"}, state_ops
