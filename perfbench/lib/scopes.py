@@ -20,8 +20,11 @@ from . import families, hostspans, peaks, readers
 from .procs import MODEL
 from .tracered import leaf_ops
 
+# (the last two are around the head of every model's programs: models/base.py
+# and ops/sampling.py open them for all families)
 SCOPES = ("moe.experts", "moe.route", "moe.shared", "attn.kda.step",
-          "attn.kda.prefill", "attn.mla", "state.update")
+          "attn.kda.prefill", "attn.mla", "state.update", "head.unembed",
+          "sample")
 KERNEL = "gmm"          # the grouped matmul's own scope, inside moe.experts
 
 

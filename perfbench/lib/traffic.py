@@ -11,9 +11,17 @@ arrive in), the token ids, and the arrival offsets: the sorted values of
 its count. So every seed sends the same number of requests, prompt tokens and
 output tokens, and the local bursts of a Poisson process stay.
 
+Above capacity a run serves only the head of its queue, so WHICH lengths
+arrive first is then work too, and the seed's. A mix may therefore set
+``strata`` = k: each phase's sorted lengths are cut into k bands of equal
+count, and every k consecutive arrivals hold one prompt length and one output
+length from each band (which of a band's, their pairing and their order
+within the k: the seed's). Any head of the queue is then the mix in small,
+on every seed. Without the key both permutations are uniform, as before.
+
 Mix keys: ``rate_rps`` (requests a second), ``ramp_s``,
 ``tail_s``, ``prompt`` and ``output`` (``{"median", "sigma", "min",
-"max"}``). Every prompt is unique.
+"max"}``), and optionally ``strata``. Every prompt is unique.
 """
 
 from __future__ import annotations
@@ -63,6 +71,22 @@ def lognormal_quantiles(dist: Dict[str, float], n: int) -> List[int]:
     return out
 
 
+def stratified(values: List[int], k: int, rng: random.Random) -> List[int]:
+    """``values`` (sorted) in an order whose every k consecutive entries
+    hold one from each of the k bands of equal count; the last entries come
+    from the bands that have any left."""
+    n = len(values)
+    bands = [values[i * n // k:(i + 1) * n // k] for i in range(k)]
+    for band in bands:
+        rng.shuffle(band)
+    out: List[int] = []
+    while any(bands):
+        block = [band.pop() for band in bands if band]
+        rng.shuffle(block)
+        out += block
+    return out
+
+
 def phase_seconds(mix: Dict[str, Any], window_s: float
                   ) -> Dict[str, Tuple[float, float]]:
     """phase -> (start, length), relative to the window's opening."""
@@ -88,8 +112,13 @@ def schedule(mix: Dict[str, Any], seed: int, window_s: float,
             continue
         plens = lognormal_quantiles(mix["prompt"], n)
         olens = lognormal_quantiles(mix["output"], n)
-        rng.shuffle(plens)
-        rng.shuffle(olens)
+        strata = int(mix.get("strata") or 0)
+        if strata > 1:
+            plens = stratified(plens, strata, rng)
+            olens = stratified(olens, strata, rng)
+        else:
+            rng.shuffle(plens)
+            rng.shuffle(olens)
         dues = sorted(start + length * rng.random() for _ in range(n))
         for due, plen, olen in zip(dues, plens, olens):
             prompt = [rng.randrange(1, vocab_size) for _ in range(plen)]

@@ -1,5 +1,7 @@
 """Share of device self time under the model programs' attn.kv_gather and
-attn.kv_update scopes (the dense K/V context sliced out, a step's rows written in).
+attn.kv_update scopes: what a step copies out of and into the pages, whatever
+the family keeps there (dense K/V rows, latent rows, a full layer's K|V beside
+recurrent state that is not paged). None for a program without the scopes.
 """
 
 from perfbench.lib import spanreaders

@@ -1,4 +1,6 @@
-"""Pages of the KV pool in use, sampled through the window."""
+"""Pages of the page pool in use (the pool's own ``utilization``), sampled
+through the window: dense K/V, latent rows or the full layers' K|V, as the
+family's cache holds them (``counts/<family>.py`` ``CACHE``)."""
 
 from perfbench.lib import readers
 from perfbench.lib.procs import MODEL

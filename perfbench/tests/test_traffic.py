@@ -110,3 +110,65 @@ def test_prime_parity_and_schedule_share_no_prefix():
             heads += [tuple(rng.randrange(1, 32768) for _ in range(16))
                       for _ in range(8)]
         assert len(set(heads)) == len(heads)
+
+
+def test_a_mix_without_strata_is_scheduled_as_it_always_was():
+    """``strata`` is a key a mix may set; the other mixes' schedules are
+    the ones their cells' numbers were read on (pinned from the generator
+    before the key existed)."""
+    mix = mix_for("chat-steady")
+    assert "strata" not in mix
+    reqs = traffic.schedule(mix, 3000000019, 20.0, 1000)
+    assert [(len(r.prompt), r.output_len) for r in reqs[:5]] == [
+        (268, 93), (223, 177), (768, 256), (673, 39), (394, 78)]
+    assert reqs[0].due_s == pytest.approx(-8.843133, abs=1e-6)
+    for off in (0, 1):
+        same = traffic.schedule(dict(mix, strata=off), 3000000019, 20.0, 1000)
+        assert [(r.due_s, r.prompt) for r in same] == \
+            [(r.due_s, r.prompt) for r in reqs]
+
+
+@pytest.mark.parametrize("k, n", [(6, 61), (6, 12), (4, 10), (8, 8), (5, 3)])
+def test_strata_every_k_arrivals_hold_one_length_of_each_band(k, n):
+    """Whatever head of the queue a saturated replica gets to serve, it is
+    the mix in small: each whole block of k consecutive arrivals has one
+    prompt length and one output length from each of the k bands."""
+    mix = dict(mix_for("longgen-overload-olmo"), strata=k, ramp_s=0.0,
+               tail_s=0.0)
+    window = 10.0
+    orders = set()
+    for seed in (1, 2, 3000000019, 2 ** 31 + 11):
+        reqs = traffic.schedule(mix, seed, window, 1000, rate_rps=n / window)
+        assert len(reqs) == n
+        for what, dist in ((lambda r: len(r.prompt), mix["prompt"]),
+                           (lambda r: r.output_len, mix["output"])):
+            got = [what(r) for r in reqs]
+            q = traffic.lognormal_quantiles(dist, n)
+            assert sorted(got) == q
+            bands = [q[i * n // k:(i + 1) * n // k] for i in range(k)]
+            least = min(len(b) for b in bands)
+            for b in range(least):
+                block = sorted(got[b * k:(b + 1) * k])
+                assert all(lo[0] <= x <= lo[-1]
+                           for x, lo in zip(block, bands)), (seed, b)
+        orders.add(tuple(len(r.prompt) for r in reqs))
+    assert len(orders) == 4 or n < 4
+
+
+def test_strata_steady_what_the_head_of_the_queue_asks_for():
+    """The sum of the lengths of a queue's first half, over seeds: a few
+    per cent of its mean with strata, about three times that without."""
+    base = mix_for("longgen-overload-olmo")
+    assert base["strata"] == 6
+
+    def heads(mix):
+        out = []
+        for seed in range(40):
+            w = [r for r in traffic.schedule(mix, seed, 51.0, 1000)
+                 if r.phase == "window"]
+            out.append(sum(len(r.prompt) for r in w[:len(w) // 2]))
+        return statistics.pstdev(out) / statistics.mean(out)
+
+    with_strata, without = heads(base), heads(dict(base, strata=0))
+    assert with_strata < 0.05 < without
+    assert without > 2 * with_strata
