@@ -19,10 +19,11 @@ from __future__ import annotations
 import argparse
 import asyncio
 import logging
+import time
 from typing import Any, Dict, List
 
 from ..config import Config, ModelConfig, ServerConfig, load_config
-from ..cluster.worker import WorkerServer
+from ..cluster.worker import WorkerServer, warmup_line
 
 _MODEL_FIELDS = {
     "name", "path", "version", "architecture", "dtype", "batch_size",
@@ -90,7 +91,8 @@ def build_parser() -> argparse.ArgumentParser:
     return p
 
 
-async def amain(args: argparse.Namespace) -> None:
+async def amain(args: argparse.Namespace,
+                boot: Dict[str, float] | None = None) -> None:
     if args.config:
         cfg = load_config(args.config)
         server_cfg = cfg.server
@@ -131,19 +133,24 @@ async def amain(args: argparse.Namespace) -> None:
                 "artifact", os.path.join(args.artifact_dir, m.name))
 
     worker = WorkerServer(server_cfg)
+    worker.boot.update(boot or {})   # what main() passed before this existed
     # preload BEFORE announcing the address: the "listening" line is the
     # readiness signal orchestration scripts wait on, and Ctrl-C during a
     # long checkpoint load still gets default KeyboardInterrupt handling
     # (signal handlers are only installed once serving starts)
     for m in models:
         print(f"loading model {m.name} ({m.architecture})...", flush=True)
+        worker.mark_boot("load_begin")
         await worker.load_model_async(m)
+        worker.mark_boot("load_end")
         load_s = worker._last_load_s.get(m.name, 0.0)
         warm_s = worker._last_warmup_s.get(m.name, 0.0)
-        hit = getattr(worker.engines.get(m.name), "artifact_manifest",
-                      None) is not None
+        build_s = load_s - warm_s   # the factory, the backend's start in it
+        engine = worker.engines.get(m.name)
+        hit = getattr(engine, "artifact_manifest", None) is not None
         print(f"loaded model {m.name} in {load_s:.2f}s "
-              f"(warm-up compile {warm_s:.2f}s)"
+              f"(build {build_s:.2f}s, warm-up {warm_s:.2f}s"
+              f"{warmup_line(worker._engine_warmup(engine))})"
               f"{' [artifact cold-start]' if hit else ''}", flush=True)
     host, port = await worker.start(install_signal_handlers=True)
     # the device goes BEFORE the address: scripts read the port as the
@@ -164,6 +171,7 @@ async def amain(args: argparse.Namespace) -> None:
 
 
 def main(argv: List[str] | None = None) -> None:
+    boot = {"main_entered": time.perf_counter()}
     args = build_parser().parse_args(argv)
     logging.basicConfig(
         level=args.log_level.upper(),
@@ -172,8 +180,9 @@ def main(argv: List[str] | None = None) -> None:
     from ..utils.compile_cache import configure_compile_cache
 
     logging.getLogger(__name__).info(
-        "compile cache: %s", configure_compile_cache())
-    asyncio.run(amain(args))
+        "compile cache: %s", configure_compile_cache())   # imports jax
+    boot["jax_imported"] = time.perf_counter()
+    asyncio.run(amain(args, boot))
 
 
 if __name__ == "__main__":

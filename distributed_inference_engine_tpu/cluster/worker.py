@@ -157,6 +157,46 @@ def build_engine(cfg: ModelConfig):
 EngineFactory = Callable[[ModelConfig], Any]
 
 
+_born_at: Optional[float] = None    # perf_counter when the process began
+
+
+def process_born_at() -> Optional[float]:
+    """This process's start on ``perf_counter``: what turns the ``boot``
+    marks (``perf_counter`` stamps) into seconds since the operating
+    system started the process. Read once, and only when a ``metrics``
+    reply needs it. From ``/proc`` (start time against uptime, to a clock
+    tick) where there is one: psutil's ``create_time()`` adds the start to
+    a boot time of whole seconds and read 0.6 s off here. ``None`` without
+    either."""
+    global _born_at
+    if _born_at is None:
+        try:
+            with open("/proc/self/stat") as f:
+                ticks = float(f.read().rsplit(")", 1)[1].split()[19])
+            with open("/proc/uptime") as f:
+                age = float(f.read().split()[0]) - ticks / os.sysconf(
+                    "SC_CLK_TCK")
+        # graftlint: ok[swallowed-transport-error] a file of /proc, no peer involved; psutil answers where there is none
+        except (OSError, ValueError, IndexError):
+            try:
+                import psutil
+            except ImportError:
+                return None
+            age = time.time() - psutil.Process().create_time()
+        _born_at = time.perf_counter() - age
+    return _born_at
+
+
+def warmup_line(warm: Dict[str, Any]) -> str:
+    """An engine's warm-up totals on one line, for the worker's own log."""
+    if not warm.get("rounds"):
+        return ""
+    return (" [trace {trace_s:.2f}s lower {lower_s:.2f}s compile "
+            "{compile_s:.2f}s (cache reads {cache_retrieval_s:.2f}s, "
+            "{cache_hits} hits, {cache_misses} misses) run {run_s:.2f}s]"
+            .format(**warm))
+
+
 def _model_identity(cfg: ModelConfig):
     """The fields that determine WHICH model an engine serves. Engine-impl
     knobs (continuous mode, page sizes, batch limits, schemas) are worker-
@@ -310,6 +350,11 @@ class WorkerServer(FramedServerMixin):
         self.model_load_stats = LatencyStats()
         self._last_load_s: Dict[str, float] = {}
         self._last_warmup_s: Dict[str, float] = {}
+        # ``perf_counter`` when this process first passed each point of
+        # its start-up (``mark_boot``; ``cli.worker`` adds the ones before
+        # this object exists); ``boot_report`` turns them into seconds
+        # since the process started
+        self.boot: Dict[str, float] = {}
         # where each real (jax) engine's params landed — platform, device
         # kind, device ids, int4 kernel paths — so a deploy can ASSERT its
         # placement instead of inferring it (see device_report)
@@ -379,11 +424,25 @@ class WorkerServer(FramedServerMixin):
         host, port = sock.getsockname()[:2]
         return host, port
 
+    def mark_boot(self, name: str) -> None:
+        """Note when start-up first passed ``name`` (a later pass — a
+        second model's load — leaves the mark where it is)."""
+        self.boot.setdefault(name, time.perf_counter())
+
+    def boot_report(self) -> Dict[str, float]:
+        """The marks as seconds since the operating system started this
+        process; empty where that start cannot be read."""
+        born = process_born_at()
+        if born is None:
+            return {}
+        return {name: at - born for name, at in self.boot.items()}
+
     async def start(self, install_signal_handlers: bool = False) -> Tuple[str, int]:
         self._server = await asyncio.start_server(
             self._handle_connection, self.config.host, self.config.port
         )
         self._started_at = time.time()
+        self.mark_boot("listening")
         if install_signal_handlers:
             loop = asyncio.get_running_loop()
             for sig in (signal.SIGINT, signal.SIGTERM):
@@ -458,6 +517,13 @@ class WorkerServer(FramedServerMixin):
                         self.worker_id, cfg.name, n,
                         self._last_warmup_s[cfg.name])
         return engine
+
+    @staticmethod
+    def _engine_warmup(engine) -> Dict[str, Any]:
+        """An engine's own account of its warm-up grid (``ContinuousEngine.
+        get_metrics()["warmup"]``); empty for an engine that keeps none."""
+        rounds = getattr(engine, "warmup_metrics", None)
+        return rounds() if rounds is not None else {}
 
     def _model_busy(self, name: str) -> bool:
         """Eviction guard: a model with queued or decoding work is pinned
@@ -1460,11 +1526,21 @@ class WorkerServer(FramedServerMixin):
             "active_connections": self._active_connections,
             "latency": self.latency.snapshot(),
             "model_load": self.model_load_stats.snapshot(),
-            # per-model set-up split: engine build + warm-up compile
+            # this process's perf_counter now: the clock of the compile
+            # log's and the step ring's ``t0``
+            "mono": time.perf_counter(),
+            # seconds since process start at each mark of start-up
+            "boot": self.boot_report(),
+            # per-model set-up split: load_s less warmup_s is the engine
+            # factory (parameters drawn / quantised / placed, pools
+            # allocated; on a process's first load the backend's start
+            # too); ``warmup`` is the engine's own account of its grid,
+            # round by round
             "model_setup": {
                 name: {"load_s": self._last_load_s.get(name, 0.0),
-                       "warmup_s": self._last_warmup_s.get(name, 0.0)}
-                for name in self.engines},
+                       "warmup_s": self._last_warmup_s.get(name, 0.0),
+                       "warmup": self._engine_warmup(engine)}
+                for name, engine in self.engines.items()},
             "device": self.device_report(memory=True),
             "artifact_hits": self._artifact_hits,
             "artifact_misses": self._artifact_misses,

@@ -61,6 +61,7 @@ from ..ops.sampling import (
     sample_tokens_with_logprobs,
 )
 from ..obs.timeline import HostSpan, StepTimeline, host_span
+from ..utils import compile_cache
 from ..utils.hotpath import hot_path
 from ..utils.tracing import LatencyStats
 from .engine import _next_bucket, _pow2_buckets
@@ -996,11 +997,18 @@ class ContinuousEngine:
         self.queue_wait_stats = LatencyStats()   # submit -> admitted
         # step timeline (obs/timeline.py): one record per host span of the
         # engine thread (device dispatches among them), exported as a
-        # Perfetto-loadable Chrome trace. A dispatch record is flagged
-        # ``compile`` when the compiler's own counter grew across it.
+        # Perfetto-loadable Chrome trace. A dispatch record that compiled
+        # says what and for how long (``obs.timeline.compile_keys``).
         cap = int(getattr(config, "timeline_capacity", 4096) or 0)
         self.timeline: Optional[StepTimeline] = (
             StepTimeline(capacity=cap, name="continuous") if cap else None)
+        # set-up seen from inside (``warmup`` / ``get_metrics``): the warm-
+        # up grid's rounds, each split by the compile log's records, and
+        # the log index + counters at which warm-up last ended (engine
+        # construction until then): what compiles after is named
+        self._warmup_rounds: List[Dict[str, Any]] = []
+        self._warm_log_index = compile_cache.log_index()
+        self._warm_counters = compile_cache.compile_counters()   # installs
         # host-gap split (ISSUE 5 satellite): dispatch-bracket seconds vs
         # the host-side gap BETWEEN consecutive dispatch brackets, so an
         # hbm_util regression is attributable at a glance — kernel-side
@@ -2634,7 +2642,10 @@ class ContinuousEngine:
             return
         mask = np.zeros((self.max_slots,), bool)
         mask[slots] = True
-        self._active = jnp.where(jnp.asarray(mask), value, self._active)
+        # outside every dispatch bracket: a span of its own, so a compile
+        # here (``compiles_after_warmup``) says where it ran
+        with self._span("engine.set_active", rows=len(slots)):
+            self._active = jnp.where(jnp.asarray(mask), value, self._active)
 
     # ---------------------------------------------------------------- run
 
@@ -2732,7 +2743,8 @@ class ContinuousEngine:
         cached-suffix hits and leaving those programs cold. The paged
         pools are fixed-shape, so the decode chunk compiles once; pages
         and slots are fully returned afterwards. Stat counters do tick.
-        Returns the number of warmup rounds."""
+        Each round is one ``engine.warmup.round`` span
+        (``_close_warmup_round``). Returns the number of warmup rounds."""
         runs = 0
         if batch:
             sizes = [batch]
@@ -2760,19 +2772,45 @@ class ContinuousEngine:
                                      self.max_seq_len - 1 - max_new_tokens)
                     if prompt_len < 1:
                         continue
+                    mark = compile_cache.log_index()
+                    sp = self._span("engine.warmup.round", batch=n,
+                                    bucket=tb)
+                    if not runs and not self._slots:
+                        # compile the active-flag update with the first
+                        # round (no slot is live: a no-op)
+                        self._set_active([0], False)
                     for _ in range(n):
                         self.submit(GenerationRequest(
                             prompt=[1] * prompt_len,
                             max_new_tokens=max_new_tokens))
                     self.run_until_idle()
                     runs += 1
+                    self._close_warmup_round(sp, mark, n, tb)
         finally:
             self.prefix_cache = saved_prefix
             self.config.max_waiting = saved_cap
-        if not self._slots:
-            # compile the active-flag update now (no slot is live: a no-op)
-            self._set_active([0], False)
+            self._warm_log_index = compile_cache.log_index()
+            self._warm_counters = compile_cache.compile_counters()
         return runs
+
+    def _close_warmup_round(self, span: HostSpan, mark: int, batch: int,
+                            bucket: int) -> None:
+        """End one round of the grid: its wall time split by the compile
+        log's records since ``mark`` into ``trace_s``, ``lower_s``,
+        ``compile_s`` (of which ``cache_retrieval_s`` read the persistent
+        cache) and ``run_s`` = the rest, the programs actually running.
+        jax traces, lowers and compiles on the thread that calls the
+        program, the engine thread here, so the four add up to the round's
+        wall time. Kept for ``get_metrics()["warmup"]`` and written on the
+        round's ring record."""
+        records, _ = compile_cache.compile_log(mark)
+        parts = compile_cache.log_summary(records)
+        wall = time.perf_counter() - span.t0
+        parts["run_s"] = wall - (parts["trace_s"] + parts["lower_s"]
+                                 + parts["compile_s"])
+        span.close(**parts)
+        self._warmup_rounds.append(
+            {"batch": batch, "bucket": bucket, "wall_s": wall, **parts})
 
     def warmup_from_manifest(self, max_new_tokens: int = 2) -> int:
         """Artifact-aware warmup: prime only the admission batch buckets
@@ -2789,6 +2827,41 @@ class ContinuousEngine:
                    for n in batches)
 
     # ------------------------------------------------------------ metrics
+
+    def warmup_metrics(self) -> Dict[str, Any]:
+        """The warm-up grid's rounds (``_close_warmup_round``) and the
+        totals of each part over them."""
+        rounds = self._warmup_rounds
+        totals = {k: sum(r[k] for r in rounds) for k in (
+            "wall_s", "trace_s", "lower_s", "compile_s", "cache_retrieval_s",
+            "run_s", "cache_hits", "cache_misses")}
+        return {"rounds": list(rounds), **totals}
+
+    def _compiles_after_warmup(self) -> Dict[str, Any]:
+        """What the process traced, lowered and compiled since this
+        engine's warm-up ended (its construction, for one never warmed):
+        the compiler's counters since then, and the newest programs by
+        name. The log is the process's: a second engine's programs, or a
+        staging thread's, are in it too."""
+        now = compile_cache.compile_counters()
+        was = self._warm_counters
+        entries: List[Dict[str, Any]] = []
+        group: List[Dict[str, Any]] = []   # one program's records
+        for r in compile_cache.compile_log(self._warm_log_index)[0]:
+            group.append(r)
+            if r["phase"] == "backend_compile":
+                parts = compile_cache.log_summary(group)
+                entries.append({
+                    "program": r["fun_name"], "t0": r["t0"],
+                    "trace_s": parts["trace_s"], "lower_s": parts["lower_s"],
+                    "compile_s": r["dur_s"], "cache": r["cache"],
+                    "span": r.get("span")})
+                group = []
+        return {
+            "count": now["backend_compiles"] - was["backend_compiles"],
+            "seconds": sum(now[k] - was[k] for k in (
+                "trace_s", "lower_s", "backend_compile_s")),
+            "last": entries[-32:]}
 
     def get_metrics(self) -> Dict[str, Any]:
         offload_m: Dict[str, Any] = {}
@@ -2863,6 +2936,8 @@ class ContinuousEngine:
                 "experts_touched": int(self._moe_decode_counts[2]),
                 "decode_assignments_held": int(self._moe_decode_counts[0]),
             },
+            "warmup": self.warmup_metrics(),
+            "compiles_after_warmup": self._compiles_after_warmup(),
             "prefilling_slots": len(self._prefilling),
             "chunked_admissions": self._chunked_admissions,
             "deferred_admissions": self._deferred_admissions,

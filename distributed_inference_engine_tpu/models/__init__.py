@@ -208,7 +208,8 @@ def engine_from_config(cfg):
               "prefix_cache", "prefill_chunk",
               "max_waiting", "queue_deadline_s",
               "kv_offload", "kv_offload_bytes",
-              "stream_chunk_steps", "admission_max_rows"):
+              "stream_chunk_steps", "admission_max_rows",
+              "timeline_capacity"):
         if k in cfg.metadata:
             setattr(ecfg, k, cfg.metadata[k])
     if spec.layer_kinds:

@@ -133,6 +133,16 @@ ENGINE_MLA_TABLE = [              # engine.get_metrics()["mla"]
      "Key blocks of their buckets' whole squares: what no skipping would visit"),
 ]
 
+ENGINE_WARMUP_TABLE = [           # engine.get_metrics()["warmup"]
+    ("run_s", "worker_warmup_run_seconds", "g",
+     "Warm-up seconds outside trace, lower and compile: programs running"),
+]
+
+ENGINE_AFTER_WARMUP_TABLE = [ # get_metrics()["compiles_after_warmup"]
+    ("count", "worker_compiles_after_warmup", "c",
+     "Programs the backend was asked for since the engine's warm-up ended"),
+]
+
 KV_TABLE = [                       # PagedKVCache.get_stats()
     ("num_pages", "kv_pages", "g", "HBM page-pool size"),
     ("page_size", "kv_page_size", "g", "Tokens per KV page"),
@@ -460,12 +470,20 @@ WORKER_COMPILE_TABLE = [           # get_metrics()["device"]["compile"]
      "Programs supplied by the persistent compile cache"),
     ("cache_misses", "worker_compile_cache_misses", "c",
      "Programs XLA compiled and the persistent cache then stored"),
+    ("trace_s", "worker_backend_trace_seconds", "c",
+     "Seconds tracing Python functions to jaxprs (inner jits not twice)"),
+    ("lower_s", "worker_backend_lower_seconds", "c",
+     "Seconds lowering jaxprs to MLIR modules (Mosaic kernels included)"),
+    ("cache_retrieval_s", "worker_compile_cache_retrieval_seconds", "c",
+     "Seconds of the backend compile requests spent reading cache entries"),
 ]
 
 _GROUPS: List[Tuple[List, Tuple[str, ...]]] = [
     (ENGINE_TABLE, MODEL_LABELS),
     (ENGINE_OFFLOAD_TABLE, MODEL_LABELS),
     (ENGINE_MLA_TABLE, MODEL_LABELS),
+    (ENGINE_WARMUP_TABLE, MODEL_LABELS),
+    (ENGINE_AFTER_WARMUP_TABLE, MODEL_LABELS),
     (KV_TABLE, MODEL_LABELS),
     (OFFLOAD_TABLE, MODEL_LABELS),
     (PUMP_TABLE, MODEL_LABELS),
@@ -557,6 +575,10 @@ def apply_engine(reg: MetricsRegistry, m: Optional[Mapping[str, Any]],
     latent = m.get("mla")
     if isinstance(latent, Mapping):
         _apply_table(reg, ENGINE_MLA_TABLE, latent, MODEL_LABELS, labels)
+    for key, table in (("warmup", ENGINE_WARMUP_TABLE),
+                       ("compiles_after_warmup", ENGINE_AFTER_WARMUP_TABLE)):
+        if isinstance(m.get(key), Mapping):
+            _apply_table(reg, table, m[key], MODEL_LABELS, labels)
     kv = m.get("kv")
     if isinstance(kv, Mapping):
         _apply_table(reg, KV_TABLE, kv, MODEL_LABELS, labels)

@@ -32,6 +32,8 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from ..utils import compile_cache
+
 _annotation_cls: Any = None
 # a TraceAnnotation's args travel as ``#k=v,k=v#`` text behind its name
 _ANNOTATION_UNSAFE = str.maketrans({",": ";", "#": "~"})
@@ -50,14 +52,20 @@ def _trace_annotation() -> Any:
     return _annotation_cls
 
 
-def _backend_compiles() -> int:
-    """The compiler's own count of the programs it was asked for
-    (``utils.compile_cache``); 0 in a process without jax."""
-    if "jax" not in sys.modules:
-        return 0
-    from ..utils.compile_cache import compile_counters
-
-    return compile_counters()["backend_compiles"]
+def compile_keys(records: List[Dict[str, Any]]) -> Dict[str, Any]:
+    """What a span's ring record says of the compile-log records that
+    closed inside it: ``compile_s`` / ``trace_s`` / ``lower_s``, the
+    ``programs`` the backend was asked for and ``cache`` (each one's
+    answer); ``compile=True`` when there was such a program."""
+    parts = compile_cache.log_summary(records)
+    keys: Dict[str, Any] = {"trace_s": parts["trace_s"],
+                            "lower_s": parts["lower_s"]}
+    if parts["programs"]:
+        keys.update(compile=True, compile_s=parts["compile_s"],
+                    programs=parts["programs"],
+                    cache=[r["cache"] for r in records
+                           if r["phase"] == "backend_compile"])
+    return keys
 
 
 class StepTimeline:
@@ -68,6 +76,9 @@ class StepTimeline:
         self.capacity = int(capacity)
         self._events: deque = deque(maxlen=max(1, self.capacity))
         self._epoch = time.perf_counter()
+        if "jax" in sys.modules:
+            # the spans of this ring say what compiled inside them
+            compile_cache.install_compile_counters()
         self._capture_from: Optional[float] = None
         self._dropped = 0
         self._open: List[str] = []       # names of the spans open now
@@ -170,13 +181,17 @@ class HostSpan:
     Open at construction, so a dispatch site can open it where its bracket
     starts and close it where it ends without re-indenting what lies
     between. ``close(**more)`` adds what is only known at the end to the
-    ring record (the annotation keeps what it was opened with). A dispatch
-    bracket's record is flagged ``compile=True`` when the compiler's
-    counter grew across it: that dispatch paid an XLA compile or a
-    compile-cache load."""
+    ring record (the annotation keeps what it was opened with). A span
+    with a ring reads one integer when it opens
+    (``compile_cache.log_index``); only if that grew by its close does it
+    take the log's delta: the records no inner span claimed get its name
+    (``span``: where a compile ran), and a dispatch bracket's ring record
+    gets ``compile_keys``: that dispatch paid an XLA compile or a
+    compile-cache load, of these programs, for this long. The compile ran
+    inside the open annotation, so in a profile it lies under this span."""
 
     __slots__ = ("name", "t0", "args", "_tl", "_ann", "_parent", "_dispatch",
-                 "_compiles")
+                 "_logged")
 
     def __init__(self, timeline: Optional[StepTimeline], name: str,
                  dispatch: bool, args: Dict[str, Any]) -> None:
@@ -185,9 +200,8 @@ class HostSpan:
         self._tl = timeline
         self._dispatch = dispatch
         self._parent: Optional[str] = None
-        self._compiles = (_backend_compiles()
-                          if dispatch and timeline is not None else 0)
         if timeline is not None:
+            self._logged = compile_cache.log_index()
             if timeline._open:
                 self._parent = timeline._open[-1]
             timeline._open.append(name)
@@ -211,8 +225,12 @@ class HostSpan:
             # tolerate a span an exception skipped: pop down to this one
             while tl._open and tl._open.pop() != self.name:
                 pass
-            if self._dispatch and _backend_compiles() != self._compiles:
-                more["compile"] = True
+            if compile_cache.log_index() != self._logged:
+                records, _ = compile_cache.compile_log(self._logged)
+                for r in records:
+                    r.setdefault("span", self.name)
+                if self._dispatch:
+                    more.update(compile_keys(records))
             tl.record(self.name, self.t0, now - self.t0,
                       parent=self._parent, dispatch=self._dispatch,
                       **{**self.args, **more})

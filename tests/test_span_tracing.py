@@ -7,7 +7,10 @@ import asyncio
 import os
 import subprocess
 import sys
+import threading
+import time
 
+import jax
 import jax.monitoring
 import numpy as np
 import pytest
@@ -27,6 +30,7 @@ from distributed_inference_engine_tpu.obs.timeline import (
     host_span,
 )
 from distributed_inference_engine_tpu.serving.pump import EnginePump
+from distributed_inference_engine_tpu.utils import compile_cache
 from distributed_inference_engine_tpu.utils.compile_cache import (
     compile_counters,
 )
@@ -233,7 +237,12 @@ def test_a_decode_chunk_adds_a_bounded_number_of_records(slots, steps, n_new):
     steps = [e for e in events if e["name"] == "engine.step"]
     assert len(chunks) <= len(steps) <= len(chunks) + 1   # one per step()
     assert all(e["parent"] == "engine.step" for e in chunks)
-    assert len(events) == 4 * len(chunks) + len(admits) + len(steps)
+    # (a chunk that revives paused rows flips their active flags under a
+    # span of its own, outside the brackets)
+    flips = [e for e in events if e["name"] == "engine.set_active"]
+    assert all(e["parent"] == "engine.process_packed" for e in flips)
+    assert len(events) == (4 * len(chunks) + len(admits) + len(steps)
+                           + len(flips))
     # only the dispatch brackets count as busy time
     split = busy_gap_split(events)
     assert split["n_events"] == sum(1 for e in events if e["dispatch"])
@@ -459,3 +468,301 @@ def test_the_residual_scope_is_on_the_mhc_familys_programs_alone():
         assert "resid.mhc" not in text
         assert "/attn.mla/" in text and "/moe.experts/" in text
         assert "/attn.kda" in text
+
+
+# ------------------------------------------------ set-up seen from inside
+
+
+TRACE, LOWER, BACKEND, RETRIEVAL, SAVED = compile_cache.DURATION_EVENTS
+HIT = "/jax/compilation_cache/cache_hits"
+MISS = "/jax/compilation_cache/cache_misses"
+ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+
+
+def test_the_five_duration_events_exist_and_carry_the_programs_name():
+    """The listener's event names against the installed jax's own, and
+    ``fun_name`` as the keyword its timing context sends."""
+    import inspect
+
+    from jax._src import compiler, dispatch
+
+    assert (dispatch.JAXPR_TRACE_EVENT, dispatch.JAXPR_TO_MLIR_MODULE_EVENT,
+            dispatch.BACKEND_COMPILE_EVENT) == (TRACE, LOWER, BACKEND)
+    assert "fun_name=self.fun_name" in inspect.getsource(
+        dispatch.LogElapsedTimeContextManager.__exit__)
+    # the cache's read is timed INSIDE compile_or_get_cached, which runs
+    # under the backend-compile event: a part of it, not a fourth addend
+    src = inspect.getsource(compiler.compile_or_get_cached)
+    for event in (RETRIEVAL, SAVED, HIT, ASKED):
+        assert event in src, event
+
+
+def test_the_listener_keeps_name_phase_seconds_and_the_caches_answer():
+    on_duration, on_event = (compile_cache._on_duration,
+                             compile_cache._on_event)
+    before = compile_counters()
+    mark = compile_cache.log_index()
+    on_duration(TRACE, 0.010, fun_name="step")
+    on_duration(LOWER, 0.020, fun_name="jit(step)")
+    on_event(ASKED)
+    on_event(HIT)
+    on_duration(SAVED, 3.0)
+    on_duration(RETRIEVAL, 0.004)
+    on_duration(BACKEND, 0.005, fun_name="jit(step)")
+    on_event(ASKED)
+    on_event(MISS)
+    on_duration(BACKEND, 0.5, fun_name="jit(other)")
+    on_event(ASKED)                       # under the cache's thresholds
+    on_duration(BACKEND, 0.001)           # and jax sent no name
+    on_duration(BACKEND, 0.002, fun_name="jit(uncached)")
+    on_duration("/jax/some/other_duration", 9.0, fun_name="x")
+    records, nxt = compile_cache.compile_log(mark)
+    assert nxt == mark + 6 == compile_cache.log_index()
+    assert [(r["fun_name"], r["phase"], r["dur_s"]) for r in records] == [
+        ("step", "trace", 0.010), ("jit(step)", "lower", 0.020),
+        ("jit(step)", "backend_compile", 0.005),
+        ("jit(other)", "backend_compile", 0.5),
+        ("?", "backend_compile", 0.001),
+        ("jit(uncached)", "backend_compile", 0.002)]
+    assert [r.get("cache") for r in records] == [
+        None, None, "hit", "miss", "unstored", "off"]
+    assert records[2]["cache_retrieval_s"] == 0.004
+    assert all(r["t0"] <= time.perf_counter() for r in records)
+    after = compile_counters()
+    grew = {k: after[k] - before[k] for k in after}
+    assert grew == pytest.approx({
+        "backend_compiles": 4, "backend_compile_s": 0.508, "cache_hits": 1,
+        "cache_misses": 1, "traces": 1, "trace_s": 0.010, "lowerings": 1,
+        "lower_s": 0.020, "cache_retrieval_s": 0.004,
+        "compile_time_saved_s": 3.0})
+    parts = compile_cache.log_summary(records)
+    assert parts["programs"] == ["jit(step)", "jit(other)", "?",
+                                 "jit(uncached)"]
+    assert (parts["cache_hits"], parts["cache_misses"]) == (1, 1)
+    assert parts["compile_s"] == pytest.approx(0.508)
+    assert compile_cache.compile_log(nxt) == ([], nxt)
+
+
+def test_the_log_is_bounded_and_a_delta_survives_the_wrap():
+    mark = compile_cache.log_index()
+    for i in range(compile_cache.LOG_CAPACITY + 10):
+        compile_cache._on_duration(LOWER, 1e-6, fun_name=f"f{i}")
+    records, nxt = compile_cache.compile_log(mark)
+    assert nxt == mark + compile_cache.LOG_CAPACITY + 10
+    assert len(records) == compile_cache.LOG_CAPACITY       # the newest
+    assert records[-1]["fun_name"] == f"f{compile_cache.LOG_CAPACITY + 9}"
+    tail, _ = compile_cache.compile_log(nxt - 3)
+    assert [r["fun_name"] for r in tail] == [r["fun_name"]
+                                             for r in records[-3:]]
+
+
+def test_a_real_jit_yields_its_three_records_and_an_inner_jit_counts_once():
+    @jax.jit
+    def span_test_inner(x):
+        return x * 2 + 1
+
+    @jax.jit
+    def span_test_outer(x):
+        return span_test_inner(x).sum()
+
+    arg = np.ones((3, 5), np.float32)
+    before = compile_counters()
+    mark = compile_cache.log_index()
+    t0 = time.perf_counter()
+    span_test_outer(arg)
+    wall = time.perf_counter() - t0
+    records, _ = compile_cache.compile_log(mark)
+    mine = {r["phase"]: r for r in records
+            if "span_test_outer" in r["fun_name"]}
+    assert set(mine) == {"trace", "lower", "backend_compile"}
+    assert mine["backend_compile"]["cache"] == "off"     # the suite's setting
+    # the inner jit (and the jitted jnp functions inside both) fired their
+    # trace events INSIDE the outer's duration: counted, and neither
+    # logged nor added to the seconds
+    assert not any(r["fun_name"] == "span_test_inner" for r in records)
+    after = compile_counters()
+    parts = compile_cache.log_summary(records)
+    assert after["traces"] - before["traces"] >= 3 + sum(
+        r["phase"] == "trace" for r in records)
+    assert after["trace_s"] - before["trace_s"] == pytest.approx(
+        parts["trace_s"])
+    assert parts["trace_s"] + parts["lower_s"] + parts["compile_s"] <= wall
+    # fed by hand, enter and exit in jax's order: the same bookkeeping
+    mark = compile_cache.log_index()
+    for name in ("outer", "inner"):
+        compile_cache._on_scalar(TRACE, 0.0, fun_name=name)
+    compile_cache._on_duration(TRACE, 0.25, fun_name="inner")
+    compile_cache._on_duration(TRACE, 1.0, fun_name="outer")
+    compile_cache._on_duration(TRACE, 0.5, fun_name="next")
+    assert [(r["fun_name"], r["dur_s"])
+            for r in compile_cache.compile_log(mark)[0]] == [
+        ("outer", 1.0), ("next", 0.5)]
+    assert compile_counters()["trace_s"] - after["trace_s"] == \
+        pytest.approx(1.5)
+    assert compile_counters()["traces"] - after["traces"] == 3
+
+
+def test_a_span_that_did_not_compile_adds_no_key_and_reads_no_counters(
+        monkeypatch):
+    tl = StepTimeline(capacity=8)
+    monkeypatch.setattr(
+        compile_cache, "compile_counters",
+        lambda: pytest.fail("a span called compile_counters()"))
+    monkeypatch.setattr(
+        compile_cache, "compile_log",
+        lambda since=0: pytest.fail("a quiet span took the log's delta"))
+    host_span(tl, "engine.decode.dispatch", dispatch=True, steps=4).close(
+        program=("decode", 4))
+    (ev,) = tl.events()
+    assert ev["args"] == {"steps": 4, "program": ("decode", 4)}
+    monkeypatch.undo()
+    # one that compiled names the program, its seconds and where it ran
+    outer = host_span(tl, "engine.admit")
+    sp = host_span(tl, "engine.prefill.dispatch", dispatch=True)
+    mark = compile_cache.log_index()
+    compile_cache._on_duration(TRACE, 0.25, fun_name="prefill")
+    compile_cache._on_duration(LOWER, 0.5, fun_name="jit(prefill)")
+    compile_cache._on_duration(BACKEND, 1.5, fun_name="jit(prefill)")
+    sp.close()
+    compile_cache._on_duration(BACKEND, 0.125, fun_name="jit(install)")
+    outer.close()
+    _quiet, bracket, admit = tl.events()
+    assert bracket["args"] == {
+        "compile": True, "trace_s": 0.25, "lower_s": 0.5, "compile_s": 1.5,
+        "programs": ["jit(prefill)"], "cache": ["off"]}
+    assert admit["args"] == {}           # not a dispatch bracket: no keys
+    assert [r["span"] for r in compile_cache.compile_log(mark)[0]] == [
+        "engine.prefill.dispatch"] * 3 + ["engine.admit"]
+
+
+def test_warmup_rounds_are_spans_whose_four_parts_sum_to_their_wall():
+    engine = ContinuousEngine(SPEC, config=_cfg(max_slots=2), seed=0)
+    grid = [(n, tb) for n in (1, 2) for tb in engine.prefill_buckets]
+    engine_thread = threading.get_ident()
+    t0 = time.perf_counter()
+    assert engine.warmup() == len(grid)
+    wall = time.perf_counter() - t0
+    warm = engine.get_metrics()["warmup"]
+    rounds = warm["rounds"]
+    assert [(r["batch"], r["bucket"]) for r in rounds] == grid
+    for r in rounds:
+        assert r["trace_s"] + r["lower_s"] + r["compile_s"] + r["run_s"] \
+            == pytest.approx(r["wall_s"])
+        # compiles ran on the calling thread: nothing left over is negative
+        assert r["run_s"] > 0 and r["cache_retrieval_s"] <= r["compile_s"]
+    first = rounds[0]
+    assert first["programs"] and first["compile_s"] > 0 < first["trace_s"]
+    assert any("_decode_chunk" in p for p in first["programs"])
+    assert warm["wall_s"] == pytest.approx(sum(r["wall_s"] for r in rounds))
+    assert 0.9 * wall <= warm["wall_s"] <= wall
+    assert warm["compile_s"] == pytest.approx(
+        sum(r["compile_s"] for r in rounds))
+    spans = [e for e in engine.timeline.events()
+             if e["name"] == "engine.warmup.round"]
+    assert [(e["args"]["batch"], e["args"]["bucket"]) for e in spans] == grid
+    assert spans[0]["args"]["programs"] == first["programs"]
+    assert spans[0]["dur"] == pytest.approx(first["wall_s"], abs=1e-3)
+    inside = [e for e in engine.timeline.events()
+              if e["name"] == "engine.prefill.dispatch"
+              and e["args"].get("compile")]
+    assert inside and inside[0]["args"]["programs"]
+    assert engine.get_metrics()["compiles_after_warmup"] == {
+        "count": 0, "seconds": 0.0, "last": []}
+    # a repeat finds every program: all of a round is running
+    assert engine.warmup() == len(grid)
+    again = engine.get_metrics()["warmup"]["rounds"][len(grid):]
+    assert len(again) == len(grid)
+    assert all(r["programs"] == [] and r["compile_s"] == 0.0
+               and r["run_s"] == pytest.approx(r["wall_s"]) for r in again)
+    assert threading.get_ident() == engine_thread
+
+
+def test_a_compile_after_warmup_is_counted_and_named_with_its_span():
+    engine = ContinuousEngine(SPEC, config=_cfg(max_slots=2), seed=0)
+    engine.warmup()
+    # 24 new tokens reach a context bucket the grid's two-token rounds
+    # never did: one decode program more
+    mark = len(engine.timeline.events())
+    engine.generate([_req(0, 24)])
+    after = engine.get_metrics()["compiles_after_warmup"]
+    assert after["count"] == len(after["last"]) >= 1
+    entry = after["last"][0]
+    assert "_decode_chunk" in entry["program"]
+    assert entry["span"] == "engine.decode.dispatch"
+    assert entry["cache"] == "off" and entry["compile_s"] > 0
+    assert after["seconds"] == pytest.approx(sum(
+        e["trace_s"] + e["lower_s"] + e["compile_s"]
+        for e in after["last"]))
+    flagged = [e for e in engine.timeline.events()[mark:]
+               if e["args"].get("compile")]
+    assert flagged[0]["args"]["programs"] == [entry["program"]]
+    assert flagged[0]["t"] <= entry["t0"] <= (flagged[0]["t"]
+                                              + flagged[0]["dur"])
+    # the active-flag update has a span of its own, outside every bracket
+    engine._set_active([0], False)
+    assert engine.timeline.events()[-1]["name"] == "engine.set_active"
+
+
+async def test_the_worker_reports_boot_marks_build_and_the_warmups_parts():
+    from distributed_inference_engine_tpu.obs import collectors
+    from distributed_inference_engine_tpu.obs.registry import MetricsRegistry
+
+    cfg = _tiny_llama()
+    cfg.metadata["warmup"] = 1
+    w = WorkerServer(ServerConfig(worker_id="w0", host="127.0.0.1", port=0))
+    compile_cache.install_compile_counters()          # as cli.worker does
+    w.boot.update(main_entered=time.perf_counter())
+    try:
+        w.mark_boot("load_begin")                     # cli.worker, again
+        await w.load_model_async(cfg)
+        w.mark_boot("load_end")
+        host, port = await w.start()
+        m = w.get_metrics()
+        boot = m["boot"]
+        order = ["main_entered", "load_begin", "load_end", "listening"]
+        marks = [boot[k] for k in order]
+        assert marks == sorted(marks) and list(boot) == order
+        w.mark_boot("load_begin")          # a later pass moves no mark
+        assert w.boot_report() == boot
+        setup = m["model_setup"]["m"]
+        assert set(setup) == {"load_s", "warmup_s", "warmup"}
+        assert 0 < setup["warmup_s"] < setup["load_s"]
+        assert boot["load_end"] - boot["load_begin"] == pytest.approx(
+            setup["load_s"], abs=0.05)
+        warm = setup["warmup"]
+        assert warm["rounds"] and warm["wall_s"] == pytest.approx(
+            setup["warmup_s"], rel=0.01)
+        assert warm["trace_s"] + warm["lower_s"] + warm["compile_s"] \
+            + warm["run_s"] == pytest.approx(warm["wall_s"])
+        assert m["mono"] <= time.perf_counter()
+        compiled = m["device"]["compile"]
+        assert compiled["trace_s"] > 0 < compiled["lower_s"]
+        assert compiled["cache_retrieval_s"] <= compiled["backend_compile_s"]
+        reg = MetricsRegistry()
+        collectors.apply_worker(reg, m)
+        text = reg.render()
+        for name in ("worker_backend_trace_seconds",
+                     "worker_backend_lower_seconds",
+                     "worker_compile_cache_retrieval_seconds",
+                     "worker_compiles_after_warmup",
+                     "worker_warmup_run_seconds"):
+            assert f"\n{name}" in text, name
+    finally:
+        await w.stop()
+
+
+def test_deploy_metadata_sizes_the_ring_and_without_it_no_span_is_named():
+    """``timeline_capacity`` travels in a served model's metadata like its
+    neighbours; with the ring off a compile is still counted and named,
+    and says no span."""
+    from distributed_inference_engine_tpu.models import engine_from_config
+
+    cfg = _tiny_llama()
+    assert engine_from_config(cfg).timeline.capacity == 4096
+    cfg.metadata["timeline_capacity"] = 0
+    engine = engine_from_config(cfg)
+    assert engine.timeline is None
+    engine.generate([GenerationRequest(prompt=[5, 6, 7], max_new_tokens=3)])
+    after = engine.get_metrics()["compiles_after_warmup"]
+    assert after["count"] >= 2 and after["seconds"] > 0
+    assert {e["span"] for e in after["last"]} == {None}
