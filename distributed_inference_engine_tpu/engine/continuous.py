@@ -94,13 +94,12 @@ def resolve_decode_body(impl: str, backend: str, spec,
     ========  ==========================================  =================
     body      when                                        attention
     ========  ==========================================  =================
-    hybrid    ``spec.layer_kinds``: latent rows           the family's, XLA,
-              (``spec.kv_row_lanes`` 0)                   the table gathered
-                                                          once a chunk
-    hybrid    ``spec.layer_kinds``: K|V rows              ``ops/flash_
-              (``spec.kv_row_lanes`` > 0), the kernel     decode.py`` in
-              applies                                     place from the
-                                                          family's ONE pool;
+    hybrid    ``spec.layer_kinds`` (latent rows, or      ``ops/flash_
+              K|V rows where ``spec.kv_row_lanes``        decode.py`` in
+              > 0), the kernel applies                    place from the
+                                                          family's ONE pool
+                                                          (its latent or
+                                                          its K|V kernel);
                                                           else XLA, a layer's
                                                           pages a step
     inline    uniform spec with ``sliding_window``        XLA, per-step
@@ -114,9 +113,10 @@ def resolve_decode_body(impl: str, backend: str, spec,
                                                           chunk
     ========  ==========================================  =================
 
-    ``"auto"`` takes the kernel on a TPU with the pool on one device and a
-    fused ``Hkv·Dh`` of whole 128-lane tiles (a uniform spec's, or a
-    per-layer spec's K|V rows); ``"pallas-decode"`` /
+    ``"auto"`` takes the kernel on a TPU with the pool on one device and
+    rows of whole 128-lane tiles (a uniform spec's fused ``Hkv·Dh``, a
+    per-layer spec's K|V rows, or its latent rows, which the pool holds at
+    whole tiles: ``ModelSpec.cache_row_width``); ``"pallas-decode"`` /
     ``"pallas-decode_interpret"`` ask for it by name (the tests' way to
     run the TPU body on a CPU); ``"xla"`` refuses it. A sliding-window spec
     runs ``inline`` whatever the string says: its prefix mask depends on the
@@ -127,20 +127,10 @@ def resolve_decode_body(impl: str, backend: str, spec,
     if impl not in ATTENTION_IMPLS:
         raise ValueError(
             f"attention_impl {impl!r} is not one of {ATTENTION_IMPLS}")
-    # lanes of a K (or V) row the kernel would copy: a uniform spec's, or
-    # those of a per-layer spec whose paged layers keep K|V rows
-    lanes = (spec.kv_row_lanes if spec.layer_kinds
+    # lanes of a row the kernel would copy: a uniform spec's K (or V), those
+    # of a per-layer spec whose paged layers keep K|V rows, or its latent row
+    lanes = ((spec.kv_row_lanes or spec.cache_row_width) if spec.layer_kinds
              else spec.n_kv_heads * spec.head_dim)
-    if spec.layer_kinds and not lanes:
-        # latent rows (576 wide: no whole 128-lane tiles, and no K/V to
-        # read): the family's XLA attention, whatever the backend
-        # (ops/mla.py)
-        if impl not in ("auto", "xla"):
-            raise ValueError(
-                f"attention_impl {impl!r}: a per-layer (hybrid) spec whose "
-                "paged layers keep latent rows runs their attention on the "
-                "XLA path only; the kernel reads K/V rows")
-        return "hybrid", "xla"
     if spec.sliding_window:
         return "inline", "xla"
     if impl == "auto":
@@ -149,7 +139,7 @@ def resolve_decode_body(impl: str, backend: str, spec,
     if spec.layer_kinds:
         if impl != "xla" and lanes % 128:
             raise ValueError(
-                f"attention_impl {impl!r}: the kernel copies K/V rows of "
+                f"attention_impl {impl!r}: the kernel copies rows of "
                 f"whole 128-lane tiles, this spec's have {lanes}")
         return "hybrid", impl
     return ("dense" if impl == "xla" else "window"), impl
@@ -443,16 +433,15 @@ class ContinuousEngine:
         # produced before (merged into the result at the finish)
         self._resumed: Dict[str, Dict[str, Any]] = {}
         self._reprefill_preemptions = 0
-        # latent rows the decode steps attended to, and rows the hybrid
-        # body read for them (the whole table, every step): host sums from
-        # each chunk's packed output
+        # latent rows the decode steps attended to (a host sum from each
+        # chunk's packed output) and rows the attention READ for them,
+        # which the program counts (the kernel's own count of the pages it
+        # copied, the last of the family's counters)
         self._mla_context_rows = 0
         self._mla_table_rows = 0
-        # where the paged layers keep K|V rows: rows attended to (the same
-        # host sum) and rows the attention READ, which the program counts
-        # (the kernel's own count of the pages it copied, in the first of
-        # the family's three counters); and the (row, step) pairs that
-        # moved a recurrent state; all per layer
+        # where the paged layers keep K|V rows: the same two (the program's
+        # count in the first of the family's counters); and the (row, step)
+        # pairs that moved a recurrent state; all per layer
         self._kv_rows = bool(self.spec.layer_kinds
                              and self.spec.kv_row_lanes)
         self._full_context_rows = 0
@@ -860,15 +849,14 @@ class ContinuousEngine:
                 use_stops: bool = False,
             ):
                 """``n_steps`` tokens for every live slot. The pages are
-                frozen for the chunk (the family gathers its latent rows
-                once, or reads its K|V rows where they lie:
-                ``decode_context``); fresh rows gather
+                frozen for the chunk and the family reads its latent or K|V
+                rows where they lie (``decode_context``); fresh rows gather
                 in a side window written back once at the end; the
                 recurrent state rides the scan carry and moves only for
                 rows that are ``active`` at that step. The packed output
-                gains three rows: the family's counters for the chunk (a
-                routed family's MoE counts; a K|V family's rows read)."""
-                del n_ctx_pages        # one program: the whole table
+                gains the family's counters for the chunk, a row each (a
+                routed family's MoE counts; rows the attention read)."""
+                del n_ctx_pages        # one program: it reads the live pages
                 start_lengths = lengths
                 b = lengths.shape[0]
                 advance = partial(_advance, cap=cap, max_new=max_new,
@@ -893,7 +881,7 @@ class ContinuousEngine:
 
                 carry, (toks, lps) = jax.lax.scan(
                     step, (side, vp, lengths, last_tokens, active, produced,
-                           jnp.zeros((3,), jnp.int32)),
+                           jnp.zeros((fam.DECODE_COUNTERS,), jnp.int32)),
                     jax.random.split(key, n_steps))
                 side, vp, lengths, last, active, produced, moe = carry
                 kp = fam.write_rows_into_pages(
@@ -902,7 +890,8 @@ class ContinuousEngine:
                 packed = jnp.concatenate(
                     [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
                      active[None].astype(jnp.int32), lengths[None], firsts,
-                     jnp.broadcast_to(moe[:, None], (3, b))], axis=0)
+                     jnp.broadcast_to(moe[:, None], (moe.shape[0], b))],
+                    axis=0)
                 return (kp, vp, lengths, last, active, produced), packed
 
         @partial(jax.jit, donate_argnums=tuple(range(11)))
@@ -2419,14 +2408,16 @@ class ContinuousEngine:
         firsts_lp = packed_np[2 * n_steps + 3].view(np.float32)
         self._decode_steps += n_steps
         if self.spec.layer_kinds:
-            moe = packed_np[2 * n_steps + 4: 2 * n_steps + 7, 0]
+            moe = packed_np[2 * n_steps + 4:, 0]
+            # what the attention READ, a paged layer, is counted in the
+            # program: a K|V family's first counter, a latent-row family's
+            # last, after the routed experts' three
             if self._kv_rows:
-                # K|V rows: the first of the family's counters is what its
-                # attention READ, a paged layer, counted in the program
                 self._full_table_rows += int(moe[0])
             else:
-                self._moe_decode_counts += moe
-                self._moe_counts += moe
+                self._moe_decode_counts += moe[:3]
+                self._moe_counts += moe[:3]
+                self._mla_table_rows += int(moe[3])
             # prefills dispatched before this chunk have finished
             while self._prefill_moe:
                 # graftlint: ok[host-sync-hot-path] 3 ints of a program that ended before the chunk just read
@@ -2452,17 +2443,14 @@ class ContinuousEngine:
         if self._per_layer:
             # a row that emitted c tokens and ends at length e attended to
             # e - c + 1 ... e rows (cached + the chunk's own, its new one
-            # included); the body read the whole table at every step
+            # included)
             ends = packed_np[2 * n_steps + 1]
             attended = int((counts_np * (ends - counts_np)
                             + counts_np * (counts_np + 1) // 2).sum())
-            table = (n_steps * self.max_slots * self.kv.max_pages_per_seq
-                     * self.kv.page_size)
             if self._kv_rows:
                 self._full_context_rows += attended
             else:
                 self._mla_context_rows += attended
-                self._mla_table_rows += table
             if self._recurrent:
                 self._state_rows_updated += int(counts_np.sum())
         tok_cols = toks_np.T.tolist()

@@ -199,12 +199,14 @@ class ModelSpec:
 
     @property
     def cache_row_width(self) -> int:
-        """Values one token adds to ONE paged pool row of one layer: a
-        latent row, K|V side by side (a per-layer spec has one pool), or
+        """Lanes of ONE paged pool row of one layer, a token's: a latent
+        row (``kv_lora_rank + qk_rope_head_dim`` values and zero lanes up to
+        whole 128-lane tiles: 576 -> 640, what the decode kernel copies and
+        multiplies), K|V side by side (a per-layer spec has one pool), or
         one of K and V (a uniform spec has a pool each)."""
         if self.layer_kinds:
-            return (2 * self.kv_row_lanes
-                    or self.kv_lora_rank + self.qk_rope_head_dim)
+            latent = self.kv_lora_rank + self.qk_rope_head_dim
+            return 2 * self.kv_row_lanes or -(-latent // 128) * 128
         return self.n_kv_heads * self.head_dim
 
     @property

@@ -22,8 +22,9 @@ chip computes its own experts' part and the shared expert, and the next-token
 prediction (MTP) layer is left out of the served tree (the next-token logits
 do not depend on it and the engine's step yields one token).
 
-Cache: an MLA layer adds one ``kv_lora_rank + qk_rope_head_dim`` row a token
-to its page pool; a KDA layer keeps ``S [H, dk, dk]`` float32 and the last
+Cache: an MLA layer adds one latent row a token to its page pool (``c`` |
+``k_rope`` | zero lanes up to whole 128-lane tiles, ``latent_row``; a decode
+step reads the live pages where they lie, ``latent_attention_step``); a KDA layer keeps ``S [H, dk, dk]`` float32 and the last
 ``conv - 1`` pre-convolution rows per SEQUENCE (``engine/paged_kv.py`` holds
 both). Pad positions of a prefill bucket and rows that are not live in a
 decode step leave a sequence's state exactly as it was.
@@ -224,32 +225,96 @@ def zero_state_slot(state: State, slot: jnp.ndarray) -> State:
     return {n: a.at[:, slot].set(0) for n, a in state.items()}
 
 
+# what a decode step's counters hold (``forward_decode_step``): the routed
+# experts' three (``ops/moe_routed.py``) and the latent rows the attention
+# read, a layer
+DECODE_COUNTERS = 4
+
+
 def decode_context(pages: jnp.ndarray, page_table: jnp.ndarray,
-                   attn_impl: str) -> jnp.ndarray:
-    """What a decode chunk's steps read the cached rows from. Latent rows
-    are gathered once a chunk (``attn_impl`` is "xla" for them): pages
-    [Lm, N, P, W], table [B, MP] -> [Lm, B, MP * P, W]."""
-    del attn_impl
-    lm, _n, p, w = pages.shape
-    b, mp = page_table.shape
-    with jax.named_scope("attn.kv_gather"):
-        return pages[:, page_table].reshape(lm, b, mp * p, w)
+                   attn_impl: str):
+    """What a decode chunk's steps read the cached rows from: the pool AS IT
+    LIES and the page table (nothing is gathered for the chunk)."""
+    return pages, page_table, attn_impl
 
 
 def write_rows_into_pages(pages, rows, page_table, counts, start):
-    """Scatter ``rows`` [Lm, B, T, W] into the pool: row b's token t lands
-    at absolute position ``start[b] + t`` while ``t < counts[b]``."""
+    """Scatter ``rows`` [L, B, T, W] into the pool [L, N, P, W]: row b's
+    token t lands at absolute position ``start[b] + t`` while ``t <
+    counts[b]``. The pool is scattered as ONE list of rows with the layer
+    folded into the index, whole rows along the major axis: XLA then writes
+    the donated pool where it lies (scattered along its middle axis it made
+    a transposed copy of all 3 GB of a K|V pool). The pool's width is the
+    one that counts: of rows wider than it, the leading lanes are kept (a
+    latent row's zero lanes go, ``latent_row``)."""
     lm, n, p, w = pages.shape
     _lm, b, t, _w = rows.shape
+    if _w > w:
+        rows = rows[..., :w]
     local = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
     pos = local + start[:, None]
     phys = jnp.take_along_axis(
         page_table, jnp.minimum(pos // p, page_table.shape[1] - 1), axis=1)
-    idx = jnp.where(local < counts[:, None], phys * p + pos % p, n * p)
+    idx = jnp.where(local < counts[:, None], phys * p + pos % p,
+                    lm * n * p)                                  # [B, T]
+    idx = jnp.where(idx < lm * n * p,
+                    idx[None] + (jnp.arange(lm) * n * p)[:, None, None],
+                    lm * n * p)                                  # [L, B, T]
     with jax.named_scope("attn.kv_update"):
-        flat = pages.reshape(lm, n * p, w).at[:, idx].set(
-            rows.astype(pages.dtype), mode="drop")
+        flat = pages.reshape(lm * n * p, w).at[idx.reshape(-1)].set(
+            rows.reshape(-1, w).astype(pages.dtype), mode="drop")
     return flat.reshape(lm, n, p, w)
+
+
+def latent_row(spec: ModelSpec, c, k_rope):
+    """A token's cache row as the pool holds it: normalised c | rotated
+    k_rope | zero lanes up to ``spec.cache_row_width`` (whole 128-lane
+    tiles, which both products of the decode kernel may read)."""
+    pad = spec.cache_row_width - c.shape[-1] - k_rope.shape[-1]
+    return jnp.concatenate(
+        [c, k_rope, jnp.zeros((*c.shape[:-1], pad), c.dtype)], -1)
+
+
+def latent_attention_step(spec: ModelSpec, w_kvb, q_nope, q_rope, row, ctx,
+                          layer, n_ctx, side, side_idx, active, scale=None):
+    """One token's absorbed attention for every slot, both latent-row
+    families': q_nope / q_rope [B, H, .]; ``row`` [B, W] this token's cache
+    row, written into ``side`` [B, Wc, W] (the chunk's own rows) at
+    ``side_idx`` where ``active``; ``ctx`` = (the pool [Lm, N, P, W], the
+    page table, the attention's name: ``decode_context``), of which paged
+    layer ``layer``'s rows below ``n_ctx`` are read where they lie (the
+    kernel, ``ops/flash_decode.py``) or from that layer's gathered pages
+    (XLA). Returns (o [B, H, dv], side, latent rows the body read: int32,
+    the kernel's own count of the pages it copied, or the whole gathered
+    table, plus the side window)."""
+    pages, page_table, impl = ctx
+    n_layers, n_pages, page, width = pages.shape
+    flat = pages.reshape(n_layers * n_pages, page, width)
+    r = spec.kv_lora_rank
+    with jax.named_scope("attn.kv_update"):
+        hot = (jnp.arange(side.shape[1])[None, :] == side_idx[:, None]) \
+            & active[:, None]
+        side = jnp.where(hot[..., None], row[:, None].astype(side.dtype),
+                         side)
+    w_kvb = w_kvb.reshape(r, spec.n_heads, -1)
+    # a row that is not live gets length 0: nothing of it is read
+    n_prefix = jnp.where(active, n_ctx, 0)
+    n_side = jnp.where(active, side_idx + 1, 0)
+    if impl == "xla":
+        with jax.named_scope("attn.kv_gather"):
+            b, mp = page_table.shape
+            own = flat[layer * n_pages + page_table].reshape(
+                b, mp * page, width)
+        o = mla.mla_absorbed_decode(q_nope, q_rope, w_kvb, own, n_prefix,
+                                    side, n_side, r, scale=scale)
+        n_pages_read = jnp.int32(b * mp)
+    else:
+        o, n_pages_read = mla.mla_absorbed_decode_inplace(
+            q_nope, q_rope, w_kvb, flat, page_table, layer, n_prefix, side,
+            n_side, r, scale=scale, n_pages_per_layer=n_pages,
+            interpret=impl.endswith("_interpret"))
+    rows_read = n_pages_read * page + side.shape[0] * side.shape[1]
+    return o, side, rows_read
 
 
 # ----------------------------------------------------------------- layers
@@ -324,8 +389,8 @@ def kda_layer_step(spec: ModelSpec, blk: Params, x, S_all, layer, tail,
 
 
 def _mla_inputs(spec: ModelSpec, blk: Params, h, positions):
-    """h [B, T, D] -> (q_nope, q_rope [B, T, H, .], cache rows [B, T, rank +
-    dr] = normalised c | rotated k_rope, head gate [B, T, H])."""
+    """h [B, T, D] -> (q_nope, q_rope [B, T, H, .], cache rows [B, T, W]
+    (``latent_row``), head gate [B, T, H])."""
     b, t, _ = h.shape
     dn, dr, r = spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.kv_lora_rank
     q = _proj(h, blk["wq"]).reshape(b, t, spec.n_heads, dn + dr)
@@ -335,7 +400,7 @@ def _mla_inputs(spec: ModelSpec, blk: Params, h, positions):
     k_rope = mla.rope_interleaved(kva[..., None, r:], positions,
                                   spec.rope_theta)[:, :, 0]
     gate = jax.nn.sigmoid(_proj(h, blk["w_gate"], jnp.float32))
-    return q[..., :dn], q_rope, jnp.concatenate([c, k_rope], -1), gate
+    return q[..., :dn], q_rope, latent_row(spec, c, k_rope), gate
 
 
 def _mla_out(blk: Params, o, gate, dtype):
@@ -344,7 +409,7 @@ def _mla_out(blk: Params, o, gate, dtype):
 
 
 def mla_layer_prefill(spec: ModelSpec, blk: Params, x, positions, seq_lens):
-    """x [B, T, D] -> (attention out, cache rows [B, T, rank + dr])."""
+    """x [B, T, D] -> (attention out, cache rows [B, T, W])."""
     b, t, _ = x.shape
     dn, r = spec.qk_nope_head_dim, spec.kv_lora_rank
     with jax.named_scope("attn.mla"):
@@ -352,31 +417,25 @@ def mla_layer_prefill(spec: ModelSpec, blk: Params, x, positions, seq_lens):
         q_nope, q_rope, rows, gate = _mla_inputs(spec, blk, h, positions)
         kv = _proj(rows[..., :r], blk["w_kvb"]).reshape(
             b, t, spec.n_heads, dn + spec.v_head_dim)
-        o = mla.mla_causal_attention(q_nope, q_rope, kv, rows[..., r:],
-                                     seq_lens)
+        o = mla.mla_causal_attention(
+            q_nope, q_rope, kv, rows[..., r:r + spec.qk_rope_head_dim],
+            seq_lens)
         return _mla_out(blk, o, gate, x.dtype), rows
 
 
-def mla_layer_step(spec: ModelSpec, blk: Params, x, positions, ctx, n_ctx,
-                   side, side_idx, active):
-    """x [B, D] at ``positions`` [B]; ctx [B, S, W] rows frozen for the
-    chunk (valid below ``n_ctx``); side [B, Wc, W] the chunk's own rows,
-    this token's written at ``side_idx`` where ``active``."""
-    r = spec.kv_lora_rank
+def mla_layer_step(spec: ModelSpec, blk: Params, x, positions, ctx, layer,
+                   n_ctx, side, side_idx, active):
+    """x [B, D] at ``positions`` [B]; ``ctx``, ``layer``, ``n_ctx``, ``side``
+    as ``latent_attention_step`` takes them. Returns (attention out, side,
+    latent rows read)."""
     with jax.named_scope("attn.mla"):
         h = rms_norm(x, blk["ln1_scale"], spec.norm_eps)[:, None]
         q_nope, q_rope, row, gate = _mla_inputs(spec, blk, h,
                                                 positions[:, None])
-        with jax.named_scope("attn.kv_update"):
-            hot = (jnp.arange(side.shape[1])[None, :] == side_idx[:, None]) \
-                & active[:, None]
-            side = jnp.where(hot[..., None], row.astype(side.dtype), side)
-        w_kvb = blk["w_kvb"].reshape(r, spec.n_heads, -1)
-        o = mla.mla_absorbed_decode(
-            q_nope[:, 0], q_rope[:, 0], w_kvb, ctx,
-            jnp.where(active, n_ctx, 0), side,
-            jnp.where(active, side_idx + 1, 0), r)
-        return _mla_out(blk, o, gate[:, 0], x.dtype), side
+        o, side, rows_read = latent_attention_step(
+            spec, blk["w_kvb"], q_nope[:, 0], q_rope[:, 0], row[:, 0], ctx,
+            layer, n_ctx, side, side_idx, active)
+        return _mla_out(blk, o, gate[:, 0], x.dtype), side, rows_read
 
 
 def mlp_block(spec: ModelSpec, blk: Params, kind: str, x, valid,
@@ -444,18 +503,21 @@ def forward_decode_step(
     tokens: jnp.ndarray,         # [B] the most recent token per slot
     lengths: jnp.ndarray,        # [B] its position
     start_lengths: jnp.ndarray,  # [B] length when the chunk began
-    ctx: jnp.ndarray,            # [Lm, B, S, W] page rows, frozen this chunk
+    ctx,                         # ``decode_context``: pool, table, attention
     side: jnp.ndarray,           # [Lm, B, Wc, W] the chunk's own rows
     state: State,                # [Lk, B, ...]: row b IS slot b
     active: jnp.ndarray,         # [B] bool
     moe_impl: str = "",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, State, jnp.ndarray]:
     """One token for every slot. Returns (hidden [B, D], side, state,
-    MoE counters [3]); rows not ``active`` leave side and state alone."""
+    counters [``DECODE_COUNTERS``]: MoE's three and the latent rows the
+    attention read, a layer); rows not ``active`` leave side and state
+    alone."""
     x = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
     S_all, conv_all = state["S"], state["conv"]
     side_idx = lengths - start_lengths
     counters = jnp.zeros((3,), jnp.int32)
+    rows_read = jnp.int32(0)
     i_kda = i_mla = 0
     for blk, (kind, mlp, _i) in zip(params["layers"], spec.layer_plan):
         if kind == "kda":
@@ -465,13 +527,15 @@ def forward_decode_step(
                 conv_all = conv_all.at[i_kda].set(tail)
             i_kda += 1
         else:
-            att, s = mla_layer_step(spec, blk, x, lengths, ctx[i_mla],
-                                    start_lengths, side[i_mla], side_idx,
-                                    active)
+            att, s, read = mla_layer_step(
+                spec, blk, x, lengths, ctx, i_mla, start_lengths,
+                side[i_mla], side_idx, active)
             side = side.at[i_mla].set(s)
+            rows_read = rows_read + read
             i_mla += 1
         x = x + att
         m, c = mlp_block(spec, blk, mlp, x, active, moe_impl)
         x = x + m
         counters = counters + c
+    counters = jnp.append(counters, rows_read // max(i_mla, 1))
     return x, side, {"S": S_all, "conv": conv_all}, counters

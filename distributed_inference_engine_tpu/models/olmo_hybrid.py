@@ -56,7 +56,12 @@ from ..ops.flash_decode import (
 )
 from ..ops.norms import rms_norm
 from .base import ModelSpec, embed
-from .ling import _init_table, _proj
+from .ling import (  # the paged pool's views are the same code
+    _init_table,
+    _proj,
+    decode_context,
+    write_rows_into_pages,
+)
 
 __all__ = ["olmo_hybrid_spec", "init_params", "init_state", "zero_state_slot",
            "decode_context", "write_rows_into_pages",
@@ -65,6 +70,9 @@ __all__ = ["olmo_hybrid_spec", "init_params", "init_state", "zero_state_slot",
 Params = Dict[str, Any]
 State = Dict[str, jnp.ndarray]
 
+# a decode step's counters: K|V rows the full layers read, and two zeros
+# where a routed family's programs carry theirs
+DECODE_COUNTERS = 3
 PERIOD = 4      # published layer_types: 3 linear_attention + 1 full_attention
 
 # published values (config.json of allenai/Olmo-Hybrid-7B)
@@ -229,37 +237,6 @@ def state_bytes_per_slot(spec: ModelSpec) -> int:
 @jax.jit
 def zero_state_slot(state: State, slot: jnp.ndarray) -> State:
     return {n: a.at[:, :, slot].set(0) for n, a in state.items()}
-
-
-def write_rows_into_pages(pages, rows, page_table, counts, start):
-    """Scatter ``rows`` [L, B, T, W] into the pool [L, N, P, W]: row b's
-    token t lands at absolute position ``start[b] + t`` while ``t <
-    counts[b]``. The pool is scattered as ONE list of rows with the layer
-    folded into the index, whole rows along the major axis: XLA then writes
-    the donated pool where it lies (scattered along its middle axis, as the
-    latent pools are, it made a transposed copy of all 3 GB)."""
-    lm, n, p, w = pages.shape
-    _lm, b, t, _w = rows.shape
-    local = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-    pos = local + start[:, None]
-    phys = jnp.take_along_axis(
-        page_table, jnp.minimum(pos // p, page_table.shape[1] - 1), axis=1)
-    idx = jnp.where(local < counts[:, None], phys * p + pos % p,
-                    lm * n * p)                                  # [B, T]
-    idx = jnp.where(idx < lm * n * p,
-                    idx[None] + (jnp.arange(lm) * n * p)[:, None, None],
-                    lm * n * p)                                  # [L, B, T]
-    with jax.named_scope("attn.kv_update"):
-        flat = pages.reshape(lm * n * p, w).at[idx.reshape(-1)].set(
-            rows.reshape(-1, w).astype(pages.dtype), mode="drop")
-    return flat.reshape(lm, n, p, w)
-
-
-def decode_context(pages: jnp.ndarray, page_table: jnp.ndarray,
-                   attn_impl: str):
-    """What a decode chunk's steps read the cached rows from: the pool AS IT
-    LIES and the page table (nothing is gathered for the chunk)."""
-    return pages, page_table, attn_impl
 
 
 # ----------------------------------------------------------------- layers

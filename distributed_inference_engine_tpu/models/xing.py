@@ -20,9 +20,11 @@ shared ones (``models/base.py`` ``unembed``).
 Python loop over ``spec.layer_plan`` and XLA compiles each kept layer.
 ``hc_attn`` / ``hc_mlp`` hold a sublayer's three mHC tensors (float32).
 
-**Cache**: one ``kv_lora_rank + qk_rope_head_dim`` row a token for every
-layer (``engine/paged_kv.py``'s latent pool, ``paged_layers`` = all of
-them) and NO per-sequence state: ``init_state`` gives arrays whose leading
+**Cache**: one latent row a token for every layer (``c`` | ``k_rope`` | zero
+lanes up to whole 128-lane tiles: ``ling.latent_row``, ``engine/paged_kv.py``'s
+latent pool, ``paged_layers`` = all of them), read in place by the decode
+kernel (``ops/flash_decode.py``), and NO per-sequence state: ``init_state``
+gives arrays whose leading
 dimension is 0, which ride the programs' donation and the decode carry as
 any other. The family has no prefill that continues from cached pages
 (prefix reuse, chunked prefill, the host tier and ``kv_export`` are refused
@@ -45,15 +47,18 @@ from ..ops import mhc, mla
 from ..ops.moe_routed import moe_block
 from ..ops.norms import rms_norm
 from .base import ModelSpec, embed
-from .ling import (  # the latent pool's views are the same code
+from .ling import (  # the latent pool's views and reader are the same code
+    DECODE_COUNTERS,
     _init_table,
     _proj,
     decode_context,
+    latent_attention_step,
+    latent_row,
     write_rows_into_pages,
 )
 
 __all__ = ["xing_spec", "init_params", "init_state", "zero_state_slot",
-           "decode_context", "write_rows_into_pages",
+           "decode_context", "write_rows_into_pages", "DECODE_COUNTERS",
            "forward_prefill_into_pages", "forward_decode_step"]
 
 Params = Dict[str, Any]
@@ -224,7 +229,7 @@ def _softmax_scale(spec: ModelSpec) -> float:
 
 def _mla_inputs(spec: ModelSpec, blk: Params, h, positions):
     """h [B, T, D] (normalised) -> (q_nope, q_rope [B, T, H, .], cache rows
-    [B, T, rank + dr] = normalised c | rotated k_rope)."""
+    [B, T, W]: ``ling.latent_row``)."""
     b, t, _ = h.shape
     dn, dr, r = spec.qk_nope_head_dim, spec.qk_rope_head_dim, spec.kv_lora_rank
     cq = rms_norm(_proj(h, blk["w_qa"]), blk["q_norm"], spec.norm_eps)
@@ -233,7 +238,7 @@ def _mla_inputs(spec: ModelSpec, blk: Params, h, positions):
     c = rms_norm(kva[..., :r], blk["kv_norm"], spec.norm_eps)
     k_rope = _rope(spec, kva[..., None, r:], positions)[:, :, 0]
     return (q[..., :dn], _rope(spec, q[..., dn:], positions),
-            jnp.concatenate([c, k_rope], -1))
+            latent_row(spec, c, k_rope))
 
 
 def _mla_out(blk: Params, o, dtype):
@@ -241,38 +246,32 @@ def _mla_out(blk: Params, o, dtype):
 
 
 def mla_layer_prefill(spec: ModelSpec, blk: Params, h, positions, seq_lens):
-    """h [B, T, D] -> (attention out, cache rows [B, T, rank + dr])."""
+    """h [B, T, D] -> (attention out, cache rows [B, T, W])."""
     b, t, _ = h.shape
     dn, r = spec.qk_nope_head_dim, spec.kv_lora_rank
     with jax.named_scope("attn.mla"):
         q_nope, q_rope, rows = _mla_inputs(spec, blk, h, positions)
         kv = _proj(rows[..., :r], blk["w_kvb"]).reshape(
             b, t, spec.n_heads, dn + spec.v_head_dim)
-        o = mla.mla_causal_attention(q_nope, q_rope, kv, rows[..., r:],
-                                     seq_lens, scale=_softmax_scale(spec))
+        o = mla.mla_causal_attention(
+            q_nope, q_rope, kv, rows[..., r:r + spec.qk_rope_head_dim],
+            seq_lens, scale=_softmax_scale(spec))
         return _mla_out(blk, o, h.dtype), rows
 
 
-def mla_layer_step(spec: ModelSpec, blk: Params, h, positions, ctx, n_ctx,
-                   side, side_idx, active):
-    """h [B, D] at ``positions`` [B]; ctx [B, S, W] rows frozen for the
-    chunk (valid below ``n_ctx``); side [B, Wc, W] the chunk's own rows,
-    this token's written at ``side_idx`` where ``active``."""
-    r = spec.kv_lora_rank
+def mla_layer_step(spec: ModelSpec, blk: Params, h, positions, ctx, layer,
+                   n_ctx, side, side_idx, active):
+    """h [B, D] at ``positions`` [B]; ``ctx``, ``layer``, ``n_ctx``, ``side``
+    as ``ling.latent_attention_step`` takes them. Returns (attention out,
+    (side, latent rows read)): ``_sublayer``'s pair."""
     with jax.named_scope("attn.mla"):
         q_nope, q_rope, row = _mla_inputs(spec, blk, h[:, None],
                                           positions[:, None])
-        with jax.named_scope("attn.kv_update"):
-            hot = (jnp.arange(side.shape[1])[None, :] == side_idx[:, None]) \
-                & active[:, None]
-            side = jnp.where(hot[..., None], row.astype(side.dtype), side)
-        w_kvb = blk["w_kvb"].reshape(r, spec.n_heads, -1)
-        o = mla.mla_absorbed_decode(
-            q_nope[:, 0], q_rope[:, 0], w_kvb, ctx,
-            jnp.where(active, n_ctx, 0), side,
-            jnp.where(active, side_idx + 1, 0), r,
+        o, side, rows_read = latent_attention_step(
+            spec, blk["w_kvb"], q_nope[:, 0], q_rope[:, 0], row[:, 0], ctx,
+            layer, n_ctx, side, side_idx, active,
             scale=_softmax_scale(spec))
-        return _mla_out(blk, o, h.dtype), side
+        return _mla_out(blk, o, h.dtype), (side, rows_read)
 
 
 def _sublayer(spec: ModelSpec, hc: Params, scale, x, fn):
@@ -346,31 +345,35 @@ def forward_decode_step(
     tokens: jnp.ndarray,         # [B] the most recent token per slot
     lengths: jnp.ndarray,        # [B] its position
     start_lengths: jnp.ndarray,  # [B] length when the chunk began
-    ctx: jnp.ndarray,            # [L, B, S, W] page rows, frozen this chunk
+    ctx,                         # ``decode_context``: pool, table, attention
     side: jnp.ndarray,           # [L, B, Wc, W] the chunk's own rows
     state: State,                # zero-layer state, handed back
     active: jnp.ndarray,         # [B] bool
     moe_impl: str = "",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, State, jnp.ndarray]:
-    """One token for every slot. Returns (hidden [B, D], side, state, MoE
-    counters [3]); rows not ``active`` leave side alone."""
+    """One token for every slot. Returns (hidden [B, D], side, state,
+    counters [``DECODE_COUNTERS``]: MoE's three and the latent rows the
+    attention read, a layer); rows not ``active`` leave side alone."""
     emb = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
     x = _streams(spec, emb)
     side_idx = lengths - start_lengths
     counters = jnp.zeros((3,), jnp.int32)
+    rows_read = jnp.int32(0)
     for i, (blk, (_kind, mlp, _id)) in enumerate(
             zip(params["layers"], spec.layer_plan)):
-        x, s = _sublayer(
+        x, (s, read) = _sublayer(
             spec, blk["hc_attn"], blk["ln1_scale"], x,
             lambda h, blk=blk, i=i: mla_layer_step(
-                spec, blk, h, lengths, ctx[i], start_lengths, side[i],
+                spec, blk, h, lengths, ctx, i, start_lengths, side[i],
                 side_idx, active))
         side = side.at[i].set(s)
+        rows_read = rows_read + read
         x, c = _sublayer(
             spec, blk["hc_mlp"], blk["ln2_scale"], x,
             lambda h, blk=blk, mlp=mlp: _mlp(spec, blk, mlp, h, active,
                                              moe_impl))
         counters = counters + c
+    counters = jnp.append(counters, rows_read // len(spec.layer_plan))
     return _collapse(spec, x), side, state, counters
 
 

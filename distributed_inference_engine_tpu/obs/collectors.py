@@ -126,7 +126,9 @@ ENGINE_MLA_TABLE = [              # engine.get_metrics()["mla"]
     ("decode_context_rows", "engine_mla_decode_context_rows", "c",
      "Latent rows the decode steps attended to, per paged layer (per-layer specs)"),
     ("decode_table_rows", "engine_mla_decode_table_rows", "c",
-     "Latent rows the decode body read for them: the whole table, every step"),
+     "Latent rows the decode body read for them, counted in the program: "
+     "the kernel's live pages (the XLA body: a layer's whole table), every "
+     "step"),
     ("prefill_key_blocks_visited", "engine_mla_prefill_key_blocks_visited", "c",
      "Key blocks the admitted prompts' latent prefills visited, per paged layer"),
     ("prefill_key_blocks_bucket", "engine_mla_prefill_key_blocks_square", "c",

@@ -47,6 +47,10 @@ layer, 60-72 % of the HBM peak over the live pages, against 120 us for
 the dense-context path's slice + attention + update at the 8-page
 bucket.
 
+``_latent_decode_kernel`` is the same loop over latent rows (MLA's
+absorbed decode, ``ops/mla.py``): one pool, one copy a page, the value the
+first lanes of the key.
+
 The kernel (``_flash_decode_kernel``) is attention only: the caller
 writes the step's fresh K/V into the side buffer (the XLA one-hot select),
 and ``n_side`` counts it as valid. It runs under ``interpret=True`` on CPU
@@ -77,7 +81,16 @@ NEG_INF = -1e30
 # 2/4/6/8 rows live (PERF.md §6, PR 25), us per layer: bp 1 18.3/19.7/20.7/
 # 30.4, bp 2 15.7/18.3/17.4/25.3, bp 4 15.5/17.3/17.0/24.1, bp 8 15.6/
 # 19.1/17.8/24.5.
-_TUNED_PAGES_PER_BLOCK: dict = {(128, 1024): 4}
+# (128, 640): the latent kernel over both MLA families' rows, 8 rows of
+# 1,500-8,400 positions in the 7-layer pool, 8 / 6 rows live, us a call by
+# pages a block / pages a softmax update (PERF.md §6, PR 38;
+# docs/sweeps/pr38-mla-decode-inplace-kernel.txt): 4/1 182/132, 4/2 122/89,
+# 4/4 101/73, 8/2 112/82, 8/4 82.5/61.8, 8/8 85/63, 16/4 82/64, 16/8 82/62,
+# 16/16 85/63.
+_TUNED_PAGES_PER_BLOCK: dict = {(128, 1024): 4, (128, 640): 8}
+# the latent kernel's pages to one softmax update, same key (unlisted: the
+# whole block)
+_TUNED_PAGES_PER_ATTEND: dict = {(128, 640): 4}
 
 
 def _default_pages_per_block(page_size: int, fused: int, mp: int) -> int:
@@ -213,7 +226,8 @@ def _prefix_loop(
     b, page_table_ref, prefix_lens_ref, next_live_ref, layer_ref,
     buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
     v_vmem, sem, m_scr, l_scr, acc_scr,
-    *, bp, page_size, n_pages_per_layer, scale, kv_lanes=0, copied_ref=None,
+    *, bp, page_size, n_pages_per_layer, scale, kv_lanes=0, v_lanes=0,
+    attend_pages=1, copied_ref=None,
 ):
     """Flash loop over row ``b``'s live prefix pages: ``bp`` pages per
     block, double-buffered manual DMA, next block (possibly the first
@@ -238,13 +252,27 @@ def _prefix_loop(
     interpreter ``step_ref`` starts every grid step at 0 again, so a later
     row's first block is issued, and counted, a second time by its own
     turn; on the chip each live page is counted once:
-    ``scripts/chip_kernels.py`` ``flash_decode_kv_fused`` holds that.)"""
+    ``scripts/chip_kernels.py`` ``flash_decode_kv_fused`` holds that.)
+
+    ``v_lanes``: latent rows, ONE pool whose row is the key and whose first
+    ``v_lanes`` lanes are the value (``v_pages_hbm`` / ``v_vmem`` are
+    None): one copy a page into ``k_vmem`` [2, bp * P, W], read there by
+    both products, ``attend_pages`` pages to one softmax update (one page
+    an update leaves the MXU waiting on the update's chain of scores, max,
+    exp and values: 0.53 us a page of 0.2 us of DMA, PR 38). A group runs
+    if its first page is live; its dead pages hold what an earlier block
+    left there (the caller zeroes the buffers once) under the mask."""
     batch = pl.num_programs(0)
     blk_tokens = bp * page_size
     base = layer_ref[0] * n_pages_per_layer
 
     def copies(row, blk, slot, j):
         page = base + page_table_ref[row, blk * bp + j]
+        if v_lanes:
+            return (pltpu.make_async_copy(
+                k_pages_hbm.at[page],
+                k_vmem.at[slot, pl.ds(j * page_size, page_size)],
+                sem.at[slot]),)
         if kv_lanes:
             # ONE pool of K|V rows (both refs are it): a page's K is its
             # first ``kv_lanes`` lanes, its V the rest
@@ -295,7 +323,24 @@ def _prefix_loop(
                     (i * bp + j) * page_size, length,
                     m_scr, l_scr, acc_scr, scale)
 
-        for_live_pages(b, i, page)
+        def group(n_live, first):
+            def wait(j):
+                for c in copies(b, i, slot, j):
+                    c.wait()
+            for j in range(first, first + attend_pages):
+                pl.when(i * bp + j < n_live)(functools.partial(wait, j))
+            k = k_vmem[slot, pl.ds(first * page_size,
+                                   attend_pages * page_size)]
+            _attend(qbd, k, k[:, :v_lanes], (i * bp + first) * page_size,
+                    length, m_scr, l_scr, acc_scr, scale)
+
+        if v_lanes:
+            n_live = lax.div(length + page_size - 1, page_size)
+            for first in range(0, bp, attend_pages):
+                pl.when(i * bp + first < n_live)(
+                    functools.partial(group, n_live, first))
+        else:
+            for_live_pages(b, i, page)
         buffer_index_ref[0] = 1 - slot
         step_ref[0] = step_ref[0] + 1
         return ()
@@ -437,6 +482,7 @@ def _scratch(hp, fused, bp, page_size, dtype):
 # its caller, e.g. ``closed_call.13``, and lands in ``other``), and a name
 # with "int4" in it would be counted as the weight kernel.
 _OP_NAME = "flash_decode_custom_call"
+_LATENT_OP_NAME = "latent_decode_custom_call"
 
 
 def _compiler_params(bp, page_size, fused, itemsize):
@@ -540,6 +586,154 @@ def flash_decode_attention_pallas(
     if count_pages:
         return out[0][:, :h], out[1][0]
     return out[:, :h]
+
+
+# ------------------------------------------- kernel: latent rows (MLA decode)
+#
+# Absorbed latent attention is flash decode with ONE K/V head shared by all
+# query heads, whose value is the first ``v_lanes`` lanes of its key: the
+# query heads [Hp, W] sit on sublanes, a page [P, W] is copied ONCE, ``s = q ·
+# pageᵀ`` runs over the row as stored and ``acc += p · page[:, :v_lanes]``
+# reads the same VMEM buffer. Everything that streams is ``_prefix_loop``.
+
+
+def _latent_decode_kernel(
+    # scalar prefetch: as ``_flash_decode_kernel``
+    page_table_ref, prefix_lens_ref, next_live_ref, n_side_ref, layer_ref,
+    buffer_index_ref, step_ref,
+    # inputs
+    q_ref,                     # [1, Hp, W] VMEM (auto-pipelined)
+    side_ref,                  # [1, Wc, W] VMEM (auto-pipelined)
+    pages_hbm,                 # [L*N, P, W] ANY (stays in HBM)
+    # outputs
+    out_ref,                   # [1, Hp, v_lanes] float32 VMEM
+    copied_ref,                # [1] SMEM: pages copied so far
+    # scratch
+    page_vmem,                 # [2, bp * P, W] double-buffered blocks
+    m_scr, l_scr,              # [Hp, 1] f32
+    acc_scr,                   # [Hp, v_lanes] f32
+    sem,
+    *,
+    v_lanes: int,
+    scale: float,
+    page_size: int,
+    pages_per_block: int,
+    pages_per_attend: int,
+    n_pages_per_layer: int,
+):
+    b = pl.program_id(0)
+
+    @pl.when(b == 0)
+    def _zero():
+        copied_ref[0] = 0
+        # a group's dead pages are multiplied under the mask: by zeros or
+        # an earlier block's rows, never by what the scratch held before
+        page_vmem[...] = jnp.zeros_like(page_vmem)
+
+    _init_acc(m_scr, l_scr, acc_scr)
+    q = q_ref[0]
+    _prefix_loop(
+        b, page_table_ref, prefix_lens_ref, next_live_ref, layer_ref,
+        buffer_index_ref, step_ref, q, pages_hbm, None, page_vmem, None,
+        sem, m_scr, l_scr, acc_scr,
+        bp=pages_per_block, page_size=page_size,
+        n_pages_per_layer=n_pages_per_layer, scale=scale, v_lanes=v_lanes,
+        attend_pages=pages_per_attend, copied_ref=copied_ref)
+
+    n_side = n_side_ref[b]
+
+    @pl.when(n_side > 0)
+    def _side():
+        side = side_ref[0]
+        _attend(q, side, side[:, :v_lanes], 0, n_side,
+                m_scr, l_scr, acc_scr, scale)
+
+    out_ref[0] = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "v_lanes", "scale", "interpret", "n_pages_per_layer", "pages_per_block",
+    "pages_per_attend"))
+def latent_decode_attention_pallas(
+    q: jnp.ndarray,            # [B, H, W] in the pool's dtype
+    pages: jnp.ndarray,        # [N, P, W] or stacked [L*N, P, W]
+    page_table: jnp.ndarray,   # [B, MP] int32
+    prefix_lens: jnp.ndarray,  # [B]
+    side: jnp.ndarray,         # [B, Wc, W]
+    n_side: jnp.ndarray,       # [B]
+    layer=None,
+    *,
+    v_lanes: int,
+    scale: float,
+    interpret: bool = False,
+    n_pages_per_layer: int = 0,
+    pages_per_block: int = 0,
+    pages_per_attend: int = 0,
+):
+    """One query token a row against latent rows read where they lie: a
+    row's key is its ``W`` lanes, its value the first ``v_lanes`` of them.
+    Returns ``(out [B, H, v_lanes] float32, pages)``, ``pages`` the kernel's
+    own int32 count of the pool pages it started a copy of; the side
+    window's ``B * Wc`` rows come in besides, every call. One ``jax.jit``:
+    a program that calls it once a layer traces and lowers it once."""
+    b, h, w = q.shape
+    n, page_size, width = pages.shape
+    if width != w or side.shape[-1] != w:
+        raise ValueError(f"q / side / pool rows differ: {w}, "
+                         f"{side.shape[-1]}, {width} lanes")
+    if w % 128 or v_lanes > w:
+        raise ValueError(
+            f"rows of {w} lanes, values of {v_lanes}: the kernel copies "
+            "whole 128-lane tiles and the value is a row's first lanes")
+    mp = page_table.shape[1]
+    wc = side.shape[1]
+    bp = min(pages_per_block
+             or _default_pages_per_block(page_size, w, mp), mp)
+    ap = min(pages_per_attend or _TUNED_PAGES_PER_ATTEND.get(
+        (page_size, w), bp), bp)
+    bp -= bp % ap
+    qp = _pad_heads(q)
+    hp = qp.shape[1]
+    rows = b * (mp * page_size + wc)
+    out, copied = pl.pallas_call(
+        functools.partial(
+            _latent_decode_kernel, v_lanes=v_lanes, scale=scale,
+            page_size=page_size, pages_per_block=bp, pages_per_attend=ap,
+            n_pages_per_layer=n_pages_per_layer or n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=7,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, hp, w), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, wc, w), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, hp, v_lanes), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM)],
+            scratch_shapes=[
+                pltpu.VMEM((2, bp * page_size, w), pages.dtype),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, 1), jnp.float32),
+                pltpu.VMEM((hp, v_lanes), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((b, hp, v_lanes), jnp.float32),
+                   jax.ShapeDtypeStruct((1,), jnp.int32)],
+        # one buffer pair, where the K/V kernel holds two
+        compiler_params=_compiler_params(bp, page_size, w // 2,
+                                         pages.dtype.itemsize),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * rows * h * (w + v_lanes),
+            bytes_accessed=rows * w * pages.dtype.itemsize,
+            transcendentals=rows * h),
+        interpret=interpret,
+        name=_LATENT_OP_NAME,
+    )(page_table, prefix_lens, _next_live(prefix_lens), n_side,
+      _layer_scalar(layer),
+      jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+      qp, side, pages)
+    return out[:, :h], copied[0]
 
 
 # ------------------------------------------------------------- dispatcher

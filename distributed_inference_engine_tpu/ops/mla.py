@@ -13,14 +13,17 @@ k_rope (qk_rope_head_dim)]`` shared by all heads, in place of K and V.
   specs) ``mla_causal_attention_xla``: einsum / softmax over query blocks,
   each reading the keys up to its own last row. ``prefill_impl`` chooses
   from the backend and ``T``; nothing else does.
-- ``mla_absorbed_decode``: one query token against cached rows as they lie.
-  ``W_kvb``'s key half is absorbed into the query (``q_nope W_k^T`` scores
-  against ``c`` directly) and its value half into the output (``(p c) W_v``),
-  so a step reads 576 values a token instead of expanding 2 x H x 128.
-  Context comes in two parts, the page rows frozen for the chunk and the
-  chunk's side rows; their scores share one softmax. It stays a plain
-  einsum / softmax chain: the row width (576) is no multiple of 128 lanes,
-  which ``ops/flash_decode.py`` requires.
+- ``mla_absorbed_decode`` / ``mla_absorbed_decode_inplace``: one query token
+  against cached rows as they lie. ``W_kvb``'s key half is absorbed into the
+  query (``q_nope W_k^T`` scores against ``c`` directly) and its value half
+  into the output (``(p c) W_v``), so a step reads one latent row a token
+  instead of expanding 2 x H x 128. Context comes in two parts, the page
+  rows frozen for the chunk and the chunk's side rows; their scores share
+  one softmax. The first is a plain einsum / softmax chain over rows the
+  caller gathered (the CPU, ``"xla"``); the second hands ``[q_abs | q_rope]``
+  to ``ops/flash_decode.py``'s latent kernel, which copies the live pages
+  from the pool where they lie (the rows are held at whole 128-lane tiles:
+  ``ModelSpec.cache_row_width``).
 
 The query may be compressed (``q_lora_rank``: the family projects it; these
 functions take q as heads either way). Rotary frequencies are plain RoPE's
@@ -41,6 +44,8 @@ import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .flash_decode import latent_decode_attention_pallas
 
 NEG_INF = -1e30
 LANES = 128
@@ -326,24 +331,37 @@ def _flash_prefill(q_nope, q_rope, kv, k_rope, seq_lens, scale: float,
     return out.reshape(b, t, h, dv)
 
 
+def _absorb_query(q_nope, w_kvb, dtype):
+    """(``q_nope W_k^T`` [B,H,rank] in ``dtype``, ``W_v`` [rank,H,dv])."""
+    dn = q_nope.shape[-1]
+    q_abs = jnp.einsum("bhd,chd->bhc", q_nope, w_kvb[..., :dn],
+                       preferred_element_type=jnp.float32).astype(dtype)
+    return q_abs, w_kvb[..., dn:]
+
+
+def _expand_values(o_lat, w_v, dtype):
+    """``(p c) W_v``: o_lat [B,H,rank] float32 -> [B,H,dv] in ``dtype``."""
+    return jnp.einsum("bhc,chd->bhd", o_lat.astype(w_v.dtype), w_v,
+                      preferred_element_type=jnp.float32).astype(dtype)
+
+
 def mla_absorbed_decode(q_nope, q_rope, w_kvb, ctx, n_ctx, side, n_side,
                         rank: int, scale: Optional[float] = None
                         ) -> jnp.ndarray:
     """q_nope [B,H,dn], q_rope [B,H,dr]; w_kvb [rank, H, dn + dv]; ctx
-    [B,S,rank+dr] cached rows valid below ``n_ctx`` [B]; side [B,W,rank+dr]
-    valid below ``n_side``. Returns [B,H,dv] in q's dtype."""
-    dn = q_nope.shape[-1]
+    [B,S,W] cached rows (c | k_rope | lanes nothing reads) valid below
+    ``n_ctx`` [B]; side [B,Wc,W] valid below ``n_side``. Returns [B,H,dv]
+    in q's dtype."""
+    dn, dr = q_nope.shape[-1], q_rope.shape[-1]
     if scale is None:
-        scale = (dn + q_rope.shape[-1]) ** -0.5
-    w_k, w_v = w_kvb[..., :dn], w_kvb[..., dn:]
-    q_abs = jnp.einsum("bhd,chd->bhc", q_nope, w_k,
-                       preferred_element_type=jnp.float32).astype(ctx.dtype)
+        scale = (dn + dr) ** -0.5
+    q_abs, w_v = _absorb_query(q_nope, w_kvb, ctx.dtype)
     q_rope = q_rope.astype(ctx.dtype)
 
     def scores(rows, n_valid):
         s = (jnp.einsum("bhc,bsc->bhs", q_abs, rows[..., :rank],
                         preferred_element_type=jnp.float32)
-             + jnp.einsum("bhr,bsr->bhs", q_rope, rows[..., rank:],
+             + jnp.einsum("bhr,bsr->bhs", q_rope, rows[..., rank:rank + dr],
                           preferred_element_type=jnp.float32)) * scale
         ok = jnp.arange(rows.shape[1])[None, :] < n_valid[:, None]
         return jnp.where(ok[:, None], s, NEG_INF)
@@ -355,5 +373,32 @@ def mla_absorbed_decode(q_nope, q_rope, w_kvb, ctx, n_ctx, side, n_side,
                         preferred_element_type=jnp.float32)
              + jnp.einsum("bhs,bsc->bhc", p[..., n:], side[..., :rank],
                           preferred_element_type=jnp.float32))
-    return jnp.einsum("bhc,chd->bhd", o_lat.astype(w_v.dtype), w_v,
-                      preferred_element_type=jnp.float32).astype(q_nope.dtype)
+    return _expand_values(o_lat, w_v, q_nope.dtype)
+
+
+def mla_absorbed_decode_inplace(q_nope, q_rope, w_kvb, pages, page_table,
+                                layer, n_ctx, side, n_side, rank: int,
+                                scale: Optional[float] = None,
+                                n_pages_per_layer: int = 0,
+                                interpret: bool = False):
+    """``mla_absorbed_decode`` over the pool where it lies: pages [L * N, P,
+    W], every paged layer's, of which layer ``layer``'s pages named by
+    ``page_table`` [B, MP] are read below ``n_ctx``; W whole 128-lane tiles
+    whose lanes past ``rank + dr`` are zero. The same arithmetic: operands
+    in the pool's dtype, float32 scores and accumulators, the probabilities
+    cast to the pool's dtype before the value product. Returns ([B,H,dv] in
+    q's dtype, pool pages the kernel copied: int32)."""
+    dn, dr = q_nope.shape[-1], q_rope.shape[-1]
+    if scale is None:
+        scale = (dn + dr) ** -0.5
+    dt = pages.dtype
+    q_abs, w_v = _absorb_query(q_nope, w_kvb, dt)
+    pad = pages.shape[-1] - rank - dr
+    q = jnp.concatenate(
+        [q_abs, q_rope.astype(dt), jnp.zeros((*q_abs.shape[:2], pad), dt)],
+        -1)
+    o_lat, copied = latent_decode_attention_pallas(
+        q, pages, page_table, n_ctx, side, n_side, layer, v_lanes=rank,
+        scale=float(scale), interpret=interpret,
+        n_pages_per_layer=n_pages_per_layer)
+    return _expand_values(o_lat, w_v, q_nope.dtype), copied

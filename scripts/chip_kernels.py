@@ -8,7 +8,9 @@ Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; the latent
 prefill at 32 heads of 128 | 64 | 128, T 1,024 and 8,192; the K|V-row read
 of a per-layer family (``fused``: heads, pages a row, side window, stacked
 layers: 30 MHA heads of 128, 8 rows of up to 6,144 positions, timed alone);
-the five int4
+the latent rows' decode read of both MLA families (``latent``: 32 heads over
+rows of 640 lanes, 8 rows of up to 8,704 positions in a 7-layer pool, timed
+alone per pages a block); the five int4
 payload shapes of mistral-7b (N=32,768 for the lm_head) at M=128 (the
 prefill bucket of ``ops.int4_matmul.blocks_for``) and, for the 2-D and
 stacked legs, at M=8 (the decode bucket: what a served decode step runs). Tolerances are the ones the CPU parity tests use
@@ -39,6 +41,9 @@ FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
             mla_dims=(128, 64, 128),
             mla_cases=((1024, 48), (1024, 1024), (8192, 48), (8192, 8192)),
             fused=(30, 48, 16, 4),
+            # (heads, rank, rope lanes, pages a row, side window, layers):
+            # both latent-row families' MLA, the Xing cell's 7-layer pool
+            latent=(32, 512, 64, 68, 16, 7),
             # (layers, heads, dk, dv, the gate's last axis): the Gated-
             # DeltaNet cell's state, the KDA cell's, and the KDA cell's
             # twice over (201 MB: alone in a program the cell's 101 MB can
@@ -50,7 +55,7 @@ TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
             int4_rows=(32, 3),
             int4_shapes=((128, 256), (256, 128)),
             mla_dims=(16, 8, 16), mla_cases=((1024, 48), (1024, 1024)),
-            fused=(2, 6, 4, 3),
+            fused=(2, 6, 4, 3), latent=(4, 32, 8, 6, 4, 2),
             delta=((3, 4, 16, 32, 1), (2, 4, 16, 16, 16)))
 OUT = os.path.join("chiprun_out", "chip_kernels.json")
 
@@ -328,6 +333,125 @@ def check_flash_decode_kv_fused(cfg, interpret):
     return "; ".join(details)
 
 
+# pages a block / pages a softmax update of the latent kernel's sweep
+SWEEP = ((4, 1), (4, 2), (4, 4), (8, 2), (8, 4), (8, 8), (16, 4), (16, 8),
+         (16, 16))
+
+
+def check_latent_decode(cfg, interpret):
+    """MLA's absorbed decode read in place (``ops/mla.py``
+    ``mla_absorbed_decode_inplace`` over ``ops/flash_decode.py``'s latent
+    kernel): 8 rows over ONE pool of every layer's pages (full: 7 layers x
+    544 pages of 128 rows of 640 lanes, 624 MB), lengths drawn as the Xing
+    cell's mix draws them (log-normal, median 3,072, sigma 0.7, 512-8,192,
+    plus up to 256 decoded), 8 and 6 rows live: against
+    ``mla_absorbed_decode`` on a layer's gathered pages, the kernel's own
+    page count against the lengths, and, on the chip, its time alone swept
+    over pages a block and pages a softmax update: a scan of calls that walks the layers, so the
+    working set is the whole pool (one layer's live pages would sit in the
+    v5e's VMEM-side cache and read above the HBM peak), on the host's
+    clock, in us a call and % of 819 GB/s for the live rows' 1,152 B."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from distributed_inference_engine_tpu.ops import mla
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        _default_pages_per_block,
+        latent_decode_attention_pallas,
+    )
+
+    p, (h, rank, dr, mp, w, layers) = cfg["P"], cfg["latent"]
+    b, dn, dv = 8, cfg["mla_dims"][0], cfg["mla_dims"][2]
+    lanes = -(-(rank + dr) // 128) * 128
+    n = b * mp
+    ks = jax.random.split(jax.random.key(38), 8)
+    bf = jnp.bfloat16
+    keep = (jnp.arange(lanes) < rank + dr)
+
+    def rows(key, shape):
+        return (jax.random.normal(key, (*shape, lanes), jnp.float32)
+                * keep).astype(bf)
+
+    pool = jax.jit(lambda k: rows(k, (layers * n, p)))(ks[0])
+    side = rows(ks[1], (b, w))
+    table = jax.random.permutation(ks[2], n).reshape(b, mp).astype(jnp.int32)
+    qn = jax.random.normal(ks[3], (b, h, dn), jnp.float32).astype(bf)
+    qr = jax.random.normal(ks[4], (b, h, dr), jnp.float32).astype(bf)
+    w_kvb = (0.05 * jax.random.normal(ks[5], (rank, h, dn + dv))).astype(bf)
+    scale = (dn + dr) ** -0.5
+    rng = np.random.default_rng(38)
+    cap = mp * p - w
+    drawn = np.clip(np.exp(rng.normal(np.log(0.35 * cap), 0.7, b)),
+                    0.06 * cap, 0.94 * cap) + rng.integers(0, cap // 32, b)
+    n_calls = 7 * 32
+
+    def timed(plen, n_side, bp, ap):
+        @jax.jit
+        def many(q, pool):
+            def body(q, i):
+                out, _ = latent_decode_attention_pallas(
+                    q, pool, table, plen, side, n_side, i % layers,
+                    v_lanes=rank, scale=scale, n_pages_per_layer=n,
+                    pages_per_block=bp, pages_per_attend=ap)
+                return q + (jnp.pad(out, ((0, 0), (0, 0), (0, lanes - rank)))
+                            * 1e-3).astype(q.dtype), None
+            return jax.lax.scan(body, q, jnp.arange(n_calls))[0]
+
+        q = jnp.zeros((b, h, lanes), bf)
+        many(q, pool).block_until_ready()
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            many(q, pool).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
+        return best / n_calls
+
+    details = []
+    layer = layers - 2
+    for name, dead in (("8 live", ()), ("6 live", (2, 5))):
+        plen = np.minimum(drawn.astype(np.int64), cap)
+        plen[list(dead)] = 0
+        if interpret:
+            plen[1] = 1          # a one-token row, which the mix never draws
+        plen = jnp.asarray(plen, jnp.int32)
+        n_side = jnp.where(plen > 0,
+                           jnp.array([1, 5, 0, w, 9, 2, 16, 3]) % (w + 1),
+                           0).astype(jnp.int32)
+        own = pool[layer * n + table].reshape(b, mp * p, lanes)
+        ref = jax.jit(mla.mla_absorbed_decode, static_argnums=(7,))(
+            qn, qr, w_kvb, own, plen, side, n_side, rank, scale)
+        got, pages = jax.jit(
+            lambda *a: mla.mla_absorbed_decode_inplace(
+                *a, rank, scale=scale, n_pages_per_layer=n,
+                interpret=interpret))(
+            qn, qr, w_kvb, pool, table, layer, plen, side, n_side)
+        live_rows = np.asarray((plen > 0) | (n_side > 0))
+        err = _close(got[live_rows], ref[live_rows], 2e-2)
+        live_pages = int(jnp.sum(-(-plen // p)))
+        bp0 = _default_pages_per_block(p, lanes, mp)
+        first_again = int(jnp.sum(jnp.minimum(-(-plen[1:] // p), bp0))) \
+            if interpret else 0
+        detail = (f"{name}: lengths {[int(x) for x in plen]}, max|err| "
+                  f"{err:.2e}, {int(pages)} pages copied, {live_pages} live")
+        assert live_pages <= int(pages) <= live_pages + first_again, (
+            "the kernel's count of page copies is not the lengths': "
+            + detail)
+        if not interpret:
+            live = (rank + dr) * 2 * int(jnp.sum(plen + n_side))
+            moved = lanes * 2 * (live_pages * p + b * w)
+            detail += (f", live rows {live / 1e6:.1f} MB, copied "
+                       f"{moved / 1e6:.1f} MB; pages a block / pages a "
+                       "softmax update -> us a call (% of 819 GB/s, live "
+                       "rows):")
+            for bp, ap in SWEEP:
+                t = timed(plen, n_side, bp, ap)
+                detail += (f" {bp}/{ap} -> {1e6 * t:.1f} "
+                           f"({100 * live / t / 819e9:.1f} %)")
+        details.append(detail)
+    return " | ".join(details)
+
+
 def check_mla_prefill(cfg, interpret):
     """The latent-attention prefill kernel at the published head shape (32
     heads of 128 | 64 | 128) against the XLA body: the smallest and the
@@ -452,6 +576,7 @@ CHECKS = {
     "flash_decode": (check_flash_decode, True),
     "flash_decode_served": (check_flash_decode_served, True),
     "flash_decode_kv_fused": (check_flash_decode_kv_fused, True),
+    "latent_decode": (check_latent_decode, True),
     "mla_prefill": (check_mla_prefill, True),
     "kda_step_inplace": (check_kda_step_inplace, True),
 }

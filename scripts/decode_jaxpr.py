@@ -1,8 +1,10 @@
 """The programs the benchmark's cells run, as text: ``str(jax.make_jaxpr)`` of
 a ``ContinuousEngine``'s ``_decode_chunk`` (8 steps) and ``_prefill_pages``
-for the three specs of the cells at test size: mistral-tiny int4 (no sliding
+for the specs of the cells at test size: mistral-tiny int4 (no sliding
 window) on the ``window`` body (``pallas-decode_interpret``) and on ``dense``
-(``xla``), and ``ling-tiny`` (``hybrid``).
+(``xla``), ``ling-tiny`` and, since PR 38, ``olmo-hybrid-tiny`` on the kernel
+and on XLA (``hybrid``; a parent dumped before has no such files: ``compare``
+walks its first directory's).
 
     JAX_PLATFORMS=cpu python -m scripts.decode_jaxpr dump <dir>
     python -m scripts.decode_jaxpr compare <dir-a> <dir-b>
@@ -69,6 +71,9 @@ def dump(out: str) -> None:
         ling_spec,
         mistral_spec,
     )
+    from distributed_inference_engine_tpu.models.olmo_hybrid import (
+        olmo_hybrid_spec,
+    )
     from distributed_inference_engine_tpu.ops.quant import (
         random_quantized_params,
     )
@@ -88,6 +93,13 @@ def dump(out: str) -> None:
                        decode_steps_per_call=8)
     _dump_engine(out, "ling_tiny",
                  ContinuousEngine(ling_spec("ling-tiny"), config=cfg))
+    for name, impl in (("olmo_tiny_kernel", "pallas-decode_interpret"),
+                       ("olmo_tiny_xla", "xla")):
+        cfg = EngineConfig(max_slots=4, max_seq_len=128, page_size=16,
+                           num_pages=40, prefill_buckets=[32, 64],
+                           decode_steps_per_call=8, attention_impl=impl)
+        _dump_engine(out, name, ContinuousEngine(
+            olmo_hybrid_spec("olmo-hybrid-tiny"), config=cfg))
 
 
 def compare(a: str, b: str) -> bool:

@@ -301,13 +301,9 @@ def test_served_row_shape_matches_cached_attention(bp, dtype, tol):
                                rtol=tol, atol=tol)
 
 
-def test_dead_rows_and_short_rows_start_no_dma(monkeypatch):
-    """Only live pages move: the pool pages the kernel starts copies from
-    are exactly those holding a token below their row's length — none for
-    a dead row, one page for a one-token row in a 4-page block, none past
-    a row's last page. (Which pages, not how often: the interpreter
-    resets the scalar-prefetch state at each grid step, so a row's first
-    block is issued again there; on the chip the state persists.)"""
+def _record_copies(monkeypatch):
+    """Every pool page a kernel traced from here on starts a copy of, in
+    order (the first index of the copy's source)."""
     from jax.experimental.pallas import tpu as pltpu
 
     started = []
@@ -327,6 +323,17 @@ def test_dead_rows_and_short_rows_start_no_dma(monkeypatch):
             self._c.wait()
 
     monkeypatch.setattr(pltpu, "make_async_copy", Recording)
+    return started
+
+
+def test_dead_rows_and_short_rows_start_no_dma(monkeypatch):
+    """Only live pages move: the pool pages the kernel starts copies from
+    are exactly those holding a token below their row's length — none for
+    a dead row, one page for a one-token row in a 4-page block, none past
+    a row's last page. (Which pages, not how often: the interpreter
+    resets the scalar-prefetch state at each grid step, so a row's first
+    block is issued again there; on the chip the state persists.)"""
+    started = _record_copies(monkeypatch)
     p, mp, n = 16, 8, 80
     q, kp, vp, _, sk, sv = _inputs(jax.random.key(12), b=8, h=8, hkv=2,
                                    dh=64, n=n, p=p, mp=mp, w=4, layers=2)
@@ -345,12 +352,117 @@ def test_dead_rows_and_short_rows_start_no_dma(monkeypatch):
     assert set(started) == live
 
 
+# ------------------------------------ latent rows (MLA's absorbed decode)
+
+
+def _latent_case(dtype, *, layers=3, n=40, p=16, mp=4, wc=8, h=4, rank=32,
+                 dr=8, dn=16, dv=16, lanes=128):
+    """ONE pool of every layer's pages whose rows are c | k_rope | zero
+    lanes; 8 rows: dead, one token, one short of / at / one past a page
+    boundary, a full table, and two mid-page; a part-filled side window
+    (full for one row, empty for the dead one and one live one)."""
+    ks = jax.random.split(jax.random.key(38), 6)
+    keep = jnp.arange(lanes) < rank + dr
+    pool = (jax.random.normal(ks[0], (layers * n, p, lanes)) * keep).astype(
+        dtype)
+    side = (jax.random.normal(ks[1], (8, wc, lanes)) * keep).astype(dtype)
+    table = jax.random.permutation(ks[2], n)[:8 * mp].reshape(8, mp).astype(
+        jnp.int32)
+    qn = jax.random.normal(ks[3], (8, h, dn)).astype(dtype)
+    qr = jax.random.normal(ks[4], (8, h, dr)).astype(dtype)
+    w_kvb = (0.3 * jax.random.normal(ks[5], (rank, h, dn + dv))).astype(dtype)
+    plen = jnp.array([0, 1, p - 1, p, p + 1, mp * p, 2 * p + 5, 3 * p + 9],
+                     jnp.int32)
+    n_side = jnp.array([0, 1, wc, 3, 0, 5, 1, 2], jnp.int32)
+    return pool, side, table, qn, qr, w_kvb, plen, n_side, rank, n, p
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("bp,ap", [(1, 1), (2, 1), (2, 2), (4, 2), (4, 4)])
+def test_latent_kernel_matches_the_absorbed_xla_body(dtype, tol, bp, ap):
+    """``mla_absorbed_decode_inplace`` (the kernel through the interpreter,
+    rows read where they lie from the middle layer of a stacked pool)
+    against ``mla_absorbed_decode`` on that layer's gathered pages, at every
+    pages a block / pages a softmax update; a dead row's output is zero."""
+    from distributed_inference_engine_tpu.ops import mla
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        latent_decode_attention_pallas)
+
+    (pool, side, table, qn, qr, w_kvb, plen, n_side, rank, n,
+     p) = _latent_case(dtype)
+    layer = 1
+    own = pool[layer * n + table].reshape(8, -1, pool.shape[-1])
+    ref = mla.mla_absorbed_decode(qn, qr, w_kvb, own, plen, side, n_side,
+                                  rank, scale=0.2)
+    w_k = w_kvb[..., :qn.shape[-1]]
+    q_abs = jnp.einsum("bhd,chd->bhc", qn, w_k,
+                       preferred_element_type=jnp.float32).astype(dtype)
+    q = jnp.concatenate(
+        [q_abs, qr, jnp.zeros((8, 4, pool.shape[-1] - rank - 8), dtype)], -1)
+    o_lat, _pages = latent_decode_attention_pallas(
+        q, pool, table, plen, side, n_side, layer, v_lanes=rank, scale=0.2,
+        interpret=True, n_pages_per_layer=n, pages_per_block=bp,
+        pages_per_attend=ap)
+    got = jnp.einsum("bhc,chd->bhd", o_lat.astype(dtype),
+                     w_kvb[..., qn.shape[-1]:],
+                     preferred_element_type=jnp.float32)
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    assert not got[0].any()
+    np.testing.assert_allclose(got[1:], ref[1:], rtol=tol, atol=tol)
+    # the one entry the models call gives the same
+    out, _ = mla.mla_absorbed_decode_inplace(
+        qn, qr, w_kvb, pool, table, layer, plen, side, n_side, rank,
+        scale=0.2, n_pages_per_layer=n, interpret=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[1:], ref[1:],
+                               rtol=tol, atol=tol)
+
+
+def test_latent_kernel_copies_the_live_pages_and_counts_them(monkeypatch):
+    """ONE copy a live page, from the layer the scalar names, none for a
+    dead row or past a row's last page; the kernel's own count is the live
+    pages (under the interpreter, which starts the scalar-prefetch state
+    anew each grid step, plus each later live row's first block, issued
+    again by its own turn; the chip's count is exact:
+    ``scripts/chip_kernels.py`` ``latent_decode`` holds that)."""
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        latent_decode_attention_pallas)
+
+    started = _record_copies(monkeypatch)
+    (pool, side, table, _qn, _qr, _w, plen, n_side, rank, n,
+     p) = _latent_case(jnp.float32)
+    q = jax.random.normal(jax.random.key(1), (8, 4, pool.shape[-1]))
+    bp = 2
+    # the launcher is ONE jax.jit: a scale no other test uses, so that it is
+    # traced here, with the recording copies
+    out, copied = latent_decode_attention_pallas(
+        q, pool, table, plen, side, n_side, 2, v_lanes=rank, scale=0.125,
+        interpret=True, n_pages_per_layer=n, pages_per_block=bp,
+        pages_per_attend=2)
+    jax.block_until_ready(out)
+    jax.effects_barrier()
+    pages = [-(-int(x) // p) for x in plen]
+    live = {2 * n + int(table[i, c]) for i in range(8)
+            for c in range(pages[i])}
+    assert len(live) == sum(pages) == 0 + 1 + 1 + 1 + 2 + 4 + 3 + 4
+    assert set(started) == live
+    first_live = next(i for i, x in enumerate(pages) if x)
+    again = sum(min(x, bp) for x in pages[first_live + 1:])
+    assert int(copied) == len(started) == sum(pages) + again
+
+
 # --------------------------------------------- what "auto" resolves to
 
 
 def _resolve_spec(**kw):
     from distributed_inference_engine_tpu.models.base import ModelSpec
 
+    if kw.pop("latent_rows", False):
+        # a per-layer spec whose paged layers keep latent rows (32 + 8
+        # values, held at one 128-lane tile)
+        from distributed_inference_engine_tpu.models.xing import xing_spec
+
+        return xing_spec("xing-tiny", **kw)
     base = dict(vocab_size=256, d_model=256, n_layers=2, n_heads=4,
                 n_kv_heads=2, d_ff=256, max_seq_len=128)    # Hkv*Dh = 128
     base.update(kw)
@@ -372,6 +484,16 @@ def _resolve_spec(**kw):
     # a sliding window has one body, whatever the string asks for
     ("pallas-decode_interpret", "cpu", {"sliding_window": 64}, True,
      ("inline", "xla")),
+    # latent rows take the kernel under exactly a K|V spec's conditions
+    ("auto", "tpu", {"latent_rows": True}, False,
+     ("hybrid", "pallas-decode")),
+    ("auto", "cpu", {"latent_rows": True}, False, ("hybrid", "xla")),
+    ("auto", "tpu", {"latent_rows": True}, True, ("hybrid", "xla")),
+    ("xla", "tpu", {"latent_rows": True}, False, ("hybrid", "xla")),
+    ("pallas-decode", "cpu", {"latent_rows": True}, False,
+     ("hybrid", "pallas-decode")),
+    ("pallas-decode_interpret", "cpu", {"latent_rows": True}, False,
+     ("hybrid", "pallas-decode_interpret")),
 ])
 def test_auto_resolution_is_a_pure_function(impl, backend, spec_kw, sharded,
                                             want):
@@ -380,7 +502,7 @@ def test_auto_resolution_is_a_pure_function(impl, backend, spec_kw, sharded,
     from distributed_inference_engine_tpu.engine.continuous import (
         resolve_decode_body)
 
-    spec = _resolve_spec(**spec_kw)
+    spec = _resolve_spec(**dict(spec_kw))
     for _ in range(2):
         assert resolve_decode_body(impl, backend, spec,
                                    sharded=sharded) == want

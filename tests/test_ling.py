@@ -131,7 +131,7 @@ class Served:
                 if self.state_dtype is not None:
                     state = dict(state, S=state["S"].astype(
                         self.state_dtype).astype(jnp.float32))
-                self.moe += np.asarray(moe)
+                self.moe += np.asarray(moe)[:3]   # + the rows the attention read
                 logits = np.asarray(unembed(self.spec, self.params, hidden))
                 for s in feeds:
                     if act[s]:
@@ -369,9 +369,11 @@ def test_kda_scan_is_the_references_layer(served_f32):
 # ------------------------------------------------------------------- MLA
 
 
-def test_absorbed_mla_decode_is_the_expanded_reference(served_f32):
-    """One query against cached rows (part frozen context, part side
-    window) = the reference's expanded attention at that position."""
+@pytest.mark.parametrize("impl", ["xla", "pallas-decode_interpret"])
+def test_absorbed_mla_decode_is_the_expanded_reference(served_f32, impl):
+    """One query against cached rows (part frozen pages, part side window)
+    = the reference's expanded attention at that position: over a layer's
+    gathered pages (XLA) and read in place by the interpreted kernel."""
     spec = tiny_spec(dtype="float32")
     blk = served_f32["layers"][2]
     t = 21
@@ -385,15 +387,21 @@ def test_absorbed_mla_decode_is_the_expanded_reference(served_f32):
         got, rows = ling.mla_layer_prefill(spec, blk, x, pos,
                                            jnp.asarray([t]))
         assert float(jnp.abs(got[0] - want).max()) < 1e-5
-        # the last token again, as a decode step: 17 rows frozen, 3 in the
-        # side window, its own written at side index 3
-        ctx = jnp.zeros((1, 32, rows.shape[-1])).at[:, :17].set(rows[:, :17])
-        side = jnp.zeros((1, 8, rows.shape[-1])).at[:, :3].set(rows[:, 17:20])
-        out, side = ling.mla_layer_step(
-            spec, blk, x[:, -1], jnp.asarray([t - 1]), ctx,
+        # the last token again, as a decode step: 17 rows frozen in pages
+        # 3 and 1 of the second of two paged layers, 3 in the side window,
+        # its own written at side index 3
+        w = rows.shape[-1]
+        assert w == spec.cache_row_width == 128
+        pool = jnp.zeros((2, 4, 16, w)).at[1, 3].set(rows[0, :16]).at[
+            1, 1, 0].set(rows[0, 16])
+        ctx = ling.decode_context(pool, jnp.asarray([[3, 1]]), impl)
+        side = jnp.zeros((1, 8, w)).at[:, :3].set(rows[:, 17:20])
+        out, side, read = ling.mla_layer_step(
+            spec, blk, x[:, -1], jnp.asarray([t - 1]), ctx, 1,
             jnp.asarray([17]), side, jnp.asarray([3]), jnp.asarray([True]))
     assert float(jnp.abs(out[0] - want[-1]).max()) < 1e-5
     assert float(jnp.abs(side[0, 3] - rows[0, -1]).max()) < 1e-6
+    assert int(read) == 2 * 16 + 8
 
 
 def test_rope_rotates_interleaved_pairs():

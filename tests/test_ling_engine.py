@@ -20,7 +20,7 @@ from distributed_inference_engine_tpu.config import (  # noqa: E402
     EngineConfig, ModelConfig,
 )
 from distributed_inference_engine_tpu.engine.continuous import (  # noqa: E402
-    ContinuousEngine,
+    ContinuousEngine, resolve_decode_body,
 )
 from distributed_inference_engine_tpu.engine.paged_kv import (  # noqa: E402
     PagedKVCache,
@@ -93,9 +93,9 @@ def test_engine_serves_the_hybrid_through_slots_pages_and_state():
     assert 0 < moe["experts_touched"] <= m["decode_steps"] * 5 * 4
     kv = m["kv"]
     assert (kv["paged_layers"], kv["state_layers"]) == (2, 4)
-    assert kv["latent_bytes_per_token"] == 2 * (32 + 8) * 2
+    assert kv["latent_bytes_per_token"] == 2 * 128 * 2    # 32 + 8: one tile
     assert kv["state_bytes"] == 4 * ling.state_bytes_per_slot(tiny_spec())
-    assert kv["hbm_bytes"] == 2 * 32 * 16 * 40 * 2
+    assert kv["hbm_bytes"] == 2 * 32 * 16 * 128 * 2
     # every slot is free again, and free means zero
     assert all(float(jnp.abs(a).max()) == 0 for a in engine.kv.state.values())
 
@@ -167,6 +167,41 @@ def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch):
         assert m["state"]["rows_updated"] == m_xla["state"]["rows_updated"] > 0
 
 
+@pytest.mark.parametrize("pages", [32, 9])
+def test_the_latent_kernel_body_emits_the_xla_bodys_tokens(pages):
+    """The MLA layers' rows read in place from the pool by the interpreted
+    kernel against ``attention_impl="xla"`` (a layer's pages gathered a
+    step): the same greedy tokens in float32 for five requests over four
+    slots, with 9 pages through a re-prefilled pre-emption too; attended
+    rows equal, and the kernel's own count of what it read stays between
+    them and half of what the XLA body gathered."""
+    rng = np.random.default_rng(4)
+    prompts = [[int(t) for t in rng.integers(1, 256, n)]
+               for n in (30, 28, 9, 17, 24)]
+    new = (40, 36, 7, 12, 21)
+
+    def serve(impl):
+        engine = tiny_engine("float32", num_pages=pages, attention_impl=impl)
+        assert (engine.body, engine.attn_impl) == ("hybrid", impl)
+        results = engine.generate([
+            GenerationRequest(prompt=list(p), max_new_tokens=n)
+            for p, n in zip(prompts, new)])
+        return [r.tokens for r in results], engine.get_metrics()
+
+    tokens_xla, m_xla = serve("xla")
+    tokens_kernel, m_kernel = serve("pallas-decode_interpret")
+    assert tokens_kernel == tokens_xla
+    assert [len(t) for t in tokens_xla] == list(new)
+    read_xla, read_kernel = m_xla["mla"], m_kernel["mla"]
+    assert (read_kernel["decode_context_rows"]
+            == read_xla["decode_context_rows"] > 0)
+    assert (read_kernel["decode_context_rows"]
+            <= read_kernel["decode_table_rows"]
+            < read_xla["decode_table_rows"] // 2)
+    # a layer's whole gathered table (4 slots x 8 pages x 16) every step
+    assert read_xla["decode_table_rows"] >= m_xla["decode_steps"] * 4 * 8 * 16
+
+
 @pytest.mark.parametrize("pages", [32, 7])
 def test_streamed_hybrid_matches_unstreamed(pages):
     """The hybrid family streamed: a chunk's tokens go out under the next
@@ -213,15 +248,20 @@ def test_existing_families_keep_their_specs():
                                 max_seq_len=3072)
     assert cut.layer_ids == [0, 2, 3, 4, 5, 6, 7]
     assert cut.layer_kinds == ["kda"] * 4 + ["mla"] + ["kda"] * 2
-    assert cut.experts_held == (0, 128) and cut.cache_row_width == 576
+    # 512 | 64 latent values held at whole 128-lane tiles
+    assert cut.experts_held == (0, 128) and cut.cache_row_width == 640
+    assert resolve_decode_body("auto", "tpu", cut) == ("hybrid",
+                                                       "pallas-decode")
+    assert resolve_decode_body("auto", "cpu", cut) == ("hybrid", "xla")
+    assert resolve_decode_body("xla", "tpu", cut) == ("hybrid", "xla")
     assert hash(cut) == hash(type(cut).from_dict(
         json.loads(json.dumps(cut.to_dict()))))
 
 
 @pytest.mark.parametrize("kw", [
     {"kv_offload": True}, {"prefill_chunk": 32},
-    {"attention_impl": "pallas-decode"},
-    {"attention_impl": "pallas-decode_interpret"}])
+    {"kv_offload": True, "prefill_chunk": 32},
+    {"kv_offload": True, "attention_impl": "pallas-decode_interpret"}])
 def test_engine_options_a_recurrent_spec_cannot_honour_raise(kw):
     with pytest.raises(ValueError, match="hybrid"):
         tiny_engine(**kw)

@@ -443,10 +443,11 @@ def _program_texts(family, spec):
         pages, state, jnp.zeros((2, 4), jnp.int32),
         jnp.zeros((2,), jnp.int32)).as_text(debug_info=True)
     decode = jax.jit(
-        lambda p, t, n, s0, cx, sd, st, ac: family.forward_decode_step(
-            spec, p, t, n, s0, cx, sd, st, ac)).lower(
+        lambda p, t, n, s0, pg, tb, sd, st, ac: family.forward_decode_step(
+            spec, p, t, n, s0, family.decode_context(pg, tb, "xla"), sd, st,
+            ac)).lower(
         params, jnp.zeros((2,), jnp.int32), jnp.ones((2,), jnp.int32),
-        jnp.ones((2,), jnp.int32), jnp.zeros((lm, 2, 64, w), jnp.bfloat16),
+        jnp.ones((2,), jnp.int32), pages, jnp.zeros((2, 4), jnp.int32),
         jnp.zeros((lm, 2, 4, w), jnp.bfloat16), state,
         jnp.ones((2,), bool)).as_text(debug_info=True)
     return prefill, decode

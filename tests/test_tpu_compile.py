@@ -64,6 +64,44 @@ def test_flash_decode_compiles_for_v5e(one_chip, b, h, hkv, dh, mp, w,
     assert "flash_decode_custom_call" in compiled.as_text()
 
 
+# B, pages a row, side window, pages a block and pages a softmax update
+# (0: the tuned defaults)
+@pytest.mark.parametrize("b,mp,w,bp,ap", [
+    (8, 68, 16, 0, 0),    # the Xing cell: 8 slots of 8,704 positions
+    (8, 24, 16, 0, 0),    # the Ling cell's one MLA layer
+    (8, 68, 1, 0, 0),     # a chunk cut to one step
+    (8, 68, 5, 0, 0),     # ... at max_seq_len's edge
+    (8, 68, 16, 4, 1), (8, 68, 16, 4, 2), (8, 68, 16, 8, 4),
+    (8, 68, 16, 8, 8), (8, 68, 16, 16, 8), (8, 68, 16, 16, 16),
+])
+def test_latent_decode_compiles_for_v5e(one_chip, b, mp, w, bp, ap):
+    """MLA's absorbed decode over the latent pool as both families hold it:
+    32 heads against rows of 640 lanes (512 | 64 | 64 zero), 7 layers."""
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        latent_decode_attention_pallas)
+
+    layers, n, p, h, lanes, rank = 7, b * mp, 128, 32, 640, 512
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pages, pt, plen, side, n_side, layer):
+        return latent_decode_attention_pallas(
+            q, pages, pt, plen, side, n_side, layer, v_lanes=rank,
+            scale=0.1, n_pages_per_layer=n, pages_per_block=bp,
+            pages_per_attend=ap)
+
+    bf = jnp.bfloat16
+    compiled = jax.jit(fn).lower(
+        sds((b, h, lanes), bf), sds((layers * n, p, lanes), bf),
+        sds((b, mp), jnp.int32), sds((b,), jnp.int32), sds((b, w, lanes), bf),
+        sds((b,), jnp.int32), sds((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "latent_decode_custom_call" in text
+    # the pool is the kernel's operand where it lies: no copy of it
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
 # the five payload shapes of the mistral-7b cells, through the blocks the
 # kernel resolves itself (ops.int4_matmul.blocks_for): rows 8 = a served
 # decode step (the decode bucket), rows 256 / 768 = its prefill programs
@@ -274,3 +312,62 @@ def test_no_program_of_the_gdn_family_holds_a_copy_of_its_kv_table(
     assert "custom-call" in state_ops and state_ops <= {
         "parameter", "tuple", "get-tuple-element", "while", "bitcast",
         "custom-call"}, state_ops
+
+
+def test_no_decode_chunk_of_a_latent_row_family_gathers_its_table(one_chip):
+    """The mHC family's decode chunk at the served size (7 MLA layers, 8
+    slots of 8,704 positions, 16 steps), compiled for the v5e: every layer
+    reads the 624 MB latent pool through the kernel where it lies and the
+    chunk writes its side window back where it lies; the program holds no
+    gathered ``[L, B, S, W]`` context (561 MB at the parent, once a chunk),
+    no layer's ``[B, S, W]``, and no temporary near the pool's size."""
+    from distributed_inference_engine_tpu.models import xing as fam
+    from distributed_inference_engine_tpu.models.base import unembed
+
+    spec = fam.xing_spec("xing4.0-pp1", max_seq_len=8704)
+    slots, n_pages, page, mp, steps = 8, 544, 128, 68, 16
+    lanes = spec.cache_row_width
+    assert lanes == 640
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def arr(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_params(spec, jax.random.key(0))))
+    state = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_state(spec, slots)))
+    pool = arr(spec.paged_layers, n_pages, page, lanes, dtype=jnp.bfloat16)
+    pool_bytes = spec.paged_layers * n_pages * page * lanes * 2
+
+    def decode(params, pages, state, lengths, last, active, table):
+        ctx = fam.decode_context(pages, table, "pallas-decode")
+        side = jnp.zeros((spec.paged_layers, slots, steps, lanes),
+                         pages.dtype)
+
+        def step(carry, _):
+            side, state, now, last = carry
+            hidden, side, state, _m = fam.forward_decode_step(
+                spec, params, last, now, lengths, ctx, side, state, active)
+            tok = jnp.argmax(unembed(spec, params, hidden), -1)
+            return (side, state, now + 1, tok.astype(jnp.int32)), tok
+
+        (side, state, now, last), toks = jax.lax.scan(
+            step, (side, state, lengths, last), None, length=steps)
+        pages = fam.write_rows_into_pages(pages, side, table, now - lengths,
+                                          lengths)
+        return pages, state, toks
+
+    compiled = jax.jit(decode, donate_argnums=(1, 2)).lower(
+        params, pool, state, arr(slots), arr(slots),
+        arr(slots, dtype=jnp.bool_), arr(slots, mp)).compile()
+    text = compiled.as_text()
+    assert text.count("latent_decode_custom_call") >= spec.paged_layers
+    for w in (576, 640):
+        for shape in (f"[7,8,8704,{w}]", f"[8,8704,{w}]", f"[8,68,128,{w}]",
+                      f"[7,8,68,128,{w}]", f"[544,{w}]"):
+            assert shape not in text, shape
+    # (167 MB today: buffers of the layers' weight matrices, none the pool's)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * pool_bytes

@@ -459,7 +459,7 @@ def test_sizes_and_the_spec_round_trip():
     assert cut.layer_ids == [0, 2, 3, 4, 5, 6, 7]
     assert cut.layer_mlps == ["dense"] + ["moe"] * 6
     assert cut.layer_kinds == ["mla"] * 7 and cut.experts_held == (0, 64)
-    assert cut.cache_row_width == 576 and cut.paged_layers == 7
+    assert cut.cache_row_width == 640 and cut.paged_layers == 7
     assert (cut.hc_mult, cut.hc_sinkhorn_iters, cut.q_lora_rank) == (4, 20,
                                                                      768)
     assert cut.rope_scaling == {

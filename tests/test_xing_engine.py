@@ -70,6 +70,7 @@ def test_engine_serves_the_family_through_slots_and_latent_pages():
     padded buckets, deferred first tokens, slot reuse, counters."""
     engine = tiny_engine(prefix_cache=True)
     assert engine.body == "hybrid" and engine.attn_impl == "xla"
+    assert engine.spec.cache_row_width == 128     # 32 + 8 values, one tile
     rng = np.random.default_rng(1)
     reqs = [GenerationRequest(
         prompt=[int(t) for t in rng.integers(1, 256, n)], max_new_tokens=m)
@@ -86,31 +87,71 @@ def test_engine_serves_the_family_through_slots_and_latent_pages():
     assert 0 < moe["experts_touched"] <= m["decode_steps"] * 3 * 8
     kv = m["kv"]
     assert (kv["paged_layers"], kv["state_layers"]) == (4, 0)
-    assert kv["latent_bytes_per_token"] == 4 * (32 + 8) * 2
+    assert kv["latent_bytes_per_token"] == 4 * 128 * 2
     assert kv["state_bytes"] == 0
-    assert kv["hbm_bytes"] == 4 * 32 * 16 * 40 * 2
+    assert kv["hbm_bytes"] == 4 * 32 * 16 * 128 * 2
     assert {a.shape for a in engine.kv.state.values()} == {(0, 4)}
 
 
 def test_mla_counters_follow_lengths_and_steps():
     """One request alone: a prompt of 20 and 9 tokens. The first comes from
     the prefill; the 8 decode steps attend to 21, 22, ... 28 rows each (the
-    cached rows and the step's own), and the body read the whole table (4
-    slots x 8 pages x 16) at every step of its chunks."""
-    engine = tiny_engine()
-    engine.generate([GenerationRequest(prompt=list(range(1, 21)),
-                                       max_new_tokens=9)])
-    m = engine.get_metrics()
-    assert m["mla"]["decode_context_rows"] == sum(range(21, 29))
-    assert m["mla"]["decode_table_rows"] == m["decode_steps"] * 4 * 8 * 16
-    assert m["decode_steps"] == 4 * m["decode_chunks"]
-    # a second request grows both
-    engine.generate([GenerationRequest(prompt=list(range(1, 6)),
-                                       max_new_tokens=3)])
-    m2 = engine.get_metrics()
-    assert (m2["mla"]["decode_context_rows"]
-            == m["mla"]["decode_context_rows"] + 6 + 7)
-    assert m2["mla"]["decode_table_rows"] > m["mla"]["decode_table_rows"]
+    cached rows and the step's own). What the attention READ is the
+    program's own count, a layer: the XLA body a layer's whole gathered
+    table (4 slots x 8 pages x 16) every step, the kernel the pages it
+    started a copy of (the live row's 2 pages of 16 below its frozen
+    prefix), both plus the side window (4 slots x 4 rows)."""
+    for impl, pages in (("xla", 4 * 8), ("pallas-decode_interpret", 2)):
+        engine = tiny_engine(attention_impl=impl)
+        engine.generate([GenerationRequest(prompt=list(range(1, 21)),
+                                           max_new_tokens=9)])
+        m = engine.get_metrics()
+        assert m["mla"]["decode_context_rows"] == sum(range(21, 29))
+        assert m["decode_steps"] == 8 == 4 * m["decode_chunks"]
+        assert m["mla"]["decode_table_rows"] == 8 * (pages * 16 + 4 * 4)
+        # a second request grows both: its 2 decode steps attend to 6 and 7
+        # rows; the kernel copies its 1 page at those two steps of the
+        # chunk's 4 and none once the row has gone inactive
+        engine.generate([GenerationRequest(prompt=list(range(1, 6)),
+                                           max_new_tokens=3)])
+        m2 = engine.get_metrics()
+        assert (m2["mla"]["decode_context_rows"]
+                == m["mla"]["decode_context_rows"] + 6 + 7)
+        assert (m2["mla"]["decode_table_rows"]
+                == m["mla"]["decode_table_rows"]
+                + (4 * pages if impl == "xla" else 2) * 16 + 4 * 4 * 4)
+
+
+def test_the_kernel_body_emits_the_xla_bodys_tokens():
+    """The TPU body through the interpreter (latent rows read in place from
+    the family's one pool) against ``attention_impl="xla"`` (a layer's pages
+    gathered a step), in float32: the same greedy tokens for six requests of
+    unequal length over four slots, through admission, slot reuse and chunks
+    that cross pages; and the kernel read the live pages' rows. (bfloat16
+    rows: ``tests/test_flash_decode.py`` holds the kernel to the XLA body.)"""
+    rng = np.random.default_rng(2)
+    shapes = ((20, 10), (37, 6), (5, 12), (50, 9), (33, 7), (12, 5))
+    prompts = [[int(t) for t in rng.integers(1, 256, n)] for n, _m in shapes]
+
+    def run(impl):
+        engine = tiny_engine(dtype="float32", attention_impl=impl)
+        assert (engine.body, engine.attn_impl) == ("hybrid", impl)
+        reqs = [GenerationRequest(prompt=p, max_new_tokens=m)
+                for p, (_n, m) in zip(prompts, shapes)]
+        res = engine.generate(reqs)
+        judged(engine, reqs, res)
+        return [r.tokens for r in res], engine.get_metrics()["mla"]
+
+    want, read_xla = run("xla")
+    got, read_kernel = run("pallas-decode_interpret")
+    assert got == want
+    assert read_kernel["decode_context_rows"] == read_xla[
+        "decode_context_rows"]
+    # every page read holds a live row: fewer rows than one more page a
+    # (row, step) on top of what was attended to, and far under the table
+    assert (read_kernel["decode_context_rows"]
+            <= read_kernel["decode_table_rows"]
+            < read_xla["decode_table_rows"] // 2)
 
 
 @pytest.fixture(scope="module")
@@ -221,7 +262,17 @@ def test_streamed_matches_unstreamed(pages):
 def test_the_body_is_chosen_from_the_spec():
     spec = spec_for_architecture("xing", size="xing4.0-pp1",
                                  max_seq_len=8704)
-    assert resolve_decode_body("auto", "tpu", spec) == ("hybrid", "xla")
+    # latent rows held at whole lane tiles: the kernel under exactly the
+    # conditions a K|V spec takes it
+    assert spec.cache_row_width == 640 and spec.kv_row_lanes == 0
+    assert resolve_decode_body("auto", "tpu", spec) == ("hybrid",
+                                                        "pallas-decode")
+    assert resolve_decode_body("auto", "cpu", spec) == ("hybrid", "xla")
+    assert resolve_decode_body("auto", "tpu", spec, sharded=True) == (
+        "hybrid", "xla")
+    assert resolve_decode_body("xla", "tpu", spec) == ("hybrid", "xla")
+    assert resolve_decode_body("pallas-decode_interpret", "cpu", spec) == (
+        "hybrid", "pallas-decode_interpret")
     assert spec.max_seq_len == 8704 and not spec.recurrent
     with pytest.raises(ValueError, match="unknown xing size"):
         spec_for_architecture("xing", size="xing-9b")
@@ -229,8 +280,8 @@ def test_the_body_is_chosen_from_the_spec():
 
 @pytest.mark.parametrize("kw", [
     {"kv_offload": True}, {"prefill_chunk": 32},
-    {"attention_impl": "pallas-decode"},
-    {"attention_impl": "pallas-decode_interpret"}])
+    {"kv_offload": True, "prefill_chunk": 32},
+    {"kv_offload": True, "attention_impl": "pallas-decode_interpret"}])
 def test_engine_options_a_per_layer_spec_cannot_honour_raise(kw):
     with pytest.raises(ValueError, match="per-layer") as e:
         tiny_engine(**kw)
