@@ -131,7 +131,7 @@ def resolve_decode_body(impl: str, backend: str, spec,
     # of a per-layer spec whose paged layers keep K|V rows, or its latent row
     lanes = ((spec.kv_row_lanes or spec.cache_row_width) if spec.layer_kinds
              else spec.n_kv_heads * spec.head_dim)
-    if spec.sliding_window:
+    if spec.sliding_window and not spec.layer_kinds:
         return "inline", "xla"
     if impl == "auto":
         kernel = backend == "tpu" and not sharded and lanes % 128 == 0
@@ -447,6 +447,12 @@ class ContinuousEngine:
         self._full_context_rows = 0
         self._full_table_rows = 0
         self._state_rows_updated = 0
+        # a spec with sliding-window layers (``self.kv.window`` rows): rows
+        # inside the window the decode steps attended to, and rows the body
+        # read for them (the last of the family's counters), per sliding
+        # layer
+        self._window_context_rows = 0
+        self._window_table_rows = 0
         # the body that moves a recurrent state in a decode step, as the
         # family's programs will pick it when they are traced (ops/kda.py)
         self.state_step_body = kda.step_impl() if self._recurrent else None
@@ -825,6 +831,10 @@ class ContinuousEngine:
             # ``_advance`` and the harvest are the shared ones.
             fam = layered_family(spec_)
             attn_impl = self.attn_impl
+            # a family with a second page pool (sliding-window layers)
+            # says how many layers a chunk's side window holds and writes
+            # it back into both pools (``write_side``)
+            side_layers = getattr(fam, "side_layers", None)
 
             @partial(jax.jit, donate_argnums=(3, 4))
             def _prefill_pages(params, tokens, seq_lens, kp, vp, table_rows,
@@ -863,8 +873,12 @@ class ContinuousEngine:
                                   eos_ids=eos_ids, stop_mat=stop_mat,
                                   use_stops=use_stops)
                 ctx = fam.decode_context(kp, page_table, attn_impl)
-                side = jnp.zeros((kp.shape[0], b, n_steps, kp.shape[-1]),
-                                 kp.dtype)
+                # a row a step of every layer that keeps K|V or latent
+                # rows: the pool's layers, or all of a family's whose
+                # sliding layers keep theirs in a pool of their own
+                side = jnp.zeros((side_layers(spec_) if side_layers
+                                  else kp.shape[0], b, n_steps,
+                                  kp.shape[-1]), kp.dtype)
 
                 def step(carry, step_key):
                     side, state, lengths, last, active, produced, moe = carry
@@ -884,9 +898,14 @@ class ContinuousEngine:
                            jnp.zeros((fam.DECODE_COUNTERS,), jnp.int32)),
                     jax.random.split(key, n_steps))
                 side, vp, lengths, last, active, produced, moe = carry
-                kp = fam.write_rows_into_pages(
-                    kp, side, page_table, lengths - start_lengths,
-                    start_lengths)
+                if side_layers:
+                    kp, vp = fam.write_side(
+                        kp, vp, side, page_table, lengths - start_lengths,
+                        start_lengths)
+                else:
+                    kp = fam.write_rows_into_pages(
+                        kp, side, page_table, lengths - start_lengths,
+                        start_lengths)
                 packed = jnp.concatenate(
                     [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
                      active[None].astype(jnp.int32), lengths[None], firsts,
@@ -1538,7 +1557,7 @@ class ContinuousEngine:
             slot_ids[:n] = [b[2] for b in batch]
             first_dev, kp, vp, moe = self._prefill_pages(
                 self.params, jnp.asarray(tokens), seq_dev,
-                self.kv.k_pages, self.kv.state,
+                *self.kv.pools,
                 jnp.asarray(table_rows), sampling, k0,
                 jnp.asarray(slot_ids),
             )
@@ -2208,6 +2227,9 @@ class ContinuousEngine:
             if state is None:
                 continue                 # finished by a mid-loop flush below
             cur = int(lengths_np[slot])
+            # a sliding layer's pages the window has wholly passed go back
+            # to their free list first (no-op for a spec without them)
+            self.kv.release_behind_window(slot, cur)
             cap_tok = self.kv.ensure_capacity(slot, cur + ahead)
             if (cap_tok <= cur and self._offload is not None
                     and self._pending is not None):
@@ -2295,6 +2317,7 @@ class ContinuousEngine:
         # capacity loop's reclaims; swap-in uploads staged by resume)
         # before the chunk writes the pools
         self.kv.sync_tiers()
+        self.kv.tally_window()
         carry, packed = self._decode_chunk(
             self.params, *self.kv.pools,
             self._lengths, self._last, self._active, self._produced,
@@ -2414,6 +2437,10 @@ class ContinuousEngine:
             # last, after the routed experts' three
             if self._kv_rows:
                 self._full_table_rows += int(moe[0])
+                if self.kv.window:     # then the experts' three and its own
+                    self._moe_decode_counts += moe[1:4]
+                    self._moe_counts += moe[1:4]
+                    self._window_table_rows += int(moe[4])
             else:
                 self._moe_decode_counts += moe[:3]
                 self._moe_counts += moe[:3]
@@ -2451,6 +2478,14 @@ class ContinuousEngine:
                 self._full_context_rows += attended
             else:
                 self._mla_context_rows += attended
+            if self.kv.window:
+                # a token at position p sees min(p + 1, window) rows
+                first = ends - counts_np
+                below = np.clip(np.minimum(ends, self.kv.window) - first, 0,
+                                None)
+                self._window_context_rows += int(
+                    (below * first + below * (below + 1) // 2
+                     + (counts_np - below) * self.kv.window).sum())
             if self._recurrent:
                 self._state_rows_updated += int(counts_np.sum())
         tok_cols = toks_np.T.tolist()
@@ -2913,7 +2948,11 @@ class ContinuousEngine:
             # read for them; recurrent specs: (row, step) pairs that moved
             # a state (per recurrent layer) and the body that moved them
             **({"attn": {"full_context_rows": self._full_context_rows,
-                         "full_table_rows": self._full_table_rows}}
+                         "full_table_rows": self._full_table_rows,
+                         **({"window_context_rows":
+                             self._window_context_rows,
+                             "window_table_rows": self._window_table_rows}
+                            if self.kv.window else {})}}
                if self._kv_rows else {}),
             **({"state": {"rows_updated": self._state_rows_updated,
                           "step_body": self.state_step_body}}

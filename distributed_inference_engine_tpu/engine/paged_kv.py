@@ -20,6 +20,17 @@ Split of responsibilities:
   on device only when host accounting changes (admission / page growth), so
   steady-state decode does zero host→device traffic for metadata.
 
+**Two page lifetimes under one allocator** (a per-layer spec with
+sliding-window layers, ``models/mellum.py``): the full-attention layers'
+pages are the pool above, held while the sequence lives; the window
+layers' pages are a second, small pool (``state["window_pages"]``,
+``max_slots * window_pages_per_slot`` pages) with a free list and a table
+of its own. A slot holds window pages only for the rows a later step can
+still see: ``release_behind_window`` hands back every page the window has
+wholly passed, ``alloc_slot`` / ``ensure_capacity`` take pages for the rows
+ahead, and admission reckons the two kinds separately (a slot needs its
+pages of BOTH). The table rides to the device inside ``state``.
+
 Chunked-decode contract: callers must ``reserve(slot, n_tokens)`` the whole
 chunk before launching it — the table is static while the chunk runs, so page
 boundaries crossed mid-chunk already have physical pages behind them.
@@ -146,7 +157,18 @@ class PagedKVCache:
             # tail). ``pools`` is the pair the programs donate.
             self.k_pages = jnp.zeros(shape, dtype=self.dtype)
             self.v_pages = None
-            self.state = layered_family(spec).init_state(spec, max_slots)
+            fam = layered_family(spec)
+            if spec.window_layers:
+                # a third kind: pages of the window layers, bounded a slot
+                self.window_pages_per_slot = fam.window_pages_per_slot(
+                    spec, page_size)
+                self.num_window_pages = (max_slots
+                                         * self.window_pages_per_slot)
+                self.state = fam.init_state(
+                    spec, max_slots, page_size, self.num_window_pages,
+                    self.max_pages_per_seq)
+            else:
+                self.state = fam.init_state(spec, max_slots)
         elif sharding is not None:
             # tp serving: each chip's pool holds only its heads' lanes.
             # Allocate DIRECTLY sharded — zeros-then-device_put would
@@ -169,6 +191,22 @@ class PagedKVCache:
         self._table_dirty = True
         self._table_dev: Optional[jnp.ndarray] = None
         self._peak_pages_used = 0
+        # ---- the window layers' pages (0 pages: the spec has none): which
+        # physical page backs each logical page a slot still holds, the
+        # host mirror of ``state["window_table"]``, and what went back
+        self.window = spec.sliding_window if spec.window_layers else 0
+        if not self.window:
+            self.num_window_pages = self.window_pages_per_slot = 0
+        self._wfree: List[int] = list(range(self.num_window_pages))
+        self._slot_wpages: Dict[int, Dict[int, int]] = {}
+        self._wtable = np.zeros_like(self._table)
+        self._wtable_dirty = False
+        self._window_pages_released = 0
+        self._peak_window_pages_used = 0
+        # sums over decode dispatches (``tally_window``): window pages held,
+        # and the pages the same contexts hold in the full layers' pool
+        self._window_pages_held_sum = 0
+        self._window_pages_uncut_sum = 0
 
         # ---- prefix cache (vLLM-style shared full pages; SURVEY.md §3.5's
         # kvstore north-star taken one level deeper: the unit of reuse is a
@@ -261,10 +299,19 @@ class PagedKVCache:
         not enough pages (caller queues the request)."""
         if not self._free_slots:
             return None
+        # the two kinds apart: the window pages of the rows a later step
+        # can still see, then the full layers' pages of every row
+        if self.window and len(self._window_span(n_tokens)) > len(
+                self._wfree):
+            return None
         pages = self._take_free(self._pages_for(n_tokens))
         if pages is None:
             return None
-        return self._install_slot_pages(pages, n_tokens)
+        slot = self._install_slot_pages(pages, n_tokens)
+        if self.window:
+            self._slot_wpages[slot] = {}
+            self._hold_window(slot, self._window_span(n_tokens))
+        return slot
 
     def _install_slot_pages(self, pages: List[int], n_tokens: int) -> int:
         """Shared tail of slot allocation: claim a slot id and point its
@@ -327,6 +374,12 @@ class PagedKVCache:
             self._table_dirty = True
         cap = min(len(pages) * self.page_size, self.max_seq_len)
         self._slot_len[slot] = max(self._slot_len[slot], min(target, cap))
+        if self.window:
+            # the window layers' pages for the same rows ahead; the pool
+            # backs every slot's bound, so it cannot run dry
+            held = self._slot_wpages[slot]
+            self._hold_window(slot, range(max(held, default=len(pages) - 1) + 1,
+                                          len(pages)))
         return cap
 
     def free_slot(self, slot: int) -> None:
@@ -340,6 +393,8 @@ class PagedKVCache:
                 self.state, jnp.int32(slot))
         for p in pages:
             self._unref(p)
+        if self.window:
+            self._wfree.extend(self._slot_wpages.pop(slot).values())
         del self._slot_len[slot]
         self._free_slots.append(slot)
         self._table[slot, :] = 0
@@ -347,6 +402,60 @@ class PagedKVCache:
 
     def _pages_for(self, n_tokens: int) -> int:
         return max(1, -(-n_tokens // self.page_size))
+
+    # ------------------------------------------------ window layers' pages
+
+    def _window_span(self, n_tokens: int) -> range:
+        """Logical pages of a prompt of ``n_tokens`` that hold a row a later
+        step can see: from the page of row ``n_tokens - window + 1`` to the
+        prompt's last."""
+        first = max(n_tokens - self.window + 1, 0) // self.page_size
+        return range(first, self._pages_for(n_tokens))
+
+    def _hold_window(self, slot: int, logical_pages: range) -> None:
+        held = self._slot_wpages[slot]
+        for lp in logical_pages:
+            if not self._wfree:
+                raise OutOfPagesError(
+                    "window pool exhausted: a slot asked for more than "
+                    f"its {self.window_pages_per_slot} pages")
+            held[lp] = self._wfree.pop(0)
+            self._wtable[slot, lp] = held[lp]
+            self._wtable_dirty = True
+        self._peak_window_pages_used = max(self._peak_window_pages_used,
+                                           self.window_pages_used)
+
+    def release_behind_window(self, slot: int, cur: int) -> int:
+        """Free the slot's window pages that lie wholly before the first row
+        a step at position >= ``cur`` can see (``cur - window + 1``); they
+        go to the back of the free list and to whoever asks next. The
+        table's entries for them stay as they are: no program reads a row
+        behind its window. Returns the pages freed (0 without a window)."""
+        if not self.window:
+            return 0
+        held = self._slot_wpages[slot]
+        first = max(cur - self.window + 1, 0) // self.page_size
+        gone = [lp for lp in held if lp < first]
+        for lp in gone:
+            self._wfree.append(held.pop(lp))
+        self._window_pages_released += len(gone)
+        return len(gone)
+
+    def window_pages_held(self, slot: int) -> int:
+        return len(self._slot_wpages.get(slot, ()))
+
+    @property
+    def window_pages_used(self) -> int:
+        return self.num_window_pages - len(self._wfree)
+
+    def tally_window(self) -> None:
+        """One sample a decode dispatch: window pages held by the live
+        slots, and the pages their contexts hold where nothing is cut (the
+        full layers' pool, the same slots)."""
+        if self.window:
+            self._window_pages_held_sum += self.window_pages_used
+            self._window_pages_uncut_sum += sum(
+                len(p) for p in self._slot_pages.values())
 
     # ----------------------------------------------------- prefix caching
 
@@ -554,7 +663,12 @@ class PagedKVCache:
     @property
     def pools(self):
         """The pair a donating program takes and returns: K and V pages,
-        or the latent pages and the per-slot state of a layered spec."""
+        or the latent pages and the per-slot state of a layered spec (with
+        the window layers' page table as the host has it now)."""
+        if self._wtable_dirty:
+            self.state = dict(self.state,
+                              window_table=jnp.array(self._wtable))
+            self._wtable_dirty = False
         return self.k_pages, (self.v_pages if self.state is None
                               else self.state)
 
@@ -698,6 +812,19 @@ class PagedKVCache:
             # by the token, a fixed state per slot for the recurrent ones
             "paged_layers": self.spec.paged_layers,
             "state_layers": self.spec.state_layers,
+            # the window layers' pool beside the keys above, which keep
+            # their meaning (the layers that keep every row)
+            **({"window_layers": self.spec.window_layers,
+                "window_num_pages": self.num_window_pages,
+                "window_pages_per_slot": self.window_pages_per_slot,
+                "window_pages_used": self.window_pages_used,
+                "peak_window_pages_used": self._peak_window_pages_used,
+                "window_pages_released": self._window_pages_released,
+                "window_pages_held_sum": self._window_pages_held_sum,
+                "window_pages_uncut_sum": self._window_pages_uncut_sum,
+                "window_hbm_bytes": int(
+                    self.state["window_pages"].nbytes)}
+               if self.window else {}),
             "state_bytes": state_bytes,
             "latent_bytes_per_token": (
                 self.spec.paged_layers * self.k_pages.shape[-1] * itemsize

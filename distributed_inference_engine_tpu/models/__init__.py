@@ -18,6 +18,7 @@ from .gemma import gemma_spec  # noqa: F401
 from .ling import ling_spec  # noqa: F401
 from .xing import xing_spec  # noqa: F401
 from .olmo_hybrid import olmo_hybrid_spec  # noqa: F401
+from .mellum import mellum_spec  # noqa: F401
 from .fake import FakeContinuousEngine, FakeEngine, FakePrefillEngine  # noqa: F401
 
 logger = logging.getLogger(__name__)
@@ -33,6 +34,7 @@ _FAMILIES = {
     "ling": (ling_spec, "ling-3.0-flash-ep4"),
     "xing": (xing_spec, "xing4.0-pp1"),
     "olmo_hybrid": (olmo_hybrid_spec, "olmo-hybrid-7b-pp2"),
+    "mellum": (mellum_spec, "mellum2-12b-a2.5b-pp1"),
 }
 
 
@@ -418,9 +420,9 @@ def engine_from_config(cfg):
 
 
 def _hybrid_engine(cfg, spec, ecfg):
-    """A per-layer (hybrid) spec, ``models/ling.py``, ``models/xing.py`` or
-    ``models/olmo_hybrid.py`` (``models/base.py`` ``layered_family`` tells
-    them apart): served by the
+    """A per-layer (hybrid) spec, ``models/ling.py``, ``models/xing.py``,
+    ``models/olmo_hybrid.py`` or ``models/mellum.py`` (``models/base.py``
+    ``layered_family`` tells them apart): served by the
     continuous engine from a random tree keyed by ``metadata.seed``. What it
     cannot do yet fails here, before any weight exists; the engine refuses
     the ``ecfg`` keys it cannot honour for such a spec (kv_offload,

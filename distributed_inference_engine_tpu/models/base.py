@@ -113,12 +113,14 @@ class ModelSpec:
     emb_scale: bool = False        # Gemma: embeddings scaled by sqrt(d_model)
     norm_plus_one: bool = False    # Gemma: RMSNorm applies (1 + weight)
     logit_softcap: float = 0.0     # Gemma-2: cap * tanh(logits / cap)
-    sliding_window: int = 0        # Mistral v0.1: window size; 0 = full attention
+    # Mistral v0.1: window size, every layer's (0 = full attention); of a
+    # per-layer spec, its "swa" layers' (token i sees i - window < j <= i)
+    sliding_window: int = 0
     # Per-layer description (hybrid families, ``models/ling.py``). Empty =
     # the uniform decoder above: every layer softmax attention over K/V,
     # its MLP dense or routed by ``n_experts`` (``layer_plan`` derives it).
-    # per layer "kda" | "mla" (models/ling.py, models/xing.py) or "gdn" |
-    # "full" (models/olmo_hybrid.py)
+    # per layer "kda" | "mla" (models/ling.py, models/xing.py), "gdn" |
+    # "full" (models/olmo_hybrid.py) or "swa" | "full" (models/mellum.py)
     layer_kinds: Tuple[str, ...] = ()
     layer_mlps: Tuple[str, ...] = ()    # per layer "dense" | "moe"
     layer_ids: Tuple[int, ...] = ()     # published index of each kept layer
@@ -141,17 +143,22 @@ class ModelSpec:
     gdn_value_head_dim: int = 0
     gdn_conv: int = 4
     # routed experts of a hybrid spec (ops/moe_routed.py): sigmoid scores,
-    # expert bias, group-limited top-k over ALL n_experts; this chip
-    # computes experts [first, first + count) and one shared expert
+    # expert bias, group-limited top-k over ALL n_experts, or a softmax
+    # over all of them and its top-k renormalised (``moe_scoring``); this
+    # chip computes experts [first, first + count) and, where shared_d_ff
+    # is set, one shared expert
     moe_d_ff: int = 0
     shared_d_ff: int = 0
+    moe_scoring: str = "sigmoid"   # "sigmoid" | "softmax"
     n_group: int = 1
     topk_group: int = 1
     routed_scaling_factor: float = 1.0
     experts_held: Tuple[int, int] = (0, 0)   # (first expert, count)
     # MLA with a compressed query (0 = the query projected in one matrix)
     # and YaRN-scaled rotary frequencies (empty = plain RoPE): the
-    # published ``rope_scaling`` group, ``ops/mla.py`` ``yarn_*``
+    # published ``rope_scaling`` group, ``ops/mla.py`` ``yarn_*``; of a
+    # spec with "swa" layers, its "full" layers' table (the "swa" layers
+    # rotate by plain RoPE at ``rope_theta``)
     q_lora_rank: int = 0
     rope_scaling: Tuple[Tuple[str, Any], ...] = ()
     # manifold-constrained hyper-connections (``ops/mhc.py``): the residual
@@ -186,7 +193,14 @@ class ModelSpec:
     @property
     def paged_layers(self) -> int:
         """Layers whose cache grows by the token (pages)."""
-        return sum(k not in ("kda", "gdn") for k, _m, _i in self.layer_plan)
+        return sum(k not in ("kda", "gdn", "swa")
+                   for k, _m, _i in self.layer_plan)
+
+    @property
+    def window_layers(self) -> int:
+        """Layers whose cache stops growing at ``sliding_window`` rows: they
+        keep pages of their own, freed as the window passes them."""
+        return sum(k == "swa" for k, _m, _i in self.layer_plan)
 
     @property
     def state_layers(self) -> int:
@@ -239,13 +253,28 @@ class ModelSpec:
                 raise ValueError("layer_kinds / layer_mlps / layer_ids must "
                                  "each describe all n_layers layers")
             kinds = set(self.layer_kinds)
-            if kinds - {"kda", "mla"} and kinds - {"gdn", "full"}:
+            if (kinds - {"kda", "mla"} and kinds - {"gdn", "full"}
+                    and kinds - {"swa", "full"}):
                 raise ValueError(
-                    "a per-layer spec holds 'kda' and 'mla' layers, or "
-                    f"'gdn' and 'full' ones, not {kinds}")
-            if kinds & {"gdn", "full"}:
-                period = self.layer_kinds[:self.layer_kinds.index("full") + 1
-                                          ] if "full" in kinds else ()
+                    "a per-layer spec holds 'kda' and 'mla' layers "
+                    "(models/ling.py, models/xing.py), 'gdn' and 'full' "
+                    "ones (models/olmo_hybrid.py) or 'swa' and 'full' ones "
+                    f"(models/mellum.py), not {sorted(kinds)}: no family "
+                    "runs that mix")
+            period = self.layer_kinds[:self.layer_kinds.index("full") + 1
+                                      ] if "full" in kinds else ()
+            if "swa" in kinds:
+                if (not period or self.sliding_window < 1
+                        or set(self.layer_mlps) != {"moe"}
+                        or self.layer_kinds
+                        != period * (n // len(period))):
+                    raise ValueError(
+                        "'swa' / 'full' layers come in whole periods of "
+                        "sliding-window layers closed by a full one (the "
+                        "tree is one period stacked over the periods), "
+                        "with sliding_window >= 1 and routed experts in "
+                        "every layer")
+            elif kinds & {"gdn", "full"}:
                 if (not period or self.gdn_key_head_dim < 1
                         or self.gdn_value_head_dim < 1
                         or "moe" in self.layer_mlps
@@ -270,6 +299,8 @@ class ModelSpec:
                     1 <= self.topk_group <= self.n_group):
                 raise ValueError("n_group must divide n_experts and "
                                  "topk_group lie in [1, n_group]")
+            if self.moe_scoring not in ("sigmoid", "softmax"):
+                raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
         if self.hc_mult and (set(self.layer_kinds) != {"mla"}
                              or self.q_lora_rank < 1
                              or self.hc_sinkhorn_iters < 1):
@@ -310,8 +341,11 @@ def layered_family(spec: ModelSpec):
     ``init_params`` / ``init_state`` / ``zero_state_slot`` and the programs'
     bodies (``forward_prefill_into_pages``, ``forward_decode_step``,
     ``decode_context``, ``write_rows_into_pages``). ``engine/`` reaches
-    them here and names no model file. Three families, told apart by what
-    the spec holds: ``gdn_key_head_dim`` (``models/olmo_hybrid.py``: Gated
+    them here and names no model file. Four families, told apart by what
+    the spec holds: "swa" layers (``models/mellum.py``: sliding-window
+    layers beside full-attention layers, K|V rows in two pools of unlike
+    lifetimes, routed experts everywhere), ``gdn_key_head_dim``
+    (``models/olmo_hybrid.py``: Gated
     DeltaNet layers beside full-attention layers over K|V rows, a dense MLP
     everywhere), ``hc_mult`` residual streams (``models/xing.py``: mHC
     around every sublayer, MLA in every layer, no recurrent state) or
@@ -320,6 +354,10 @@ def layered_family(spec: ModelSpec):
     if not spec.layer_kinds:
         raise ValueError("a uniform spec has no per-layer family: its "
                          "forward_* live in models/base.py")
+    if "swa" in spec.layer_kinds:
+        from . import mellum
+
+        return mellum
     if spec.gdn_key_head_dim:
         from . import olmo_hybrid
 

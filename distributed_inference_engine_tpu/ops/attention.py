@@ -108,6 +108,50 @@ def causal_attention_blocked(
     return jnp.concatenate([block(i0) for i0 in range(0, t, qb)], axis=1)
 
 
+def band_attention_blocked(
+    q: jnp.ndarray,          # [B, T, H, Dh]
+    k: jnp.ndarray,          # [B, T, Hkv, Dh]
+    v: jnp.ndarray,          # [B, T, Hkv, Dh]
+    seq_lens: jnp.ndarray,   # [B]
+    window: int = 0,         # row i sees i - window < j <= i (0 = causal)
+    q_block: int = 512,
+) -> jnp.ndarray:
+    """Grouped-query prefill attention in query blocks of ``q_block`` rows
+    (one block where ``T`` is no whole number of them), unrolled, each
+    reading ONLY the keys its rows can see: up to its own last row and,
+    with a ``window``, from ``window - 1`` rows before its first. A key
+    block wholly outside the band is not multiplied, not masked after the
+    fact: a windowed layer's work grows with ``T * window``, not ``T^2``,
+    and no ``[T, T]`` score tensor exists. K/V are broadcast across the
+    group as an indexing pattern. Returns [B, T, H, Dh]; rows past
+    ``seq_lens`` are not specified."""
+    b, t, h, dh = q.shape
+    n_kv = k.shape[2]
+    qb = q_block if t % q_block == 0 else t
+    qg = _group_query(q, n_kv)                              # [B,T,Hkv,G,Dh]
+    key_ok = jnp.arange(t)[None, :] < seq_lens[:, None]     # [B, T]
+    scale = dh ** -0.5
+
+    def block(i0):
+        k0 = max(0, i0 - window + 1) if window else 0
+        k1 = i0 + qb
+        s = jnp.einsum("bikgd,bjkd->bkgij", qg[:, i0:k1], k[:, k0:k1],
+                       preferred_element_type=jnp.float32) * scale
+        rows = i0 + jnp.arange(qb)[:, None]
+        keys = k0 + jnp.arange(k1 - k0)[None, :]
+        mask = keys <= rows
+        if window:
+            mask &= rows - keys < window
+        mask = mask[None] & key_ok[:, None, k0:k1]           # [B, qb, nk]
+        s = jnp.where(mask[:, None, None], s, NEG_INF)
+        p = jnp.exp(s - s.max(axis=-1, keepdims=True))
+        p = p / p.sum(axis=-1, keepdims=True)
+        o = jnp.einsum("bkgij,bjkd->bikgd", p.astype(v.dtype), v[:, k0:k1])
+        return o.reshape(b, qb, h, dh)
+
+    return jnp.concatenate([block(i0) for i0 in range(0, t, qb)], axis=1)
+
+
 def suffix_attention(
     q: jnp.ndarray,            # [B, Ts, H, Dh] suffix queries
     k_ctx: jnp.ndarray,        # [B, Tc, Hkv, Dh] cached-context keys (padded)

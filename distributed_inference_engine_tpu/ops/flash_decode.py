@@ -114,14 +114,17 @@ def flash_decode_attention_xla(
     n_side: jnp.ndarray,       # [B] valid side entries (incl. this step's)
     *,
     n_kv_heads: int,
+    first_rows=None,           # [B] first cached row a row may read
 ) -> jnp.ndarray:
     """Reference composition: the exact three-part path the kernel fuses
     (paged prefix with stats ⊕ windowed side, merged). Correct everywhere;
     the parity tests pin the kernel to this and this to
-    ``cached_attention`` ground truth."""
+    ``cached_attention`` ground truth. ``first_rows``: cached rows below
+    it are masked (a sliding-window layer's lower bound; the pages of
+    ``page_table`` count from position 0)."""
     prefix = paged_attention_xla(
         q, k_pages, v_pages, page_table, prefix_lens,
-        n_kv_heads=n_kv_heads, with_stats=True)
+        n_kv_heads=n_kv_heads, with_stats=True, first_rows=first_rows)
     window_part = window_decode_attention(q, side_k, side_v, n_side)
     return merge_attention([prefix, window_part], dtype=q.dtype)
 
@@ -169,11 +172,13 @@ def _init_acc(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _attend(qbd, k, v, first_tok, n_valid, m_scr, l_scr, acc_scr, scale):
+def _attend(qbd, k, v, first_tok, n_valid, m_scr, l_scr, acc_scr, scale,
+            first_valid=None):
     """One online-softmax update over a key block.
 
     qbd [Hp, F], k/v [S, F] in the pool/side dtype; key j of the block is
-    valid iff ``first_tok + j < n_valid``. Invalid probs are explicitly
+    valid iff ``first_tok + j < n_valid`` (and, with ``first_valid``, not
+    below it: a sliding window's lower edge). Invalid probs are explicitly
     zeroed (not just NEG_INF-masked): a block may be ENTIRELY masked
     (empty side window), and with m still at NEG_INF
     exp(NEG_INF - NEG_INF) = 1 would sum stale buffer contents into the
@@ -194,6 +199,8 @@ def _attend(qbd, k, v, first_tok, n_valid, m_scr, l_scr, acc_scr, scale):
             precision=_precision(cdt)) * scale                # [Hp, S]
     tok = first_tok + lax.broadcasted_iota(jnp.int32, s.shape, 1)
     valid = tok < n_valid
+    if first_valid is not None:
+        valid &= tok >= first_valid
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_scr[...]                                       # [Hp, 1]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -227,7 +234,7 @@ def _prefix_loop(
     buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
     v_vmem, sem, m_scr, l_scr, acc_scr,
     *, bp, page_size, n_pages_per_layer, scale, kv_lanes=0, v_lanes=0,
-    attend_pages=1, copied_ref=None,
+    attend_pages=1, copied_ref=None, first_rows_ref=None,
 ):
     """Flash loop over row ``b``'s live prefix pages: ``bp`` pages per
     block, double-buffered manual DMA, next block (possibly the first
@@ -239,6 +246,12 @@ def _prefix_loop(
     than a block pays for its own pages only and a dead row (length 0)
     starts no DMA at all. Each buffer slot has its own DMA semaphore: the
     other slot's prefetch is in flight while this one is waited on.
+
+    ``first_rows_ref[b]`` (the K/V kernel): the first cached row the row may
+    read, a sliding-window layer's lower edge. The row's blocks then count
+    from the page that holds it: no copy of a page wholly before it is
+    started (the allocator has freed those), and the rows before it in the
+    first page read are masked. 0 = every live page, as before.
 
     ``next_live_ref[b]`` holds the next row after ``b`` with a non-empty
     prefix (or B): rows that never enter this loop must not be prefetched
@@ -266,8 +279,14 @@ def _prefix_loop(
     blk_tokens = bp * page_size
     base = layer_ref[0] * n_pages_per_layer
 
+    def first_page(row):
+        return lax.div(first_rows_ref[row], page_size)
+
     def copies(row, blk, slot, j):
-        page = base + page_table_ref[row, blk * bp + j]
+        col = blk * bp + j
+        if first_rows_ref is not None:
+            col = first_page(row) + col
+        page = base + page_table_ref[row, col]
         if v_lanes:
             return (pltpu.make_async_copy(
                 k_pages_hbm.at[page],
@@ -287,6 +306,8 @@ def _prefix_loop(
 
     def for_live_pages(row, blk, fn):
         n_live = lax.div(prefix_lens_ref[row] + page_size - 1, page_size)
+        if first_rows_ref is not None:
+            n_live = n_live - first_page(row)
         for j in range(bp):
             pl.when(blk * bp + j < n_live)(functools.partial(fn, j))
 
@@ -299,7 +320,13 @@ def _prefix_loop(
         for_live_pages(row, blk, go)
 
     length = prefix_lens_ref[b]
-    nblk = lax.div(length + blk_tokens - 1, blk_tokens)
+    if first_rows_ref is None:
+        first_tok, first_valid = 0, None
+        nblk = lax.div(length + blk_tokens - 1, blk_tokens)
+    else:
+        first_tok, first_valid = first_page(b) * page_size, first_rows_ref[b]
+        nblk = lax.div(lax.div(length + page_size - 1, page_size)
+                       - first_page(b) + bp - 1, bp)
 
     def body(i, _):
         slot = lax.rem(buffer_index_ref[0], 2)
@@ -319,9 +346,12 @@ def _prefix_loop(
         def page(j):
             for c in copies(b, i, slot, j):
                 c.wait()
-            _attend(qbd, k_vmem[slot, j], v_vmem[slot, j],
-                    (i * bp + j) * page_size, length,
-                    m_scr, l_scr, acc_scr, scale)
+            k, v = k_vmem[slot, j], v_vmem[slot, j]
+            tok = (i * bp + j) * page_size
+            if first_rows_ref is not None:
+                tok = first_tok + tok
+            _attend(qbd, k, v, tok, length, m_scr, l_scr, acc_scr, scale,
+                    first_valid)
 
         def group(n_live, first):
             def wait(j):
@@ -355,19 +385,19 @@ def _flash_decode_kernel(
     # scalar prefetch
     page_table_ref,            # [B, MP] SMEM
     prefix_lens_ref,           # [B]
-    next_live_ref,             # [B] next row with a non-empty prefix
+    next_live_ref,             # [B] next row with a page to copy
     n_side_ref,                # [B]
     layer_ref,                 # [1] layer offset into stacked pools
-    buffer_index_ref,          # [1] MUTABLE: double-buffer slot
-    step_ref,                  # [1] MUTABLE: global processed-block count
+    # then, with ``lower_bound``, first_rows_ref [B]: the first cached row a
+    # row may read; then
+    #   buffer_index_ref       [1] MUTABLE: double-buffer slot
+    #   step_ref               [1] MUTABLE: global processed-block count
     # inputs
-    q_ref,                     # [1, Hp, Dh] VMEM (auto-pipelined)
-    side_k_ref,                # [1, W, Hkv*Dh] VMEM (auto-pipelined)
-    side_v_ref,
-    k_pages_hbm,               # [L*N, P, Hkv*Dh] ANY (stays in HBM)
-    v_pages_hbm,
+    #   q_ref                  [1, Hp, Dh] VMEM (auto-pipelined)
+    #   side_k_ref, side_v_ref [1, W, Hkv*Dh] VMEM (auto-pipelined)
+    #   k_pages_hbm, v_pages_hbm  [L*N, P, Hkv*Dh] ANY (stays in HBM)
     # outputs
-    out_ref,                   # [1, Hp, Dh] VMEM
+    #   out_ref                [1, Hp, Dh] VMEM
     # then, with ``count_pages``, copied_ref [1] SMEM: pages copied so far;
     # then the scratch:
     #   k_vmem, v_vmem         [2, bp, P, Hkv*Dh] double-buffered blocks
@@ -383,7 +413,12 @@ def _flash_decode_kernel(
     n_pages_per_layer: int,
     kv_lanes: int = 0,
     count_pages: bool = False,
+    lower_bound: bool = False,
 ):
+    first_rows_ref = rest[0] if lower_bound else None
+    (buffer_index_ref, step_ref, q_ref, side_k_ref, side_v_ref, k_pages_hbm,
+     v_pages_hbm, out_ref) = rest[int(lower_bound):][:8]
+    rest = rest[int(lower_bound) + 8:]
     copied_ref = rest[0] if count_pages else None
     k_vmem, v_vmem, m_scr, l_scr, acc_scr, sem = rest[int(count_pages):]
     b = pl.program_id(0)
@@ -404,7 +439,8 @@ def _flash_decode_kernel(
         v_vmem, sem, m_scr, l_scr, acc_scr,
         bp=pages_per_block, page_size=page_size,
         n_pages_per_layer=n_pages_per_layer, scale=scale,
-        kv_lanes=kv_lanes, copied_ref=copied_ref)
+        kv_lanes=kv_lanes, copied_ref=copied_ref,
+        first_rows_ref=first_rows_ref)
 
     # final block: the chunk side window (auto-pipelined into VMEM — its
     # DMA overlaps the previous grid step's compute)
@@ -448,7 +484,8 @@ def _layer_scalar(layer):
 
 def _next_live(prefix_lens: jnp.ndarray) -> jnp.ndarray:
     """next_live[b] = smallest row r > b with prefix_lens[r] > 0, else B —
-    the kernel's cross-row prefetch target (see ``_prefix_loop``)."""
+    the kernel's cross-row prefetch target (see ``_prefix_loop``); with a
+    lower bound the launcher hands in each row's pages to copy instead."""
     batch = prefix_lens.shape[0]
     rows = jnp.arange(batch, dtype=jnp.int32)
     cand = jnp.where(prefix_lens > 0, rows, jnp.int32(batch))
@@ -519,8 +556,14 @@ def flash_decode_attention_pallas(
     pages_per_block: int = 0,
     kv_fused: bool = False,
     count_pages: bool = False,
+    first_rows=None,
 ):
     """Fused attention, side writes stay with the caller. [B, H, Dh].
+    ``first_rows`` [B]: the first cached row each row may read (a
+    sliding-window layer's lower edge, at most its ``prefix_lens``): no
+    page wholly before it is copied and the rows before it are masked;
+    None = every live page, the kernel as it was (no such scalar at all:
+    the callers that pass none trace the program they always traced).
     ``kv_fused``: ``k_pages`` and ``v_pages`` are ONE pool whose rows are
     K|V side by side (``[.., P, 2 * fused]``, a per-layer family's); the
     kernel copies each half of a page's lanes where it lies.
@@ -542,8 +585,16 @@ def flash_decode_attention_pallas(
     kv_scratch, acc_scratch = _scratch(hp, fused, bp, page_size,
                                        k_pages.dtype)
 
+    lower_bound = first_rows is not None
+    # a row enters the prefix loop iff it has a page to copy
+    enters, bound = prefix_lens, ()
+    if lower_bound:
+        first_rows = first_rows.astype(jnp.int32)
+        enters = -(-prefix_lens // page_size) - first_rows // page_size
+        bound = (first_rows,)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=7,
+        num_scalar_prefetch=7 + lower_bound,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, hp, dh), lambda i, *_: (i, 0, 0)),
@@ -564,7 +615,8 @@ def flash_decode_attention_pallas(
         n_kv_heads=n_kv_heads, head_dim=dh, page_size=page_size,
         n_heads=h, pages_per_block=bp,
         n_pages_per_layer=n_pages_per_layer or n,
-        kv_lanes=fused if kv_fused else 0, count_pages=count_pages)
+        kv_lanes=fused if kv_fused else 0, count_pages=count_pages,
+        lower_bound=lower_bound)
     out_shape = jax.ShapeDtypeStruct((b, hp, dh), q.dtype)
     if count_pages:
         out_shape = [out_shape, jax.ShapeDtypeStruct((1,), jnp.int32)]
@@ -578,8 +630,8 @@ def flash_decode_attention_pallas(
                             k_pages.dtype.itemsize, side_k.dtype.itemsize),
         interpret=interpret,
         name=_OP_NAME,
-    )(page_table, prefix_lens, _next_live(prefix_lens), n_side,
-      _layer_scalar(layer),
+    )(page_table, prefix_lens, _next_live(enters), n_side,
+      _layer_scalar(layer), *bound,
       jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
       qp, side_k.reshape(b, w, fused), side_v.reshape(b, w, fused),
       k_pages, v_pages)

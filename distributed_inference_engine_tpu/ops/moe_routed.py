@@ -5,11 +5,15 @@ Routing, float32, over ALL ``n_experts``: ``s = sigmoid(x W_r)``; choice
 scores ``s + b`` (``b`` the expert bias); ``n_group`` groups, a group's
 score the sum of its two largest ``s + b``, the ``topk_group`` best groups
 kept; top-k of ``s + b`` inside them; gates ``s`` at the chosen experts over
-their sum, times ``routed_scaling_factor``.
+their sum, times ``routed_scaling_factor``. A spec whose ``moe_scoring`` is
+``softmax`` (Mellum 2, the Qwen-MoE convention) has neither bias nor groups:
+``p = softmax(x W_r)`` over all experts, the ``k`` largest, gates ``p`` at
+the chosen experts over their sum (``norm_topk_prob``).
 
 This chip holds experts ``[first, first + count)`` (``spec.experts_held``)
 and computes their gate-weighted part of the result, plus the shared expert
-once; what the absent experts would add is left out. No capacity, nothing
+once where the spec has one (``shared_d_ff``); what the absent experts would
+add is left out. No capacity, nothing
 dropped. The product is GROUPED: the (token, choice) pairs that landed on a
 held expert are sorted by expert and each expert multiplies only its own
 rows (``grouped_matmul``), so operations follow the routed tokens and, in
@@ -25,13 +29,18 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def route(spec, x: jnp.ndarray, w_router: jnp.ndarray, bias: jnp.ndarray
+def route(spec, x: jnp.ndarray, w_router: jnp.ndarray, bias=None
           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x [N, D] -> (expert ids [N, k] int32, gates [N, k] float32)."""
+    """x [N, D] -> (expert ids [N, k] int32, gates [N, k] float32).
+    ``bias`` is the sigmoid scoring's expert bias; softmax takes none."""
     e, ng, k = spec.n_experts, spec.n_group, spec.experts_per_token
-    s = jax.nn.sigmoid(jnp.einsum(
+    logits = jnp.einsum(
         "nd,de->ne", x.astype(jnp.float32), w_router.astype(jnp.float32),
-        precision=lax.Precision.HIGHEST))
+        precision=lax.Precision.HIGHEST)
+    if spec.moe_scoring == "softmax":
+        g, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        return idx.astype(jnp.int32), g / jnp.sum(g, axis=-1, keepdims=True)
+    s = jax.nn.sigmoid(logits)
     c = s + bias.astype(jnp.float32)
     top2, _ = lax.top_k(c.reshape(-1, ng, e // ng), 2)
     _, groups = lax.top_k(top2.sum(-1), spec.topk_group)       # [N, topk_g]
@@ -94,18 +103,23 @@ def _swiglu(x, w_gate_up, w_down):
 
 
 def moe_block(spec, blk: Dict[str, jnp.ndarray], x: jnp.ndarray,
-              valid: jnp.ndarray, impl: str = ""
+              valid: jnp.ndarray, impl: str = "", expert_offset=None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x [N, D], ``valid`` [N] bool (pad rows and rows not live route
     nowhere). Returns (out [N, D] in x's dtype, counters int32 [3]:
     assignments on held experts, assignments in all, held experts with a
-    row)."""
+    row). ``expert_offset`` (a traced int32): ``w_gate_up`` / ``w_down``
+    hold SEVERAL layers' experts one after another (a tree stacked over
+    periods and scanned, ``models/mellum.py``) and this layer's begin
+    there; the other layers' are groups of no rows, which the grouped
+    product does not visit, so no layer's matrices are sliced out (a copy
+    of 0.8 GB a layer a step at Mellum's widths)."""
     impl = impl or default_impl()
     n, d = x.shape
     k = spec.experts_per_token
     first, held = spec.experts_held
     with jax.named_scope("moe.route"):
-        idx, gates = route(spec, x, blk["w_router"], blk["router_bias"])
+        idx, gates = route(spec, x, blk["w_router"], blk.get("router_bias"))
         local = idx - first
         on = (local >= 0) & (local < held) & valid[:, None]
         key = jnp.where(on, local, held).reshape(-1)           # [N*k]
@@ -116,19 +130,27 @@ def moe_block(spec, blk: Dict[str, jnp.ndarray], x: jnp.ndarray,
         pad = -m % tm
         tok = jnp.pad(order // k, (0, pad))
         row_ok = jnp.arange(m + pad) < jnp.sum(sizes)
+        groups = sizes
+        if expert_offset is not None:
+            groups = lax.dynamic_update_slice(
+                jnp.zeros((blk["w_gate_up"].shape[0],), sizes.dtype), sizes,
+                (expert_offset,))
     with jax.named_scope("moe.experts"):
         rows = x[tok]                                          # [M, D]
-        gu = grouped_matmul(rows, blk["w_gate_up"], sizes, impl)
+        gu = grouped_matmul(rows, blk["w_gate_up"], groups, impl)
         gate, up = jnp.split(gu, 2, axis=-1)
         h = jnp.where(row_ok[:, None], jax.nn.silu(gate) * up, 0.0)
-        y = grouped_matmul(h.astype(x.dtype), blk["w_down"], sizes, impl)
+        y = grouped_matmul(h.astype(x.dtype), blk["w_down"], groups, impl)
         g_sorted = jnp.pad(gates.reshape(-1)[order], (0, pad))
         y = jnp.where(row_ok[:, None], y * g_sorted[:, None], 0.0)
         # back to (token, choice) order, then the k choices of a token add
         inv = jnp.argsort(order)
         routed = y[inv].reshape(n, k, d).sum(axis=1)
-    with jax.named_scope("moe.shared"):
-        shared = _swiglu(x, blk["ws_gate_up"], blk["ws_down"])
+    shared = None
+    if spec.shared_d_ff:
+        with jax.named_scope("moe.shared"):
+            shared = _swiglu(x, blk["ws_gate_up"], blk["ws_down"])
     counters = jnp.stack([
         jnp.sum(on), jnp.sum(valid) * k, jnp.sum(sizes > 0)]).astype(jnp.int32)
-    return (routed + shared).astype(x.dtype), counters
+    out = routed if shared is None else routed + shared
+    return out.astype(x.dtype), counters

@@ -43,6 +43,7 @@ def paged_attention_xla(
     n_kv_heads: int,
     window: int = 0,           # sliding-window size (0 = full attention)
     with_stats: bool = False,
+    first_rows=None,           # [B] rows below it are masked (None = 0)
 ):
     """Reference implementation via gather; correct everywhere (CPU tests,
     interpret-mode cross-check), but reads the whole gathered cache through
@@ -67,6 +68,8 @@ def paged_attention_xla(
     valid = jnp.arange(mp * p)[None, :] < lengths[:, None]        # [B, S]
     if window:
         valid &= jnp.arange(mp * p)[None, :] >= (lengths[:, None] - window)
+    if first_rows is not None:
+        valid &= jnp.arange(mp * p)[None, :] >= first_rows[:, None]
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     m = scores.max(axis=-1)                                       # [B,Hkv,G]
     probs = jnp.exp(scores - m[..., None])

@@ -371,3 +371,85 @@ def test_no_decode_chunk_of_a_latent_row_family_gathers_its_table(one_chip):
             assert shape not in text, shape
     # (167 MB today: buffers of the layers' weight matrices, none the pool's)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5 * pool_bytes
+
+
+def test_the_sliding_window_family_reads_both_pools_in_place_and_fits(
+        one_chip):
+    """The sliding-window family's programs at the served size (12 layers of
+    64 experts, 8 slots of 16,896 positions, 16 steps; a prefill at the
+    16,384 bucket), compiled for the v5e with the Mosaic grouped matmul:
+    every layer of both kinds reads its pool through the K|V kernel where it
+    lies, the window pool rides the decode scan with no copy, and the
+    largest prefill's temporaries fit beside the 10.9 GB tree and the 1 GB
+    of pages in a chip's 15.75 GB."""
+    from distributed_inference_engine_tpu.models import mellum as fam
+    from distributed_inference_engine_tpu.models.base import unembed
+
+    spec = fam.mellum_spec("mellum2-12b-a2.5b-pp1", max_seq_len=16896)
+    slots, page, mp, steps = 8, 128, 132, 16
+    n_pages = slots * mp
+    n_window = slots * fam.window_pages_per_slot(spec, page)
+    assert n_window == 80 and spec.cache_row_width == 1024
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def arr(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_params(spec, jax.random.key(0))))
+    state = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_state(spec, slots, page, n_window, mp)))
+    pool = arr(spec.paged_layers, n_pages, page, spec.cache_row_width,
+               dtype=jnp.bfloat16)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        (params, state, pool)))
+    assert 11.9e9 < held < 12.0e9
+
+    def decode(params, pages, state, lengths, last, active, table):
+        ctx = fam.decode_context(pages, table, "pallas-decode")
+        side = jnp.zeros((fam.side_layers(spec), slots, steps,
+                          spec.cache_row_width), pages.dtype)
+
+        def step(carry, _):
+            side, state, now, last = carry
+            hidden, side, state, _m = fam.forward_decode_step(
+                spec, params, last, now, lengths, ctx, side, state, active,
+                moe_impl="gmm")
+            tok = jnp.argmax(unembed(spec, params, hidden), -1)
+            return (side, state, now + 1, tok.astype(jnp.int32)), tok
+
+        (side, state, now, last), toks = jax.lax.scan(
+            step, (side, state, lengths, last), None, length=steps)
+        pages, state = fam.write_side(pages, state, side, table,
+                                      now - lengths, lengths)
+        return pages, state, toks
+
+    def prefill(params, tokens, lens, pages, state, table, slot_ids):
+        hidden, pages, state, _ = fam.forward_prefill_into_pages(
+            spec, params, tokens, lens, pages, state, table, slot_ids,
+            moe_impl="gmm")
+        return hidden[:, -1], pages, state
+
+    dec = jax.jit(decode, donate_argnums=(1, 2)).lower(
+        params, pool, state, arr(slots), arr(slots),
+        arr(slots, dtype=jnp.bool_), arr(slots, mp)).compile()
+    text = dec.as_text()
+    assert text.count("flash_decode_custom_call") >= 2     # one a kind
+    # neither pool is copied, gathered or sliced by the chunk
+    for shape in ("[9,80,128,1024]", "[3,1056,128,1024]"):
+        ops = set()
+        for line in text.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z-]*)\(", line)
+            if m and shape in m.group(1).split(" ")[0]:
+                ops.add(m.group(2))
+        assert ops and ops <= {"parameter", "tuple", "get-tuple-element",
+                               "while", "bitcast", "scatter", "fusion",
+                               "custom-call"}, (shape, ops)
+    assert dec.memory_analysis().temp_size_in_bytes < 0.6e9
+    pre = jax.jit(prefill, donate_argnums=(3, 4)).lower(
+        params, arr(1, 16384), arr(1), pool, state, arr(1, mp),
+        arr(1)).compile()
+    temp = pre.memory_analysis().temp_size_in_bytes
+    assert held + temp < 15.2e9, (held, temp)
