@@ -53,7 +53,7 @@ from ..models.base import (
     unembed,
     write_prefill_pages,
 )
-from ..ops import kda
+from ..ops import flash_prefill, kda
 from ..ops.mla import prefill_key_blocks
 from ..ops.sampling import (
     SamplingParams,
@@ -460,6 +460,13 @@ class ContinuousEngine:
         # their buckets' whole squares (ops/mla.py), per paged layer
         self._mla_prefill_visited = 0
         self._mla_prefill_square = 0
+        # the same two where the paged layers keep K|V rows, by layer kind
+        # (ops/flash_prefill.py: a sliding layer's are the band's), per
+        # layer of the kind: kind -> (its window, [visited, square])
+        self._kv_prefill_blocks = {
+            kind: (window, [0, 0])
+            for kind, window in (("full", 0), ("window", self.kv.window))
+            if self._kv_rows and (kind == "full" or window)}
         # defer_sync: chunk k's packed output is read AFTER dispatching
         # chunk k+1, overlapping the host round trip with device compute
         # (validated pre-init above; the pool's own bound must agree)
@@ -1562,11 +1569,16 @@ class ContinuousEngine:
                 jnp.asarray(slot_ids),
             )
             self._prefill_moe.append(moe)
-            if not self._kv_rows:          # the latent prefill's blocks
-                for row in batch:
+            for row in batch:              # the prefill's key blocks
+                if not self._kv_rows:
                     visited, square = prefill_key_blocks(len(row[3]), tb)
                     self._mla_prefill_visited += visited
                     self._mla_prefill_square += square
+                for window, sums in self._kv_prefill_blocks.values():
+                    visited, square = flash_prefill.prefill_key_blocks(
+                        len(row[3]), tb, window)
+                    sums[0] += visited
+                    sums[1] += square
         elif self._prefill_pages is not None:
             # fused path: per-layer KV scatters into the donated pools
             # inside the prefill scan (pad rows' seq_len 0 drops every
@@ -2947,12 +2959,18 @@ class ContinuousEngine:
             # decode steps attended to (per paged layer) and rows the body
             # read for them; recurrent specs: (row, step) pairs that moved
             # a state (per recurrent layer) and the body that moved them
+            # and the key blocks their prefills visited / the blocks of
+            # the buckets' squares, per layer of each kind
             **({"attn": {"full_context_rows": self._full_context_rows,
                          "full_table_rows": self._full_table_rows,
                          **({"window_context_rows":
                              self._window_context_rows,
                              "window_table_rows": self._window_table_rows}
-                            if self.kv.window else {})}}
+                            if self.kv.window else {}),
+                         **{f"{kind}_prefill_key_blocks_{name}": n
+                            for kind, (_, sums) in
+                            self._kv_prefill_blocks.items()
+                            for name, n in zip(("visited", "bucket"), sums)}}}
                if self._kv_rows else {}),
             **({"state": {"rows_updated": self._state_rows_updated,
                           "step_body": self.state_step_body}}

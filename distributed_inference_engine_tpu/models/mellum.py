@@ -54,11 +54,11 @@ import numpy as np
 from jax import lax
 
 from ..ops import mla
-from ..ops.attention import band_attention_blocked
 from ..ops.flash_decode import (
     flash_decode_attention_pallas,
     flash_decode_attention_xla,
 )
+from ..ops.flash_prefill import kv_prefill_attention
 from ..ops.moe_routed import moe_block
 from ..ops.norms import rms_norm
 from .base import ModelSpec, embed
@@ -319,9 +319,8 @@ def attn_layer_prefill(spec: ModelSpec, kind: str, blk: Params, x,
     with jax.named_scope(f"attn.{kind}"):
         h = rms_norm(x, blk["attn_norm"], spec.norm_eps)
         q, rows = _attn_inputs(spec, kind, blk, h, positions)
-        k, v = _kv_heads(spec, rows)
-        o = band_attention_blocked(
-            q, k, v, seq_lens,
+        o = kv_prefill_attention(
+            q, rows, seq_lens, spec.n_kv_heads,
             window=spec.sliding_window if kind == "swa" else 0)
         return _attn_out(blk, o, x.dtype), rows
 

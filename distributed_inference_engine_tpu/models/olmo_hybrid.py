@@ -49,11 +49,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..ops import kda
-from ..ops.attention import causal_attention_blocked
 from ..ops.flash_decode import (
     flash_decode_attention_pallas,
     flash_decode_attention_xla,
 )
+from ..ops.flash_prefill import kv_prefill_attention
 from ..ops.norms import rms_norm
 from .base import ModelSpec, embed
 from .ling import (  # the paged pool's views are the same code
@@ -334,8 +334,7 @@ def full_layer_prefill(spec: ModelSpec, blk: Params, x, seq_lens):
     """x [B, T, D] -> (sublayer out, cache rows [B, T, 2 * lanes])."""
     with jax.named_scope("attn.full"):
         q, rows = _full_inputs(spec, blk, x)
-        k, v = _kv_heads(spec, rows)
-        o = causal_attention_blocked(q, k, v, seq_lens)
+        o = kv_prefill_attention(q, rows, seq_lens, spec.n_kv_heads)
         return _full_out(spec, blk, o, x.dtype), rows
 
 

@@ -135,6 +135,23 @@ ENGINE_MLA_TABLE = [              # engine.get_metrics()["mla"]
      "Key blocks of their buckets' whole squares: what no skipping would visit"),
 ]
 
+ENGINE_ATTN_TABLE = [             # engine.get_metrics()["attn"]
+    ("full_prefill_key_blocks_visited",
+     "engine_attn_full_prefill_key_blocks_visited", "c",
+     "Key blocks the admitted prompts' full-attention prefills visited, per "
+     "full layer (specs whose paged layers keep K|V rows)"),
+    ("full_prefill_key_blocks_bucket",
+     "engine_attn_full_prefill_key_blocks_square", "c",
+     "Key blocks of their buckets' whole squares: what no skipping would visit"),
+    ("window_prefill_key_blocks_visited",
+     "engine_attn_window_prefill_key_blocks_visited", "c",
+     "Key blocks inside the band the sliding-window prefills visited, per "
+     "sliding layer"),
+    ("window_prefill_key_blocks_bucket",
+     "engine_attn_window_prefill_key_blocks_square", "c",
+     "Key blocks of their buckets' whole squares"),
+]
+
 ENGINE_WARMUP_TABLE = [           # engine.get_metrics()["warmup"]
     ("run_s", "worker_warmup_run_seconds", "g",
      "Warm-up seconds outside trace, lower and compile: programs running"),
@@ -484,6 +501,7 @@ _GROUPS: List[Tuple[List, Tuple[str, ...]]] = [
     (ENGINE_TABLE, MODEL_LABELS),
     (ENGINE_OFFLOAD_TABLE, MODEL_LABELS),
     (ENGINE_MLA_TABLE, MODEL_LABELS),
+    (ENGINE_ATTN_TABLE, MODEL_LABELS),
     (ENGINE_WARMUP_TABLE, MODEL_LABELS),
     (ENGINE_AFTER_WARMUP_TABLE, MODEL_LABELS),
     (KV_TABLE, MODEL_LABELS),
@@ -574,10 +592,9 @@ def apply_engine(reg: MetricsRegistry, m: Optional[Mapping[str, Any]],
     off = m.get("kv_offload")
     if isinstance(off, Mapping):
         _apply_table(reg, ENGINE_OFFLOAD_TABLE, off, MODEL_LABELS, labels)
-    latent = m.get("mla")
-    if isinstance(latent, Mapping):
-        _apply_table(reg, ENGINE_MLA_TABLE, latent, MODEL_LABELS, labels)
-    for key, table in (("warmup", ENGINE_WARMUP_TABLE),
+    for key, table in (("mla", ENGINE_MLA_TABLE),
+                       ("attn", ENGINE_ATTN_TABLE),
+                       ("warmup", ENGINE_WARMUP_TABLE),
                        ("compiles_after_warmup", ENGINE_AFTER_WARMUP_TABLE)):
         if isinstance(m.get(key), Mapping):
             _apply_table(reg, table, m[key], MODEL_LABELS, labels)

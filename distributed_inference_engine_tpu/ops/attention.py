@@ -10,9 +10,14 @@ points matching the two serving phases:
 - ``cached_attention``: decode, one query token per slot against the
   HBM-resident KV cache, masked by each slot's live length.
 
-Both are pure einsum/softmax chains: XLA fuses mask+softmax+matmul well on
-the MXU for these shapes. The Pallas paged-attention kernel
-(``ops/paged_attention.py``) takes over when the cache is paged.
+Both are einsum/softmax chains: XLA fuses mask+softmax+matmul well on the
+MXU at the uniform families' prompt lengths (<= 768 rows in the cells).
+The Pallas kernels take over where a chain would send its scores through
+HBM: ``ops/flash_decode.py`` when the cache is paged, and
+``ops/flash_prefill.py`` for the long prefills of the families whose cache
+rows are K|V. ``band_attention_blocked`` here is that prefill's ONE XLA
+body (the CPU, a ``T`` of no whole blocks): grouped-query, causal or
+banded, query blocks unrolled.
 
 GQA layout note: K/V carry ``n_kv_heads``; queries carry ``n_heads``. We
 reshape Q to [B, T, n_kv, group, Dh] and broadcast K/V across the group dim —
@@ -73,39 +78,6 @@ def causal_attention(
     probs = probs / probs.sum(axis=-1, keepdims=True)
     out = jnp.einsum("bkgij,bjkd->bikgd", probs.astype(v.dtype), v)
     return out.reshape(b, t, h, dh)
-
-
-def causal_attention_blocked(
-    q: jnp.ndarray,          # [B, T, H, Dh]
-    k: jnp.ndarray,          # [B, T, H, Dh]
-    v: jnp.ndarray,          # [B, T, H, Dh]
-    seq_lens: jnp.ndarray,   # [B]
-    q_block: int = 512,
-) -> jnp.ndarray:
-    """``causal_attention`` for one K/V head a query head (MHA) in query
-    blocks of ``q_block`` rows (one block where ``T`` is no whole number of
-    them), unrolled, each reading only the keys up to its own last row: no
-    [T, T] score tensor (30 heads at 4,096 positions: 2 GB in float32).
-    Returns [B, T, H, Dh]; rows past ``seq_lens`` are not specified."""
-    t, dh = q.shape[1], q.shape[-1]
-    qb = q_block if t % q_block == 0 else t
-    key_ok = jnp.arange(t)[None, :] < seq_lens[:, None]          # [B, T]
-    scale = dh ** -0.5
-
-    def block(i0):
-        n_keys = i0 + qb
-        s = jnp.einsum("bihd,bjhd->bhij", q[:, i0:n_keys], k[:, :n_keys],
-                       preferred_element_type=jnp.float32) * scale
-        rows = i0 + jnp.arange(qb)[:, None]
-        mask = (jnp.arange(n_keys)[None, :] <= rows)[None] \
-            & key_ok[:, None, :n_keys]
-        s = jnp.where(mask[:, None], s, NEG_INF)
-        p = jnp.exp(s - s.max(axis=-1, keepdims=True))
-        p = p / p.sum(axis=-1, keepdims=True)
-        return jnp.einsum("bhij,bjhd->bihd", p.astype(v.dtype),
-                          v[:, :n_keys])
-
-    return jnp.concatenate([block(i0) for i0 in range(0, t, qb)], axis=1)
 
 
 def band_attention_blocked(

@@ -5,7 +5,10 @@ the mistral-7b serving shapes, each against its XLA path.
     python -m scripts.chip_kernels --tiny     # CPU debug: interpreted, tiny
 
 Shapes (full): B=128, H=32, Hkv=8, Dh=128, page 128, ctx 256; the latent
-prefill at 32 heads of 128 | 64 | 128, T 1,024 and 8,192; the K|V-row read
+prefill at 32 heads of 128 | 64 | 128, T 1,024 and 8,192; the K|V-row
+families' prefill (``kv_prefill``: 32 : 4 heads full and banded, 30 MHA
+heads, timed alone beside the XLA body at 4,096 / 8,192 / 16,384 and over a
+sweep of block shapes); the K|V-row read
 of a per-layer family (``fused``: heads, pages a row, side window, stacked
 layers: 30 MHA heads of 128, 8 rows of up to 6,144 positions, timed alone);
 the latent rows' decode read of both MLA families (``latent``: 32 heads over
@@ -40,6 +43,26 @@ FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
                          (7168, 4096), (2048, 32768)),
             mla_dims=(128, 64, 128),
             mla_cases=((1024, 48), (1024, 1024), (8192, 48), (8192, 8192)),
+            # the K|V-row families' prefill: (H, Hkv, window, T, length)
+            # checked against the XLA body, then (H, Hkv, window, T, length)
+            # timed alone beside it, then the (Q_BLOCK, K_BLOCK,
+            # HEADS_PER_STEP) each timed shape is swept over
+            kv_prefill=((32, 4, 0, 1024, 1024), (32, 4, 1024, 2048, 2048),
+                        (32, 4, 1024, 8192, 4100), (32, 4, 0, 8192, 600),
+                        (30, 30, 0, 1024, 700), (30, 30, 0, 4096, 4096)),
+            kv_prefill_timed=((32, 4, 0, 4096, 4096), (32, 4, 0, 8192, 8192),
+                              (32, 4, 0, 16384, 16384),
+                              (32, 4, 0, 8192, 4100),
+                              (32, 4, 1024, 4096, 4096),
+                              (32, 4, 1024, 8192, 8192),
+                              (32, 4, 1024, 16384, 16384),
+                              (32, 4, 1024, 8192, 4100),
+                              (30, 30, 0, 1024, 1024),
+                              (30, 30, 0, 4096, 4096)),
+            kv_prefill_sweep=((512, 512, 8), (512, 512, 4), (512, 256, 8),
+                              (256, 512, 8), (256, 512, 4), (256, 256, 8),
+                              (128, 512, 8), (256, 1024, 8), (128, 256, 8),
+                              (1024, 512, 8)),
             fused=(30, 48, 16, 4),
             # (heads, rank, rope lanes, pages a row, side window, layers):
             # both latent-row families' MLA, the Xing cell's 7-layer pool
@@ -55,6 +78,10 @@ TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
             int4_rows=(32, 3),
             int4_shapes=((128, 256), (256, 128)),
             mla_dims=(16, 8, 16), mla_cases=((1024, 48), (1024, 1024)),
+            kv_prefill=((4, 2, 0, 1024, 700), (4, 2, 600, 1024, 1024),
+                        (2, 2, 0, 512, 48)),
+            kv_prefill_timed=((4, 2, 600, 1024, 700),),
+            kv_prefill_sweep=((256, 512, 4),),
             fused=(2, 6, 4, 3), latent=(4, 32, 8, 6, 4, 2),
             delta=((3, 4, 16, 32, 1), (2, 4, 16, 16, 16)))
 OUT = os.path.join("chiprun_out", "chip_kernels.json")
@@ -479,6 +506,82 @@ def check_mla_prefill(cfg, interpret):
     return f"{len(errs)} cases, max|err| {max(errs):.2e}"
 
 
+def check_kv_prefill(cfg, interpret):
+    """The K|V-row families' prefill kernel (``ops/flash_prefill.py``) at the
+    sliding-window family's head shape (32 : 4 heads of 128, full and with
+    its window of 1,024) and the Gated-DeltaNet family's (30 MHA heads),
+    one row, bfloat16, against the XLA body: whole buckets and a short row
+    in a long bucket, rows below ``seq_lens`` compared and the query blocks
+    past them zeros; then its time alone beside the XLA body's (ms a call,
+    the host's clock over calls ended by ``block_until_ready``), at the
+    blocks the served path resolves and over ``kv_prefill_sweep``."""
+    import jax
+    import jax.numpy as jnp
+
+    from distributed_inference_engine_tpu.ops import flash_prefill as fp
+
+    dh = cfg["Dh"]
+    flash = "flash_interpret" if interpret else "flash"
+
+    def inputs(h, hkv, t, n):
+        ks = jax.random.split(jax.random.key(h + t + n), 2)
+        q = jax.random.normal(ks[0], (1, t, h, dh), jnp.bfloat16)
+        rows = jax.random.normal(ks[1], (1, t, 2 * hkv * dh), jnp.bfloat16)
+        # values a quarter as large: outputs of magnitude <= 1
+        rows = rows.at[..., hkv * dh:].multiply(0.25)
+        return q, rows, jnp.asarray([n], jnp.int32)
+
+    def ms_a_call(fn, args, n):
+        jax.block_until_ready(fn(*args))
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return 1e3 * (time.perf_counter() - t0) / n
+
+    def body(hkv, window, impl):
+        return jax.jit(lambda *a: fp.kv_prefill_attention(
+            *a, hkv, window=window, impl=impl))
+
+    errs = []
+    for h, hkv, window, t, n in cfg["kv_prefill"]:
+        args = inputs(h, hkv, t, n)
+        got, ref = (body(hkv, window, impl)(*args)
+                    for impl in (flash, "xla"))
+        errs.append(_close(got[:, :n], ref[:, :n], 2e-2))
+        assert not bool(jnp.any(got[:, -(-n // fp.Q_BLOCK) * fp.Q_BLOCK:])), \
+            "a query block past the prompt is not zero"
+    detail = f"{len(errs)} cases, max|err| {max(errs):.2e}"
+    if interpret:
+        detail += " (interpreted: the times below are the host's, of the " \
+            "control flow)"
+    reps = 1 if interpret else 10
+    for h, hkv, window, t, n in cfg["kv_prefill_timed"]:
+        args = inputs(h, hkv, t, n)
+        visited, square = fp.prefill_key_blocks(n, t, window)
+        line = (f"{h}:{hkv} window {window} T {t} length {n} (blocks "
+                f"{visited} of {square}): xla "
+                f"{ms_a_call(body(hkv, window, 'xla'), args, 3):.3f} ms, "
+                f"kernel {ms_a_call(body(hkv, window, flash), args, reps):.3f}"
+                f" ms at {fp.Q_BLOCK}/{fp.K_BLOCK}/{fp.HEADS_PER_STEP}")
+        for bq, bk, hb in cfg["kv_prefill_sweep"]:
+            if t % bq or t % bk:
+                continue
+            fn = jax.jit(lambda q, rows, lens, bq=bq, bk=bk, hb=hb:
+                         fp._flash_prefill(
+                             q.reshape(1, t, h * dh), rows, lens,
+                             n_kv_heads=hkv, window=window, bq=bq, bk=bk,
+                             heads_per_step=hb, interpret=interpret))
+            try:
+                line += f"; {bq}/{bk}/{hb} {ms_a_call(fn, args, reps):.3f}"
+            except Exception as e:    # a refused block shape: record, go on
+                line += f"; {bq}/{bk}/{hb} refused ({str(e)[:80]})"
+        print("kv_prefill: " + line, flush=True)
+        detail += " | " + line
+    return detail
+
+
 def check_kda_step_inplace(cfg, interpret):
     """The delta rule's decode step over the engine's whole state array, in
     place (``ops/kda.py`` ``kda_step_inplace``), at the Gated-DeltaNet
@@ -578,6 +681,7 @@ CHECKS = {
     "flash_decode_kv_fused": (check_flash_decode_kv_fused, True),
     "latent_decode": (check_latent_decode, True),
     "mla_prefill": (check_mla_prefill, True),
+    "kv_prefill": (check_kv_prefill, True),
     "kda_step_inplace": (check_kda_step_inplace, True),
 }
 

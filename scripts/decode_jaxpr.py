@@ -4,7 +4,9 @@ for the specs of the cells at test size: mistral-tiny int4 (no sliding
 window) on the ``window`` body (``pallas-decode_interpret``) and on ``dense``
 (``xla``), ``ling-tiny`` and, since PR 38, ``olmo-hybrid-tiny`` on the kernel
 and on XLA (``hybrid``; a parent dumped before has no such files: ``compare``
-walks its first directory's).
+walks its first directory's); since PR 40 ``xing-tiny`` and ``mellum-tiny``
+(window 32) the same way. To dump a parent that lacks an entry, run THIS
+file over its package (``PYTHONPATH=<parent> python <this file> dump <dir>``).
 
     JAX_PLATFORMS=cpu python -m scripts.decode_jaxpr dump <dir>
     python -m scripts.decode_jaxpr compare <dir-a> <dir-b>
@@ -71,9 +73,11 @@ def dump(out: str) -> None:
         ling_spec,
         mistral_spec,
     )
+    from distributed_inference_engine_tpu.models.mellum import mellum_spec
     from distributed_inference_engine_tpu.models.olmo_hybrid import (
         olmo_hybrid_spec,
     )
+    from distributed_inference_engine_tpu.models.xing import xing_spec
     from distributed_inference_engine_tpu.ops.quant import (
         random_quantized_params,
     )
@@ -100,6 +104,13 @@ def dump(out: str) -> None:
                            decode_steps_per_call=8, attention_impl=impl)
         _dump_engine(out, name, ContinuousEngine(
             olmo_hybrid_spec("olmo-hybrid-tiny"), config=cfg))
+        _dump_engine(out, name.replace("olmo", "xing"), ContinuousEngine(
+            xing_spec("xing-tiny", max_seq_len=128), config=cfg))
+        cfg = EngineConfig(max_slots=4, max_seq_len=256, page_size=8,
+                           num_pages=128, prefill_buckets=[32, 64],
+                           decode_steps_per_call=8, attention_impl=impl)
+        _dump_engine(out, name.replace("olmo", "mellum"), ContinuousEngine(
+            mellum_spec("mellum-tiny", max_seq_len=256), config=cfg))
 
 
 def compare(a: str, b: str) -> bool:

@@ -44,6 +44,7 @@ from distributed_inference_engine_tpu.models.base import (  # noqa: E402
     unembed,
 )
 from distributed_inference_engine_tpu.ops import flash_decode  # noqa: E402
+from distributed_inference_engine_tpu.ops import flash_prefill  # noqa: E402
 from distributed_inference_engine_tpu.ops import moe_routed  # noqa: E402
 from perfbench.lib import families  # noqa: E402
 
@@ -446,6 +447,33 @@ def test_interpreted_kernel_body_gives_the_xla_bodys_logits(served_f32):
 
 
 # ------------------------------------------------------------- the router
+
+
+def test_prefill_through_the_interpreted_kernel_is_the_xla_path(monkeypatch,
+                                                               served_f32):
+    """``mellum-tiny``'s prefill with the flash kernel forced through the
+    interpreter (blocks of 16 in a bucket of 160, the window 32: a query
+    block's first key block is masked whole for its last rows; rows of 150,
+    77 and 40 tokens and a pad row) against the XLA band body this process
+    resolves by itself, at the family's float32 limit; and against the
+    reference."""
+    seqs = sequences()
+    with jax.default_matmul_precision("highest"):
+        spec = tiny_spec(dtype="float32")
+        _, xla = Served(spec, served_f32).prefill(seqs, 160)
+        monkeypatch.setattr(flash_prefill, "Q_BLOCK", 16)
+        monkeypatch.setattr(flash_prefill, "K_BLOCK", 16)
+        monkeypatch.setattr(flash_prefill, "prefill_impl",
+                            lambda t, dh: "flash_interpret")
+        real, windows = flash_prefill._flash_prefill, []
+        monkeypatch.setattr(
+            flash_prefill, "_flash_prefill",
+            lambda *a, **kw: windows.append(kw["window"]) or real(*a, **kw))
+        _, got = Served(spec, served_f32).prefill(seqs, 160)
+        worst, scale = max_diff(got, CFG, served_f32, seqs)
+    assert sorted(windows) == [0, WINDOW, WINDOW, WINDOW]   # one a layer
+    assert max(float(np.abs(a - b).max()) for a, b in zip(got, xla)) < F32_TOL
+    assert worst < F32_TOL and scale > 0.3, (worst, scale)
 
 
 def _route_inputs(seed, n, d, e):

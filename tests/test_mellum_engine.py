@@ -141,6 +141,48 @@ def test_counters_follow_lengths_and_steps():
         assert "state" not in m
 
 
+@pytest.fixture(scope="module")
+def counting_engine():
+    """One engine for the hand counts below: its programs compile once."""
+    return tiny_engine()
+
+
+@pytest.mark.parametrize("length,full,band,square", [
+    (20, 1 + 2, 1 + 2, 4),                   # bucket 32: both blocks live
+    (50, 1 + 2 + 3 + 4, 1 + 2 + 3 + 3, 16),  # bucket 64: block 3 sees 1-3
+    (100, 28, 1 + 2 + 5 * 3, 64)])           # bucket 128: 7 of 8 live
+def test_prefill_key_block_counters_by_hand(monkeypatch, counting_engine,
+                                            length, full, band, square):
+    """Blocks of 16 for the count and the window of 32: what the prefill
+    kernel would visit for an admitted prompt, a layer of each kind (a full
+    layer: at or under the diagonal; a sliding one: from the block holding
+    row ``q0 - 31`` on; both below the prompt's length), over the blocks of
+    its bucket's whole square; the counters add from one admission to the
+    next."""
+    from distributed_inference_engine_tpu.ops import flash_prefill
+
+    monkeypatch.setattr(flash_prefill, "Q_BLOCK", 16)
+    monkeypatch.setattr(flash_prefill, "K_BLOCK", 16)
+    names = [f"{kind}_prefill_key_blocks_{what}"
+             for kind in ("full", "window") for what in ("visited", "bucket")]
+    before = counting_engine.get_metrics()["attn"]
+    counting_engine.generate([GenerationRequest(
+        prompt=list(range(1, length + 1)), max_new_tokens=2)])
+    got = counting_engine.get_metrics()["attn"]
+    assert [got[n] - before[n] for n in names] == [full, square, band, square]
+    assert not any(k.startswith("prefill_key_blocks")
+                   for k in counting_engine.get_metrics()["mla"])
+
+
+def test_a_dense_tree_reports_no_prefill_blocks_of_either_kind():
+    from distributed_inference_engine_tpu.models.llama import llama_spec
+
+    eng = ContinuousEngine(
+        llama_spec("llama-tiny", max_seq_len=64), config=EngineConfig(
+            max_slots=2, page_size=16, num_pages=16, max_seq_len=64))
+    assert "attn" not in eng.get_metrics()
+
+
 def test_the_spans_are_in_the_programs():
     """Every scope the per-layer metrics read is on some operation of the
     lowered decode and prefill programs."""

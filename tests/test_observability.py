@@ -181,6 +181,31 @@ def test_the_latent_prefills_block_counters_reach_the_scrape():
         assert f'prefill_key_blocks_{name}_total{{model="dense"' not in text
 
 
+def test_the_kv_row_families_prefill_block_counters_reach_the_scrape():
+    reg = MetricsRegistry()
+    obs_collectors.apply_engine(
+        reg, {"attn": {"full_context_rows": 9, "full_table_rows": 9,
+                       "full_prefill_key_blocks_visited": 45,
+                       "full_prefill_key_blocks_bucket": 256,
+                       "window_prefill_key_blocks_visited": 24,
+                       "window_prefill_key_blocks_bucket": 256}},
+        model="m", worker_id="w0")
+    # a spec without sliding layers: the full layers' pair alone
+    obs_collectors.apply_engine(
+        reg, {"attn": {"full_prefill_key_blocks_visited": 3,
+                       "full_prefill_key_blocks_bucket": 4}}, model="mha")
+    obs_collectors.apply_engine(reg, {"total_requests": 3}, model="dense")
+    text = reg.render()
+    for kind, name, v in (("full", "visited", 45), ("full", "square", 256),
+                          ("window", "visited", 24),
+                          ("window", "square", 256)):
+        metric = f"engine_attn_{kind}_prefill_key_blocks_{name}"
+        assert f'{metric}_total{{model="m",worker_id="w0"}} {v}' in text
+        assert f'{metric}_total{{model="dense"' not in text
+        assert (f'{metric}_total{{model="mha"' in text) == (kind == "full")
+        assert obs_collectors.CATALOG[metric][0] == "counter"
+
+
 def test_latency_stats_histogram_snapshot():
     ls = LatencyStats()
     ls.add(0.0005)            # below first bound

@@ -35,6 +35,7 @@ from distributed_inference_engine_tpu.models import olmo_hybrid  # noqa: E402
 from distributed_inference_engine_tpu.models.base import (  # noqa: E402
     layered_family,
 )
+from distributed_inference_engine_tpu.ops import flash_prefill  # noqa: E402
 from perfbench.lib import families  # noqa: E402
 from test_ling import Served as _Served  # noqa: E402  (this directory)
 
@@ -144,6 +145,32 @@ def test_the_interpreted_kernel_reads_the_pool_as_the_xla_body(served_f32):
         worst, _ = max_diff(ker, CFG, served_f32, seqs)
     assert worst < F32_TOL, worst
     assert max(float(np.abs(a - b).max()) for a, b in zip(xla, ker)) < F32_TOL
+
+
+def test_prefill_through_the_interpreted_kernel_is_the_xla_path(monkeypatch,
+                                                               served_f32):
+    """``olmo-hybrid-tiny``'s prefill with the flash kernel forced through
+    the interpreter (blocks of 16 in a bucket of 96; MHA: several K/V heads
+    a step, no window; rows of 77, 45 and 9 tokens and a pad row) against
+    the XLA body this process resolves by itself, at the family's float32
+    limit; and against the reference."""
+    seqs = sequences()
+    with jax.default_matmul_precision("highest"):
+        spec = tiny_spec(dtype="float32")
+        _, xla = Served(spec, served_f32).prefill(seqs, 96)
+        monkeypatch.setattr(flash_prefill, "Q_BLOCK", 16)
+        monkeypatch.setattr(flash_prefill, "K_BLOCK", 16)
+        monkeypatch.setattr(flash_prefill, "prefill_impl",
+                            lambda t, dh: "flash_interpret")
+        real, windows = flash_prefill._flash_prefill, []
+        monkeypatch.setattr(
+            flash_prefill, "_flash_prefill",
+            lambda *a, **kw: windows.append(kw["window"]) or real(*a, **kw))
+        _, got = Served(spec, served_f32).prefill(seqs, 96)
+        worst, scale = max_diff(got, CFG, served_f32, seqs)
+    assert windows == [0]                 # the period's one full layer
+    assert max(float(np.abs(a - b).max()) for a, b in zip(got, xla)) < F32_TOL
+    assert worst < F32_TOL, (worst, scale)
 
 
 @pytest.mark.parametrize("lens,pages_per_block", [

@@ -117,6 +117,28 @@ def test_counters_follow_lengths_and_steps():
         assert m["mla"] == {"decode_context_rows": 0, "decode_table_rows": 0}
 
 
+@pytest.mark.parametrize("length,visited,square", [
+    (20, 1 + 2, 4),              # bucket 32: both query blocks live
+    (37, 1 + 2 + 3, 16)])        # bucket 64: three of four
+def test_prefill_key_block_counters_by_hand(monkeypatch, length, visited,
+                                            square):
+    """Blocks of 16 for the count: what the prefill kernel would visit for
+    an admitted prompt in a full-attention layer (at or under the diagonal,
+    below its length) over the blocks of its bucket's whole square; a spec
+    without sliding layers reports no window pair."""
+    from distributed_inference_engine_tpu.ops import flash_prefill
+
+    monkeypatch.setattr(flash_prefill, "Q_BLOCK", 16)
+    monkeypatch.setattr(flash_prefill, "K_BLOCK", 16)
+    engine = tiny_engine()
+    engine.generate([GenerationRequest(prompt=list(range(1, length + 1)),
+                                       max_new_tokens=2)])
+    got = engine.get_metrics()["attn"]
+    assert (got["full_prefill_key_blocks_visited"],
+            got["full_prefill_key_blocks_bucket"]) == (visited, square)
+    assert not any(k.startswith("window") for k in got)
+
+
 def test_the_spans_are_in_the_programs():
     """Every scope the per-layer metrics read is on some operation of the
     lowered decode and prefill programs."""

@@ -151,6 +151,29 @@ def test_mla_prefill_compiles_for_v5e(one_chip, b, t):
     assert f"f32[{b},32,512," not in text and f"f32[32,512,{t}]" not in text
 
 
+# the K|V-row families' prefill kernel alone: the sliding-window family's
+# 32 : 4 heads of 128 at its smallest and largest buckets, full and with its
+# window of 1,024 (16,896 = 33 blocks), the Gated-DeltaNet family's 30 MHA
+# heads (6 heads a step), 7 query heads a K/V head, and two rows at once
+@pytest.mark.parametrize("b,t,h,hkv,window", [
+    (1, 512, 32, 4, 1024), (1, 16384, 32, 4, 0), (1, 16896, 32, 4, 1024),
+    (1, 4096, 30, 30, 0), (1, 1024, 28, 4, 0), (2, 1024, 32, 4, 1024)])
+def test_kv_prefill_compiles_for_v5e(one_chip, b, t, h, hkv, window):
+    from distributed_inference_engine_tpu.ops import flash_prefill
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *a: flash_prefill.kv_prefill_attention(
+        *a, hkv, window=window, impl="flash")).lower(
+            sds((b, t, h, 128)), sds((b, t, 2 * hkv * 128)),
+            sds((b,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "kv_prefill_flash" in text
+    # no score tensor in HBM: nothing float32 of 512 query rows a head
+    assert not re.search(r"f32\[[0-9,]*\b512,[0-9]+\]", text)
+
+
 # the in-place decode kernel over ONE pool of K|V rows (``kv_fused``: each
 # half of a page's lanes copied where it lies), at the Gated-DeltaNet
 # family's served shape (30 K/V heads of 128, 48 pages a row, chunks of 16)
@@ -245,9 +268,11 @@ def test_no_program_of_the_gdn_family_holds_a_copy_of_its_kv_table(
     answer here: this process sees the CPU)."""
     from distributed_inference_engine_tpu.models import olmo_hybrid as fam
     from distributed_inference_engine_tpu.models.base import unembed
-    from distributed_inference_engine_tpu.ops import kda
+    from distributed_inference_engine_tpu.ops import flash_prefill, kda
 
     monkeypatch.setattr(kda, "step_impl", lambda: "inplace")
+    monkeypatch.setattr(flash_prefill, "prefill_impl",
+                        lambda t, dh: "flash")
 
     spec = fam.olmo_hybrid_spec("olmo-hybrid-7b-pp2", max_seq_len=6144)
     slots, n_pages, page, mp, steps = 8, 384, 128, 48, 16
@@ -302,6 +327,13 @@ def test_no_program_of_the_gdn_family_holds_a_copy_of_its_kv_table(
                       "[4,8,48,128,7680]"):
             assert shape not in text, shape
         assert compiled.memory_analysis().temp_size_in_bytes < 0.6 * pool_bytes
+    # since PR 40 the prefill's full-attention layers run the flash kernel
+    # (steered as above): no float32 ``[1, 30, 512, keys]`` scores, and the
+    # temporaries under the XLA body's 1,529,178,624 B (1,517,031,936)
+    pre = programs[1].compile()
+    assert "kv_prefill_flash" in pre.as_text()
+    assert not re.search(r"f32\[1,30,512,[0-9]+\]", pre.as_text())
+    assert pre.memory_analysis().temp_size_in_bytes < 1_529_178_624
     text = programs[0].compile().as_text()
     assert "flash_decode_custom_call" in text
     state_ops = set()
@@ -374,16 +406,24 @@ def test_no_decode_chunk_of_a_latent_row_family_gathers_its_table(one_chip):
 
 
 def test_the_sliding_window_family_reads_both_pools_in_place_and_fits(
-        one_chip):
+        one_chip, monkeypatch):
     """The sliding-window family's programs at the served size (12 layers of
     64 experts, 8 slots of 16,896 positions, 16 steps; a prefill at the
     16,384 bucket), compiled for the v5e with the Mosaic grouped matmul:
     every layer of both kinds reads its pool through the K|V kernel where it
     lies, the window pool rides the decode scan with no copy, and the
     largest prefill's temporaries fit beside the 10.9 GB tree and the 1 GB
-    of pages in a chip's 15.75 GB."""
+    of pages in a chip's 15.75 GB. Since PR 40 the prefill's attention is
+    the flash kernel in both layer kinds (``flash_prefill.prefill_impl`` is
+    steered to the chip's answer here: this process sees the CPU): no
+    float32 ``[1, 4, 8, 512, keys]`` score tensor is left in the program,
+    and its temporaries are 1.28 GB against 1.83 GB with the XLA body."""
     from distributed_inference_engine_tpu.models import mellum as fam
     from distributed_inference_engine_tpu.models.base import unembed
+    from distributed_inference_engine_tpu.ops import flash_prefill
+
+    monkeypatch.setattr(flash_prefill, "prefill_impl",
+                        lambda t, dh: "flash")
 
     spec = fam.mellum_spec("mellum2-12b-a2.5b-pp1", max_seq_len=16896)
     slots, page, mp, steps = 8, 128, 132, 16
@@ -453,3 +493,11 @@ def test_the_sliding_window_family_reads_both_pools_in_place_and_fits(
         arr(1)).compile()
     temp = pre.memory_analysis().temp_size_in_bytes
     assert held + temp < 15.2e9, (held, temp)
+    text = pre.as_text()
+    # under its layer kind's scope in a sub-scope of its own, never under
+    # ``flash_decode`` (the benchmark counts decode steps by that name)
+    for kind in ("swa", "full"):
+        assert f"attn.{kind}/flash_prefill/" in text
+    assert "kv_prefill_flash" in text and "flash_decode" not in text
+    assert not re.search(r"f32\[1,4,8,512,[0-9]+\]", text)
+    assert temp < 1.5e9, temp          # the parent's: 1,831,853,568
