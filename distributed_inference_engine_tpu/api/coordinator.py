@@ -2329,10 +2329,16 @@ class Coordinator:
 
     def _pool_gauges(self) -> Dict[str, int]:
         """Connection-pool gauges over the router's and the load balancer's
-        worker clients."""
+        worker clients: calls holding a connection, calls waiting for one,
+        and the connections the pools may hold (each follows its worker's
+        slots)."""
         pools = (self.router.pool_stats(), self.lb.pool_stats())
+        # a worker's streams ride ONE of its two clients (the router's
+        # where it is registered there): its pool counts once
+        sizes = {**pools[1]["size_by_worker"], **pools[0]["size_by_worker"]}
         return {"pool_in_use": sum(p["in_use"] for p in pools),
-                "pool_waiting": sum(p["waiting"] for p in pools)}
+                "pool_waiting": sum(p["waiting"] for p in pools),
+                "pool_size": sum(sizes.values())}
 
     def _worker_roles(self) -> Dict[str, str]:
         """Fleet role per registered worker for the scrape: pool membership

@@ -169,12 +169,16 @@ class LoadBalancer:
             await client.close()
         self._clients.clear()
 
-    def pool_stats(self) -> Dict[str, int]:
+    def pool_stats(self) -> Dict[str, Any]:
         """Connection-pool gauges summed over this load balancer's worker clients:
-        calls holding a connection, callers waiting for one."""
-        pools = [c.pool_stats() for c in self._clients.values()]
-        return {"in_use": sum(p["in_use"] for p in pools),
-                "waiting": sum(p["waiting"] for p in pools)}
+        calls holding a connection, callers waiting for one, and each
+        worker's pool size."""
+        pools = {w: c.pool_stats() for w, c in self._clients.items()}
+        return {"in_use": sum(p["in_use"] for p in pools.values()),
+                "waiting": sum(p["waiting"] for p in pools.values()),
+                # each pool's bound (it follows the worker's slots:
+                # ``WorkerClient._follow_slots``)
+                "size_by_worker": {w: p["size"] for w, p in pools.items()}}
 
     # -- membership (reference src/load_balancer.py:97-126) -------------------
 

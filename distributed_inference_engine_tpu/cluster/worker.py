@@ -44,6 +44,7 @@ from ..utils.rpc import (
     FramedRPCClient,
     FramedServerMixin,
     RPCError,
+    pool_for_slots,
     relay_stream,
 )
 from ..obs import collectors as obs_collectors
@@ -286,7 +287,18 @@ def _engine_placement(engine) -> Dict[str, Any]:
             "decode_attention": getattr(engine, "attn_impl", None),
             # the body that moves a recurrent family's per-slot state in a
             # decode step ("inplace": the kernel; None: no such state)
-            "state_step_body": getattr(engine, "state_step_body", None)}
+            "state_step_body": getattr(engine, "state_step_body", None),
+            # requests the engine runs at once (the coordinator's pool to
+            # this worker follows it) and the residual a per-layer spec has
+            # around its sublayers ("mhc": hyper-connection streams)
+            "slots": _engine_slots(engine),
+            "residual": getattr(getattr(engine, "spec", None), "residual",
+                                None)}
+
+
+def _engine_slots(engine) -> Optional[int]:
+    slots = getattr(engine, "max_slots", None)
+    return int(slots) if slots else None
 
 
 # --------------------------------------------------------------------------
@@ -725,6 +737,7 @@ class WorkerServer(FramedServerMixin):
                 "models": sorted(self.engines),
                 "staged": self.model_manager.staged_names(),
                 "draining": self._draining,
+                "slots": self.slots_report(),
                 "device": self.device_report()}
 
     def _admit(self) -> None:
@@ -1305,7 +1318,9 @@ class WorkerServer(FramedServerMixin):
         return {"loaded": cfg.name,
                 # measured engine-construction wall time (idempotent
                 # re-loads report the original) — demo/supervisor receipts
-                "load_s": self._last_load_s.get(cfg.name, 0.0)}
+                "load_s": self._last_load_s.get(cfg.name, 0.0),
+                # what the caller's pool to this worker follows
+                "slots": self.slots_report()}
 
     async def _rpc_stage_model(self, msg: Dict[str, Any]) -> Dict[str, Any]:
         """Begin background staging; returns immediately (the build runs on
@@ -1454,6 +1469,12 @@ class WorkerServer(FramedServerMixin):
 
     # -- metrics (reference src/worker.py:186-209) ----------------------------
 
+    def slots_report(self) -> Optional[int]:
+        """Requests this worker's engines run at once, summed over the
+        resident models; ``None`` where no engine says (the fakes)."""
+        slots = [_engine_slots(e) for e in self.engines.values()]
+        return sum(s for s in slots if s) or None
+
     def device_report(self, memory: bool = False) -> Optional[Dict[str, Any]]:
         """Where this worker's engines run, as JAX reports it: platform,
         device kind, visible device count, and per resident model the ids
@@ -1571,10 +1592,26 @@ class WorkerClient(FramedRPCClient):
     transparently re-established after a drop (``utils/rpc.py``).
     """
 
+    # the worker's last report of its engines' slots (``_follow_slots``)
+    _slots_seen: Optional[int] = None
+
     # convenience wrappers -----------------------------------------------
 
     async def ping(self, timeout: Optional[float] = None) -> Dict[str, Any]:
-        return await self.call("ping", timeout=timeout)
+        return self._follow_slots(await self.call("ping", timeout=timeout))
+
+    def _follow_slots(self, reply: Any) -> Any:
+        """Size this client's pool from what the worker just reported (a
+        ``ping`` or a ``load_model`` receipt): a stream holds a connection
+        for its life, so the pool follows the engines' slots. A reply with
+        no ``slots`` (an older worker, a fake engine) leaves the pool. The
+        first report sizes the pool whatever ``max_connections`` the client
+        was built with; after it only a changed report does."""
+        slots = reply.get("slots") if isinstance(reply, dict) else None
+        if slots and slots != self._slots_seen:
+            self._slots_seen = slots
+            self.resize_pool(pool_for_slots(slots))
+        return reply
 
     async def events(self, timeout: Optional[float] = None) -> Dict[str, Any]:
         """Flight-recorder collection: event ring + step timelines."""
@@ -1667,9 +1704,9 @@ class WorkerClient(FramedRPCClient):
         """Load ``cfg`` on the worker; returns the measured-load receipt
         ({loaded, load_s}) — the cold-start half of the staged-swap
         latency comparison."""
-        return await self.call("load_model", config=cfg.to_dict(),
-                               timeout=timeout if timeout is not None
-                               else 300.0)
+        return self._follow_slots(await self.call(
+            "load_model", config=cfg.to_dict(),
+            timeout=timeout if timeout is not None else 300.0))
 
     async def unload_model(self, name: str) -> bool:
         result = await self.call("unload_model", model=name)

@@ -2923,6 +2923,11 @@ class ContinuousEngine:
             "total_generated_tokens": self._total_generated,
             "waiting": self.n_waiting,
             "live_slots": len(self._slots),
+            # requests this engine runs at once (what a coordinator's pool
+            # to the worker follows) and, of a per-layer spec, the residual
+            # around its sublayers ("mhc": hyper-connection streams)
+            "slots": self.max_slots,
+            "residual": self.spec.residual,
             "admission_denied": self._admission_denied,
             "rejected_queue_full": self._rejected_full,
             "shed_deadline": self._shed_deadline,

@@ -16,7 +16,7 @@ from .qwen import qwen_spec  # noqa: F401
 from .mistral import mistral_spec  # noqa: F401
 from .gemma import gemma_spec  # noqa: F401
 from .ling import ling_spec  # noqa: F401
-from .xing import xing_spec  # noqa: F401
+from .xing import kimi_spec, xing_spec  # noqa: F401
 from .olmo_hybrid import olmo_hybrid_spec  # noqa: F401
 from .mellum import mellum_spec  # noqa: F401
 from .fake import FakeContinuousEngine, FakeEngine, FakePrefillEngine  # noqa: F401
@@ -33,6 +33,7 @@ _FAMILIES = {
     "llama": (llama_spec, "llama3-8b"),
     "ling": (ling_spec, "ling-3.0-flash-ep4"),
     "xing": (xing_spec, "xing4.0-pp1"),
+    "kimi": (kimi_spec, "kimi-k2.5-ep32-pp1"),
     "olmo_hybrid": (olmo_hybrid_spec, "olmo-hybrid-7b-pp2"),
     "mellum": (mellum_spec, "mellum2-12b-a2.5b-pp1"),
 }

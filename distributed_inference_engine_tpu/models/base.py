@@ -235,6 +235,13 @@ class ModelSpec:
     def jnp_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def residual(self) -> str:
+        """What stands around the sublayers: ``"mhc"`` (``hc_mult``
+        hyper-connection streams) or ``"plain"``; the engine's metrics and
+        the worker's device report name it."""
+        return "mhc" if self.hc_mult else "plain"
+
     def validate(self) -> "ModelSpec":
         if not self.head_dim_override and self.d_model % self.n_heads:
             raise ValueError("d_model must divide by n_heads")
@@ -301,12 +308,16 @@ class ModelSpec:
                                  "topk_group lie in [1, n_group]")
             if self.moe_scoring not in ("sigmoid", "softmax"):
                 raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
-        if self.hc_mult and (set(self.layer_kinds) != {"mla"}
-                             or self.q_lora_rank < 1
+        if self.q_lora_rank and set(self.layer_kinds) != {"mla"}:
+            raise ValueError(
+                "a compressed query (q_lora_rank) belongs to a per-layer "
+                "spec whose layers are all MLA (models/xing.py)")
+        if self.hc_mult and (self.q_lora_rank < 1
                              or self.hc_sinkhorn_iters < 1):
             raise ValueError(
-                "hc_mult residual streams (mHC) belong to a per-layer spec "
-                "of MLA layers with a compressed query (q_lora_rank) and "
+                "hc_mult says which RESIDUAL a compressed-query MLA spec "
+                "(q_lora_rank >= 1, models/xing.py) has: 0 the plain "
+                "pre-norm one, n >= 1 that many mHC streams, which need "
                 "hc_sinkhorn_iters >= 1")
         if self.n_experts:
             if not 1 <= self.experts_per_token <= self.n_experts:
@@ -341,16 +352,18 @@ def layered_family(spec: ModelSpec):
     ``init_params`` / ``init_state`` / ``zero_state_slot`` and the programs'
     bodies (``forward_prefill_into_pages``, ``forward_decode_step``,
     ``decode_context``, ``write_rows_into_pages``). ``engine/`` reaches
-    them here and names no model file. Four families, told apart by what
-    the spec holds: "swa" layers (``models/mellum.py``: sliding-window
-    layers beside full-attention layers, K|V rows in two pools of unlike
-    lifetimes, routed experts everywhere), ``gdn_key_head_dim``
-    (``models/olmo_hybrid.py``: Gated
-    DeltaNet layers beside full-attention layers over K|V rows, a dense MLP
-    everywhere), ``hc_mult`` residual streams (``models/xing.py``: mHC
-    around every sublayer, MLA in every layer, no recurrent state) or
-    neither (KDA + MLA layers, ``models/ling.py``); imported late because
-    they import this module."""
+    them here and names no model file. Five families in four modules, told
+    apart by what the spec holds: "swa" layers (``models/mellum.py``:
+    sliding-window layers beside full-attention layers, K|V rows in two
+    pools of unlike lifetimes, routed experts everywhere),
+    ``gdn_key_head_dim`` (``models/olmo_hybrid.py``: Gated DeltaNet layers
+    beside full-attention layers over K|V rows, a dense MLP everywhere),
+    ``q_lora_rank`` (``models/xing.py``: MLA with a compressed query in
+    every layer, no recurrent state; its two families differ in the
+    residual alone, ``hc_mult`` mHC streams around every sublayer or, with
+    ``hc_mult`` 0, the plain pre-norm one) or none of these (KDA + MLA
+    layers, ``models/ling.py``); imported late because they import this
+    module."""
     if not spec.layer_kinds:
         raise ValueError("a uniform spec has no per-layer family: its "
                          "forward_* live in models/base.py")
@@ -362,7 +375,7 @@ def layered_family(spec: ModelSpec):
         from . import olmo_hybrid
 
         return olmo_hybrid
-    if spec.hc_mult:
+    if spec.q_lora_rank:
         from . import xing
 
         return xing

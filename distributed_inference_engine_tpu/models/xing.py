@@ -1,24 +1,34 @@
-"""Xing4.0-29B-A4B (``model_type`` ``xing4_0``): every layer MLA with a
-compressed query and YaRN-scaled rotary frequencies, a dense SwiGLU MLP in
-the leading layers and sigmoid-routed experts (plus one shared expert) in
-the rest, and a residual of ``hc_mult`` = 4 streams read, written and mixed
-around EVERY sublayer by manifold-constrained hyper-connections (mHC,
-``ops/mhc.py``).
+"""The compressed-query latent-attention families: every layer MLA with a
+compressed query (``q_lora_rank``) and YaRN-scaled rotary frequencies, a
+dense SwiGLU MLP in the leading layers and sigmoid-routed experts (plus one
+shared expert) in the rest. Two models, one seam between them, the residual
+(``_sublayer`` / ``_streams`` / ``_collapse``):
+
+- Xing4.0-29B-A4B (``model_type`` ``xing4_0``, ``xing_spec``): a residual of
+  ``hc_mult`` = 4 streams read, written and mixed around EVERY sublayer by
+  manifold-constrained hyper-connections (mHC, ``ops/mhc.py``);
+- Kimi-K2.5 (``model_type`` ``kimi_k2``, ``kimi_spec``): ``hc_mult`` 0, the
+  plain pre-norm residual ``x + F(RMSNorm(x))`` in the activation dtype, 64
+  heads, and a chip that holds a FRACTION of the one routing group
+  (``experts_held`` (0, 12) of 384), whose expert layer runs over its held
+  assignments only (``ops/moe_routed.py`` ``moe_block_held``).
 
 Published layer ``l`` (0-based) has a dense MLP if ``l <
 first_k_dense_replace``, else experts. The equations are written out in
 ``perfbench/reference/xing4_mhc.py`` (the plain float32 reference) and in
 ``ops/mhc.py``, ``ops/mla.py``, ``ops/moe_routed.py``.
 
-**The residual** is ``X [B, T, n, D]`` float32 inside this file and nowhere
-else: ``X_0[i]`` is the token's embedding for every stream, and after the
-last kept layer the streams ADD to ``hidden [B, T, D]`` in the activation
-dtype, so the final norm, the head, sampling and the packed output are the
-shared ones (``models/base.py`` ``unembed``).
+**The residual** of an mHC spec is ``X [B, T, n, D]`` float32 inside this
+file and nowhere else (a plain one is ``[B, T, D]`` in the activation dtype
+and has no ``resid.mhc`` scope): ``X_0[i]`` is the token's embedding for
+every stream, and after the last kept layer the streams ADD to ``hidden
+[B, T, D]`` in the activation dtype, so the final norm, the head, sampling
+and the packed output are the shared ones (``models/base.py`` ``unembed``).
 
 **The tree** is a list of per-layer dicts, as ``models/ling.py``'s: a
 Python loop over ``spec.layer_plan`` and XLA compiles each kept layer.
-``hc_attn`` / ``hc_mlp`` hold a sublayer's three mHC tensors (float32).
+``hc_attn`` / ``hc_mlp`` hold a sublayer's three mHC tensors (float32; a
+plain-residual tree has neither).
 
 **Cache**: one latent row a token for every layer (``c`` | ``k_rope`` | zero
 lanes up to whole 128-lane tiles: ``ling.latent_row``, ``engine/paged_kv.py``'s
@@ -44,7 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import mhc, mla
-from ..ops.moe_routed import moe_block
+from ..ops.moe_routed import held_fraction_of_one_group, moe_body
 from ..ops.norms import rms_norm
 from .base import ModelSpec, embed
 from .ling import (  # the latent pool's views and reader are the same code
@@ -57,9 +67,10 @@ from .ling import (  # the latent pool's views and reader are the same code
     write_rows_into_pages,
 )
 
-__all__ = ["xing_spec", "init_params", "init_state", "zero_state_slot",
-           "decode_context", "write_rows_into_pages", "DECODE_COUNTERS",
-           "forward_prefill_into_pages", "forward_decode_step"]
+__all__ = ["xing_spec", "kimi_spec", "init_params", "init_state",
+           "zero_state_slot", "decode_context", "write_rows_into_pages",
+           "DECODE_COUNTERS", "forward_prefill_into_pages",
+           "forward_decode_step"]
 
 Params = Dict[str, Any]
 State = Dict[str, jnp.ndarray]
@@ -89,6 +100,18 @@ _PUBLISHED = dict(
 # eighth it moves it by ~2 %, the size of the rounding itself (one v5e
 # chip, PR 31: ``perfbench/reference/xing4_mhc.py``).
 ROUTED_DOWN_SCALE = 0.125
+# A chip that holds a FRACTION of one routing group (12 of 384: a token sends
+# it 0.25 assignments a layer at a gate of ~0.35) draws them at 16 x the
+# shared expert's instead, so that its share weighs in the layer's output
+# (0.25^1/2 x 0.35 x 16 = 2.8 shared experts) and a wrong gate or a wrong
+# slice moves a served chain of 72 tokens: at an eighth and at 1 it moved it
+# less than the rounding, at 4 and 8 not every chain (one v5e chip, PR 41,
+# calls 8 and 9). Only 1 top-8 swap in 16 touches a held expert; where one
+# does, that token lands as far from the float32 argmax as a wrong model's
+# (up to 1.1 of max|logit|), so this family's chains are judged by their
+# share of exact argmaxes and not by the gap
+# (``perfbench/reference/mla_moe_share.py``).
+ROUTED_DOWN_SCALE_SHARE = 16.0
 
 _SIZES: Dict[str, Dict[str, Any]] = {
     # every layer, for the record and for a pipeline that can hold it
@@ -110,18 +133,60 @@ _SIZES: Dict[str, Dict[str, Any]] = {
 }
 
 
+# published values (config.json of moonshotai/Kimi-K2.5, the language model;
+# no hyper-connections: ``hc_mult`` 0 is the plain residual)
+_KIMI_PUBLISHED = dict(
+    vocab_size=163840, d_model=7168, n_heads=64, d_ff=18432,
+    n_layers_published=61, first_k_dense_replace=1,
+    n_experts=384, experts_per_token=8, moe_d_ff=2048, shared_d_ff=2048,
+    n_group=1, topk_group=1, routed_scaling_factor=2.827,
+    q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, rope_theta=50000.0,
+    rope_scaling=_YARN, norm_eps=1e-5, max_seq_len=262144,
+)
+
+_KIMI_SIZES: Dict[str, Dict[str, Any]] = {
+    # one of 32 chips that share each layer (attention data-parallel, the
+    # routed experts expert-parallel 12 a chip, the vocabulary in 8
+    # slices): experts 0-11 of the ONE routing group, vocabulary rows
+    # 0-20,479, stage 1 of a pipeline = published layers 0-6
+    "kimi-k2.5-ep32-pp1": dict(
+        kept_layers=tuple(range(7)), experts_held=(0, 12),
+        vocab_size=20480),
+    # test scale: a quarter of one group held, YaRN factor 4 over 32
+    "kimi-tiny": dict(
+        vocab_size=256, d_model=64, n_heads=4, d_ff=128,
+        n_layers_published=4, first_k_dense_replace=1,
+        kept_layers=(0, 1, 2, 3), n_experts=16, experts_per_token=4,
+        experts_held=(0, 4), moe_d_ff=32, shared_d_ff=32, q_lora_rank=24,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16,
+        rope_scaling=dict(_YARN, factor=4,
+                          original_max_position_embeddings=32),
+        max_seq_len=512),
+}
+
+
 def xing_spec(size: str = "xing4.0-pp1", **overrides) -> ModelSpec:
-    if size not in _SIZES:
+    return _spec("xing", _PUBLISHED, _SIZES, size, overrides)
+
+
+def kimi_spec(size: str = "kimi-k2.5-ep32-pp1", **overrides) -> ModelSpec:
+    return _spec("kimi", _KIMI_PUBLISHED, _KIMI_SIZES, size, overrides)
+
+
+def _spec(family: str, published, sizes, size: str, overrides) -> ModelSpec:
+    if size not in sizes:
         raise ValueError(
-            f"unknown xing size {size!r}; choose from {sorted(_SIZES)}")
-    c = dict(_PUBLISHED, **_SIZES[size])
+            f"unknown {family} size {size!r}; choose from {sorted(sizes)}")
+    c = dict(published, **sizes[size])
     kept = tuple(c.pop("kept_layers"))
     dense = c.pop("first_k_dense_replace")
     c.pop("n_layers_published")
     base = dict(
         c, n_layers=len(kept), n_kv_heads=c["n_heads"],
         head_dim_override=c["qk_nope_head_dim"] + c["qk_rope_head_dim"],
-        experts_held=(0, c["n_experts"]),
+        experts_held=c.get("experts_held", (0, c["n_experts"])),
         layer_kinds=("mla",) * len(kept),
         layer_mlps=tuple("dense" if i < dense else "moe" for i in kept),
         layer_ids=kept, pos_emb="rope", norm="rmsnorm", mlp="swiglu",
@@ -155,8 +220,10 @@ def _layer_shapes(spec: ModelSpec, mlp: str
         held = spec.experts_held[1]
         s.update(w_router=((D, spec.n_experts), "float32", std),
                  w_gate_up=((held, D, 2 * spec.moe_d_ff), dt, std),
-                 w_down=((held, spec.moe_d_ff, D), dt,
-                         out_std * ROUTED_DOWN_SCALE),
+                 w_down=((held, spec.moe_d_ff, D), dt, out_std * (
+                     ROUTED_DOWN_SCALE_SHARE
+                     if held_fraction_of_one_group(spec)
+                     else ROUTED_DOWN_SCALE)),
                  ws_gate_up=((D, 2 * spec.shared_d_ff), dt, std),
                  ws_down=((spec.shared_d_ff, D), dt, out_std))
     return s
@@ -178,8 +245,9 @@ def _init_layer(spec: ModelSpec, mlp: str, layer_id: int, key) -> Params:
                         ("q_norm", spec.q_lora_rank),
                         ("kv_norm", spec.kv_lora_rank)):
         out[name] = jnp.ones((width,), dt)
-    out["hc_attn"] = mhc.init_hc(spec, keys[-1])
-    out["hc_mlp"] = mhc.init_hc(spec, keys[-2])
+    if spec.hc_mult:
+        out["hc_attn"] = mhc.init_hc(spec, keys[-1])
+        out["hc_mlp"] = mhc.init_hc(spec, keys[-2])
     if mlp == "moe":
         out["router_bias"] = 0.01 * jax.random.normal(
             jax.random.fold_in(jax.random.key(0), layer_id),
@@ -188,8 +256,8 @@ def _init_layer(spec: ModelSpec, mlp: str, layer_id: int, key) -> Params:
 
 
 def init_params(spec: ModelSpec, key: jax.Array) -> Params:
-    """Random tree in ``spec.dtype``; float32 router, expert bias and mHC
-    tensors. The worker hands ``metadata.seed`` as the key."""
+    """Random tree in ``spec.dtype``; float32 router, expert bias and (of
+    an mHC spec) mHC tensors. The worker hands ``metadata.seed`` as the key."""
     spec.validate()
     plan = spec.layer_plan
     keys = jax.random.split(key, len(plan) + 2)
@@ -274,10 +342,15 @@ def mla_layer_step(spec: ModelSpec, blk: Params, h, positions, ctx, layer,
         return _mla_out(blk, o, h.dtype), (side, rows_read)
 
 
-def _sublayer(spec: ModelSpec, hc: Params, scale, x, fn):
-    """One mHC-wrapped sublayer over x [N, n, D] float32: ``fn`` takes the
-    normalised read-out [N, D] in the activation dtype and returns (y
-    [N, D], whatever else it made)."""
+def _sublayer(spec: ModelSpec, hc, scale, x, fn):
+    """One sublayer around the residual: ``fn`` takes the normalised
+    read-out [N, D] in the activation dtype and returns (y [N, D], whatever
+    else it made). mHC-wrapped over x [N, n, D] float32 (``hc`` the
+    sublayer's three tensors), or with ``hc_mult`` 0 the plain pre-norm
+    residual ``x + fn(RMSNorm(x))`` over x [N, D] in the activation dtype."""
+    if not spec.hc_mult:
+        y, extra = fn(rms_norm(x, scale, spec.norm_eps))
+        return x + y, extra
     with jax.named_scope("resid.mhc"):
         pre, post, res = mhc.hc_maps(spec, hc, x)
         h = rms_norm(mhc.hc_read(x, pre), scale,
@@ -288,13 +361,18 @@ def _sublayer(spec: ModelSpec, hc: Params, scale, x, fn):
 
 
 def _streams(spec: ModelSpec, emb: jnp.ndarray) -> jnp.ndarray:
-    """emb [N, D] -> X_0 [N, n, D] float32, every stream the embedding."""
+    """emb [N, D] -> X_0 [N, n, D] float32, every stream the embedding (a
+    plain residual: the embedding itself)."""
+    if not spec.hc_mult:
+        return emb
     with jax.named_scope("resid.mhc"):
         return jnp.broadcast_to(emb.astype(jnp.float32)[:, None],
                                 (emb.shape[0], spec.hc_mult, emb.shape[1]))
 
 
 def _collapse(spec: ModelSpec, x: jnp.ndarray) -> jnp.ndarray:
+    if not spec.hc_mult:
+        return x
     with jax.named_scope("resid.mhc"):
         return jnp.sum(x, axis=1).astype(spec.jnp_dtype)
 
@@ -328,10 +406,10 @@ def forward_prefill_into_pages(
                                        positions, seq_lens)
             return att.reshape(b * t, -1), r
 
-        x, r = _sublayer(spec, blk["hc_attn"], blk["ln1_scale"], x, attn)
+        x, r = _sublayer(spec, blk.get("hc_attn"), blk["ln1_scale"], x, attn)
         rows.append(r)
         x, c = _sublayer(
-            spec, blk["hc_mlp"], blk["ln2_scale"], x,
+            spec, blk.get("hc_mlp"), blk["ln2_scale"], x,
             lambda h, blk=blk, mlp=mlp: _mlp(spec, blk, mlp, h, valid,
                                              moe_impl))
         counters = counters + c
@@ -362,14 +440,14 @@ def forward_decode_step(
     for i, (blk, (_kind, mlp, _id)) in enumerate(
             zip(params["layers"], spec.layer_plan)):
         x, (s, read) = _sublayer(
-            spec, blk["hc_attn"], blk["ln1_scale"], x,
+            spec, blk.get("hc_attn"), blk["ln1_scale"], x,
             lambda h, blk=blk, i=i: mla_layer_step(
                 spec, blk, h, lengths, ctx, i, start_lengths, side[i],
                 side_idx, active))
         side = side.at[i].set(s)
         rows_read = rows_read + read
         x, c = _sublayer(
-            spec, blk["hc_mlp"], blk["ln2_scale"], x,
+            spec, blk.get("hc_mlp"), blk["ln2_scale"], x,
             lambda h, blk=blk, mlp=mlp: _mlp(spec, blk, mlp, h, active,
                                              moe_impl))
         counters = counters + c
@@ -381,7 +459,7 @@ def _mlp(spec: ModelSpec, blk: Params, kind: str, h, valid, moe_impl):
     """The layer's MLP over the normalised read-out h [N, D] -> (out,
     counters int32 [3])."""
     if kind == "moe":
-        return moe_block(spec, blk, h, valid, moe_impl)
+        return moe_body(spec)(spec, blk, h, valid, moe_impl)
     gate, up = jnp.split(_proj(h, blk["w_gate_up"], jnp.float32), 2, axis=-1)
     out = _proj((jax.nn.silu(gate) * up).astype(h.dtype), blk["w_down"])
     return out, jnp.zeros((3,), jnp.int32)

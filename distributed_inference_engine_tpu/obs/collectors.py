@@ -333,6 +333,8 @@ COORDINATOR_TABLE = [              # Coordinator.get_stats() top level
      "Stream dispatches waiting for a pooled worker connection"),
     ("pool_in_use", "coordinator_pool_in_use", "g",
      "Worker connections held by calls in flight"),
+    ("pool_size", "coordinator_pool_size", "g",
+     "Worker connections the pools may hold (follows the workers' slots)"),
     ("pool_wait", "coordinator_pool_wait_seconds", "h",
      "Wait of a stream dispatch for a pooled worker connection"),
     ("deadline_expired", "coordinator_deadline_expired", "c",

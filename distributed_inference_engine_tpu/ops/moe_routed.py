@@ -18,6 +18,16 @@ dropped. The product is GROUPED: the (token, choice) pairs that landed on a
 held expert are sorted by expert and each expert multiplies only its own
 rows (``grouped_matmul``), so operations follow the routed tokens and, in
 decode, the weight bytes read follow the experts that got one.
+
+Two bodies, chosen from the spec (``moe_body``). ``moe_block`` gathers and
+multiplies rows for ALL ``N x k`` assignments and masks the ones not held:
+harmless where the chip holds a quarter of the experts or all of them.
+``moe_block_held`` is for a chip that holds a fraction of ONE routing group
+(``held_fraction_of_one_group``: 12 of 384, so 3 % of the assignments are
+held and most tokens send none): the sort puts the held assignments first,
+and the gather, the two grouped products and the sum back to tokens run over
+those alone, in row blocks of a fixed size under a loop whose trip count
+follows the held count. Exact, nothing dropped, shapes static.
 """
 
 from __future__ import annotations
@@ -117,14 +127,8 @@ def moe_block(spec, blk: Dict[str, jnp.ndarray], x: jnp.ndarray,
     impl = impl or default_impl()
     n, d = x.shape
     k = spec.experts_per_token
-    first, held = spec.experts_held
     with jax.named_scope("moe.route"):
-        idx, gates = route(spec, x, blk["w_router"], blk.get("router_bias"))
-        local = idx - first
-        on = (local >= 0) & (local < held) & valid[:, None]
-        key = jnp.where(on, local, held).reshape(-1)           # [N*k]
-        order = jnp.argsort(key, stable=True)
-        sizes = jnp.bincount(key, length=held + 1)[:held]
+        gates, on, order, sizes = _sort_by_held_expert(spec, blk, x, valid)
         m = n * k
         tm = 128 if m >= 128 else -(-m // 16) * 16
         pad = -m % tm
@@ -146,11 +150,111 @@ def moe_block(spec, blk: Dict[str, jnp.ndarray], x: jnp.ndarray,
         # back to (token, choice) order, then the k choices of a token add
         inv = jnp.argsort(order)
         routed = y[inv].reshape(n, k, d).sum(axis=1)
+    return _with_shared(spec, blk, x, routed, on, valid, sizes)
+
+
+def _sort_by_held_expert(spec, blk, x, valid):
+    """Route, then sort the (token, choice) pairs by held expert, the ones
+    not held (or of rows not ``valid``) last: (gates [N, k], ``on`` [N, k]
+    bool: the pair landed on a held expert, ``order`` [N*k]: the sort,
+    ``sizes`` [held]: pairs an expert)."""
+    first, held = spec.experts_held
+    idx, gates = route(spec, x, blk["w_router"], blk.get("router_bias"))
+    local = idx - first
+    on = (local >= 0) & (local < held) & valid[:, None]
+    key = jnp.where(on, local, held).reshape(-1)               # [N*k]
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.bincount(key, length=held + 1)[:held]
+    return gates, on, order, sizes
+
+
+def _with_shared(spec, blk, x, routed, on, valid, sizes):
+    """(routed + the shared expert in x's dtype, the three counters)."""
     shared = None
     if spec.shared_d_ff:
         with jax.named_scope("moe.shared"):
             shared = _swiglu(x, blk["ws_gate_up"], blk["ws_down"])
     counters = jnp.stack([
-        jnp.sum(on), jnp.sum(valid) * k, jnp.sum(sizes > 0)]).astype(jnp.int32)
+        jnp.sum(on), jnp.sum(valid) * spec.experts_per_token,
+        jnp.sum(sizes > 0)]).astype(jnp.int32)
     out = routed if shared is None else routed + shared
     return out.astype(x.dtype), counters
+
+
+def held_fraction_of_one_group(spec) -> bool:
+    """The chip holds some, not all, experts of a spec whose router has ONE
+    group: nothing confines a token's choices to what is held here."""
+    return spec.n_group == 1 and 0 < spec.experts_held[1] < spec.n_experts
+
+
+def moe_body(spec):
+    """The expert layer's body, from the spec: over the held assignments
+    only where the chip holds a fraction of ONE routing group (most tokens
+    then send it nothing), ``moe_block`` (all N x k assignments, the ones not
+    held masked) where it holds whole groups or everything. Whether the
+    second still wins anywhere is not measured (ROADMAP S16 (d))."""
+    return moe_block_held if held_fraction_of_one_group(spec) else moe_block
+
+
+HELD_BLOCK_MAX = 2048
+
+
+def held_block_rows(spec, n: int) -> int:
+    """Rows of one block of ``moe_block_held`` for ``n`` tokens: twice what
+    uniform routing sends the held experts, in whole 128-row tiles (the
+    grouped product's row tile) up to ``HELD_BLOCK_MAX`` (a block re-reads
+    every touched expert: large blocks keep a long prefill's products
+    compute-bound), never past all ``n x k`` assignments. A decode step of
+    32 rows holds ~8 assignments in its one block of 128; a skewed batch
+    takes more trips, not more room."""
+    m = n * spec.experts_per_token
+    twice = 2 * m * spec.experts_held[1] // spec.n_experts
+    r = min(max(-(-twice // 128) * 128, 128), HELD_BLOCK_MAX)
+    return min(r, -(-m // 16) * 16)
+
+
+def moe_block_held(spec, blk: Dict[str, jnp.ndarray], x: jnp.ndarray,
+                   valid: jnp.ndarray, impl: str = ""
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``moe_block``'s result and counters at the cost of the HELD
+    assignments: x [N, D] -> (out [N, D], counters int32 [3]). Sorted by
+    held expert the held assignments come first; block j takes sorted rows
+    ``[j R, (j + 1) R)``, its group sizes the part of each expert's run
+    inside it, and adds its gate-weighted rows to their tokens in float32.
+    The loop ends with the held count, so a batch in which no token is held
+    multiplies nothing and one in which every token is runs ``N k / R``
+    blocks. Temporaries are ``R`` rows long whatever ``N`` is."""
+    impl = impl or default_impl()
+    n, d = x.shape
+    k = spec.experts_per_token
+    r = held_block_rows(spec, n)
+    with jax.named_scope("moe.route"):
+        gates, on, order, sizes = _sort_by_held_expert(spec, blk, x, valid)
+        n_held = jnp.sum(sizes)
+        ends = jnp.cumsum(sizes)
+        starts = ends - sizes
+        pad = -(n * k) % r
+        tok = jnp.pad(order // k, (0, pad))
+        g_sorted = jnp.pad(gates.reshape(-1)[order], (0, pad))
+
+    def block(carry):
+        j, acc = carry
+        lo = j * r
+        t = lax.dynamic_slice(tok, (lo,), (r,))
+        g = lax.dynamic_slice(g_sorted, (lo,), (r,))
+        row_ok = lo + jnp.arange(r) < n_held
+        groups = jnp.clip(jnp.minimum(ends, lo + r) - jnp.maximum(starts, lo),
+                          0, r)
+        gu = grouped_matmul(x[t], blk["w_gate_up"], groups, impl)
+        gate, up = jnp.split(gu, 2, axis=-1)
+        h = jnp.where(row_ok[:, None], jax.nn.silu(gate) * up, 0.0)
+        y = grouped_matmul(h.astype(x.dtype), blk["w_down"], groups, impl)
+        y = jnp.where(row_ok[:, None], y * g[:, None], 0.0)
+        # the held choices of a token add; rows past the held count add 0
+        return j + 1, acc.at[t].add(y)
+
+    with jax.named_scope("moe.experts"):
+        _, routed = lax.while_loop(
+            lambda c: c[0] * r < n_held, block,
+            (jnp.int32(0), jnp.zeros((n, d), jnp.float32)))
+    return _with_shared(spec, blk, x, routed, on, valid, sizes)

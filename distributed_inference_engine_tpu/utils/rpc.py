@@ -50,6 +50,19 @@ class RPCError(RuntimeError):
         self.detail = detail
 
 
+# connections a client keeps to a peer that has not said how many requests
+# it runs at once (a worker says: ``pool_for_slots``)
+DEFAULT_POOL = 8
+
+
+def pool_for_slots(slots) -> int:
+    """The pool a coordinator keeps to a worker whose engines run ``slots``
+    requests at once: a stream holds a connection for its life, so fewer
+    connections than slots leave slots empty. ``DEFAULT_POOL`` where the
+    worker reports that many, fewer, or nothing."""
+    return max(DEFAULT_POOL, int(slots or 0))
+
+
 class FramedRPCClient:
     """Pooled framed-RPC client: concurrent calls each ride their own
     connection (bounded by ``max_connections``), with transparent reconnect
@@ -69,7 +82,7 @@ class FramedRPCClient:
     def __init__(self, host: str, port: int,
                  timeout: float = 30.0,
                  max_frame: int = 64 * 1024 * 1024,
-                 max_connections: int = 8) -> None:
+                 max_connections: int = DEFAULT_POOL) -> None:
         self.host = host
         self.port = port
         self.timeout = timeout
@@ -93,8 +106,20 @@ class FramedRPCClient:
 
     def pool_stats(self) -> Dict[str, int]:
         """Gauges of the connection pool: calls holding a connection,
-        callers blocked because all ``max_connections`` are held."""
-        return {"in_use": len(self._inuse), "waiting": self._waiting}
+        callers blocked because all ``max_connections`` are held, and
+        ``max_connections`` itself."""
+        return {"in_use": len(self._inuse), "waiting": self._waiting,
+                "size": self.max_connections}
+
+    def resize_pool(self, max_connections: int) -> None:
+        """A new bound on the pool. Growing wakes the callers that wait;
+        shrinking closes nothing: connections over the bound are let go as
+        their calls end (``_release_nowait``)."""
+        n = max(1, int(max_connections))
+        grown = n > self.max_connections
+        self.max_connections = n
+        if grown and self._waiting:
+            self._notify_detached(all_waiters=True)
 
     async def _acquire(
         self, timeout: float
@@ -139,6 +164,9 @@ class FramedRPCClient:
             # socket nobody will ever close again
             self._discard_nowait(conn)
             return
+        if self._total > self.max_connections:     # the pool was shrunk
+            self._discard_nowait(conn)
+            return
         self._free.append(conn)
         self._notify_detached()
 
@@ -152,14 +180,17 @@ class FramedRPCClient:
         self._total -= 1
         self._notify_detached()
 
-    def _notify_detached(self) -> None:
-        """Wake one _acquire waiter from a task that can't be cancelled
-        with the caller (Condition.notify needs the lock, which needs an
-        await)."""
+    def _notify_detached(self, all_waiters: bool = False) -> None:
+        """Wake one _acquire waiter (or all of them) from a task that can't
+        be cancelled with the caller (Condition.notify needs the lock, which
+        needs an await)."""
 
         async def _notify() -> None:
             async with self._cond:
-                self._cond.notify()
+                if all_waiters:
+                    self._cond.notify_all()
+                else:
+                    self._cond.notify()
 
         try:
             task = asyncio.get_running_loop().create_task(_notify())

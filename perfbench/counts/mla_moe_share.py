@@ -1,0 +1,158 @@
+"""Counts of the ``mla_moe_share`` family (Kimi-K2.5) as ONE chip of its
+stated deployment holds it: MLA with a compressed query in every layer,
+whole; a dense SwiGLU MLP in the leading layers, whole; in the rest
+``n_routed_experts`` HELD routed experts (a fraction of the one routing
+group of ``num_experts_published``), one shared expert and a router over
+all published experts; ``vocab_size`` rows of the vocabulary (the chip's
+slice); a plain residual (no tensor of its own). HF ``config.json`` key
+names; the kept layers are ``kept_layers`` (published indices). Stored in
+``serve.dtype`` except the router and the expert bias (float32)."""
+
+from typing import Any, Dict, List, Tuple
+
+ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
+CACHE = ("576 latent values a token (512 normalised c | 64 rotated k_rope) "
+         "for EVERY layer, stored in rows of 640 lanes, shared by the 64 "
+         "heads; no per-sequence state")
+
+
+def widths(cfg: Dict[str, Any]) -> Dict[str, int]:
+    kept = [int(i) for i in cfg["kept_layers"]]
+    dense = [i for i in kept if i < int(cfg["first_k_dense_replace"])]
+    return {
+        "D": int(cfg["hidden_size"]), "H": int(cfg["num_attention_heads"]),
+        "F": int(cfg["intermediate_size"]),
+        "Fm": int(cfg["moe_intermediate_size"]),
+        "Fs": int(cfg["moe_intermediate_size"]) * int(cfg["n_shared_experts"]),
+        "V": int(cfg["vocab_size"]),
+        "E": int(cfg["num_experts_published"]),
+        "held": int(cfg["experts_held"][1]),
+        "k": int(cfg["num_experts_per_tok"]),
+        "qr": int(cfg["q_lora_rank"]), "rank": int(cfg["kv_lora_rank"]),
+        "dn": int(cfg["qk_nope_head_dim"]), "dr": int(cfg["qk_rope_head_dim"]),
+        "dv": int(cfg["v_head_dim"]),
+        "L": len(kept), "L_dense": len(dense), "L_moe": len(kept) - len(dense)}
+
+
+def stored_dtype(cfg: Dict[str, Any]) -> str:
+    return str(cfg["serve"].get("dtype", "bfloat16"))
+
+
+def mla_matrices(cfg: Dict[str, Any]) -> List[Tuple[str, int, int]]:
+    """One layer's five MLA matrices, ``(name, K, N)``."""
+    w = widths(cfg)
+    return [("mla_q_a", w["D"], w["qr"]),
+            ("mla_q_b", w["qr"], w["H"] * (w["dn"] + w["dr"])),
+            ("mla_kva", w["D"], w["rank"] + w["dr"]),
+            ("mla_kvb", w["rank"], w["H"] * (w["dn"] + w["dv"])),
+            ("mla_out", w["H"] * w["dv"], w["D"])]
+
+
+def weight_matmuls(cfg: Dict[str, Any]
+                   ) -> List[Tuple[str, int, int, float, str]]:
+    """``(name, K, N, times per pass, dtype)`` for ONE token's forward pass
+    on this chip. An expert matrix is multiplied only for the tokens routed
+    to it: of a token's ``k`` choices among ``E`` experts ``k held / E``
+    land here on average (uniform routing), so the expert matrices count
+    ``L_moe k held / E`` times a token."""
+    w = widths(cfg)
+    dt = stored_dtype(cfg)
+    routed = w["L_moe"] * w["k"] * w["held"] / w["E"]
+    return [(name, k, n, w["L"], dt) for name, k, n in mla_matrices(cfg)] + [
+        ("dense_gate_up", w["D"], 2 * w["F"], w["L_dense"], dt),
+        ("dense_down", w["F"], w["D"], w["L_dense"], dt),
+        ("router", w["D"], w["E"], w["L_moe"], "float32"),
+        ("shared_gate_up", w["D"], 2 * w["Fs"], w["L_moe"], dt),
+        ("shared_down", w["Fs"], w["D"], w["L_moe"], dt),
+        ("expert_gate_up", w["D"], 2 * w["Fm"], routed, dt),
+        ("expert_down", w["Fm"], w["D"], routed, dt),
+        ("lm_head", w["D"], w["V"], 1, dt)]
+
+
+def expert_bytes(cfg: Dict[str, Any]) -> int:
+    """Stored bytes of ONE routed expert (gate, up, down)."""
+    w = widths(cfg)
+    return 3 * w["D"] * w["Fm"] * ITEMSIZE[stored_dtype(cfg)]
+
+
+def param_bytes(cfg: Dict[str, Any]) -> int:
+    """Every tensor of the served tree once: the matrices above with every
+    HELD expert, the embedding, norms, the float32 expert bias."""
+    w = widths(cfg)
+    item = ITEMSIZE[stored_dtype(cfg)]
+    total = 0
+    for name, k, n, times, dt in weight_matmuls(cfg):
+        if name.startswith("expert_"):
+            times = w["L_moe"] * w["held"]
+        total += k * n * times * ITEMSIZE[dt]
+    total += w["V"] * w["D"] * item                       # tok_emb
+    total += (2 * w["L"] + 1) * w["D"] * item             # ln1, ln2, final
+    total += w["L"] * (w["qr"] + w["rank"]) * item        # q_norm, kv_norm
+    total += w["L_moe"] * w["E"] * 4                      # expert bias
+    return total
+
+
+def kv_bytes_per_token(cfg: Dict[str, Any], kv_itemsize: int = 2) -> int:
+    w = widths(cfg)
+    return w["L"] * (w["rank"] + w["dr"]) * kv_itemsize
+
+
+def step_weight_bytes(cfg: Dict[str, Any]) -> int:
+    """What every decode step reads whatever it routes: every kept weight
+    OUTSIDE the routed experts (MLA, the dense layer, routers, shared
+    experts, norms) and the head. The embedding is a gather of live rows
+    and is not counted."""
+    w = widths(cfg)
+    total = param_bytes(cfg)
+    total -= w["L_moe"] * w["held"] * expert_bytes(cfg)
+    total -= w["V"] * w["D"] * ITEMSIZE[stored_dtype(cfg)]   # tok_emb
+    return total
+
+
+def expert_stream_cost(cfg: Dict[str, Any], experts_touched: float,
+                       rows: float) -> Dict[str, float]:
+    """Bytes and operations of the routed experts' two grouped products
+    over decode steps: ``experts_touched`` distinct (layer, expert) pairs
+    that got a row, each expert's three matrices read once; ``rows`` HELD
+    (token, choice) pairs in and out. Counts touched experts, never all
+    held."""
+    w = widths(cfg)
+    act = rows * (2 * w["D"] + 4 * w["D"] + 2 * 2 * w["Fm"] + 2 * w["Fm"])
+    return {"bytes": experts_touched * expert_bytes(cfg) + act,
+            "flops": 2.0 * rows * 3 * w["D"] * w["Fm"]}
+
+
+def mla_decode_cost(cfg: Dict[str, Any], context_rows: float,
+                    kv_itemsize: int = 2) -> Dict[str, float]:
+    """The latent kernel's work over a run's decode steps, in ALL layers:
+    ``context_rows`` = the rows of the live sequences summed over the steps
+    (counter ``mla.decode_context_rows``: live rows, never the table), each
+    576 values (1,152 B) read once a layer; operations: the absorbed scores
+    over 576 lanes and values over 512 for each of the H heads, 2 H (576 +
+    512) = 139 kFLOP a row a layer at 64 heads. 121 FLOP/B: below the
+    v5e's ridge (240), so the HBM peak binds; ``_least_seconds`` takes the
+    larger of the two all the same."""
+    w = widths(cfg)
+    width = w["rank"] + w["dr"]
+    return {"bytes": w["L"] * context_rows * width * kv_itemsize,
+            "flops": w["L"] * context_rows * w["H"]
+            * (2.0 * width + 2.0 * w["rank"])}
+
+
+def decode_stream_cost(cfg: Dict[str, Any], steps: float,
+                       experts_touched: float, expert_rows: float,
+                       context_rows: float, live_rows: float
+                       ) -> Dict[str, float]:
+    """Least bytes of ``steps`` WHOLE decode steps: every kept weight
+    outside the routed experts and the head once a step
+    (``step_weight_bytes``), the experts TOUCHED (never all held), the live
+    latent rows. ``live_rows`` = (live row, step) pairs. Operations: two a
+    weight value a row it multiplies, plus the experts' and the
+    attention's."""
+    item = ITEMSIZE[stored_dtype(cfg)]
+    per_step = step_weight_bytes(cfg)
+    ex = expert_stream_cost(cfg, experts_touched, expert_rows)
+    kv = mla_decode_cost(cfg, context_rows)
+    return {"bytes": steps * per_step + ex["bytes"] + kv["bytes"],
+            "flops": live_rows * per_step / item * 2.0 + ex["flops"]
+            + kv["flops"]}

@@ -5,8 +5,10 @@ window) on the ``window`` body (``pallas-decode_interpret``) and on ``dense``
 (``xla``), ``ling-tiny`` and, since PR 38, ``olmo-hybrid-tiny`` on the kernel
 and on XLA (``hybrid``; a parent dumped before has no such files: ``compare``
 walks its first directory's); since PR 40 ``xing-tiny`` and ``mellum-tiny``
-(window 32) the same way. To dump a parent that lacks an entry, run THIS
-file over its package (``PYTHONPATH=<parent> python <this file> dump <dir>``).
+(window 32) the same way; since PR 41 ``kimi-tiny`` (12 slots: the plain
+residual and the held-rows expert layer). To dump a parent that lacks an
+entry, run THIS file over its package (``PYTHONPATH=<parent> python <this
+file> dump <dir>``).
 
     JAX_PLATFORMS=cpu python -m scripts.decode_jaxpr dump <dir>
     python -m scripts.decode_jaxpr compare <dir-a> <dir-b>
@@ -77,6 +79,7 @@ def dump(out: str) -> None:
     from distributed_inference_engine_tpu.models.olmo_hybrid import (
         olmo_hybrid_spec,
     )
+    from distributed_inference_engine_tpu.models import xing
     from distributed_inference_engine_tpu.models.xing import xing_spec
     from distributed_inference_engine_tpu.ops.quant import (
         random_quantized_params,
@@ -111,6 +114,13 @@ def dump(out: str) -> None:
                            decode_steps_per_call=8, attention_impl=impl)
         _dump_engine(out, name.replace("olmo", "mellum"), ContinuousEngine(
             mellum_spec("mellum-tiny", max_seq_len=256), config=cfg))
+        if not hasattr(xing, "kimi_spec"):      # a parent before PR 41
+            continue
+        cfg = EngineConfig(max_slots=12, max_seq_len=128, page_size=16,
+                           num_pages=96, prefill_buckets=[32, 64],
+                           decode_steps_per_call=8, attention_impl=impl)
+        _dump_engine(out, name.replace("olmo", "kimi"), ContinuousEngine(
+            xing.kimi_spec("kimi-tiny", max_seq_len=128), config=cfg))
 
 
 def compare(a: str, b: str) -> bool:

@@ -501,3 +501,114 @@ def test_the_sliding_window_family_reads_both_pools_in_place_and_fits(
     assert "kv_prefill_flash" in text and "flash_decode" not in text
     assert not re.search(r"f32\[1,4,8,512,[0-9]+\]", text)
     assert temp < 1.5e9, temp          # the parent's: 1,831,853,568
+
+
+def test_latent_decode_at_64_heads_and_32_rows_compiles_for_v5e(one_chip):
+    """The latent kernel at the Kimi cell's shape: 64 query heads on the
+    sublanes (twice the other two families'), 32 rows of 60 pages, the
+    7-layer pool of 1,920 pages a layer read where it lies."""
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        latent_decode_attention_pallas)
+
+    b, mp, w, layers, p, h, lanes, rank = 32, 60, 16, 7, 128, 64, 640, 512
+    n = b * mp
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def fn(q, pages, pt, plen, side, n_side, layer):
+        return latent_decode_attention_pallas(
+            q, pages, pt, plen, side, n_side, layer, v_lanes=rank,
+            scale=0.1, n_pages_per_layer=n)
+
+    bf = jnp.bfloat16
+    compiled = jax.jit(fn).lower(
+        sds((b, h, lanes), bf), sds((layers * n, p, lanes), bf),
+        sds((b, mp), jnp.int32), sds((b,), jnp.int32), sds((b, w, lanes), bf),
+        sds((b,), jnp.int32), sds((), jnp.int32)).compile()
+    assert "latent_decode_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_the_expert_share_family_fits_with_32_slots_and_its_held_rows(
+        one_chip, monkeypatch):
+    """The plain-residual MLA family's programs at the served size (Kimi-K2.5
+    as one chip of EP32: 7 layers of d 7168, 64 heads, 12 of 384 experts, 32
+    slots of 7,680 positions, 16 steps; a prefill at the 6,144 bucket),
+    compiled for the v5e with the Mosaic grouped matmul and the latent
+    prefill kernel steered in (this process sees the CPU): every layer reads
+    the 2.2 GB latent pool through the kernel where it lies, and the
+    largest prefill's temporaries fit beside the 9.73 GB tree and the pool
+    in a chip's 15.75 GB because the expert layer runs over its HELD
+    assignments in blocks of 2,048 rows (``moe_block_held``: 1.11 GB of
+    temporaries; with ``moe_block``'s 49,152 rows a layer 3.08 GB, 15.0 GB
+    in all, PR 41)."""
+    from distributed_inference_engine_tpu.models import xing as fam
+    from distributed_inference_engine_tpu.models.base import unembed
+    from distributed_inference_engine_tpu.ops import mla
+
+    monkeypatch.setattr(mla, "prefill_impl", lambda t: "flash")
+    spec = fam.kimi_spec("kimi-k2.5-ep32-pp1", max_seq_len=7680)
+    slots, page, mp, steps = 32, 128, 60, 16
+    n_pages = slots * mp
+    lanes = spec.cache_row_width
+    assert lanes == 640
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def arr(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_params(spec, jax.random.key(0))))
+    state = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_state(spec, slots)))
+    pool = arr(spec.paged_layers, n_pages, page, lanes, dtype=jnp.bfloat16)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        (params, state, pool)))
+    assert 11.9e9 < held < 12.0e9
+
+    def decode(params, pages, state, lengths, last, active, table):
+        ctx = fam.decode_context(pages, table, "pallas-decode")
+        side = jnp.zeros((spec.paged_layers, slots, steps, lanes),
+                         pages.dtype)
+
+        def step(carry, _):
+            side, state, now, last = carry
+            hidden, side, state, _m = fam.forward_decode_step(
+                spec, params, last, now, lengths, ctx, side, state, active,
+                moe_impl="gmm")
+            tok = jnp.argmax(unembed(spec, params, hidden), -1)
+            return (side, state, now + 1, tok.astype(jnp.int32)), tok
+
+        (side, state, now, last), toks = jax.lax.scan(
+            step, (side, state, lengths, last), None, length=steps)
+        pages = fam.write_rows_into_pages(pages, side, table, now - lengths,
+                                          lengths)
+        return pages, state, toks
+
+    def prefill(params, tokens, lens, pages, state, table, slot_ids):
+        hidden, pages, state, _ = fam.forward_prefill_into_pages(
+            spec, params, tokens, lens, pages, state, table, slot_ids,
+            moe_impl="gmm")
+        return hidden[:, -1], pages, state
+
+    dec = jax.jit(decode, donate_argnums=(1, 2)).lower(
+        params, pool, state, arr(slots), arr(slots),
+        arr(slots, dtype=jnp.bool_), arr(slots, mp)).compile()
+    text = dec.as_text()
+    assert text.count("latent_decode_custom_call") >= spec.paged_layers
+    for shape in ("[7,32,7680,640]", "[32,7680,640]", "[32,60,128,640]",
+                  "[7,32,60,128,640]"):
+        assert shape not in text, shape
+    assert dec.memory_analysis().temp_size_in_bytes < 0.6e9
+    pre = jax.jit(prefill, donate_argnums=(3, 4)).lower(
+        params, arr(1, 6144), arr(1), pool, state, arr(1, mp),
+        arr(1)).compile()
+    temp = pre.memory_analysis().temp_size_in_bytes
+    # no tensor of all 6,144 x 8 assignments' rows: blocks of 2,048
+    assert temp < 1.5e9 and held + temp < 13.5e9, (held, temp)
+    text = pre.as_text()
+    assert "[49152,7168]" not in text and "[49152,4096]" not in text
+    assert "[2048,7168]" in text
