@@ -2330,8 +2330,9 @@ class Coordinator:
     def _pool_gauges(self) -> Dict[str, int]:
         """Connection-pool gauges over the router's and the load balancer's
         worker clients: calls holding a connection, calls waiting for one,
-        and the connections the pools may hold (each follows its worker's
-        slots)."""
+        and the connections the pools may hold: the requests kept AT the
+        workers (each pool is its worker's slots plus a look-ahead,
+        ``utils.rpc.pool_for_slots``)."""
         pools = (self.router.pool_stats(), self.lb.pool_stats())
         # a worker's streams ride ONE of its two clients (the router's
         # where it is registered there): its pool counts once

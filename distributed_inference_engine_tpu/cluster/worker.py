@@ -737,7 +737,7 @@ class WorkerServer(FramedServerMixin):
                 "models": sorted(self.engines),
                 "staged": self.model_manager.staged_names(),
                 "draining": self._draining,
-                "slots": self.slots_report(),
+                **self.capacity_report(),
                 "device": self.device_report()}
 
     def _admit(self) -> None:
@@ -1007,16 +1007,17 @@ class WorkerServer(FramedServerMixin):
                 out = [kv.probe_prefix([bytes(h) for h in hs])
                        * kv.page_size
                        for hs in msg["hashes"]]
-            return {"model": name, "cached_tokens": out,
-                    "page_size": my_page}
-        for prompt in msg.get("prompts", []):    # legacy full-prompt probe
-            if not enabled:
-                out.append(0)
-                continue
-            matchable = (len(prompt) - 1) // kv.page_size
-            hashes = page_chain_hashes(prompt, matchable, kv.page_size)
-            out.append(kv.probe_prefix(hashes) * kv.page_size)
-        return {"model": name, "cached_tokens": out, "page_size": my_page}
+        else:
+            for prompt in msg.get("prompts", []):  # legacy full-prompt probe
+                if not enabled:
+                    out.append(0)
+                    continue
+                matchable = (len(prompt) - 1) // kv.page_size
+                hashes = page_chain_hashes(prompt, matchable, kv.page_size)
+                out.append(kv.probe_prefix(hashes) * kv.page_size)
+        # the relay's pool to this peer follows the capacity report
+        return {"model": name, "cached_tokens": out, "page_size": my_page,
+                **self.capacity_report()}
 
     # -- KV fabric (engine/kv_fabric.py) ------------------------------------
 
@@ -1206,7 +1207,8 @@ class WorkerServer(FramedServerMixin):
                                 for i, h in zip(g_idxs, handoffs)],
                         timeout=peer_timeout,
                     )
-                    cached = probe.get("cached_tokens", [])
+                    cached = peer._follow_slots(probe).get(
+                        "cached_tokens", [])
                     if int(probe.get("page_size", 0)) > 0:
                         peer.probe_page_size = int(probe["page_size"])
                 except RPCError:
@@ -1320,7 +1322,7 @@ class WorkerServer(FramedServerMixin):
                 # re-loads report the original) — demo/supervisor receipts
                 "load_s": self._last_load_s.get(cfg.name, 0.0),
                 # what the caller's pool to this worker follows
-                "slots": self.slots_report()}
+                **self.capacity_report()}
 
     async def _rpc_stage_model(self, msg: Dict[str, Any]) -> Dict[str, Any]:
         """Begin background staging; returns immediately (the build runs on
@@ -1469,11 +1471,25 @@ class WorkerServer(FramedServerMixin):
 
     # -- metrics (reference src/worker.py:186-209) ----------------------------
 
-    def slots_report(self) -> Optional[int]:
-        """Requests this worker's engines run at once, summed over the
-        resident models; ``None`` where no engine says (the fakes)."""
+    def capacity_report(self) -> Dict[str, Any]:
+        """What a caller's pool to this worker is sized from
+        (``utils.rpc.pool_for_slots``). ``slots``: requests the engines run
+        at once, summed over the resident models (``None`` where no engine
+        says). ``queue``: the requests an engine here keeps waiting before
+        it sheds: its ``max_waiting``, 0 where it sheds by
+        ``queue_deadline_s`` (a request that waits here can then be shed
+        where one that waits at the caller is not), the least over the
+        engines, ``None`` where none sheds."""
         slots = [_engine_slots(e) for e in self.engines.values()]
-        return sum(s for s in slots if s) or None
+        bounds = []
+        for engine in self.engines.values():
+            cfg = getattr(engine, "config", None)
+            if getattr(cfg, "queue_deadline_s", 0):
+                bounds.append(0)
+            elif getattr(cfg, "max_waiting", 0):
+                bounds.append(int(cfg.max_waiting))
+        return {"slots": sum(s for s in slots if s) or None,
+                "queue": min(bounds) if bounds else None}
 
     def device_report(self, memory: bool = False) -> Optional[Dict[str, Any]]:
         """Where this worker's engines run, as JAX reports it: platform,
@@ -1592,8 +1608,8 @@ class WorkerClient(FramedRPCClient):
     transparently re-established after a drop (``utils/rpc.py``).
     """
 
-    # the worker's last report of its engines' slots (``_follow_slots``)
-    _slots_seen: Optional[int] = None
+    # the worker's last report of its capacity (``_follow_slots``)
+    _capacity_seen: Optional[Tuple[int, Optional[int]]] = None
 
     # convenience wrappers -----------------------------------------------
 
@@ -1602,15 +1618,17 @@ class WorkerClient(FramedRPCClient):
 
     def _follow_slots(self, reply: Any) -> Any:
         """Size this client's pool from what the worker just reported (a
-        ``ping`` or a ``load_model`` receipt): a stream holds a connection
-        for its life, so the pool follows the engines' slots. A reply with
-        no ``slots`` (an older worker, a fake engine) leaves the pool. The
-        first report sizes the pool whatever ``max_connections`` the client
-        was built with; after it only a changed report does."""
-        slots = reply.get("slots") if isinstance(reply, dict) else None
-        if slots and slots != self._slots_seen:
-            self._slots_seen = slots
-            self.resize_pool(pool_for_slots(slots))
+        ``ping``, a ``load_model`` receipt, a decode peer's ``prefix_probe``
+        answer): a stream holds a connection for its life, so the pool is
+        the engines' slots plus the look-ahead (``pool_for_slots``). A reply
+        with no ``slots`` (an older worker) leaves the pool. The first
+        report sizes the pool whatever ``max_connections`` the client was
+        built with; after it only a changed report does."""
+        if isinstance(reply, dict) and reply.get("slots"):
+            seen = (reply["slots"], reply.get("queue"))
+            if seen != self._capacity_seen:
+                self._capacity_seen = seen
+                self.resize_pool(pool_for_slots(*seen))
         return reply
 
     async def events(self, timeout: Optional[float] = None) -> Dict[str, Any]:

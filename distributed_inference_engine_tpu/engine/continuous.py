@@ -502,6 +502,14 @@ class ContinuousEngine:
         self._prefilling: Dict[int, _PrefillProgress] = {}   # slot -> progress
         self._chunked_admissions = 0
         self._deferred_admissions = 0
+        # does a freed slot find its successor here? Admissions, those of
+        # them whose request was already queued when the slot it took was
+        # freed, and decode dispatches sent with a free slot and nothing
+        # queued (a slot that waits for a request still on its way)
+        self._admissions = 0
+        self._admissions_from_queue = 0
+        self._empty_slot_dispatches = 0
+        self._slot_freed_at = [0.0] * self.max_slots    # perf_counter
 
         # ---- queues / state: (request, stream cb or None, t_submit)
         self._waiting: Deque[Tuple[GenerationRequest, Any, float]] = (
@@ -1289,6 +1297,18 @@ class ContinuousEngine:
             admit.close()
         return admitted
 
+    def _count_admission(self, slot: int, t_submit: float,
+                         t_admit: float) -> None:
+        self.queue_wait_stats.add(t_admit - t_submit)
+        self._admissions += 1
+        if t_submit < self._slot_freed_at[slot]:
+            self._admissions_from_queue += 1
+
+    def _release_slot(self, slot: int) -> None:
+        """Free a slot a sequence held, and note when."""
+        self.kv.free_slot(slot)
+        self._slot_freed_at[slot] = time.perf_counter()
+
     def _register_slot_host(self, req: GenerationRequest, slot: int,
                             prompt_len: int, first: int, t_submit: float,
                             t_admit: float, on_tokens=None,
@@ -1301,7 +1321,7 @@ class ContinuousEngine:
         state.produced = 1
         state.first_token_at = time.perf_counter()
         self.ttft_stats.add(state.first_token_at - t_submit)
-        self.queue_wait_stats.add(t_admit - t_submit)
+        self._count_admission(slot, t_submit, t_admit)
         self._slots[slot] = state
         # prefill_stats is recorded once per DISPATCH by the caller
         # (batched admission would otherwise count one wall time N times)
@@ -1626,7 +1646,7 @@ class ContinuousEngine:
                 self._total_prompt_tokens += len(prompt)
                 state = _Slot(req, slot, len(prompt), t_submit, t_admit, cb)
                 state.first_pending = True
-                self.queue_wait_stats.add(t_admit - t_submit)
+                self._count_admission(slot, t_submit, t_admit)
                 self._slots[slot] = state
                 rows.append(self._slot_row(req, slot, len(prompt), 0))
                 cols.append(i)
@@ -1881,7 +1901,7 @@ class ContinuousEngine:
     def _finish(self, slot: int, reason: str) -> None:
         state = self._slots.pop(slot)
         self._stop_slots.discard(slot)
-        self.kv.free_slot(slot)
+        self._release_slot(slot)
         req = state.request
         if state.first_pending:
             # retired before any packed read delivered its deferred first
@@ -1944,7 +1964,7 @@ class ContinuousEngine:
             return False
         self._slots.pop(slot)
         self._stop_slots.discard(slot)
-        self.kv.free_slot(slot)
+        self._release_slot(slot)
         first = self._resumed.get(req.request_id)
         if first is None:
             first = {"tokens": [], "logprobs": [],
@@ -1995,7 +2015,7 @@ class ContinuousEngine:
         ks, vs = self.kv.read_pages(pages)   # one batched device→host read
         self._swapped.append(_SwapRecord(state, cur, ks, vs, nbytes))
         self._slots.pop(slot)
-        self.kv.free_slot(slot)
+        self._release_slot(slot)
         self._swap_outs += 1
         return True
 
@@ -2302,6 +2322,8 @@ class ContinuousEngine:
             return (len(self._slots) + len(self._prefilling)
                     + len(self._swapped))
 
+        if self.kv.n_free_slots and not self.n_waiting:
+            self._empty_slot_dispatches += 1
         sp = self._dispatch_span("engine.decode.dispatch", steps=n_steps,
                                  live_slots=len(self._slots))
         t0 = sp.t0
@@ -2991,6 +3013,10 @@ class ContinuousEngine:
             "prefilling_slots": len(self._prefilling),
             "chunked_admissions": self._chunked_admissions,
             "deferred_admissions": self._deferred_admissions,
+            # does a freed slot find its successor here (see __init__)
+            "admissions": self._admissions,
+            "admissions_from_queue": self._admissions_from_queue,
+            "empty_slot_dispatches": self._empty_slot_dispatches,
             # serving metrics the reference's mock could never know
             # (SURVEY.md §5): per-request TTFT from submit, and mean decode
             # batch occupancy (live slots / max_slots per engine step)

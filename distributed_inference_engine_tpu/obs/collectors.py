@@ -62,6 +62,12 @@ ENGINE_TABLE = [
      "Admissions that prefill in chunks"),
     ("deferred_admissions", "engine_deferred_admissions", "c",
      "Admissions whose first-token read was deferred"),
+    ("admissions", "engine_admissions", "c",
+     "Requests given a slot"),
+    ("admissions_from_queue", "engine_admissions_from_queue", "c",
+     "Admissions whose request was already queued when its slot was freed"),
+    ("empty_slot_dispatches", "engine_empty_slot_dispatches", "c",
+     "Decode dispatches sent with a free slot and nothing queued"),
     ("rounds", "engine_spec_rounds", "c",
      "Speculative target+draft verification rounds"),
     ("waiting", "engine_waiting", "g", "Requests in the waiting queue"),

@@ -55,12 +55,25 @@ class RPCError(RuntimeError):
 DEFAULT_POOL = 8
 
 
-def pool_for_slots(slots) -> int:
+def pool_for_slots(slots, queue=None) -> int:
     """The pool a coordinator keeps to a worker whose engines run ``slots``
-    requests at once: a stream holds a connection for its life, so fewer
-    connections than slots leave slots empty. ``DEFAULT_POOL`` where the
-    worker reports that many, fewer, or nothing."""
-    return max(DEFAULT_POOL, int(slots or 0))
+    requests at once: the slots plus a look-ahead. A stream holds a
+    connection for its life, so the pool is how many requests the
+    coordinator keeps AT the worker; with only as many as slots, a freed
+    slot's successor is a round trip away and the slot runs one decode
+    chunk empty for every request it serves. The look-ahead (a quarter of
+    the slots, two at least) waits in the engine's own queue and is
+    prefilled in the iteration its slot frees; the rest of the backlog stays
+    with the coordinator. ``queue`` is the worker's report of how many
+    requests its engines keep waiting before they shed (``None``: no bound):
+    the look-ahead takes only the room that leaves beyond the slots, so
+    it is never what makes an engine shed. ``DEFAULT_POOL`` where the worker
+    reports fewer slots than fill it, or nothing."""
+    slots = int(slots or 0)
+    ahead = max(2, slots // 4)
+    if queue is not None:
+        ahead = min(ahead, max(0, int(queue) - slots))
+    return max(DEFAULT_POOL, slots + ahead)
 
 
 class FramedRPCClient:

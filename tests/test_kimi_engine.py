@@ -1,9 +1,10 @@
 """The plain-residual compressed-query MLA family (Kimi-K2.5 at the tiny
 size) through ``ContinuousEngine`` with MORE than 8 slots, and behind a real
 coordinator and worker whose connection pool follows the slots the worker
-reports: twelve streams in flight at once where the ninth used to wait for a
-connection, slot reuse and pre-emption by re-prefill with more than eight
-rows live, and a worker that reports eight slots keeps a pool of eight.
+reports, plus a look-ahead: twelve streams in flight at once where the ninth
+used to wait for a connection, slot reuse and pre-emption by re-prefill with
+more than eight rows live, a worker that reports eight slots gets a pool of
+ten, and a freed slot finds its successor already queued at the engine.
 ``tests/test_kimi.py`` holds the logits comparisons against the reference."""
 
 import asyncio
@@ -179,9 +180,16 @@ def test_the_body_and_the_architecture_string():
 
 
 def test_pool_for_slots():
+    """The slots plus a look-ahead of a quarter of them, two at least; the
+    default where the worker says nothing or that fills less; and a worker
+    that sheds what waits gets only the look-ahead its queue leaves room for
+    beyond its slots."""
     assert DEFAULT_POOL == 8 == FramedRPCClient("h", 1).max_connections
-    assert [pool_for_slots(n) for n in (None, 0, 4, 8, 12, 32)] == [
-        8, 8, 8, 8, 12, 32]
+    assert [pool_for_slots(n) for n in (None, 0, 4, 6, 7, 8, 12, 32)] == [
+        8, 8, 8, 8, 9, 10, 15, 40]
+    assert [pool_for_slots(8, q) for q in (None, 0, 4, 8, 9, 10, 64)] == [
+        10, 8, 8, 8, 9, 10, 10]
+    assert [pool_for_slots(32, q) for q in (0, 36, 64)] == [32, 36, 40]
 
 
 async def test_a_shrunk_pool_lets_go_of_connections_as_calls_end():
@@ -223,12 +231,13 @@ async def _fleet(model):
 
 async def test_twelve_streams_are_in_flight_at_once_behind_a_coordinator():
     """A worker whose engine runs 12 slots: the deploy's load receipt sizes
-    the coordinator's pool to 12, twelve streams hold a connection each and
-    none waits (with the default of 8 the ninth waited for one), the engine
-    has more than eight rows live, and every stream ends with its tokens."""
+    the coordinator's pool to the 12 and their look-ahead, twelve streams
+    hold a connection each and none waits (with the default of 8 the ninth
+    waited for one), the engine has more than eight rows live, and every
+    stream ends with its tokens."""
     coord, w = await _fleet(_model(SLOTS))
     try:
-        assert coord.get_stats()["pool_size"] == SLOTS
+        assert coord.get_stats()["pool_size"] == pool_for_slots(SLOTS) > SLOTS
         dev = w.device_report()["models"]["m"]
         assert (dev["slots"], dev["residual"]) == (SLOTS, "plain")
         reqs = requests(SLOTS, seed=6, new=(24, 32))
@@ -245,9 +254,9 @@ async def test_twelve_streams_are_in_flight_at_once_behind_a_coordinator():
                             w.engines["m"].get_metrics()["live_slots"])
             await asyncio.sleep(0.01)
         outs = await asyncio.gather(*streams)
-        # (a health probe may hold the load balancer's own connection, or
-        # wait while all twelve of the router's are held: not a stream)
-        assert SLOTS <= peak_use <= SLOTS + 1 and peak_wait <= 1
+        # (a health probe may hold a connection of either client beside
+        # the twelve streams: not a stream)
+        assert SLOTS <= peak_use <= SLOTS + 2 and peak_wait == 0
         assert peak_live > 8
         for r, o in zip(reqs, outs):
             assert len(o["tokens"]) == r.max_new_tokens
@@ -265,17 +274,85 @@ async def test_twelve_streams_are_in_flight_at_once_behind_a_coordinator():
         await w.stop()
 
 
-async def test_a_worker_that_reports_eight_slots_keeps_a_pool_of_eight():
+async def test_a_worker_that_reports_eight_slots_gets_its_look_ahead():
     coord, w = await _fleet(_model(8))
     try:
-        stats = coord.get_stats()
-        assert stats["pool_size"] == 8 == DEFAULT_POOL
-        assert coord.router.client_for("w0").max_connections == 8
+        pool = pool_for_slots(8)
+        assert pool > 8 == DEFAULT_POOL
+        assert coord.get_stats()["pool_size"] == pool
+        assert coord.router.client_for("w0").max_connections == pool
         # the load balancer's own client learns the same from its pings
         lb_client = coord.lb.client_for("w0")
         await lb_client.ping()
-        assert lb_client.max_connections == 8
-        assert coord.get_stats()["pool_size"] == 8      # one pool a worker
+        assert lb_client.max_connections == pool
+        assert coord.get_stats()["pool_size"] == pool   # one pool a worker
+        # an engine that sheds what waits too long is sent no look-ahead
+        w.engines["m"].config.queue_deadline_s = 5.0
+        assert (await lb_client.ping())["queue"] == 0
+        assert lb_client.max_connections == pool_for_slots(8, 0) == 8
     finally:
         await coord.stop()
         await w.stop()
+
+
+async def _turnovers(pool=None):
+    """24 streams through a worker of 8 slots (``pool``: the coordinator's
+    pool to it, ``None`` for the rule's). The first eight end two chunks
+    apart, so a chunk sees a finish or two and never more than the
+    look-ahead. Returns the engine's metrics, the outputs with the lengths
+    asked, and what each decode dispatch after the first finish saw:
+    (``empty_slot_dispatches`` so far, streams waiting at the coordinator)."""
+    slots, chunk = 8, 4
+    coord, w = await _fleet(_model(slots))
+    try:
+        client = coord.router.client_for("w0")
+        if pool is not None:
+            client.resize_pool(pool)        # the report stays: no resize back
+        eng = w.engines["m"]
+        reqs = requests(3 * slots, seed=9)
+        for i, r in enumerate(reqs):
+            r.max_new_tokens = chunk * (3 + 2 * i if i < slots else 8)
+        seen = []
+        streams = [asyncio.ensure_future(coord.submit_stream(
+            "m", prompt=list(r.prompt), on_tokens=lambda t: None,
+            max_new_tokens=r.max_new_tokens, request_id=f"s{i}"))
+            for i, r in enumerate(reqs)]
+        while w._pumps.get("m") is None:
+            await asyncio.sleep(0.001)
+        after_dispatch = eng.overlap_hook       # the pump's, on its thread
+
+        def hook():
+            if eng._admissions > slots:         # a slot has been given twice
+                seen.append((eng._empty_slot_dispatches,
+                             client.pool_stats()["waiting"]))
+            after_dispatch()
+
+        eng.overlap_hook = hook
+        outs = await asyncio.gather(*streams)
+        return eng.get_metrics(), list(zip(reqs, outs)), seen
+    finally:
+        await coord.stop()
+        await w.stop()
+
+
+async def test_a_freed_slots_successor_is_already_queued_at_the_engine():
+    """With the look-ahead at the worker, no decode chunk after the first
+    finish runs with a free slot while a stream still waits at the
+    coordinator: the successor is in the engine's queue when the slot frees
+    and joins the very next chunk. On a pool of the slots alone every finish
+    costs its slot an empty chunk."""
+    m, pairs, seen = await _turnovers()
+    for r, o in pairs:
+        assert len(o["tokens"]) == r.max_new_tokens
+    assert m["admissions"] == len(pairs)
+    waited = [(e, n) for e, n in seen if n > 0]
+    assert len(waited) >= 10, seen
+    assert waited[0][0] == waited[-1][0], seen          # none grew meanwhile
+    assert m["admissions_from_queue"] >= 8, m["admissions_from_queue"]
+
+    m, pairs, seen = await _turnovers(pool=8)
+    for r, o in pairs:
+        assert len(o["tokens"]) == r.max_new_tokens
+    waited = [e for e, n in seen if n > 0]
+    assert waited[-1] - waited[0] >= 6, seen            # one a finish, nearly
+    assert m["admissions_from_queue"] == 0
