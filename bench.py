@@ -57,12 +57,9 @@ Env knobs:
                    BENCH_MAX_WAITING (queue cap, default 4x slots; 0 = off),
                    BENCH_DEADLINE_S (queue deadline shed, default 10; 0 = off)
     BENCH_RUNS     timed repetitions, best-of reported (default 3)
-    BENCH_DEFER    1 = defer_sync: overlap each chunk's packed readback
-                   with the next chunk's execution (serving-mode lever)
     BENCH_STREAM   1 = sub-chunk streaming: streaming-flagged slots decode
                    in BENCH_STREAM_STEPS-step chunks (pow2-bucketed,
-                   default 2) and emit through the device->host token
-                   ring; pure-batch slots keep the full megastep
+                   default 2); pure-batch slots keep the full megastep
     BENCH_MIX_EVERY / BENCH_MIX_PROMPT   mixed workload: every Nth serving
                    request carries a BENCH_MIX_PROMPT-token prompt
                    (default 0 = off / 2048)
@@ -243,14 +240,10 @@ def _engine(spec, params, kind: str, batch: int, steps: int):
         cfg.kv_dtype = os.environ["BENCH_KV_DTYPE"]
     if os.environ.get("BENCH_ATTN"):
         cfg.attention_impl = os.environ["BENCH_ATTN"]
-    if os.environ.get("BENCH_DEFER"):
-        # overlap each chunk's packed readback with the next chunk's
-        # execution (serving-mode lever)
-        cfg.defer_sync = True
     if os.environ.get("BENCH_STREAM", "") not in ("", "0"):
         # sub-chunk streaming (ISSUE 13): while any live slot has a
         # stream callback, clamp decode chunks to BENCH_STREAM_STEPS
-        # (pow2-bucketed) so the token ring emits at sub-chunk cadence;
+        # (pow2-bucketed) so tokens reach the host at sub-chunk cadence;
         # pure-batch waves keep the full megastep
         cfg.stream_chunk_steps = int(
             os.environ.get("BENCH_STREAM_STEPS", "2"))

@@ -115,33 +115,12 @@ class EngineConfig:
                                        # this prefill in chunks interleaved with
                                        # decode (0 = whole-prompt prefill);
                                        # rounded to a multiple of page_size
-    defer_admission: bool = True       # continuous engine: under decode
-                                       # pressure (>=1/4 slots live), skip
-                                       # the blocking first-token read at
-                                       # admission — install firsts device-
-                                       # side and harvest them from the
-                                       # next chunk's packed output (saves
-                                       # one blocking host round trip per
-                                       # admission round; first token
-                                       # arrives with the chunk). Light
-                                       # load keeps the sync path for
-                                       # minimal TTFT.
-    defer_sync: bool = False           # continuous engine: dispatch chunk
-                                       # k+1 BEFORE the blocking read of
-                                       # chunk k's packed output, so the
-                                       # host<->device round trip overlaps
-                                       # the next chunk's execution. Costs
-                                       # one chunk of extra latency on
-                                       # host-side stop detection and
-                                       # token streaming; requires a fully
-                                       # backed page pool (num_pages >=
-                                       # max_slots * max_pages_per_seq)
     stream_chunk_steps: int = 0        # sub-chunk streaming (ISSUE 13):
                                        # while any live slot has a stream
                                        # callback, clamp decode chunks to
                                        # this many steps (pow2-bucketed —
                                        # at most ONE extra decode program)
-                                       # so tokens reach the host ring
+                                       # so tokens reach the host
                                        # every few steps instead of once
                                        # per decode_steps_per_call
                                        # megastep. Pure-batch rounds keep

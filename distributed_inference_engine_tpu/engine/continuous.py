@@ -17,8 +17,9 @@ Static-shape discipline (SURVEY.md §7 hard-part #1):
   bucket, seq to a prefill bucket): at most ``(log2(max_slots)+1) ×
   len(prefill_buckets)`` prefill programs exist.
 - The decode chunk is ``lax.scan`` over ``decode_steps_per_call`` steps with
-  pages donated in — zero per-token host round-trips, one small host sync
-  per chunk.
+  pages donated in — zero per-token host round-trips, one small host read
+  per chunk, made AFTER the next chunk is dispatched (``step``): the
+  host's bookkeeping runs under a program, never between two.
 
 Capacity discipline (SURVEY.md §7 hard-part #2): before each chunk every
 active slot reserves capacity for the chunk's worst case; slots whose grant
@@ -171,10 +172,9 @@ class _Slot:
         self.on_tokens = on_tokens      # streaming: cb(new_tokens: List[int])
         self.streamed = 0               # tokens already emitted to the cb
         self.stop_cut = -1              # earliest stop cut, once found
-        self.first_pending = False      # deferred admission: the prefill-
-                                        # sampled first token lives in the
-                                        # device firsts buffer until the
-                                        # next chunk's packed read
+        self.first_pending = False      # the prefill-sampled first token is
+                                        # still in the prefill's own output
+                                        # on the device (_read_firsts)
 
 
 class _PrefillProgress:
@@ -195,56 +195,30 @@ class _PrefillProgress:
 
 
 class _ChunkEntry:
-    """One dispatched decode chunk's packed output in flight to the
-    host, plus everything needed to process it later. Chunk processing
-    has three parts: ``_harvest_chunk`` (the token half — blocking read,
-    length mirror, token/logprob appends, stop scan), ``_judge_packed``
-    (the control half — pause/finish/revive judgments; a slot that
-    finishes streams its own last frames from ``_finish``), and the
-    streaming of the slots that live on, which decides nothing and is
-    CARRIED under the next dispatch (``_carry_emit`` / ``_flush_emit``).
-    ``defer_sync`` dispatches push the entry onto the engine's stream
-    ring and kick an async device→host copy; the pump's
-    ``poll_stream()`` harvests the token half early when the copy lands
-    (inside the measured host bubble), and the deferred flush runs the
-    control half either way — harvest is idempotent via ``harvested``."""
+    """The decode chunk in flight: its packed output on its way to the
+    host, and what reading it later needs. The engine keeps ONE (the
+    chunk it dispatched last, ``_pending``) and reads it after it has
+    dispatched the next. ``snapshot`` are the ``_Slot`` objects live at
+    the dispatch: a column belongs to that object, never to a later
+    tenant of its slot. ``caps`` is the per-slot token capacity the chunk
+    ran with, which tells a row the device PAUSED at its grant from one
+    that finished. ``handed_on``: slots whose row is certain to end in
+    this chunk and whose slot went to a successor while it ran
+    (``_hand_on_foreseen``); their last tokens and results come out of
+    this entry's read. ``idle``: slots revived after this chunk was
+    dispatched, so their row sits it out."""
 
-    __slots__ = ("packed", "n_steps", "snapshot", "t0", "caps",
-                 "fresh_firsts", "host", "harvested", "progressed",
-                 "streamed")
+    __slots__ = ("packed", "n_steps", "snapshot", "caps", "handed_on",
+                 "idle")
 
     def __init__(self, packed, n_steps: int, snapshot: Dict[int, _Slot],
-                 t0: float, caps: Optional[List[int]],
-                 fresh_firsts: bool) -> None:
+                 caps: List[int]) -> None:
         self.packed = packed
         self.n_steps = n_steps
         self.snapshot = snapshot
-        self.t0 = t0
         self.caps = caps
-        self.fresh_firsts = fresh_firsts
-        self.host: Optional[np.ndarray] = None   # set by _harvest_chunk
-        self.harvested = False
-        # slot -> progressed flag stashed at harvest time, so control can
-        # re-judge without re-deriving it from a possibly-mutated _Slot
-        self.progressed: Dict[int, bool] = {}
-        # the harvest met a slot with a stream callback: the chunk counts
-        # once, as carried or as flushed (get_metrics)
-        self.streamed = False
-
-    def ready(self) -> bool:
-        """True when the packed buffer can be read without blocking.
-        Backends without ``is_ready`` report NOT ready — the poll must
-        never risk turning the host bubble into a sync point; the
-        deferred flush still reads the buffer (blocking) either way."""
-        if self.host is not None:
-            return True
-        probe = getattr(self.packed, "is_ready", None)
-        if probe is None:
-            return False
-        try:
-            return bool(probe())
-        except Exception:       # pragma: no cover - backend quirk
-            return False
+        self.handed_on: set = set()
+        self.idle: set = set()
 
 
 class _SwapRecord:
@@ -351,18 +325,6 @@ class ContinuousEngine:
                 raise ValueError(
                     "a per-layer (hybrid) spec does not support: "
                     + "; ".join(refused))
-        # defer_sync needs a fully backed pool: host lengths go one chunk
-        # stale, and only a pool that can always grow every slot to
-        # max_seq_len guarantees a chunk never writes past reserved pages.
-        # Checked here (cfg+spec only), before an 8B-scale init is paid
-        # for; re-asserted against the pool's own max_pages_per_seq after
-        # construction so the two formulas cannot silently diverge.
-        if cfg.defer_sync and cfg.num_pages < cfg.max_slots * (
-                -(-min(cfg.max_seq_len, spec.max_seq_len)
-                  // cfg.page_size)):
-            raise ValueError(
-                "defer_sync needs a fully backed page pool: num_pages >= "
-                "max_slots * ceil(max_seq_len / page_size)")
         if params is None:
             init = (layered_family(spec).init_params if spec.layer_kinds
                     else init_params)
@@ -467,26 +429,14 @@ class ContinuousEngine:
             kind: (window, [0, 0])
             for kind, window in (("full", 0), ("window", self.kv.window))
             if self._kv_rows and (kind == "full" or window)}
-        # defer_sync: chunk k's packed output is read AFTER dispatching
-        # chunk k+1, overlapping the host round trip with device compute
-        # (validated pre-init above; the pool's own bound must agree)
-        self._defer = bool(cfg.defer_sync)
-        assert not self._defer or cfg.num_pages >= (
-            cfg.max_slots * self.kv.max_pages_per_seq)
-        # deferred chunk in flight (see _ChunkEntry); under defer_sync
-        # the same entry also sits on the stream ring below until its
-        # token half is harvested
+        # the decode chunk in flight (see _ChunkEntry): read after the NEXT
+        # one is dispatched, so the host's bookkeeping runs under a program
         self._pending: Optional[_ChunkEntry] = None
-        # device→host token ring (ISSUE 13): dispatched-but-unharvested
-        # chunks, oldest first. poll_stream() drains ready heads so
-        # streamed tokens reach consumers up to one chunk early.
-        self._ring: Deque[_ChunkEntry] = collections.deque()
-        # the slots of the last processed chunk that live on and whose
-        # fresh tokens are not streamed yet: emitted right after the NEXT
-        # dispatch, under the running program (_flush_emit). None =
-        # nothing carried; _emit_stream is cumulative per _Slot, so a
-        # flush at any point delivers each token once, in order
-        self._carried: Optional[List[_Slot]] = None
+        # prefills whose sampled first tokens are still on the device:
+        # (the prefill's [2, bb] output, when it was dispatched, [(column,
+        # _Slot)]), read after the next decode dispatch (_read_firsts)
+        self._first_reads: List[
+            Tuple[Any, float, List[Tuple[int, _Slot]]]] = []
         self._emit_carried_chunks = 0    # streamed under a later dispatch
         self._emit_flushed_chunks = 0    # streamed with nothing to hide it
         # the open engine.decode.dispatch bracket and its closing args:
@@ -501,7 +451,6 @@ class ContinuousEngine:
                        if cfg.prefill_chunk else 0)
         self._prefilling: Dict[int, _PrefillProgress] = {}   # slot -> progress
         self._chunked_admissions = 0
-        self._deferred_admissions = 0
         # does a freed slot find its successor here? Admissions, those of
         # them whose request was already queued when the slot it took was
         # freed, and decode dispatches sent with a free slot and nothing
@@ -510,6 +459,16 @@ class ContinuousEngine:
         self._admissions_from_queue = 0
         self._empty_slot_dispatches = 0
         self._slot_freed_at = [0.0] * self.max_slots    # perf_counter
+        # how often the one sequence engages: successors prefilled behind
+        # the chunk their predecessor ends in; finishes only a read could
+        # tell (EOS, a stop, a row paused at its grant), each one chunk of
+        # one slot; seconds blocked in the packed reads (near zero a chunk:
+        # the overlap holds); iterations that read the chunk in flight
+        # BEFORE dispatching, the pool unable to back a chunk ahead of it
+        self._admissions_ahead = 0
+        self._finishes_learned_late = 0
+        self._harvest_wait_s = 0.0
+        self._sync_fallback_iterations = 0
 
         # ---- queues / state: (request, stream cb or None, t_submit)
         self._waiting: Deque[Tuple[GenerationRequest, Any, float]] = (
@@ -540,22 +499,12 @@ class ContinuousEngine:
         self._top_k = jnp.zeros((n,), jnp.int32)
         self._top_p = jnp.ones((n,), jnp.float32)
         self._min_p = jnp.zeros((n,), jnp.float32)
-        # deferred admission (r4): per-slot [token; logprob-bits] of the
-        # prefill-sampled first token, harvested from the NEXT chunk's
-        # packed output instead of a dedicated blocking read (a full
-        # host round trip per admission round, paid while the device
-        # sits idle). Deferral engages only under decode
-        # pressure — see _admit_batch.
+        # per-slot [token; logprob-bits] of the prefill-sampled first
+        # token, as ``_install_first`` parks it and the chunk's packed
+        # output carries it. The host reads a first token from the
+        # prefill's own output (_read_firsts), a chunk earlier; the buffer
+        # stays an operand so that both programs are the ones they were.
         self._firsts_dev = jnp.zeros((2, n), jnp.int32)
-        # host cache of the firsts buffer (ISSUE 5 satellite): retire-path
-        # rescues (_finish/_try_swap_out) used to pay one [2]-element
-        # device round trip PER SLOT; the packed chunk output already
-        # carries the whole buffer, so sync chunk processing caches it
-        # here and a retire wave reads it for free. None = stale (an
-        # install rewrote columns); _firsts_snapshot then refetches the
-        # WHOLE buffer once, not per slot.
-        self._firsts_host: Optional[np.ndarray] = None
-        self._defer_admit = bool(getattr(cfg, "defer_admission", True))
         # device-side stop ids (ISSUE 5b): the first _DEVICE_STOP_K
         # single-token stops per slot ride a [n, K] matrix so the decode
         # loop retires a stopped slot IN-CHUNK instead of generating (and
@@ -605,9 +554,9 @@ class ContinuousEngine:
 
         def _sample_firsts(params, hidden, seq_lens, sampling, key):
             """Shared prefill tail: last-token logits → sampled first
-            token + logprob, packed into ONE [2, B] int32 buffer (the
-            deferred-admission harvest contract — change it here and
-            BOTH admission programs stay in sync). Sampling happens
+            token + logprob, packed into ONE [2, B] int32 buffer (what
+            ``_install_first`` and ``_read_firsts`` take — change it here
+            and BOTH admission programs stay in sync). Sampling happens
             in-program because eager sampling is a chain of separate
             dispatches whose launch latencies all land in TTFT."""
             last = hidden[jnp.arange(hidden.shape[0]), seq_lens - 1]
@@ -829,7 +778,7 @@ class ContinuousEngine:
                             lengths - start_lengths, start=start_lengths,
                         )
             # pack tokens + logprobs (bitcast) + active flags + lengths +
-            # the deferred-admission firsts buffer into ONE output buffer:
+            # the firsts buffer into ONE output buffer:
             # the host makes exactly one blocking read per chunk (each
             # sync is a full round trip on remote devices)
             packed = jnp.concatenate(
@@ -956,19 +905,18 @@ class ContinuousEngine:
         def _install_first(lengths, last, active, produced, max_new, eos,
                            temps, top_k, top_p, min_p, stops, firsts_buf,
                            slots, vals, first_dev, cols):
-            """Deferred-admission install: like ``_install`` but the first
-            tokens stay ON DEVICE — ``first_dev`` is the prefill program's
-            [2, bb] output, ``cols`` maps each row to its column in it.
-            The tokens seed the decode state directly and are parked in
-            ``firsts_buf`` for the host to harvest from the next chunk's
-            packed read (no dedicated blocking readback)."""
+            """The install of a local prefill's rows: like ``_install`` but
+            the first tokens stay ON DEVICE — ``first_dev`` is the prefill
+            program's [2, bb] output, ``cols`` maps each row to its column
+            in it. The tokens seed the decode state directly (and are
+            parked in ``firsts_buf``); the host reads them from
+            ``first_dev`` after the next decode dispatch."""
             i = slots
             kw = dict(mode="drop")
             sel = first_dev[:, cols]               # [2, bb_rows]
             # a prefill-sampled first token that IS eos must not decode:
-            # the sync path finishes it host-side before install; here the
-            # device sees it, so install the slot inactive (the host
-            # harvest then retires it on the next packed read)
+            # the device sees it first, so the slot comes up inactive (the
+            # host retires it when it reads the token)
             live = (sel[0] != vals["eos"]) | (vals["eos"] < 0)
             return (
                 lengths.at[i].set(vals["prompt_len"], **kw),
@@ -1033,16 +981,14 @@ class ContinuousEngine:
         self._warm_log_index = compile_cache.log_index()
         self._warm_counters = compile_cache.compile_counters()   # installs
         # host-gap split (ISSUE 5 satellite): dispatch-bracket seconds vs
-        # the host-side gap BETWEEN consecutive dispatch brackets, so an
-        # hbm_util regression is attributable at a glance — kernel-side
-        # (dispatch grew) or scheduler-side (gap grew). Counted even with
-        # the timeline ring disabled. A decode bracket runs from the
-        # dispatch call to the end of the step's blocking packed read,
-        # i.e. ≈ device-busy wall time: everything the host does between
-        # that read and the next dispatch (appends, judgments, admission,
-        # the capacity loop) is gap. defer_sync brackets end at the read
-        # of the PREVIOUS chunk, so its gap share reads differently —
-        # compare like with like.
+        # the host-side gap BETWEEN consecutive dispatch brackets. Counted
+        # even with the timeline ring disabled. A decode bracket runs from
+        # the dispatch of chunk k+1 to the end of the blocking read of
+        # chunk k that follows it; what the host does from there to the
+        # next dispatch (appends, judgments, streaming, admission, the
+        # capacity loop) is gap on the host's clock, and runs under chunk
+        # k+1 on the device's: ``harvest_wait_s_total`` says whether the
+        # device was still busy when the host came back to read.
         self._dispatch_s = 0.0
         self._host_gap_s = 0.0
         self._last_dispatch_end: Optional[float] = None
@@ -1050,19 +996,10 @@ class ContinuousEngine:
         # after each chunk dispatch, while the device is busy. The
         # serving pump wires its inbox drain (batch formation) here so
         # admission work rides the device step's shadow instead of the
-        # gap between steps. The hook must only enqueue (engine.submit)
-        # or poll the stream ring; it must NOT call step()/install paths.
+        # gap between steps. The hook must only enqueue (engine.submit);
+        # it must NOT call step()/install paths.
         self.overlap_hook: Optional[Any] = None
-        # sub-chunk streaming counters (ISSUE 13): ring traffic, the
-        # clamp engagements, and firsts-buffer device fetches (the
-        # retire-rescue path's regression guard — one per invalidation,
-        # never per slot)
-        self._ring_pushes = 0        # entries dispatched onto the ring
-        self._ring_polls = 0         # poll_stream calls w/ a live ring
-        self._ring_ready_polls = 0   # polls that harvested an entry
-        self._ring_high_water = 0    # max ring depth observed
         self._stream_clamped_chunks = 0   # chunks shortened for streaming
-        self._firsts_fetches = 0     # whole-buffer firsts readbacks
 
         if self.artifact_manifest is not None and artifact_selfcheck:
             # golden-token self-check BEFORE any traffic: replays the
@@ -1303,39 +1240,17 @@ class ContinuousEngine:
         self._admissions += 1
         if t_submit < self._slot_freed_at[slot]:
             self._admissions_from_queue += 1
+        if self._pending is not None and slot in self._pending.handed_on:
+            self._admissions_ahead += 1
 
     def _release_slot(self, slot: int) -> None:
         """Free a slot a sequence held, and note when."""
         self.kv.free_slot(slot)
         self._slot_freed_at[slot] = time.perf_counter()
 
-    def _register_slot_host(self, req: GenerationRequest, slot: int,
-                            prompt_len: int, first: int, t_submit: float,
-                            t_admit: float, on_tokens=None,
-                            first_lp: float = 0.0) -> bool:
-        """Host bookkeeping of one admission; returns True when the slot
-        stays live (i.e. needs its device state installed)."""
-        state = _Slot(req, slot, prompt_len, t_submit, t_admit, on_tokens)
-        state.tokens.append(first)
-        state.logprobs.append(first_lp)
-        state.produced = 1
-        state.first_token_at = time.perf_counter()
-        self.ttft_stats.add(state.first_token_at - t_submit)
-        self._count_admission(slot, t_submit, t_admit)
-        self._slots[slot] = state
-        # prefill_stats is recorded once per DISPATCH by the caller
-        # (batched admission would otherwise count one wall time N times)
-        self._emit_stream(state)
-
-        state.stop_cut = find_stop_cut([first], req)
-        if state.stop_cut >= 0 or req.max_new_tokens <= 1:
-            self._finish(slot, "stop" if state.stop_cut >= 0 else "length")
-            return False
-        return True
-
     def _pack_rows(self, rows: List[Dict[str, Any]]):
         """Pad an admission round's rows to a pow2 bucket of device-ready
-        arrays (shared by the sync and deferred installs). Pad entries
+        arrays (shared by the two installs). Pad entries
         hold ``max_slots`` and fall out of the scatters' range. Also
         updates the host length mirror."""
         bb = 1 << (len(rows) - 1).bit_length()
@@ -1374,11 +1289,10 @@ class ContinuousEngine:
 
     def _install_device_first(self, rows: List[Dict[str, Any]],
                               cols: List[int], first_dev) -> None:
-        """Deferred-admission install: device state comes up exactly as in
-        ``_install_device`` but the first tokens are wired from the
-        prefill output ``first_dev`` (device) — column ``cols[i]`` for
-        ``rows[i]`` (``vals["first"]`` goes unused) — and parked in
-        ``_firsts_dev`` for the next packed read. No host round trip."""
+        """Device state comes up exactly as in ``_install_device`` but the
+        first tokens are wired from the prefill output ``first_dev``
+        (device) — column ``cols[i]`` for ``rows[i]`` (``vals["first"]``
+        goes unused). No host round trip."""
         if not rows:
             return
         bb, slots, vals = self._pack_rows(rows)
@@ -1393,7 +1307,6 @@ class ContinuousEngine:
             self._top_p, self._min_p, self._stops_dev, self._firsts_dev,
             slots, vals, first_dev, jnp.asarray(cols_np),
         )
-        self._firsts_host = None     # device columns rewritten: cache stale
 
     @staticmethod
     def _slot_row(req: GenerationRequest, slot: int, prompt_len: int,
@@ -1408,18 +1321,103 @@ class ContinuousEngine:
                       prompt_len: int, first: int, dispatch: HostSpan,
                       on_tokens, t_submit: float, t_admit: float,
                       first_lp: float = 0.0) -> None:
-        """Single-admission tail (suffix / disaggregated paths); batched
-        admissions go through ``_admit_batch``. ``dispatch`` is the open
-        prefill bracket (it feeds the prefill-latency histogram);
-        ``t_submit`` starts the request's TTFT clock (queue wait
-        included); ``t_admit`` is when its prefill was dispatched."""
+        """Tail of an admission whose first token the HOST already holds
+        (a disaggregated handoff: ``_admit_prefilled``); a local prefill's
+        rows go through ``_seat``. ``dispatch`` is the open page-write
+        bracket (it feeds the prefill-latency histogram); ``t_submit``
+        starts the request's TTFT clock (queue wait included)."""
         self.prefill_stats.add(time.perf_counter() - dispatch.t0)
         self._tl_record(dispatch)
-        if self._register_slot_host(req, slot, prompt_len, first,
-                                    t_submit, t_admit, on_tokens,
-                                    first_lp=first_lp):
+        state = _Slot(req, slot, prompt_len, t_submit, t_admit, on_tokens)
+        state.tokens.append(first)
+        state.logprobs.append(first_lp)
+        state.produced = 1
+        state.first_token_at = time.perf_counter()
+        self.ttft_stats.add(state.first_token_at - t_submit)
+        self._count_admission(slot, t_submit, t_admit)
+        self._slots[slot] = state
+        self._emit_stream(state)
+        state.stop_cut = find_stop_cut([first], req)
+        if state.stop_cut >= 0 or req.max_new_tokens <= 1:
+            self._finish(slot, "stop" if state.stop_cut >= 0 else "length")
+        else:
             self._install_device(
                 [self._slot_row(req, slot, prompt_len, first)])
+
+    def _seat(self, rows: List[Tuple], first_dev, t_sent: float) -> None:
+        """Tail of every local prefill (dispatched at ``t_sent``): its rows
+        ``(request, stream cb, slot, prompt_len, t_submit, t_admit, column
+        of first_dev)`` come up on the device with their first tokens
+        wired from ``first_dev`` where it lies (``_install_first``), so
+        admission never blocks the decode dispatch that follows; the host
+        reads ``first_dev`` after that dispatch (``_read_firsts``). A request that ends with its first
+        token (``max_new_tokens <= 1``) never decodes: it is not installed
+        and gives its slot back at once (device order keeps its pages
+        until the prefill has written them)."""
+        install: List[Dict[str, Any]] = []
+        cols: List[int] = []
+        reads: List[Tuple[int, _Slot]] = []
+        for req, cb, slot, prompt_len, t_submit, t_admit, col in rows:
+            state = _Slot(req, slot, prompt_len, t_submit, t_admit, cb)
+            state.first_pending = True
+            self._count_admission(slot, t_submit, t_admit)
+            reads.append((col, state))
+            if req.max_new_tokens <= 1:
+                self._release_slot(slot)
+                state.slot_id = -1
+                continue
+            self._slots[slot] = state
+            install.append(self._slot_row(req, slot, prompt_len, 0))
+            cols.append(col)
+        self._install_device_first(install, cols, first_dev)
+        if reads:
+            self._first_reads.append((first_dev, t_sent, reads))
+
+    def _read_firsts(self) -> None:
+        """Deliver the first tokens of the prefills dispatched since the
+        last call: one blocking read of each prefill's own [2, bb] output.
+        ``_step`` calls it AFTER the decode dispatch and the read of the
+        chunk before it (the device runs that chunk, then the prefill, then
+        the new chunk), so the wait is the prefill's and the first frame
+        leaves a chunk before the packed output that follows it. A first
+        token that already ends its request (EOS, a stop, a budget of one)
+        finishes it here."""
+        if not self._first_reads:
+            return
+        reads, self._first_reads = self._first_reads, []
+        stopped: List[int] = []
+        with self._span("engine.first_tokens",
+                        rows=sum(len(r[2]) for r in reads)):
+            for first_dev, t_sent, rows in reads:
+                # graftlint: ok[host-sync-hot-path] ONE read per prefill dispatch, after the next decode chunk is on the device
+                fp = np.asarray(first_dev)       # [2, bb]: tokens; lp bits
+                toks = fp[0].tolist()
+                lps = fp[1].view(np.float32).tolist()
+                now = time.perf_counter()
+                # per dispatch: prefill sent -> its first tokens on the host
+                self.prefill_stats.add(now - t_sent)
+                for col, state in rows:
+                    req = state.request
+                    state.first_pending = False
+                    state.tokens.append(toks[col])
+                    state.logprobs.append(lps[col])
+                    state.produced = 1
+                    state.first_token_at = now
+                    self.ttft_stats.add(now - state.submitted_at)
+                    state.stop_cut = find_stop_cut(state.tokens, req)
+                    reason = "stop" if state.stop_cut >= 0 else "length"
+                    if state.slot_id < 0:                   # never seated
+                        self._finish_state(state, reason)
+                    elif state.stop_cut >= 0:
+                        # the chunk just dispatched carries the row: one
+                        # chunk of one slot (a stop id decodes through it,
+                        # an EOS came up inactive)
+                        self._finishes_learned_late += 1
+                        stopped.append(state.slot_id)
+                        self._finish(state.slot_id, reason)
+                    else:
+                        self._emit_stream(state)
+        self._deactivate_many(stopped)
 
     def _admit_row_cap(self) -> int:
         """Rows per admission-prefill dispatch: bounds the [L, bb, T,
@@ -1514,16 +1512,11 @@ class ContinuousEngine:
                 first_dev = self._prefill_cached_suffix(
                     prompt, slot, n_cached, req, k0)
                 t_admit = time.perf_counter()
+                self._tl_record(sp)
                 self.kv.register_prefix(slot, prompt)
-                self._flush_emit(True)       # streams under the prefill
-                # graftlint: ok[host-sync-hot-path] sync cached-suffix admission needs its first token now; [2,1] elements, once per admission
-                fp = np.asarray(first_dev)           # [2, 1]: token; lp bits
-                first = int(fp[0, 0])
-                first_lp = float(fp[1].view(np.float32)[0])
                 self._total_prompt_tokens += len(prompt)
-                self._install_slot(req, slot, len(prompt), first, sp,
-                                   on_tok, t_submit=t_submit,
-                                   t_admit=t_admit, first_lp=first_lp)
+                self._seat([(req, on_tok, slot, len(prompt), t_submit,
+                             t_admit, 0)], first_dev, sp.t0)
                 admit.close()
             else:
                 batch.append((req, on_tok, slot, prompt, t_submit, None))
@@ -1618,51 +1611,8 @@ class ContinuousEngine:
             )
         self.kv.swap(kp, vp)
         t_admit = time.perf_counter()    # slots held, prefill dispatched
-        # deferred admission: under decode pressure (≥1/4 of slots live),
-        # skip the blocking first-token read — install the firsts device-
-        # side and let the host harvest them from the NEXT chunk's packed
-        # output. Saves a full host round trip per admission round while
-        # the device would otherwise idle. Light load keeps the sync path
-        # (first token delivered ~a chunk earlier). max_new<=1 requests
-        # must stop BEFORE decoding, which needs the token on host — sync.
-        defer = (self._defer_admit
-                 and len(self._slots) * 4 >= self.max_slots
-                 and all(r.max_new_tokens > 1 for r, *_ in batch))
-        if defer:
-            self.prefill_stats.add(time.perf_counter() - sp.t0)  # dispatch only
-            self._tl_record(sp, program=("prefill", bb, tb), deferred=True)
-            rows: List[Dict[str, Any]] = []
-            cols: List[int] = []
-            for i, (req, cb, slot, prompt, t_submit, full) in enumerate(batch):
-                if full is not None:
-                    # chunked first-chunk rows take the sync machinery
-                    # either way (their sample is discarded) — they are
-                    # not deferred admissions
-                    self._start_chunked(req, cb, slot, full, t_submit,
-                                        t_admit, done=len(prompt))
-                    continue
-                if self.prefix_cache:
-                    self.kv.register_prefix(slot, prompt)
-                self._total_prompt_tokens += len(prompt)
-                state = _Slot(req, slot, len(prompt), t_submit, t_admit, cb)
-                state.first_pending = True
-                self._count_admission(slot, t_submit, t_admit)
-                self._slots[slot] = state
-                rows.append(self._slot_row(req, slot, len(prompt), 0))
-                cols.append(i)
-            self._deferred_admissions += len(rows)
-            self._install_device_first(rows, cols, first_dev)
-            return
-        # the sync path blocks on the prefill: what the last chunk left
-        # carried streams under it, not after it
-        self._flush_emit(True)
-        # graftlint: ok[host-sync-hot-path] ONE read per admission round, amortized over the whole batch (deferred path returns above)
-        fp = np.asarray(first_dev)                 # [2, bb]: tokens; lp bits
-        firsts = fp[0]
-        first_lps = fp[1].view(np.float32)
-        self.prefill_stats.add(time.perf_counter() - sp.t0)  # once per dispatch
         self._tl_record(sp, program=("prefill", bb, tb))
-        rows = []
+        rows: List[Tuple] = []
         for i, (req, cb, slot, prompt, t_submit, full) in enumerate(batch):
             if full is not None:
                 # first chunk of a chunked admission: its KV pages are
@@ -1676,12 +1626,8 @@ class ContinuousEngine:
             if self.prefix_cache:
                 self.kv.register_prefix(slot, prompt)
             self._total_prompt_tokens += len(prompt)
-            first = int(firsts[i])
-            if self._register_slot_host(req, slot, len(prompt), first,
-                                        t_submit, t_admit, cb,
-                                        first_lp=float(first_lps[i])):
-                rows.append(self._slot_row(req, slot, len(prompt), first))
-        self._install_device(rows)
+            rows.append((req, cb, slot, len(prompt), t_submit, t_admit, i))
+        self._seat(rows, first_dev, sp.t0)
 
     def _run_suffix_prefill(self, suffixes, slots, n_ctxs, reqs, key):
         """Run ONE jitted suffix-prefill over N partially prefilled
@@ -1806,10 +1752,8 @@ class ContinuousEngine:
             [prog.done for _, prog in items],
             [prog.request for _, prog in items], k0)
         self._prefill_calls += 1
-        self.prefill_stats.add(time.perf_counter() - sp.t0)
         self._tl_record(sp)
-        fp = None                         # read back only if someone finished
-        rows: List[Dict[str, Any]] = []
+        rows: List[Tuple] = []
         for i, (slot, prog) in enumerate(items):
             prog.done += len(suffixes[i])
             if prog.done < len(prog.prompt):
@@ -1821,32 +1765,20 @@ class ContinuousEngine:
             # only the LAST chunk's sample is the real first token (earlier
             # chunks' samples are discarded — their logits see a truncated
             # prompt)
-            if fp is None:
-                self._flush_emit(True)   # streams under the suffix program
-                # graftlint: ok[host-sync-hot-path] guarded by fp is None: ONE read per finished prefill group, not per row
-                fp = np.asarray(first_dev)        # [2, bb]: token; lp bits
-            first = int(fp[0, i])
-            first_lp = float(fp[1].view(np.float32)[i])
-            if self._register_slot_host(prog.request, slot,
-                                        len(prog.prompt), first,
-                                        prog.t_submit, prog.t_admit,
-                                        prog.on_tokens,
-                                        first_lp=first_lp):
-                rows.append(self._slot_row(prog.request, slot,
-                                           len(prog.prompt), first))
-        self._install_device(rows)
+            rows.append((prog.request, prog.on_tokens, slot,
+                         len(prog.prompt), prog.t_submit, prog.t_admit, i))
+        self._seat(rows, first_dev, sp.t0)
 
     # ---------------------------------------------------------- streaming
 
-    def _emit_stream(self, state: _Slot) -> int:
+    def _emit_stream(self, state: _Slot) -> None:
         """Push newly generated tokens to the slot's streaming callback,
         trimmed exactly like ``_finish`` trims the final result (cap at
         max_new_tokens, cut after EOS) so a streaming consumer never sees
-        tokens the result won't contain. Returns 1 when a frame was
-        delivered (ring poll accounting), else 0."""
+        tokens the result won't contain."""
         cb = state.on_tokens
         if cb is None:
-            return 0
+            return
         req = state.request
         toks = state.tokens[: req.max_new_tokens]
         if 0 <= state.stop_cut <= len(toks):
@@ -1862,56 +1794,32 @@ class ContinuousEngine:
                 logger.exception("stream callback failed for %s",
                                  req.request_id)
                 state.on_tokens = None     # don't retry a broken consumer
-            return 1
-        return 0
 
     # ------------------------------------------------------------- finish
 
-    def _firsts_snapshot(self) -> np.ndarray:
-        """Host [2, max_slots] copy of the deferred-firsts buffer for the
-        retire-path rescues. Usually free: sync chunk processing caches
-        the copy that rode the packed read (``fresh_firsts``). When stale
-        (an install rewrote columns, or defer_sync processing lags), ONE
-        whole-buffer readback refills it — a retire wave that previously
-        paid a [2]-element round trip PER SLOT now pays at most one."""
-        if self._firsts_host is None:
-            # graftlint: ok[host-sync-hot-path] cache-miss refill: ONE whole-buffer read replaces a per-slot round trip (see docstring)
-            self._firsts_host = np.asarray(self._firsts_dev)
-            self._firsts_fetches += 1   # regression guard: per
-            #                             invalidation, never per slot
-        return self._firsts_host
-
-    def _rescue_first(self, state: _Slot, slot: int) -> None:
-        """Deliver a deferred first token for a slot retiring before any
-        packed read harvested it. Reads the BATCHED firsts snapshot —
-        cached in ``_firsts_host``, so a whole retire wave shares one
-        device fetch at most (``firsts_fetches`` counts them; ISSUE 13
-        replaces the old per-slot ``ascontiguousarray`` recompute with
-        direct column indexing)."""
-        state.first_pending = False
-        fp = self._firsts_snapshot()
-        state.tokens.insert(0, int(fp[0, slot]))
-        # 1-element copy: the column slice is strided, .view needs
-        # contiguous bytes — but only 4 of them, not the whole column
-        state.logprobs.insert(
-            0, float(fp[1:2, slot].copy().view(np.float32)[0]))
-        state.first_token_at = time.perf_counter()
-        self.ttft_stats.add(state.first_token_at - state.submitted_at)
+    def _first_read_ended_it(self, slot: int, state: _Slot) -> bool:
+        """A slot about to be retired before its first token was read
+        (the capacity loop met it right after its admission): read the
+        token now. True when that read already finished the request (the
+        token was a stop), so the caller has nothing left to retire."""
+        if state.first_pending:
+            self._read_firsts()
+        return self._slots.get(slot) is not state
 
     def _finish(self, slot: int, reason: str) -> None:
-        state = self._slots.pop(slot)
+        state = self._slots[slot]
+        if self._first_read_ended_it(slot, state):
+            return
+        del self._slots[slot]
         self._stop_slots.discard(slot)
         self._release_slot(slot)
+        self._finish_state(state, reason)
+
+    def _finish_state(self, state: _Slot, reason: str) -> None:
+        """The result of a sequence that holds no slot any more."""
         req = state.request
-        if state.first_pending:
-            # retired before any packed read delivered its deferred first
-            # token (e.g. capacity-retire on the very next step): rescue
-            # it from the batched snapshot — no per-slot round trip
-            self._rescue_first(state, slot)
-            state.stop_cut = find_stop_cut(state.tokens, req)
-        # a finishing slot streams what it still holds at once, ahead of
-        # its result: frames in token order, the final envelope last,
-        # whether or not an earlier chunk's emit is still carried
+        # a finishing sequence streams what it still holds at once, ahead
+        # of its result: frames in token order, the final envelope last
         self._emit_stream(state)
         toks, stopped = trim_at_stops(state.tokens, req)
         if stopped:
@@ -1955,8 +1863,8 @@ class ContinuousEngine:
         spent, or no other sequence is live to free a page."""
         state = self._slots[slot]
         req = state.request
-        if state.first_pending:
-            self._rescue_first(state, slot)
+        if self._first_read_ended_it(slot, state):
+            return True
         total = state.prompt_len + len(state.tokens)
         left = req.max_new_tokens - len(state.tokens)
         if (left < 1 or state.stop_cut >= 0 or total >= self.max_seq_len - 1
@@ -1997,13 +1905,8 @@ class ContinuousEngine:
         cur = int(self._lengths_host[slot])
         if cur >= self.max_seq_len:
             return False                 # model cap: "length" is correct
-        if state.first_pending:
-            # the deferred first token lives only in the device firsts
-            # buffer, which the slot's successor will overwrite — rescue
-            # it now (same batched snapshot as _finish)
-            self._rescue_first(state, slot)
-            state.produced = len(state.tokens)
-            state.stop_cut = find_stop_cut(state.tokens, req)
+        if self._first_read_ended_it(slot, state):
+            return True
         if state.produced >= req.max_new_tokens or state.stop_cut >= 0:
             return False                 # already done — plain finish
         n_pages = self.kv._pages_for(cur)
@@ -2210,19 +2113,19 @@ class ContinuousEngine:
 
     @hot_path
     def step(self) -> int:
-        """One engine iteration: admit, advance one prefill chunk, then one
-        decode chunk. Returns live + mid-prefill slots after the
-        iteration. The order around a decode chunk: dispatch chunk k+1;
-        stream chunk k's tokens for the slots that lived on (carried
-        from the last iteration, now under the running program); block
-        on k+1's packed read; append its tokens and scan for stops; judge
-        (a finishing slot streams its own frames at once); return, with
-        k+1's streaming carried in turn. An iteration that dispatches
-        nothing streams what is carried at once (``flush_stream``). With
-        ``defer_sync``, chunk k's packed output is read after dispatching
-        chunk k+1 (the round trip overlaps device compute); host
-        bookkeeping — finishes, host-side stops, streaming — runs one
-        chunk behind the device."""
+        """One engine iteration, the engine's ONE sequence: hand on the
+        slots of the rows certain to end in the chunk in flight; admit and
+        dispatch prefills (first tokens stay on the device); advance one
+        prefill chunk; dispatch decode chunk k+1; THEN read chunk k's
+        packed output, append, judge, stream; then read the new
+        admissions' first tokens from their prefills' own outputs. All of
+        the host's bookkeeping runs while chunk k+1 is on the device;
+        finishes the host cannot foresee (EOS, stops, a row paused at its
+        grant) and host-side stops are learned one chunk behind the
+        device. Where the page pool cannot back a chunk ahead of the one in
+        flight, the iteration reads chunk k BEFORE it dispatches and
+        decides on current lengths. Returns live + mid-prefill slots after
+        the iteration."""
         # one span over the whole iteration: the admission scan and the
         # capacity loop run before any bracket below opens
         with self._span("engine.step"):
@@ -2230,82 +2133,36 @@ class ContinuousEngine:
 
     @hot_path
     def _step(self) -> int:
+        self._hand_on_foreseen()
         self._try_admit()
         self._advance_chunked()
+        if self._slots:
+            self._steps += 1
+            self._occupancy_sum += len(self._slots)   # batch occupancy metric
+            n_steps = self._reserve_chunk()
+            if n_steps is None:
+                # the pool cannot back a chunk AHEAD of the one in flight:
+                # read that one first and decide on current lengths (its
+                # finishes may free the pages; a swap or a re-prefill
+                # needs the current tokens)
+                self._sync_fallback_iterations += 1
+                self._read_pending()
+                n_steps = self._reserve_chunk()
         if not self._slots:
-            # drop a stale deferred chunk: when processing chunk N frees
-            # the last live slots, the already-dispatched chunk N+1 stays
-            # pending with every snapshot entry no longer current —
-            # processing it would be a no-op, so release its device
-            # buffer and _Slot references here instead of holding them
-            # across an idle period
-            self._pending = None
-            self._ring.clear()
-            self._flush_emit(False)
-            return len(self._prefilling) + len(self._swapped)
-        self._steps += 1
-        self._occupancy_sum += len(self._slots)   # batch occupancy metric
-
-        # capacity: grow every active slot toward a full chunk (two chunks
-        # under defer_sync: the device may already be n_steps past the
-        # host mirror); a slot that can't even fit one more token is
-        # finished (pool pressure or cap)
-        n_steps = self.config.decode_steps_per_call
-        lengths_np = self._lengths_host
-        ahead = 2 * n_steps if self._defer else n_steps
-        retired: List[int] = []
-        for slot in list(self._slots):
-            state = self._slots.get(slot)
-            if state is None:
-                continue                 # finished by a mid-loop flush below
-            cur = int(lengths_np[slot])
-            # a sliding layer's pages the window has wholly passed go back
-            # to their free list first (no-op for a spec without them)
-            self.kv.release_behind_window(slot, cur)
-            cap_tok = self.kv.ensure_capacity(slot, cur + ahead)
-            if (cap_tok <= cur and self._offload is not None
-                    and self._pending is not None):
-                # before preempting under defer_sync, process the deferred
-                # chunk: a swap decision needs CURRENT host state (lengths,
-                # produced, stops), and the flush's finishes may free
-                # enough pages to avoid preempting at all. Earlier slots'
-                # grants already covered the in-flight chunk (ahead =
-                # 2*n_steps), so flushing mid-loop is safe for them.
-                prev, self._pending = self._pending, None
-                self._process_packed(prev)
-                if self._slots.get(slot) is not state:
-                    continue             # the flush finished this slot
-                cur = int(lengths_np[slot])
-                cap_tok = self.kv.ensure_capacity(slot, cur + ahead)
-            if cap_tok <= cur and self._per_layer and self._pending is not None:
-                # a re-prefill decision needs the CURRENT tokens (see the
-                # offload branch above for why flushing mid-loop is safe)
-                prev, self._pending = self._pending, None
-                self._process_packed(prev)
-                if self._slots.get(slot) is not state:
-                    continue
-                cur = int(lengths_np[slot])
-                cap_tok = self.kv.ensure_capacity(slot, cur + ahead)
-            if cap_tok <= cur:
-                # retiring a slot (re-queue, swap, finish) hands its stream
-                # on: what is carried goes out first
-                self._flush_emit(False)
-                if self._per_layer and self._preempt_recompute(slot):
-                    retired.append(slot)       # re-queued, no finish
-                elif self._try_swap_out(slot):
-                    retired.append(slot)       # deactivate, no finish
-                else:
-                    self._capacity_finishes += 1
-                    retired.append(slot)
-                    self._finish(slot, "length")
-            else:
-                n_steps = min(n_steps, cap_tok - cur)
-        self._deactivate_many(retired)
+            # nothing to dispatch. What the chunk in flight still owes
+            # (handed-on rows' last tokens) is read now; with none of
+            # those every row of its snapshot is already finished and it
+            # is dropped unread, device buffer and _Slot references
+            if self._pending is not None and not self._pending.handed_on:
+                self._pending = None
+            self._read_pending()
+            self._read_firsts()
+            return self.n_live
 
         # adaptive chunk length (ISSUE 13): while ANY live slot is
         # streaming, decode in shorter chunks so tokens reach the host
-        # (and the ring poll) every stream_chunk_steps instead of every
-        # full megastep. Pow2-bucketed so the whole run adds at most ONE
+        # every stream_chunk_steps instead of every full megastep.
+        # Pow2-bucketed so the whole run adds at most ONE
         # decode program per (bucket, ctx) pair — the compile-count guard
         # in tests/test_streaming.py audits this. Pure-batch rounds keep
         # the full chunk: the clamp looks at live callbacks, not config.
@@ -2317,16 +2174,10 @@ class ContinuousEngine:
                 n_steps = sub
                 self._stream_clamped_chunks += 1
 
-        if not self._slots or n_steps <= 0:
-            self._flush_emit(False)
-            return (len(self._slots) + len(self._prefilling)
-                    + len(self._swapped))
-
         if self.kv.n_free_slots and not self.n_waiting:
             self._empty_slot_dispatches += 1
         sp = self._dispatch_span("engine.decode.dispatch", steps=n_steps,
                                  live_slots=len(self._slots))
-        t0 = sp.t0
         cap_list = [min(self.kv.slot_capacity(s), self.max_seq_len)
                     if s in self._slots else 0
                     for s in range(self.max_slots)]
@@ -2336,12 +2187,11 @@ class ContinuousEngine:
             # dense working buffer covers the longest LIVE prefix, padded
             # to a pow2 page bucket (one compiled chunk per bucket) — NOT
             # max_pages_per_seq, so short-context rounds read short
-            # buffers. Under defer_sync the mirror is one chunk stale, so
-            # pad by the in-flight chunk's worst-case growth.
+            # buffers. The mirror is as old as the chunk in flight: pad by
+            # its worst-case growth.
             mx = max(int(self._lengths_host[s]) for s in self._slots)
-            if self._defer:
-                mx = min(mx + self.config.decode_steps_per_call,
-                         self.max_seq_len)
+            if self._pending is not None:
+                mx = min(mx + self._pending.n_steps, self.max_seq_len)
             mpb = _next_bucket(-(-mx // self.kv.page_size),
                                self._ctx_page_buckets)
         sampling = SamplingParams(self._temps, self._top_k, self._top_p,
@@ -2362,107 +2212,137 @@ class ContinuousEngine:
         kp, vp, self._lengths, self._last, self._active, self._produced = carry
         self.kv.swap(kp, vp)
         self._decode_chunks += 1
+        # start the packed output's device→host copy: by the time the
+        # NEXT iteration reads it the bytes are usually already host-side
+        start = getattr(packed, "copy_to_host_async", None)
+        if start is not None:
+            try:
+                start()
+            except Exception:   # pragma: no cover - backend quirk
+                pass
         # the chunk is in flight: overlap serving-side batch formation
-        # with the device step (ISSUE 5c) before the blocking read below
+        # with the device step (ISSUE 5c)
         self._run_overlap_hook()
-        # and stream the LAST chunk's tokens (the slots that lived on)
-        # under this one, before blocking on it
-        self._flush_emit(True)
 
         # snapshot at dispatch: packed columns belong to THESE _Slot
         # objects — a slot freed and re-admitted before this chunk is
-        # processed must not have the old chunk's column applied to it
+        # read must not have the old chunk's column applied to it
         snapshot = dict(self._slots)
         self._open_dispatch = (sp, {"program": ("decode", n_steps, mpb),
                                     "rows": len(snapshot),
                                     "n_steps": n_steps})
-        if self._defer:
-            entry = _ChunkEntry(packed, n_steps, snapshot, t0, cap_list,
-                                False)
-            # ring push + async device→host copy: by the time the pump
-            # polls (overlap hook / between steps) the bytes are usually
-            # already host-side and the harvest costs no sync
-            self._ring.append(entry)
-            self._ring_pushes += 1
-            if len(self._ring) > self._ring_high_water:
-                self._ring_high_water = len(self._ring)
-            start = getattr(packed, "copy_to_host_async", None)
-            if start is not None:
-                try:
-                    start()
-                except Exception:   # pragma: no cover - backend quirk
-                    pass
-            prev, self._pending = self._pending, entry
-            if prev is not None:
-                self._process_packed(prev)
-        else:
-            self._process_packed(_ChunkEntry(packed, n_steps, snapshot,
-                                             t0, cap_list, True))
-        self._close_dispatch()      # nothing was read (defer's first chunk)
-        return (len(self._slots) + len(self._prefilling)
-                + len(self._swapped))
+        prev, self._pending = self._pending, _ChunkEntry(
+            packed, n_steps, snapshot, cap_list)
+        if prev is not None:
+            self._process_packed(prev)
+        self._read_firsts()
+        self._close_dispatch()      # nothing was read (the first chunk)
+        return self.n_live
 
-    def poll_stream(self) -> int:
-        """Drain ready stream-ring entries' TOKEN halves without blocking
-        (ISSUE 13). The serving pump calls this inside the measured host
-        bubble — the overlap hook right after dispatch and the gap
-        between steps — so streamed tokens reach consumers as soon as
-        the async copy lands instead of one full chunk later at the
-        deferred flush. Control (pause/finish/revive) stays with the
-        flush: ``_harvest_chunk`` is idempotent, so the later
-        ``_process_packed`` call skips straight to judging. Returns the
-        number of streamed frames delivered."""
-        if not self._ring:
-            return 0
-        self._ring_polls += 1
-        frames = 0
-        with self._span("engine.poll_stream"):
-            while self._ring:
-                entry = self._ring[0]
-                if entry.harvested:
-                    self._ring.popleft()
-                    continue
-                if not entry.ready():
-                    break
-                self._ring_ready_polls += 1
-                self._harvest_chunk(entry)
-                for slot, state in entry.snapshot.items():
-                    if self._slots.get(slot) is state:
-                        frames += self._emit_stream(state)
-        return frames
-
-    def _harvest_chunk(self, entry: _ChunkEntry) -> None:
-        """TOKEN half of chunk processing: the blocking host read (a
-        no-op wait when the ring's async copy already landed), the
-        length-mirror refresh, token and logprob appends and the
-        incremental stop scan — what the judgments and the next dispatch
-        depend on. Streaming is NOT here: ``_judge_packed`` /
-        ``_carry_emit`` follow. Only a slot's FIRST frame goes out at
-        once (one a request: ``first_token_at`` stays the delivery
-        time). Idempotent — guarded by ``entry.harvested`` — so the ring
-        poll and the deferred flush compose. Snapshot-identity rules
-        match ``_process_packed``: columns apply only to the exact
-        ``_Slot`` objects live at dispatch."""
-        if entry.harvested:
+    def _hand_on_foreseen(self) -> None:
+        """Take out of the slot map every row that is CERTAIN to be
+        inactive when the chunk in flight ends, and free its slot, pages
+        and recurrent state row for a successor now: device order makes it
+        safe (whatever takes them is queued behind that chunk). The host
+        is one chunk behind, but a live row emits every step, so it knows
+        which rows reach ``max_new_tokens`` (or the model's
+        ``max_seq_len``) inside the chunk — provided the capacity the chunk
+        ran with covers those steps: a row the device paused at its grant
+        has produced less than the host reckons, and is learned at the
+        read. A row that stops sooner (EOS, a stop) ends in the chunk all
+        the same. The chunk's own read delivers the old request's last
+        tokens and its result from the entry's snapshot
+        (``_judge_packed``)."""
+        entry = self._pending
+        if entry is None:
             return
-        entry.harvested = True
-        try:                      # pop self from the ring, wherever it is
-            self._ring.remove(entry)
-        except ValueError:
-            pass
+        for slot, state in entry.snapshot.items():
+            if (self._slots.get(slot) is not state or slot in entry.idle
+                    or state.first_pending):
+                continue
+            start = int(self._lengths_host[slot])    # as the chunk began
+            left = state.request.max_new_tokens - state.produced
+            cap = entry.caps[slot]
+            if ((left <= entry.n_steps and start + left <= cap)
+                    or (start + entry.n_steps >= cap >= self.max_seq_len)):
+                del self._slots[slot]
+                self._stop_slots.discard(slot)
+                self._release_slot(slot)
+                entry.handed_on.add(slot)
+
+    def _reserve_chunk(self) -> Optional[int]:
+        """The capacity loop: grow every live slot to hold one more chunk
+        BEYOND what the chunk in flight can bring it to (the host's
+        lengths are that chunk's starting ones), and one row more, so that
+        a grant never ends exactly where a chunk does (the device pauses a
+        row AT its grant, and under a chunk in flight a pause costs the row
+        the next chunk). Returns the steps the next chunk may run, or None
+        when a slot's want cannot be met while a chunk is in flight: the
+        caller reads that chunk and calls again. With none in flight,
+        lengths are current and a slot that cannot fit one more token is
+        re-queued, swapped out or finished (pool pressure or cap)."""
+        n_steps = full = self.config.decode_steps_per_call
+        pending = self._pending
+        retired: List[int] = []
+        for slot in list(self._slots):
+            state = self._slots.get(slot)
+            if state is None:
+                continue       # a first token read in this loop ended it
+            cur = int(self._lengths_host[slot])
+            # a sliding layer's pages the window has wholly passed go back
+            # to their free list first (no-op for a spec without them)
+            self.kv.release_behind_window(slot, cur)
+            if (pending is not None and slot not in pending.idle
+                    and pending.snapshot.get(slot) is state):
+                # the most it can hold when the chunk in flight ends
+                cur = min(cur + pending.n_steps, pending.caps[slot])
+            want = min(cur + full + 1, self.max_seq_len)
+            cap_tok = self.kv.ensure_capacity(slot, want)
+            if pending is not None and (cap_tok < want or cap_tok <= cur):
+                return None
+            if cap_tok <= cur:
+                if self._per_layer and self._preempt_recompute(slot):
+                    retired.append(slot)       # re-queued, no finish
+                elif self._try_swap_out(slot):
+                    retired.append(slot)       # deactivate, no finish
+                else:
+                    self._capacity_finishes += 1
+                    retired.append(slot)
+                    self._finish(slot, "length")
+            else:
+                n_steps = min(n_steps, cap_tok - cur)
+        self._deactivate_many(retired)
+        return n_steps
+
+    def _read_pending(self) -> None:
+        """Read and process the chunk in flight now, if there is one."""
+        prev, self._pending = self._pending, None
+        if prev is not None:
+            self._process_packed(prev)
+
+    def _harvest_chunk(self, entry: _ChunkEntry
+                       ) -> Tuple[np.ndarray, Dict[int, bool], bool]:
+        """TOKEN half of chunk processing: the blocking host read (short
+        or none when the chunk ended while the host was busy: its async
+        copy has landed), the length-mirror refresh, token and logprob
+        appends and the incremental stop scan. Returns the packed rows,
+        slot -> "its row emitted in this chunk", and whether a slot with a
+        stream callback was met. Snapshot-identity rule: a column applies
+        only to the exact ``_Slot`` object live at dispatch, still in its
+        slot or handed on in this entry."""
         n_steps = entry.n_steps
         wait = self._span("engine.harvest.wait")     # the blocking read alone
-        t_read = wait.t0
-        # graftlint: ok[host-sync-hot-path] THE designed sync point: ONE packed read per decode chunk carries tokens+lps+active+lengths+firsts
+        # graftlint: ok[host-sync-hot-path] THE designed sync point: ONE packed read per decode chunk carries tokens+lps+active+lengths
         packed_np = np.asarray(entry.packed)   # ONE blocking read per chunk
-        wait.close()
+        # the residue the overlap failed to hide; near zero means the
+        # host came back to a chunk that had already ended
+        waited = wait.close() - wait.t0
+        self.chunk_stats.add(waited)
+        self._harvest_wait_s += waited
         self._close_dispatch()
-        entry.host = packed_np
         toks_np = packed_np[:n_steps]                    # [n_steps, max_slots]
         lps_np = packed_np[n_steps:2 * n_steps].view(np.float32)
         lengths = packed_np[2 * n_steps + 1].tolist()
-        firsts_tok = packed_np[2 * n_steps + 2]          # deferred admissions
-        firsts_lp = packed_np[2 * n_steps + 3].view(np.float32)
         self._decode_steps += n_steps
         if self.spec.layer_kinds:
             moe = packed_np[2 * n_steps + 4:, 0]
@@ -2483,17 +2363,6 @@ class ContinuousEngine:
             while self._prefill_moe:
                 # graftlint: ok[host-sync-hot-path] 3 ints of a program that ended before the chunk just read
                 self._moe_counts += np.asarray(self._prefill_moe.pop())
-        if entry.fresh_firsts:
-            # the whole firsts buffer rode the packed read: retire-path
-            # rescues (_finish/_try_swap_out) read this copy instead of
-            # paying a per-slot device round trip (ISSUE 5 satellite)
-            self._firsts_host = packed_np[2 * n_steps + 2: 2 * n_steps + 4]
-        # sync: dispatch-to-ready per chunk. defer: dispatch time would
-        # span a whole unrelated host step (samples overlapping wall
-        # clock), so record the actual blocking WAIT — the residue the
-        # overlap failed to hide; near zero means the overlap is working
-        self.chunk_stats.add(time.perf_counter()
-                             - (t_read if self._defer else entry.t0))
 
         book = self._span("engine.harvest.book")  # mirror, appends, stops
         # a row emits from step 0 until it goes inactive and never again
@@ -2524,31 +2393,26 @@ class ContinuousEngine:
                 self._state_rows_updated += int(counts_np.sum())
         tok_cols = toks_np.T.tolist()
         lp_cols = lps_np.T.tolist()
+        progressed: Dict[int, bool] = {}
+        streamed = False
         for slot, state in entry.snapshot.items():
-            if self._slots.get(slot) is not state:
-                continue                 # finished earlier (or slot reused)
-            self._lengths_host[slot] = lengths[slot]
-            n = counts[slot]
-            # no progress == the slot was device-INACTIVE when this chunk
-            # was dispatched (an active slot always emits >=1 token per
-            # chunk: the capacity loop guarantees cap > length at
-            # dispatch). Happens under defer_sync when a capacity-paused
-            # slot's revive lands after the next chunk already launched —
-            # that chunk's harvest must not re-judge the slot (its caps
-            # row is from AFTER the pool grew, so the pause test would
-            # misread the pause as a finished "length"). Stashed on the
-            # entry: control may run after further slot mutation.
-            entry.progressed[slot] = bool(state.first_pending or n)
-            prev = len(state.tokens)           # first index not yet stop-checked
             if state.first_pending:
-                # harvest the deferred first token (prev stays 0: the stop
-                # scan below must cover it). TTFT is stamped at DELIVERY —
-                # the honest consumer-visible time under deferral.
-                state.first_pending = False
-                state.tokens.append(int(firsts_tok[slot]))
-                state.logprobs.append(float(firsts_lp[slot]))
-                state.first_token_at = time.perf_counter()
-                self.ttft_stats.add(state.first_token_at - state.submitted_at)
+                self._read_firsts()      # its first token goes first
+            handed = slot in entry.handed_on
+            if not handed:
+                if self._slots.get(slot) is not state:
+                    continue             # finished earlier (or slot reused)
+                self._lengths_host[slot] = lengths[slot]
+            n = counts[slot]
+            # no progress == the row was device-INACTIVE when this chunk
+            # was dispatched (an active row always emits >=1 token a
+            # chunk: the capacity loop guarantees cap > length at
+            # dispatch): a row paused at its grant whose revive landed
+            # after this chunk was sent. Its judgment was the pausing
+            # chunk's; this chunk's caps row is from AFTER the pool grew
+            # and would misread the pause as a finished "length".
+            progressed[slot] = bool(n)
+            prev = len(state.tokens)           # first index not yet stop-checked
             state.tokens += tok_cols[slot][:n]
             state.logprobs += lp_cols[slot][:n]
             state.produced = len(state.tokens)
@@ -2559,78 +2423,55 @@ class ContinuousEngine:
                 # scan only the new window: O(total) stop detection across
                 # a generation, shared with the streaming emit
                 state.stop_cut = find_stop_cut(state.tokens, req, start=prev)
-            if state.on_tokens is not None:
-                entry.streamed = True
-                if not state.streamed:
-                    self._emit_stream(state)     # the request's first frame
+            streamed = streamed or state.on_tokens is not None
         book.close()
+        return packed_np, progressed, streamed
 
     def _process_packed(self, entry: _ChunkEntry) -> None:
-        """Chunk processing after the dispatch: the token half (when the
-        ring poll has not already run it), then the CONTROL half — finish
-        retired slots, retire host-side stops, revive capacity-paused
-        slots — then the streaming of the slots that live on, carried to
-        the next dispatch (``_carry_emit``). ``entry.caps`` is the
-        per-slot token-capacity array the chunk was dispatched with —
-        needed to tell a PAUSED slot (device stopped at the chunk's
-        capacity grant) from a finished one. ``entry.fresh_firsts`` marks
-        SYNC call sites, where no install can have landed between
-        dispatch and the read — the packed firsts rows are then current
-        and refresh the host cache for free (deferred processing runs a
-        chunk behind admissions, so its rows may be stale)."""
-        self._harvest_chunk(entry)      # ends the dispatch bracket
+        """Process a chunk's packed output, the next chunk (if any)
+        already on the device: the token half, then the CONTROL half —
+        finish what ended, retire host-side stops, revive capacity-paused
+        slots — then the streaming of the slots that live on."""
+        packed_np, progressed, streamed = self._harvest_chunk(entry)
         with self._span("engine.process_packed"):
-            self._judge_packed(entry)
-            self._carry_emit(entry)
+            self._judge_packed(entry, packed_np, progressed)
+            if streamed:
+                self._emit_chunk(entry)
 
-    def _carry_emit(self, entry: _ChunkEntry) -> None:
-        """Hand the entry's streaming to the next dispatch: the slots of
-        its snapshot that live on, have a stream callback and hold tokens
-        not yet streamed. With none of them the chunk's streaming already
-        happened, unhidden (first frames, finishing slots): it counts as
-        flushed. Under ``defer_sync`` the next chunk is already on the
-        device, so the emit runs here and now, under it."""
-        if not entry.streamed:
-            return
-        carried = [st for slot, st in entry.snapshot.items()
-                   if st.on_tokens is not None
-                   and self._slots.get(slot) is st
-                   and len(st.tokens) > st.streamed]
-        if not carried:
-            self._emit_flushed_chunks += 1
-            return
-        self._carried = carried
-        if self._defer:
-            self._flush_emit(True)
-
-    def _flush_emit(self, under_dispatch: bool) -> None:
-        """Stream what is carried (nothing, for engines nobody streams
-        from). ``under_dispatch``: a program dispatched since the carry
-        is running or queued on the device, so the callbacks, and the
-        event-loop thread they wake, run in its shadow."""
-        carried = self._carried
-        if carried is None:
-            return
-        self._carried = None
-        if under_dispatch:
+    def _emit_chunk(self, entry: _ChunkEntry) -> None:
+        """Stream the entry's fresh tokens for the slots that live on
+        (a finishing one streamed its own from ``_finish_state``). The
+        chunk counts once: as carried when the callbacks, and the
+        event-loop thread they wake, run in the shadow of a chunk in
+        flight, else as flushed (also when only finishing slots had
+        anything to stream)."""
+        live = [st for slot, st in entry.snapshot.items()
+                if st.on_tokens is not None
+                and self._slots.get(slot) is st
+                and len(st.tokens) > st.streamed]
+        hidden = bool(live) and self._pending is not None
+        if hidden:
             self._emit_carried_chunks += 1
         else:
             self._emit_flushed_chunks += 1
-        with self._span("engine.emit.carried" if under_dispatch
-                        else "engine.emit.flushed", slots=len(carried)):
-            for state in carried:
-                self._emit_stream(state)
+        if live:
+            with self._span("engine.emit.carried" if hidden
+                            else "engine.emit.flushed", slots=len(live)):
+                for state in live:
+                    self._emit_stream(state)
 
     def flush_stream(self) -> None:
-        """Stream every token the engine holds back for its next dispatch,
-        now. For callers that stop driving ``step()`` with slots live (the
-        pump on shutdown); ``step()`` itself never returns holding tokens
-        past an iteration that dispatched nothing."""
-        self._flush_emit(False)
+        """Deliver every token the device has produced and the host has
+        not read: the chunk in flight (a blocking read of at most one
+        chunk; its finishes among them) and the first tokens of prefills
+        not read yet. For callers that stop driving ``step()`` with
+        slots live (the pump on shutdown)."""
+        self._read_pending()
+        self._read_firsts()
 
-    def _judge_packed(self, entry: _ChunkEntry) -> None:
+    def _judge_packed(self, entry: _ChunkEntry, packed_np: np.ndarray,
+                      progressed: Dict[int, bool]) -> None:
         """The judgments of ``_process_packed``, on a harvested entry."""
-        packed_np = entry.host
         n_steps = entry.n_steps
         caps = entry.caps
         active_np = packed_np[2 * n_steps].astype(bool)
@@ -2639,46 +2480,53 @@ class ContinuousEngine:
         stop_retired: List[int] = []
         revived: List[int] = []
         for slot, state in entry.snapshot.items():
+            if slot in entry.handed_on:
+                # its slot went on while the chunk ran: the result leaves
+                # here (_finish_state upgrades the reason to "stop" when a
+                # stop condition is inside the cap)
+                assert not active_np[slot], "a handed-on row outlived its chunk"
+                self._finish_state(state, "length")
+                continue
             if self._slots.get(slot) is not state:
                 continue                 # finished earlier (or slot reused)
-            progressed = entry.progressed.get(slot, False)
             req = state.request
             if not active_np[slot]:
-                if not progressed:
+                if not progressed.get(slot, False):
                     # inactive for the WHOLE chunk: pause/finish was (or
                     # will be) decided by the chunk that actually stopped
                     # it; nothing to judge here
                     pass
-                elif (caps is not None
-                        and state.produced < req.max_new_tokens
+                elif (state.produced < req.max_new_tokens
                         and state.stop_cut < 0
                         and int(lengths_row[slot]) >= caps[slot]
                         and caps[slot] < self.max_seq_len):
-                    # the device stopped at the chunk's CAPACITY grant
-                    # (ensure_capacity landed exactly on a page boundary,
-                    # e.g. prompt+chunk = one page), not at a budget or
-                    # stop condition: the slot is paused, not finished.
-                    # Revive it — next step's capacity loop grows its
-                    # pages (or retires it for real if the pool is dry).
-                    # Without this, a request whose prompt+chunk filled
-                    # page 1 finished early as "length" with budget left.
-                    # A slot already granted max_seq_len is NOT paused —
-                    # no revive can grow it past the model cap, so it
-                    # falls through to the "length" finish below instead
-                    # of burning one more dispatch to learn the same.
+                    # the device stopped at the chunk's CAPACITY grant, not
+                    # at a budget or stop condition: the slot is paused,
+                    # not finished. Revive it — the next capacity loop
+                    # grows its pages (or retires it for real if the pool
+                    # is dry). A slot already granted max_seq_len is NOT
+                    # paused — no revive can grow it past the model cap,
+                    # so it falls through to the "length" finish below
+                    # instead of burning one more dispatch to learn the
+                    # same.
                     revived.append(slot)
                 else:
                     # _finish re-trims and upgrades the reason to "stop"
                     # when a stop condition is inside the cap
+                    self._finishes_learned_late += 1
                     self._finish(slot, "length")
             elif ((req.stop_ids or req.stop_sequences)
                   and 0 <= state.stop_cut <= req.max_new_tokens):
                 # host-side stops (multi-id / multi-token): the device loop
                 # only knows eos_id, so retire the slot here
+                self._finishes_learned_late += 1
                 stop_retired.append(slot)
                 self._finish(slot, "stop")
         self._deactivate_many(stop_retired)
         self._set_active(revived, True)
+        if self._pending is not None:
+            # the chunk in flight was sent before the revive: they sit it out
+            self._pending.idle.update(revived)
 
     def _deactivate_many(self, slots: List[int]) -> None:
         """Clear retired slots' device active flags in ONE dispatch — a
@@ -2746,14 +2594,20 @@ class ContinuousEngine:
 
     def abort_all(self) -> int:
         """Drop every waiting and live request (no results produced) and
-        return their pages to the pool. Recovery hook for the pump when a
-        decode step fails irrecoverably."""
+        return their pages to the pool; returns how many. Recovery hook
+        for the pump when a decode step fails irrecoverably. What the
+        device had finished before is read first, where it still can be."""
+        try:
+            # tokens the device has already produced are delivered: a
+            # handed-on slot's last ones and its result among them
+            self.flush_stream()
+        except Exception:
+            logger.exception("abort_all: the chunk in flight was lost")
+            self._pending = None
+            self._first_reads.clear()
         n = (len(self._waiting) + len(self._waiting_prefilled)
              + len(self._slots) + len(self._prefilling)
              + len(self._swapped))
-        self._pending = None            # drop an unprocessed deferred chunk
-        self._ring.clear()              # and its stream-ring entry
-        self._flush_emit(False)         # tokens already read are delivered
         self._open_dispatch = None      # the failed step's bracket
         self._waiting.clear()
         self._waiting_prefilled.clear()
@@ -3012,11 +2866,15 @@ class ContinuousEngine:
             "compiles_after_warmup": self._compiles_after_warmup(),
             "prefilling_slots": len(self._prefilling),
             "chunked_admissions": self._chunked_admissions,
-            "deferred_admissions": self._deferred_admissions,
             # does a freed slot find its successor here (see __init__)
             "admissions": self._admissions,
             "admissions_from_queue": self._admissions_from_queue,
             "empty_slot_dispatches": self._empty_slot_dispatches,
+            # how often the one sequence engages (see __init__)
+            "admissions_ahead": self._admissions_ahead,
+            "finishes_learned_late": self._finishes_learned_late,
+            "harvest_wait_s_total": self._harvest_wait_s,
+            "sync_fallback_iterations": self._sync_fallback_iterations,
             # serving metrics the reference's mock could never know
             # (SURVEY.md §5): per-request TTFT from submit, and mean decode
             # batch occupancy (live slots / max_slots per engine step)
@@ -3029,21 +2887,14 @@ class ContinuousEngine:
             "host_bubble_frac": (
                 self._host_gap_s / (self._dispatch_s + self._host_gap_s)
                 if (self._dispatch_s + self._host_gap_s) > 0 else 0.0),
-            # sub-chunk streaming (ISSUE 13): ring traffic + adaptive
-            # chunk engagements, and the firsts-buffer fetch count the
-            # retire-rescue regression test pins (one per invalidation)
-            "stream_ring_pushes": self._ring_pushes,
-            "stream_ring_polls": self._ring_polls,
-            "stream_ring_ready_polls": self._ring_ready_polls,
-            "stream_ring_depth": self._ring_high_water,
+            # chunks shortened because a live slot streams (ISSUE 13)
             "stream_clamped_chunks": self._stream_clamped_chunks,
             # decode chunks with a streamed slot, by where their tokens'
-            # callbacks ran: under a later dispatch (the carried emit),
-            # or with no program to hide them (first frames and finishing
-            # slots only, an idle engine, abort, shutdown)
+            # callbacks ran: under a chunk in flight, or with no program
+            # to hide them (only finishing slots had any, an idle engine,
+            # a sync fallback, abort, shutdown)
             "emit_carried_chunks": self._emit_carried_chunks,
             "emit_flushed_chunks": self._emit_flushed_chunks,
-            "firsts_fetches": self._firsts_fetches,
             "ttft": self.ttft_stats.snapshot(),
             # submit -> slot held and prefill dispatched
             "queue_wait": self.queue_wait_stats.snapshot(),

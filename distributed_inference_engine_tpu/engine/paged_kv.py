@@ -366,6 +366,13 @@ class PagedKVCache:
         pages = self._slot_pages[slot]
         need = self._pages_for(target) - len(pages)
         take = min(max(need, 0), self.available_pages)
+        if self.window:
+            # a page of the full layers comes with one of the window
+            # layers, as far as their pool can spare them: it backs every
+            # slot's window and one chunk, and a slot granted a chunk AHEAD
+            # of the one in flight (its pages behind the window freed a
+            # chunk late) may ask for a page more than that
+            take = min(take, len(self._wfree))
         if take > 0:
             fresh = self._take_free(take)
             assert fresh is not None
@@ -375,8 +382,6 @@ class PagedKVCache:
         cap = min(len(pages) * self.page_size, self.max_seq_len)
         self._slot_len[slot] = max(self._slot_len[slot], min(target, cap))
         if self.window:
-            # the window layers' pages for the same rows ahead; the pool
-            # backs every slot's bound, so it cannot run dry
             held = self._slot_wpages[slot]
             self._hold_window(slot, range(max(held, default=len(pages) - 1) + 1,
                                           len(pages)))
@@ -652,11 +657,14 @@ class PagedKVCache:
     @property
     def page_table(self) -> jnp.ndarray:
         """Device copy of the table; re-uploaded only after host changes.
-        ``jnp.array`` (not ``asarray``): on CPU backends asarray may
-        zero-copy-alias the mutable host table, making the "snapshot" track
-        live host mutations."""
+        From a private host copy that nothing else holds: the engine frees
+        and re-issues slots while the chunk that took this snapshot is
+        still in flight, and a snapshot must not track those mutations
+        (``asarray`` of the table itself may zero-copy-alias it on a CPU
+        backend; ``jnp.array`` of it raced with them under async dispatch,
+        jax 0.9.0, PR 43: the chunk read a zeroed row)."""
         if self._table_dirty or self._table_dev is None:
-            self._table_dev = jnp.array(self._table)
+            self._table_dev = jnp.asarray(self._table.copy())
             self._table_dirty = False
         return self._table_dev
 
@@ -667,7 +675,7 @@ class PagedKVCache:
         the window layers' page table as the host has it now)."""
         if self._wtable_dirty:
             self.state = dict(self.state,
-                              window_table=jnp.array(self._wtable))
+                              window_table=jnp.asarray(self._wtable.copy()))
             self._wtable_dirty = False
         return self.k_pages, (self.v_pages if self.state is None
                               else self.state)

@@ -60,14 +60,20 @@ ENGINE_TABLE = [
      "Admissions that reused cached prefix KV pages"),
     ("chunked_admissions", "engine_chunked_admissions", "c",
      "Admissions that prefill in chunks"),
-    ("deferred_admissions", "engine_deferred_admissions", "c",
-     "Admissions whose first-token read was deferred"),
     ("admissions", "engine_admissions", "c",
      "Requests given a slot"),
     ("admissions_from_queue", "engine_admissions_from_queue", "c",
      "Admissions whose request was already queued when its slot was freed"),
     ("empty_slot_dispatches", "engine_empty_slot_dispatches", "c",
      "Decode dispatches sent with a free slot and nothing queued"),
+    ("admissions_ahead", "engine_admissions_ahead", "c",
+     "Successors prefilled behind the chunk their predecessor ended in"),
+    ("finishes_learned_late", "engine_finishes_learned_late", "c",
+     "Finishes only a read could tell (EOS, stop, grant): one chunk of one slot each"),
+    ("harvest_wait_s_total", "engine_harvest_wait_seconds", "c",
+     "Seconds blocked reading decode chunks' packed outputs"),
+    ("sync_fallback_iterations", "engine_sync_fallback_iterations", "c",
+     "Iterations that read the chunk in flight before dispatching (pool not backed a chunk ahead)"),
     ("rounds", "engine_spec_rounds", "c",
      "Speculative target+draft verification rounds"),
     ("waiting", "engine_waiting", "g", "Requests in the waiting queue"),
@@ -87,29 +93,20 @@ ENGINE_TABLE = [
      "Accepted / proposed draft tokens"),
     ("tokens_per_round", "engine_spec_tokens_per_round", "g",
      "Mean tokens emitted per speculative round"),
-    ("stream_ring_pushes", "engine_stream_ring_pushes", "c",
-     "Decode chunks pushed onto the device->host token ring"),
-    ("stream_ring_polls", "engine_stream_ring_polls", "c",
-     "poll_stream calls that found ring entries in flight"),
-    ("stream_ring_ready_polls", "engine_stream_ring_ready_polls", "c",
-     "Ring entries harvested early by a host-bubble poll"),
-    ("stream_ring_depth", "engine_stream_ring_depth", "g",
-     "High-water depth of the device->host token ring"),
     ("stream_clamped_chunks", "engine_stream_clamped_chunks", "c",
      "Decode chunks shortened by the adaptive streaming clamp"),
     ("emit_carried_chunks", "engine_emit_carried_chunks", "c",
-     "Decode chunks whose tokens were streamed under a later dispatch"),
+     "Decode chunks whose tokens were streamed under a chunk in flight"),
     ("emit_flushed_chunks", "engine_emit_flushed_chunks", "c",
      "Decode chunks whose tokens were streamed with no program to hide it"),
-    ("firsts_fetches", "engine_firsts_fetches", "c",
-     "Whole-buffer deferred-firsts readbacks (one per invalidation)"),
     ("ttft", "engine_ttft_seconds", "h",
      "Time to first token (continuous: from submit, incl. queue wait)"),
     ("queue_wait", "engine_queue_wait_seconds", "h",
      "Submit to admitted: the wait for a slot, until the prefill is dispatched"),
-    ("prefill", "engine_prefill_seconds", "h", "Prefill dispatch wall time"),
+    ("prefill", "engine_prefill_seconds", "h",
+     "Prefill dispatched to its first tokens on the host"),
     ("decode_chunk", "engine_decode_chunk_seconds", "h",
-     "Decode-chunk wall time (defer_sync: residual blocking wait)"),
+     "Blocking residue of a decode chunk's packed read"),
     ("decode", "engine_decode_seconds", "h",
      "Decode wall time per generate call (static/speculative engines)"),
 ]

@@ -57,11 +57,6 @@ class EnginePump:
         self.idle_wait_s = idle_wait_s          # safety-net poll when idle
         self.error_backoff_s = error_backoff_s  # pause after a failed step
         self._overlap_admitted = 0
-        self._stream_frames_polled = 0
-        # sub-chunk streaming (ISSUE 13): harvest ready token-ring
-        # entries inside the measured host bubble. Engine-thread-only by
-        # the same argument as the overlap hook below.
-        self._poll_stream = getattr(engine, "poll_stream", None)
         if overlap_forms and hasattr(engine, "overlap_hook"):
             # batch-formation overlap (ISSUE 5c): the engine calls this
             # right after dispatching a decode chunk, while the
@@ -73,11 +68,6 @@ class EnginePump:
             # engine via submit()/submit_prefilled() (enqueue-only).
             def _overlap() -> None:
                 self._overlap_admitted += self._drain_inbox()
-                # the previous chunk's async device→host copy has had a
-                # full chunk of device time to land: drain it now so
-                # streaming consumers see its tokens one chunk early
-                if self._poll_stream is not None:
-                    self._stream_frames_polled += self._poll_stream()
 
             engine.overlap_hook = _overlap
         # (request, optional handoff, optional stream cb, future, loop,
@@ -223,10 +213,6 @@ class EnginePump:
                         with self._span("pump.resolve", results=len(finished)):
                             for res in finished:
                                 self._resolve(res)
-                    # between-steps half of the host bubble: the chunk
-                    # dispatched by step() may already be host-side
-                    if self._poll_stream is not None:
-                        self._stream_frames_polled += self._poll_stream()
             except Exception as e:  # engine failure fans to all in-flight
                 self._step_errors += 1
                 logger.exception("engine pump step failed")
@@ -246,11 +232,18 @@ class EnginePump:
                 with self._span("pump.idle_wait"):
                     self._wake.wait(timeout=self.idle_wait_s)
                 self._wake.clear()
-        # tokens the engine read and holds for its next dispatch reach
-        # their streams before the futures fail: no frame is lost
+        # tokens the device has produced and the engine has not read (the
+        # chunk in flight) reach their streams, and what finished in them
+        # its future, before the rest fail: no frame is lost
         flush = getattr(self.engine, "flush_stream", None)
         if flush is not None:
-            flush()             # a failing callback is the engine's to log
+            try:
+                flush()         # a failing callback is the engine's to log
+                for res in self.engine.drain_finished():
+                    self._resolve(res)
+            # graftlint: ok[swallowed-transport-error] engine-local read of the chunk in flight at shutdown; no peer involved, and every future left is failed right below
+            except Exception:
+                logger.exception("engine pump: final flush failed")
         # fail anything still in flight so no caller hangs on shutdown
         self._fail_all(RuntimeError("engine pump shut down"))
         logger.info("engine pump stopped")
@@ -357,8 +350,5 @@ class EnginePump:
             # requests admitted INSIDE a device step's shadow via the
             # engine's overlap hook (vs the top-of-loop drain)
             "overlap_admitted": self._overlap_admitted,
-            # streamed frames delivered by host-bubble ring polls rather
-            # than the deferred flush (ISSUE 13)
-            "stream_frames_polled": self._stream_frames_polled,
             "engine": self.engine.get_metrics(),
         }

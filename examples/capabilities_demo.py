@@ -221,9 +221,9 @@ def demo_round2_compositions() -> None:
 
 def demo_round3_serving() -> None:
     """Round-3 serving features: overload shedding (typed per-request
-    outcomes), defer_sync readback overlap (token parity), and the
-    prefix-aware delta KV handoff between disaggregated pools."""
-    banner("round 3: overload shedding / defer_sync / delta handoff")
+    outcomes) and the prefix-aware delta KV handoff between disaggregated
+    pools."""
+    banner("round 3: overload shedding / delta handoff")
     from distributed_inference_engine_tpu.engine.disagg import (
         PrefillEngine,
         trim_handoff,
@@ -251,17 +251,6 @@ def demo_round3_serving() -> None:
           f"{served} accepted+served, {len(shed)} refused "
           f"({shed[0].metadata['overload_reason']}) — per-request "
           "outcomes, accepted siblings keep their generations")
-
-    # ---- defer_sync: readback overlaps the next chunk; tokens identical
-    d = ContinuousEngine(spec, params=params,
-                         config=cfg(num_pages=16, defer_sync=True))
-    sync = ContinuousEngine(spec, params=params, config=cfg(num_pages=16))
-    req = lambda: [GenerationRequest(prompt=[5, 6, 7], max_new_tokens=8,
-                                     request_id="d")]
-    t_defer = d.generate(req())[0].tokens
-    t_sync = sync.generate(req())[0].tokens
-    assert t_defer == t_sync
-    print(f"  defer_sync tokens match synchronous: {t_defer}")
 
     # ---- prefix-aware delta handoff (disaggregated pools, in-process)
     pe = PrefillEngine(spec, params=params, config=cfg())

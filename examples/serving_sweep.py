@@ -177,10 +177,6 @@ def main():
         os.environ.get("BENCH_MAX_WAITING", str(bench.BATCH)))
     engine.config.queue_deadline_s = float(
         os.environ.get("BENCH_DEADLINE_S", "8"))
-    # BENCH_DEFER_ADMIT=0: synchronous first-token reads at admission —
-    # TTFT drops ~a chunk at some goodput cost (the latency-SLO knee)
-    if os.environ.get("BENCH_DEFER_ADMIT", "") == "0":
-        engine.config.defer_admission = False
     log(f"engine init ({bench.MODEL}, bs{bench.BATCH}, "
         f"prompt={bench.PROMPT_LEN}+{bench.NEW_TOKENS}, "
         f"quant={bench.QUANT_BITS if bench.QUANT else 0}, "

@@ -162,5 +162,9 @@ def test_engine_field_is_read(field):
         f"EngineConfig.{field} is read by nothing in the package")
 
 
-def test_engine_config_has_twenty_fields():
-    assert len(dataclasses.fields(EngineConfig)) == 20
+def test_engine_config_has_eighteen_fields():
+    """PR 43 retired ``defer_sync`` and ``defer_admission``: the engine has
+    one dispatch-and-harvest sequence and no field selects another."""
+    names = {f.name for f in dataclasses.fields(EngineConfig)}
+    assert len(names) == 18
+    assert not names & {"defer_sync", "defer_admission"}

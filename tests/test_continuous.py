@@ -200,41 +200,48 @@ def test_serving_metrics_ttft_and_occupancy():
     assert ttfts[-1] > ttfts[0]
 
 
-def test_defer_sync_matches_synchronous_output():
-    """defer_sync overlaps the packed readback with the next chunk's
-    execution; outputs must be token-for-token the synchronous engine's,
-    including mid-flight admissions and host-side stop sequences (which
-    defer detects one chunk late but trims identically)."""
+def _copy(r, **over):
+    kw = dict(prompt=list(r.prompt), max_new_tokens=r.max_new_tokens,
+              temperature=0.0, eos_id=r.eos_id, stop_ids=r.stop_ids,
+              stop_sequences=r.stop_sequences, request_id=r.request_id)
+    kw.update(over)
+    return GenerationRequest(**kw)
+
+
+def _static(params, reqs, **cfg):
+    """(tokens, reason) a request from the static engine: the reference
+    the one sequence is held to."""
+    static = Engine(SPEC, params=params, config=_cfg(**cfg), seed=0)
+    return {r.request_id: (r.tokens, r.finish_reason)
+            for i in range(0, len(reqs), 4)
+            for r in static.generate([_copy(r) for r in reqs[i: i + 4]])}
+
+
+def test_one_sequence_matches_the_static_engine():
+    """Chunk k is read after chunk k+1 is dispatched; outputs must be
+    token-for-token the static engine's, including a mid-flight admission
+    and a host-side stop sequence (found one chunk late, trimmed
+    identically)."""
     rs = np.random.RandomState(7)
-    # fully backed pool (defer requirement): 4 slots x 8 pages
-    cfg = lambda **kw: _cfg(num_pages=32, **kw)
-    sync = ContinuousEngine(SPEC, config=cfg(), seed=0)
-    defer = ContinuousEngine(SPEC, params=sync.params,
-                             config=cfg(defer_sync=True), seed=0)
+    eng = ContinuousEngine(SPEC, config=_cfg(), seed=0)
     reqs = _reqs(rs, 3, max_new=14)
     reqs[1].stop_sequences = [[int(x)] for x in
-                              sync.generate([_reqs(rs, 1)[0]])[0].tokens[:1]]
-    sync2 = ContinuousEngine(SPEC, params=sync.params, config=cfg(), seed=0)
+                              eng.generate([_reqs(rs, 1)[0]])[0].tokens[:1]]
+    reqs[2] = _copy(reqs[2], max_new_tokens=10, request_id="late")
+    want = _static(eng.params, reqs)
 
-    def run(eng):
-        ids = [eng.submit(r) for r in
-               [GenerationRequest(prompt=r.prompt,
-                                  max_new_tokens=r.max_new_tokens,
-                                  stop_sequences=r.stop_sequences,
-                                  request_id=r.request_id) for r in reqs[:2]]]
-        eng.step()                              # mid-flight admission below
-        ids.append(eng.submit(GenerationRequest(
-            prompt=reqs[2].prompt, max_new_tokens=10, request_id="late")))
-        out = {r.request_id: (r.tokens, r.finish_reason)
-               for r in eng.run_until_idle()}
-        return {i: out[i] for i in ids}
-
-    assert run(sync2) == run(defer)
+    ids = [eng.submit(_copy(r)) for r in reqs[:2]]
+    eng.step()                              # mid-flight admission below
+    ids.append(eng.submit(_copy(reqs[2])))
+    out = {r.request_id: (r.tokens, r.finish_reason)
+           for r in eng.run_until_idle()}
+    assert {i: out[i] for i in ids} == want
+    assert eng._pending is None and not eng._first_reads
 
 
 def test_streamed_run_matches_unstreamed_output():
     """Streaming decides nothing: with every request streamed (a chunk's
-    tokens go out under the NEXT dispatch) the outputs are token-for-token
+    tokens go out under the NEXT chunk) the outputs are token-for-token
     the unstreamed engine's, including a mid-flight admission and a
     host-side stop sequence, and each stream splices to its result."""
     rs = np.random.RandomState(7)
@@ -276,18 +283,121 @@ def test_streamed_run_matches_unstreamed_output():
             == m["decode_chunks"])
 
 
-def test_defer_sync_requires_fully_backed_pool():
-    import pytest
+def _pages_written(eng):
+    """Physical pages of the K pool any row of which is not zero."""
+    k = np.asarray(eng.kv.k_pages)              # [L, pages, page_size, fused]
+    return set(np.nonzero(np.abs(k).sum(axis=(0, 2, 3)))[0].tolist())
 
-    with pytest.raises(ValueError, match="fully backed"):
-        ContinuousEngine(SPEC, config=_cfg(defer_sync=True, num_pages=8))
+
+def test_a_part_backed_pool_is_served_and_never_writes_past_its_pages():
+    """8 pages for 4 slots of up to 8 pages each: nothing is refused at
+    load. Where the pool cannot back a chunk ahead of the one in flight
+    the iteration reads that chunk first (``sync_fallback_iterations``),
+    outputs are the static engine's, and no page is written that the
+    allocator did not hand to a live slot at that dispatch."""
+    rs = np.random.RandomState(11)
+    eng = ContinuousEngine(SPEC, config=_cfg(num_pages=8), seed=0)
+    reqs = _reqs(rs, 4, prompt_len=12, max_new=18)
+    want = _static(eng.params, reqs)
+    for r in reqs:
+        eng.submit(_copy(r))
+    out, held = {}, set()
+    while eng.n_live or eng.n_waiting:
+        eng.step()
+        held |= {p for pages in eng.kv._slot_pages.values() for p in pages}
+        assert _pages_written(eng) <= held
+        out.update({r.request_id: (r.tokens, r.finish_reason)
+                    for r in eng.drain_finished()})
+    assert out == want
+    m = eng.get_metrics()
+    assert m["sync_fallback_iterations"] > 0
+    assert m["capacity_finishes"] == 0 and m["kv"]["pages_used"] == 0
 
 
-def test_deferred_admission_parity_and_ttft():
-    """Under decode pressure the deferred-admission path (first token
-    installed device-side, harvested from the next chunk's packed read)
-    must produce exactly the tokens of the sync path, with TTFT stamped
-    and >=1 token per result."""
+def test_eight_requests_over_four_slots_hand_their_slots_on():
+    """Every request ends by ``max_new_tokens``, which the host foresees:
+    the four successors are prefilled behind the chunk their predecessors
+    end in, no decode chunk is sent with an empty slot, and the tokens are
+    the static engine's."""
+    rs = np.random.RandomState(12)
+    eng = ContinuousEngine(SPEC, config=_cfg(), seed=0)
+    reqs = _reqs(rs, 8, max_new=11)
+    want = _static(eng.params, reqs)
+    out = {r.request_id: (r.tokens, r.finish_reason)
+           for r in eng.generate([_copy(r) for r in reqs])}
+    assert out == want
+    m = eng.get_metrics()
+    assert m["admissions"] == 8 and m["admissions_ahead"] == 4
+    assert m["empty_slot_dispatches"] == 0
+    assert m["finishes_learned_late"] == 0
+    assert m["sync_fallback_iterations"] == 0
+    # 1 first + 10 decoded over chunks of 4: three chunks a wave, none
+    # of them empty for a slot
+    assert m["decode_chunks"] == 6
+
+
+def test_an_eos_finish_is_learned_late_and_leaks_nothing():
+    """An EOS is learned at the read: it costs its slot one chunk
+    (``finishes_learned_late``), nothing past it is emitted or streamed,
+    and the slot's next tenant gets none of the old request's column."""
+    rs = np.random.RandomState(2)
+    eng = ContinuousEngine(SPEC, config=_cfg(max_slots=1), seed=0)
+    a, b = _reqs(rs, 2, max_new=12)
+    probe = _static(eng.params, [a, b])
+    eos = probe["r0"][0][5]
+    cut = probe["r0"][0].index(eos) + 1
+    frames = []
+    eng.submit(_copy(a, eos_id=eos), on_tokens=frames.append)
+    eng.submit(_copy(b))
+    out = {r.request_id: r for r in eng.run_until_idle()}
+    assert out["r0"].tokens == probe["r0"][0][:cut]
+    assert out["r0"].finish_reason == "stop"
+    assert [t for f in frames for t in f] == out["r0"].tokens
+    assert (out["r1"].tokens, out["r1"].finish_reason) == probe["r1"]
+    m = eng.get_metrics()
+    assert m["finishes_learned_late"] == 1 and m["admissions_ahead"] == 0
+
+
+def test_first_frame_leaves_before_the_read_of_the_chunk_that_follows():
+    """An admission's first token is read from its prefill's own output
+    right after the next decode dispatch: its first frame is delivered
+    before that chunk's packed output is read, with the engine busy or
+    idle, and TTFT is stamped then."""
+    rs = np.random.RandomState(5)
+    eng = ContinuousEngine(SPEC, config=_cfg(), seed=0)
+    log = []
+    harvest = eng._harvest_chunk
+
+    def spy(entry):
+        log.append(("read", id(entry)))
+        return harvest(entry)
+
+    eng._harvest_chunk = spy
+    busy, new = _reqs(rs, 2, max_new=16)
+    want = _static(eng.params, [busy, new])
+    for req in (busy, new):
+        eng.submit(_copy(req), on_tokens=lambda toks, rid=req.request_id:
+                   log.append(("frame", rid, list(toks))))
+        eng.step()                      # admits it, dispatches, reads
+        follows = id(eng._pending)      # the chunk sent behind its prefill
+        first = log.index(("frame", req.request_id,
+                           want[req.request_id][0][:1]))
+        assert ("read", follows) not in log[:first]
+        state = next(s for s in eng._slots.values()
+                     if s.request.request_id == req.request_id)
+        assert not state.first_pending and state.first_token_at > 0
+        eng.step()
+        assert ("read", follows) in log
+    out = {r.request_id: (r.tokens, r.finish_reason)
+           for r in eng.run_until_idle()}
+    assert out == want
+    assert eng.get_metrics()["ttft"]["count"] == 2
+
+
+def test_admissions_under_decode_pressure_match_the_static_engine():
+    """Admissions into a busy engine (first tokens installed device-side,
+    read after the next dispatch) produce exactly the static engine's
+    tokens, with TTFT stamped and >=1 token per result."""
     import jax
 
     from distributed_inference_engine_tpu.models.base import init_params
@@ -295,58 +405,53 @@ def test_deferred_admission_parity_and_ttft():
     params = init_params(SPEC, jax.random.key(3))
     rs = np.random.RandomState(5)
     reqs = _reqs(rs, 4, max_new=10)
-
-    def run(defer: bool):
-        eng = ContinuousEngine(SPEC, params=params,
-                               config=_cfg(defer_admission=defer))
-        eng.submit(reqs[0])
-        while not eng._slots:                  # r0 live -> pressure >= 1/4
-            eng.step()
-        for r in reqs[1:]:
-            eng.submit(r)
-        eng.step()                             # admission round for r1..r3
-        if defer:
-            assert eng.get_metrics()["deferred_admissions"] >= 3, \
-                "deferred path did not engage"
-        out = {r.request_id: r for r in eng.run_until_idle()}
-        assert not any(getattr(s, "first_pending", False)
-                       for s in eng._slots.values())
-        return out
-
-    got = run(True)
-    ref = run(False)
-    assert set(got) == set(ref)
-    for rid in ref:
-        assert got[rid].tokens == ref[rid].tokens, rid
+    eng = ContinuousEngine(SPEC, params=params, config=_cfg())
+    want = _static(eng.params, reqs)
+    eng.submit(_copy(reqs[0]))
+    while not eng._slots:
+        eng.step()
+    for r in reqs[1:]:
+        eng.submit(_copy(r))
+    eng.step()                             # admission round for r1..r3
+    assert not any(s.first_pending for s in eng._slots.values())
+    got = {r.request_id: r for r in eng.run_until_idle()}
+    assert set(got) == set(want)
+    for rid in want:
+        assert (got[rid].tokens, got[rid].finish_reason) == want[rid], rid
         assert len(got[rid].tokens) >= 1
         assert got[rid].ttft_s > 0
 
 
-def test_deferred_admission_single_token_request_falls_back():
-    """max_new_tokens=1 must resolve with exactly one token even when the
-    engine is busy (the deferred path cannot stop before decoding, so the
-    admission round takes the sync path)."""
+def test_single_token_request_never_decodes_and_holds_no_slot():
+    """max_new_tokens=1 resolves with exactly one token with the engine
+    busy: its row is never installed, its slot goes back at once, and the
+    admission does not wait for the token."""
     import jax
 
     from distributed_inference_engine_tpu.models.base import init_params
 
     params = init_params(SPEC, jax.random.key(3))
     rs = np.random.RandomState(6)
-    eng = ContinuousEngine(SPEC, params=params, config=_cfg())
-    eng.submit(_reqs(rs, 1, max_new=12)[0])
-    while not eng._slots:
-        eng.step()
+    eng = ContinuousEngine(SPEC, params=params, config=_cfg(max_slots=2))
+    busy = _reqs(rs, 1, max_new=12)[0]
     one = GenerationRequest(prompt=[5, 6, 7], max_new_tokens=1,
                             temperature=0.0, request_id="one")
-    eng.submit(one)
-    out = {r.request_id: r for r in eng.run_until_idle()}
-    assert len(out["one"].tokens) == 1
+    want = _static(eng.params, [busy, one])
+    eng.submit(_copy(busy))
+    while not eng._slots:
+        eng.step()
+    eng.submit(_copy(one))
+    eng.step()
+    assert len(eng._slots) == 1 and eng.kv.n_free_slots == 1
+    out = {r.request_id: (r.tokens, r.finish_reason)
+           for r in eng.drain_finished() + eng.run_until_idle()}
+    assert out == want and len(out["one"][0]) == 1
 
 
-def test_deferred_admission_eos_first_token_stops_clean():
-    """A deferred admission whose prefill-sampled first token IS eos must
-    resolve as a stop with just that token — installed inactive on device
-    (no dead decode steps) and retired at the next packed read."""
+def test_eos_first_token_stops_clean():
+    """An admission whose prefill-sampled first token IS eos resolves as a
+    stop with just that token — installed inactive on device (no dead
+    decode steps) and retired when the host reads the token."""
     import jax
 
     from distributed_inference_engine_tpu.models.base import init_params
@@ -354,31 +459,21 @@ def test_deferred_admission_eos_first_token_stops_clean():
     params = init_params(SPEC, jax.random.key(3))
     rs = np.random.RandomState(7)
     busy = _reqs(rs, 1, max_new=12)[0]
+    eng = ContinuousEngine(SPEC, params=params, config=_cfg())
     probe = GenerationRequest(prompt=[9, 8, 7], max_new_tokens=6,
                               temperature=0.0, request_id="p")
-
-    # discover the greedy first token for this prompt
-    eng0 = ContinuousEngine(SPEC, params=params, config=_cfg())
-    first = eng0.generate([probe])[0].tokens[0]
-
-    def run(defer: bool):
-        eng = ContinuousEngine(SPEC, params=params,
-                               config=_cfg(defer_admission=defer))
-        eng.submit(GenerationRequest(prompt=busy.prompt, max_new_tokens=12,
-                                     temperature=0.0, request_id="busy"))
-        while not eng._slots:
-            eng.step()
-        eng.submit(GenerationRequest(prompt=[9, 8, 7], max_new_tokens=6,
-                                     temperature=0.0, eos_id=first,
-                                     request_id="p"))
-        out = {r.request_id: r for r in eng.run_until_idle()}
-        if defer:
-            assert eng.get_metrics()["deferred_admissions"] >= 1
-        return out["p"]
-
-    got, ref = run(True), run(False)
-    assert got.finish_reason == ref.finish_reason == "stop"
-    assert got.tokens == ref.tokens
+    first = _static(eng.params, [probe])["p"][0][0]
+    eng.submit(_copy(busy))
+    while not eng._slots:
+        eng.step()
+    eng.submit(_copy(probe, eos_id=first))
+    eng.step()
+    got = {r.request_id: r for r in eng.drain_finished()}["p"]
+    assert (got.tokens, got.finish_reason) == ([first], "stop")
+    assert eng.get_metrics()["finishes_learned_late"] == 1
+    rest = eng.run_until_idle()
+    assert [(r.tokens, r.finish_reason) for r in rest] == [
+        _static(eng.params, [busy])[busy.request_id]]
 
 
 def test_page_boundary_pause_revives_not_finishes():
@@ -404,25 +499,56 @@ def test_page_boundary_pause_revives_not_finishes():
     assert cont.get_metrics()["capacity_finishes"] == 0
 
 
-def test_page_boundary_pause_revives_under_defer_sync():
-    """Pause + revive through the deferred-readback path. Shape chosen so
-    ensure_capacity's grant lands EXACTLY on a page boundary mid-flight
-    (prompt 8, chunk 4, ahead 2x4: 8+8=16=page): the device pauses at
-    the cap while the NEXT chunk is already dispatched with the slot
-    inactive — that chunk's harvest sees a grown caps row and must not
-    re-judge the paused slot as finished (the no-progress skip)."""
+def test_a_grant_never_ends_where_a_chunk_does():
+    """Under a chunk in flight a pause at the grant costs the row the next
+    chunk, so the capacity loop asks for one row more than the chunks can
+    write: a prompt of 8 with chunks of 4 (8+4+4 = one page of 16) runs
+    through the page boundary without a pause, and equals the static
+    engine."""
     rs = np.random.RandomState(3)
-    req = [GenerationRequest(
+    req = GenerationRequest(
         prompt=rs.randint(1, SPEC.vocab_size, size=8).tolist(),
-        max_new_tokens=16, temperature=0.0, request_id="edge")]
-    # defer_sync needs a fully backed pool: 4 slots * 8 pages
-    cfg = _cfg(defer_sync=True, num_pages=32, max_seq_len=128)
-    cont = ContinuousEngine(SPEC, config=cfg, seed=0)
-    out = cont.generate(req)
-    assert len(out[0].tokens) == 16, out[0].tokens
+        max_new_tokens=16, temperature=0.0, request_id="edge")
+    cont = ContinuousEngine(SPEC, config=_cfg(), seed=0)
+    want = _static(cont.params, [req])
+    revived = []
+    set_active = cont._set_active
+    cont._set_active = lambda slots, value: (
+        revived.extend(slots if value else []), set_active(slots, value))
+    out = cont.generate([_copy(req)])
+    assert (out[0].tokens, out[0].finish_reason) == want["edge"]
+    assert not revived and cont.get_metrics()["decode_chunks"] == 4
 
 
-# ---------------------------------- the firsts host cache, device stop ids
+def test_a_row_paused_at_its_grant_is_revived_a_chunk_later():
+    """Pool pressure can still end a grant inside a chunk: the device
+    pauses the row, the read (one chunk behind) revives it, the chunk
+    already in flight carries it idle and must not re-judge it as a
+    finished "length" (the no-progress skip)."""
+    rs = np.random.RandomState(3)
+    req = GenerationRequest(
+        prompt=rs.randint(1, SPEC.vocab_size, size=8).tolist(),
+        max_new_tokens=16, temperature=0.0, request_id="edge")
+    cont = ContinuousEngine(SPEC, config=_cfg(), seed=0)
+    want = _static(cont.params, [req])
+    # the allocator grants what is asked and no row more, as a pool with
+    # one page to spare would
+    ensure = cont.kv.ensure_capacity
+    cont.kv.ensure_capacity = lambda slot, total: ensure(slot, total - 1)
+    idle = []
+    judge = cont._judge_packed
+
+    def spy(entry, packed, progressed):
+        idle.extend(s for s, p in progressed.items() if not p)
+        return judge(entry, packed, progressed)
+
+    cont._judge_packed = spy
+    out = cont.generate([_copy(req)])
+    assert (out[0].tokens, out[0].finish_reason) == want["edge"]
+    assert idle and cont.get_metrics()["capacity_finishes"] == 0
+
+
+# ----------------------------------------------------------- device stop ids
 
 
 def _plain_engine():
@@ -440,31 +566,6 @@ def _short_reqs(n=2, new=8):
         prompt=[(5 * i + j) % 250 + 1 for j in range(4 + 3 * i)],
         max_new_tokens=new, temperature=0.0, request_id=f"r{i}")
         for i in range(n)]
-
-
-def test_firsts_snapshot_cache():
-    """The packed chunk output carries the whole firsts buffer, so sync
-    processing caches it host-side for free; rescue reads go through
-    _firsts_snapshot() — one whole-buffer transfer at most, and the cache
-    invalidates when an admission rewrites the device columns."""
-    eng = _plain_engine()
-    assert eng._firsts_host is None
-    res = eng.generate(_short_reqs())
-    assert all(r.tokens for r in res)
-    # a sync decode chunk ran -> the packed read populated the cache
-    assert eng._firsts_host is not None
-    np.testing.assert_array_equal(eng._firsts_snapshot(),
-                                  np.asarray(eng._firsts_dev))
-    # stale-path: drop the cache, the snapshot refetches the device buffer
-    eng._firsts_host = None
-    snap = eng._firsts_snapshot()
-    np.testing.assert_array_equal(snap, np.asarray(eng._firsts_dev))
-    assert eng._firsts_host is not None
-    # a second wave re-admits (install rewrites firsts columns -> cache
-    # invalidated mid-run) and must still finish with a consistent cache
-    eng.generate(_short_reqs())
-    np.testing.assert_array_equal(eng._firsts_snapshot(),
-                                  np.asarray(eng._firsts_dev))
 
 
 def test_device_stop_ids():

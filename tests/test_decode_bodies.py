@@ -115,8 +115,9 @@ def _check_body(eng, body):
     else:
         assert m["attn_impl"] == "xla" and dense == 0 and in_place == 0
         assert m["decode_chunks"] > 0
-    # six requests over four slots: some admission met a decoding batch
-    assert m["deferred_admissions"] > 0
+    # six requests over four slots: some successor was prefilled behind
+    # the chunk its predecessor ended in
+    assert m["admissions_ahead"] > 0
     assert m["total_requests"] == len(_SHAPES)
 
 

@@ -545,8 +545,8 @@ def _requests(shapes):
 def test_engine_greedy_parity_through_admission_and_slot_reuse(impl):
     """A default (``auto``) engine on the CPU runs the dense XLA path; the
     same engine on the kernel path emits its greedy tokens exactly —
-    through batched admission, deferred first tokens (admissions while a
-    slot is live) and a slot freed and re-admitted mid-run. The counters
+    through batched admission, first tokens installed device-side and a
+    slot handed on behind the chunk its request ends in. The counters
     say which path each engine's decode chunks took."""
     ref, fd = _tiny_engines(impl)
     assert ref.attn_impl == "xla"            # auto, on the CPU backend
@@ -558,8 +558,8 @@ def test_engine_greedy_parity_through_admission_and_slot_reuse(impl):
     assert len(a) == len(shapes) and a == b
     assert [len(a[f"r{i}"]) for i in range(5)] == [m for _, m in shapes]
     ma, mb = ref.get_metrics(), fd.get_metrics()
-    assert ma["deferred_admissions"] > 0
-    assert mb["deferred_admissions"] == ma["deferred_admissions"]
+    assert ma["admissions_ahead"] > 0
+    assert mb["admissions_ahead"] == ma["admissions_ahead"]
     assert ma["attn_impl"] == "xla" and mb["attn_impl"] == impl
     assert ma["decode_chunks_dense"] > 0 and ma["decode_chunks_in_place"] == 0
     assert mb["decode_chunks_in_place"] > 0 and mb["decode_chunks_dense"] == 0

@@ -202,6 +202,36 @@ def test_a_preempted_sequence_is_re_prefilled_and_resumes():
         assert b.finish_reason == a.finish_reason
 
 
+def test_a_slot_handed_on_leaks_no_state_to_its_successor():
+    """One slot, three requests that end by ``max_new_tokens``: each
+    successor is prefilled into its predecessor's slot, pages and
+    recurrent state row BEHIND the chunk the predecessor ends in
+    (``admissions_ahead``). Device order zeroes the row after that chunk
+    and the prefill overwrites it: every successor's tokens are the
+    reference's, and those of the same request served alone (float32)."""
+    rng = np.random.default_rng(5)
+    shapes = ((30, 9), (21, 11), (44, 6))
+    prompts = [[int(t) for t in rng.integers(1, 256, n)] for n, _ in shapes]
+
+    def make():
+        return [GenerationRequest(prompt=list(p), max_new_tokens=m)
+                for p, (_, m) in zip(prompts, shapes)]
+
+    engine = tiny_engine("float32", max_slots=1)
+    reqs = make()
+    together = engine.generate(reqs)
+    m = engine.get_metrics()
+    assert m["admissions"] == 3 and m["admissions_ahead"] == 2
+    assert m["empty_slot_dispatches"] == 0 and m["finishes_learned_late"] == 0
+    judged(engine, reqs, together)
+    for req, res in zip(make(), together):
+        alone = tiny_engine("float32", max_slots=1).generate([req])[0]
+        assert alone.tokens == res.tokens
+    # every slot is free again, and free means zero
+    assert all(float(jnp.abs(a).max()) == 0
+               for k, a in engine.kv.state.items() if k != "window_table")
+
+
 def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch):
     """The in-place kernel (through the interpreter) against the XLA body
     the CPU picks, under everything that touches a slot's state: five

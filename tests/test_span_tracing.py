@@ -216,22 +216,28 @@ def test_a_decode_chunk_adds_a_bounded_number_of_records(slots, steps, n_new):
     events = engine.timeline.events()
     chunks = [e for e in events if e["name"] == "engine.decode.dispatch"]
     assert len(chunks) >= 2
-    # per chunk: its bracket with the blocking read inside it, then the
-    # token half and the judgments, siblings under the step (nobody
-    # streams here: no engine.emit.* span)
+    # per chunk: its bracket, the blocking read, then the token half and
+    # the judgments, siblings under the step (nobody streams here: no
+    # engine.emit.* span). Chunk k is read inside the bracket of chunk
+    # k+1, which the read ends; the last chunk's read lies in a step that
+    # dispatches nothing
     by_name = {n: [e for e in events if e["name"] == n] for n in (
         "engine.harvest.wait", "engine.harvest.book",
         "engine.process_packed")}
     assert all(len(v) == len(chunks) for v in by_name.values())
-    assert all(e["parent"] == "engine.decode.dispatch"
-               for e in by_name["engine.harvest.wait"])
+    assert [e["parent"] for e in by_name["engine.harvest.wait"]] == (
+        ["engine.decode.dispatch"] * (len(chunks) - 1) + ["engine.step"])
     assert all(e["parent"] == "engine.step"
                for n in ("engine.harvest.book", "engine.process_packed")
                for e in by_name[n])
-    # the bracket ends with its read: the token half starts after it
-    for c, w, b in zip(chunks, by_name["engine.harvest.wait"],
+    # a bracket ends with the read in it: the token half starts after it
+    for c, w, b in zip(chunks[1:], by_name["engine.harvest.wait"],
                        by_name["engine.harvest.book"]):
         assert w["t"] + w["dur"] <= c["t"] + c["dur"] <= b["t"]
+    # one read of first tokens an admission round, after its decode dispatch
+    firsts = [e for e in events if e["name"] == "engine.first_tokens"]
+    assert [e["args"]["rows"] for e in firsts] == [slots]
+    assert firsts[0]["t"] >= chunks[0]["t"]
     admits = [e for e in events if e["name"] in (
         "engine.admit", "engine.prefill.dispatch")]
     steps = [e for e in events if e["name"] == "engine.step"]
@@ -242,7 +248,7 @@ def test_a_decode_chunk_adds_a_bounded_number_of_records(slots, steps, n_new):
     flips = [e for e in events if e["name"] == "engine.set_active"]
     assert all(e["parent"] == "engine.process_packed" for e in flips)
     assert len(events) == (4 * len(chunks) + len(admits) + len(steps)
-                           + len(flips))
+                           + len(flips) + len(firsts))
     # only the dispatch brackets count as busy time
     split = busy_gap_split(events)
     assert split["n_events"] == sum(1 for e in events if e["dispatch"])

@@ -216,7 +216,7 @@ async def test_coordinator_stream_fails_over_before_first_chunk():
         await workers[1].stop()
 
 
-# --------------------- the carried emit: chunk k streams under chunk k+1
+# --------------- the one sequence: chunk k is read and streamed under k+1
 
 
 class _Log:
@@ -272,39 +272,48 @@ def _counters(eng):
             m["decode_chunks"])
 
 
-def test_a_chunks_tokens_stream_after_the_next_dispatch():
+def test_a_chunks_tokens_are_read_and_streamed_under_the_next_chunk():
     """The mechanism itself: when ``step()`` returns with the slot alive,
-    the chunk it just read is appended but NOT yet streamed (carried); the
-    next ``step()`` streams it, under its own dispatch, and holds the next
-    one's. A request's first frame is not carried."""
+    the chunk it dispatched is in flight, unread; the next ``step()``
+    dispatches another and THEN reads and streams it. A request's first
+    frame comes from its prefill's own output, in the step that admits
+    it."""
     eng = ContinuousEngine(SPEC, config=_ecfg())
     log = _Log()
     eng.submit(_sreq("a", 14), on_tokens=log.cb("a"))
-    eng.step()                      # sync admission (first token) + chunk 1
+    eng.step()                      # admission, chunk 1, the first token
     state = next(iter(eng._slots.values()))
-    assert len(state.tokens) == 5 and log.streamed("a") == state.tokens[:1]
-    assert eng._carried == [state] and _counters(eng)[:2] == (0, 0)
-    eng.step()                      # dispatch 2, THEN chunk 1's frame
-    assert len(state.tokens) == 9 and log.streamed("a") == state.tokens[:5]
+    assert len(state.tokens) == 1 and log.streamed("a") == state.tokens
+    assert eng._pending is not None and _counters(eng) == (0, 0, 1)
+    eng.step()                      # dispatch 2, THEN chunk 1's read + frame
+    assert len(state.tokens) == 5 and log.streamed("a") == state.tokens
     assert _counters(eng) == (1, 0, 2)
     spans = [e for e in eng.timeline.events()
-             if e["name"] in ("engine.emit.carried", "engine.harvest.wait")]
-    # inside the second bracket: the carried emit, then the blocking read
-    assert [e["name"] for e in spans[-2:]] == ["engine.emit.carried",
-                                               "engine.harvest.wait"]
-    assert all(e["parent"] == "engine.decode.dispatch" for e in spans[-2:])
+             if e["name"] in ("engine.emit.carried", "engine.harvest.wait",
+                              "engine.decode.dispatch")]
+    # the second bracket opens, the blocking read ends it, the emit
+    # follows (a ring record is written when its span closes)
+    assert [e["name"] for e in spans[-3:]] == [
+        "engine.harvest.wait", "engine.decode.dispatch",
+        "engine.emit.carried"]
+    wait = next(e for e in reversed(spans)
+                if e["name"] == "engine.harvest.wait")
+    assert wait["parent"] == "engine.decode.dispatch"
     log.drive(eng)
     log.check_order("a")
     carried, flushed, chunks = _counters(eng)
-    # the last chunk's slot finishes in it: streamed at once, unhidden
+    # the last chunk's slot was handed on: it streams its own, unhidden
     assert (carried, flushed) == (chunks - 1, 1) and chunks == 4
+    m = eng.get_metrics()
+    assert m["decode_chunk"]["count"] == chunks
+    assert m["harvest_wait_s_total"] == pytest.approx(
+        m["decode_chunk"]["sum_s"], rel=1e-6, abs=1e-9)
 
 
 @pytest.mark.parametrize("news", [(6, 14), (9, 10), (13, 5), (2, 11)])
 def test_frames_in_token_order_and_the_final_frame_last(news):
     """Two streams of unequal length (and a third nobody streams): one
-    finishes in a chunk whose predecessor's emit is still carried for the
-    other; per stream the frames splice to the result, the final envelope
+    finishes in a chunk the other lives through; per stream the frames splice to the result, the final envelope
     comes last, and streaming changes no token, logprob or reason."""
     reqs = [("a", news[0], (1, 2, 3)), ("b", news[1], (4, 5, 6, 7)),
             ("quiet", 7, (8, 9))]
@@ -336,54 +345,96 @@ def test_frames_in_token_order_and_the_final_frame_last(news):
     assert _counters(_plain_eng)[:2] == (0, 0)
 
 
-def test_generate_carries_nothing():
+def test_generate_leaves_nothing_in_flight():
     eng = ContinuousEngine(SPEC, config=_ecfg())
     res = eng.generate([_sreq("g", 12)])
     assert len(res[0].tokens) == 12
-    assert eng._carried is None and _counters(eng)[:2] == (0, 0)
+    assert eng._pending is None and _counters(eng)[:2] == (0, 0)
+    assert not eng._first_reads
     assert not [e for e in eng.timeline.events()
                 if e["name"].startswith("engine.emit.")]
 
 
-def _until_carried(eng, log, n_chunks=2):
-    log.drive(eng, until=lambda e: e._carried is not None
+def _until_in_flight(eng, log, n_chunks=2):
+    """Drive until ``n_chunks`` are dispatched and the last is unread."""
+    log.drive(eng, until=lambda e: e._pending is not None
               and e.get_metrics()["decode_chunks"] >= n_chunks)
-    assert eng._carried is not None
+    assert eng._pending is not None
 
 
-def test_flush_stream_delivers_what_is_held_once():
+def test_flush_stream_delivers_the_chunk_in_flight_once():
     eng = ContinuousEngine(SPEC, config=_ecfg())
     log = _Log()
     eng.submit(_sreq("f", 16), on_tokens=log.cb("f"))
-    _until_carried(eng, log)
+    _until_in_flight(eng, log)
     state = next(iter(eng._slots.values()))
-    held = list(state.tokens)
-    assert len(log.streamed("f")) < len(held)
-    eng.flush_stream()
-    assert log.streamed("f") == held and eng._carried is None
+    read = list(state.tokens)
+    assert log.streamed("f") == read            # what is read is streamed
+    eng.flush_stream()                          # chunk 2: a blocking read
+    assert len(state.tokens) == len(read) + 4 and eng._pending is None
+    assert log.streamed("f") == state.tokens
     eng.flush_stream()                          # nothing left: no frame
-    assert log.streamed("f") == held
+    assert log.streamed("f") == state.tokens
     log.drive(eng)
     log.check_order("f")
     carried, flushed, chunks = _counters(eng)
     assert carried + flushed == chunks
 
 
-def test_abort_all_flushes_the_carried_emit():
+def test_abort_all_delivers_the_chunk_in_flight():
     eng = ContinuousEngine(SPEC, config=_ecfg())
     log = _Log()
     eng.submit(_sreq("x", 16), on_tokens=log.cb("x"))
-    _until_carried(eng, log)
-    held = list(next(iter(eng._slots.values())).tokens)
+    _until_in_flight(eng, log)
+    state = next(iter(eng._slots.values()))
+    read = len(state.tokens)
     assert eng.abort_all() == 1
-    assert log.streamed("x") == held            # none lost, none twice
-    assert eng._carried is None and not eng._slots
-    assert eng.step() == 0 and log.streamed("x") == held
+    assert len(state.tokens) == read + 4        # the device's, not dropped
+    assert log.streamed("x") == state.tokens    # none lost, none twice
+    assert eng._pending is None and not eng._slots
+    assert eng.step() == 0 and log.streamed("x") == state.tokens
+
+
+def test_abort_all_delivers_a_handed_on_slots_last_tokens_and_result():
+    """A slot handed on behind the chunk in flight is no live request any
+    more: an abort between the hand-on and that chunk's read (a step that
+    failed after it) still delivers its last tokens and its result."""
+    eng = ContinuousEngine(SPEC, config=_ecfg())
+    log = _Log()
+    eng.submit(_sreq("h", 6), on_tokens=log.cb("h"))
+    _until_in_flight(eng, log)                  # 5 read, the 6th in flight
+    eng._hand_on_foreseen()
+    assert not eng._slots and eng._pending.handed_on
+    assert eng.kv.n_free_slots == eng.max_slots
+    assert eng.abort_all() == 0
+    for res in eng.drain_finished():
+        log.events.append(("final", res.request_id, res))
+    log.check_order("h")
+    assert len(log.result("h").tokens) == 6
+    assert log.result("h").finish_reason == "length"
+
+
+def test_a_slot_retired_before_its_first_token_was_read_gets_it_first():
+    """The pool is dry right after an admission (a prompt of one whole
+    page, no page to grow into): the capacity loop retires the slot before
+    any decode dispatch, reads its first token from the prefill's output
+    there, and the result and the stream carry it."""
+    eng = ContinuousEngine(SPEC, config=_ecfg(num_pages=1, max_slots=1))
+    log = _Log()
+    eng.submit(_sreq("r", 8, prompt=range(1, 17)), on_tokens=log.cb("r"))
+    log.drive(eng)
+    log.check_order("r")
+    res = log.result("r")
+    assert len(res.tokens) == 1 and res.finish_reason == "length"
+    m = eng.get_metrics()
+    assert m["capacity_finishes"] == 1 and m["decode_chunks"] == 0
+    assert m["ttft"]["count"] == 1
 
 
 def test_a_slot_retired_by_the_capacity_loop_streams_first():
-    """No dispatch follows for a slot the pool cannot grow: what is
-    carried for it goes out before its result, from the capacity loop."""
+    """No dispatch follows for a slot the pool cannot grow: the chunk in
+    flight is read first (the sync fallback), its tokens go out before the
+    result, from the capacity loop."""
     eng = ContinuousEngine(SPEC, config=_ecfg(num_pages=2, max_slots=2))
     log = _Log()
     eng.submit(_sreq("a", 40, prompt=range(1, 12)), on_tokens=log.cb("a"))
@@ -395,14 +446,15 @@ def test_a_slot_retired_by_the_capacity_loop_streams_first():
         assert log.result(rid).finish_reason == "length"
     carried, flushed, chunks = _counters(eng)
     assert carried + flushed == chunks and flushed >= 1
+    assert eng.get_metrics()["sync_fallback_iterations"] >= 1
 
 
 @pytest.mark.parametrize("end", [6, 7, 9, 10, 12])
-def test_host_stop_sequence_inside_a_carried_chunk_trims(end):
+def test_host_stop_sequence_found_a_chunk_late_trims(end):
     """A two-token stop sequence (host-side: the device knows single ids
-    only) that ends at token ``end``: inside chunk 2 or 3, whose
-    predecessor's emit is carried when the stop is found. The stream is
-    the result, cut after the sequence, exactly as without streaming."""
+    only) that ends at token ``end``: inside chunk 2 or 3, found at its
+    read with the next chunk already in flight. The stream is the result,
+    cut after the sequence, exactly as without streaming."""
     probe = ContinuousEngine(SPEC, config=_ecfg()).generate(
         [_sreq("p", 16)])[0].tokens
     seq = probe[end - 2: end]
@@ -424,10 +476,10 @@ def test_host_stop_sequence_inside_a_carried_chunk_trims(end):
     log.check_order("s")
 
 
-def test_pause_and_revive_at_a_page_boundary_with_a_carried_emit():
-    """The capacity grant lands exactly on a page boundary (prompt 8 +
-    two chunks of 4 = one page of 16): the device pauses the slot, the
-    judgment revives it, and its stream carries across the pause."""
+def test_a_stream_runs_through_a_page_boundary():
+    """Prompt 8 + two chunks of 4 = one page of 16: the grant is taken a
+    row past what the chunks write, so the stream crosses the boundary
+    without a pause."""
     eng = ContinuousEngine(SPEC, config=_ecfg(max_seq_len=64))
     log = _Log()
     eng.submit(_sreq("edge", 16, prompt=range(1, 9)),
@@ -439,20 +491,20 @@ def test_pause_and_revive_at_a_page_boundary_with_a_carried_emit():
 
 
 @pytest.mark.asyncio
-async def test_pump_shutdown_flushes_the_carried_emit():
-    """The pump stops with a slot alive and a chunk's emit carried: the
-    engine thread streams it before the futures fail."""
+async def test_pump_shutdown_delivers_the_chunk_in_flight():
+    """The pump stops with a slot alive and a chunk in flight: the engine
+    thread reads and streams it before the futures fail."""
     from distributed_inference_engine_tpu.serving.pump import EnginePump
 
     eng = ContinuousEngine(SPEC, config=_ecfg())
     pump = EnginePump(eng)
-    held = []
+    seen = []
     step = eng.step
 
     def step_then_stop():
         live = step()
-        if eng._carried is not None and eng._decode_chunks >= 2:
-            held[:] = next(iter(eng._slots.values())).tokens
+        if eng._pending is not None and eng._decode_chunks >= 2:
+            seen.append(next(iter(eng._slots.values())))
             pump._stop.set()                    # what shutdown_nowait sets
         return live
 
@@ -461,7 +513,35 @@ async def test_pump_shutdown_flushes_the_carried_emit():
     with pytest.raises(RuntimeError, match="shut down"):
         await pump.generate_streaming(_sreq("z", 40), got.extend)
     await pump.stop()
-    assert held and got == held                 # none lost, none twice
+    # 1 + 4 read by the steps, 4 more by the final flush: none lost or twice
+    assert len(got) == 9 and got == seen[0].tokens
+
+
+@pytest.mark.asyncio
+async def test_pump_shutdown_resolves_a_handed_on_slot():
+    """The pump stops between a hand-on and the read of the chunk the old
+    request ends in: the final flush streams its last tokens and resolves
+    its future with the result."""
+    from distributed_inference_engine_tpu.serving.pump import EnginePump
+
+    eng = ContinuousEngine(SPEC, config=_ecfg())
+    pump = EnginePump(eng)
+    step = eng.step
+
+    def step_then_stop():
+        live = step()
+        if eng._pending is not None and eng._decode_chunks >= 2:
+            eng._hand_on_foreseen()             # as the next step would
+            assert eng._pending.handed_on
+            pump._stop.set()
+        return live
+
+    eng.step = step_then_stop
+    got = []
+    res = await pump.generate_streaming(_sreq("z", 6), got.extend)
+    await pump.stop()
+    assert len(res.tokens) == 6 and got == res.tokens
+    assert res.finish_reason == "length"
 
 
 # ------------------------------------------- sub-chunk streaming (ISSUE 13)
@@ -474,11 +554,11 @@ def _ecfg(**over):
     return EngineConfig(**kw)
 
 
-def test_token_ring_roundtrip_bit_exact():
-    """defer_sync path: each chunk's emitted rows ride the device->host
-    ring and are harvested by poll_stream inside the host bubble; the
-    streamed concatenation must equal the packed-harvest result exactly."""
-    eng = ContinuousEngine(SPEC, config=_ecfg(defer_sync=True))
+def test_packed_copy_roundtrip_bit_exact():
+    """Each chunk's emitted rows ride the packed output's async
+    device->host copy and are read one dispatch later; the streamed
+    concatenation must equal the result exactly."""
+    eng = ContinuousEngine(SPEC, config=_ecfg())
     chunks = []
     eng.submit(GenerationRequest(prompt=[1, 2, 3], max_new_tokens=12,
                                  temperature=0.0, request_id="ring"),
@@ -486,16 +566,15 @@ def test_token_ring_roundtrip_bit_exact():
     results = []
     for _ in range(10000):
         live = eng.step()
-        eng.poll_stream()               # the pump's host-bubble poll
         results.extend(eng.drain_finished())
         if live == 0 and not eng.n_waiting:
             break
     assert results and results[0].tokens
     streamed = [t for c in chunks for t in c]
-    assert streamed == results[0].tokens        # bit-exact ring copy
+    assert streamed == results[0].tokens        # bit-exact copy
     m = eng.get_metrics()
-    assert m["stream_ring_pushes"] >= 1
-    assert m["stream_ring_polls"] >= 1
+    assert m["decode_chunk"]["count"] == m["decode_chunks"] >= 1
+    assert m["harvest_wait_s_total"] >= 0.0
 
 
 def test_subchunk_greedy_parity_with_packed_harvest():
@@ -576,23 +655,19 @@ def test_adaptive_chunk_compile_count_guard():
     assert [t for c in chunks for t in c]
 
 
-def test_firsts_snapshot_one_fetch_per_rescue_wave():
-    """Regression for the hoisted per-slot ascontiguousarray recompute: a
-    whole retire wave shares at most ONE deferred-firsts readback, and a
-    cache hit costs zero host reads."""
-    eng = ContinuousEngine(SPEC, config=_ecfg(max_slots=4, defer_sync=True))
+def test_one_first_token_read_a_prefill_dispatch():
+    """A whole admission round shares ONE read of its prefill's output
+    (``engine.first_tokens`` spans say how many rows each read carried),
+    never one a slot."""
+    eng = ContinuousEngine(SPEC, config=_ecfg(max_slots=4))
     reqs = [GenerationRequest(prompt=[1 + i, 2, 3], max_new_tokens=6,
                               temperature=0.0) for i in range(3)]
     res = eng.generate(reqs)
     assert all(len(r.tokens) == 6 for r in res)
-    # direct probe: one invalidation, two lookups, ONE fetch
-    eng._firsts_host = None
-    base = eng._firsts_fetches
-    a = eng._firsts_snapshot()
-    b = eng._firsts_snapshot()
-    assert a is b
-    assert eng._firsts_fetches == base + 1
-    assert eng.get_metrics()["firsts_fetches"] == eng._firsts_fetches
+    reads = [e for e in eng.timeline.events()
+             if e["name"] == "engine.first_tokens"]
+    assert [e["args"]["rows"] for e in reads] == [3]
+    assert eng.get_metrics()["prefill"]["count"] == 1
 
 
 @pytest.mark.asyncio
