@@ -33,7 +33,6 @@ import collections
 import dataclasses
 import logging
 import time
-from functools import partial
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
 import jax
@@ -44,29 +43,19 @@ from ..config import EngineConfig, validate_prefill_compose
 from ..models.base import (
     ModelSpec,
     Params,
-    forward_decode,
-    forward_decode_paged,
-    forward_decode_window,
-    forward_prefill_into_pages,
-    forward_prefill_suffix,
     init_params,
     layered_family,
-    unembed,
     write_prefill_pages,
 )
-from ..ops import flash_prefill, kda
-from ..ops.mla import prefill_key_blocks
-from ..ops.sampling import (
-    SamplingParams,
-    sample_tokens,
-    sample_tokens_with_logprobs,
-)
+from ..ops import kda
+from ..ops.sampling import SamplingParams
 from ..obs.timeline import HostSpan, StepTimeline, host_span
 from ..utils import compile_cache
 from ..utils.hotpath import hot_path
 from ..utils.tracing import LatencyStats
 from .engine import _next_bucket, _pow2_buckets
 from .paged_kv import PagedKVCache, page_chain_hashes
+from .programs import build_programs, decode_body
 from .types import (
     EngineOverloadedError,
     GenerationRequest,
@@ -81,6 +70,13 @@ logger = logging.getLogger(__name__)
 # single-token stop ids than this keep the extras on the host scan path
 _DEVICE_STOP_K = 8
 
+
+# counters every spec reports, zeros where it has none: the worker's
+# counters and ``obs/collectors.py`` read these groups of any engine
+_COUNTERS_OF_EVERY_SPEC = (
+    "mla.decode_context_rows", "mla.decode_table_rows",
+    "moe.assignments_held", "moe.assignments_total", "moe.experts_touched",
+    "moe.decode_assignments_held")
 
 # attention_impl strings a deploy may pass; anything else is refused at load
 ATTENTION_IMPLS = ("auto", "xla", "pallas-decode", "pallas-decode_interpret")
@@ -311,6 +307,9 @@ class ContinuousEngine:
         # Each refusal fails here, at load, not at its first request.
         self._per_layer = bool(self.spec.layer_kinds)
         self._recurrent = self.spec.recurrent
+        # the module that runs a per-layer spec (models.base.layered_family)
+        self._family = (layered_family(self.spec) if self._per_layer
+                        else None)
         if self._per_layer:
             refused = [name for name, on in (
                 ("a tp/sp mesh (shard_fn / kv_sharding / sp_mesh)",
@@ -326,8 +325,7 @@ class ContinuousEngine:
                     "a per-layer (hybrid) spec does not support: "
                     + "; ".join(refused))
         if params is None:
-            init = (layered_family(spec).init_params if spec.layer_kinds
-                    else init_params)
+            init = self._family.init_params if self._family else init_params
             params = init(spec, jax.random.key(seed))
         if shard_fn is not None:
             params = shard_fn(params)
@@ -383,52 +381,30 @@ class ContinuousEngine:
             self._per_layer and self.prefix_cache)
         if self._per_layer:
             self.prefix_cache = False
-        # routed-expert counters of a hybrid spec, summed from the rows
-        # each chunk's packed output carries (no read of their own)
-        self._moe_counts = np.zeros((3,), np.int64)
-        self._moe_decode_counts = np.zeros((3,), np.int64)
+        # the family's counters by name, ``<group>.<key>`` of
+        # ``get_metrics()``: what a decode chunk's packed output carries
+        # (the family's DECODE_COUNTERS, no read of their own), what its
+        # prefills return (PREFILL_COUNTERS) and the host sums of each
+        # (``decode_sums`` / ``prefill_sums``, whose names an empty chunk
+        # and prompt give)
+        fam = self._family
+        no_rows = np.zeros((0,), np.int64)
+        self._counters: Dict[str, int] = dict.fromkeys(
+            _COUNTERS_OF_EVERY_SPEC + (() if fam is None else tuple(
+                n for n in (*fam.DECODE_COUNTERS, *fam.PREFILL_COUNTERS,
+                            *fam.decode_sums(self.spec, no_rows, no_rows),
+                            *fam.prefill_sums(self.spec, 0, 1)) if n)), 0)
         self._decode_steps = 0
         # prefill programs' counters stay on the device until a chunk's
         # harvest has synced past them (read then, without a wait)
-        self._prefill_moe: List[Any] = []
+        self._prefill_counters: List[Any] = []
         # sequences pre-empted by re-prefill: request id -> what they had
         # produced before (merged into the result at the finish)
         self._resumed: Dict[str, Dict[str, Any]] = {}
         self._reprefill_preemptions = 0
-        # latent rows the decode steps attended to (a host sum from each
-        # chunk's packed output) and rows the attention READ for them,
-        # which the program counts (the kernel's own count of the pages it
-        # copied, the last of the family's counters)
-        self._mla_context_rows = 0
-        self._mla_table_rows = 0
-        # where the paged layers keep K|V rows: the same two (the program's
-        # count in the first of the family's counters); and the (row, step)
-        # pairs that moved a recurrent state; all per layer
-        self._kv_rows = bool(self.spec.layer_kinds
-                             and self.spec.kv_row_lanes)
-        self._full_context_rows = 0
-        self._full_table_rows = 0
-        self._state_rows_updated = 0
-        # a spec with sliding-window layers (``self.kv.window`` rows): rows
-        # inside the window the decode steps attended to, and rows the body
-        # read for them (the last of the family's counters), per sliding
-        # layer
-        self._window_context_rows = 0
-        self._window_table_rows = 0
         # the body that moves a recurrent state in a decode step, as the
         # family's programs will pick it when they are traced (ops/kda.py)
         self.state_step_body = kda.step_impl() if self._recurrent else None
-        # key blocks the admitted prompts' prefills visited / blocks of
-        # their buckets' whole squares (ops/mla.py), per paged layer
-        self._mla_prefill_visited = 0
-        self._mla_prefill_square = 0
-        # the same two where the paged layers keep K|V rows, by layer kind
-        # (ops/flash_prefill.py: a sliding layer's are the band's), per
-        # layer of the kind: kind -> (its window, [visited, square])
-        self._kv_prefill_blocks = {
-            kind: (window, [0, 0])
-            for kind, window in (("full", 0), ("window", self.kv.window))
-            if self._kv_rows and (kind == "full" or window)}
         # the decode chunk in flight (see _ChunkEntry): read after the NEXT
         # one is dispatched, so the host's bookkeeping runs under a program
         self._pending: Optional[_ChunkEntry] = None
@@ -524,8 +500,7 @@ class ContinuousEngine:
         # mirror — each chunk's packed row is consumed immediately.)
         self._lengths_host = np.zeros((n,), np.int32)
 
-        # ---- jitted programs
-        spec_ = self.spec
+        # ---- jitted programs (engine/programs.py)
         has_sp = (sp_mesh is not None
                   and sp_mesh.shape.get("sp", 1) > 1)
         # compose rule lifted into config.validate_prefill_compose so
@@ -550,402 +525,27 @@ class ContinuousEngine:
                 self.prefix_cache = False
         from ..parallel.long_context import prefill_fn_for
 
-        fwd_prefill = prefill_fn_for(spec_, sp_mesh, self.prefill_buckets)
-
-        def _sample_firsts(params, hidden, seq_lens, sampling, key):
-            """Shared prefill tail: last-token logits → sampled first
-            token + logprob, packed into ONE [2, B] int32 buffer (what
-            ``_install_first`` and ``_read_firsts`` take — change it here
-            and BOTH admission programs stay in sync). Sampling happens
-            in-program because eager sampling is a chain of separate
-            dispatches whose launch latencies all land in TTFT."""
-            last = hidden[jnp.arange(hidden.shape[0]), seq_lens - 1]
-            logits = unembed(spec_, params, last)
-            first, lp = sample_tokens_with_logprobs(logits, sampling, key)
-            return jnp.stack(
-                [first, jax.lax.bitcast_convert_type(lp, jnp.int32)])
-
-        @jax.jit
-        def _prefill(params, tokens, seq_lens, sampling, key):
-            hidden, ks, vs = fwd_prefill(spec_, params, tokens, seq_lens)
-            return (_sample_firsts(params, hidden, seq_lens, sampling, key),
-                    ks, vs)
-
-        @partial(jax.jit, donate_argnums=(3, 4))
-        def _prefill_pages(params, tokens, seq_lens, kp, vp, table_rows,
-                           sampling, key):
-            """Fused admission prefill: per-layer KV scatters straight
-            into the (donated) pools inside the layer scan — no
-            [L, bb, T, Hkv, Dh] transient (~2.1 GB at 8B bb=128, the
-            nondeterministic bs128-warmup OOM) and one dispatch instead
-            of prefill + page-write."""
-            hidden, kp, vp = forward_prefill_into_pages(
-                spec_, params, tokens, seq_lens, kp, vp, table_rows)
-            return (_sample_firsts(params, hidden, seq_lens, sampling, key),
-                    kp, vp)
-
-        page_size = self.kv.page_size
-
-        @partial(jax.jit, static_argnames=("n_ctx_pages",))
-        def _prefill_suffix(params, tokens, suffix_lens, n_ctx, phys_pages,
-                            k_pages, v_pages, sampling, key,
-                            n_ctx_pages: int):
-            """Continue partially prefilled sequences: prefill only each
-            row's suffix, attending over its context gathered from its
-            pages (``phys_pages`` [B, n_ctx_pages]). Batched — one program
-            per (batch bucket, suffix bucket, ctx-pages bucket) — shared by
-            prefix-cache hits and the parallel chunked-prefill advance.
-            Rows whose true context is shorter than the page bucket are
-            masked by ``n_ctx`` inside suffix attention."""
-            L = spec_.n_layers
-            Hkv, Dh = spec_.n_kv_heads, spec_.head_dim
-            b = tokens.shape[0]
-            tc = n_ctx_pages * page_size
-            ck = k_pages[:, phys_pages].reshape(L, b, tc, Hkv, Dh)
-            cv = v_pages[:, phys_pages].reshape(L, b, tc, Hkv, Dh)
-            ck = ck.astype(spec_.jnp_dtype)
-            cv = cv.astype(spec_.jnp_dtype)
-            hidden, ks, vs = forward_prefill_suffix(
-                spec_, params, tokens, suffix_lens, n_ctx, ck, cv
-            )
-            last = hidden[jnp.arange(b), suffix_lens - 1]
-            logits = unembed(spec_, params, last)
-            first, lp = sample_tokens_with_logprobs(logits, sampling, key)
-            return jnp.stack(
-                [first, jax.lax.bitcast_convert_type(lp, jnp.int32)]), ks, vs
-
-        # The uniform bodies (resolve_decode_body). ``dense`` and ``window``
-        # freeze the page pools for a chunk and write the chunk's fresh K/V
-        # back once at its end (write_prefill_pages): the per-step page
-        # scatter they replace held decode at ~28% of the dense engine's
-        # throughput at 8B bs64.
-        # - dense: the frozen prefix is gathered from the pages ONCE per
-        #   chunk into a [L, B, Sb+W, Hkv, Dh] working buffer (Sb = a page
-        #   bucket covering the longest live prefix) and the chunk runs the
-        #   static engine's decode against it: one program per (n_steps,
-        #   context-page bucket). The reference the kernel is pinned to.
-        # - window: the kernel's operand is the page pool itself; fresh K/V
-        #   collects in a side window. No dense copy, no per-layer slice,
-        #   and the program does not depend on the context's page bucket
-        #   (n_ctx_pages stays 0: one program per n_steps).
-        # - inline: fresh K/V is scattered into the pages every step (a
-        #   sliding-window prefix mask depends on the growing length).
-        body = self.body
-        fwd_window = partial(forward_decode_window,
-                             interpret=self.attn_impl.endswith("_interpret"))
+        # fused prefill+page-write for batched admissions; the sp path
+        # keeps the two-program shape (ring prefill returns stacked KV)
+        (self._prefill, prefill_pages, self._prefill_suffix,
+         self._decode_chunk, self._install,
+         self._install_first) = build_programs(
+            self.spec,
+            decode_body(self.body, self.spec, self._family, self.attn_impl,
+                        self.kv.page_size, self.max_seq_len),
+            self._family,
+            prefill_fn_for(self.spec, sp_mesh, self.prefill_buckets),
+            self.kv.page_size)
+        self._prefill_pages = None if has_sp else prefill_pages
         # decode chunks dispatched; get_metrics() reports them by how
         # attention reached the context: a Pallas kernel reading the page
         # pool where it lies, or the dense per-chunk copy
         self._decode_chunks = 0
-
-        def _advance(next_tok, lp, lengths, last, active, produced, *,
-                     cap, max_new, eos_ids, stop_mat, use_stops):
-            """Shared post-sample bookkeeping of one decode step."""
-            was_active = active
-            produced = produced + was_active.astype(jnp.int32)
-            hit_eos = (next_tok == eos_ids) & (eos_ids >= 0)
-            new_len = lengths + was_active.astype(jnp.int32)
-            done = (hit_eos | (produced >= max_new)
-                    | (new_len >= cap))
-            if use_stops:
-                # device-side single-token stops ([B, K] stop-id
-                # matrix): a stopped slot goes inactive IN-CHUNK
-                # instead of decoding dead tokens until the host scan
-                # sees it. Static flag: engines with no live stop ids
-                # keep compiling the stop-free program.
-                done = done | ((next_tok[:, None] == stop_mat)
-                               & (stop_mat >= 0)).any(axis=-1)
-            active = was_active & ~done
-            last = jnp.where(was_active, next_tok, last)
-            emitted = jnp.where(was_active, next_tok, -1)
-            lp = jnp.where(was_active, lp, 0.0)
-            return new_len, last, active, produced, emitted, lp
-
-        @partial(jax.jit,
-                 static_argnames=("n_steps", "n_ctx_pages", "use_stops"),
-                 donate_argnums=(1, 2, 3, 4, 5, 6))
-        def _decode_chunk(
-            params, kp, vp, lengths, last_tokens, active, produced,
-            page_table, cap, max_new, sampling, eos_ids, stop_mat, firsts,
-            key, n_steps: int, n_ctx_pages: int = 0,
-            use_stops: bool = False,
-        ):
-            start_lengths = lengths
-            L = spec_.n_layers
-            Hkv, Dh = spec_.n_kv_heads, spec_.head_dim
-            b = lengths.shape[0]
-
-            advance = partial(_advance, cap=cap, max_new=max_new,
-                              eos_ids=eos_ids, stop_mat=stop_mat,
-                              use_stops=use_stops)
-
-            keys = jax.random.split(key, n_steps)
-            if body == "dense":
-                s_ctx = n_ctx_pages * page_size
-                pt = page_table[:, :n_ctx_pages]
-                # one gather per chunk; the buffer stays in the cache dtype
-                # (fp8 upcasts inside attention, fused into the read).
-                # Chunk headroom is clamped at max_seq_len: no slot can
-                # write past it (cap <= max_seq_len), and the whole buffer
-                # is re-read EVERY step — un-clamped, a chunk starting at a
-                # full context bucket would read s_ctx + n_steps wide when
-                # s_ctx already covers every reachable position
-                s_buf = min(s_ctx + n_steps, max(self.max_seq_len, s_ctx))
-                with jax.named_scope("attn.kv_gather"):
-                    ctx_k = kp[:, pt].reshape(L, b, s_ctx, Hkv, Dh)
-                    ctx_v = vp[:, pt].reshape(L, b, s_ctx, Hkv, Dh)
-                    zpad = jnp.zeros((L, b, s_buf - s_ctx, Hkv, Dh),
-                                     ctx_k.dtype)
-                    ctx_k = jnp.concatenate([ctx_k, zpad], axis=2)
-                    ctx_v = jnp.concatenate([ctx_v, zpad], axis=2)
-
-                def step(carry, step_key):
-                    ctx_k, ctx_v, lengths, last, active, produced = carry
-                    # dense in-place decode (models.base.forward_decode):
-                    # slots whose start prefix is shorter than Sb overwrite
-                    # their own gathered garbage; attention masks by length.
-                    # Retired slots keep scattering at their stale length
-                    # into their OWN row (clamped in-bounds) — discarded by
-                    # the zero writeback count below.
-                    hidden, ctx_k, ctx_v = forward_decode(
-                        spec_, params, last, lengths, ctx_k, ctx_v)
-                    logits = unembed(spec_, params, hidden)
-                    next_tok, lp = sample_tokens_with_logprobs(
-                        logits, sampling, step_key)
-                    new_len, last, active, produced, emitted, lp = advance(
-                        next_tok, lp, lengths, last, active, produced)
-                    return ((ctx_k, ctx_v, new_len, last, active, produced),
-                            (emitted, lp))
-
-                carry, (toks, lps) = jax.lax.scan(
-                    step,
-                    (ctx_k, ctx_v, lengths, last_tokens, active, produced),
-                    keys,
-                )
-                ctx_k, ctx_v, lengths, last, active, produced = carry
-                # chunk-end writeback: each slot's fresh KV sits at
-                # [start, start + produced-this-chunk) in its dense row;
-                # the count mask drops everything past it
-                bi = jnp.arange(b)[:, None]
-                idx = start_lengths[:, None] + jnp.arange(n_steps)[None, :]
-                with jax.named_scope("attn.kv_update"):
-                    kp, vp = write_prefill_pages(
-                        kp, vp, ctx_k[:, bi, idx], ctx_v[:, bi, idx],
-                        page_table, lengths - start_lengths,
-                        start=start_lengths,
-                    )
-            else:
-                def step(carry, step_key):
-                    kp, vp, side_k, side_v, lengths, last, active, produced \
-                        = carry
-                    if body == "window":
-                        hidden, side_k, side_v = fwd_window(
-                            spec_, params, last, lengths, start_lengths,
-                            kp, vp, page_table, side_k, side_v, active,
-                        )
-                    else:
-                        hidden, kp, vp = forward_decode_paged(
-                            spec_, params, last, lengths, kp, vp, page_table,
-                            active,
-                        )
-                    logits = unembed(spec_, params, hidden)
-                    next_tok, lp = sample_tokens_with_logprobs(
-                        logits, sampling, step_key)
-                    new_len, last, active, produced, emitted, lp = advance(
-                        next_tok, lp, lengths, last, active, produced)
-                    return ((kp, vp, side_k, side_v, new_len, last, active,
-                             produced), (emitted, lp))
-
-                w = n_steps if body == "window" else 1    # dummy when unused
-                side_k = jnp.zeros((L, b, w, Hkv, Dh), spec_.jnp_dtype)
-                side_v = jnp.zeros_like(side_k)
-                carry, (toks, lps) = jax.lax.scan(
-                    step,
-                    (kp, vp, side_k, side_v, lengths, last_tokens, active,
-                     produced),
-                    keys,
-                )
-                kp, vp, side_k, side_v, lengths, last, active, produced = \
-                    carry
-                if body == "window":
-                    # one batched scatter merges the chunk's fresh KV into
-                    # the pages (0.03 ms at 8B bs64 — vs ~45 ms/step for
-                    # per-step writes); inactive-slot garbage past each
-                    # slot's produced count is dropped by the length mask
-                    with jax.named_scope("attn.kv_update"):
-                        kp, vp = write_prefill_pages(
-                            kp, vp, side_k, side_v, page_table,
-                            lengths - start_lengths, start=start_lengths,
-                        )
-            # pack tokens + logprobs (bitcast) + active flags + lengths +
-            # the firsts buffer into ONE output buffer:
-            # the host makes exactly one blocking read per chunk (each
-            # sync is a full round trip on remote devices)
-            packed = jnp.concatenate(
-                [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
-                 active[None].astype(jnp.int32), lengths[None], firsts],
-                axis=0)
-            return (kp, vp, lengths, last, active, produced), packed
-
-        if spec_.layer_kinds:
-            # ---- per-layer (hybrid) spec: the SAME two programs by name,
-            # signature and packed layout, over the other cache. ``kp`` is
-            # the latent page pool, ``vp`` the per-slot recurrent state
-            # (engine/paged_kv.py); admission, the chunk scan, sampling,
-            # ``_advance`` and the harvest are the shared ones.
-            fam = layered_family(spec_)
-            attn_impl = self.attn_impl
-            # a family with a second page pool (sliding-window layers)
-            # says how many layers a chunk's side window holds and writes
-            # it back into both pools (``write_side``)
-            side_layers = getattr(fam, "side_layers", None)
-
-            @partial(jax.jit, donate_argnums=(3, 4))
-            def _prefill_pages(params, tokens, seq_lens, kp, vp, table_rows,
-                               sampling, key, slot_ids):
-                """Whole prompts at a padded bucket: latent rows into the
-                pages, each row's state as of its TRUE end into its slot."""
-                hidden, kp, vp, moe = fam.forward_prefill_into_pages(
-                    spec_, params, tokens, seq_lens, kp, vp, table_rows,
-                    slot_ids)
-                firsts = _sample_firsts(params, hidden, seq_lens, sampling,
-                                        key)
-                return firsts, kp, vp, moe
-
-            @partial(jax.jit,
-                     static_argnames=("n_steps", "n_ctx_pages",
-                                      "use_stops"),
-                     donate_argnums=(1, 2, 3, 4, 5, 6))
-            def _decode_chunk(
-                params, kp, vp, lengths, last_tokens, active, produced,
-                page_table, cap, max_new, sampling, eos_ids, stop_mat,
-                firsts, key, n_steps: int, n_ctx_pages: int = 0,
-                use_stops: bool = False,
-            ):
-                """``n_steps`` tokens for every live slot. The pages are
-                frozen for the chunk and the family reads its latent or K|V
-                rows where they lie (``decode_context``); fresh rows gather
-                in a side window written back once at the end; the
-                recurrent state rides the scan carry and moves only for
-                rows that are ``active`` at that step. The packed output
-                gains the family's counters for the chunk, a row each (a
-                routed family's MoE counts; rows the attention read)."""
-                del n_ctx_pages        # one program: it reads the live pages
-                start_lengths = lengths
-                b = lengths.shape[0]
-                advance = partial(_advance, cap=cap, max_new=max_new,
-                                  eos_ids=eos_ids, stop_mat=stop_mat,
-                                  use_stops=use_stops)
-                ctx = fam.decode_context(kp, page_table, attn_impl)
-                # a row a step of every layer that keeps K|V or latent
-                # rows: the pool's layers, or all of a family's whose
-                # sliding layers keep theirs in a pool of their own
-                side = jnp.zeros((side_layers(spec_) if side_layers
-                                  else kp.shape[0], b, n_steps,
-                                  kp.shape[-1]), kp.dtype)
-
-                def step(carry, step_key):
-                    side, state, lengths, last, active, produced, moe = carry
-                    hidden, side, state, c = fam.forward_decode_step(
-                        spec_, params, last, lengths, start_lengths, ctx,
-                        side, state, active)
-                    logits = unembed(spec_, params, hidden)
-                    next_tok, lp = sample_tokens_with_logprobs(
-                        logits, sampling, step_key)
-                    new_len, last, active, produced, emitted, lp = advance(
-                        next_tok, lp, lengths, last, active, produced)
-                    return ((side, state, new_len, last, active, produced,
-                             moe + c), (emitted, lp))
-
-                carry, (toks, lps) = jax.lax.scan(
-                    step, (side, vp, lengths, last_tokens, active, produced,
-                           jnp.zeros((fam.DECODE_COUNTERS,), jnp.int32)),
-                    jax.random.split(key, n_steps))
-                side, vp, lengths, last, active, produced, moe = carry
-                if side_layers:
-                    kp, vp = fam.write_side(
-                        kp, vp, side, page_table, lengths - start_lengths,
-                        start_lengths)
-                else:
-                    kp = fam.write_rows_into_pages(
-                        kp, side, page_table, lengths - start_lengths,
-                        start_lengths)
-                packed = jnp.concatenate(
-                    [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
-                     active[None].astype(jnp.int32), lengths[None], firsts,
-                     jnp.broadcast_to(moe[:, None], (moe.shape[0], b))],
-                    axis=0)
-                return (kp, vp, lengths, last, active, produced), packed
-
-        @partial(jax.jit, donate_argnums=tuple(range(11)))
-        def _install(lengths, last, active, produced, max_new, eos,
-                     temps, top_k, top_p, min_p, stops, slots, vals):
-            """All per-slot state writes of a WHOLE admission round in ONE
-            dispatch (an eager .at[].set chain is one dispatch per
-            write). ``slots`` is a padded
-            int32 vector; pad entries hold ``max_slots`` and fall out of
-            range (``mode="drop"``)."""
-            i = slots
-            kw = dict(mode="drop")
-            return (
-                lengths.at[i].set(vals["prompt_len"], **kw),
-                last.at[i].set(vals["first"], **kw),
-                active.at[i].set(True, **kw),
-                produced.at[i].set(1, **kw),
-                max_new.at[i].set(vals["max_new"], **kw),
-                eos.at[i].set(vals["eos"], **kw),
-                temps.at[i].set(vals["temp"], **kw),
-                top_k.at[i].set(vals["top_k"], **kw),
-                top_p.at[i].set(vals["top_p"], **kw),
-                min_p.at[i].set(vals["min_p"], **kw),
-                stops.at[i].set(vals["stops"], **kw),
-            )
-
-        @partial(jax.jit, donate_argnums=tuple(range(12)))
-        def _install_first(lengths, last, active, produced, max_new, eos,
-                           temps, top_k, top_p, min_p, stops, firsts_buf,
-                           slots, vals, first_dev, cols):
-            """The install of a local prefill's rows: like ``_install`` but
-            the first tokens stay ON DEVICE — ``first_dev`` is the prefill
-            program's [2, bb] output, ``cols`` maps each row to its column
-            in it. The tokens seed the decode state directly (and are
-            parked in ``firsts_buf``); the host reads them from
-            ``first_dev`` after the next decode dispatch."""
-            i = slots
-            kw = dict(mode="drop")
-            sel = first_dev[:, cols]               # [2, bb_rows]
-            # a prefill-sampled first token that IS eos must not decode:
-            # the device sees it first, so the slot comes up inactive (the
-            # host retires it when it reads the token)
-            live = (sel[0] != vals["eos"]) | (vals["eos"] < 0)
-            return (
-                lengths.at[i].set(vals["prompt_len"], **kw),
-                last.at[i].set(sel[0], **kw),
-                active.at[i].set(live, **kw),
-                produced.at[i].set(1, **kw),
-                max_new.at[i].set(vals["max_new"], **kw),
-                eos.at[i].set(vals["eos"], **kw),
-                temps.at[i].set(vals["temp"], **kw),
-                top_k.at[i].set(vals["top_k"], **kw),
-                top_p.at[i].set(vals["top_p"], **kw),
-                min_p.at[i].set(vals["min_p"], **kw),
-                stops.at[i].set(vals["stops"], **kw),
-                firsts_buf.at[:, i].set(sel, **kw),
-            )
-
         # page-pool writes donate the pool: an un-donated eager scatter
         # would materialise a full copy of the (possibly multi-GiB) pages
         # on every admission
         self._write_pages = jax.jit(write_prefill_pages,
                                     donate_argnums=(0, 1))
-        self._install = _install
-        self._install_first = _install_first
-        self._prefill = _prefill
-        # fused prefill+page-write for batched admissions; the sp path
-        # keeps the two-program shape (ring prefill returns stacked KV)
-        self._prefill_pages = None if has_sp else _prefill_pages
-        self._prefill_suffix = _prefill_suffix
-        self._decode_chunk = _decode_chunk
 
         # ---- metrics
         self.prefill_stats = LatencyStats()
@@ -1575,28 +1175,21 @@ class ContinuousEngine:
             # pad rows point past the last slot: their state write drops
             slot_ids = np.full((bb,), self.max_slots, np.int32)
             slot_ids[:n] = [b[2] for b in batch]
-            first_dev, kp, vp, moe = self._prefill_pages(
+            first_dev, kp, vp, counters = self._prefill_pages(
                 self.params, jnp.asarray(tokens), seq_dev,
                 *self.kv.pools,
                 jnp.asarray(table_rows), sampling, k0,
                 jnp.asarray(slot_ids),
             )
-            self._prefill_moe.append(moe)
+            self._prefill_counters.append(counters)
             for row in batch:              # the prefill's key blocks
-                if not self._kv_rows:
-                    visited, square = prefill_key_blocks(len(row[3]), tb)
-                    self._mla_prefill_visited += visited
-                    self._mla_prefill_square += square
-                for window, sums in self._kv_prefill_blocks.values():
-                    visited, square = flash_prefill.prefill_key_blocks(
-                        len(row[3]), tb, window)
-                    sums[0] += visited
-                    sums[1] += square
+                self._count(self._family.prefill_sums(
+                    self.spec, len(row[3]), tb).items())
         elif self._prefill_pages is not None:
             # fused path: per-layer KV scatters into the donated pools
             # inside the prefill scan (pad rows' seq_len 0 drops every
             # position, exactly like the two-program path's write)
-            first_dev, kp, vp = self._prefill_pages(
+            first_dev, kp, vp, _ = self._prefill_pages(
                 self.params, jnp.asarray(tokens), seq_dev,
                 self.kv.k_pages, self.kv.v_pages,
                 jnp.asarray(table_rows), sampling, k0,
@@ -2320,6 +1913,13 @@ class ContinuousEngine:
         if prev is not None:
             self._process_packed(prev)
 
+    def _count(self, named) -> None:
+        """Add ``(name, n)`` pairs to the family's counters; no name: an
+        entry nothing reads."""
+        for name, n in named:
+            if name:
+                self._counters[name] += n
+
     def _harvest_chunk(self, entry: _ChunkEntry
                        ) -> Tuple[np.ndarray, Dict[int, bool], bool]:
         """TOKEN half of chunk processing: the blocking host read (short
@@ -2344,25 +1944,21 @@ class ContinuousEngine:
         lps_np = packed_np[n_steps:2 * n_steps].view(np.float32)
         lengths = packed_np[2 * n_steps + 1].tolist()
         self._decode_steps += n_steps
-        if self.spec.layer_kinds:
-            moe = packed_np[2 * n_steps + 4:, 0]
-            # what the attention READ, a paged layer, is counted in the
-            # program: a K|V family's first counter, a latent-row family's
-            # last, after the routed experts' three
-            if self._kv_rows:
-                self._full_table_rows += int(moe[0])
-                if self.kv.window:     # then the experts' three and its own
-                    self._moe_decode_counts += moe[1:4]
-                    self._moe_counts += moe[1:4]
-                    self._window_table_rows += int(moe[4])
-            else:
-                self._moe_decode_counts += moe[:3]
-                self._moe_counts += moe[:3]
-                self._mla_table_rows += int(moe[3])
+        if self._family is not None:
+            chunk = dict(zip(self._family.DECODE_COUNTERS,
+                             packed_np[2 * n_steps + 4:, 0].tolist()))
+            # the one counter a decode chunk feeds twice: its assignments
+            # on held experts are the whole run's (prefills add theirs) and
+            # the decode steps' own
+            if "moe.assignments_held" in chunk:
+                chunk["moe.decode_assignments_held"] = chunk[
+                    "moe.assignments_held"]
+            self._count(chunk.items())
             # prefills dispatched before this chunk have finished
-            while self._prefill_moe:
+            while self._prefill_counters:
                 # graftlint: ok[host-sync-hot-path] 3 ints of a program that ended before the chunk just read
-                self._moe_counts += np.asarray(self._prefill_moe.pop())
+                done = np.asarray(self._prefill_counters.pop()).tolist()
+                self._count(zip(self._family.PREFILL_COUNTERS, done))
 
         book = self._span("engine.harvest.book")  # mirror, appends, stops
         # a row emits from step 0 until it goes inactive and never again
@@ -2370,27 +1966,9 @@ class ContinuousEngine:
         # column: one count a slot, the columns as lists in one call each
         counts_np = (toks_np >= 0).sum(axis=0)
         counts = counts_np.tolist()
-        if self._per_layer:
-            # a row that emitted c tokens and ends at length e attended to
-            # e - c + 1 ... e rows (cached + the chunk's own, its new one
-            # included)
-            ends = packed_np[2 * n_steps + 1]
-            attended = int((counts_np * (ends - counts_np)
-                            + counts_np * (counts_np + 1) // 2).sum())
-            if self._kv_rows:
-                self._full_context_rows += attended
-            else:
-                self._mla_context_rows += attended
-            if self.kv.window:
-                # a token at position p sees min(p + 1, window) rows
-                first = ends - counts_np
-                below = np.clip(np.minimum(ends, self.kv.window) - first, 0,
-                                None)
-                self._window_context_rows += int(
-                    (below * first + below * (below + 1) // 2
-                     + (counts_np - below) * self.kv.window).sum())
-            if self._recurrent:
-                self._state_rows_updated += int(counts_np.sum())
+        if self._family is not None:
+            self._count(self._family.decode_sums(
+                self.spec, counts_np, packed_np[2 * n_steps + 1]).items())
         tok_cols = toks_np.T.tolist()
         lp_cols = lps_np.T.tolist()
         progressed: Dict[int, bool] = {}
@@ -2612,7 +2190,7 @@ class ContinuousEngine:
         self._waiting.clear()
         self._waiting_prefilled.clear()
         self._resumed.clear()           # nothing is left to resume
-        self._prefill_moe.clear()
+        self._prefill_counters.clear()
         while self._swapped:            # release their host reservations
             self._offload.release_swap(self._swapped.popleft().nbytes)
         for slot in list(self._slots):
@@ -2793,6 +2371,12 @@ class ContinuousEngine:
                 "prefetch_hidden_latency_est_s": (
                     self.kv._host_hit_tokens * rate),
             }
+        groups: Dict[str, Dict[str, Any]] = {}
+        for name, n in self._counters.items():
+            group, key = name.split(".")
+            groups.setdefault(group, {})[key] = n
+        if "state" in groups:
+            groups["state"]["step_body"] = self.state_step_body
         return {
             "total_requests": self._total_requests,
             "total_prompt_tokens": self._total_prompt_tokens,
@@ -2816,52 +2400,24 @@ class ContinuousEngine:
             # (no prefill that continues from cached pages): off from the
             # spec, never a page hit
             "prefix_disabled_per_layer": self._prefix_disabled_per_layer,
-            # decode steps the harvested chunks ran, and the routed
-            # experts' counters (zeros for a spec without them): top-k
-            # choices that landed on held experts / all choices, over
-            # prefill and decode; distinct held experts with a row, summed
-            # over expert layers and DECODE steps
+            # decode steps the harvested chunks ran
             "decode_steps": self._decode_steps,
             "decode_chunks": self._decode_chunks,
             # sequences of a per-layer spec re-queued as prompt + tokens
             # when the pool ran dry
             "reprefill_preemptions": self._reprefill_preemptions,
-            # per-layer specs: latent rows the decode steps attended to
-            # (per paged layer), and rows the body read for them; key blocks
-            # their prefills visited, and blocks of the buckets' squares
-            "mla": {"decode_context_rows": self._mla_context_rows,
-                    "decode_table_rows": self._mla_table_rows,
-                    **({"prefill_key_blocks_visited":
-                        self._mla_prefill_visited,
-                        "prefill_key_blocks_bucket":
-                        self._mla_prefill_square}
-                       if self._per_layer and not self._kv_rows else {})},
-            # per-layer specs whose paged layers keep K|V rows: rows the
-            # decode steps attended to (per paged layer) and rows the body
-            # read for them; recurrent specs: (row, step) pairs that moved
-            # a state (per recurrent layer) and the body that moved them
-            # and the key blocks their prefills visited / the blocks of
-            # the buckets' squares, per layer of each kind
-            **({"attn": {"full_context_rows": self._full_context_rows,
-                         "full_table_rows": self._full_table_rows,
-                         **({"window_context_rows":
-                             self._window_context_rows,
-                             "window_table_rows": self._window_table_rows}
-                            if self.kv.window else {}),
-                         **{f"{kind}_prefill_key_blocks_{name}": n
-                            for kind, (_, sums) in
-                            self._kv_prefill_blocks.items()
-                            for name, n in zip(("visited", "bucket"), sums)}}}
-               if self._kv_rows else {}),
-            **({"state": {"rows_updated": self._state_rows_updated,
-                          "step_body": self.state_step_body}}
-               if self._recurrent else {}),
-            "moe": {
-                "assignments_held": int(self._moe_counts[0]),
-                "assignments_total": int(self._moe_counts[1]),
-                "experts_touched": int(self._moe_decode_counts[2]),
-                "decode_assignments_held": int(self._moe_decode_counts[0]),
-            },
+            # the counters by group (see __init__ and
+            # ``models.base.layered_family``). ``mla``: latent rows the
+            # decode steps attended to (per paged layer), rows the body
+            # read for them, key blocks the prefills visited and the blocks
+            # of their buckets' squares; ``attn``: the same of K|V rows, by
+            # layer kind; ``state``: (row, step) pairs that moved a
+            # recurrent state (per recurrent layer) and the body that moved
+            # them; ``moe``: top-k choices that landed on held experts / all
+            # choices, over prefill and decode, and of DECODE steps the
+            # choices held and the distinct held experts with a row, summed
+            # over expert layers
+            **groups,
             "warmup": self.warmup_metrics(),
             "compiles_after_warmup": self._compiles_after_warmup(),
             "prefilling_slots": len(self._prefilling),

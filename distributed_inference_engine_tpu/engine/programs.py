@@ -1,0 +1,442 @@
+"""The device programs a ``ContinuousEngine`` dispatches, built once an
+engine by ``build_programs``: admission (``_prefill``, ``_prefill_pages``,
+``_prefill_suffix``), the slot-state installs (``_install``,
+``_install_first``) and ONE ``_decode_chunk``. The loop that dispatches and
+reads them is ``engine/continuous.py``; what a step computes is
+``models/base.py``'s (a uniform spec) or the family module's (a per-layer
+spec, ``models.base.layered_family``).
+
+The decode chunk is one sequence whatever the cache: split the key, ``begin``,
+``lax.scan`` of (``step`` -> ``unembed`` -> sample -> ``_advance``), ``end``,
+pack. How a step REACHES its cache is a ``DecodeBody``, picked by
+``decode_body`` from the name ``continuous.resolve_decode_body`` resolved:
+
+- ``dense`` and ``window`` freeze the page pools for a chunk and write the
+  chunk's fresh K/V back once at its end (``write_prefill_pages``): the
+  per-step page scatter they replace held decode at ~28% of the dense
+  engine's throughput at 8B bs64.
+- ``dense``: the frozen prefix is gathered from the pages ONCE per chunk
+  into a [L, B, Sb+W, Hkv, Dh] working buffer (Sb = a page bucket covering
+  the longest live prefix) and the chunk runs the static engine's decode
+  against it: one program per (n_steps, context-page bucket). The reference
+  the kernel is pinned to.
+- ``window``: the kernel's operand is the page pool itself; fresh K/V
+  collects in a side window. No dense copy, no per-layer slice, and the
+  program does not depend on the context's page bucket (n_ctx_pages stays 0:
+  one program per n_steps).
+- ``inline``: fresh K/V is scattered into the pages every step (a
+  sliding-window prefix mask depends on the growing length).
+- ``hybrid``: a per-layer spec's family module. The first pool is the
+  family's paged rows, frozen for the chunk and read where they lie
+  (``decode_context``); the chunk's own rows gather in a side window the
+  family writes back once (``write_side``); the second pool is the family's
+  per-slot state, which rides the scan and moves only for rows ``active`` at
+  that step. Its steps return counters, which the chunk sums and packs.
+
+The jitted functions' names are read outside the package (a device trace
+sorts programs by "decode" / "prefill" in the module name): keep them.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Any, Callable, NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..models.base import (
+    ModelSpec,
+    forward_decode,
+    forward_decode_paged,
+    forward_decode_window,
+    forward_prefill_into_pages,
+    forward_prefill_suffix,
+    unembed,
+    write_prefill_pages,
+)
+from ..ops.sampling import sample_tokens_with_logprobs
+
+
+class DecodeBody(NamedTuple):
+    """How the steps of one decode chunk reach the cache.
+
+    ``begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages)`` ->
+    ``(frozen, cache)``: what every step of the chunk reads and none changes
+    (the scan closes over it), and what the scan carries.
+
+    ``step(params, last, lengths, start_lengths, frozen, cache, active)`` ->
+    ``(hidden [B, D], cache, counters)``: one token for every slot;
+    ``counters`` is an int32 vector as long as ``counters`` below, or None.
+
+    ``end(frozen, cache, kp, vp, page_table, lengths, start_lengths)`` ->
+    ``(kp, vp)``: the pools as the chunk leaves them, the chunk's one
+    write-back made (``lengths - start_lengths`` rows a slot), or the pools
+    the scan carried.
+
+    ``counters``: a dotted name for each entry of a step's counter vector
+    (None: an entry nothing reads); empty for a body whose steps count
+    nothing, whose chunk then packs no counter rows."""
+
+    begin: Callable
+    step: Callable
+    end: Callable
+    counters: Tuple[Optional[str], ...] = ()
+    # the per-layer programs have split the chunk's key AFTER their side
+    # window's zeros, the uniform ones before their gather: kept until the
+    # programs' text changes anyway
+    split_after_begin: bool = False
+
+
+def _dense_body(spec: ModelSpec, page_size: int, max_seq_len: int
+                ) -> DecodeBody:
+    L, Hkv, Dh = spec.n_layers, spec.n_kv_heads, spec.head_dim
+
+    def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
+        b = start_lengths.shape[0]
+        s_ctx = n_ctx_pages * page_size
+        pt = page_table[:, :n_ctx_pages]
+        # one gather per chunk; the buffer stays in the cache dtype
+        # (fp8 upcasts inside attention, fused into the read).
+        # Chunk headroom is clamped at max_seq_len: no slot can
+        # write past it (cap <= max_seq_len), and the whole buffer
+        # is re-read EVERY step — un-clamped, a chunk starting at a
+        # full context bucket would read s_ctx + n_steps wide when
+        # s_ctx already covers every reachable position
+        s_buf = min(s_ctx + n_steps, max(max_seq_len, s_ctx))
+        with jax.named_scope("attn.kv_gather"):
+            ctx_k = kp[:, pt].reshape(L, b, s_ctx, Hkv, Dh)
+            ctx_v = vp[:, pt].reshape(L, b, s_ctx, Hkv, Dh)
+            zpad = jnp.zeros((L, b, s_buf - s_ctx, Hkv, Dh), ctx_k.dtype)
+            ctx_k = jnp.concatenate([ctx_k, zpad], axis=2)
+            ctx_v = jnp.concatenate([ctx_v, zpad], axis=2)
+        return n_steps, (ctx_k, ctx_v)
+
+    def step(params, last, lengths, start_lengths, n_steps, cache, active):
+        # dense in-place decode (models.base.forward_decode): slots whose
+        # start prefix is shorter than Sb overwrite their own gathered
+        # garbage; attention masks by length. Retired slots keep scattering
+        # at their stale length into their OWN row (clamped in-bounds) —
+        # discarded by the zero writeback count of ``end``.
+        hidden, ctx_k, ctx_v = forward_decode(spec, params, last, lengths,
+                                              *cache)
+        return hidden, (ctx_k, ctx_v), None
+
+    def end(n_steps, cache, kp, vp, page_table, lengths, start_lengths):
+        # each slot's fresh KV sits at [start, start + produced-this-chunk)
+        # in its dense row; the count mask drops everything past it
+        ctx_k, ctx_v = cache
+        bi = jnp.arange(lengths.shape[0])[:, None]
+        idx = start_lengths[:, None] + jnp.arange(n_steps)[None, :]
+        with jax.named_scope("attn.kv_update"):
+            return write_prefill_pages(
+                kp, vp, ctx_k[:, bi, idx], ctx_v[:, bi, idx], page_table,
+                lengths - start_lengths, start=start_lengths)
+
+    return DecodeBody(begin, step, end)
+
+
+def _pools_with_side(spec: ModelSpec, kp, vp, slots: int, width: int):
+    """The pools and a zeroed side window of ``width`` rows a slot, as the
+    ``window`` and ``inline`` bodies carry them."""
+    side_k = jnp.zeros((spec.n_layers, slots, width, spec.n_kv_heads,
+                        spec.head_dim), spec.jnp_dtype)
+    return kp, vp, side_k, jnp.zeros_like(side_k)
+
+
+def _window_body(spec: ModelSpec, interpret: bool) -> DecodeBody:
+    fwd_window = partial(forward_decode_window, interpret=interpret)
+
+    def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
+        return page_table, _pools_with_side(
+            spec, kp, vp, start_lengths.shape[0], n_steps)
+
+    def step(params, last, lengths, start_lengths, page_table, cache,
+             active):
+        kp, vp, side_k, side_v = cache
+        hidden, side_k, side_v = fwd_window(
+            spec, params, last, lengths, start_lengths, kp, vp, page_table,
+            side_k, side_v, active)
+        return hidden, (kp, vp, side_k, side_v), None
+
+    def end(_frozen, cache, _kp, _vp, page_table, lengths, start_lengths):
+        # one batched scatter merges the chunk's fresh KV into the pages
+        # (0.03 ms at 8B bs64 — vs ~45 ms/step for per-step writes);
+        # inactive-slot garbage past each slot's produced count is dropped
+        # by the length mask
+        kp, vp, side_k, side_v = cache
+        with jax.named_scope("attn.kv_update"):
+            return write_prefill_pages(
+                kp, vp, side_k, side_v, page_table, lengths - start_lengths,
+                start=start_lengths)
+
+    return DecodeBody(begin, step, end)
+
+
+def _inline_body(spec: ModelSpec) -> DecodeBody:
+    def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
+        # one dummy side row a slot, unused: the carry the program has had
+        return page_table, _pools_with_side(
+            spec, kp, vp, start_lengths.shape[0], 1)
+
+    def step(params, last, lengths, start_lengths, page_table, cache,
+             active):
+        kp, vp, side_k, side_v = cache
+        hidden, kp, vp = forward_decode_paged(
+            spec, params, last, lengths, kp, vp, page_table, active)
+        return hidden, (kp, vp, side_k, side_v), None
+
+    def end(_frozen, cache, _kp, _vp, page_table, lengths, start_lengths):
+        return cache[:2]        # every step wrote its own row
+
+    return DecodeBody(begin, step, end)
+
+
+def _family_body(spec: ModelSpec, fam, attn_impl: str) -> DecodeBody:
+    """A per-layer spec's body, over its family module: ``kp`` is the paged
+    pool, ``vp`` the per-slot state (``engine/paged_kv.py``)."""
+
+    def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
+        del n_ctx_pages        # one program: it reads the live pages
+        # a row a step of every layer that keeps K|V or latent rows
+        ctx = fam.decode_context(kp, page_table, attn_impl)
+        side = jnp.zeros((fam.side_layers(spec), start_lengths.shape[0],
+                          n_steps, kp.shape[-1]), kp.dtype)
+        return ctx, (side, vp)
+
+    def step(params, last, lengths, start_lengths, ctx, cache, active):
+        hidden, side, state, counters = fam.forward_decode_step(
+            spec, params, last, lengths, start_lengths, ctx, *cache, active)
+        return hidden, (side, state), counters
+
+    def end(_ctx, cache, kp, _vp, page_table, lengths, start_lengths):
+        side, state = cache
+        return fam.write_side(kp, state, side, page_table,
+                              lengths - start_lengths, start_lengths)
+
+    return DecodeBody(begin, step, end, tuple(fam.DECODE_COUNTERS),
+                      split_after_begin=True)
+
+
+def decode_body(body: str, spec: ModelSpec, fam, attn_impl: str,
+                page_size: int, max_seq_len: int) -> DecodeBody:
+    """The ``DecodeBody`` that ``continuous.resolve_decode_body`` named."""
+    if body == "hybrid":
+        return _family_body(spec, fam, attn_impl)
+    if body == "dense":
+        return _dense_body(spec, page_size, max_seq_len)
+    if body == "window":
+        return _window_body(spec, attn_impl.endswith("_interpret"))
+    return _inline_body(spec)
+
+
+def build_programs(spec: ModelSpec, body: DecodeBody, fam, fwd_prefill,
+                   page_size: int) -> Tuple[Any, ...]:
+    """One engine's jitted programs ``(_prefill, _prefill_pages,
+    _prefill_suffix, _decode_chunk, _install, _install_first)``, closed
+    over its constants: ``body`` from ``decode_body``, ``fam`` the family
+    module of a per-layer spec (None for a uniform one), ``fwd_prefill``
+    what ``prefill_fn_for`` chose."""
+
+    def _sample_firsts(params, hidden, seq_lens, sampling, key):
+        """Shared prefill tail: last-token logits → sampled first
+        token + logprob, packed into ONE [2, B] int32 buffer (what
+        ``_install_first`` and ``_read_firsts`` take — change it here
+        and BOTH admission programs stay in sync). Sampling happens
+        in-program because eager sampling is a chain of separate
+        dispatches whose launch latencies all land in TTFT."""
+        last = hidden[jnp.arange(hidden.shape[0]), seq_lens - 1]
+        logits = unembed(spec, params, last)
+        first, lp = sample_tokens_with_logprobs(logits, sampling, key)
+        return jnp.stack(
+            [first, jax.lax.bitcast_convert_type(lp, jnp.int32)])
+
+    @jax.jit
+    def _prefill(params, tokens, seq_lens, sampling, key):
+        hidden, ks, vs = fwd_prefill(spec, params, tokens, seq_lens)
+        return (_sample_firsts(params, hidden, seq_lens, sampling, key),
+                ks, vs)
+
+    @partial(jax.jit, donate_argnums=(3, 4))
+    def _prefill_pages(params, tokens, seq_lens, kp, vp, table_rows,
+                       sampling, key, slot_ids=None):
+        """Fused admission prefill: per-layer KV scatters straight
+        into the (donated) pools inside the layer scan — no
+        [L, bb, T, Hkv, Dh] transient (~2.1 GB at 8B bb=128, the
+        nondeterministic bs128-warmup OOM) and one dispatch instead
+        of prefill + page-write. A per-layer spec's: whole prompts at a
+        padded bucket, the paged rows into the pages, each row's state as of
+        its TRUE end into its slot (``slot_ids``), and the family's prefill
+        counters (None for a uniform spec)."""
+        if fam is None:
+            hidden, kp, vp = forward_prefill_into_pages(
+                spec, params, tokens, seq_lens, kp, vp, table_rows)
+            counters = None
+        else:
+            hidden, kp, vp, counters = fam.forward_prefill_into_pages(
+                spec, params, tokens, seq_lens, kp, vp, table_rows, slot_ids)
+        return (_sample_firsts(params, hidden, seq_lens, sampling, key),
+                kp, vp, counters)
+
+    @partial(jax.jit, static_argnames=("n_ctx_pages",))
+    def _prefill_suffix(params, tokens, suffix_lens, n_ctx, phys_pages,
+                        k_pages, v_pages, sampling, key,
+                        n_ctx_pages: int):
+        """Continue partially prefilled sequences: prefill only each
+        row's suffix, attending over its context gathered from its
+        pages (``phys_pages`` [B, n_ctx_pages]). Batched — one program
+        per (batch bucket, suffix bucket, ctx-pages bucket) — shared by
+        prefix-cache hits and the parallel chunked-prefill advance.
+        Rows whose true context is shorter than the page bucket are
+        masked by ``n_ctx`` inside suffix attention."""
+        L = spec.n_layers
+        Hkv, Dh = spec.n_kv_heads, spec.head_dim
+        b = tokens.shape[0]
+        tc = n_ctx_pages * page_size
+        ck = k_pages[:, phys_pages].reshape(L, b, tc, Hkv, Dh)
+        cv = v_pages[:, phys_pages].reshape(L, b, tc, Hkv, Dh)
+        ck = ck.astype(spec.jnp_dtype)
+        cv = cv.astype(spec.jnp_dtype)
+        hidden, ks, vs = forward_prefill_suffix(
+            spec, params, tokens, suffix_lens, n_ctx, ck, cv
+        )
+        return (_sample_firsts(params, hidden, suffix_lens, sampling, key),
+                ks, vs)
+
+    def _advance(next_tok, lp, lengths, last, active, produced, *,
+                 cap, max_new, eos_ids, stop_mat, use_stops):
+        """Shared post-sample bookkeeping of one decode step."""
+        was_active = active
+        produced = produced + was_active.astype(jnp.int32)
+        hit_eos = (next_tok == eos_ids) & (eos_ids >= 0)
+        new_len = lengths + was_active.astype(jnp.int32)
+        done = (hit_eos | (produced >= max_new)
+                | (new_len >= cap))
+        if use_stops:
+            # device-side single-token stops ([B, K] stop-id
+            # matrix): a stopped slot goes inactive IN-CHUNK
+            # instead of decoding dead tokens until the host scan
+            # sees it. Static flag: engines with no live stop ids
+            # keep compiling the stop-free program.
+            done = done | ((next_tok[:, None] == stop_mat)
+                           & (stop_mat >= 0)).any(axis=-1)
+        active = was_active & ~done
+        last = jnp.where(was_active, next_tok, last)
+        emitted = jnp.where(was_active, next_tok, -1)
+        lp = jnp.where(was_active, lp, 0.0)
+        return new_len, last, active, produced, emitted, lp
+
+    @partial(jax.jit,
+             static_argnames=("n_steps", "n_ctx_pages", "use_stops"),
+             donate_argnums=(1, 2, 3, 4, 5, 6))
+    def _decode_chunk(
+        params, kp, vp, lengths, last_tokens, active, produced,
+        page_table, cap, max_new, sampling, eos_ids, stop_mat, firsts,
+        key, n_steps: int, n_ctx_pages: int = 0,
+        use_stops: bool = False,
+    ):
+        """``n_steps`` tokens for every live slot, through ``body``."""
+        start_lengths = lengths
+        advance = partial(_advance, cap=cap, max_new=max_new,
+                          eos_ids=eos_ids, stop_mat=stop_mat,
+                          use_stops=use_stops)
+        if not body.split_after_begin:
+            keys = jax.random.split(key, n_steps)
+        frozen, cache = body.begin(kp, vp, page_table, start_lengths,
+                                   n_steps, n_ctx_pages)
+        counters = (jnp.zeros((len(body.counters),), jnp.int32)
+                    if body.counters else None)
+        if body.split_after_begin:
+            keys = jax.random.split(key, n_steps)
+
+        def step(carry, step_key):
+            cache, lengths, last, active, produced, counters = carry
+            hidden, cache, counted = body.step(
+                params, last, lengths, start_lengths, frozen, cache, active)
+            logits = unembed(spec, params, hidden)
+            next_tok, lp = sample_tokens_with_logprobs(
+                logits, sampling, step_key)
+            new_len, last, active, produced, emitted, lp = advance(
+                next_tok, lp, lengths, last, active, produced)
+            if body.counters:
+                counters = counters + counted
+            return ((cache, new_len, last, active, produced, counters),
+                    (emitted, lp))
+
+        carry, (toks, lps) = jax.lax.scan(
+            step, (cache, lengths, last_tokens, active, produced, counters),
+            keys)
+        cache, lengths, last, active, produced, counters = carry
+        kp, vp = body.end(frozen, cache, kp, vp, page_table, lengths,
+                          start_lengths)
+        # pack tokens + logprobs (bitcast) + active flags + lengths +
+        # the firsts buffer (+ the body's counters for the chunk, a row
+        # each) into ONE output buffer: the host makes exactly one
+        # blocking read per chunk (each sync is a full round trip on
+        # remote devices)
+        rows = [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
+                active[None].astype(jnp.int32), lengths[None], firsts]
+        if body.counters:
+            rows.append(jnp.broadcast_to(
+                counters[:, None], (counters.shape[0], lengths.shape[0])))
+        packed = jnp.concatenate(rows, axis=0)
+        return (kp, vp, lengths, last, active, produced), packed
+
+    @partial(jax.jit, donate_argnums=tuple(range(11)))
+    def _install(lengths, last, active, produced, max_new, eos,
+                 temps, top_k, top_p, min_p, stops, slots, vals):
+        """All per-slot state writes of a WHOLE admission round in ONE
+        dispatch (an eager .at[].set chain is one dispatch per
+        write). ``slots`` is a padded
+        int32 vector; pad entries hold ``max_slots`` and fall out of
+        range (``mode="drop"``)."""
+        i = slots
+        kw = dict(mode="drop")
+        return (
+            lengths.at[i].set(vals["prompt_len"], **kw),
+            last.at[i].set(vals["first"], **kw),
+            active.at[i].set(True, **kw),
+            produced.at[i].set(1, **kw),
+            max_new.at[i].set(vals["max_new"], **kw),
+            eos.at[i].set(vals["eos"], **kw),
+            temps.at[i].set(vals["temp"], **kw),
+            top_k.at[i].set(vals["top_k"], **kw),
+            top_p.at[i].set(vals["top_p"], **kw),
+            min_p.at[i].set(vals["min_p"], **kw),
+            stops.at[i].set(vals["stops"], **kw),
+        )
+
+    @partial(jax.jit, donate_argnums=tuple(range(12)))
+    def _install_first(lengths, last, active, produced, max_new, eos,
+                       temps, top_k, top_p, min_p, stops, firsts_buf,
+                       slots, vals, first_dev, cols):
+        """The install of a local prefill's rows: like ``_install`` but
+        the first tokens stay ON DEVICE — ``first_dev`` is the prefill
+        program's [2, bb] output, ``cols`` maps each row to its column
+        in it. The tokens seed the decode state directly (and are
+        parked in ``firsts_buf``); the host reads them from
+        ``first_dev`` after the next decode dispatch."""
+        i = slots
+        kw = dict(mode="drop")
+        sel = first_dev[:, cols]               # [2, bb_rows]
+        # a prefill-sampled first token that IS eos must not decode:
+        # the device sees it first, so the slot comes up inactive (the
+        # host retires it when it reads the token)
+        live = (sel[0] != vals["eos"]) | (vals["eos"] < 0)
+        return (
+            lengths.at[i].set(vals["prompt_len"], **kw),
+            last.at[i].set(sel[0], **kw),
+            active.at[i].set(live, **kw),
+            produced.at[i].set(1, **kw),
+            max_new.at[i].set(vals["max_new"], **kw),
+            eos.at[i].set(vals["eos"], **kw),
+            temps.at[i].set(vals["temp"], **kw),
+            top_k.at[i].set(vals["top_k"], **kw),
+            top_p.at[i].set(vals["top_p"], **kw),
+            min_p.at[i].set(vals["min_p"], **kw),
+            stops.at[i].set(vals["stops"], **kw),
+            firsts_buf.at[:, i].set(sel, **kw),
+        )
+
+    return (_prefill, _prefill_pages, _prefill_suffix, _decode_chunk,
+            _install, _install_first)

@@ -347,12 +347,54 @@ class ModelSpec:
 # ------------------------------------------------- per-layer (hybrid) specs
 
 
+# what ``layered_family`` returns: every name a family module defines
+LAYERED_FAMILY = (
+    "init_params", "init_state", "zero_state_slot",
+    "forward_prefill_into_pages", "PREFILL_COUNTERS", "prefill_sums",
+    "decode_context", "side_layers", "forward_decode_step",
+    "DECODE_COUNTERS", "write_side", "decode_sums")
+
+
+def rows_attended(counts, ends) -> int:
+    """Cached rows the steps of one decode chunk attended to, a paged layer
+    (a host sum: ``counts`` / ``ends`` are numpy [slots], the tokens each
+    slot emitted in the chunk and its length at the chunk's end). A slot
+    that emitted c tokens and ends at length e attended to e - c + 1 ... e
+    rows: the cached ones and the chunk's own, its new one included."""
+    return int((counts * (ends - counts) + counts * (counts + 1) // 2).sum())
+
+
 def layered_family(spec: ModelSpec):
-    """The module that builds and runs a spec with ``layer_kinds``: its
-    ``init_params`` / ``init_state`` / ``zero_state_slot`` and the programs'
-    bodies (``forward_prefill_into_pages``, ``forward_decode_step``,
-    ``decode_context``, ``write_rows_into_pages``). ``engine/`` reaches
-    them here and names no model file. Five families in four modules, told
+    """The module that builds and runs a spec with ``layer_kinds``.
+    ``engine/`` reaches a family here and names no model file; a family
+    module defines every name of ``LAYERED_FAMILY``
+    (``tests/test_xing.py`` holds the five tiny specs to it):
+
+    - ``init_params(spec, key)``; ``init_state(spec, max_slots, ...)``: the
+      second pool (``engine/paged_kv.py``), a dict of arrays that rides the
+      programs' donation and the decode carry; ``zero_state_slot(state,
+      slot)``.
+    - ``forward_prefill_into_pages(spec, params, tokens, seq_lens, pages,
+      state, table_rows, slot_ids)`` -> ``(hidden, pages, state,
+      counters)``; ``PREFILL_COUNTERS`` names the counters, in order.
+    - ``decode_context(pages, page_table, attn_impl)``: what the steps of a
+      chunk read the cached rows from, frozen for the chunk;
+      ``side_layers(spec)``: the layers of the side window ``[layers, slots,
+      steps, row]`` a chunk's own rows gather in; ``forward_decode_step(spec,
+      params, tokens, lengths, start_lengths, ctx, side, state, active)`` ->
+      ``(hidden, side, state, counters)``; ``DECODE_COUNTERS`` names the
+      counters, in order; ``write_side(pages, state, side, page_table,
+      counts, start)`` -> ``(pages, state)``: the chunk's one write-back.
+    - on the host, sums no program counts, as ``{name: int}``:
+      ``decode_sums(spec, counts, ends)`` of one decode chunk (the arrays of
+      ``rows_attended``), ``prefill_sums(spec, prompt_len, bucket)`` of one
+      admitted prompt.
+
+    A counter's name is ``<group>.<key>`` of ``ContinuousEngine
+    .get_metrics()`` (None: an entry nothing reads); the engine sums by
+    name and knows no family's layout.
+
+    Five families in four modules, told
     apart by what the spec holds: "swa" layers (``models/mellum.py``:
     sliding-window layers beside full-attention layers, K|V rows in two
     pools of unlike lifetimes, routed experts everywhere),

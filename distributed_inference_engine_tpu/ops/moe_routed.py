@@ -103,6 +103,14 @@ def default_impl() -> str:
     return "gmm" if jax.default_backend() == "tpu" else "xla"
 
 
+# what the three counters of ``moe_block`` / ``moe_block_held`` are called
+# where an engine sums them (``models.base.layered_family``)
+COUNTERS = ("moe.assignments_held", "moe.assignments_total",
+            "moe.experts_touched")
+# ... of a prefill: the experts touched are reported of decode steps alone
+PREFILL_COUNTERS = COUNTERS[:2] + (None,)
+
+
 def _swiglu(x, w_gate_up, w_down):
     gu = jnp.einsum("nd,df->nf", x, w_gate_up,
                     preferred_element_type=jnp.float32)

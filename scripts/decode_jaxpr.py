@@ -2,7 +2,8 @@
 a ``ContinuousEngine``'s ``_decode_chunk`` (8 steps) and ``_prefill_pages``
 for the specs of the cells at test size: mistral-tiny int4 (no sliding
 window) on the ``window`` body (``pallas-decode_interpret``) and on ``dense``
-(``xla``), ``ling-tiny`` and, since PR 38, ``olmo-hybrid-tiny`` on the kernel
+(``xla``) and, since PR 44, with a sliding window of 64 on ``inline``,
+``ling-tiny`` and, since PR 38, ``olmo-hybrid-tiny`` on the kernel
 and on XLA (``hybrid``; a parent dumped before has no such files: ``compare``
 walks its first directory's); since PR 40 ``xing-tiny`` and ``mellum-tiny``
 (window 32) the same way; since PR 41 ``kimi-tiny`` (12 slots: the plain
@@ -88,13 +89,15 @@ def dump(out: str) -> None:
     os.makedirs(out, exist_ok=True)
     spec = mistral_spec("mistral-tiny", sliding_window=0, max_seq_len=128)
     params = random_quantized_params(spec, jax.random.key(0), bits=4)
-    for name, impl in (("mistral_int4_window", "pallas-decode_interpret"),
-                       ("mistral_int4_dense", "xla")):
+    for name, impl, window in (
+            ("mistral_int4_window", "pallas-decode_interpret", 0),
+            ("mistral_int4_dense", "xla", 0),
+            ("mistral_int4_inline", "auto", 64)):
         cfg = EngineConfig(max_slots=8, max_seq_len=128, page_size=16,
                            num_pages=72, prefill_buckets=[32, 64, 96],
                            decode_steps_per_call=8, attention_impl=impl)
-        _dump_engine(out, name, ContinuousEngine(spec, params=params,
-                                                 config=cfg))
+        _dump_engine(out, name, ContinuousEngine(
+            spec.replace(sliding_window=window), params=params, config=cfg))
     cfg = EngineConfig(max_slots=4, max_seq_len=128, page_size=16,
                        num_pages=40, prefill_buckets=[32, 64],
                        decode_steps_per_call=8)

@@ -29,9 +29,20 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from distributed_inference_engine_tpu.models import xing  # noqa: E402
+from distributed_inference_engine_tpu.engine.paged_kv import (  # noqa: E402
+    PagedKVCache,
+)
+from distributed_inference_engine_tpu.models import (  # noqa: E402
+    ling_spec, xing,
+)
 from distributed_inference_engine_tpu.models.base import (  # noqa: E402
-    layered_family,
+    LAYERED_FAMILY, layered_family,
+)
+from distributed_inference_engine_tpu.models.mellum import (  # noqa: E402
+    mellum_spec,
+)
+from distributed_inference_engine_tpu.models.olmo_hybrid import (  # noqa: E402
+    olmo_hybrid_spec,
 )
 from distributed_inference_engine_tpu.ops import mhc, mla  # noqa: E402
 from perfbench.lib import families  # noqa: E402
@@ -175,6 +186,55 @@ def test_a_reused_slot_serves_a_fresh_sequence(served_f32):
         got = np.concatenate([pre[0], np.stack(dec[slot2])])
         worst, _ = max_diff([got], CFG, served_f32, [second])
     assert worst < F32_TOL, worst
+
+
+_PER_LAYER = {
+    "ling-tiny": lambda: ling_spec("ling-tiny"),
+    "xing-tiny": lambda: xing.xing_spec("xing-tiny", max_seq_len=128),
+    "kimi-tiny": lambda: xing.kimi_spec("kimi-tiny", max_seq_len=128),
+    "olmo-hybrid-tiny": lambda: olmo_hybrid_spec("olmo-hybrid-tiny"),
+    "mellum-tiny": lambda: mellum_spec("mellum-tiny", max_seq_len=256),
+}
+
+
+@pytest.mark.parametrize("size", sorted(_PER_LAYER))
+def test_a_family_module_defines_the_whole_interface(size):
+    """What ``layered_family``'s docstring says a family module holds: every
+    name, counters named once each as ``<group>.<key>``, and as many names
+    as the vectors its two programs' bodies return (traced, not run)."""
+    spec = _PER_LAYER[size]()
+    fam = layered_family(spec)
+    assert [n for n in LAYERED_FAMILY if not hasattr(fam, n)] == []
+    for names in (fam.DECODE_COUNTERS, fam.PREFILL_COUNTERS):
+        named = [n for n in names if n]
+        assert len(set(named)) == len(named)
+        assert all(n.count(".") == 1 for n in named)
+    kv = PagedKVCache(spec, max_slots=2, page_size=8, num_pages=32,
+                      max_seq_len=64)
+    pages, state = kv.pools
+    params = jax.eval_shape(lambda: fam.init_params(spec, jax.random.key(0)))
+    z = jnp.zeros((2,), jnp.int32)
+    side = jnp.zeros((fam.side_layers(spec), 2, 4, pages.shape[-1]),
+                     pages.dtype)
+    step = jax.eval_shape(
+        lambda p: fam.forward_decode_step(
+            spec, p, z, z, z, fam.decode_context(pages, kv.page_table, "xla"),
+            side, state, z > 0), params)
+    assert step[3].shape == (len(fam.DECODE_COUNTERS),)
+    back = jax.eval_shape(
+        lambda: fam.write_side(pages, state, side, kv.page_table, z, z))
+    assert back[0].shape == pages.shape and set(back[1]) == set(state)
+    table_rows = jnp.zeros((2, kv.max_pages_per_seq), jnp.int32)
+    prefill = jax.eval_shape(
+        lambda p: fam.forward_prefill_into_pages(
+            spec, p, jnp.zeros((2, 16), jnp.int32), z + 1, pages, state,
+            table_rows, z), params)
+    assert prefill[3].shape == (len(fam.PREFILL_COUNTERS),)
+    sums = {**fam.decode_sums(spec, np.array([3, 0]), np.array([10, 5])),
+            **fam.prefill_sums(spec, 10, 16)}
+    assert all(n.count(".") == 1 and type(v) is int and v > 0
+               for n, v in sums.items()), sums
+    assert not set(sums) & set(fam.DECODE_COUNTERS + fam.PREFILL_COUNTERS)
 
 
 def test_the_state_is_zero_layers_wide_and_rides_every_program(served_f32):
