@@ -475,12 +475,6 @@ class ContinuousEngine:
         self._top_k = jnp.zeros((n,), jnp.int32)
         self._top_p = jnp.ones((n,), jnp.float32)
         self._min_p = jnp.zeros((n,), jnp.float32)
-        # per-slot [token; logprob-bits] of the prefill-sampled first
-        # token, as ``_install_first`` parks it and the chunk's packed
-        # output carries it. The host reads a first token from the
-        # prefill's own output (_read_firsts), a chunk earlier; the buffer
-        # stays an operand so that both programs are the ones they were.
-        self._firsts_dev = jnp.zeros((2, n), jnp.int32)
         # device-side stop ids (ISSUE 5b): the first _DEVICE_STOP_K
         # single-token stops per slot ride a [n, K] matrix so the decode
         # loop retires a stopped slot IN-CHUNK instead of generating (and
@@ -873,40 +867,29 @@ class ContinuousEngine:
         vals["stops"] = jnp.asarray(stops)
         return bb, jnp.asarray(slots), vals
 
-    def _install_device(self, rows: List[Dict[str, Any]]) -> None:
+    def _install_device(self, rows: List[Dict[str, Any]], cols=None,
+                        first_dev=None) -> None:
         """Install device state for a round of admissions in one dispatch;
-        ``rows`` entries carry slot + per-slot fields."""
-        if not rows:
-            return
-        _bb, slots, vals = self._pack_rows(rows)
-        (self._lengths, self._last, self._active, self._produced,
-         self._max_new, self._eos, self._temps, self._top_k,
-         self._top_p, self._min_p, self._stops_dev) = self._install(
-            self._lengths, self._last, self._active, self._produced,
-            self._max_new, self._eos, self._temps, self._top_k,
-            self._top_p, self._min_p, self._stops_dev, slots, vals,
-        )
-
-    def _install_device_first(self, rows: List[Dict[str, Any]],
-                              cols: List[int], first_dev) -> None:
-        """Device state comes up exactly as in ``_install_device`` but the
-        first tokens are wired from the prefill output ``first_dev``
-        (device) — column ``cols[i]`` for ``rows[i]`` (``vals["first"]``
-        goes unused). No host round trip."""
+        ``rows`` entries carry slot + per-slot fields. With ``first_dev``
+        (a local prefill's output, on the device) the first tokens are
+        wired from it, column ``cols[i]`` for ``rows[i]``
+        (``vals["first"]`` goes unused): no host round trip."""
         if not rows:
             return
         bb, slots, vals = self._pack_rows(rows)
-        cols_np = np.zeros((bb,), np.int32)
-        cols_np[: len(cols)] = cols
+        state = (self._lengths, self._last, self._active, self._produced,
+                 self._max_new, self._eos, self._temps, self._top_k,
+                 self._top_p, self._min_p, self._stops_dev)
+        if first_dev is None:
+            state = self._install(*state, slots, vals)
+        else:
+            cols_np = np.zeros((bb,), np.int32)
+            cols_np[: len(cols)] = cols
+            state = self._install_first(*state, slots, vals, first_dev,
+                                        jnp.asarray(cols_np))
         (self._lengths, self._last, self._active, self._produced,
          self._max_new, self._eos, self._temps, self._top_k,
-         self._top_p, self._min_p, self._stops_dev,
-         self._firsts_dev) = self._install_first(
-            self._lengths, self._last, self._active, self._produced,
-            self._max_new, self._eos, self._temps, self._top_k,
-            self._top_p, self._min_p, self._stops_dev, self._firsts_dev,
-            slots, vals, first_dev, jnp.asarray(cols_np),
-        )
+         self._top_p, self._min_p, self._stops_dev) = state
 
     @staticmethod
     def _slot_row(req: GenerationRequest, slot: int, prompt_len: int,
@@ -969,7 +952,7 @@ class ContinuousEngine:
             self._slots[slot] = state
             install.append(self._slot_row(req, slot, prompt_len, 0))
             cols.append(col)
-        self._install_device_first(install, cols, first_dev)
+        self._install_device(install, cols, first_dev)
         if reads:
             self._first_reads.append((first_dev, t_sent, reads))
 
@@ -1799,7 +1782,7 @@ class ContinuousEngine:
             self.params, *self.kv.pools,
             self._lengths, self._last, self._active, self._produced,
             self.kv.page_table, cap, self._max_new, sampling, self._eos,
-            self._stops_dev, self._firsts_dev, kc, n_steps=n_steps,
+            self._stops_dev, kc, n_steps=n_steps,
             n_ctx_pages=mpb, use_stops=bool(self._stop_slots),
         )
         kp, vp, self._lengths, self._last, self._active, self._produced = carry
@@ -1946,7 +1929,7 @@ class ContinuousEngine:
         self._decode_steps += n_steps
         if self._family is not None:
             chunk = dict(zip(self._family.DECODE_COUNTERS,
-                             packed_np[2 * n_steps + 4:, 0].tolist()))
+                             packed_np[2 * n_steps + 2:, 0].tolist()))
             # the one counter a decode chunk feeds twice: its assignments
             # on held experts are the whole run's (prefills add theirs) and
             # the decode steps' own

@@ -82,10 +82,6 @@ class DecodeBody(NamedTuple):
     step: Callable
     end: Callable
     counters: Tuple[Optional[str], ...] = ()
-    # the per-layer programs have split the chunk's key AFTER their side
-    # window's zeros, the uniform ones before their gather: kept until the
-    # programs' text changes anyway
-    split_after_begin: bool = False
 
 
 def _dense_body(spec: ModelSpec, page_size: int, max_seq_len: int
@@ -136,20 +132,14 @@ def _dense_body(spec: ModelSpec, page_size: int, max_seq_len: int
     return DecodeBody(begin, step, end)
 
 
-def _pools_with_side(spec: ModelSpec, kp, vp, slots: int, width: int):
-    """The pools and a zeroed side window of ``width`` rows a slot, as the
-    ``window`` and ``inline`` bodies carry them."""
-    side_k = jnp.zeros((spec.n_layers, slots, width, spec.n_kv_heads,
-                        spec.head_dim), spec.jnp_dtype)
-    return kp, vp, side_k, jnp.zeros_like(side_k)
-
-
 def _window_body(spec: ModelSpec, interpret: bool) -> DecodeBody:
     fwd_window = partial(forward_decode_window, interpret=interpret)
 
     def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
-        return page_table, _pools_with_side(
-            spec, kp, vp, start_lengths.shape[0], n_steps)
+        side_k = jnp.zeros(
+            (spec.n_layers, start_lengths.shape[0], n_steps,
+             spec.n_kv_heads, spec.head_dim), spec.jnp_dtype)
+        return page_table, (kp, vp, side_k, jnp.zeros_like(side_k))
 
     def step(params, last, lengths, start_lengths, page_table, cache,
              active):
@@ -175,19 +165,16 @@ def _window_body(spec: ModelSpec, interpret: bool) -> DecodeBody:
 
 def _inline_body(spec: ModelSpec) -> DecodeBody:
     def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
-        # one dummy side row a slot, unused: the carry the program has had
-        return page_table, _pools_with_side(
-            spec, kp, vp, start_lengths.shape[0], 1)
+        return page_table, (kp, vp)
 
     def step(params, last, lengths, start_lengths, page_table, cache,
              active):
-        kp, vp, side_k, side_v = cache
         hidden, kp, vp = forward_decode_paged(
-            spec, params, last, lengths, kp, vp, page_table, active)
-        return hidden, (kp, vp, side_k, side_v), None
+            spec, params, last, lengths, *cache, page_table, active)
+        return hidden, (kp, vp), None
 
     def end(_frozen, cache, _kp, _vp, page_table, lengths, start_lengths):
-        return cache[:2]        # every step wrote its own row
+        return cache            # every step wrote its own row
 
     return DecodeBody(begin, step, end)
 
@@ -214,8 +201,7 @@ def _family_body(spec: ModelSpec, fam, attn_impl: str) -> DecodeBody:
         return fam.write_side(kp, state, side, page_table,
                               lengths - start_lengths, start_lengths)
 
-    return DecodeBody(begin, step, end, tuple(fam.DECODE_COUNTERS),
-                      split_after_begin=True)
+    return DecodeBody(begin, step, end, tuple(fam.DECODE_COUNTERS))
 
 
 def decode_body(body: str, spec: ModelSpec, fam, attn_impl: str,
@@ -331,23 +317,19 @@ def build_programs(spec: ModelSpec, body: DecodeBody, fam, fwd_prefill,
              donate_argnums=(1, 2, 3, 4, 5, 6))
     def _decode_chunk(
         params, kp, vp, lengths, last_tokens, active, produced,
-        page_table, cap, max_new, sampling, eos_ids, stop_mat, firsts,
-        key, n_steps: int, n_ctx_pages: int = 0,
-        use_stops: bool = False,
+        page_table, cap, max_new, sampling, eos_ids, stop_mat, key,
+        n_steps: int, n_ctx_pages: int = 0, use_stops: bool = False,
     ):
         """``n_steps`` tokens for every live slot, through ``body``."""
         start_lengths = lengths
         advance = partial(_advance, cap=cap, max_new=max_new,
                           eos_ids=eos_ids, stop_mat=stop_mat,
                           use_stops=use_stops)
-        if not body.split_after_begin:
-            keys = jax.random.split(key, n_steps)
+        keys = jax.random.split(key, n_steps)
         frozen, cache = body.begin(kp, vp, page_table, start_lengths,
                                    n_steps, n_ctx_pages)
         counters = (jnp.zeros((len(body.counters),), jnp.int32)
                     if body.counters else None)
-        if body.split_after_begin:
-            keys = jax.random.split(key, n_steps)
 
         def step(carry, step_key):
             cache, lengths, last, active, produced, counters = carry
@@ -369,74 +351,54 @@ def build_programs(spec: ModelSpec, body: DecodeBody, fam, fwd_prefill,
         cache, lengths, last, active, produced, counters = carry
         kp, vp = body.end(frozen, cache, kp, vp, page_table, lengths,
                           start_lengths)
-        # pack tokens + logprobs (bitcast) + active flags + lengths +
-        # the firsts buffer (+ the body's counters for the chunk, a row
-        # each) into ONE output buffer: the host makes exactly one
-        # blocking read per chunk (each sync is a full round trip on
-        # remote devices)
+        # pack tokens + logprobs (bitcast) + active flags + lengths (+ the
+        # body's counters for the chunk, a row each) into ONE output
+        # buffer: the host makes exactly one blocking read per chunk (each
+        # sync is a full round trip on remote devices)
         rows = [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
-                active[None].astype(jnp.int32), lengths[None], firsts]
+                active[None].astype(jnp.int32), lengths[None]]
         if body.counters:
             rows.append(jnp.broadcast_to(
                 counters[:, None], (counters.shape[0], lengths.shape[0])))
         packed = jnp.concatenate(rows, axis=0)
         return (kp, vp, lengths, last, active, produced), packed
 
-    @partial(jax.jit, donate_argnums=tuple(range(11)))
-    def _install(lengths, last, active, produced, max_new, eos,
-                 temps, top_k, top_p, min_p, stops, slots, vals):
-        """All per-slot state writes of a WHOLE admission round in ONE
-        dispatch (an eager .at[].set chain is one dispatch per
-        write). ``slots`` is a padded
-        int32 vector; pad entries hold ``max_slots`` and fall out of
-        range (``mode="drop"``)."""
-        i = slots
-        kw = dict(mode="drop")
-        return (
-            lengths.at[i].set(vals["prompt_len"], **kw),
-            last.at[i].set(vals["first"], **kw),
-            active.at[i].set(True, **kw),
-            produced.at[i].set(1, **kw),
-            max_new.at[i].set(vals["max_new"], **kw),
-            eos.at[i].set(vals["eos"], **kw),
-            temps.at[i].set(vals["temp"], **kw),
-            top_k.at[i].set(vals["top_k"], **kw),
-            top_p.at[i].set(vals["top_p"], **kw),
-            min_p.at[i].set(vals["min_p"], **kw),
-            stops.at[i].set(vals["stops"], **kw),
-        )
+    def _set_slots(state, slots, vals, first, live):
+        """The per-slot state of ``slots`` as an admission leaves it.
+        ``state``: lengths, last, active, produced, max_new, eos, temps,
+        top_k, top_p, min_p, stops; ``slots`` is a padded int32 vector whose
+        pad entries hold ``max_slots`` and fall out of range
+        (``mode="drop"``)."""
+        values = (vals["prompt_len"], first, live, 1, vals["max_new"],
+                  vals["eos"], vals["temp"], vals["top_k"], vals["top_p"],
+                  vals["min_p"], vals["stops"])
+        return tuple(a.at[slots].set(v, mode="drop")
+                     for a, v in zip(state, values))
 
-    @partial(jax.jit, donate_argnums=tuple(range(12)))
-    def _install_first(lengths, last, active, produced, max_new, eos,
-                       temps, top_k, top_p, min_p, stops, firsts_buf,
-                       slots, vals, first_dev, cols):
-        """The install of a local prefill's rows: like ``_install`` but
-        the first tokens stay ON DEVICE — ``first_dev`` is the prefill
-        program's [2, bb] output, ``cols`` maps each row to its column
-        in it. The tokens seed the decode state directly (and are
-        parked in ``firsts_buf``); the host reads them from
-        ``first_dev`` after the next decode dispatch."""
-        i = slots
-        kw = dict(mode="drop")
-        sel = first_dev[:, cols]               # [2, bb_rows]
+    @partial(jax.jit, donate_argnums=tuple(range(11)))
+    def _install(*args):
+        """``(*state, slots, vals)``: all per-slot state writes of a WHOLE
+        admission round in ONE dispatch (an eager .at[].set chain is one
+        dispatch per write), the first tokens from the host
+        (``vals["first"]``)."""
+        *state, slots, vals = args
+        return _set_slots(state, slots, vals, vals["first"], True)
+
+    @partial(jax.jit, donate_argnums=tuple(range(11)))
+    def _install_first(*args):
+        """``(*state, slots, vals, first_dev, cols)``: the install of a
+        local prefill's rows, like ``_install`` but the first tokens stay
+        ON DEVICE — ``first_dev`` is the prefill program's [2, bb] output,
+        ``cols`` maps each row to its column in it. The tokens seed the
+        decode state directly; the host reads them from ``first_dev`` after
+        the next decode dispatch."""
+        *state, slots, vals, first_dev, cols = args
+        first = first_dev[0, cols]
         # a prefill-sampled first token that IS eos must not decode:
         # the device sees it first, so the slot comes up inactive (the
         # host retires it when it reads the token)
-        live = (sel[0] != vals["eos"]) | (vals["eos"] < 0)
-        return (
-            lengths.at[i].set(vals["prompt_len"], **kw),
-            last.at[i].set(sel[0], **kw),
-            active.at[i].set(live, **kw),
-            produced.at[i].set(1, **kw),
-            max_new.at[i].set(vals["max_new"], **kw),
-            eos.at[i].set(vals["eos"], **kw),
-            temps.at[i].set(vals["temp"], **kw),
-            top_k.at[i].set(vals["top_k"], **kw),
-            top_p.at[i].set(vals["top_p"], **kw),
-            min_p.at[i].set(vals["min_p"], **kw),
-            stops.at[i].set(vals["stops"], **kw),
-            firsts_buf.at[:, i].set(sel, **kw),
-        )
+        live = (first != vals["eos"]) | (vals["eos"] < 0)
+        return _set_slots(state, slots, vals, first, live)
 
     return (_prefill, _prefill_pages, _prefill_suffix, _decode_chunk,
             _install, _install_first)

@@ -73,9 +73,10 @@ __all__ = ["olmo_hybrid_spec", "init_params", "init_state", "zero_state_slot",
 Params = Dict[str, Any]
 State = Dict[str, jnp.ndarray]
 
-# a decode step's counters: K|V rows the full layers read, a layer, and two
-# zeros where a routed family's programs carry theirs; a prefill's: three
-DECODE_COUNTERS = ("attn.full_table_rows", None, None)
+# a decode step's counter: K|V rows the full layers read, a layer; a
+# prefill returns three zeros (where a routed family's programs carry the
+# experts')
+DECODE_COUNTERS = ("attn.full_table_rows",)
 PREFILL_COUNTERS = (None, None, None)
 PERIOD = 4      # published layer_types: 3 linear_attention + 1 full_attention
 
@@ -477,8 +478,8 @@ def forward_decode_step(
     moe_impl: str = "",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, State, jnp.ndarray]:
     """One token for every slot. Returns (hidden [B, D], side, state, the
-    family's three counters: K|V rows the full layers' attention read, a
-    layer, and two zeros); rows not ``active`` leave side and state alone."""
+    family's counter [1]: K|V rows the full layers' attention read, a
+    layer); rows not ``active`` leave side and state alone."""
     del moe_impl
     x = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
     side_idx = lengths - start_lengths
@@ -517,5 +518,5 @@ def forward_decode_step(
         period, (x, side, S.reshape(-1, *S.shape[2:]), state["conv"],
                  jnp.int32(0)),
         (params["period"], jnp.arange(n)))
-    counters = jnp.zeros((3,), jnp.int32).at[0].set(rows_read // n)
-    return x, side, {"S": S_flat.reshape(S.shape), "conv": conv}, counters
+    return (x, side, {"S": S_flat.reshape(S.shape), "conv": conv},
+            (rows_read // n)[None])

@@ -12,22 +12,36 @@ entry, run THIS file over its package (``PYTHONPATH=<parent> python <this
 file> dump <dir>``).
 
     JAX_PLATFORMS=cpu python -m scripts.decode_jaxpr dump <dir>
-    python -m scripts.decode_jaxpr compare <dir-a> <dir-b>
+    python -m scripts.decode_jaxpr compare <dir-a> <dir-b> [-v]
 
 A PR that must not change those programs dumps in a copy of its parent
 (``git archive``) and in its own tree, then compares: equal files mean equal
 programs, without the chip. ``compare`` drops equations whose only output is
 ``_`` (traced, read by nothing: XLA removes them) from both sides and says how
-many each held.
+many each held. Of two files that differ it counts the equations that differ
+by more than the NAMES of variables (an operand fewer renames every variable
+after it and moves the line breaks: the names are struck out of both sides
+and each equation laid on one line first), and ``-v`` prints them.
 """
 
 from __future__ import annotations
 
+import difflib
 import os
 import re
 import sys
 
 _DEAD = re.compile(r"^\s*_:[^\n]* = \w+\[\n(?:[^\n\]]*\n)*?\s*\] \w+\n", re.M)
+# a variable: one to four letters, not where a primitive stands (after
+# "= "), not a type (after ":", "<", "{") and not a parameter's key
+_NAME = re.compile(r"(?<!= )(?<![\w.:<{])[a-z]{1,4}(?![\w=(.])")
+_EQUATION = re.compile(r" (?=(?:%:\S+ )+= |\{ lambda|in \()")
+
+
+def _equations(text: str):
+    """The text with variables' names struck out, an equation a line."""
+    flat = re.sub(r"\s+", " ", _NAME.sub("%", text))
+    return _EQUATION.sub("\n", flat).splitlines()
 
 
 def _dump_engine(out: str, name: str, eng) -> None:
@@ -45,11 +59,13 @@ def _dump_engine(out: str, name: str, eng) -> None:
         return eng._decode_chunk(*args, n_steps=8, n_ctx_pages=pages,
                                  use_stops=True)
 
+    # a package before PR 44's second commit passes a buffer of first
+    # tokens through the chunk
+    firsts = [eng._firsts_dev] if hasattr(eng, "_firsts_dev") else []
     text = jax.make_jaxpr(decode)(
         eng.params, *kv.pools, eng._lengths, eng._last, eng._active,
         eng._produced, kv.page_table, jnp.zeros((n,), jnp.int32),
-        eng._max_new, sampling, eng._eos, eng._stops_dev, eng._firsts_dev,
-        key)
+        eng._max_new, sampling, eng._eos, eng._stops_dev, *firsts, key)
     with open(os.path.join(out, f"{name}.decode.txt"), "w") as f:
         f.write(str(text))
     bb, tb = 2, 32
@@ -126,7 +142,7 @@ def dump(out: str) -> None:
             xing.kimi_spec("kimi-tiny", max_seq_len=128), config=cfg))
 
 
-def compare(a: str, b: str) -> bool:
+def compare(a: str, b: str, verbose: bool = False) -> bool:
     same = True
     for name in sorted(os.listdir(a)):
         with open(os.path.join(a, name)) as f:
@@ -138,6 +154,13 @@ def compare(a: str, b: str) -> bool:
         print(f"{name}: byte_equal={ta == tb} equal_without_dead={equal} "
               f"dead_equations a={len(_DEAD.findall(ta))} "
               f"b={len(_DEAD.findall(tb))} chars={len(tb)}")
+        if not equal:
+            lines = [d for d in difflib.unified_diff(
+                _equations(ta), _equations(tb), lineterm="", n=0)
+                if d[0] in "+-" and d[:3] not in ("+++", "---")]
+            print(f"  equations_differing_beyond_names={len(lines)}")
+            if verbose:
+                print("\n".join("    " + d for d in lines))
     return same
 
 
@@ -145,8 +168,9 @@ def main(argv) -> int:
     if len(argv) == 2 and argv[0] == "dump":
         dump(argv[1])
         return 0
-    if len(argv) == 3 and argv[0] == "compare":
-        return 0 if compare(argv[1], argv[2]) else 1
+    if len(argv) in (3, 4) and argv[0] == "compare" and argv[3:] in (
+            [], ["-v"]):
+        return 0 if compare(argv[1], argv[2], bool(argv[3:])) else 1
     print(__doc__, file=sys.stderr)
     return 2
 

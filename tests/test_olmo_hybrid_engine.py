@@ -150,7 +150,7 @@ def test_the_spans_are_in_the_programs():
     dec = eng._decode_chunk.lower(
         eng.params, *kv.pools, eng._lengths, eng._last, eng._active,
         eng._produced, kv.page_table, jnp.zeros((n,), jnp.int32),
-        eng._max_new, sampling, eng._eos, eng._stops_dev, eng._firsts_dev,
+        eng._max_new, sampling, eng._eos, eng._stops_dev,
         jax.random.key(0), n_steps=4).as_text(debug_info=True)
     for scope in ("attn.gdn.step", "recurrence", "attn.full", "flash_decode",
                   "attn.kv_update", "attn.kv_gather", "state.update",
