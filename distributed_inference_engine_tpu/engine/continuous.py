@@ -43,8 +43,10 @@ from ..config import EngineConfig, validate_prefill_compose
 from ..models.base import (
     ModelSpec,
     Params,
+    decode_sums,
     init_params,
     layered_family,
+    prefill_sums,
     write_prefill_pages,
 )
 from ..ops import kda
@@ -55,7 +57,7 @@ from ..utils.hotpath import hot_path
 from ..utils.tracing import LatencyStats
 from .engine import _next_bucket, _pow2_buckets
 from .paged_kv import PagedKVCache, page_chain_hashes
-from .programs import build_programs, decode_body
+from .programs import build_programs
 from .types import (
     EngineOverloadedError,
     GenerationRequest,
@@ -385,15 +387,15 @@ class ContinuousEngine:
         # ``get_metrics()``: what a decode chunk's packed output carries
         # (the family's DECODE_COUNTERS, no read of their own), what its
         # prefills return (PREFILL_COUNTERS) and the host sums of each
-        # (``decode_sums`` / ``prefill_sums``, whose names an empty chunk
-        # and prompt give)
+        # (``models.base.decode_sums`` / ``prefill_sums``, whose names an
+        # empty chunk and prompt give)
         fam = self._family
         no_rows = np.zeros((0,), np.int64)
         self._counters: Dict[str, int] = dict.fromkeys(
             _COUNTERS_OF_EVERY_SPEC + (() if fam is None else tuple(
                 n for n in (*fam.DECODE_COUNTERS, *fam.PREFILL_COUNTERS,
-                            *fam.decode_sums(self.spec, no_rows, no_rows),
-                            *fam.prefill_sums(self.spec, 0, 1)) if n)), 0)
+                            *decode_sums(self.spec, no_rows, no_rows),
+                            *prefill_sums(self.spec, 0, 1)) if n)), 0)
         self._decode_steps = 0
         # prefill programs' counters stay on the device until a chunk's
         # harvest has synced past them (read then, without a wait)
@@ -524,12 +526,9 @@ class ContinuousEngine:
         (self._prefill, prefill_pages, self._prefill_suffix,
          self._decode_chunk, self._install,
          self._install_first) = build_programs(
-            self.spec,
-            decode_body(self.body, self.spec, self._family, self.attn_impl,
-                        self.kv.page_size, self.max_seq_len),
-            self._family,
+            self.spec, self.body, self.attn_impl, self._family,
             prefill_fn_for(self.spec, sp_mesh, self.prefill_buckets),
-            self.kv.page_size)
+            self.kv.page_size, self.max_seq_len)
         self._prefill_pages = None if has_sp else prefill_pages
         # decode chunks dispatched; get_metrics() reports them by how
         # attention reached the context: a Pallas kernel reading the page
@@ -1134,57 +1133,33 @@ class ContinuousEngine:
                                  prefill_tokens=sum(len(b[3])
                                                     for b in batch))
         self._prefill_calls += 1
-        tokens = np.zeros((bb, tb), np.int32)
-        seq_lens = np.zeros((bb,), np.int32)
-        temps = np.zeros((bb,), np.float32)
-        top_k = np.zeros((bb,), np.int32)
-        top_p = np.ones((bb,), np.float32)
-        min_p = np.zeros((bb,), np.float32)
-        table_rows = np.zeros((bb, self.kv.max_pages_per_seq), np.int32)
-        for i, (req, _cb, slot, prompt, _ts, _full) in enumerate(batch):
-            tokens[i, : len(prompt)] = prompt
-            seq_lens[i] = len(prompt)
-            temps[i] = req.temperature
-            top_k[i] = req.top_k
-            top_p[i] = req.top_p
-            min_p[i] = req.min_p
-            table_rows[i] = self.kv._table[slot]
-        sampling = SamplingParams(jnp.asarray(temps), jnp.asarray(top_k),
-                                  jnp.asarray(top_p), jnp.asarray(min_p))
+        tokens, seq_dev, table_rows, sampling = self._padded_rows(
+            bb, tb, [(b[3], b[0], b[2]) for b in batch])
         self._rng, k0 = jax.random.split(self._rng)
-        seq_dev = jnp.asarray(seq_lens)
         self.kv.sync_tiers()           # flush host-tier traffic pre-write
-        if self.spec.layer_kinds:
-            # pad rows point past the last slot: their state write drops
-            slot_ids = np.full((bb,), self.max_slots, np.int32)
-            slot_ids[:n] = [b[2] for b in batch]
-            first_dev, kp, vp, counters = self._prefill_pages(
-                self.params, jnp.asarray(tokens), seq_dev,
-                *self.kv.pools,
-                jnp.asarray(table_rows), sampling, k0,
-                jnp.asarray(slot_ids),
-            )
-            self._prefill_counters.append(counters)
-            for row in batch:              # the prefill's key blocks
-                self._count(self._family.prefill_sums(
-                    self.spec, len(row[3]), tb).items())
-        elif self._prefill_pages is not None:
+        if self._prefill_pages is not None:
             # fused path: per-layer KV scatters into the donated pools
             # inside the prefill scan (pad rows' seq_len 0 drops every
             # position, exactly like the two-program path's write)
-            first_dev, kp, vp, _ = self._prefill_pages(
-                self.params, jnp.asarray(tokens), seq_dev,
-                self.kv.k_pages, self.kv.v_pages,
-                jnp.asarray(table_rows), sampling, k0,
-            )
+            slot_ids = None
+            if self._family is not None:
+                # pad rows point past the last slot: their state write drops
+                slot_ids = np.full((bb,), self.max_slots, np.int32)
+                slot_ids[:n] = [b[2] for b in batch]
+                for row in batch:              # the prefill's key blocks
+                    self._count(prefill_sums(
+                        self.spec, len(row[3]), tb).items())
+            first_dev, kp, vp, counters = self._prefill_pages(
+                self.params, tokens, seq_dev, *self.kv.pools, table_rows,
+                sampling, k0, slot_ids)
+            if counters is not None:
+                self._prefill_counters.append(counters)
         else:                      # sp: ring prefill returns stacked KV
             first_dev, ks, vs = self._prefill(
-                self.params, jnp.asarray(tokens), seq_dev, sampling, k0
-            )
+                self.params, tokens, seq_dev, sampling, k0)
             kp, vp = self._write_pages(
-                self.kv.k_pages, self.kv.v_pages, ks, vs,
-                jnp.asarray(table_rows), seq_dev,
-            )
+                self.kv.k_pages, self.kv.v_pages, ks, vs, table_rows,
+                seq_dev)
         self.kv.swap(kp, vp)
         t_admit = time.perf_counter()    # slots held, prefill dispatched
         self._tl_record(sp, program=("prefill", bb, tb))
@@ -1205,6 +1180,30 @@ class ContinuousEngine:
             rows.append((req, cb, slot, len(prompt), t_submit, t_admit, i))
         self._seat(rows, first_dev, sp.t0)
 
+    def _padded_rows(self, bb: int, tb: int, rows):
+        """A prefill program's operands for ``rows`` of (tokens, request,
+        slot), padded to ``bb`` rows of ``tb``: tokens [bb, tb], lengths
+        [bb], the slots' page-table rows, the requests' sampling."""
+        tokens = np.zeros((bb, tb), np.int32)
+        lens = np.zeros((bb,), np.int32)
+        table_rows = np.zeros((bb, self.kv.max_pages_per_seq), np.int32)
+        temps = np.zeros((bb,), np.float32)
+        top_k = np.zeros((bb,), np.int32)
+        top_p = np.ones((bb,), np.float32)
+        min_p = np.zeros((bb,), np.float32)
+        for i, (toks, req, slot) in enumerate(rows):
+            tokens[i, : len(toks)] = toks
+            lens[i] = len(toks)
+            table_rows[i] = self.kv._table[slot]
+            temps[i] = req.temperature
+            top_k[i] = req.top_k
+            top_p[i] = req.top_p
+            min_p[i] = req.min_p
+        return (jnp.asarray(tokens), jnp.asarray(lens),
+                jnp.asarray(table_rows),
+                SamplingParams(jnp.asarray(temps), jnp.asarray(top_k),
+                               jnp.asarray(top_p), jnp.asarray(min_p)))
+
     def _run_suffix_prefill(self, suffixes, slots, n_ctxs, reqs, key):
         """Run ONE jitted suffix-prefill over N partially prefilled
         sequences: row i's ``suffixes[i]`` continues ``n_ctxs[i]`` tokens
@@ -1220,41 +1219,24 @@ class ContinuousEngine:
                           self.prefill_buckets)
         mpb = _next_bucket(max(c // self.kv.page_size for c in n_ctxs),
                            self._ctx_page_buckets)
-        tokens = np.zeros((bb, tb), np.int32)
-        suffix_lens = np.zeros((bb,), np.int32)
+        tokens, lens_dev, table_rows, sampling = self._padded_rows(
+            bb, tb, list(zip(suffixes, reqs, slots)))
         n_ctx = np.zeros((bb,), np.int32)
-        phys = np.zeros((bb, mpb), np.int32)
-        table_rows = np.zeros((bb, self.kv.max_pages_per_seq), np.int32)
-        temps = np.zeros((bb,), np.float32)
-        top_k = np.zeros((bb,), np.int32)
-        top_p = np.ones((bb,), np.float32)
-        min_p = np.zeros((bb,), np.float32)
-        for i, (suffix, slot, ctx, req) in enumerate(
-                zip(suffixes, slots, n_ctxs, reqs)):
-            tokens[i, : len(suffix)] = suffix
-            suffix_lens[i] = len(suffix)
-            n_ctx[i] = ctx
-            phys[i] = self.kv._table[slot, :mpb]
-            table_rows[i] = self.kv._table[slot]
-            temps[i] = req.temperature
-            top_k[i] = req.top_k
-            top_p[i] = req.top_p
-            min_p[i] = req.min_p
-        sampling = SamplingParams(jnp.asarray(temps), jnp.asarray(top_k),
-                                  jnp.asarray(top_p), jnp.asarray(min_p))
-        lens_dev = jnp.asarray(suffix_lens)
+        n_ctx[:n] = n_ctxs
         ctx_dev = jnp.asarray(n_ctx)
+        phys = np.zeros((bb, mpb), np.int32)
+        phys[:n] = self.kv._table[list(slots), :mpb]
         # flush host-tier traffic: staged uploads (host prefix hits) must
         # land before the suffix program reads its context pages
         self.kv.sync_tiers()
         first_dev, ks, vs = self._prefill_suffix(
-            self.params, jnp.asarray(tokens), lens_dev, ctx_dev,
-            jnp.asarray(phys), self.kv.k_pages, self.kv.v_pages,
-            sampling, key, n_ctx_pages=mpb,
+            self.params, tokens, lens_dev, ctx_dev, jnp.asarray(phys),
+            self.kv.k_pages, self.kv.v_pages, sampling, key,
+            n_ctx_pages=mpb,
         )
         kp, vp = self._write_pages(
-            self.kv.k_pages, self.kv.v_pages, ks, vs,
-            jnp.asarray(table_rows), lens_dev, start=ctx_dev,
+            self.kv.k_pages, self.kv.v_pages, ks, vs, table_rows, lens_dev,
+            start=ctx_dev,
         )
         self.kv.swap(kp, vp)
         return first_dev
@@ -1897,8 +1879,7 @@ class ContinuousEngine:
             self._process_packed(prev)
 
     def _count(self, named) -> None:
-        """Add ``(name, n)`` pairs to the family's counters; no name: an
-        entry nothing reads."""
+        """Add ``(name, n)`` pairs to the counters (no name: no reader)."""
         for name, n in named:
             if name:
                 self._counters[name] += n
@@ -1927,9 +1908,17 @@ class ContinuousEngine:
         lps_np = packed_np[n_steps:2 * n_steps].view(np.float32)
         lengths = packed_np[2 * n_steps + 1].tolist()
         self._decode_steps += n_steps
+        book = self._span("engine.harvest.book")  # mirror, appends, stops
+        # a row emits from step 0 until it goes inactive and never again
+        # in the chunk (_advance), so its tokens are a PREFIX of its
+        # column: one count a slot, the columns as lists in one call each
+        counts_np = (toks_np >= 0).sum(axis=0)
+        counts = counts_np.tolist()
         if self._family is not None:
             chunk = dict(zip(self._family.DECODE_COUNTERS,
-                             packed_np[2 * n_steps + 2:, 0].tolist()))
+                             packed_np[2 * n_steps + 2:, 0].tolist()),
+                         **decode_sums(self.spec, counts_np,
+                                       packed_np[2 * n_steps + 1]))
             # the one counter a decode chunk feeds twice: its assignments
             # on held experts are the whole run's (prefills add theirs) and
             # the decode steps' own
@@ -1942,16 +1931,6 @@ class ContinuousEngine:
                 # graftlint: ok[host-sync-hot-path] 3 ints of a program that ended before the chunk just read
                 done = np.asarray(self._prefill_counters.pop()).tolist()
                 self._count(zip(self._family.PREFILL_COUNTERS, done))
-
-        book = self._span("engine.harvest.book")  # mirror, appends, stops
-        # a row emits from step 0 until it goes inactive and never again
-        # in the chunk (_advance), so its tokens are a PREFIX of its
-        # column: one count a slot, the columns as lists in one call each
-        counts_np = (toks_np >= 0).sum(axis=0)
-        counts = counts_np.tolist()
-        if self._family is not None:
-            self._count(self._family.decode_sums(
-                self.spec, counts_np, packed_np[2 * n_steps + 1]).items())
         tok_cols = toks_np.T.tolist()
         lp_cols = lps_np.T.tolist()
         progressed: Dict[int, bool] = {}
@@ -2389,17 +2368,10 @@ class ContinuousEngine:
             # sequences of a per-layer spec re-queued as prompt + tokens
             # when the pool ran dry
             "reprefill_preemptions": self._reprefill_preemptions,
-            # the counters by group (see __init__ and
-            # ``models.base.layered_family``). ``mla``: latent rows the
-            # decode steps attended to (per paged layer), rows the body
-            # read for them, key blocks the prefills visited and the blocks
-            # of their buckets' squares; ``attn``: the same of K|V rows, by
-            # layer kind; ``state``: (row, step) pairs that moved a
-            # recurrent state (per recurrent layer) and the body that moved
-            # them; ``moe``: top-k choices that landed on held experts / all
-            # choices, over prefill and decode, and of DECODE steps the
-            # choices held and the distinct held experts with a row, summed
-            # over expert layers
+            # the families' counters by group (see __init__; what each key
+            # holds: ``docs/observability.md``): ``mla`` and ``moe`` of every
+            # spec, ``attn`` where the paged layers keep K|V rows, ``state``
+            # where some layers are recurrent
             **groups,
             "warmup": self.warmup_metrics(),
             "compiles_after_warmup": self._compiles_after_warmup(),

@@ -8,30 +8,13 @@ spec, ``models.base.layered_family``).
 
 The decode chunk is one sequence whatever the cache: split the key, ``begin``,
 ``lax.scan`` of (``step`` -> ``unembed`` -> sample -> ``_advance``), ``end``,
-pack. How a step REACHES its cache is a ``DecodeBody``, picked by
-``decode_body`` from the name ``continuous.resolve_decode_body`` resolved:
-
-- ``dense`` and ``window`` freeze the page pools for a chunk and write the
-  chunk's fresh K/V back once at its end (``write_prefill_pages``): the
-  per-step page scatter they replace held decode at ~28% of the dense
-  engine's throughput at 8B bs64.
-- ``dense``: the frozen prefix is gathered from the pages ONCE per chunk
-  into a [L, B, Sb+W, Hkv, Dh] working buffer (Sb = a page bucket covering
-  the longest live prefix) and the chunk runs the static engine's decode
-  against it: one program per (n_steps, context-page bucket). The reference
-  the kernel is pinned to.
-- ``window``: the kernel's operand is the page pool itself; fresh K/V
-  collects in a side window. No dense copy, no per-layer slice, and the
-  program does not depend on the context's page bucket (n_ctx_pages stays 0:
-  one program per n_steps).
-- ``inline``: fresh K/V is scattered into the pages every step (a
-  sliding-window prefix mask depends on the growing length).
-- ``hybrid``: a per-layer spec's family module. The first pool is the
-  family's paged rows, frozen for the chunk and read where they lie
-  (``decode_context``); the chunk's own rows gather in a side window the
-  family writes back once (``write_side``); the second pool is the family's
-  per-slot state, which rides the scan and moves only for rows ``active`` at
-  that step. Its steps return counters, which the chunk sums and packs.
+pack. How a step REACHES its cache is a ``DecodeBody``, picked by the name
+``continuous.resolve_decode_body`` resolved.
+``dense``, ``window`` and ``hybrid`` freeze the page pools for a chunk and
+write the chunk's fresh rows back once at its end: the per-step page scatter
+they replace (``inline`` still makes it: a sliding-window prefix mask depends
+on the growing length) held decode at ~28% of the dense engine's throughput
+at 8B bs64.
 
 The jitted functions' names are read outside the package (a device trace
 sorts programs by "decode" / "prefill" in the module name): keep them.
@@ -86,6 +69,11 @@ class DecodeBody(NamedTuple):
 
 def _dense_body(spec: ModelSpec, page_size: int, max_seq_len: int
                 ) -> DecodeBody:
+    """XLA over a copy: the frozen prefix is gathered from the pages ONCE
+    per chunk into a [L, B, Sb+W, Hkv, Dh] working buffer (Sb = a page
+    bucket covering the longest live prefix) and the chunk runs the static
+    engine's decode against it: one program per (n_steps, context-page
+    bucket). The reference the kernel is pinned to."""
     L, Hkv, Dh = spec.n_layers, spec.n_kv_heads, spec.head_dim
 
     def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
@@ -133,6 +121,10 @@ def _dense_body(spec: ModelSpec, page_size: int, max_seq_len: int
 
 
 def _window_body(spec: ModelSpec, interpret: bool) -> DecodeBody:
+    """The kernel's operand is the page pool itself; fresh K/V collects in a
+    side window. No dense copy, no per-layer slice, and the program does not
+    depend on the context's page bucket (n_ctx_pages stays 0: one program
+    per n_steps)."""
     fwd_window = partial(forward_decode_window, interpret=interpret)
 
     def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
@@ -164,6 +156,8 @@ def _window_body(spec: ModelSpec, interpret: bool) -> DecodeBody:
 
 
 def _inline_body(spec: ModelSpec) -> DecodeBody:
+    """Fresh K/V is scattered into the pages every step."""
+
     def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
         return page_table, (kp, vp)
 
@@ -180,15 +174,19 @@ def _inline_body(spec: ModelSpec) -> DecodeBody:
 
 
 def _family_body(spec: ModelSpec, fam, attn_impl: str) -> DecodeBody:
-    """A per-layer spec's body, over its family module: ``kp`` is the paged
-    pool, ``vp`` the per-slot state (``engine/paged_kv.py``)."""
+    """A per-layer spec's body, over its family module: ``kp`` is the
+    family's paged rows, read where they lie (``decode_context``), the
+    chunk's own rows gather in a side window the family writes back once
+    (``write_side``); ``vp`` is its per-slot state (``engine/paged_kv.py``),
+    which rides the scan and moves only for rows ``active`` at that step."""
 
     def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
-        del n_ctx_pages        # one program: it reads the live pages
-        # a row a step of every layer that keeps K|V or latent rows
-        ctx = fam.decode_context(kp, page_table, attn_impl)
-        side = jnp.zeros((fam.side_layers(spec), start_lengths.shape[0],
-                          n_steps, kp.shape[-1]), kp.dtype)
+        ctx = fam.decode_context(kp, page_table, attn_impl)   # live pages
+        # a row a step of every layer that keeps K|V or latent rows, the
+        # window layers' (a pool of their own, in the state) first
+        side = jnp.zeros((spec.window_layers + spec.paged_layers,
+                          start_lengths.shape[0], n_steps, kp.shape[-1]),
+                         kp.dtype)
         return ctx, (side, vp)
 
     def step(params, last, lengths, start_lengths, ctx, cache, active):
@@ -204,25 +202,20 @@ def _family_body(spec: ModelSpec, fam, attn_impl: str) -> DecodeBody:
     return DecodeBody(begin, step, end, tuple(fam.DECODE_COUNTERS))
 
 
-def decode_body(body: str, spec: ModelSpec, fam, attn_impl: str,
-                page_size: int, max_seq_len: int) -> DecodeBody:
-    """The ``DecodeBody`` that ``continuous.resolve_decode_body`` named."""
-    if body == "hybrid":
-        return _family_body(spec, fam, attn_impl)
-    if body == "dense":
-        return _dense_body(spec, page_size, max_seq_len)
-    if body == "window":
-        return _window_body(spec, attn_impl.endswith("_interpret"))
-    return _inline_body(spec)
-
-
-def build_programs(spec: ModelSpec, body: DecodeBody, fam, fwd_prefill,
-                   page_size: int) -> Tuple[Any, ...]:
+def build_programs(spec: ModelSpec, body: str, attn_impl: str, fam,
+                   fwd_prefill, page_size: int, max_seq_len: int
+                   ) -> Tuple[Any, ...]:
     """One engine's jitted programs ``(_prefill, _prefill_pages,
     _prefill_suffix, _decode_chunk, _install, _install_first)``, closed
-    over its constants: ``body`` from ``decode_body``, ``fam`` the family
+    over its constants: ``body`` / ``attn_impl`` as
+    ``continuous.resolve_decode_body`` resolved them, ``fam`` the family
     module of a per-layer spec (None for a uniform one), ``fwd_prefill``
     what ``prefill_fn_for`` chose."""
+    body = {"hybrid": lambda: _family_body(spec, fam, attn_impl),
+            "dense": lambda: _dense_body(spec, page_size, max_seq_len),
+            "window": lambda: _window_body(
+                spec, attn_impl.endswith("_interpret")),
+            "inline": lambda: _inline_body(spec)}[body]()
 
     def _sample_firsts(params, hidden, seq_lens, sampling, key):
         """Shared prefill tail: last-token logits → sampled first

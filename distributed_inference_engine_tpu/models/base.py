@@ -35,6 +35,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..ops.attention import (
@@ -350,18 +351,54 @@ class ModelSpec:
 # what ``layered_family`` returns: every name a family module defines
 LAYERED_FAMILY = (
     "init_params", "init_state", "zero_state_slot",
-    "forward_prefill_into_pages", "PREFILL_COUNTERS", "prefill_sums",
-    "decode_context", "side_layers", "forward_decode_step",
-    "DECODE_COUNTERS", "write_side", "decode_sums")
+    "forward_prefill_into_pages", "PREFILL_COUNTERS", "decode_context",
+    "forward_decode_step", "DECODE_COUNTERS", "write_side")
 
 
-def rows_attended(counts, ends) -> int:
-    """Cached rows the steps of one decode chunk attended to, a paged layer
-    (a host sum: ``counts`` / ``ends`` are numpy [slots], the tokens each
-    slot emitted in the chunk and its length at the chunk's end). A slot
-    that emitted c tokens and ends at length e attended to e - c + 1 ... e
-    rows: the cached ones and the chunk's own, its new one included."""
-    return int((counts * (ends - counts) + counts * (counts + 1) // 2).sum())
+def decode_sums(spec: ModelSpec, counts, ends) -> Dict[str, int]:
+    """What one decode chunk of a per-layer spec adds to the counters no
+    program counts, by name (a host sum: ``counts`` / ``ends`` are numpy
+    [slots], the tokens each slot emitted in the chunk and its length at
+    the chunk's end). A slot that emitted c tokens and ends at length e
+    attended to e - c + 1 ... e rows of a paged layer (the cached ones and
+    the chunk's own, its new one included), K|V rows or latent rows; in a
+    sliding layer a token at position p sees ``min(p + 1, window)``; each
+    token moves the state of a recurrent layer once."""
+    first = ends - counts
+    sums = {"attn.full_context_rows" if spec.kv_row_lanes
+            else "mla.decode_context_rows":
+            int((counts * first + counts * (counts + 1) // 2).sum())}
+    if spec.window_layers:
+        window = spec.sliding_window
+        below = np.clip(np.minimum(ends, window) - first, 0, None)
+        sums["attn.window_context_rows"] = int(
+            (below * first + below * (below + 1) // 2
+             + (counts - below) * window).sum())
+    if spec.recurrent:
+        sums["state.rows_updated"] = int(counts.sum())
+    return sums
+
+
+def prefill_sums(spec: ModelSpec, prompt_len: int, bucket: int
+                 ) -> Dict[str, int]:
+    """What one admitted prompt of a per-layer spec adds, by name: the key
+    blocks its prefill visited and the blocks of its bucket's whole square,
+    a layer of each paged kind (``ops/mla.py`` for latent rows,
+    ``ops/flash_prefill.py`` for K|V rows: a sliding layer's are the
+    band's)."""
+    from ..ops import flash_prefill, mla
+
+    if not spec.kv_row_lanes:
+        pairs = {"mla.": mla.prefill_key_blocks(prompt_len, bucket)}
+    else:
+        pairs = {"attn.full_": flash_prefill.prefill_key_blocks(
+            prompt_len, bucket)}
+        if spec.window_layers:
+            pairs["attn.window_"] = flash_prefill.prefill_key_blocks(
+                prompt_len, bucket, spec.sliding_window)
+    return {f"{prefix}prefill_key_blocks_{what}": n
+            for prefix, pair in pairs.items()
+            for what, n in zip(("visited", "bucket"), pair)}
 
 
 def layered_family(spec: ModelSpec):
@@ -379,20 +416,17 @@ def layered_family(spec: ModelSpec):
       counters)``; ``PREFILL_COUNTERS`` names the counters, in order.
     - ``decode_context(pages, page_table, attn_impl)``: what the steps of a
       chunk read the cached rows from, frozen for the chunk;
-      ``side_layers(spec)``: the layers of the side window ``[layers, slots,
-      steps, row]`` a chunk's own rows gather in; ``forward_decode_step(spec,
-      params, tokens, lengths, start_lengths, ctx, side, state, active)`` ->
-      ``(hidden, side, state, counters)``; ``DECODE_COUNTERS`` names the
+      ``forward_decode_step(spec, params, tokens, lengths, start_lengths,
+      ctx, side, state, active)`` -> ``(hidden, side, state, counters)``,
+      ``side`` the window ``[window_layers + paged_layers, slots, steps,
+      row]`` a chunk's own rows gather in; ``DECODE_COUNTERS`` names the
       counters, in order; ``write_side(pages, state, side, page_table,
       counts, start)`` -> ``(pages, state)``: the chunk's one write-back.
-    - on the host, sums no program counts, as ``{name: int}``:
-      ``decode_sums(spec, counts, ends)`` of one decode chunk (the arrays of
-      ``rows_attended``), ``prefill_sums(spec, prompt_len, bucket)`` of one
-      admitted prompt.
 
     A counter's name is ``<group>.<key>`` of ``ContinuousEngine
     .get_metrics()`` (None: an entry nothing reads); the engine sums by
-    name and knows no family's layout.
+    name, with the host's ``decode_sums`` / ``prefill_sums`` above, and
+    knows no family's layout.
 
     Five families in four modules, told
     apart by what the spec holds: "swa" layers (``models/mellum.py``:

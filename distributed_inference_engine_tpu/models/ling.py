@@ -40,10 +40,9 @@ import jax.numpy as jnp
 
 from ..ops import kda, mla
 from ..ops.moe_routed import COUNTERS as MOE_COUNTERS
-from ..ops.moe_routed import PREFILL_COUNTERS  # noqa: F401  (the family's)
-from ..ops.moe_routed import moe_block
+from ..ops.moe_routed import PREFILL_COUNTERS, moe_block  # noqa: F401
 from ..ops.norms import rms_norm
-from .base import ModelSpec, embed, rows_attended
+from .base import ModelSpec, embed
 
 Params = Dict[str, Any]
 State = Dict[str, jnp.ndarray]
@@ -231,28 +230,6 @@ def zero_state_slot(state: State, slot: jnp.ndarray) -> State:
 # experts' three (``ops/moe_routed.py``) and the latent rows the attention
 # read, a layer
 DECODE_COUNTERS = MOE_COUNTERS + ("mla.decode_table_rows",)
-
-
-def decode_sums(spec: ModelSpec, counts, ends) -> Dict[str, int]:
-    """One decode chunk's host sums (``models.base.layered_family``):
-    latent rows its steps attended to, a paged layer, and the (row, step)
-    pairs that moved a recurrent state, a recurrent layer."""
-    return {"mla.decode_context_rows": rows_attended(counts, ends),
-            "state.rows_updated": int(counts.sum())}
-
-
-def prefill_sums(spec: ModelSpec, prompt_len: int, bucket: int
-                 ) -> Dict[str, int]:
-    """Key blocks one prompt's latent prefill visited and the blocks of its
-    bucket's whole square (``ops/mla.py``), a paged layer."""
-    visited, square = mla.prefill_key_blocks(prompt_len, bucket)
-    return {"mla.prefill_key_blocks_visited": visited,
-            "mla.prefill_key_blocks_bucket": square}
-
-
-def side_layers(spec: ModelSpec) -> int:
-    """Layers with rows in a decode chunk's side window: the pool's."""
-    return spec.paged_layers
 
 
 def decode_context(pages: jnp.ndarray, page_table: jnp.ndarray,

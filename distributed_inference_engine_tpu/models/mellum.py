@@ -53,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..ops import flash_prefill, mla
+from ..ops import mla
 from ..ops.flash_decode import (
     flash_decode_attention_pallas,
     flash_decode_attention_xla,
@@ -62,14 +62,12 @@ from ..ops.flash_prefill import kv_prefill_attention
 from ..ops.moe_routed import COUNTERS as MOE_COUNTERS
 from ..ops.moe_routed import PREFILL_COUNTERS, moe_block
 from ..ops.norms import rms_norm
-from .base import ModelSpec, embed, rows_attended
+from .base import ModelSpec, embed
 from .ling import _init_table, _proj, decode_context, write_rows_into_pages
 
 __all__ = ["mellum_spec", "init_params", "init_state", "zero_state_slot",
-           "decode_context", "write_side", "side_layers",
-           "window_pages_per_slot", "DECODE_COUNTERS", "PREFILL_COUNTERS",
-           "decode_sums", "prefill_sums", "forward_prefill_into_pages",
-           "forward_decode_step"]
+           "decode_context", "write_side", "window_pages_per_slot", "DECODE_COUNTERS", "PREFILL_COUNTERS",
+           "forward_prefill_into_pages", "forward_decode_step"]
 
 Params = Dict[str, Any]
 State = Dict[str, jnp.ndarray]
@@ -231,40 +229,6 @@ def window_read_pages(spec: ModelSpec, page_size: int) -> int:
     """The most cached pages a decode step of a sliding layer reads a row:
     ``window - 1`` rows touch ``ceil(window / page) + 1`` pages at most."""
     return -(-spec.sliding_window // page_size) + 1
-
-
-def side_layers(spec: ModelSpec) -> int:
-    """Layers with rows in a decode chunk's side window: all of them, the
-    sliding layers first (period-major, as the window pool), then the full
-    layers (as the full pool)."""
-    return spec.n_layers
-
-
-def decode_sums(spec: ModelSpec, counts, ends) -> Dict[str, int]:
-    """One decode chunk's host sums (``models.base.layered_family``): K|V
-    rows its steps attended to, a full layer and a sliding layer (a token
-    at position p sees ``min(p + 1, window)`` rows there)."""
-    window = spec.sliding_window
-    first = ends - counts
-    below = np.clip(np.minimum(ends, window) - first, 0, None)
-    return {"attn.full_context_rows": rows_attended(counts, ends),
-            "attn.window_context_rows": int(
-                (below * first + below * (below + 1) // 2
-                 + (counts - below) * window).sum())}
-
-
-def prefill_sums(spec: ModelSpec, prompt_len: int, bucket: int
-                 ) -> Dict[str, int]:
-    """Key blocks one prompt's prefill visited and the blocks of its
-    bucket's whole square (``ops/flash_prefill.py``: a sliding layer's are
-    the band's), a layer of each kind."""
-    sums = {}
-    for kind, window in (("full", 0), ("window", spec.sliding_window)):
-        visited, square = flash_prefill.prefill_key_blocks(
-            prompt_len, bucket, window)
-        sums[f"attn.{kind}_prefill_key_blocks_visited"] = visited
-        sums[f"attn.{kind}_prefill_key_blocks_bucket"] = square
-    return sums
 
 
 def init_state(spec: ModelSpec, max_slots: int, page_size: int = 0,

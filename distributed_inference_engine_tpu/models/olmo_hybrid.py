@@ -48,27 +48,26 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import flash_prefill, kda
+from ..ops import kda
 from ..ops.flash_decode import (
     flash_decode_attention_pallas,
     flash_decode_attention_xla,
 )
 from ..ops.flash_prefill import kv_prefill_attention
 from ..ops.norms import rms_norm
-from .base import ModelSpec, embed, rows_attended
+from .base import ModelSpec, embed
 from .ling import (  # the paged pool's views are the same code
     _init_table,
     _proj,
     decode_context,
-    side_layers,
     write_rows_into_pages,
     write_side,
 )
 
 __all__ = ["olmo_hybrid_spec", "init_params", "init_state", "zero_state_slot",
-           "decode_context", "side_layers", "write_side", "DECODE_COUNTERS",
-           "PREFILL_COUNTERS", "decode_sums", "prefill_sums",
-           "forward_prefill_into_pages", "forward_decode_step"]
+           "decode_context", "write_side", "DECODE_COUNTERS",
+           "PREFILL_COUNTERS", "forward_prefill_into_pages",
+           "forward_decode_step"]
 
 Params = Dict[str, Any]
 State = Dict[str, jnp.ndarray]
@@ -245,23 +244,6 @@ def zero_state_slot(state: State, slot: jnp.ndarray) -> State:
 
 
 # ----------------------------------------------------------------- layers
-
-
-def decode_sums(spec: ModelSpec, counts, ends) -> Dict[str, int]:
-    """One decode chunk's host sums (``models.base.layered_family``): K|V
-    rows its steps attended to, a full layer, and the (row, step) pairs
-    that moved a recurrent state, a recurrent layer."""
-    return {"attn.full_context_rows": rows_attended(counts, ends),
-            "state.rows_updated": int(counts.sum())}
-
-
-def prefill_sums(spec: ModelSpec, prompt_len: int, bucket: int
-                 ) -> Dict[str, int]:
-    """Key blocks one prompt's prefill visited and the blocks of its
-    bucket's whole square (``ops/flash_prefill.py``), a full layer."""
-    visited, square = flash_prefill.prefill_key_blocks(prompt_len, bucket)
-    return {"attn.full_prefill_key_blocks_visited": visited,
-            "attn.full_prefill_key_blocks_bucket": square}
 
 
 def _gdn_inputs(spec: ModelSpec, blk: Params, x: jnp.ndarray):

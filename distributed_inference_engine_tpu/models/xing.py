@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from ..ops import mhc, mla
 from ..ops.moe_routed import held_fraction_of_one_group, moe_body
 from ..ops.norms import rms_norm
-from .base import ModelSpec, embed, rows_attended
+from .base import ModelSpec, embed
 from .ling import (  # the latent pool's views and reader are the same code
     DECODE_COUNTERS,
     PREFILL_COUNTERS,
@@ -65,17 +65,14 @@ from .ling import (  # the latent pool's views and reader are the same code
     decode_context,
     latent_attention_step,
     latent_row,
-    prefill_sums,
-    side_layers,
     write_rows_into_pages,
     write_side,
 )
 
 __all__ = ["xing_spec", "kimi_spec", "init_params", "init_state",
-           "zero_state_slot", "decode_context", "side_layers", "write_side",
-           "DECODE_COUNTERS", "PREFILL_COUNTERS", "decode_sums",
-           "prefill_sums", "forward_prefill_into_pages",
-           "forward_decode_step"]
+           "zero_state_slot", "decode_context", "write_side",
+           "DECODE_COUNTERS", "PREFILL_COUNTERS",
+           "forward_prefill_into_pages", "forward_decode_step"]
 
 Params = Dict[str, Any]
 State = Dict[str, jnp.ndarray]
@@ -287,12 +284,6 @@ def zero_state_slot(state: State, slot: jnp.ndarray) -> State:
 
 
 # ----------------------------------------------------------------- layers
-
-
-def decode_sums(spec: ModelSpec, counts, ends) -> Dict[str, int]:
-    """One decode chunk's host sum (``models.base.layered_family``): latent
-    rows its steps attended to, a layer."""
-    return {"mla.decode_context_rows": rows_attended(counts, ends)}
 
 
 def _rope(spec: ModelSpec, x, positions):

@@ -103,3 +103,30 @@ def pytest_pyfunc_call(pyfuncitem):
         asyncio.run(fn(**kwargs))
         return True
     return None
+
+
+@pytest.fixture(scope="module")
+def shared(request):
+    """One engine a distinct (dtype, ``EngineConfig``) of the test module's
+    ``tiny_engine``, built the first time a case asks for it: its programs
+    compile once a module, not once a case. A case leaves it idle
+    (``generate`` / ``run_until_idle``) and reads its counters as
+    differences (``grown``)."""
+    built = {}
+
+    def get(dtype="bfloat16", **cfg_kw):
+        key = (dtype, tuple(sorted(cfg_kw.items())))
+        if key not in built:
+            built[key] = request.module.tiny_engine(dtype, **cfg_kw)
+        engine = built[key]
+        assert engine.n_live == 0 and engine.n_waiting == 0
+        return engine
+
+    return get
+
+
+def grown(before, after):
+    """What the counters of ``get_metrics()``, or of one group of it, grew
+    by between two readings."""
+    return {k: v - before[k] for k, v in after.items()
+            if isinstance(v, (int, float)) and not isinstance(v, bool)}

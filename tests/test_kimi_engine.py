@@ -336,26 +336,37 @@ async def _turnovers(pool=None):
 
 
 async def test_a_freed_slots_successor_is_already_queued_at_the_engine():
-    """With the look-ahead at the worker, no decode chunk after the first
-    finish runs with a free slot while a stream still waits at the
-    coordinator: the successor is in the engine's queue when the slot frees
-    and joins the very next chunk. On a pool of the slots alone every finish
-    costs its slot an empty chunk."""
+    """With the look-ahead at the worker a freed slot's successor is in the
+    engine's queue when the slot frees and joins the very next chunk; on a
+    pool of the slots alone (the control, same process) every finish costs
+    its slot an empty chunk. Held against the control, not against a count
+    of chunks: the coordinator's and the engine's threads race, and a slot
+    is handed on one chunk before its result leaves (and its successor's
+    successor sets out), so a finish or two can outrun a look-ahead of
+    two."""
+    slots = 8
+
+    def empty_while_streams_waited(seen):
+        """Growth of ``empty_slot_dispatches`` over the decode dispatches
+        that saw a stream still waiting at the coordinator."""
+        waited = [e for e, n in seen if n > 0]
+        assert len(waited) >= 10, seen
+        return waited[-1] - waited[0]
+
     m, pairs, seen = await _turnovers()
     for r, o in pairs:
         assert len(o["tokens"]) == r.max_new_tokens
     assert m["admissions"] == len(pairs)
-    waited = [(e, n) for e, n in seen if n > 0]
-    assert len(waited) >= 10, seen
-    # none grew meanwhile, or hardly: a slot is handed on one chunk before
-    # its result leaves (and its successor's successor sets out), so three
-    # finishes in three chunks can outrun a look-ahead of two
-    assert waited[-1][0] - waited[0][0] <= 1, seen
-    assert m["admissions_from_queue"] >= 8, m["admissions_from_queue"]
+    ahead = empty_while_streams_waited(seen)
+    # every slot but the look-ahead's worth found its successor queued
+    successors = len(pairs) - slots
+    assert m["admissions_from_queue"] >= successors - (
+        pool_for_slots(slots) - slots), m["admissions_from_queue"]
 
-    m, pairs, seen = await _turnovers(pool=8)
+    m, pairs, seen = await _turnovers(pool=slots)
     for r, o in pairs:
         assert len(o["tokens"]) == r.max_new_tokens
-    waited = [e for e, n in seen if n > 0]
-    assert waited[-1] - waited[0] >= 6, seen            # one a finish, nearly
+    alone = empty_while_streams_waited(seen)
+    assert alone >= 6, seen                     # one a finish, nearly
     assert m["admissions_from_queue"] == 0
+    assert 3 * ahead <= alone, (ahead, alone)

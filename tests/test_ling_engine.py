@@ -33,6 +33,7 @@ from distributed_inference_engine_tpu.models import (  # noqa: E402
 )
 from distributed_inference_engine_tpu.ops import kda  # noqa: E402
 from perfbench.lib import families  # noqa: E402
+from conftest import grown  # noqa: E402  (this directory)
 
 with open(os.path.join(ROOT, "perfbench", "rehearse", "ling-tiny.json")) as _f:
     CFG = json.load(_f)
@@ -73,10 +74,11 @@ def judged(engine, requests, results):
             assert row.max() - row[tok] <= 0.06 * np.abs(row).max(), (i, tok)
 
 
-def test_engine_serves_the_hybrid_through_slots_pages_and_state():
+def test_engine_serves_the_hybrid_through_slots_pages_and_state(shared):
     """Six requests of unequal length over four slots: batched admission at
     padded buckets, deferred first tokens, slot reuse, counters."""
-    engine = tiny_engine(prefix_cache=True)
+    engine = shared(prefix_cache=True)
+    m0 = engine.get_metrics()
     rng = np.random.default_rng(1)
     reqs = [GenerationRequest(
         prompt=[int(t) for t in rng.integers(1, 256, n)], max_new_tokens=m)
@@ -87,10 +89,11 @@ def test_engine_serves_the_hybrid_through_slots_pages_and_state():
     assert m["prefix_disabled_per_layer"] == 1
     assert m["prefix_hit_admissions"] == 0 and m["kv"]["prefix_queries"] == 0
     assert m["attn_impl"] == "xla"
-    assert m["decode_steps"] >= 12 and m["decode_chunks"] >= 3
-    moe = m["moe"]
+    steps = m["decode_steps"] - m0["decode_steps"]
+    assert steps >= 12 and m["decode_chunks"] - m0["decode_chunks"] >= 3
+    moe = grown(m0["moe"], m["moe"])
     assert 0 < moe["assignments_held"] < moe["assignments_total"]
-    assert 0 < moe["experts_touched"] <= m["decode_steps"] * 5 * 4
+    assert 0 < moe["experts_touched"] <= steps * 5 * 4
     kv = m["kv"]
     assert (kv["paged_layers"], kv["state_layers"]) == (2, 4)
     assert kv["latent_bytes_per_token"] == 2 * 128 * 2    # 32 + 8: one tile
@@ -100,8 +103,8 @@ def test_engine_serves_the_hybrid_through_slots_pages_and_state():
     assert all(float(jnp.abs(a).max()) == 0 for a in engine.kv.state.values())
 
 
-def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
-    engine = tiny_engine(prefix_cache=True)
+def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens(shared):
+    engine = shared(prefix_cache=True)
     prompt = [int(t) for t in np.random.default_rng(2).integers(1, 256, 40)]
     first = engine.generate([GenerationRequest(prompt=list(prompt),
                                                max_new_tokens=8)])
@@ -112,7 +115,7 @@ def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
     assert m["prefix_hit_admissions"] == 0 and m["kv"]["prefix_hit_pages"] == 0
 
 
-def test_a_preempted_sequence_resumes_where_it_stopped():
+def test_a_preempted_sequence_resumes_where_it_stopped(shared):
     """A pool too small for both requests at full length: the one that
     cannot grow is re-queued with its tokens so far and re-prefilled (its
     state rebuilt from the tokens, never resumed on a zero state); the
@@ -126,17 +129,19 @@ def test_a_preempted_sequence_resumes_where_it_stopped():
         return [GenerationRequest(prompt=list(p), max_new_tokens=40)
                 for p in prompts]
 
-    alone = [tiny_engine("float32").generate([r])[0] for r in make()]
-    tight = tiny_engine("float32", num_pages=7)        # 2 x 2 pages at admission, 7 all
+    alone = [shared("float32", attention_impl="xla").generate([r])[0]
+             for r in make()]
+    tight = shared("float32", num_pages=7)  # 2 x 2 pages at admission, 7 all
+    m0 = tight.get_metrics()
     together = tight.generate(make())
-    m = tight.get_metrics()
+    m = grown(m0, tight.get_metrics())
     assert m["reprefill_preemptions"] >= 1 and m["capacity_finishes"] == 0
     for a, b in zip(alone, together):
         assert a.tokens == b.tokens and len(b.tokens) == 40
         assert b.finish_reason == a.finish_reason
 
 
-def test_a_slot_handed_on_leaks_no_state_to_its_successor():
+def test_a_slot_handed_on_leaks_no_state_to_its_successor(shared):
     """One slot, three requests that end by ``max_new_tokens``: each
     successor is prefilled into its predecessor's slot, pages and
     recurrent state row BEHIND the chunk the predecessor ends in
@@ -151,22 +156,22 @@ def test_a_slot_handed_on_leaks_no_state_to_its_successor():
         return [GenerationRequest(prompt=list(p), max_new_tokens=m)
                 for p, (_, m) in zip(prompts, shapes)]
 
-    engine = tiny_engine("float32", max_slots=1)
+    engine = shared("float32", max_slots=1)
+    m0 = engine.get_metrics()
     reqs = make()
     together = engine.generate(reqs)
-    m = engine.get_metrics()
+    m = grown(m0, engine.get_metrics())
     assert m["admissions"] == 3 and m["admissions_ahead"] == 2
     assert m["empty_slot_dispatches"] == 0 and m["finishes_learned_late"] == 0
     judged(engine, reqs, together)
     for req, res in zip(make(), together):
-        alone = tiny_engine("float32", max_slots=1).generate([req])[0]
-        assert alone.tokens == res.tokens
+        assert engine.generate([req])[0].tokens == res.tokens
     # every slot is free again, and free means zero
     assert all(float(jnp.abs(a).max()) == 0
                for k, a in engine.kv.state.items() if k != "window_table")
 
 
-def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch):
+def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch, shared):
     """The in-place kernel (through the interpreter) against the XLA body
     the CPU picks, under everything that touches a slot's state: five
     requests of unequal length over 4 slots and a pool too small for them,
@@ -178,27 +183,31 @@ def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch):
                for n in (30, 28, 9, 17, 24)]
     new = (40, 36, 7, 12, 21)
 
-    def serve():
-        engine = tiny_engine("float32", num_pages=9)
+    def serve(engine):
+        m0 = engine.get_metrics()
         results = engine.generate([
             GenerationRequest(prompt=list(p), max_new_tokens=n)
             for p, n in zip(prompts, new)])
-        return [r.tokens for r in results], engine.get_metrics()
+        m = engine.get_metrics()
+        return [r.tokens for r in results], m["state"]["step_body"], (
+            m["reprefill_preemptions"] - m0["reprefill_preemptions"],
+            m["state"]["rows_updated"] - m0["state"]["rows_updated"])
 
-    tokens_xla, m_xla = serve()
+    tokens_xla, body_xla, grew_xla = serve(
+        shared("float32", num_pages=9, attention_impl="xla"))
+    # its own engine: the body is picked when the programs are traced
     monkeypatch.setattr(kda, "step_impl", lambda: "inplace_interpret")
-    tokens_kernel, m_kernel = serve()
+    tokens_kernel, body_kernel, grew_kernel = serve(
+        tiny_engine("float32", num_pages=9))
     assert tokens_kernel == tokens_xla
     assert [len(t) for t in tokens_xla] == list(new)
-    assert m_xla["state"]["step_body"] == "xla"
-    assert m_kernel["state"]["step_body"] == "inplace_interpret"
-    for m in (m_xla, m_kernel):
-        assert m["reprefill_preemptions"] >= 1
-        assert m["state"]["rows_updated"] == m_xla["state"]["rows_updated"] > 0
+    assert (body_xla, body_kernel) == ("xla", "inplace_interpret")
+    assert grew_kernel[0] >= 1 and grew_xla[0] >= 1
+    assert grew_kernel[1] == grew_xla[1] > 0
 
 
 @pytest.mark.parametrize("pages", [32, 9])
-def test_the_latent_kernel_body_emits_the_xla_bodys_tokens(pages):
+def test_the_latent_kernel_body_emits_the_xla_bodys_tokens(shared, pages):
     """The MLA layers' rows read in place from the pool by the interpreted
     kernel against ``attention_impl="xla"`` (a layer's pages gathered a
     step): the same greedy tokens in float32 for five requests over four
@@ -211,12 +220,18 @@ def test_the_latent_kernel_body_emits_the_xla_bodys_tokens(pages):
     new = (40, 36, 7, 12, 21)
 
     def serve(impl):
-        engine = tiny_engine("float32", num_pages=pages, attention_impl=impl)
+        engine = (shared("float32", attention_impl=impl) if pages == 32
+                  else shared("float32", num_pages=pages,
+                              attention_impl=impl))
         assert (engine.body, engine.attn_impl) == ("hybrid", impl)
+        m0 = engine.get_metrics()
         results = engine.generate([
             GenerationRequest(prompt=list(p), max_new_tokens=n)
             for p, n in zip(prompts, new)])
-        return [r.tokens for r in results], engine.get_metrics()
+        m = engine.get_metrics()
+        return [r.tokens for r in results], {
+            "mla": grown(m0["mla"], m["mla"]),
+            "decode_steps": m["decode_steps"] - m0["decode_steps"]}
 
     tokens_xla, m_xla = serve("xla")
     tokens_kernel, m_kernel = serve("pallas-decode_interpret")
@@ -233,7 +248,7 @@ def test_the_latent_kernel_body_emits_the_xla_bodys_tokens(pages):
 
 
 @pytest.mark.parametrize("pages", [32, 7])
-def test_streamed_hybrid_matches_unstreamed(pages):
+def test_streamed_hybrid_matches_unstreamed(shared, pages):
     """The hybrid family streamed: a chunk's tokens go out under the next
     dispatch; tokens, logprobs and reasons are those of the same engine
     unstreamed, each stream splices to its result. With 7 pages one
@@ -242,23 +257,26 @@ def test_streamed_hybrid_matches_unstreamed(pages):
     rng = np.random.default_rng(3)
     prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in (30, 28)]
 
+    eng = (shared("float32", num_pages=7) if pages == 7
+           else shared("float32", attention_impl="xla"))
+
     def run(stream):
-        eng = tiny_engine("float32", num_pages=pages)
+        m0 = eng.get_metrics()
         frames = [[] for _ in prompts]
         for i, p in enumerate(prompts):
             eng.submit(GenerationRequest(prompt=list(p), max_new_tokens=40,
                                          request_id=f"h{i}"),
                        on_tokens=frames[i].append if stream else None)
         res = {r.request_id: r for r in eng.run_until_idle()}
-        return eng, [res[f"h{i}"] for i in range(len(prompts))], frames
+        return (grown(m0, eng.get_metrics()),
+                [res[f"h{i}"] for i in range(len(prompts))], frames)
 
-    eng, got, frames = run(True)
+    m, got, frames = run(True)
     _plain, want, _none = run(False)
     for g, w, fr in zip(got, want, frames):
         assert (g.tokens, g.logprobs, g.finish_reason) == (
             w.tokens, w.logprobs, w.finish_reason)
         assert [t for f in fr for t in f] == g.tokens and len(g.tokens) == 40
-    m = eng.get_metrics()
     assert (m["reprefill_preemptions"] >= 1) == (pages == 7)
     assert m["emit_carried_chunks"] >= 1
     assert (m["emit_carried_chunks"] + m["emit_flushed_chunks"]
@@ -334,8 +352,8 @@ def test_deploys_a_hybrid_architecture_cannot_serve_raise(change, match):
         engine_from_config(cfg)
 
 
-def test_calls_a_recurrent_spec_cannot_answer_raise():
-    engine = tiny_engine()
+def test_calls_a_recurrent_spec_cannot_answer_raise(shared):
+    engine = shared(prefix_cache=True)
     with pytest.raises(ValueError, match="recurrent state"):
         engine.kv_export([1, 2, 3])
     with pytest.raises(ValueError, match="hybrid"):

@@ -126,7 +126,7 @@ class Served:
             start = np.zeros((b,), np.int32)
             for s in feeds:
                 start[s] = pos[s]
-            side = jnp.zeros((mellum.side_layers(self.spec), b, n_steps,
+            side = jnp.zeros((self.spec.n_layers, b, n_steps,
                               self.spec.cache_row_width), self.kv.dtype)
             kp, state = self.kv.pools
             cur = start.copy()

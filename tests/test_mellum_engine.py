@@ -35,6 +35,7 @@ from distributed_inference_engine_tpu.models import (  # noqa: E402
     engine_from_config, mellum, spec_for_architecture,
 )
 from perfbench.lib import families  # noqa: E402
+from conftest import grown  # noqa: E402  (this directory)
 
 with open(os.path.join(ROOT, "perfbench", "rehearse",
                        "mellum-tiny.json")) as _f:
@@ -111,7 +112,7 @@ def test_engine_serves_eight_rows_of_unequal_length(impl):
     assert 0 < kv["window_pages_held_sum"] < kv["window_pages_uncut_sum"]
 
 
-def test_counters_follow_lengths_and_steps():
+def test_counters_follow_lengths_and_steps(shared):
     """One request alone: a prompt of 60 (past the window of 32) and 9
     tokens. The first comes from the prefill; the 8 decode steps attend to
     61 ... 68 rows in a full layer and to 32 each in a sliding one. What the
@@ -120,41 +121,37 @@ def test_counters_follow_lengths_and_steps():
     a sliding one, the kernel the pages it started a copy of, both plus the
     side window (4 slots x 4 rows)."""
     for impl in ("xla", "pallas-decode_interpret"):
-        engine = tiny_engine(attention_impl=impl)
+        engine = shared(attention_impl=impl)
+        m0 = engine.get_metrics()
         engine.generate([GenerationRequest(prompt=list(range(1, 61)),
                                            max_new_tokens=9)])
         m = engine.get_metrics()
-        assert m["decode_steps"] == 8
-        assert m["attn"]["full_context_rows"] == sum(range(61, 69))
-        assert m["attn"]["window_context_rows"] == 8 * WINDOW
+        attn = grown(m0["attn"], m["attn"])
+        assert m["decode_steps"] - m0["decode_steps"] == 8
+        assert attn["full_context_rows"] == sum(range(61, 69))
+        assert attn["window_context_rows"] == 8 * WINDOW
         side = 4 * 4
         if impl == "xla":
-            assert m["attn"]["full_table_rows"] == 8 * (4 * 32 * PAGE + side)
-            assert m["attn"]["window_table_rows"] == 8 * (
+            assert attn["full_table_rows"] == 8 * (4 * 32 * PAGE + side)
+            assert attn["window_table_rows"] == 8 * (
                 4 * (WINDOW // PAGE + 1) * PAGE + side)
         else:
             # chunk 1 from 60 cached rows (8 pages), chunk 2 from 64 (8);
             # a sliding layer's steps from rows 29, 30, 31 (page 3 on: 5
             # pages), 32 (page 4 on: 4) and 33 ... 36 (4 each)
-            assert m["attn"]["full_table_rows"] == 8 * (8 * PAGE + side)
-            assert m["attn"]["window_table_rows"] == (
+            assert attn["full_table_rows"] == 8 * (8 * PAGE + side)
+            assert attn["window_table_rows"] == (
                 (3 * 5 + 5 * 4) * PAGE + 8 * side)
         assert m["mla"] == {"decode_context_rows": 0, "decode_table_rows": 0}
         assert "state" not in m
-
-
-@pytest.fixture(scope="module")
-def counting_engine():
-    """One engine for the hand counts below: its programs compile once."""
-    return tiny_engine()
 
 
 @pytest.mark.parametrize("length,full,band,square", [
     (20, 1 + 2, 1 + 2, 4),                   # bucket 32: both blocks live
     (50, 1 + 2 + 3 + 4, 1 + 2 + 3 + 3, 16),  # bucket 64: block 3 sees 1-3
     (100, 28, 1 + 2 + 5 * 3, 64)])           # bucket 128: 7 of 8 live
-def test_prefill_key_block_counters_by_hand(monkeypatch, counting_engine,
-                                            length, full, band, square):
+def test_prefill_key_block_counters_by_hand(monkeypatch, shared, length,
+                                            full, band, square):
     """Blocks of 16 for the count and the window of 32: what the prefill
     kernel would visit for an admitted prompt, a layer of each kind (a full
     layer: at or under the diagonal; a sliding one: from the block holding
@@ -167,13 +164,14 @@ def test_prefill_key_block_counters_by_hand(monkeypatch, counting_engine,
     monkeypatch.setattr(flash_prefill, "K_BLOCK", 16)
     names = [f"{kind}_prefill_key_blocks_{what}"
              for kind in ("full", "window") for what in ("visited", "bucket")]
-    before = counting_engine.get_metrics()["attn"]
-    counting_engine.generate([GenerationRequest(
+    engine = shared(attention_impl="xla")
+    before = engine.get_metrics()["attn"]
+    engine.generate([GenerationRequest(
         prompt=list(range(1, length + 1)), max_new_tokens=2)])
-    got = counting_engine.get_metrics()["attn"]
-    assert [got[n] - before[n] for n in names] == [full, square, band, square]
+    got = grown(before, engine.get_metrics()["attn"])
+    assert [got[n] for n in names] == [full, square, band, square]
     assert not any(k.startswith("prefill_key_blocks")
-                   for k in counting_engine.get_metrics()["mla"])
+                   for k in engine.get_metrics()["mla"])
 
 
 def test_a_dense_tree_reports_no_prefill_blocks_of_either_kind():
@@ -185,12 +183,12 @@ def test_a_dense_tree_reports_no_prefill_blocks_of_either_kind():
     assert "attn" not in eng.get_metrics()
 
 
-def test_the_spans_are_in_the_programs():
+def test_the_spans_are_in_the_programs(shared):
     """Every scope the per-layer metrics read is on some operation of the
     lowered decode and prefill programs."""
     from distributed_inference_engine_tpu.ops.sampling import SamplingParams
 
-    eng = tiny_engine()
+    eng = shared(attention_impl="xla")
     kv, n = eng.kv, eng.max_slots
     sampling = SamplingParams(eng._temps, eng._top_k, eng._top_p, eng._min_p)
     dec = eng._decode_chunk.lower(
@@ -226,7 +224,7 @@ def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
     assert m["prefix_hit_admissions"] == 0 and m["kv"]["prefix_hit_pages"] == 0
 
 
-def test_a_preempted_sequence_is_re_prefilled_and_resumes():
+def test_a_preempted_sequence_is_re_prefilled_and_resumes(shared):
     """A full-layer pool too small for both requests at full length: the
     victim is re-queued as prompt + tokens and re-prefilled (its window
     pages went back with its slot and come anew); the result equals the same
@@ -238,7 +236,7 @@ def test_a_preempted_sequence_is_re_prefilled_and_resumes():
         return [GenerationRequest(prompt=list(p), max_new_tokens=60)
                 for p in prompts]
 
-    alone = [tiny_engine("float32").generate([r])[0] for r in make()]
+    alone = [shared("float32").generate([r])[0] for r in make()]
     tight = tiny_engine("float32", num_pages=16)
     together = tight.generate(make())
     m = tight.get_metrics()
@@ -268,7 +266,8 @@ def test_a_slot_is_reused_after_a_long_request_and_its_window_pages_too():
     assert kv["window_pages_used"] == 0
 
 
-def test_a_chunk_as_long_as_a_page_is_served_from_what_the_window_pool_spares():
+def test_a_chunk_as_long_as_a_page_is_served_from_what_the_window_pool_spares(
+        shared):
     """A chunk of 8 on pages of 8 (``perfbench/rehearse/mellum-tiny.json``):
     a window and TWO chunks no longer fit a slot's six window pages, so a
     grant a chunk ahead of the one in flight comes back short, the engine
@@ -282,7 +281,7 @@ def test_a_chunk_as_long_as_a_page_is_served_from_what_the_window_pool_spares():
         return [GenerationRequest(prompt=list(p), max_new_tokens=44)
                 for p in prompts]
 
-    ref = tiny_engine("float32").generate(make())
+    ref = shared("float32").generate(make())
     engine = tiny_engine("float32", decode_steps_per_call=PAGE)
     got = engine.generate(make())
     assert [r.tokens for r in got] == [r.tokens for r in ref]
@@ -293,12 +292,12 @@ def test_a_chunk_as_long_as_a_page_is_served_from_what_the_window_pool_spares():
     assert m["kv"]["window_pages_used"] == 0
 
 
-def test_streamed_matches_unstreamed():
+def test_streamed_matches_unstreamed(shared):
     rng = np.random.default_rng(3)
     prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in (30, 45)]
+    eng = shared("float32")
 
     def run(stream):
-        eng = tiny_engine("float32")
         frames = [[] for _ in prompts]
         for i, p in enumerate(prompts):
             eng.submit(GenerationRequest(prompt=list(p), max_new_tokens=24,
@@ -399,8 +398,8 @@ def test_deploys_this_architecture_cannot_serve_raise(change, match):
         engine_from_config(cfg)
 
 
-def test_calls_a_per_layer_spec_cannot_answer_raise():
-    engine = tiny_engine()
+def test_calls_a_per_layer_spec_cannot_answer_raise(shared):
+    engine = shared(attention_impl="xla")
     with pytest.raises(ValueError, match="per-layer spec has no prefill"):
         engine.kv_export([1, 2, 3])
     with pytest.raises(ValueError, match="per-layer"):

@@ -35,6 +35,7 @@ from distributed_inference_engine_tpu.models import (  # noqa: E402
 )
 from distributed_inference_engine_tpu.ops import kda  # noqa: E402
 from perfbench.lib import families  # noqa: E402
+from conftest import grown  # noqa: E402  (this directory)
 
 with open(os.path.join(ROOT, "perfbench", "rehearse", "olmo-tiny.json")) as _f:
     CFG = json.load(_f)
@@ -97,7 +98,7 @@ def test_engine_serves_eight_rows_of_unequal_length(impl):
     assert all(float(jnp.abs(a).max()) == 0 for a in engine.kv.state.values())
 
 
-def test_counters_follow_lengths_and_steps():
+def test_counters_follow_lengths_and_steps(shared):
     """One request alone: a prompt of 20 and 9 tokens. The first comes from
     the prefill; the 8 decode steps attend to 21 ... 28 rows each (cached
     and the chunk's own) and move one state each. What the attention READ
@@ -106,22 +107,24 @@ def test_counters_follow_lengths_and_steps():
     live row's 2 pages of 16 below its frozen prefix, counted in the
     kernel), both plus the side window (4 slots x 4 rows)."""
     for impl, pages in (("xla", 4 * 8), ("pallas-decode_interpret", 2)):
-        engine = tiny_engine(attention_impl=impl)
+        engine = shared(attention_impl=impl)
+        m0 = engine.get_metrics()
         engine.generate([GenerationRequest(prompt=list(range(1, 21)),
                                            max_new_tokens=9)])
         m = engine.get_metrics()
-        assert m["decode_steps"] == 8
-        assert m["attn"]["full_context_rows"] == sum(range(21, 29))
-        assert m["attn"]["full_table_rows"] == 8 * (pages * 16 + 4 * 4)
-        assert m["state"]["rows_updated"] == 8
+        attn = grown(m0["attn"], m["attn"])
+        assert m["decode_steps"] - m0["decode_steps"] == 8
+        assert attn["full_context_rows"] == sum(range(21, 29))
+        assert attn["full_table_rows"] == 8 * (pages * 16 + 4 * 4)
+        assert grown(m0["state"], m["state"]) == {"rows_updated": 8}
         assert m["mla"] == {"decode_context_rows": 0, "decode_table_rows": 0}
 
 
 @pytest.mark.parametrize("length,visited,square", [
     (20, 1 + 2, 4),              # bucket 32: both query blocks live
     (37, 1 + 2 + 3, 16)])        # bucket 64: three of four
-def test_prefill_key_block_counters_by_hand(monkeypatch, length, visited,
-                                            square):
+def test_prefill_key_block_counters_by_hand(monkeypatch, shared, length,
+                                            visited, square):
     """Blocks of 16 for the count: what the prefill kernel would visit for
     an admitted prompt in a full-attention layer (at or under the diagonal,
     below its length) over the blocks of its bucket's whole square; a spec
@@ -130,21 +133,22 @@ def test_prefill_key_block_counters_by_hand(monkeypatch, length, visited,
 
     monkeypatch.setattr(flash_prefill, "Q_BLOCK", 16)
     monkeypatch.setattr(flash_prefill, "K_BLOCK", 16)
-    engine = tiny_engine()
+    engine = shared(attention_impl="xla")
+    before = engine.get_metrics()["attn"]
     engine.generate([GenerationRequest(prompt=list(range(1, length + 1)),
                                        max_new_tokens=2)])
-    got = engine.get_metrics()["attn"]
+    got = grown(before, engine.get_metrics()["attn"])
     assert (got["full_prefill_key_blocks_visited"],
             got["full_prefill_key_blocks_bucket"]) == (visited, square)
     assert not any(k.startswith("window") for k in got)
 
 
-def test_the_spans_are_in_the_programs():
+def test_the_spans_are_in_the_programs(shared):
     """Every scope the per-layer metrics read is on some operation of the
     lowered decode and prefill programs."""
     from distributed_inference_engine_tpu.ops.sampling import SamplingParams
 
-    eng = tiny_engine()
+    eng = shared(attention_impl="xla")
     kv, n = eng.kv, eng.max_slots
     sampling = SamplingParams(eng._temps, eng._top_k, eng._top_p, eng._min_p)
     dec = eng._decode_chunk.lower(
@@ -180,7 +184,7 @@ def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
     assert m["prefix_hit_admissions"] == 0 and m["kv"]["prefix_hit_pages"] == 0
 
 
-def test_a_preempted_sequence_is_re_prefilled_and_resumes():
+def test_a_preempted_sequence_is_re_prefilled_and_resumes(shared):
     """A pool too small for both requests at full length: the victim is
     re-queued as prompt + tokens and re-prefilled (its state rebuilt from
     nothing, the host tier refused); the result equals the same request
@@ -192,17 +196,18 @@ def test_a_preempted_sequence_is_re_prefilled_and_resumes():
         return [GenerationRequest(prompt=list(p), max_new_tokens=40)
                 for p in prompts]
 
-    alone = [tiny_engine("float32").generate([r])[0] for r in make()]
-    tight = tiny_engine("float32", num_pages=7)
+    alone = [shared("float32").generate([r])[0] for r in make()]
+    tight = shared("float32", num_pages=7)
+    m0 = tight.get_metrics()
     together = tight.generate(make())
-    m = tight.get_metrics()
+    m = grown(m0, tight.get_metrics())
     assert m["reprefill_preemptions"] >= 1 and m["capacity_finishes"] == 0
     for a, b in zip(alone, together):
         assert a.tokens == b.tokens and len(b.tokens) == 40
         assert b.finish_reason == a.finish_reason
 
 
-def test_a_slot_handed_on_leaks_no_state_to_its_successor():
+def test_a_slot_handed_on_leaks_no_state_to_its_successor(shared):
     """One slot, three requests that end by ``max_new_tokens``: each
     successor is prefilled into its predecessor's slot, pages and
     recurrent state row BEHIND the chunk the predecessor ends in
@@ -217,22 +222,22 @@ def test_a_slot_handed_on_leaks_no_state_to_its_successor():
         return [GenerationRequest(prompt=list(p), max_new_tokens=m)
                 for p, (_, m) in zip(prompts, shapes)]
 
-    engine = tiny_engine("float32", max_slots=1)
+    engine = shared("float32", max_slots=1)
+    m0 = engine.get_metrics()
     reqs = make()
     together = engine.generate(reqs)
-    m = engine.get_metrics()
+    m = grown(m0, engine.get_metrics())
     assert m["admissions"] == 3 and m["admissions_ahead"] == 2
     assert m["empty_slot_dispatches"] == 0 and m["finishes_learned_late"] == 0
     judged(engine, reqs, together)
     for req, res in zip(make(), together):
-        alone = tiny_engine("float32", max_slots=1).generate([req])[0]
-        assert alone.tokens == res.tokens
+        assert engine.generate([req])[0].tokens == res.tokens
     # every slot is free again, and free means zero
     assert all(float(jnp.abs(a).max()) == 0
                for k, a in engine.kv.state.items() if k != "window_table")
 
 
-def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch):
+def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch, shared):
     """The in-place kernel (through the interpreter) against the XLA body
     the CPU picks, under everything that touches a slot's state: five
     requests of unequal length over 4 slots and a pool too small for them,
@@ -244,16 +249,16 @@ def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch):
                for n in (30, 28, 9, 17, 24)]
     new = (40, 36, 7, 12, 21)
 
-    def serve():
-        engine = tiny_engine("float32", num_pages=9)
+    def serve(engine):
         results = engine.generate([
             GenerationRequest(prompt=list(p), max_new_tokens=n)
             for p, n in zip(prompts, new)])
         return [r.tokens for r in results], engine.get_metrics()
 
-    tokens_xla, m_xla = serve()
+    # an engine each: the body is picked when the programs are traced
+    tokens_xla, m_xla = serve(tiny_engine("float32", num_pages=9))
     monkeypatch.setattr(kda, "step_impl", lambda: "inplace_interpret")
-    tokens_kernel, m_kernel = serve()
+    tokens_kernel, m_kernel = serve(tiny_engine("float32", num_pages=9))
     assert tokens_kernel == tokens_xla
     assert [len(t) for t in tokens_xla] == list(new)
     assert m_xla["state"]["step_body"] == "xla"
@@ -263,12 +268,12 @@ def test_both_state_step_bodies_serve_the_same_tokens(monkeypatch):
         assert m["state"]["rows_updated"] == m_xla["state"]["rows_updated"] > 0
 
 
-def test_streamed_matches_unstreamed():
+def test_streamed_matches_unstreamed(shared):
     rng = np.random.default_rng(3)
     prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in (30, 28)]
+    eng = shared("float32")
 
     def run(stream):
-        eng = tiny_engine("float32")
         frames = [[] for _ in prompts]
         for i, p in enumerate(prompts):
             eng.submit(GenerationRequest(prompt=list(p), max_new_tokens=24,
@@ -356,8 +361,8 @@ def test_deploys_this_architecture_cannot_serve_raise(change, match):
         engine_from_config(cfg)
 
 
-def test_calls_a_per_layer_spec_cannot_answer_raise():
-    engine = tiny_engine()
+def test_calls_a_per_layer_spec_cannot_answer_raise(shared):
+    engine = shared(attention_impl="xla")
     with pytest.raises(ValueError, match="without the recurrent state"):
         engine.kv_export([1, 2, 3])
     with pytest.raises(ValueError, match="per-layer"):

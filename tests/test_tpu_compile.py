@@ -449,7 +449,7 @@ def test_the_sliding_window_family_reads_both_pools_in_place_and_fits(
 
     def decode(params, pages, state, lengths, last, active, table):
         ctx = fam.decode_context(pages, table, "pallas-decode")
-        side = jnp.zeros((fam.side_layers(spec), slots, steps,
+        side = jnp.zeros((spec.n_layers, slots, steps,
                           spec.cache_row_width), pages.dtype)
 
         def step(carry, _):

@@ -36,7 +36,7 @@ from distributed_inference_engine_tpu.models import (  # noqa: E402
     ling_spec, xing,
 )
 from distributed_inference_engine_tpu.models.base import (  # noqa: E402
-    LAYERED_FAMILY, layered_family,
+    LAYERED_FAMILY, decode_sums, layered_family, prefill_sums,
 )
 from distributed_inference_engine_tpu.models.mellum import (  # noqa: E402
     mellum_spec,
@@ -214,7 +214,8 @@ def test_a_family_module_defines_the_whole_interface(size):
     pages, state = kv.pools
     params = jax.eval_shape(lambda: fam.init_params(spec, jax.random.key(0)))
     z = jnp.zeros((2,), jnp.int32)
-    side = jnp.zeros((fam.side_layers(spec), 2, 4, pages.shape[-1]),
+    side = jnp.zeros((spec.window_layers + spec.paged_layers, 2, 4,
+                      pages.shape[-1]),
                      pages.dtype)
     step = jax.eval_shape(
         lambda p: fam.forward_decode_step(
@@ -230,8 +231,8 @@ def test_a_family_module_defines_the_whole_interface(size):
             spec, p, jnp.zeros((2, 16), jnp.int32), z + 1, pages, state,
             table_rows, z), params)
     assert prefill[3].shape == (len(fam.PREFILL_COUNTERS),)
-    sums = {**fam.decode_sums(spec, np.array([3, 0]), np.array([10, 5])),
-            **fam.prefill_sums(spec, 10, 16)}
+    sums = {**decode_sums(spec, np.array([3, 0]), np.array([10, 5])),
+            **prefill_sums(spec, 10, 16)}
     assert all(n.count(".") == 1 and type(v) is int and v > 0
                for n, v in sums.items()), sums
     assert not set(sums) & set(fam.DECODE_COUNTERS + fam.PREFILL_COUNTERS)

@@ -34,6 +34,7 @@ from distributed_inference_engine_tpu.models import (  # noqa: E402
     engine_from_config, spec_for_architecture, xing,
 )
 from perfbench.lib import families  # noqa: E402
+from conftest import grown  # noqa: E402  (this directory)
 
 with open(os.path.join(ROOT, "perfbench", "rehearse", "xing-tiny.json")) as _f:
     CFG = json.load(_f)
@@ -65,10 +66,11 @@ def judged(engine, requests, results):
             assert row.max() - row[tok] <= 0.08 * np.abs(row).max(), (i, tok)
 
 
-def test_engine_serves_the_family_through_slots_and_latent_pages():
+def test_engine_serves_the_family_through_slots_and_latent_pages(shared):
     """Six requests of unequal length over four slots: batched admission at
     padded buckets, deferred first tokens, slot reuse, counters."""
-    engine = tiny_engine(prefix_cache=True)
+    engine = shared(prefix_cache=True)
+    m0 = engine.get_metrics()
     assert engine.body == "hybrid" and engine.attn_impl == "xla"
     assert engine.spec.cache_row_width == 128     # 32 + 8 values, one tile
     rng = np.random.default_rng(1)
@@ -81,10 +83,11 @@ def test_engine_serves_the_family_through_slots_and_latent_pages():
     # off from the spec, and the counter says why: per-layer, not recurrent
     assert m["prefix_disabled_per_layer"] == 1
     assert m["prefix_hit_admissions"] == 0 and m["kv"]["prefix_queries"] == 0
-    assert m["decode_steps"] >= 12 and m["decode_chunks"] >= 3
-    moe = m["moe"]
+    steps = m["decode_steps"] - m0["decode_steps"]
+    assert steps >= 12 and m["decode_chunks"] - m0["decode_chunks"] >= 3
+    moe = grown(m0["moe"], m["moe"])
     assert 0 < moe["assignments_held"] == moe["assignments_total"]
-    assert 0 < moe["experts_touched"] <= m["decode_steps"] * 3 * 8
+    assert 0 < moe["experts_touched"] <= steps * 3 * 8
     kv = m["kv"]
     assert (kv["paged_layers"], kv["state_layers"]) == (4, 0)
     assert kv["latent_bytes_per_token"] == 4 * 128 * 2
@@ -93,7 +96,7 @@ def test_engine_serves_the_family_through_slots_and_latent_pages():
     assert {a.shape for a in engine.kv.state.values()} == {(0, 4)}
 
 
-def test_mla_counters_follow_lengths_and_steps():
+def test_mla_counters_follow_lengths_and_steps(shared):
     """One request alone: a prompt of 20 and 9 tokens. The first comes from
     the prefill; the 8 decode steps attend to 21, 22, ... 28 rows each (the
     cached rows and the step's own). What the attention READ is the
@@ -102,13 +105,16 @@ def test_mla_counters_follow_lengths_and_steps():
     started a copy of (the live row's 2 pages of 16 below its frozen
     prefix), both plus the side window (4 slots x 4 rows)."""
     for impl, pages in (("xla", 4 * 8), ("pallas-decode_interpret", 2)):
-        engine = tiny_engine(attention_impl=impl)
+        engine = shared(attention_impl=impl)
+        m0 = engine.get_metrics()
         engine.generate([GenerationRequest(prompt=list(range(1, 21)),
                                            max_new_tokens=9)])
         m = engine.get_metrics()
-        assert m["mla"]["decode_context_rows"] == sum(range(21, 29))
-        assert m["decode_steps"] == 8 == 4 * m["decode_chunks"]
-        assert m["mla"]["decode_table_rows"] == 8 * (pages * 16 + 4 * 4)
+        mla = grown(m0["mla"], m["mla"])
+        assert mla["decode_context_rows"] == sum(range(21, 29))
+        assert (m["decode_steps"] - m0["decode_steps"] == 8
+                == 4 * (m["decode_chunks"] - m0["decode_chunks"]))
+        assert mla["decode_table_rows"] == 8 * (pages * 16 + 4 * 4)
         # a second request grows both: its 2 decode steps attend to 6 and 7
         # rows; the kernel copies its 1 page at those two steps of the
         # chunk's 4 and none once the row has gone inactive
@@ -122,7 +128,7 @@ def test_mla_counters_follow_lengths_and_steps():
                 + (4 * pages if impl == "xla" else 2) * 16 + 4 * 4 * 4)
 
 
-def test_the_kernel_body_emits_the_xla_bodys_tokens():
+def test_the_kernel_body_emits_the_xla_bodys_tokens(shared):
     """The TPU body through the interpreter (latent rows read in place from
     the family's one pool) against ``attention_impl="xla"`` (a layer's pages
     gathered a step), in float32: the same greedy tokens for six requests of
@@ -134,13 +140,15 @@ def test_the_kernel_body_emits_the_xla_bodys_tokens():
     prompts = [[int(t) for t in rng.integers(1, 256, n)] for n, _m in shapes]
 
     def run(impl):
-        engine = tiny_engine(dtype="float32", attention_impl=impl)
+        engine = shared("float32", attention_impl=impl)
         assert (engine.body, engine.attn_impl) == ("hybrid", impl)
+        before = engine.get_metrics()["mla"]
         reqs = [GenerationRequest(prompt=p, max_new_tokens=m)
                 for p, (_n, m) in zip(prompts, shapes)]
         res = engine.generate(reqs)
         judged(engine, reqs, res)
-        return [r.tokens for r in res], engine.get_metrics()["mla"]
+        return ([r.tokens for r in res],
+                grown(before, engine.get_metrics()["mla"]))
 
     want, read_xla = run("xla")
     got, read_kernel = run("pallas-decode_interpret")
@@ -154,18 +162,12 @@ def test_the_kernel_body_emits_the_xla_bodys_tokens():
             < read_xla["decode_table_rows"] // 2)
 
 
-@pytest.fixture(scope="module")
-def counting_engine():
-    """One engine for the hand counts below: its programs compile once."""
-    return tiny_engine()
-
-
 @pytest.mark.parametrize("length,visited,square", [
     (20, 1 + 2, 4),              # bucket 32: both query blocks live
     (37, 1 + 2 + 3, 16),         # bucket 64: three of four
     (50, 1 + 2 + 3 + 4, 16)])    # bucket 64: all four
-def test_prefill_key_block_counters_by_hand(monkeypatch, counting_engine,
-                                            length, visited, square):
+def test_prefill_key_block_counters_by_hand(monkeypatch, shared, length,
+                                            visited, square):
     """Blocks of 16 for the count: what the kernel would visit for an
     admitted prompt (at or under the diagonal, below its length) over the
     blocks of its bucket's whole square, per paged layer; the counters add
@@ -174,14 +176,13 @@ def test_prefill_key_block_counters_by_hand(monkeypatch, counting_engine,
 
     monkeypatch.setattr(mla, "Q_BLOCK", 16)
     monkeypatch.setattr(mla, "K_BLOCK", 16)
-    before = counting_engine.get_metrics()["mla"]
-    counting_engine.generate([GenerationRequest(
+    engine = shared(attention_impl="xla")
+    before = engine.get_metrics()["mla"]
+    engine.generate([GenerationRequest(
         prompt=list(range(1, length + 1)), max_new_tokens=2)])
-    got = counting_engine.get_metrics()["mla"]
-    assert (got["prefill_key_blocks_visited"]
-            - before["prefill_key_blocks_visited"],
-            got["prefill_key_blocks_bucket"]
-            - before["prefill_key_blocks_bucket"]) == (visited, square)
+    got = grown(before, engine.get_metrics()["mla"])
+    assert (got["prefill_key_blocks_visited"],
+            got["prefill_key_blocks_bucket"]) == (visited, square)
 
 
 def test_a_tree_without_latent_layers_reports_no_prefill_blocks():
@@ -194,8 +195,8 @@ def test_a_tree_without_latent_layers_reports_no_prefill_blocks():
                    for k in eng.get_metrics().get("mla", {}))
 
 
-def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
-    engine = tiny_engine(prefix_cache=True)
+def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens(shared):
+    engine = shared(prefix_cache=True)
     prompt = [int(t) for t in np.random.default_rng(2).integers(1, 256, 40)]
     first = engine.generate([GenerationRequest(prompt=list(prompt),
                                                max_new_tokens=8)])
@@ -206,7 +207,7 @@ def test_the_same_prompt_twice_is_no_prefix_hit_and_the_same_tokens():
     assert m["prefix_hit_admissions"] == 0 and m["kv"]["prefix_hit_pages"] == 0
 
 
-def test_a_preempted_sequence_is_re_prefilled_and_resumes():
+def test_a_preempted_sequence_is_re_prefilled_and_resumes(shared):
     """A pool too small for both requests at full length: with no recurrent
     layer the pre-emption is still a re-prefill (the host tier is refused
     and no prefill continues from pages); the result equals the same
@@ -218,10 +219,12 @@ def test_a_preempted_sequence_is_re_prefilled_and_resumes():
         return [GenerationRequest(prompt=list(p), max_new_tokens=40)
                 for p in prompts]
 
-    alone = [tiny_engine("float32").generate([r])[0] for r in make()]
-    tight = tiny_engine("float32", num_pages=7)
+    alone = [shared("float32", attention_impl="xla").generate([r])[0]
+             for r in make()]
+    tight = shared("float32", num_pages=7)
+    m0 = tight.get_metrics()
     together = tight.generate(make())
-    m = tight.get_metrics()
+    m = grown(m0, tight.get_metrics())
     assert m["reprefill_preemptions"] >= 1 and m["capacity_finishes"] == 0
     for a, b in zip(alone, together):
         assert a.tokens == b.tokens and len(b.tokens) == 40
@@ -229,28 +232,30 @@ def test_a_preempted_sequence_is_re_prefilled_and_resumes():
 
 
 @pytest.mark.parametrize("pages", [32, 7])
-def test_streamed_matches_unstreamed(pages):
+def test_streamed_matches_unstreamed(shared, pages):
     rng = np.random.default_rng(3)
     prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in (30, 28)]
+    eng = (shared("float32", num_pages=7) if pages == 7
+           else shared("float32", attention_impl="xla"))
 
     def run(stream):
-        eng = tiny_engine("float32", num_pages=pages)
+        m0 = eng.get_metrics()
         frames = [[] for _ in prompts]
         for i, p in enumerate(prompts):
             eng.submit(GenerationRequest(prompt=list(p), max_new_tokens=40,
                                          request_id=f"x{i}"),
                        on_tokens=frames[i].append if stream else None)
         res = {r.request_id: r for r in eng.run_until_idle()}
-        return eng, [res[f"x{i}"] for i in range(len(prompts))], frames
+        return (grown(m0, eng.get_metrics()),
+                [res[f"x{i}"] for i in range(len(prompts))], frames)
 
-    eng, got, frames = run(True)
+    m, got, frames = run(True)
     _plain, want, _none = run(False)
     assert len(got) == len(want) == 2
     for g, w, fr in zip(got, want, frames):
         assert (g.tokens, g.logprobs, g.finish_reason) == (
             w.tokens, w.logprobs, w.finish_reason)
         assert [t for f in fr for t in f] == g.tokens and len(g.tokens) == 40
-    m = eng.get_metrics()
     assert (m["reprefill_preemptions"] >= 1) == (pages == 7)
     assert (m["emit_carried_chunks"] + m["emit_flushed_chunks"]
             == m["decode_chunks"])
@@ -327,8 +332,8 @@ def test_deploys_this_architecture_cannot_serve_raise(change, match):
         engine_from_config(cfg)
 
 
-def test_calls_a_per_layer_spec_cannot_answer_raise():
-    engine = tiny_engine()
+def test_calls_a_per_layer_spec_cannot_answer_raise(shared):
+    engine = shared(attention_impl="xla")
     with pytest.raises(ValueError,
                        match="continues from cached pages") as e:
         engine.kv_export([1, 2, 3])
