@@ -158,17 +158,20 @@ class PagedKVCache:
             self.k_pages = jnp.zeros(shape, dtype=self.dtype)
             self.v_pages = None
             fam = layered_family(spec)
-            if spec.window_layers:
-                # a third kind: pages of the window layers, bounded a slot
-                self.window_pages_per_slot = fam.window_pages_per_slot(
-                    spec, page_size)
-                self.num_window_pages = (max_slots
-                                         * self.window_pages_per_slot)
-                self.state = fam.init_state(
-                    spec, max_slots, page_size, self.num_window_pages,
-                    self.max_pages_per_seq)
-            else:
-                self.state = fam.init_state(spec, max_slots)
+            # a third kind: pages of the window layers, bounded a slot
+            self.window_pages_per_slot = (
+                fam.window_pages_per_slot(spec, page_size)
+                if spec.window_layers else 0)
+            self.num_window_pages = max_slots * self.window_pages_per_slot
+            # ONE call for every family: each takes of the pool's sizes what
+            # its own storage needs (a per-slot state none; the window
+            # layers' pool ``window_pages``; the index keys' pool, a second
+            # cache of another WIDTH on this pool's own table and lifetimes,
+            # ``num_pages``)
+            self.state = fam.init_state(
+                spec, max_slots, page_size=page_size, num_pages=num_pages,
+                window_pages=self.num_window_pages,
+                max_pages_per_seq=self.max_pages_per_seq)
         elif sharding is not None:
             # tp serving: each chip's pool holds only its heads' lanes.
             # Allocate DIRECTLY sharded — zeros-then-device_put would
@@ -837,5 +840,8 @@ class PagedKVCache:
             "latent_bytes_per_token": (
                 self.spec.paged_layers * self.k_pages.shape[-1] * itemsize
                 if self.spec.layer_kinds else 0),
+            **({"index_bytes_per_token": self.spec.paged_layers
+                * self.spec.index_head_dim * itemsize}
+               if self.spec.index_topk else {}),
             **({"host_tier": host} if host is not None else {}),
         }

@@ -183,10 +183,11 @@ def _family_body(spec: ModelSpec, fam, attn_impl: str) -> DecodeBody:
     def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
         ctx = fam.decode_context(kp, page_table, attn_impl)   # live pages
         # a row a step of every layer that keeps K|V or latent rows, the
-        # window layers' (a pool of their own, in the state) first
+        # window layers' (a pool of their own, in the state) first; with an
+        # indexer the token's index key rides in the row's last lanes
         side = jnp.zeros((spec.window_layers + spec.paged_layers,
-                          start_lengths.shape[0], n_steps, kp.shape[-1]),
-                         kp.dtype)
+                          start_lengths.shape[0], n_steps,
+                          kp.shape[-1] + spec.index_head_dim), kp.dtype)
         return ctx, (side, vp)
 
     def step(params, last, lengths, start_lengths, ctx, cache, active):

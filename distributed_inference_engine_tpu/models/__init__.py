@@ -19,6 +19,7 @@ from .ling import ling_spec  # noqa: F401
 from .xing import kimi_spec, xing_spec  # noqa: F401
 from .olmo_hybrid import olmo_hybrid_spec  # noqa: F401
 from .mellum import mellum_spec  # noqa: F401
+from .keye import keye_spec  # noqa: F401
 from .fake import FakeContinuousEngine, FakeEngine, FakePrefillEngine  # noqa: F401
 
 logger = logging.getLogger(__name__)
@@ -36,6 +37,7 @@ _FAMILIES = {
     "kimi": (kimi_spec, "kimi-k2.5-ep32-pp1"),
     "olmo_hybrid": (olmo_hybrid_spec, "olmo-hybrid-7b-pp2"),
     "mellum": (mellum_spec, "mellum2-12b-a2.5b-pp1"),
+    "keye": (keye_spec, "keye-vl-2.0-30b-a3b-pp1"),
 }
 
 
@@ -422,8 +424,8 @@ def engine_from_config(cfg):
 
 def _hybrid_engine(cfg, spec, ecfg):
     """A per-layer (hybrid) spec, ``models/ling.py``, ``models/xing.py``,
-    ``models/olmo_hybrid.py`` or ``models/mellum.py`` (``models/base.py``
-    ``layered_family`` tells them apart): served by the
+    ``models/olmo_hybrid.py``, ``models/mellum.py`` or ``models/keye.py``
+    (``models/base.py`` ``layered_family`` tells them apart): served by the
     continuous engine from a random tree keyed by ``metadata.seed``. What it
     cannot do yet fails here, before any weight exists; the engine refuses
     the ``ecfg`` keys it cannot honour for such a spec (kv_offload,

@@ -121,7 +121,8 @@ class ModelSpec:
     # the uniform decoder above: every layer softmax attention over K/V,
     # its MLP dense or routed by ``n_experts`` (``layer_plan`` derives it).
     # per layer "kda" | "mla" (models/ling.py, models/xing.py), "gdn" |
-    # "full" (models/olmo_hybrid.py) or "swa" | "full" (models/mellum.py)
+    # "full" (models/olmo_hybrid.py), "swa" | "full" (models/mellum.py) or
+    # "full" alone with ``index_topk`` (models/keye.py)
     layer_kinds: Tuple[str, ...] = ()
     layer_mlps: Tuple[str, ...] = ()    # per layer "dense" | "moe"
     layer_ids: Tuple[int, ...] = ()     # published index of each kept layer
@@ -170,6 +171,15 @@ class ModelSpec:
     hc_eps: float = 0.0
     hc_clamp_min: float = 0.0
     hc_clamp_max: float = 0.0
+    # learned sparse attention (``models/keye.py``, ``ops/sparse_index.py``):
+    # in every "full" layer an indexer of ``index_heads`` query heads of
+    # ``index_head_dim`` over ONE index key a token scores the context, and
+    # the layer's attention reads the ``index_topk`` rows of largest score
+    # (0 = every row). The index keys are a second paged cache, of
+    # ``index_head_dim`` lanes, on the K|V pages' own table.
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
 
     def __post_init__(self) -> None:
         # hashable whatever a dict/JSON round trip handed in
@@ -282,6 +292,15 @@ class ModelSpec:
                         "tree is one period stacked over the periods), "
                         "with sliding_window >= 1 and routed experts in "
                         "every layer")
+            elif self.index_topk:
+                if (kinds != {"full"} or set(self.layer_mlps) != {"moe"}
+                        or self.index_heads < 1 or self.index_head_dim < 1
+                        or self.index_head_dim % 2):
+                    raise ValueError(
+                        "index_topk belongs to a spec whose layers are all "
+                        "'full' attention over K|V rows with routed experts "
+                        "(models/keye.py), with index_heads >= 1 and an "
+                        "even index_head_dim >= 2")
             elif kinds & {"gdn", "full"}:
                 if (not period or self.gdn_key_head_dim < 1
                         or self.gdn_value_head_dim < 1
@@ -309,6 +328,11 @@ class ModelSpec:
                                  "topk_group lie in [1, n_group]")
             if self.moe_scoring not in ("sigmoid", "softmax"):
                 raise ValueError(f"unknown moe_scoring {self.moe_scoring!r}")
+        if self.index_topk < 0 or (self.index_topk
+                                   and "full" not in self.layer_kinds):
+            raise ValueError(
+                "index_topk >= 1 belongs to a per-layer spec of 'full' "
+                "layers (models/keye.py)")
         if self.q_lora_rank and set(self.layer_kinds) != {"mla"}:
             raise ValueError(
                 "a compressed query (q_lora_rank) belongs to a per-layer "
@@ -363,11 +387,14 @@ def decode_sums(spec: ModelSpec, counts, ends) -> Dict[str, int]:
     attended to e - c + 1 ... e rows of a paged layer (the cached ones and
     the chunk's own, its new one included), K|V rows or latent rows; in a
     sliding layer a token at position p sees ``min(p + 1, window)``; each
-    token moves the state of a recurrent layer once."""
+    token moves the state of a recurrent layer once; an indexer
+    (``index_topk``) scores every row its token may see."""
     first = ends - counts
     sums = {"attn.full_context_rows" if spec.kv_row_lanes
             else "mla.decode_context_rows":
             int((counts * first + counts * (counts + 1) // 2).sum())}
+    if spec.index_topk:
+        sums["attn.index_rows_scored"] = sums["attn.full_context_rows"]
     if spec.window_layers:
         window = spec.sliding_window
         below = np.clip(np.minimum(ends, window) - first, 0, None)
@@ -385,7 +412,7 @@ def prefill_sums(spec: ModelSpec, prompt_len: int, bucket: int
     blocks its prefill visited and the blocks of its bucket's whole square,
     a layer of each paged kind (``ops/mla.py`` for latent rows,
     ``ops/flash_prefill.py`` for K|V rows: a sliding layer's are the
-    band's)."""
+    band's); with an indexer the (query, key) pairs it scored, a layer."""
     from ..ops import flash_prefill, mla
 
     if not spec.kv_row_lanes:
@@ -396,9 +423,12 @@ def prefill_sums(spec: ModelSpec, prompt_len: int, bucket: int
         if spec.window_layers:
             pairs["attn.window_"] = flash_prefill.prefill_key_blocks(
                 prompt_len, bucket, spec.sliding_window)
-    return {f"{prefix}prefill_key_blocks_{what}": n
+    sums = {f"{prefix}prefill_key_blocks_{what}": n
             for prefix, pair in pairs.items()
             for what, n in zip(("visited", "bucket"), pair)}
+    if spec.index_topk:
+        sums["attn.index_prefill_pairs"] = prompt_len * (prompt_len + 1) // 2
+    return sums
 
 
 def layered_family(spec: ModelSpec):
@@ -407,7 +437,9 @@ def layered_family(spec: ModelSpec):
     module defines every name of ``LAYERED_FAMILY``
     (``tests/test_xing.py`` holds the five tiny specs to it):
 
-    - ``init_params(spec, key)``; ``init_state(spec, max_slots, ...)``: the
+    - ``init_params(spec, key)``; ``init_state(spec, max_slots, *,
+      page_size, num_pages, window_pages, max_pages_per_seq)`` (ONE call for
+      every family, which takes what its storage needs of them): the
       second pool (``engine/paged_kv.py``), a dict of arrays that rides the
       programs' donation and the decode carry; ``zero_state_slot(state,
       slot)``.
@@ -428,8 +460,11 @@ def layered_family(spec: ModelSpec):
     name, with the host's ``decode_sums`` / ``prefill_sums`` above, and
     knows no family's layout.
 
-    Five families in four modules, told
-    apart by what the spec holds: "swa" layers (``models/mellum.py``:
+    Six families in five modules, told
+    apart by what the spec holds: ``index_topk`` (``models/keye.py``:
+    full-attention layers whose queries read the rows a learned indexer
+    scored highest, index keys in a second paged cache on the K|V pages'
+    table, routed experts everywhere), "swa" layers (``models/mellum.py``:
     sliding-window layers beside full-attention layers, K|V rows in two
     pools of unlike lifetimes, routed experts everywhere),
     ``gdn_key_head_dim`` (``models/olmo_hybrid.py``: Gated DeltaNet layers
@@ -443,6 +478,10 @@ def layered_family(spec: ModelSpec):
     if not spec.layer_kinds:
         raise ValueError("a uniform spec has no per-layer family: its "
                          "forward_* live in models/base.py")
+    if spec.index_topk:
+        from . import keye
+
+        return keye
     if "swa" in spec.layer_kinds:
         from . import mellum
 

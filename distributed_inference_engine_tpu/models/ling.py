@@ -207,8 +207,10 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
 # ------------------------------------------------------------- cache views
 
 
-def init_state(spec: ModelSpec, max_slots: int) -> State:
-    """Recurrent state of every KDA layer for ``max_slots`` sequences."""
+def init_state(spec: ModelSpec, max_slots: int, **_pool) -> State:
+    """Recurrent state of every KDA layer for ``max_slots`` sequences (the
+    pool's sizes, which ``engine/paged_kv.py`` hands every family, are not
+    needed)."""
     lk, h, dk = spec.state_layers, spec.n_heads, spec.kda_head_dim
     return {"S": jnp.zeros((lk, max_slots, h, dk, dk), jnp.float32),
             "conv": jnp.zeros((lk, max_slots, spec.kda_conv - 1, 3 * h * dk),

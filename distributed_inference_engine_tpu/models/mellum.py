@@ -232,7 +232,8 @@ def window_read_pages(spec: ModelSpec, page_size: int) -> int:
 
 
 def init_state(spec: ModelSpec, max_slots: int, page_size: int = 0,
-               window_pages: int = 0, max_pages_per_seq: int = 0) -> State:
+               window_pages: int = 0, max_pages_per_seq: int = 0,
+               **_pool) -> State:
     """The sliding layers' cache: their page pool (all sliding layers, one
     page id naming the same page in each) and the table of the pages each
     slot holds, which the allocator (``engine/paged_kv.py``) rewrites as

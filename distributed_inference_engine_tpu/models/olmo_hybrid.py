@@ -216,7 +216,7 @@ def _lane_pack(spec: ModelSpec) -> int:
     return kda.lane_pack(spec.n_heads, spec.gdn_value_head_dim)
 
 
-def init_state(spec: ModelSpec, max_slots: int) -> State:
+def init_state(spec: ModelSpec, max_slots: int, **_pool) -> State:
     """Recurrent state of every Gated-DeltaNet layer for ``max_slots``
     sequences, ``[n_periods, gdn layers a period, slots, ...]``. ``S`` keeps
     ``kda.lane_pack`` heads side by side along the lanes (two of 96 x 192:

@@ -273,7 +273,7 @@ def init_params(spec: ModelSpec, key: jax.Array) -> Params:
     }
 
 
-def init_state(spec: ModelSpec, max_slots: int) -> State:
+def init_state(spec: ModelSpec, max_slots: int, **_pool) -> State:
     """No layer keeps a per-sequence state: one array of 0 layers."""
     return {"none": jnp.zeros((0, max_slots), jnp.float32)}
 

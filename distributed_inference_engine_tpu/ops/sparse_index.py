@@ -1,0 +1,391 @@
+"""Learned sparse attention (a DeepSeek-Sparse-Attention style indexer,
+``models/keye.py``): index scores, the exact top-k of a query's context, and
+the attention over the rows it selected.
+
+A query t scores every position s <= t it may see,
+
+    I[t, s] = sum_j w[t, j] * relu(q_idx[t, j] . k_idx[s])
+
+(``index_scores``: float32 from the activations' dtype; the XLA body is one
+batched product, the TPU prefill's a tile kernel, and the two may differ in
+a sum's last bits), and its attention reads the ``topk`` positions of
+largest I, equal scores going to the lower position as ``lax.top_k`` does;
+while the context is no longer than ``topk`` that is every position.
+
+- *Decode* (``decode_select``): the scores of a row's cached index keys and
+  of the chunk's own lie side by side in position order, ``lax.top_k`` picks
+  (0.44 ms for 8 rows of 33,808 on one v5e chip, PR 45) and the family
+  gathers the K|V rows picked.
+- *Prefill* (``prefill_attention``): masked-dense, in blocks of queries. The
+  k-th largest score of a query is found by bisection over the float's bits
+  (``kth_key``: 32 counting passes over the block's scores, exact: 0.75 ms
+  for 512 queries of 32,768 keys where ``lax.top_k`` sorts for 17.8 ms), the
+  selection is the mask ``select_mask`` and the attention the blocked
+  softmax under it. On a TPU, at a ``T`` of whole blocks and heads of whole
+  128-lane tiles (``flash_prefill.prefill_impl``, the K|V-row families'
+  rule), the attention is ONE blocked flash kernel a chunk of ``Q_CHUNK``
+  queries (``_masked_flash_kernel``: ``ops/flash_prefill.py``'s grid, online
+  softmax and layout, with the selection handed in as an int8 mask tile a
+  (query block, key block) pair, which also carries the diagonal and the
+  row's length: the kernel compares no position). A chunk's mask is
+  ``[Q_CHUNK, T]`` int8 (128 MB at 32,768), made from the chunk's scores a
+  block of queries at a time (the scores there by ``_index_score_kernel``:
+  a tile's heads summed in VMEM and written once); the chunks run one after another under ONE
+  ``lax.scan`` (every chunk the same shapes and the same kernel, told its
+  first query, writing its query blocks of the result where they lie:
+  unrolled, each chunk's temporaries were given memory of their own, 3.3
+  GiB at 32,768). Elsewhere (the CPU, the tiny specs) the einsum /
+  softmax body.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import flash_prefill
+from .attention import NEG_INF
+from .mla import _VMEM_LIMIT, LANES, _dot_nt, _lanes
+
+# queries a kernel call of the prefill: its selection mask is [Q_CHUNK, T]
+# int8 in HBM
+Q_CHUNK = 4096
+
+__all__ = ["index_scores", "kth_key", "select_mask", "decode_select",
+           "masked_softmax", "prefill_attention", "query_block"]
+
+
+def index_scores(q_idx, k_idx, w):
+    """q_idx [..., Tq, Hi, Di], k_idx [..., S, Di], w [..., Tq, Hi] float32
+    -> I [..., Tq, S] float32: ONE product of all the heads against the
+    keys, ReLU, the weighted sum over the heads. ReLU is written as a
+    select, so a head that is not positive adds +0.0 whatever the sign of
+    its weight: a negative weight times ReLU's 0 is -0.0, which a top-k may
+    order BELOW +0.0, and with few heads such ties are many (every score of
+    a query whose weights are all negative is <= 0, and its top-k is the
+    zeros of lowest position: only if they are equal)."""
+    s = jnp.einsum("...thd,...sd->...ths", q_idx, k_idx,
+                   preferred_element_type=jnp.float32)
+    return jnp.where(s > 0.0, w[..., None].astype(jnp.float32) * s,
+                     0.0).sum(-2)
+
+
+def _ordered(x):
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    i = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    i = jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+    return lax.bitcast_convert_type(i, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+
+def kth_key(keys, k: int):
+    """keys uint32 [..., S] -> the k-th largest of each row (the smallest
+    where the row has fewer than k): its bits from the top down, a counting
+    pass a bit."""
+    def body(i, prefix):
+        cand = prefix | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        count = (keys >= cand[..., None]).sum(-1, dtype=jnp.int32)
+        return jnp.where(count >= k, cand, prefix)
+
+    return lax.fori_loop(0, 32, body,
+                         jnp.zeros(keys.shape[:-1], jnp.uint32))
+
+
+def select_mask(scores, visible, k: int):
+    """scores float32 [..., S], visible bool [..., S] -> bool [..., S]: the
+    min(k, visible) visible positions of largest score, equal scores to the
+    lower position."""
+    keys = jnp.where(visible, _ordered(scores), jnp.uint32(0))
+    kth = kth_key(keys, k)[..., None]
+    above = keys > kth
+    ties = (keys == kth) & visible
+    room = k - above.sum(-1, dtype=jnp.int32, keepdims=True)
+    return (above | (ties & (jnp.cumsum(ties, -1, dtype=jnp.int32) <= room))
+            ) & visible
+
+
+def decode_select(scores, k: int):
+    """scores float32 [B, S], ``-inf`` where a position is not visible ->
+    (positions int32 [B, k'], valid bool [B, k']), k' = min(k, S): the
+    visible positions of largest score, equal scores to the lower one."""
+    vals, idx = lax.top_k(scores, min(k, scores.shape[-1]))
+    return idx, vals > -jnp.inf
+
+
+def query_block(t: int, n_heads: int, limit_bytes: int = 1 << 28) -> int:
+    """Queries a block of the XLA prefill body: the largest power of two
+    that divides ``t``, is at most 512 and keeps the block's float32
+    attention scores ``[heads, block, t]`` under ``limit_bytes``."""
+    bq = 1
+    while (bq < 512 and t % (2 * bq) == 0
+           and n_heads * 2 * bq * t * 4 <= limit_bytes):
+        bq *= 2
+    return bq
+
+
+def _block_selection(q_idx, k_idx, w, seq_lens, topk: int, first,
+                     score_impl: str = "xla"):
+    """The selection of ONE block of queries (``q_idx`` [B, bq, Hi, Di], the
+    queries from ``first`` on) over the keys ``k_idx`` [B, S, Di]: bool [B,
+    bq, S], the diagonal and the row's length in it."""
+    b, bq, hi, di = q_idx.shape
+    cols = jnp.arange(k_idx.shape[1])[None, None, :]
+    qi = first + jnp.arange(bq)[None, :, None]
+    visible = (cols <= qi) & (cols < seq_lens[:, None, None])
+    with jax.named_scope("attn.index"):
+        if score_impl == "xla":
+            scores = index_scores(q_idx, k_idx, w)
+        else:
+            scores = _index_scores_flash(
+                q_idx.reshape(b, bq, hi * di), k_idx, w.astype(jnp.float32),
+                bk=flash_prefill.K_BLOCK,
+                interpret=score_impl == "flash_interpret")
+    with jax.named_scope("attn.select"):
+        return select_mask(scores, visible, topk)
+
+
+def masked_softmax(s, keep):
+    """softmax of ``s`` over its last axis among the entries ``keep`` (bool,
+    broadcast against ``s``); a row that keeps nothing is all zeros."""
+    s = jnp.where(keep, s, NEG_INF)
+    p = jnp.where(keep, jnp.exp(s - s.max(-1, keepdims=True)), 0.0)
+    den = p.sum(-1, keepdims=True)
+    return p / jnp.where(den == 0.0, 1.0, den)
+
+
+def _selection(q_idx, k_idx, w, seq_lens, topk: int, q0, bq: int,
+               score_impl: str = "xla"):
+    """The selection of the queries ``[q0, q0 + Tq)`` (``q_idx`` [B, Tq, Hi,
+    Di], ``w`` [B, Tq, Hi]) over the keys ``k_idx`` [B, S, Di], a block of
+    ``bq`` queries at a time: int8 [B, Tq, S] (1 = selected), the diagonal
+    and the row's length in it. ``score_impl`` "flash" / "flash_interpret":
+    the block's scores from ``_index_score_kernel``."""
+    b, tq = q_idx.shape[:2]
+    s = k_idx.shape[1]
+
+    def block(i0):
+        take = lambda a: lax.dynamic_slice_in_dim(a, i0, bq, axis=1)
+        return _block_selection(take(q_idx), k_idx, take(w), seq_lens, topk,
+                                q0 + i0, score_impl).astype(jnp.int8)
+
+    keep = lax.map(block, jnp.arange(0, tq, bq))       # [nb, B, bq, S]
+    return jnp.moveaxis(keep, 0, 1).reshape(b, tq, s)
+
+
+def _index_score_kernel(q_ref, k_ref, w_ref, o_ref, *, heads: int, di: int):
+    """One (query block, key block) tile of ``index_scores``: the heads one
+    after another, the sum in VMEM, ONE write of the tile (XLA's form holds
+    every head's [512, keys] float32 product before it sums them)."""
+    k = k_ref[0]
+    acc = jnp.zeros(o_ref.shape[1:], jnp.float32)
+    for j in range(heads):
+        s = _dot_nt(q_ref[0, :, j * di:(j + 1) * di], k)       # [bq, bk]
+        acc = acc + jnp.where(s > 0.0, w_ref[0, :, j:j + 1] * s, 0.0)
+    o_ref[0] = acc
+
+
+@functools.partial(jax.jit, static_argnames=("bk", "interpret"))
+def _index_scores_flash(q_idx, k_idx, w, *, bk: int, interpret: bool):
+    """q_idx [B, bq, Hi * Di], k_idx [B, S, Di], w float32 [B, bq, Hi] ->
+    index scores float32 [B, bq, S]."""
+    b, bq, width = q_idx.shape
+    s, di = k_idx.shape[1], k_idx.shape[2]
+    heads = width // di
+    return pl.pallas_call(
+        functools.partial(_index_score_kernel, heads=heads, di=di),
+        grid=(b, s // bk),
+        in_specs=[pl.BlockSpec((1, bq, width), lambda r, j: (r, 0, 0)),
+                  pl.BlockSpec((1, bk, di), lambda r, j: (r, j, 0)),
+                  pl.BlockSpec((1, bq, heads), lambda r, j: (r, 0, 0))],
+        out_specs=pl.BlockSpec((1, bq, bk), lambda r, j: (r, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((b, bq, s), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        # the name says the work of a call, which has no other trace in a
+        # profile: rows x queries x keys, every pair scored
+        name=f"index_scores_flash_b{b}q{bq}k{s}",
+    )(q_idx, k_idx, w)
+
+
+def prefill_attention(q, rows, q_idx, k_idx, w, seq_lens, n_kv_heads: int,
+                      topk: int, impl: str = "") -> jnp.ndarray:
+    """q [B, T, H, Dh]; rows [B, T, 2 * Hkv * Dh], a token's ``k | v``;
+    q_idx [B, T, Hi, Di], k_idx [B, T, Di], w [B, T, Hi]. Query i attends to
+    the ``topk`` positions j <= i (below ``seq_lens``) of largest index
+    score, all of them while i < topk; scores at ``Dh^-1/2``. Returns
+    [B, T, H, Dh]; rows past ``seq_lens`` are not specified. ``impl``: see
+    ``flash_prefill.prefill_impl``, which chooses when it is empty."""
+    b, t, h, dh = q.shape
+    impl = impl or flash_prefill.prefill_impl(t, dh)
+    if impl != "xla":
+        bq, bk = flash_prefill.Q_BLOCK, flash_prefill.K_BLOCK
+        # whole query blocks that divide the bucket (33,792 = 11 x 3,072)
+        chunk = max(c for c in range(bq, min(Q_CHUNK, t) + 1, bq)
+                    if t % c == 0)
+        lens = seq_lens.astype(jnp.int32)
+
+        q_flat = q.reshape(b, t, h * dh)
+
+        def one(out, q0):
+            take = lambda a: lax.dynamic_slice_in_dim(a, q0, chunk, axis=1)
+            keep = _selection(take(q_idx), k_idx, take(w), seq_lens, topk,
+                              q0, bq, score_impl=impl)
+            with jax.named_scope("attn.sparse"):
+                return _masked_flash_prefill(
+                    q_flat, rows, keep, lens, q0[None], out,
+                    n_kv_heads=n_kv_heads, bq=bq, bk=bk,
+                    heads_per_step=flash_prefill.HEADS_PER_STEP,
+                    interpret=impl == "flash_interpret"), None
+
+        out, _ = lax.scan(one, jnp.zeros_like(q_flat),
+                          jnp.arange(0, t, chunk, dtype=jnp.int32))
+        return out.reshape(b, t, h, dh)
+    g = h // n_kv_heads
+    lanes = n_kv_heads * dh
+    k, v = (rows[..., at:at + lanes].reshape(b, t, n_kv_heads, dh)
+            for at in (0, lanes))
+    bq = query_block(t, h)
+
+    def block(i0):
+        take = lambda a: lax.dynamic_slice_in_dim(a, i0, bq, axis=1)
+        keep = _block_selection(take(q_idx), k_idx, take(w), seq_lens, topk,
+                                i0)                            # [B, bq, T]
+        with jax.named_scope("attn.sparse"):
+            qb = take(q).reshape(b, bq, n_kv_heads, g, dh)
+            s = jnp.einsum("bikgd,bjkd->bkgij", qb, k,
+                           preferred_element_type=jnp.float32) * dh ** -0.5
+            p = masked_softmax(s, keep[:, None, None])
+            o = jnp.einsum("bkgij,bjkd->bikgd", p.astype(v.dtype), v)
+        return o.reshape(b, bq, h, dh)
+
+    out = lax.map(block, jnp.arange(0, t, bq))               # [nb, B, bq, ..]
+    return jnp.moveaxis(out, 0, 1).reshape(b, t, h, dh)
+
+
+def _masked_flash_kernel(lens_ref, q0_ref, qi_ref, ki_ref, q_ref, k_ref,
+                         v_ref, keep_ref, _o_in, o_ref, m_ref, l_ref,
+                         acc_ref, *,
+                         scale: float, heads: int, group: int, dh: int,
+                         bq: int, bk: int, last: int):
+    """``flash_prefill._flash_kernel`` for the queries from ``q0_ref[0]`` on,
+    every pair masked by the tile of ``keep`` handed in: the diagonal and
+    the length are in the mask. The pair list is every (query block, key
+    block) of the chunk against the whole row; a pair past the diagonal or
+    the length computes nothing."""
+    n = lens_ref[pl.program_id(0)]
+    pair = pl.program_id(2)
+    q_at, k_at = q0_ref[0] + qi_ref[pair] * bq, ki_ref[pair] * bk
+
+    @pl.when(k_at == 0)                        # the query block's first pair
+    def _():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when((q_at < n) & (k_at < n) & (k_at < q_at + bq))
+    def _():
+        keep = keep_ref[0].astype(jnp.int32) != 0              # [bq, bk]
+        for j in range(heads):
+            at = j // group * dh               # the head's K/V head's lanes
+            s = _dot_nt(q_ref[0, :, j * dh:(j + 1) * dh],
+                        k_ref[0, :, at:at + dh]) * scale
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_ref[j]                                  # [bq, LANES]
+            m_next = jnp.maximum(m_prev, s.max(axis=-1)[:, None])
+            # a row with no key in this block and none before it: its m is
+            # still NEG_INF and exp(s - m) is 1
+            p = jnp.where(keep, jnp.exp(s - _lanes(m_next, bk)), 0.0)
+            alpha = jnp.exp(m_prev - m_next)
+            l_ref[j] = alpha * l_ref[j] + p.sum(axis=-1)[:, None]
+            m_ref[j] = m_next
+            v = v_ref[0, :, at:at + dh]
+            acc_ref[j] = _lanes(alpha, dh) * acc_ref[j] + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    @pl.when(ki_ref[pair] == last)             # the query block's last pair
+    def _():
+        for j in range(heads):
+            l = l_ref[j]                       # 0: a row that saw no key
+            o = acc_ref[j] / _lanes(jnp.where(l == 0.0, 1.0, l), dh)
+            o_ref[0, :, j * dh:(j + 1) * dh] = o.astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "n_kv_heads", "bq", "bk", "heads_per_step", "interpret"))
+def _masked_flash_prefill(q, rows, keep, seq_lens, q0, out, *,
+                          n_kv_heads: int, bq: int, bk: int,
+                          heads_per_step: int, interpret: bool):
+    """q [B, T, H * Dh], rows [B, T, 2 * Hkv * Dh], keep int8 [B, Tq, T] the
+    selection of the queries ``[q0, q0 + Tq)`` (``q0`` int32 [1], whole
+    query blocks), seq_lens int32 [B], out [B, T, H * Dh] -> out with those
+    queries' blocks written (the buffer is the argument's: aliased)."""
+    b, t, width = q.shape
+    tq = keep.shape[1]
+    dh = rows.shape[-1] // (2 * n_kv_heads)
+    h = width // dh
+    g = h // n_kv_heads
+    # query heads a step: whole K/V heads' groups, or a part of one group
+    hb = max(d for d in range(1, heads_per_step + 1)
+             if h % d == 0 and (d % g == 0 or g % d == 0))
+    kb = max(hb // g, 1)                       # K/V heads a step
+    qi, ki = (np.repeat(np.arange(tq // bq, dtype=np.int32), t // bk),
+              np.tile(np.arange(t // bk, dtype=np.int32), tq // bq))
+
+    def needed(row, pair, lens, q0, qi, ki):
+        # the last key block this pair's query block reads of this row:
+        # past it nothing is computed, and the block held is named again
+        diag = (q0[0] + qi[pair] * bq + bq - 1) // bk
+        return jnp.minimum(ki[pair], jnp.minimum(
+            diag, jnp.maximum(lens[row] - 1, 0) // bk))
+
+    def q_at(row, grp, pair, lens, q0, qi, ki):
+        return row, q0[0] // bq + qi[pair], grp
+
+    def k_at(row, grp, pair, lens, q0, qi, ki):
+        return (row, needed(row, pair, lens, q0, qi, ki),
+                grp * hb // g // kb)
+
+    def v_at(*a):
+        row, blk, lane_blk = k_at(*a)
+        return row, blk, n_kv_heads // kb + lane_blk
+
+    def keep_at(row, grp, pair, lens, q0, qi, ki):
+        return row, qi[pair], needed(row, pair, lens, q0, qi, ki)
+
+    kernel = functools.partial(
+        _masked_flash_kernel, scale=dh ** -0.5, heads=hb, group=g, dh=dh,
+        bq=bq, bk=bk, last=t // bk - 1)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b, h // hb, len(qi)),
+            in_specs=[
+                pl.BlockSpec((1, bq, hb * dh), q_at),
+                pl.BlockSpec((1, bk, kb * dh), k_at),
+                pl.BlockSpec((1, bk, kb * dh), v_at),
+                pl.BlockSpec((1, bq, bk), keep_at),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, bq, hb * dh), q_at),
+            scratch_shapes=[pltpu.VMEM((hb, bq, LANES), jnp.float32),
+                            pltpu.VMEM((hb, bq, LANES), jnp.float32),
+                            pltpu.VMEM((hb, bq, dh), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, t, width), q.dtype),
+        input_output_aliases={8: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        # the call's grid: rows x the chunk's queries x the bucket's keys
+        # (what of it is live the diagonal and the lengths decide)
+        name=f"sparse_prefill_flash_b{b}q{tq}k{t}",
+    )(seq_lens, q0, qi, ki, q, rows, rows, keep, out)

@@ -7,7 +7,8 @@ window) on the ``window`` body (``pallas-decode_interpret``) and on ``dense``
 and on XLA (``hybrid``; a parent dumped before has no such files: ``compare``
 walks its first directory's); since PR 40 ``xing-tiny`` and ``mellum-tiny``
 (window 32) the same way; since PR 41 ``kimi-tiny`` (12 slots: the plain
-residual and the held-rows expert layer). To dump a parent that lacks an
+residual and the held-rows expert layer); since PR 45 ``keye-tiny`` (a top-k
+of 16, its one XLA body) where the package has it. To dump a parent that lacks an
 entry, run THIS file over its package (``PYTHONPATH=<parent> python <this
 file> dump <dir>``).
 
@@ -140,6 +141,15 @@ def dump(out: str) -> None:
                            decode_steps_per_call=8, attention_impl=impl)
         _dump_engine(out, name.replace("olmo", "kimi"), ContinuousEngine(
             xing.kimi_spec("kimi-tiny", max_seq_len=128), config=cfg))
+    try:
+        from distributed_inference_engine_tpu.models.keye import keye_spec
+    except ImportError:                         # a parent before PR 45
+        return
+    cfg = EngineConfig(max_slots=4, max_seq_len=256, page_size=8,
+                       num_pages=128, prefill_buckets=[32, 64],
+                       decode_steps_per_call=8)
+    _dump_engine(out, "keye_tiny_xla", ContinuousEngine(
+        keye_spec("keye-tiny", max_seq_len=256), config=cfg))
 
 
 def compare(a: str, b: str, verbose: bool = False) -> bool:

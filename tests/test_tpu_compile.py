@@ -503,6 +503,101 @@ def test_the_sliding_window_family_reads_both_pools_in_place_and_fits(
     assert temp < 1.5e9, temp          # the parent's: 1,831,853,568
 
 
+def test_the_learned_selection_family_compiles_and_fits(one_chip,
+                                                         monkeypatch):
+    """The learned-sparse-attention family's programs at the served size (6
+    layers of 128 experts, 8 slots of 33,792 positions, 16 steps; a prefill
+    at the 32,768 bucket), compiled for the v5e with the Mosaic grouped
+    matmul: the decode chunk gathers index keys through the table and the
+    selected K|V rows from the pool where it lies (no copy or slice of
+    either pool), and the largest prefill (``flash_prefill.prefill_impl`` is
+    steered to the chip's answer here: the masked flash kernel a chunk of
+    4,096 queries, one index head's scores at a time) fits its temporaries
+    beside the 8.75 GB tree and the 3.53 GB of pages (12.28 GB held) in a
+    chip's 15.75 GiB = 16.9 GB: 2.3 GB (with every chunk unrolled and all 16
+    heads' score products alive at once it was 4.6 GB and did not)."""
+    from distributed_inference_engine_tpu.models import keye as fam
+    from distributed_inference_engine_tpu.models.base import unembed
+    from distributed_inference_engine_tpu.ops import flash_prefill
+
+    monkeypatch.setattr(flash_prefill, "prefill_impl",
+                        lambda t, dh: "flash")
+
+    spec = fam.keye_spec("keye-vl-2.0-30b-a3b-pp1", max_seq_len=33792)
+    slots, page, mp, steps = 8, 128, 264, 16
+    n_pages = slots * mp
+    width = spec.cache_row_width
+    assert (width, spec.index_head_dim, spec.index_topk) == (1024, 64, 2048)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def arr(*shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_params(spec, jax.random.key(0))))
+    state = jax.tree.map(sds, jax.eval_shape(
+        lambda: fam.init_state(spec, slots, page, n_pages)))
+    pool = arr(spec.paged_layers, n_pages, page, width, dtype=jnp.bfloat16)
+    held = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(
+        (params, state, pool)))
+    assert 12.2e9 < held < 12.3e9
+
+    def decode(params, pages, state, lengths, last, active, table):
+        ctx = fam.decode_context(pages, table, "xla")
+        side = jnp.zeros((spec.n_layers, slots, steps,
+                          width + spec.index_head_dim), pages.dtype)
+
+        def step(carry, _):
+            side, state, now, last = carry
+            hidden, side, state, _m = fam.forward_decode_step(
+                spec, params, last, now, lengths, ctx, side, state, active,
+                moe_impl="gmm")
+            tok = jnp.argmax(unembed(spec, params, hidden), -1)
+            return (side, state, now + 1, tok.astype(jnp.int32)), tok
+
+        (side, state, now, last), toks = jax.lax.scan(
+            step, (side, state, lengths, last), None, length=steps)
+        pages, state = fam.write_side(pages, state, side, table,
+                                      now - lengths, lengths)
+        return pages, state, toks
+
+    def prefill(params, tokens, lens, pages, state, table, slot_ids):
+        hidden, pages, state, _ = fam.forward_prefill_into_pages(
+            spec, params, tokens, lens, pages, state, table, slot_ids,
+            moe_impl="gmm")
+        return hidden[:, -1], pages, state
+
+    dec = jax.jit(decode, donate_argnums=(1, 2)).lower(
+        params, pool, state, arr(slots), arr(slots),
+        arr(slots, dtype=jnp.bool_), arr(slots, mp)).compile()
+    text = dec.as_text()
+    # the K|V pool is gathered from and scattered into, never copied or
+    # sliced a layer
+    ops = set()
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z-]*)\(", line)
+        if m and "[6,2112,128,1024]" in m.group(1).split(" ")[0]:
+            ops.add(m.group(2))
+    assert ops and ops <= {"parameter", "tuple", "get-tuple-element",
+                           "while", "bitcast", "scatter", "fusion"}, ops
+    assert dec.memory_analysis().temp_size_in_bytes < 1.0e9
+    pre = jax.jit(prefill, donate_argnums=(3, 4)).lower(
+        params, arr(1, 32768), arr(1), pool, state, arr(1, mp),
+        arr(1)).compile()
+    temp = pre.memory_analysis().temp_size_in_bytes
+    assert temp < 2.6e9 and held + temp < 15.0e9, (held, temp)
+    text = pre.as_text()
+    # the two kernels, each run's shape in its name (what a profile's
+    # reader counts their work by: perfbench/lib/scopes_dsa.py)
+    assert "sparse_prefill_flash_b1q4096k32768" in text
+    assert "index_scores_flash_b1q512k32768" in text
+    assert "attn.dsa/attn.sparse/" in text or "attn.sparse" in text
+    # no float32 score tensor of every head of a block of queries
+    assert not re.search(r"f32\[1,4,8,\d+,32768\]", text)
+
+
 def test_latent_decode_at_64_heads_and_32_rows_compiles_for_v5e(one_chip):
     """The latent kernel at the Kimi cell's shape: 64 query heads on the
     sublanes (twice the other two families'), 32 rows of 60 pages, the
