@@ -412,7 +412,8 @@ def prefill_sums(spec: ModelSpec, prompt_len: int, bucket: int
     blocks its prefill visited and the blocks of its bucket's whole square,
     a layer of each paged kind (``ops/mla.py`` for latent rows,
     ``ops/flash_prefill.py`` for K|V rows: a sliding layer's are the
-    band's); with an indexer the (query, key) pairs it scored, a layer."""
+    band's); with an indexer the (query, key) pairs it scored, a layer, and
+    the blocks of queries whose selection the TPU's kernel made."""
     from ..ops import flash_prefill, mla
 
     if not spec.kv_row_lanes:
@@ -428,6 +429,13 @@ def prefill_sums(spec: ModelSpec, prompt_len: int, bucket: int
             for what, n in zip(("visited", "bucket"), pair)}
     if spec.index_topk:
         sums["attn.index_prefill_pairs"] = prompt_len * (prompt_len + 1) // 2
+        # blocks of queries whose top-k the selection kernel makes
+        # (``ops/sparse_index.py``): a bucket above the top-k on the kernel
+        # body, every layer; 0 where the rule falls back to the XLA body
+        kernel = (bucket > spec.index_topk and flash_prefill.prefill_impl(
+            bucket, spec.head_dim) != "xla")
+        sums["attn.prefill_select_blocks"] = (
+            bucket // flash_prefill.Q_BLOCK * spec.n_layers if kernel else 0)
     return sums
 
 

@@ -18,24 +18,31 @@ while the context is no longer than ``topk`` that is every position.
   gathers the K|V rows picked.
 - *Prefill* (``prefill_attention``): masked-dense, in blocks of queries. The
   k-th largest score of a query is found by bisection over the float's bits
-  (``kth_key``: 32 counting passes over the block's scores, exact: 0.75 ms
-  for 512 queries of 32,768 keys where ``lax.top_k`` sorts for 17.8 ms), the
-  selection is the mask ``select_mask`` and the attention the blocked
+  (``kth_key``: 32 counting passes over the block's scores, exact, where
+  ``lax.top_k`` sorts for 17.8 ms a block of 512 queries of 32,768 keys),
+  the selection is the mask ``select_mask`` and the attention the blocked
   softmax under it. On a TPU, at a ``T`` of whole blocks and heads of whole
   128-lane tiles (``flash_prefill.prefill_impl``, the K|V-row families'
-  rule), the attention is ONE blocked flash kernel a chunk of ``Q_CHUNK``
-  queries (``_masked_flash_kernel``: ``ops/flash_prefill.py``'s grid, online
-  softmax and layout, with the selection handed in as an int8 mask tile a
-  (query block, key block) pair, which also carries the diagonal and the
-  row's length: the kernel compares no position). A chunk's mask is
-  ``[Q_CHUNK, T]`` int8 (128 MB at 32,768), made from the chunk's scores a
-  block of queries at a time (the scores there by ``_index_score_kernel``:
-  a tile's heads summed in VMEM and written once); the chunks run one after another under ONE
-  ``lax.scan`` (every chunk the same shapes and the same kernel, told its
+  rule), a block of ``Q_BLOCK`` queries goes through THREE kernels, HBM
+  between them: ``_index_score_kernel`` (a tile's heads summed in VMEM, the
+  block's float32 scores written once), ``_select_mask_kernel`` (a tile of
+  32 queries' whole score rows held in VMEM: the ordered keys made once,
+  the 32 counting passes and the tie rule there, up to the tile's last
+  visible key, the scores read once and the int8 mask written once: 0.28
+  ms a block of 512 queries over a 32,768 prompt's 64 blocks in a served
+  trace on one v5e chip, 0.60 ms the last block alone, where the passes as
+  XLA ops, each over the block in HBM, took 2.06 and 2.39, PR 46) and, a
+  chunk of ``Q_CHUNK`` queries at a time, ONE blocked flash kernel
+  (``_masked_flash_kernel``: ``ops/flash_prefill.py``'s grid, online softmax
+  and layout, with the selection handed in as an int8 mask tile a (query
+  block, key block) pair, which also carries the diagonal and the row's
+  length: the kernel compares no position). A chunk's mask is ``[Q_CHUNK,
+  T]`` int8 (128 MB at 32,768); the chunks run one after another under ONE
+  ``lax.scan`` (every chunk the same shapes and the same kernels, told its
   first query, writing its query blocks of the result where they lie:
   unrolled, each chunk's temporaries were given memory of their own, 3.3
-  GiB at 32,768). Elsewhere (the CPU, the tiny specs) the einsum /
-  softmax body.
+  GiB at 32,768). Elsewhere (the CPU, the tiny specs) ``select_mask`` and
+  the einsum / softmax body: the kernels' oracle.
 """
 
 from __future__ import annotations
@@ -76,10 +83,15 @@ def index_scores(q_idx, k_idx, w):
                      0.0).sum(-2)
 
 
+def _ordered_signed(x):
+    """float32 -> int32 whose signed order is the floats' order."""
+    i = lax.bitcast_convert_type(x, jnp.int32)
+    return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+
+
 def _ordered(x):
     """float32 -> uint32 whose unsigned order is the floats' order."""
-    i = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
-    i = jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
+    i = _ordered_signed(x.astype(jnp.float32))
     return lax.bitcast_convert_type(i, jnp.uint32) ^ jnp.uint32(0x80000000)
 
 
@@ -131,22 +143,28 @@ def query_block(t: int, n_heads: int, limit_bytes: int = 1 << 28) -> int:
 def _block_selection(q_idx, k_idx, w, seq_lens, topk: int, first,
                      score_impl: str = "xla"):
     """The selection of ONE block of queries (``q_idx`` [B, bq, Hi, Di], the
-    queries from ``first`` on) over the keys ``k_idx`` [B, S, Di]: bool [B,
-    bq, S], the diagonal and the row's length in it."""
+    queries from ``first`` on) over the keys ``k_idx`` [B, S, Di]: [B, bq,
+    S], the diagonal and the row's length in it; bool from the XLA body,
+    int8 from the kernels (``score_impl`` "flash" / "flash_interpret")."""
     b, bq, hi, di = q_idx.shape
-    cols = jnp.arange(k_idx.shape[1])[None, None, :]
-    qi = first + jnp.arange(bq)[None, :, None]
-    visible = (cols <= qi) & (cols < seq_lens[:, None, None])
-    with jax.named_scope("attn.index"):
-        if score_impl == "xla":
+    if score_impl == "xla":
+        cols = jnp.arange(k_idx.shape[1])[None, None, :]
+        qi = first + jnp.arange(bq)[None, :, None]
+        visible = (cols <= qi) & (cols < seq_lens[:, None, None])
+        with jax.named_scope("attn.index"):
             scores = index_scores(q_idx, k_idx, w)
-        else:
-            scores = _index_scores_flash(
-                q_idx.reshape(b, bq, hi * di), k_idx, w.astype(jnp.float32),
-                bk=flash_prefill.K_BLOCK,
-                interpret=score_impl == "flash_interpret")
+        with jax.named_scope("attn.select"):
+            return select_mask(scores, visible, topk)
+    interpret = score_impl == "flash_interpret"
+    with jax.named_scope("attn.index"):
+        scores = _index_scores_flash(
+            q_idx.reshape(b, bq, hi * di), k_idx, w.astype(jnp.float32),
+            bk=flash_prefill.K_BLOCK, interpret=interpret)
     with jax.named_scope("attn.select"):
-        return select_mask(scores, visible, topk)
+        return _select_mask_flash(
+            scores, seq_lens.astype(jnp.int32),
+            jnp.asarray(first, jnp.int32)[None], topk=topk,
+            bk=flash_prefill.K_BLOCK, interpret=interpret)
 
 
 def masked_softmax(s, keep):
@@ -159,19 +177,19 @@ def masked_softmax(s, keep):
 
 
 def _selection(q_idx, k_idx, w, seq_lens, topk: int, q0, bq: int,
-               score_impl: str = "xla"):
+               score_impl: str):
     """The selection of the queries ``[q0, q0 + Tq)`` (``q_idx`` [B, Tq, Hi,
     Di], ``w`` [B, Tq, Hi]) over the keys ``k_idx`` [B, S, Di], a block of
-    ``bq`` queries at a time: int8 [B, Tq, S] (1 = selected), the diagonal
-    and the row's length in it. ``score_impl`` "flash" / "flash_interpret":
-    the block's scores from ``_index_score_kernel``."""
+    ``bq`` queries at a time through the two kernels (``score_impl`` "flash"
+    / "flash_interpret"): int8 [B, Tq, S] (1 = selected), the diagonal and
+    the row's length in it."""
     b, tq = q_idx.shape[:2]
     s = k_idx.shape[1]
 
     def block(i0):
         take = lambda a: lax.dynamic_slice_in_dim(a, i0, bq, axis=1)
         return _block_selection(take(q_idx), k_idx, take(w), seq_lens, topk,
-                                q0 + i0, score_impl).astype(jnp.int8)
+                                q0 + i0, score_impl)
 
     keep = lax.map(block, jnp.arange(0, tq, bq))       # [nb, B, bq, S]
     return jnp.moveaxis(keep, 0, 1).reshape(b, tq, s)
@@ -212,6 +230,126 @@ def _index_scores_flash(q_idx, k_idx, w, *, bk: int, interpret: bool):
         # profile: rows x queries x keys, every pair scored
         name=f"index_scores_flash_b{b}q{bq}k{s}",
     )(q_idx, k_idx, w)
+
+
+_NO_KEY = -2 ** 31      # the signed form of ``_ordered``'s 0: not visible
+
+
+def _select_mask_kernel(lens_ref, first_ref, s_ref, o_ref, keys_ref, *,
+                        topk: int, tq: int, bk: int):
+    """``select_mask`` of ``tq`` queries' whole rows of scores, held in VMEM:
+    the ordered keys written once (``_ordered_signed``, ``_NO_KEY``
+    where the diagonal or the length hides a position), ``kth_key``'s 32
+    counting passes over them, then the ties: of the keys equal to the k-th
+    the lowest positions fill what room is left, their bound found by the
+    same bisection over a position's bits (only where a tile has a row with
+    more ties than room). Every loop over key tiles ends at the tile's last
+    visible position; the mask past it is zeros."""
+    s = s_ref.shape[-1]
+    lanes = min(bk, LANES)
+    n = lens_ref[pl.program_id(0)]
+    q_first = first_ref[0] + pl.program_id(1) * tq
+    seen = jnp.minimum(q_first + tq, n)        # positions the last query sees
+    live = jnp.minimum((seen - 1 + bk) // bk, s // bk)
+    rows = q_first + lax.broadcasted_iota(jnp.int32, (tq, bk), 0)
+
+    def at(j):
+        return pl.ds(pl.multiple_of(j * bk, bk), bk)
+
+    def cols(j):
+        return j * bk + lax.broadcasted_iota(jnp.int32, (tq, bk), 1)
+
+    def order(j, _):
+        i, c = _ordered_signed(s_ref[0, :, at(j)]), cols(j)
+        keys_ref[:, at(j)] = jnp.where((c <= rows) & (c < n), i, _NO_KEY)
+
+    lax.fori_loop(0, live, order, None)
+
+    def dead(j, _):
+        o_ref[0, :, at(j)] = jnp.zeros((tq, bk), jnp.int8)
+
+    lax.fori_loop(live, s // bk, dead, None)
+
+    lane = lax.broadcasted_iota(jnp.int32, (tq, lanes), 1)
+
+    def count(hit):
+        """hit(keys [tq, lanes], their first column) -> bool; int32 [tq, 1]
+        of the hits over the live tiles."""
+        def tile(j, acc):
+            keys = keys_ref[:, at(j)]
+            for l in range(0, bk, lanes):
+                acc = acc + hit(keys[:, l:l + lanes],
+                                j * bk + l).astype(jnp.int32)
+            return acc
+
+        acc = lax.fori_loop(0, live, tile, jnp.zeros((tq, lanes), jnp.int32))
+        return acc.sum(-1, keepdims=True)
+
+    def wide(x):                               # [tq, 1] -> [tq, lanes]
+        return jnp.broadcast_to(x, (tq, lanes))
+
+    def key_bit(i, prefix):
+        cand = prefix | (jnp.int32(1) << (31 - i))
+        least = wide(cand ^ _NO_KEY)
+        return jnp.where(count(lambda keys, _: keys >= least) >= topk,
+                         cand, prefix)
+
+    # a tile whose last query sees no more than topk positions keeps them
+    # all: no pass, the k-th is _NO_KEY
+    kth = lax.fori_loop(0, jnp.where(seen > topk, 32, 0), key_bit,
+                        jnp.zeros((tq, 1), jnp.int32)) ^ _NO_KEY
+    kth_w = wide(kth)
+    room = topk - count(lambda keys, _: keys > kth_w)
+    ties = count(lambda keys, _: keys == kth_w)
+    # fewer visible than topk: the k-th is _NO_KEY, which is no visible
+    # position's key, and nothing ties
+    none = kth == _NO_KEY
+    more = (ties > room) & ~none               # more ties than room
+
+    def position_bit(i, bound):
+        cand = bound | (jnp.int32(1) << (s.bit_length() - 1 - i))
+        cand_w = wide(cand)
+        below = count(lambda keys, c0: (keys == kth_w) & (c0 + lane < cand_w))
+        return jnp.where(below <= room, cand, bound)
+
+    # the largest bound with no more than ``room`` ties below it
+    bound = lax.fori_loop(
+        0, jnp.where(more.astype(jnp.int32).max() > 0, s.bit_length(), 0),
+        position_bit, jnp.zeros((tq, 1), jnp.int32))
+    bound = jnp.where(none, 0, jnp.where(more, bound, s))
+
+    def write(j, _):
+        keys, c = keys_ref[:, at(j)], cols(j)
+        o_ref[0, :, at(j)] = ((keys > kth) | ((keys == kth) & (c < bound))
+                              ).astype(jnp.int8)
+
+    lax.fori_loop(0, live, write, None)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "bk", "interpret"))
+def _select_mask_flash(scores, seq_lens, first, *, topk: int, bk: int,
+                       interpret: bool):
+    """scores float32 [B, bq, S] of the queries from ``first`` (int32 [1])
+    on, seq_lens int32 [B] -> ``select_mask`` under the diagonal and the
+    lengths, int8 [B, bq, S]: the scores read once, the mask written once."""
+    b, bq, s = scores.shape
+    tq = min(bq, 32)                           # int8's sublane tile
+    return pl.pallas_call(
+        functools.partial(_select_mask_kernel, topk=topk, tq=tq, bk=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, bq // tq),
+            in_specs=[pl.BlockSpec((1, tq, s), lambda r, i, *_: (r, i, 0))],
+            out_specs=pl.BlockSpec((1, tq, s), lambda r, i, *_: (r, i, 0)),
+            scratch_shapes=[pltpu.VMEM((tq, s), jnp.int32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, bq, s), jnp.int8),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name=f"select_mask_flash_b{b}q{bq}k{s}",
+    )(seq_lens, first, scores)
 
 
 def prefill_attention(q, rows, q_idx, k_idx, w, seq_lens, n_kv_heads: int,

@@ -385,6 +385,118 @@ def test_select_mask_is_top_k_with_ties_to_the_lower_position():
     assert got[3].sum() == 9 and got[4].sum() == 0
 
 
+def _kernel_and_oracle(scores, lens, first, k, bk, interpret=True):
+    """(the selection kernel's int8 mask, ``select_mask``'s under the same
+    diagonal and lengths) for the queries from ``first`` on."""
+    b, bq, s = scores.shape
+    scores = jnp.asarray(scores)
+    lens = jnp.asarray(lens, jnp.int32)
+    cols = jnp.arange(s)[None, None, :]
+    rows = first + jnp.arange(bq)[None, :, None]
+    visible = (cols <= rows) & (cols < lens[:, None, None])
+    got = sparse_index._select_mask_flash(
+        scores, lens, jnp.asarray([first], jnp.int32), topk=k, bk=bk,
+        interpret=interpret)
+    assert got.dtype == jnp.int8 and got.shape == scores.shape
+    return (np.asarray(got),
+            np.asarray(sparse_index.select_mask(scores, visible, k), np.int8))
+
+
+def _plateau(s):
+    s[:, :, 5:60] = 0.25                 # equal scores across the threshold
+
+
+def _all_equal(s):
+    s[...] = -1.0
+
+
+def _inf_and_signed_zeros(s):
+    s[0, :, 3] = -np.inf
+    s[0, :, 70:90] = -np.inf
+    s[1, :, ::2] = -0.0                  # ordered BELOW +0.0, as top_k does
+    s[1, :, 1::2] = 0.0
+
+
+def _relu_zeros(s):
+    s[...] = np.where(s > 0.4, s, 0.0)   # the k-th is one of many +0.0
+
+
+# scores' edit, lengths of the two rows, first query, keys
+SELECT_CASES = {
+    "plateau": (_plateau, (128, 100), 64, 128),
+    "all_equal": (_all_equal, (128, 128), 64, 128),
+    "inf_and_signed_zeros": (_inf_and_signed_zeros, (128, 128), 64, 128),
+    "relu_zeros": (_relu_zeros, (128, 90), 64, 128),
+    "fewer_visible_than_k": (None, (7, 3), 64, 128),
+    "none_visible": (None, (0, 0), 0, 128),
+    "length_inside_a_tile": (None, (85, 41), 32, 128),
+    "length_on_a_tiles_edge": (None, (96, 48), 32, 128),
+    "first_chunk": (None, (128, 50), 0, 128),
+    "a_later_chunk": (_plateau, (128, 120), 64, 128),
+    "five_key_tiles": (_relu_zeros, (80, 77), 16, 80),
+    "a_tile_of_lanes": (_plateau, (256, 130), 192, 256),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECT_CASES))
+def test_the_selection_kernel_is_select_mask_bit_for_bit(case):
+    """``_select_mask_kernel`` through the interpreter: two rows, 64 queries
+    (two tiles of 32 a row) against key tiles of 16 (of 128 lanes in the
+    last case), a top-k of 12."""
+    edit, lens, first, keys = SELECT_CASES[case]
+    scores = np.random.default_rng(3).standard_normal(
+        (2, 64, keys)).astype(np.float32)
+    if edit:
+        edit(scores)
+    got, want = _kernel_and_oracle(scores, lens, first, 12,
+                                   128 if keys == 256 else 16)
+    assert (got == want).all(), np.argwhere(got != want)[:5]
+    # the rows' own sums say the case is what its name says
+    assert want.sum(-1).max() == min(12, max(min(first + 64, n)
+                                             for n in lens))
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("keys,first,lens", [
+    (32768, 0, 32768), (32768, 32256, 32700), (32768, 16384, 20000),
+    (33792, 33280, 33792), (33792, 30720, 31000)])
+def test_the_compiled_selection_kernel_is_select_mask(keys, first, lens):
+    """The Mosaic kernel at the served shapes (a block of 512 queries
+    against 32,768 keys; the 33,792 bucket's last chunk), on a TPU only
+    (``python -m pytest --noconftest tests/test_keye.py -m slow -k
+    compiled`` there: conftest.py pins the CPU): scores with ReLU's zeros
+    and a plateau, the top-2,048."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("the compiled kernel needs a TPU")
+    from distributed_inference_engine_tpu.ops import flash_prefill
+
+    scores = np.random.default_rng(keys + first).standard_normal(
+        (1, flash_prefill.Q_BLOCK, keys)).astype(np.float32)
+    scores[:, 100:200] = np.where(scores[:, 100:200] > 1.5,
+                                  scores[:, 100:200], 0.0)
+    scores[:, 300:400, 1000:9000] = 0.25
+    got, want = _kernel_and_oracle(scores, (lens,), first, 2048,
+                                   flash_prefill.K_BLOCK, interpret=False)
+    assert (got == want).all(), int((got != want).sum())
+    assert want.sum() > 0
+
+
+def test_select_blocks_count_the_kernels_engagement(monkeypatch):
+    """``attn.prefill_select_blocks``: a bucket's query blocks x layers
+    where the selection kernel runs (a bucket above the top-k on the kernel
+    body), 0 on the XLA body and at a bucket that selects every row."""
+    from distributed_inference_engine_tpu.ops import flash_prefill
+
+    spec = tiny_spec()
+    assert prefill_sums(spec, 37, 64)["attn.prefill_select_blocks"] == 0
+    monkeypatch.setattr(flash_prefill, "prefill_impl",
+                        lambda t, dh: "flash_interpret")
+    monkeypatch.setattr(flash_prefill, "Q_BLOCK", 16)
+    assert prefill_sums(spec, 37, 64)["attn.prefill_select_blocks"] \
+        == 64 // 16 * spec.n_layers
+    assert prefill_sums(spec, 9, TOPK)["attn.prefill_select_blocks"] == 0
+
+
 def test_family_module_is_whole_and_the_spec_tells_it():
     spec = tiny_spec()
     fam = layered_family(spec)

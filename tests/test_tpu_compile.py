@@ -589,10 +589,14 @@ def test_the_learned_selection_family_compiles_and_fits(one_chip,
     temp = pre.memory_analysis().temp_size_in_bytes
     assert temp < 2.6e9 and held + temp < 15.0e9, (held, temp)
     text = pre.as_text()
-    # the two kernels, each run's shape in its name (what a profile's
-    # reader counts their work by: perfbench/lib/scopes_dsa.py)
+    # the kernels, each run's shape in its name (what a profile's reader
+    # counts the first two's work by: perfbench/lib/scopes_dsa.py)
     assert "sparse_prefill_flash_b1q4096k32768" in text
     assert "index_scores_flash_b1q512k32768" in text
+    # between them the top-2,048 of a block of queries, one kernel: no
+    # counted threshold in XLA (its keys were u32[1,512,32768])
+    assert "select_mask_flash_b1q512k32768" in text
+    assert "u32[1,512,32768]" not in text
     assert "attn.dsa/attn.sparse/" in text or "attn.sparse" in text
     # no float32 score tensor of every head of a block of queries
     assert not re.search(r"f32\[1,4,8,\d+,32768\]", text)
