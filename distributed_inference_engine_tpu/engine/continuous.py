@@ -93,10 +93,21 @@ def resolve_decode_body(impl: str, backend: str, spec,
     ========  ==========================================  =================
     body      when                                        attention
     ========  ==========================================  =================
-    hybrid    ``spec.index_topk`` (a learned selection)   XLA: index scores
-                                                          through the table,
-                                                          top-k, the picked
-                                                          rows gathered
+    hybrid    ``spec.index_topk`` (a learned selection    ``ops/sparse_
+              over K|V rows), the kernel applies          index.py`` in place:
+                                                          index scores over
+                                                          the LIVE index-key
+                                                          pages, the top-k a
+                                                          counted threshold
+                                                          in VMEM, the live
+                                                          K|V pages read
+                                                          under its mask;
+                                                          else XLA: the keys
+                                                          gathered through
+                                                          the table,
+                                                          ``lax.top_k``, the
+                                                          picked rows
+                                                          gathered
     hybrid    ``spec.layer_kinds`` (latent rows, or      ``ops/flash_
               K|V rows where ``spec.kv_row_lanes``        decode.py`` in
               > 0), the kernel applies                    place from the
@@ -136,14 +147,6 @@ def resolve_decode_body(impl: str, backend: str, spec,
              else spec.n_kv_heads * spec.head_dim)
     if spec.sliding_window and not spec.layer_kinds:
         return "inline", "xla"
-    if spec.index_topk:
-        # a learned selection (models/keye.py): ONE decode body, the XLA
-        # gather; no kernel reads rows scattered over the pages yet
-        if impl not in ("auto", "xla"):
-            raise ValueError(
-                f"attention_impl {impl!r}: a spec that selects its rows by "
-                "an indexer (index_topk) has one decode body, 'xla'")
-        return "hybrid", "xla"
     if impl == "auto":
         kernel = backend == "tpu" and not sharded and lanes % 128 == 0
         impl = "pallas-decode" if kernel else "xla"

@@ -32,8 +32,16 @@ layer adds a K|V row (``2 * Hkv * Dh`` lanes) to the family's pool
 ``state["index_pages"]`` ``[layers, pages, page, Di]`` a token; a page id
 names the same page in both. A decode step scores a row's cached index keys
 through its table and the chunk's own in the side window, takes the top-k
-over both in position order, and gathers the K|V rows picked from where
-they lie. The chunk's own rows of every layer gather in ONE side window
+over both in position order, and attends to the K|V rows picked. On a TPU
+(``ops/sparse_index.py``'s three decode kernels, ``attn_layer_step``) each
+piece reads pages where they lie: the scores over the row's LIVE index-key
+pages, the top-k as a counted threshold over the rows' scores in VMEM (a
+mask, no index), the attention as ``ops/flash_decode.py``'s loop over the
+live K|V pages under that mask; nothing as wide as the table reaches HBM
+but a row of scores and a row of mask a sequence. ``"xla"`` (the CPU, the
+tests' plain form) gathers the index keys through the whole table, takes
+``lax.top_k`` and gathers the picked rows. The chunk's own rows of every
+layer gather in ONE side window
 ``[layers, B, Wc, 2 * lanes + Di]`` (K | V | index key) written back once a
 chunk (``write_side``). A freed page's stale index keys lie past its next
 holder's length: every score past a row's length is masked.
@@ -69,11 +77,14 @@ __all__ = ["keye_spec", "init_params", "init_state", "zero_state_slot",
 Params = Dict[str, Any]
 State = Dict[str, jnp.ndarray]
 
-# a decode step's counters: index keys the indexer read (a layer, padding
-# included), the routed experts' three, K|V rows the attention gathered (a
-# layer: min(context, index_topk) a live row)
+# a decode step's counters, a layer: index keys the indexer READ (the live
+# pages' rows and the side window on the kernel body; every slot's whole
+# table, padding included, on "xla"), the routed experts' three, K|V rows
+# the attention selected (min(context, index_topk) a live row) and K|V rows
+# it READ for them (the live pages' rows and the side window under the
+# kernel's mask; the picked rows gathered and the side window on "xla")
 DECODE_COUNTERS = (("attn.index_table_rows",) + MOE_COUNTERS
-                   + ("attn.rows_selected",))
+                   + ("attn.rows_selected", "attn.kv_rows_read"))
 
 # published values (config.json of Kwai-Keye/Keye-VL-2.0-30B-A3B, the
 # language model's keys; ``sa_config`` is the indexer's group)
@@ -271,19 +282,30 @@ def attn_layer_prefill(spec: ModelSpec, blk: Params, x, positions,
         return _attn_out(blk, o, x.dtype), rows, k_idx.astype(rows.dtype)
 
 
-def attn_layer_step(spec: ModelSpec, blk: Params, x, positions, pool,
-                    index_pool, page_table, layer, n_ctx, side, side_idx,
-                    active):
-    """x [B, D] at ``positions`` [B]; ``pool`` [L, N, P, 2 * lanes] the K|V
-    pages, ``index_pool`` [L, N, P, Di] the index keys' and ``page_table``
-    [B, MP] the rows' pages in both, rows valid below ``n_ctx``; side [B,
-    Wc, 2 * lanes + Di] the chunk's own rows, this token's written at
-    ``side_idx`` where ``active``. Returns (attention out, side, index keys
-    read: the table's and the side window's, K|V rows selected)."""
+def attn_layer_step(spec: ModelSpec, blk: Params, x, positions, ctx,
+                    index_pool, layer, n_ctx, side, side_idx, active):
+    """x [B, D] at ``positions`` [B]; ``ctx`` = (the pool [L, N, P, 2 *
+    lanes] of K|V pages, ``page_table`` [B, MP] the rows' pages in it and in
+    ``index_pool`` [L, N, P, Di], the attention's name), rows valid below
+    ``n_ctx``; side [B, Wc, 2 * lanes + Di] the chunk's own rows, this
+    token's written at ``side_idx`` where ``active``. Returns (attention
+    out, side, index keys read, K|V rows selected, K|V rows read).
+
+    The selection's three pieces run under ``attn.index``, ``attn.select``
+    and ``attn.sparse``. On the kernel body (``ops/sparse_index.py``) each
+    reads pages where they lie: index scores over the row's LIVE index-key
+    pages, the top-k as a counted threshold over the rows' scores in VMEM
+    (a mask, no index), the attention as ``flash_decode``'s loop over the
+    live K|V pages under the mask; what it read of either pool is the live
+    pages' rows and the side window. ``"xla"``: the index keys gathered
+    through the whole table, ``lax.top_k``, the picked rows gathered
+    (``attn.gather``)."""
+    pool, page_table, impl = ctx
     n_layers, n_pages, page, width = pool.shape
     b, mp = page_table.shape
     s_tab, wc = mp * page, side.shape[1]
     di = spec.index_head_dim
+    index_flat = index_pool.reshape(n_layers * n_pages, page, di)
     with jax.named_scope("attn.dsa"):
         h = rms_norm(x, blk["attn_norm"], spec.norm_eps)
         q, row = _attn_inputs(spec, blk, h[:, None], positions[:, None])
@@ -299,11 +321,35 @@ def attn_layer_step(spec: ModelSpec, blk: Params, x, positions, pool,
         # a row that is not live gets length 0: nothing of it is selected
         n_prefix = jnp.where(active, n_ctx, 0)
         n_side = jnp.where(active, side_idx + 1, 0)
+        if impl != "xla":
+            interpret = impl.endswith("_interpret")
+            kernel = dict(interpret=interpret, n_pages_per_layer=n_pages)
+            with jax.named_scope("attn.index"):
+                scores = sparse_index.index_scores_decode(
+                    q_idx[:, 0], w[:, 0], index_flat.swapaxes(1, 2),
+                    page_table, n_prefix, side[..., width:], n_side, layer,
+                    **kernel)
+            with jax.named_scope("attn.select"):
+                keep = sparse_index.select_mask_decode(
+                    scores, n_prefix, n_side, topk=spec.index_topk, mp=mp,
+                    page_size=page, interpret=interpret)
+                n_selected = keep.sum(dtype=jnp.int32)
+            with jax.named_scope("attn.sparse"):
+                side_k, side_v = _kv_heads(spec, side[..., :width])
+                o = sparse_index.sparse_decode_attention(
+                    q[:, 0], pool.reshape(n_layers * n_pages, page, width),
+                    page_table, n_prefix, side_k, side_v, n_side, keep,
+                    layer, n_kv_heads=spec.n_kv_heads, **kernel)
+            # either pool's rows: the live pages whole, the side window
+            rows_read = (-(-n_prefix // page)).sum(dtype=jnp.int32) * page \
+                + b * wc
+            return (_attn_out(blk, o, x.dtype), side, rows_read, n_selected,
+                    rows_read)
         with jax.named_scope("attn.index"):
             # ONE layer's index keys of the rows in this batch, through the
             # table (the layer folded into the page id: no slice of the pool)
-            cached = index_pool.reshape(n_layers * n_pages, page, di)[
-                layer * n_pages + page_table].reshape(b, s_tab, di)
+            cached = index_flat[layer * n_pages + page_table].reshape(
+                b, s_tab, di)
             scores = jnp.concatenate([
                 jnp.where(jnp.arange(s_tab)[None, :] < n_prefix[:, None],
                           sparse_index.index_scores(q_idx, cached, w)[:, 0],
@@ -338,7 +384,8 @@ def attn_layer_step(spec: ModelSpec, blk: Params, x, positions, pool,
         out = _attn_out(blk, o.reshape(b, spec.n_heads, spec.head_dim),
                         x.dtype)
         return (out, side, jnp.int32(b * (s_tab + wc)),
-                valid.sum(dtype=jnp.int32))
+                valid.sum(dtype=jnp.int32),
+                jnp.int32(b * (picked.shape[1] + wc)))
 
 
 # --------------------------------------------------------------- programs
@@ -396,38 +443,35 @@ def forward_decode_step(
     moe_impl: str = "",
 ) -> Tuple[jnp.ndarray, jnp.ndarray, State, jnp.ndarray]:
     """One token for every slot. Returns (hidden [B, D], side, state as it
-    came, the family's five counters: index keys read a layer, MoE's three,
-    K|V rows selected a layer); rows not ``active`` leave side alone.
-    ``ctx``'s attention string is not read: the selection has ONE decode
-    body, the XLA gather."""
-    pages, page_table, _impl = ctx
+    came, the family's ``DECODE_COUNTERS``); rows not ``active`` leave side
+    alone. ``ctx``'s attention string picks the selection's body
+    (``attn_layer_step``): the kernels over the pages where they lie, or
+    ``"xla"``'s gathers."""
     index_pool = state["index_pages"]
     x = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
     side_idx = lengths - start_lengths
     (light,), (heavy,) = _scanned(params)
 
     def layer(carry, xs):
-        x, side, counters, read, selected = carry
+        x, side, counters, rows = carry
         blk, p = xs
         with jax.named_scope("attn.kv_gather"):
             side_l = lax.dynamic_index_in_dim(side, p, 0, keepdims=False)
-        att, side_l, r, n_sel = attn_layer_step(
-            spec, blk, x, lengths, pages, index_pool, page_table, p,
-            start_lengths, side_l, side_idx, active)
+        att, side_l, *r = attn_layer_step(
+            spec, blk, x, lengths, ctx, index_pool, p, start_lengths, side_l,
+            side_idx, active)
         with jax.named_scope("attn.kv_update"):
             side = lax.dynamic_update_index_in_dim(side, side_l, p, 0)
         x = x + att
         y, c = _moe(spec, blk, heavy, p, x, active, moe_impl)
-        return (x + y, side, counters + c, read + r, selected + n_sel), None
+        return (x + y, side, counters + c, rows + jnp.stack(r)), None
 
-    (x, side, moe, read, selected), _ = lax.scan(
-        layer, (x, side, jnp.zeros((3,), jnp.int32), jnp.int32(0),
-                jnp.int32(0)),
+    (x, side, moe, rows), _ = lax.scan(
+        layer, (x, side, jnp.zeros((3,), jnp.int32),
+                jnp.zeros((3,), jnp.int32)),
         (light, jnp.arange(spec.n_layers)))
-    n = spec.n_layers
-    counters = jnp.concatenate([(read // n)[None], moe,
-                                (selected // n)[None]])
-    return x, side, state, counters
+    rows = rows // spec.n_layers       # index keys read, selected, K|V read
+    return x, side, state, jnp.concatenate([rows[:1], moe, rows[1:]])
 
 
 def write_side(pages, state: State, side, page_table, counts, start):

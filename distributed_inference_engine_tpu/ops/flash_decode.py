@@ -173,12 +173,13 @@ def _init_acc(m_scr, l_scr, acc_scr):
 
 
 def _attend(qbd, k, v, first_tok, n_valid, m_scr, l_scr, acc_scr, scale,
-            first_valid=None):
+            first_valid=None, keep=None):
     """One online-softmax update over a key block.
 
     qbd [Hp, F], k/v [S, F] in the pool/side dtype; key j of the block is
     valid iff ``first_tok + j < n_valid`` (and, with ``first_valid``, not
-    below it: a sliding window's lower edge). Invalid probs are explicitly
+    below it: a sliding window's lower edge; and, with ``keep`` bool [1, S],
+    kept by it: a learned selection's mask). Invalid probs are explicitly
     zeroed (not just NEG_INF-masked): a block may be ENTIRELY masked
     (empty side window), and with m still at NEG_INF
     exp(NEG_INF - NEG_INF) = 1 would sum stale buffer contents into the
@@ -201,6 +202,8 @@ def _attend(qbd, k, v, first_tok, n_valid, m_scr, l_scr, acc_scr, scale,
     valid = tok < n_valid
     if first_valid is not None:
         valid &= tok >= first_valid
+    if keep is not None:
+        valid &= keep
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_scr[...]                                       # [Hp, 1]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -215,6 +218,15 @@ def _attend(qbd, k, v, first_tok, n_valid, m_scr, l_scr, acc_scr, scale,
                      precision=_precision(cdt))               # [Hp, F]
     acc_scr[...] = acc_scr[...] * alpha + pv
     m_scr[...] = m_new
+
+
+def _mask_row(keep_ref, b, at):
+    """Row ``b`` of a mask [B, S] (int32, the rows on sublanes) at lanes
+    ``at`` -> bool [1, n]: the rows' tile under a sublane select (Mosaic
+    loads no single sublane at a dynamic index)."""
+    tile = keep_ref[:, at]
+    mine = lax.broadcasted_iota(jnp.int32, tile.shape, 0) == b
+    return jnp.where(mine, tile, 0).max(axis=0, keepdims=True) != 0
 
 
 def _finish(out_ref, l_scr, acc_scr, *, g, dh, n_kv_heads):
@@ -234,7 +246,8 @@ def _prefix_loop(
     buffer_index_ref, step_ref, qbd, k_pages_hbm, v_pages_hbm, k_vmem,
     v_vmem, sem, m_scr, l_scr, acc_scr,
     *, bp, page_size, n_pages_per_layer, scale, kv_lanes=0, v_lanes=0,
-    attend_pages=1, copied_ref=None, first_rows_ref=None,
+    attend_pages=1, copied_ref=None, first_rows_ref=None, keep_ref=None,
+    on_group=None,
 ):
     """Flash loop over row ``b``'s live prefix pages: ``bp`` pages per
     block, double-buffered manual DMA, next block (possibly the first
@@ -274,7 +287,18 @@ def _prefix_loop(
     an update leaves the MXU waiting on the update's chain of scores, max,
     exp and values: 0.53 us a page of 0.2 us of DMA, PR 38). A group runs
     if its first page is live; its dead pages hold what an earlier block
-    left there (the caller zeroes the buffers once) under the mask."""
+    left there (the caller zeroes the buffers once) under the mask.
+    ``on_group(rows, first position)`` takes a group in place of the softmax
+    update (the learned selection's index scores, ``ops/sparse_index.py``;
+    ``qbd`` and the accumulators are then None): its pages are TRANSPOSED
+    ([W, P], the positions on the lanes) and lie side by side along the
+    lanes of ``k_vmem`` [2, W, bp * P].
+
+    ``keep_ref`` (the K/V kernel; VMEM int32 [B, >= the table's positions]):
+    a learned selection's mask, 1 where row ``b``'s cached position is
+    kept. The K/V kernel then too takes ``attend_pages`` pages to one
+    softmax update, each key under its entry of the mask (no lower bound
+    then; dead pages of a live group as the latent rows')."""
     batch = pl.num_programs(0)
     blk_tokens = bp * page_size
     base = layer_ref[0] * n_pages_per_layer
@@ -288,9 +312,11 @@ def _prefix_loop(
             col = first_page(row) + col
         page = base + page_table_ref[row, col]
         if v_lanes:
+            at = pl.ds(j * page_size, page_size)
             return (pltpu.make_async_copy(
                 k_pages_hbm.at[page],
-                k_vmem.at[slot, pl.ds(j * page_size, page_size)],
+                k_vmem.at[slot, at] if on_group is None
+                else k_vmem.at[slot, :, at],
                 sem.at[slot]),)
         if kv_lanes:
             # ONE pool of K|V rows (both refs are it): a page's K is its
@@ -353,22 +379,42 @@ def _prefix_loop(
             _attend(qbd, k, v, tok, length, m_scr, l_scr, acc_scr, scale,
                     first_valid)
 
-        def group(n_live, first):
+        def wait_group(n_live, first):
             def wait(j):
                 for c in copies(b, i, slot, j):
                     c.wait()
             for j in range(first, first + attend_pages):
                 pl.when(i * bp + j < n_live)(functools.partial(wait, j))
-            k = k_vmem[slot, pl.ds(first * page_size,
-                                   attend_pages * page_size)]
-            _attend(qbd, k, k[:, :v_lanes], (i * bp + first) * page_size,
-                    length, m_scr, l_scr, acc_scr, scale)
 
-        if v_lanes:
+        def group(n_live, first):
+            wait_group(n_live, first)
+            at = pl.ds(first * page_size, attend_pages * page_size)
+            tok = (i * bp + first) * page_size
+            if on_group is not None:
+                on_group(k_vmem[slot, :, at], tok)
+                return
+            k = k_vmem[slot, at]
+            _attend(qbd, k, k[:, :v_lanes], tok, length, m_scr, l_scr,
+                    acc_scr, scale)
+
+        def kv_group(n_live, first):
+            wait_group(n_live, first)
+            rows = attend_pages * page_size
+            k, v = (ref[slot, pl.ds(first, attend_pages)].reshape(
+                rows, ref.shape[-1]) for ref in (k_vmem, v_vmem))
+            tok = (i * bp + first) * page_size
+            keep = None
+            if keep_ref is not None:
+                keep = _mask_row(keep_ref, b, pl.ds(
+                    pl.multiple_of(tok, page_size), rows))
+            _attend(qbd, k, v, tok, length, m_scr, l_scr, acc_scr, scale,
+                    keep=keep)
+
+        if v_lanes or keep_ref is not None:
             n_live = lax.div(length + page_size - 1, page_size)
             for first in range(0, bp, attend_pages):
-                pl.when(i * bp + first < n_live)(
-                    functools.partial(group, n_live, first))
+                pl.when(i * bp + first < n_live)(functools.partial(
+                    group if v_lanes else kv_group, n_live, first))
         else:
             for_live_pages(b, i, page)
         buffer_index_ref[0] = 1 - slot

@@ -8,7 +8,8 @@ and on XLA (``hybrid``; a parent dumped before has no such files: ``compare``
 walks its first directory's); since PR 40 ``xing-tiny`` and ``mellum-tiny``
 (window 32) the same way; since PR 41 ``kimi-tiny`` (12 slots: the plain
 residual and the held-rows expert layer); since PR 45 ``keye-tiny`` (a top-k
-of 16, its one XLA body) where the package has it. To dump a parent that lacks an
+of 16) on XLA and, since PR 47, on the selection's three decode kernels, where
+the package has them. To dump a parent that lacks an
 entry, run THIS file over its package (``PYTHONPATH=<parent> python <this
 file> dump <dir>``).
 
@@ -145,11 +146,17 @@ def dump(out: str) -> None:
         from distributed_inference_engine_tpu.models.keye import keye_spec
     except ImportError:                         # a parent before PR 45
         return
-    cfg = EngineConfig(max_slots=4, max_seq_len=256, page_size=8,
-                       num_pages=128, prefill_buckets=[32, 64],
-                       decode_steps_per_call=8)
-    _dump_engine(out, "keye_tiny_xla", ContinuousEngine(
-        keye_spec("keye-tiny", max_seq_len=256), config=cfg))
+    for name, impl in (("keye_tiny_xla", "xla"),
+                       ("keye_tiny_kernel", "pallas-decode_interpret")):
+        cfg = EngineConfig(max_slots=4, max_seq_len=256, page_size=8,
+                           num_pages=128, prefill_buckets=[32, 64],
+                           decode_steps_per_call=8, attention_impl=impl)
+        try:
+            engine = ContinuousEngine(
+                keye_spec("keye-tiny", max_seq_len=256), config=cfg)
+        except ValueError:      # a parent before PR 47: one body, XLA
+            continue
+        _dump_engine(out, name, engine)
 
 
 def compare(a: str, b: str, verbose: bool = False) -> bool:

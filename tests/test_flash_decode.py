@@ -579,3 +579,71 @@ def test_decode_programs_do_not_grow_with_context_bucket(impl):
             eng.generate([req])
     assert ref._decode_chunk._cache_size() >= 3
     assert fd._decode_chunk._cache_size() == 1
+
+
+# ------------------------------- the other families' programs, as they were
+
+
+def _pallas_eqn(fn, *args):
+    """The one ``pallas_call`` equation of ``fn``'s jaxpr, found through any
+    ``jit`` that wraps it."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in eqn.params.values():
+                if hasattr(sub, "jaxpr"):
+                    yield from find(getattr(sub.jaxpr, "jaxpr", sub.jaxpr))
+    (eqn,) = find(jax.make_jaxpr(fn)(*args).jaxpr)
+    return eqn
+
+
+@pytest.mark.parametrize("caller,operands,kernel_refs", [
+    # Mistral (models/base.py forward_decode_window): K and V pools apart
+    ("window", 12, 19),
+    # Olmo-Hybrid: ONE pool of K|V rows, the kernel's own page count
+    ("kv_fused", 12, 20),
+    # Mellum's sliding layers: a lower bound besides
+    ("kv_fused_lower_bound", 13, 21),
+    # Ling / Xing / Kimi: latent rows
+    ("latent", 10, 17),
+])
+def test_the_other_families_kernels_take_no_mask(caller, operands,
+                                                 kernel_refs):
+    """``_attend`` and ``_prefix_loop`` took a selection's mask for the
+    learned-sparse family (``ops/sparse_index.py``), as arguments absent at
+    trace time for every other caller: the ``window``, ``kv_fused`` and
+    latent launchers called as Mistral, Olmo / Mellum and Ling / Xing / Kimi
+    call them trace the operands and the kernel refs they had before it."""
+    from distributed_inference_engine_tpu.ops.flash_decode import (
+        latent_decode_attention_pallas)
+
+    b, h, hkv, dh, n, p, mp, w = 2, 4, 2, 64, 8, 8, 3, 4
+    q = jnp.zeros((b, h, dh))
+    table = jnp.zeros((b, mp), jnp.int32)
+    lens = jnp.zeros((b,), jnp.int32)
+    side = jnp.zeros((b, w, hkv, dh))
+    if caller == "window":
+        pool = jnp.zeros((n, p, hkv * dh))
+        eqn = _pallas_eqn(
+            lambda *a: flash_decode_attention_pallas(*a, n_kv_heads=hkv),
+            q, pool, pool, table, lens, side, side, lens)
+    elif caller.startswith("kv_fused"):
+        pool = jnp.zeros((n, p, 2 * hkv * dh))
+        bound = {"first_rows": lens} if caller.endswith("bound") else {}
+        eqn = _pallas_eqn(
+            lambda q, pool, *a: flash_decode_attention_pallas(
+                q, pool, pool, *a, n_kv_heads=hkv, layer=0,
+                n_pages_per_layer=n, kv_fused=True, count_pages=True,
+                **bound),
+            q, pool, table, lens, side, side, lens)
+    else:
+        pool = jnp.zeros((n, p, 128))
+        eqn = _pallas_eqn(
+            lambda *a: latent_decode_attention_pallas(
+                *a, v_lanes=64, scale=0.1, n_pages_per_layer=n),
+            jnp.zeros((b, h, 128)), pool, table, lens,
+            jnp.zeros((b, w, 128)), lens, jnp.int32(0))
+    kernel = eqn.params["jaxpr"]
+    assert len(eqn.invars) == operands
+    assert len(kernel.invars) == kernel_refs

@@ -73,14 +73,15 @@ class Served:
     at a padded bucket, then teacher-forced decode chunks through both
     pools, collecting every position's logits."""
 
-    def __init__(self, spec, params, slots=4, pages=128, cache_dtype=None):
-        self.spec, self.params = spec, params
+    def __init__(self, spec, params, slots=4, pages=128, cache_dtype=None,
+                 impl="xla"):
+        self.spec, self.params, self.impl = spec, params, impl
         self.kv = PagedKVCache(spec, max_slots=slots, page_size=PAGE,
                                num_pages=pages, max_seq_len=256)
         if cache_dtype:
             self.kv.state = {k: v.astype(cache_dtype)
                              for k, v in self.kv.state.items()}
-        self.counters = np.zeros(5, np.int64)
+        self.counters = np.zeros(len(keye.DECODE_COUNTERS), np.int64)
         self.sums = {}
 
     def prefill(self, prompts, bucket):
@@ -112,7 +113,7 @@ class Served:
         step = jax.jit(lambda kp, table, tok, cur, start, *a:
                        keye.forward_decode_step(
             self.spec, self.params, tok, cur, start,
-            keye.decode_context(kp, table, "xla"), *a))
+            keye.decode_context(kp, table, self.impl), *a))
         pos = dict(lengths)
         fed = {s: 0 for s in feeds}
         while any(fed[s] < len(feeds[s]) for s in feeds):
@@ -255,6 +256,57 @@ def test_counters_hold_the_selection(float32_run):
         == 37 * 38 // 2
 
 
+@pytest.fixture(scope="module")
+def float32_kernel_run(served_f32):
+    """``float32_run`` on the kernel body: the three rows, the pad row and
+    the dead slot through the TPU's three kernels (interpreted)."""
+    seqs = sequences()
+    with jax.default_matmul_precision("highest"):
+        got, sv = served_logits(tiny_spec(dtype="float32"), served_f32, seqs,
+                                PROMPTS, impl=KERNELS)
+    return seqs, got, sv
+
+
+def test_the_kernel_body_is_the_xla_body_and_the_reference(
+        served_f32, float32_run, float32_kernel_run):
+    """The masked read of the live pages sums its softmax in another order
+    than the gathered rows': inside the float32 bound of this file, against
+    the XLA body and against the reference."""
+    seqs, got, _ = float32_kernel_run
+    for a, b in zip(got, float32_run[1]):
+        assert float(np.abs(a - b).max()) < F32_TOL
+    with jax.default_matmul_precision("highest"):
+        worst, scale = max_diff(got, CFG, served_f32, seqs)
+    assert worst < F32_TOL and scale > 0.3, (worst, scale)
+
+
+def test_the_kernel_bodys_counters_hold_what_it_read(float32_run,
+                                                     float32_kernel_run):
+    """On the kernel body the indexer and the attention read a row's LIVE
+    pages whole and the side window, no table-wide array: what the program
+    counted is the host's reckoning from the lengths; the selection is the
+    XLA body's to the row."""
+    seqs, _got, sv = float32_kernel_run
+    names = dict(zip(keye.DECODE_COUNTERS, sv.counters))
+    xla = dict(zip(keye.DECODE_COUNTERS, float32_run[2].counters))
+    assert names["attn.rows_selected"] == xla["attn.rows_selected"]
+    # chunks of 4 steps: a live row's pages as the chunk began, a step
+    read = 0
+    for s, n in zip(seqs, PROMPTS):
+        for start in range(n, len(s), 4):
+            steps = min(4, len(s) - start)
+            read += steps * -(-start // PAGE) * PAGE
+    steps = (150 - 37 + 3) // 4 * 4
+    read += steps * 4 * 4                # the side window, every slot's
+    assert names["attn.kv_rows_read"] == read
+    assert names["attn.index_table_rows"] == read
+    assert names["attn.rows_selected"] <= names["attn.kv_rows_read"]
+    # the XLA body read every slot's whole table, and gathered the top-k
+    assert xla["attn.index_table_rows"] == steps * 4 * (256 + 4)
+    assert xla["attn.kv_rows_read"] == steps * 4 * (TOPK + 4)
+    assert sv.sums == float32_run[2].sums
+
+
 def test_served_bfloat16_is_close_and_bfloat16_index_keys_are_not_float32(
         served_bf16, served_f32):
     seqs = sequences(3, lens=(90, 50))
@@ -270,6 +322,21 @@ def test_served_bfloat16_is_close_and_bfloat16_index_keys_are_not_float32(
                                (40, 12), cache_dtype="bfloat16")
         worst, _ = max_diff(got, CFG, served_f32, seqs)
         assert worst > 4 * F32_TOL, worst
+
+
+@pytest.mark.parametrize("n_prompt", [1, TOPK - 1, TOPK, TOPK + 1,
+                                      6 * TOPK + 3])
+def test_prompt_lengths_around_the_topk_through_the_kernels(served_f32,
+                                                            n_prompt):
+    """``test_prompt_lengths_around_the_topk`` on the kernel body: a
+    16-step chunk that begins below the top-k and ends above it."""
+    seq = sequences(5, lens=(n_prompt + 20,))
+    with jax.default_matmul_precision("highest"):
+        got, _ = served_logits(tiny_spec(dtype="float32"), served_f32, seq,
+                               (n_prompt,), bucket=128, n_steps=16,
+                               impl=KERNELS)
+        worst, _ = max_diff(got, CFG, served_f32, seq)
+    assert worst < F32_TOL, worst
 
 
 @pytest.mark.parametrize("n_prompt", [1, TOPK - 1, TOPK, TOPK + 1,
@@ -295,7 +362,16 @@ def test_prompt_lengths_around_the_topk(served_f32, n_prompt):
     assert float(np.abs(got[0][TOPK:] - dense[TOPK:]).max()) > 20 * F32_TOL
 
 
-def test_a_selection_takes_side_rows_and_cached_rows_together(served_f32):
+# the decode bodies: "xla" (index keys gathered through the table,
+# ``lax.top_k``, the picked rows gathered) and the TPU's three kernels
+# through the interpreter
+KERNELS = "pallas-decode_interpret"
+BODIES = pytest.mark.parametrize("impl", ["xla", KERNELS])
+
+
+@BODIES
+def test_a_selection_takes_side_rows_and_cached_rows_together(served_f32,
+                                                              impl):
     """A 16-step chunk far above the top-k: by its last steps the chunk's
     own rows (the side window) are most of what a top-16 may pick, and
     cached rows the rest; the logits are the reference's, and would not be
@@ -303,12 +379,15 @@ def test_a_selection_takes_side_rows_and_cached_rows_together(served_f32):
     seq = sequences(8, lens=(60,))
     spec = tiny_spec(dtype="float32")
     with jax.default_matmul_precision("highest"):
-        got, _ = served_logits(spec, served_f32, seq, (44,), n_steps=16)
+        got, _ = served_logits(spec, served_f32, seq, (44,), n_steps=16,
+                               impl=impl)
         worst, _ = max_diff(got, CFG, served_f32, seq)
     assert worst < F32_TOL, worst
 
 
-def test_eight_rows_of_unlike_lengths_equal_each_served_alone(served_f32):
+@BODIES
+def test_eight_rows_of_unlike_lengths_equal_each_served_alone(served_f32,
+                                                              impl):
     lens = (150, 33, 90, 17, 61, 120, 48, 75)
     prompts = (100, 9, 40, 3, 30, 64, 16, 50)
     seqs = sequences(11, lens=lens)
@@ -316,23 +395,25 @@ def test_eight_rows_of_unlike_lengths_equal_each_served_alone(served_f32):
     with jax.default_matmul_precision("highest"):
         together, _ = served_logits(spec, served_f32, seqs, prompts,
                                     bucket=128, slots=8, pages=256,
-                                    n_steps=8)
+                                    n_steps=8, impl=impl)
         worst, _ = max_diff(together, CFG, served_f32, seqs)
         assert worst < F32_TOL, worst
         for i in (0, 3, 6):
             alone, _ = served_logits(spec, served_f32, [seqs[i]],
-                                     (prompts[i],), bucket=128, n_steps=8)
+                                     (prompts[i],), bucket=128, n_steps=8,
+                                     impl=impl)
             assert float(np.abs(alone[0] - together[i]).max()) < F32_TOL
 
 
-def test_a_freed_slots_stale_index_keys_are_not_selectable(served_f32):
+@BODIES
+def test_a_freed_slots_stale_index_keys_are_not_selectable(served_f32, impl):
     """Row A fills pages with index keys and is freed; row B, shorter, takes
     the same pages: A's stale keys lie past B's length in B's pages and in
     the table's padding, and no logit of B moves."""
     a, b = sequences(6, lens=(120, 70))
     spec = tiny_spec(dtype="float32")
     with jax.default_matmul_precision("highest"):
-        sv = Served(spec, served_f32, slots=1, pages=16)
+        sv = Served(spec, served_f32, slots=1, pages=16, impl=impl)
         (sa,), _pre = sv.prefill([a[:100]], 128)
         sv.decode({sa: a[100:]}, {sa: 100})
         held = set(sv.kv._slot_pages[sa])
@@ -454,6 +535,276 @@ def test_the_selection_kernel_is_select_mask_bit_for_bit(case):
     # the rows' own sums say the case is what its name says
     assert want.sum(-1).max() == min(12, max(min(first + 64, n)
                                              for n in lens))
+
+
+# ----------------------------------------- the decode step's three kernels
+
+
+def _decode_layout(mp):
+    return sparse_index.decode_layout(mp, PAGE)
+
+
+def _decode_scores(rng, b, mp):
+    """Scores as ``index_scores_decode`` lays them: [B, s_pad]."""
+    _g, s_side, s_pad, _tile = _decode_layout(mp)
+    return rng.standard_normal((b, s_pad)).astype(np.float32), s_side
+
+
+def _decode_oracle(scores, lens, n_side, s_side, k):
+    cols = np.arange(scores.shape[1])[None, :]
+    visible = np.where(cols < s_side, cols < np.asarray(lens)[:, None],
+                       cols - s_side < np.asarray(n_side)[:, None])
+    return np.asarray(sparse_index.select_mask(
+        jnp.asarray(scores), jnp.asarray(visible), k), np.int32)
+
+
+def _d_plateau(s, s_side):
+    s[:, 5:60] = 0.25
+
+
+def _d_all_equal(s, s_side):
+    s[...] = -1.0
+
+
+def _d_side_wins(s, s_side):
+    s[:, s_side:s_side + 4] = 9.0        # the chunk's own rows score highest
+
+
+def _d_side_ties(s, s_side):
+    s[...] = 0.5                         # equal: the cached rows go first
+
+
+def _d_stale(s, s_side):
+    # a freed slot's keys past a row's length, and the table's padding,
+    # score above everything (and NaN): never visible
+    s[0, 40:] = np.inf
+    s[1, 90:s_side] = np.nan
+    s[:, s_side + 2:] = np.inf
+
+
+def _d_relu_zeros(s, s_side):
+    s[...] = np.where(s > 0.4, s, 0.0)
+    s[2, ::2] = -0.0
+
+
+# scores' edit, cached rows valid a row, side rows valid a row
+DECODE_SELECT_CASES = {
+    "below_the_topk": (None, (5, 0, 9, 3), (1, 0, 2, 4)),
+    "at_the_topk": (None, (8, 12, 11, 10), (4, 0, 1, 2)),
+    "far_above": (None, (256, 200, 131, 77), (4, 1, 2, 3)),
+    "plateau": (_d_plateau, (256, 100, 64, 30), (1, 1, 1, 1)),
+    "all_equal": (_d_all_equal, (256, 255, 17, 12), (4, 4, 4, 4)),
+    "a_dead_row": (None, (200, 0, 90, 0), (3, 0, 1, 0)),
+    "side_rows_win": (_d_side_wins, (256, 100, 40, 20), (4, 4, 4, 4)),
+    "side_rows_tie": (_d_side_ties, (256, 100, 11, 20), (4, 4, 4, 4)),
+    "stale_keys": (_d_stale, (40, 90, 128, 33), (2, 2, 1, 2)),
+    "relu_zeros": (_d_relu_zeros, (250, 129, 128, 127), (4, 3, 2, 1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_SELECT_CASES))
+def test_the_decode_threshold_kernel_is_select_mask_bit_for_bit(case):
+    """``_select_decode_kernel`` through the interpreter: one query a row,
+    four rows on the sublanes, a table of 32 pages of 8 and a side window of
+    4, a top-k of 12; the mask is ``select_mask``'s to the bit, zeros past
+    every length and in the padding."""
+    edit, lens, n_side = DECODE_SELECT_CASES[case]
+    scores, s_side = _decode_scores(np.random.default_rng(4), 4, 32)
+    if edit:
+        edit(scores, s_side)
+    got = np.asarray(sparse_index.select_mask_decode(
+        jnp.asarray(scores), jnp.asarray(lens, jnp.int32),
+        jnp.asarray(n_side, jnp.int32), topk=12, mp=32, page_size=PAGE,
+        interpret=True))
+    want = _decode_oracle(scores, lens, n_side, s_side, 12)
+    assert got.dtype == np.int32 and got.shape == scores.shape
+    assert (got == want).all(), np.argwhere(got != want)[:5]
+    assert [int(x) for x in want.sum(-1)] == [
+        min(12, a + c) for a, c in zip(lens, n_side)]
+    if case == "side_rows_win":
+        assert want[:, s_side:s_side + 4].all()
+    if case == "side_rows_tie":
+        # equal scores go to the lower position: the cached rows first
+        assert not want[[0, 1, 3], s_side:].any()
+        assert want[2, :11].all() and want[2, s_side] and not want[
+            2, s_side + 1:].any()
+
+
+def _index_case(dtype, layers=3, n=40, b=4, mp=32, wc=4, hi=2, di=32):
+    rng = np.random.default_rng(8)
+    pool = jnp.asarray(rng.standard_normal((layers * n, PAGE, di)), dtype)
+    table = jnp.asarray(rng.permutation(n)[:b * mp].reshape(b, -1)
+                        if n >= b * mp else rng.integers(0, n, (b, mp)),
+                        jnp.int32)
+    q = jnp.asarray(rng.standard_normal((b, hi, di)), dtype)
+    w = jnp.asarray(rng.standard_normal((b, hi)), jnp.float32)
+    side = jnp.asarray(rng.standard_normal((b, wc, di)), dtype)
+    return pool, table, q, w, side
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 0.0)])
+def test_the_decode_index_kernel_is_index_scores_through_the_table(dtype,
+                                                                   tol):
+    """``index_scores_decode`` (the kernel through the interpreter, the
+    middle layer of a stacked pool, a page's keys handed in transposed)
+    against ``index_scores`` over that layer's pages gathered through the
+    table: equal on every LIVE position of the table and of the side window
+    (in bfloat16 the products are exact and two heads sum in one order: to
+    the bit), ``-inf`` everywhere else, a dead row all ``-inf``."""
+    pool, table, q, w, side = _index_case(dtype)
+    n, layer = 40, 1
+    lens = jnp.asarray([37, 0, 256, 8], jnp.int32)
+    n_side = jnp.asarray([1, 0, 4, 2], jnp.int32)
+    _g, s_side, s_pad, _tile = _decode_layout(32)
+    got = np.asarray(sparse_index.index_scores_decode(
+        q, w, pool.swapaxes(1, 2), table, lens, side, n_side, layer,
+        interpret=True, n_pages_per_layer=n))
+    assert got.shape == (4, s_pad)
+    with jax.default_matmul_precision("highest"):
+        cached = pool[layer * n + table].reshape(4, -1, pool.shape[-1])
+        want = np.asarray(sparse_index.index_scores(
+            q[:, None], jnp.concatenate([cached, side], 1), w[:, None])[:, 0])
+    for r in range(4):
+        a, c = int(lens[r]), int(n_side[r])
+        np.testing.assert_allclose(got[r, :a], want[r, :a], rtol=tol,
+                                   atol=tol)
+        np.testing.assert_allclose(got[r, s_side:s_side + c],
+                                   want[r, 256:256 + c], rtol=tol, atol=tol)
+        assert np.isneginf(got[r, a:s_side]).all()
+        assert np.isneginf(got[r, s_side + c:]).all()
+
+
+def test_the_masked_read_is_the_softmax_over_the_selected_rows():
+    """``sparse_decode_attention`` (the ``kv_fused`` loop under a mask,
+    interpreted) against the masked softmax over the same rows gathered
+    through the table: four rows of a stacked pool's last layer, one dead,
+    one whose mask keeps a single side row."""
+    rng = np.random.default_rng(2)
+    b, mp, wc, h, hkv, dh, n, layers = 4, 32, 4, 8, 2, 64, 140, 2
+    _g, s_side, s_pad, _tile = _decode_layout(mp)
+    pool = jnp.asarray(rng.standard_normal((layers * n, PAGE, 2 * hkv * dh)),
+                       jnp.float32)
+    table = jnp.asarray(rng.permutation(n)[:b * mp].reshape(b, mp),
+                        jnp.int32)
+    q = jnp.asarray(rng.standard_normal((b, h, dh)), jnp.float32)
+    side = jnp.asarray(rng.standard_normal((b, wc, 2 * hkv * dh)),
+                       jnp.float32)
+    lens = np.asarray([256, 0, 77, 9], np.int32)
+    n_side = np.asarray([4, 0, 2, 1], np.int32)
+    keep = np.zeros((b, s_pad), np.int32)
+    for r in range(b):
+        keep[r, :lens[r]] = rng.random(lens[r]) < 0.3
+        keep[r, s_side:s_side + n_side[r]] = 1
+    keep[3, :] = 0
+    keep[3, s_side] = 1                  # one side row, no cached row
+    # garbage where nothing is visible must not be read as kept
+    keep[0, 256:s_side] = 1
+    keep[2, 77:256] = 1
+    keep[2, s_side + 2:s_side + 4] = 1
+    lanes = hkv * dh
+    split = lambda a: (a[..., :lanes].reshape(*a.shape[:-1], hkv, dh),
+                       a[..., lanes:].reshape(*a.shape[:-1], hkv, dh))
+    side_k, side_v = split(side)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(sparse_index.sparse_decode_attention(
+            q, pool, table, jnp.asarray(lens), side_k, side_v,
+            jnp.asarray(n_side), jnp.asarray(keep), 1, n_kv_heads=hkv,
+            interpret=True, n_pages_per_layer=n))
+        rows = jnp.concatenate(
+            [pool[n + table].reshape(b, mp * PAGE, -1), side], 1)
+        k, v = split(rows)
+        cols = np.arange(mp * PAGE + wc)[None, :]
+        visible = np.where(cols < mp * PAGE, cols < lens[:, None],
+                           cols - mp * PAGE < n_side[:, None])
+        kept = np.concatenate([keep[:, :mp * PAGE],
+                               keep[:, s_side:s_side + wc]], 1) != 0
+        s = jnp.einsum("bkgd,bskd->bkgs", q.reshape(b, hkv, h // hkv, dh), k
+                       ) * dh ** -0.5
+        p = sparse_index.masked_softmax(
+            s, jnp.asarray(kept & visible)[:, None, None])
+        want = np.asarray(jnp.einsum("bkgs,bskd->bkgd", p, v)).reshape(
+            b, h, dh)
+    assert not got[1].any()
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("lens", [
+    (33000, 2048, 0, 11000, 2049, 20000, 128, 5000),
+    (2048, 2047, 2049, 4096, 0, 0, 33792, 33776),
+    (16, 100, 1000, 0, 0, 0, 0, 0)])
+def test_the_compiled_decode_kernels_are_their_oracles(lens):
+    """The three Mosaic kernels at the served shape (8 rows of 264 pages of
+    128, 6 layers' pools, a side window of 16; the top-2,048), on a TPU only
+    (``python -m pytest --noconftest tests/test_keye.py -m slow -k
+    compiled`` there): the scores against ``index_scores`` through the
+    table, the mask against ``select_mask`` to the bit, the masked read
+    against the masked softmax over the gathered rows."""
+    if jax.default_backend() != "tpu":
+        pytest.skip("the compiled kernels need a TPU")
+    rng = np.random.default_rng(sum(lens))
+    b, mp, page, wc, n, layers, layer = 8, 264, 128, 16, 2112, 2, 1
+    hi, di, h, hkv, dh, k = 16, 64, 32, 4, 128, 2048
+    _g, s_side, s_pad, _tile = sparse_index.decode_layout(mp, page)
+    bf = jnp.bfloat16
+    index_pool = jnp.asarray(rng.standard_normal((layers * n, page, di)), bf)
+    pool = jnp.asarray(rng.standard_normal((layers * n, page, 2 * hkv * dh)),
+                       bf)
+    table = jnp.asarray(rng.permutation(n).reshape(b, mp), jnp.int32)
+    q_idx = jnp.asarray(rng.standard_normal((b, hi, di)), bf)
+    w = jnp.asarray(rng.standard_normal((b, hi)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((b, h, dh)), bf)
+    side = jnp.asarray(rng.standard_normal((b, wc, 2 * hkv * dh + di)), bf)
+    lens = jnp.asarray(lens, jnp.int32)
+    n_side = jnp.where(lens > 0, jnp.asarray(rng.integers(1, wc + 1, b)), 0
+                       ).astype(jnp.int32)
+    scores = sparse_index.index_scores_decode(
+        q_idx, w, index_pool.swapaxes(1, 2), table, lens,
+        side[..., 2 * hkv * dh:], n_side, layer, n_pages_per_layer=n)
+    cached = index_pool[layer * n + table].reshape(b, mp * page, di)
+    want = sparse_index.index_scores(
+        q_idx[:, None], jnp.concatenate([cached, side[..., 2 * hkv * dh:]],
+                                        1), w[:, None])[:, 0]
+    got = np.asarray(scores)
+    for r in range(b):
+        a, c = int(lens[r]), int(n_side[r])
+        np.testing.assert_allclose(got[r, :a], np.asarray(want[r, :a]),
+                                   rtol=1e-5, atol=1e-4)
+        np.testing.assert_allclose(
+            got[r, s_side:s_side + c],
+            np.asarray(want[r, mp * page:mp * page + c]), rtol=1e-5,
+            atol=1e-4)
+        assert np.isneginf(got[r, a:s_side]).all()
+        assert np.isneginf(got[r, s_side + c:]).all()
+    keep = np.asarray(sparse_index.select_mask_decode(
+        scores, lens, n_side, topk=k, mp=mp, page_size=page))
+    oracle = _decode_oracle(got, np.asarray(lens), np.asarray(n_side),
+                            s_side, k)
+    assert (keep == oracle).all(), int((keep != oracle).sum())
+    assert [int(x) for x in keep.sum(-1)] == [
+        min(k, int(a) + int(c)) for a, c in zip(lens, n_side)]
+    lanes = hkv * dh
+    split = lambda a: (a[..., :lanes].reshape(*a.shape[:-1], hkv, dh),
+                       a[..., lanes:2 * lanes].reshape(*a.shape[:-1], hkv,
+                                                       dh))
+    side_k, side_v = split(side)
+    out = np.asarray(sparse_index.sparse_decode_attention(
+        q, pool, table, lens, side_k, side_v, n_side, jnp.asarray(keep),
+        layer, n_kv_heads=hkv, n_pages_per_layer=n), np.float32)
+    rows = jnp.concatenate(
+        [pool[layer * n + table].reshape(b, mp * page, -1),
+         side[..., :2 * lanes]], 1)
+    kk, vv = split(rows)
+    kept = np.concatenate([keep[:, :mp * page],
+                           keep[:, s_side:s_side + wc]], 1) != 0
+    s = jnp.einsum("bkgd,bskd->bkgs", q.reshape(b, hkv, h // hkv, dh), kk,
+                   preferred_element_type=jnp.float32) * dh ** -0.5
+    p = sparse_index.masked_softmax(s, jnp.asarray(kept)[:, None, None])
+    ref = np.asarray(jnp.einsum("bkgs,bskd->bkgd", p.astype(bf), vv),
+                     np.float32).reshape(b, h, dh)
+    np.testing.assert_allclose(out, ref, rtol=2e-2, atol=2e-2)
+    assert not out[np.asarray(lens) == 0].any()
 
 
 @pytest.mark.slow

@@ -49,6 +49,7 @@ with open(os.path.join(ROOT, "perfbench", "rehearse",
     CFG = json.load(_f)
 REF = families.reference(CFG)
 TOPK, PAGE = 16, 8
+KERNELS = "pallas-decode_interpret"     # the TPU's decode body, interpreted
 
 
 def tiny_spec(**kw):
@@ -135,6 +136,8 @@ def test_counters_follow_lengths_and_steps(shared):
     assert attn["index_prefill_pairs"] == 60 * 61 // 2
     assert attn["full_prefill_key_blocks_visited"] >= 1
     assert "state" not in m
+    # the XLA body gathered the top-16 and the side window, every slot's
+    assert attn["kv_rows_read"] == 8 * (4 * TOPK + 4 * 4)
     # below the top-k every row is selected
     m0 = engine.get_metrics()
     engine.generate([GenerationRequest(prompt=[5, 6, 7], max_new_tokens=5)])
@@ -143,19 +146,74 @@ def test_counters_follow_lengths_and_steps(shared):
         range(4, 8))
 
 
+def test_the_kernel_bodys_counters_follow_the_live_pages(shared):
+    """The same request on the kernel body: what the indexer and the
+    attention READ is the row's live pages whole (8 pages of 8 as the two
+    chunks of 4 steps began at 60 and 64 rows) and the side window (4 slots x
+    4 rows) a step, not the table; the selection and the context are the
+    XLA body's."""
+    engine = shared(attention_impl=KERNELS)
+    assert (engine.body, engine.attn_impl) == ("hybrid", KERNELS)
+    m0 = engine.get_metrics()
+    engine.generate([GenerationRequest(prompt=list(range(1, 61)),
+                                       max_new_tokens=9)])
+    m = engine.get_metrics()
+    attn = grown(m0["attn"], m["attn"])
+    assert m["decode_steps"] - m0["decode_steps"] == 8
+    assert attn["index_rows_scored"] == sum(range(61, 69))
+    assert attn["rows_selected"] == 8 * TOPK
+    assert attn["index_table_rows"] == 8 * (8 * PAGE + 4 * 4)
+    assert attn["kv_rows_read"] == attn["index_table_rows"]
+    assert attn["rows_selected"] <= attn["kv_rows_read"]
+    assert attn["index_rows_scored"] <= attn["index_table_rows"]
+
+
+def _lowered_decode_chunk(eng):
+    from distributed_inference_engine_tpu.ops.sampling import SamplingParams
+
+    kv, n = eng.kv, eng.max_slots
+    sampling = SamplingParams(eng._temps, eng._top_k, eng._top_p, eng._min_p)
+    return eng._decode_chunk.lower(
+        eng.params, *kv.pools, eng._lengths, eng._last, eng._active,
+        eng._produced, kv.page_table, jnp.zeros((n,), jnp.int32),
+        eng._max_new, sampling, eng._eos, eng._stops_dev,
+        jax.random.key(0), n_steps=4).as_text(debug_info=True)
+
+
+def test_the_kernel_bodys_ops_carry_the_selections_scopes(shared):
+    """The TPU body's decode chunk: the three kernels under ``attn.index``,
+    ``attn.select`` and ``attn.sparse`` inside ``attn.dsa`` (the scopes the
+    decode roofline's denominator sums; ``attn.gather`` has no op), under
+    names the prefill kernels' reader does not match, and nothing of the
+    XLA body (no sort, no ``top_k``)."""
+    dec = _lowered_decode_chunk(shared(attention_impl=KERNELS))
+    for scope, launcher, kernel in (
+            ("attn.index", "index_scores_decode", "index_scores_decode"),
+            ("attn.select", "select_mask_decode", "select_mask_decode"),
+            ("attn.sparse", "sparse_decode_attention",
+             "sparse_decode_flash")):
+        assert f"attn.dsa/{scope}/jit({launcher})" in dec, launcher
+        assert f'"{kernel}/' in dec, kernel
+    assert "attn.gather" not in dec
+    assert not re.search(
+        r"(index_scores_flash|sparse_prefill_flash)_b\d+q\d+k\d+", dec)
+    dsa = [line for line in dec.splitlines() if "attn.dsa" in line]
+    assert dsa and not [line for line in dsa
+                        if re.search(r"top_k|chlo\.top_k|stablehlo\.sort",
+                                     line)]
+    for scope in ("attn.kv_update", "moe.route", "moe.experts",
+                  "head.unembed", "sample"):
+        assert re.search(rf'["/]{re.escape(scope)}/', dec), scope
+
+
 def test_the_spans_are_in_the_programs(shared):
     """Every scope the per-layer metrics read is on some operation of the
     lowered decode and prefill programs."""
     from distributed_inference_engine_tpu.ops.sampling import SamplingParams
 
     eng = shared()
-    kv, n = eng.kv, eng.max_slots
-    sampling = SamplingParams(eng._temps, eng._top_k, eng._top_p, eng._min_p)
-    dec = eng._decode_chunk.lower(
-        eng.params, *kv.pools, eng._lengths, eng._last, eng._active,
-        eng._produced, kv.page_table, jnp.zeros((n,), jnp.int32),
-        eng._max_new, sampling, eng._eos, eng._stops_dev,
-        jax.random.key(0), n_steps=4).as_text(debug_info=True)
+    kv = eng.kv
+    dec = _lowered_decode_chunk(eng)
     for scope in ("attn.dsa", "attn.index", "attn.select", "attn.gather",
                   "attn.sparse", "attn.kv_update", "moe.route",
                   "moe.experts", "head.unembed", "sample"):
@@ -245,6 +303,28 @@ def test_a_sixteen_step_chunk_crosses_the_topk(shared):
     judged(engine, make(), got)
 
 
+def test_a_sixteen_step_chunk_crosses_the_topk_through_the_kernels(shared):
+    """The same four requests through the TPU's decode body (the three
+    kernels, interpreted) at the cell's cadence, over slots that are reused
+    (ten requests over four slots: a successor's pages hold its
+    predecessor's index keys): every token is the reference's."""
+    rng = np.random.default_rng(9)
+    reqs = [GenerationRequest(
+        prompt=[int(t) for t in rng.integers(1, 256, n)], max_new_tokens=m)
+        for n, m in ((10, 40), (37, 40), (3, 40), (20, 40), (100, 30),
+                     (33, 7), (12, 5), (61, 14), (9, 45), (31, 6))]
+    with jax.default_matmul_precision("highest"):
+        engine = shared("float32", decode_steps_per_call=16,
+                        attention_impl=KERNELS)
+        got = engine.generate(reqs)
+        xla = shared("float32", decode_steps_per_call=16).generate(reqs[:4])
+    judged(engine, reqs, got)
+    assert [r.tokens for r in got[:4]] == [r.tokens for r in xla]
+    attn = engine.get_metrics()["attn"]
+    assert attn["rows_selected"] < attn["kv_rows_read"] < attn[
+        "full_context_rows"] + 16 * 4 * engine.get_metrics()["decode_steps"]
+
+
 def test_streamed_matches_unstreamed(shared):
     rng = np.random.default_rng(3)
     prompts = [[int(t) for t in rng.integers(1, 256, n)] for n in (30, 45)]
@@ -271,8 +351,10 @@ def test_streamed_matches_unstreamed(shared):
 
 
 def test_the_body_is_chosen_from_what_the_spec_states():
-    """The selection has ONE decode body, the XLA gather, on any backend:
-    no kernel reads rows scattered over the pages yet."""
+    """A learned selection takes its kernels under exactly a K|V-row spec's
+    conditions (a TPU, the pool on one device, K|V rows of whole 128-lane
+    tiles): ``auto`` resolves from the spec and the backend, ``xla`` keeps
+    the gathers, the kernel's names ask for it anywhere."""
     spec = spec_for_architecture("keye", size="keye-vl-2.0-30b-a3b-pp1",
                                  max_seq_len=33792)
     assert spec.max_seq_len == 33792 and not spec.recurrent
@@ -280,10 +362,18 @@ def test_the_body_is_chosen_from_what_the_spec_states():
     assert (spec.paged_layers, spec.window_layers) == (6, 0)
     assert (spec.index_heads, spec.index_head_dim, spec.index_topk) == (
         16, 64, 2048)
-    assert resolve_decode_body("auto", "tpu", spec) == ("hybrid", "xla")
+    assert resolve_decode_body("auto", "tpu", spec) == (
+        "hybrid", "pallas-decode")
     assert resolve_decode_body("auto", "cpu", spec) == ("hybrid", "xla")
-    with pytest.raises(ValueError, match="selects its rows"):
-        resolve_decode_body("pallas-decode", "tpu", spec)
+    assert resolve_decode_body("auto", "tpu", spec, sharded=True) == (
+        "hybrid", "xla")
+    assert resolve_decode_body("xla", "tpu", spec) == ("hybrid", "xla")
+    for name in ("pallas-decode", KERNELS):
+        assert resolve_decode_body(name, "cpu", spec) == ("hybrid", name)
+    assert resolve_decode_body("auto", "tpu", tiny_spec()) == (
+        "hybrid", "pallas-decode")
+    with pytest.raises(ValueError, match="not one of"):
+        resolve_decode_body("sparse-decode", "tpu", spec)
     with pytest.raises(ValueError, match="unknown keye size"):
         spec_for_architecture("keye", size="keye-9b")
 

@@ -508,9 +508,10 @@ def test_the_learned_selection_family_compiles_and_fits(one_chip,
     """The learned-sparse-attention family's programs at the served size (6
     layers of 128 experts, 8 slots of 33,792 positions, 16 steps; a prefill
     at the 32,768 bucket), compiled for the v5e with the Mosaic grouped
-    matmul: the decode chunk gathers index keys through the table and the
-    selected K|V rows from the pool where it lies (no copy or slice of
-    either pool), and the largest prefill (``flash_prefill.prefill_impl`` is
+    matmul: the decode chunk's selection is the three kernels of
+    ``ops/sparse_index.py``, each reading its pool's LIVE pages where they
+    lie (no copy or slice of either pool, no ``lax.top_k``, no row
+    gather), and the largest prefill (``flash_prefill.prefill_impl`` is
     steered to the chip's answer here: the masked flash kernel a chunk of
     4,096 queries, one index head's scores at a time) fits its temporaries
     beside the 8.75 GB tree and the 3.53 GB of pages (12.28 GB held) in a
@@ -545,7 +546,7 @@ def test_the_learned_selection_family_compiles_and_fits(one_chip,
     assert 12.2e9 < held < 12.3e9
 
     def decode(params, pages, state, lengths, last, active, table):
-        ctx = fam.decode_context(pages, table, "xla")
+        ctx = fam.decode_context(pages, table, "pallas-decode")
         side = jnp.zeros((spec.n_layers, slots, steps,
                           width + spec.index_head_dim), pages.dtype)
 
@@ -573,15 +574,40 @@ def test_the_learned_selection_family_compiles_and_fits(one_chip,
         params, pool, state, arr(slots), arr(slots),
         arr(slots, dtype=jnp.bool_), arr(slots, mp)).compile()
     text = dec.as_text()
-    # the K|V pool is gathered from and scattered into, never copied or
-    # sliced a layer
-    ops = set()
-    for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z-]*)\(", line)
-        if m and "[6,2112,128,1024]" in m.group(1).split(" ")[0]:
-            ops.add(m.group(2))
+    # the selection's three kernels, and nothing of the XLA body: no sort
+    # (``lax.top_k``), no gather of 2,048 rows a sequence
+    for kernel in ("index_scores_decode", "select_mask_decode",
+                   "sparse_decode_flash"):
+        assert kernel in text, kernel
+    assert not [line for line in text.splitlines() if "attn.dsa" in line
+                and re.search(r" (sort|topk)\(|top_k|TopK", line)]
+    assert "[8,2048,1024]" not in text
+    # the K|V pool is read where it lies and scattered into, never copied
+    # or sliced a layer
+    def ops_over(shape_rx):
+        found = []
+        for line in text.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][a-z-]*)\(",
+                         line)
+            if m and re.search(shape_rx, m.group(1).split(" ")[0]):
+                found.append((m.group(2), line))
+        return found
+
+    ops = {op for op, _ in ops_over(r"\[(6,2112|12672),128,1024\]")}
     assert ops and ops <= {"parameter", "tuple", "get-tuple-element",
                            "while", "bitcast", "scatter", "fusion"}, ops
+    # the index keys' pool: the kernel's view of a page (its positions on
+    # the lanes) is a BITCAST of how the pool lies on a TPU (the longer of
+    # its last two axes minor), inside the steps' loop; the only copies are
+    # the write-back's two, once a chunk (ROADMAP S19 (c))
+    index = ops_over(r"\[(6,2112,128,64|12672,64,128)\]")
+    assert any(op == "bitcast" and "attn.index/transpose" in line
+               for op, line in index)
+    copies = [line for op, line in index if op == "copy"]
+    assert len(copies) <= 2 and not any("while" in c for c in copies)
+    assert {op for op, _ in index} <= {
+        "parameter", "tuple", "get-tuple-element", "while", "bitcast",
+        "scatter", "fusion", "copy"}
     assert dec.memory_analysis().temp_size_in_bytes < 1.0e9
     pre = jax.jit(prefill, donate_argnums=(3, 4)).lower(
         params, arr(1, 32768), arr(1), pool, state, arr(1, mp),
