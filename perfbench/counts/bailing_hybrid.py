@@ -12,6 +12,7 @@ ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
 CACHE = ("576 latent values a token for each MLA layer as stored (padding "
          "counted); KDA layers hold S and a 3-row conv tail per slot, "
          "state_bytes_per_slot(cfg)")
+SCOPE_READERS = "scopes"       # the module under lib/ (lib/families.py)
 
 
 def widths(cfg: Dict[str, Any]) -> Dict[str, int]:

@@ -13,6 +13,7 @@ CACHE = ("TWO rows a token a layer on ONE page table: a K|V row of 2 x 4 x "
          "B a layer, 13,056 B a token over the 6 kept layers; a decode step "
          "reads every live index key and gathers at most 2,048 K|V rows a "
          "sequence a layer")
+SCOPE_READERS = "scopes_dsa"       # the module under lib/ (lib/families.py)
 
 
 def widths(cfg: Dict[str, Any]) -> Dict[str, int]:

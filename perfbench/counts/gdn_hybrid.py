@@ -13,6 +13,7 @@ CACHE = ("K|V rows of 2 x 30 x 128 values a token for the FULL-attention "
          "layers only (4 of the 16 kept); the 12 Gated-DeltaNet layers hold "
          "per SLOT a 30 x 96 x 192 float32 state (2.21 MB) and a 3-row conv "
          "tail of 11,520 values instead: state_bytes_per_slot(cfg)")
+SCOPE_READERS = "scopes_gdn"       # the module under lib/ (lib/families.py)
 
 
 def widths(cfg: Dict[str, Any]) -> Dict[str, int]:

@@ -14,6 +14,7 @@ ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
 CACHE = ("576 latent values a token (512 normalised c | 64 rotated k_rope) "
          "for EVERY layer, stored in rows of 640 lanes, shared by the 64 "
          "heads; no per-sequence state")
+SCOPE_READERS = "scopes_mla_share"  # the module under lib/ (families.py)
 
 
 def widths(cfg: Dict[str, Any]) -> Dict[str, int]:

@@ -14,6 +14,7 @@ CACHE = ("K|V rows of 2 x 4 x 128 values (2,048 B) a token a layer; the 3 "
          "SLIDING layers stop growing at 1,024 rows: their pages go back "
          "to a free list as the window passes them, 10 pages of 128 a slot "
          "at most: window_bytes_per_slot(cfg)")
+SCOPE_READERS = "scopes_swa"       # the module under lib/ (lib/families.py)
 
 
 def widths(cfg: Dict[str, Any]) -> Dict[str, int]:

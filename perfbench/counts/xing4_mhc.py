@@ -13,6 +13,7 @@ ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
 CACHE = ("576 latent values a token (512 normalised c | 64 rotated k_rope) "
          "for EVERY layer as stored, shared by the heads; no per-sequence "
          "state")
+SCOPE_READERS = "scopes_mhc"       # the module under lib/ (lib/families.py)
 
 
 def widths(cfg: Dict[str, Any]) -> Dict[str, int]:

@@ -13,6 +13,19 @@ which a later PR adds without touching any that exist:
     a forward pass multiplies by, ``(name, K, N, times per pass, dtype)`` as
     stored.
 
+    Optionally ``SCOPE_READERS``: the module under ``lib/`` that holds the
+    family's scope readers (its list of ``jax.named_scope`` names, how it
+    counts a slice, which of ``counts``' costs it divides by). A reading that
+    several families have has ONE entry in ``BENCHMARK.json`` and one file
+    under ``metrics/``, which asks the run's own family's module for it by
+    the function's name (``scope_reading``); a module without that function
+    has no such reading, and the reader returns ``None``. The names in use:
+    ``decode_step_ms``, ``prefill_time_share_pct``,
+    ``decode_stream_roofline_pct``, ``share_pct(run, scopes)``,
+    ``expert_stream_roofline_pct(run[, scope])``,
+    ``held_assignment_share_pct``, ``mla_decode_roofline_pct``,
+    ``mla_table_live_share_pct``, ``full_table_live_share_pct``.
+
 ``reference/<family>.py`` (jax: a child of the traced run imports it)
     ``logits(cfg, params, tokens)``: the plain float32 reference;
     ``SPEC_PAIRS``: (configuration key, ``ModelSpec`` field) pairs that must
@@ -24,10 +37,11 @@ which a later PR adds without touching any that exist:
 
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import os
 from types import ModuleType
-from typing import Any, Dict
+from typing import Any, Dict, Optional
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT = "dense_int4"
@@ -61,6 +75,21 @@ def counts(cfg: Dict[str, Any]) -> ModuleType:
 
 def reference(cfg: Dict[str, Any]) -> ModuleType:
     return _load("reference", cfg, REFERENCE_API)
+
+
+def scopes(cfg: Dict[str, Any]) -> Optional[ModuleType]:
+    """The family's scope readers (``counts/<family>.py`` ``SCOPE_READERS``
+    names the module under ``lib/``); ``None`` for a family that names
+    none."""
+    name = getattr(counts(cfg), "SCOPE_READERS", None)
+    return importlib.import_module(f"{__package__}.{name}") if name else None
+
+
+def scope_reading(run, reading: str, *args: Any) -> Optional[float]:
+    """``reading`` as the run's own family reads it; ``None`` where the
+    family has no such reading."""
+    fn = getattr(scopes(run.config), reading, None)
+    return fn(run, *args) if fn else None
 
 
 def int4_calls_per_pass(cfg: Dict[str, Any]) -> int:

@@ -28,12 +28,10 @@ family) leaves the metric out.
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Any, Dict, List, Optional, Sequence
 
-from . import families, hostspans, peaks, scopes
-from .procs import MODEL
+from . import scopes
+from .scopes import least_seconds as _least_seconds, per_slice_step
 from .tracered import leaf_ops
 
 SCOPES = ("attn.gdn.step", "attn.gdn.prefill", "recurrence", "attn.full",
@@ -74,38 +72,16 @@ def reduce_scopes(scoped_ops: Sequence[Sequence[Sequence[Any]]]
 def scope_seconds(run) -> Optional[Dict[str, Any]]:
     """``reduce_scopes`` summed over the workers' traced slices; ``None``
     without a trace or when no op carries one of the family's own scopes."""
-    total: Dict[str, Any] = {"busy_s": 0.0, "scopes": {}, "kernel_calls": 0}
-    for wid, trace_dir in run.trace_dirs.items():
-        path = os.path.join(os.path.dirname(trace_dir),
-                            f"scopes-gdn-{wid}.json")
-        if not os.path.exists(path):
-            with open(path, "w") as f:
-                json.dump(reduce_scopes(hostspans.scoped_ops(trace_dir)), f)
-        with open(path) as f:
-            red = json.load(f)
-        total["busy_s"] += red["busy_s"]
-        total["kernel_calls"] += red.get("kernel_calls", 0)
-        for name, d in red["scopes"].items():
-            t = total["scopes"].setdefault(name, {"decode": 0.0, "other": 0.0})
-            for kind, s in d.items():
-                t[kind] += s
+    total = scopes.summed_reductions(run, "scopes-gdn", reduce_scopes)
     own = any(n in total["scopes"] for n in OWN)
     return total if own and total["busy_s"] else None
 
 
 def share_pct(run, names: Sequence[str]) -> Optional[float]:
-    """Device self time under ``names`` (scopes that do not nest in one
-    another) over all device self time."""
-    sc = scope_seconds(run)
-    if not sc:
-        return None
-    s = sum(sum(sc["scopes"].get(n, {}).values()) for n in names)
-    return 100.0 * s / sc["busy_s"]
+    return scopes.share_of(scope_seconds(run), names)
 
 
-def _counts(run, name: str):
-    counts = families.counts(run.config)
-    return counts if hasattr(counts, name) else None
+_counts = scopes.counts_with
 
 
 def steps_in_slice(run) -> Optional[float]:
@@ -119,18 +95,11 @@ def steps_in_slice(run) -> Optional[float]:
 
 
 def decode_step_ms(run) -> Optional[float]:
-    n = steps_in_slice(run)
-    if not n:
-        return None
-    return 1e3 * run.trace["program_s"].get("decode", 0.0) / n
+    return scopes.step_ms(run, steps_in_slice(run))
 
 
 def prefill_time_share_pct(run) -> Optional[float]:
-    """The prefill programs' device time over the device's busy time."""
-    t = run.trace
-    if not t or not t.get("busy_s") or scope_seconds(run) is None:
-        return None
-    return 100.0 * t["program_s"].get("prefill", 0.0) / t["busy_s"]
+    return scopes.prefill_share_pct(run, scope_seconds(run))
 
 
 def table_live_share_pct(run) -> Optional[float]:
@@ -141,39 +110,6 @@ def table_live_share_pct(run) -> Optional[float]:
     if live is None or not table:
         return None
     return 100.0 * live / table
-
-
-def slice_counter(run, *path: str) -> Optional[float]:
-    """Growth of a counter of the model's ``get_metrics()`` over the TRACED
-    SLICE, summed over the workers: between the two stamps of
-    ``counters.json`` in each trace directory. ``None`` without the file
-    (an earlier program) or without the counter."""
-    total = 0.0
-    try:
-        for trace_dir in run.trace_dirs.values():
-            with open(os.path.join(trace_dir, "counters.json")) as f:
-                stamps = json.load(f)
-            a, b = stamps["stop"], stamps["start"]
-            for k in ("models", MODEL, *path):
-                a, b = a[k], b[k]
-            total += a - b
-    except (OSError, ValueError, KeyError, TypeError):
-        return None
-    return total if run.trace_dirs else None
-
-
-def per_slice_step(run, *path: str) -> Optional[float]:
-    """``slice_counter`` a decode step between the same two stamps."""
-    steps, value = slice_counter(run, "decode_steps"), slice_counter(run, *path)
-    if not steps or value is None:
-        return None
-    return value / steps
-
-
-def _least_seconds(run, cost: Dict[str, float]) -> float:
-    pk = peaks.peaks_for(run.device["kind"])
-    return max(cost["bytes"] / pk["hbm_bytes_per_s"],
-               cost["flops"] / pk["bf16_flops_per_s"])
 
 
 def decode_stream_roofline_pct(run) -> Optional[float]:
@@ -232,3 +168,7 @@ def state_roofline_pct(run) -> Optional[float]:
         return None
     return 100.0 * _least_seconds(
         run, counts.state_cost(run.config, moved * n)) / seconds
+
+
+# the names the folded readers ask every family's module for
+full_table_live_share_pct = table_live_share_pct

@@ -1,8 +1,10 @@
-"""What the ``*.xing`` readers need beyond ``lib/scopes.py`` (which they use
-as it is for the scopes both per-layer families carry: ``attn.mla``,
-``moe.route``, ``moe.experts``, ``gmm``): device self time under the residual
-path's own scope ``resid.mhc``, the prefill programs' share of the device's
-time, and the latent attention's share of its HBM bound in decode.
+"""The mHC family's scope readers (``counts/xing4_mhc.py``
+``SCOPE_READERS``): ``lib/scopes.py``'s own for the scopes both per-layer
+families carry (``attn.mla``, ``moe.route``, ``moe.experts``, ``gmm``,
+``head.unembed``: taken over by name below), and beyond them device self
+time under the residual path's own scope ``resid.mhc``, the prefill
+programs' share of the device's time, and the latent attention's share of
+its HBM bound in decode.
 
 Same sources as ``lib/scopes.py``: ``hostspans.scoped_ops`` reads each op's
 scope path from the ``.xplane.pb``, ``tracered.leaf_ops`` gives it its self
@@ -14,14 +16,18 @@ commit, another family) leaves the metric out.
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Any, Dict, Optional, Sequence
 
-from . import families, hostspans, peaks, scopes
+from . import families, scopes
+from .scopes import (  # noqa: F401  (the family's readers by one name)
+    decode_step_ms, expert_stream_roofline_pct, share_pct,
+)
 from .tracered import leaf_ops
 
 SCOPES = ("resid.mhc",)
+# what ``share_pct`` (``lib/scopes.py``'s) finds in this family's programs
+SHARE_SCOPES = ("attn.mla", "moe.route", "moe.experts", "moe.shared",
+                "head.unembed", "sample")
 
 
 def reduce_scopes(scoped_ops: Sequence[Sequence[Sequence[Any]]]
@@ -43,24 +49,13 @@ def reduce_scopes(scoped_ops: Sequence[Sequence[Sequence[Any]]]
 
 
 def scope_seconds(run) -> Optional[Dict[str, Any]]:
-    total: Dict[str, Any] = {"busy_s": 0.0, "scopes": {}}
-    for wid, trace_dir in run.trace_dirs.items():
-        path = os.path.join(os.path.dirname(trace_dir),
-                            f"scopes-mhc-{wid}.json")
-        if not os.path.exists(path):
-            with open(path, "w") as f:
-                json.dump(reduce_scopes(hostspans.scoped_ops(trace_dir)), f)
-        with open(path) as f:
-            red = json.load(f)
-        total["busy_s"] += red["busy_s"]
-        for name, d in red["scopes"].items():
-            t = total["scopes"].setdefault(name, {"decode": 0.0, "other": 0.0})
-            for kind, s in d.items():
-                t[kind] += s
+    """The family's reduction over the workers' traced slices; ``None``
+    without a trace or when it is another program's."""
+    total = scopes.summed_reductions(run, "scopes-mhc", reduce_scopes)
     return total if total["scopes"] and total["busy_s"] else None
 
 
-def share_pct(run) -> Optional[float]:
+def mhc_share_pct(run) -> Optional[float]:
     """Device self time under ``resid.mhc`` over all device self time."""
     sc = scope_seconds(run)
     if not sc:
@@ -78,14 +73,10 @@ def mhc_ms_per_decode_step(run) -> Optional[float]:
 
 
 def prefill_time_share_pct(run) -> Optional[float]:
-    """The prefill programs' device time over the device's busy time."""
-    t = run.trace
-    if not t or not t.get("busy_s") or scope_seconds(run) is None:
-        return None
-    return 100.0 * t["program_s"].get("prefill", 0.0) / t["busy_s"]
+    return scopes.prefill_share_pct(run, scope_seconds(run))
 
 
-def table_live_share_pct(run) -> Optional[float]:
+def mla_table_live_share_pct(run) -> Optional[float]:
     """Rows the decode steps attended to over rows the body read for them."""
     live = scopes.counter(run, "mla", "decode_context_rows")
     table = scopes.counter(run, "mla", "decode_table_rows")
@@ -96,23 +87,20 @@ def table_live_share_pct(run) -> Optional[float]:
 
 def mla_decode_roofline_pct(run) -> Optional[float]:
     """Least time the chip could take to read the latent rows the decode
-    steps attended to (the LIVE rows, counter ``mla.decode_context_rows``,
-    never the table) over the decode programs' self time under
+    steps attended to (the LIVE rows, counter ``mla.decode_context_rows``
+    between the slice's two stamps, never the table) over the decode
+    programs' self time under
     ``attn.mla``. Only what that scope surely reads is counted: the
     layers' MLA matrices are left out of the bytes (part of their read is
     charged to ops outside the scope), so the share is a floor of the
     attention's use of the HBM peak and cannot pass 100 %."""
     sc = scopes.scope_seconds(run)
-    n = scopes.decode_steps_in_slice(run)
-    rows = scopes.per_decode_step(run, "mla", "decode_context_rows")
-    if not sc or not n or rows is None:
+    rows = scopes.slice_counter(run, "mla", "decode_context_rows")
+    if not sc or rows is None:
         return None
     seconds = sc["scopes"].get("attn.mla", {}).get("decode")
     counts = families.counts(run.config)
     if not seconds or not hasattr(counts, "mla_decode_cost"):
         return None
-    cost = counts.mla_decode_cost(run.config, rows * n)
-    pk = peaks.peaks_for(run.device["kind"])
-    least = max(cost["bytes"] / pk["hbm_bytes_per_s"],
-                cost["flops"] / pk["bf16_flops_per_s"])
-    return 100.0 * least / seconds
+    return 100.0 * scopes.least_seconds(
+        run, counts.mla_decode_cost(run.config, rows)) / seconds

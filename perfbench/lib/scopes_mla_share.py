@@ -33,12 +33,10 @@ commit, another family) leaves the metric out.
 
 from __future__ import annotations
 
-import json
-import os
 from typing import Any, Dict, List, Optional, Sequence
 
-from . import families, hostspans, scopes
-from .scopes_gdn import _least_seconds, per_slice_step
+from . import scopes
+from .scopes import least_seconds as _least_seconds, per_slice_step
 from .tracered import leaf_ops
 
 SCOPES = ("attn.mla", "attn.kv_update", "moe.route", "moe.experts",
@@ -74,40 +72,15 @@ def reduce_scopes(scoped_ops: Sequence[Sequence[Sequence[Any]]]
 def scope_seconds(run) -> Optional[Dict[str, Any]]:
     """``reduce_scopes`` summed over the workers' traced slices; ``None``
     without a trace or when no op ran under ``attn.mla``."""
-    total: Dict[str, Any] = {"busy_s": 0.0, "scopes": {}, "kernel_calls": 0,
-                             "kernel_s": 0.0}
-    for wid, trace_dir in run.trace_dirs.items():
-        path = os.path.join(os.path.dirname(trace_dir),
-                            f"scopes-mla-share-{wid}.json")
-        if not os.path.exists(path):
-            with open(path, "w") as f:
-                json.dump(reduce_scopes(hostspans.scoped_ops(trace_dir)), f)
-        with open(path) as f:
-            red = json.load(f)
-        total["busy_s"] += red["busy_s"]
-        total["kernel_calls"] += red.get("kernel_calls", 0)
-        total["kernel_s"] += red.get("kernel_s", 0.0)
-        for name, d in red["scopes"].items():
-            t = total["scopes"].setdefault(name, {"decode": 0.0, "other": 0.0})
-            for kind, s in d.items():
-                t[kind] += s
-    return total if "attn.mla" in total["scopes"] and total["busy_s"] \
-        else None
+    total = scopes.summed_reductions(run, "scopes-mla-share", reduce_scopes)
+    return total if "attn.mla" in total["scopes"] and total["busy_s"] else None
 
 
 def share_pct(run, names: Sequence[str]) -> Optional[float]:
-    """Device self time under ``names`` (scopes that do not nest in one
-    another) over all device self time."""
-    sc = scope_seconds(run)
-    if not sc:
-        return None
-    s = sum(sum(sc["scopes"].get(n, {}).values()) for n in names)
-    return 100.0 * s / sc["busy_s"]
+    return scopes.share_of(scope_seconds(run), names)
 
 
-def _counts(run, name: str):
-    counts = families.counts(run.config)
-    return counts if hasattr(counts, name) else None
+_counts = scopes.counts_with
 
 
 def steps_in_slice(run) -> Optional[float]:
@@ -121,18 +94,11 @@ def steps_in_slice(run) -> Optional[float]:
 
 
 def decode_step_ms(run) -> Optional[float]:
-    n = steps_in_slice(run)
-    if not n:
-        return None
-    return 1e3 * run.trace["program_s"].get("decode", 0.0) / n
+    return scopes.step_ms(run, steps_in_slice(run))
 
 
 def prefill_time_share_pct(run) -> Optional[float]:
-    """The prefill programs' device time over the device's busy time."""
-    t = run.trace
-    if not t or not t.get("busy_s") or scope_seconds(run) is None:
-        return None
-    return 100.0 * t["program_s"].get("prefill", 0.0) / t["busy_s"]
+    return scopes.prefill_share_pct(run, scope_seconds(run))
 
 
 def table_live_share_pct(run) -> Optional[float]:
@@ -220,3 +186,7 @@ def expert_stream_roofline_pct(run, scope: str = "moe.experts"
         return None
     return 100.0 * _least_seconds(run, counts.expert_stream_cost(
         run.config, rows["touched"] * n, rows["pairs"] * n)) / seconds
+
+
+# the name the folded reader asks every latent-row family's module for
+mla_table_live_share_pct = table_live_share_pct

@@ -1,6 +1,6 @@
 """Shared arithmetic of the readers of the program's own spans, marks and
 counters (``metrics/coord.pool_wait*``, ``pump.inbox_wait*``, ``engine.*``,
-``device.idle_attributed_share.*``, ``kv.copy_time_share.*``,
+``kv.copy_time_share.*``,
 ``setup.backend_*``). Like
 ``readers.py``: a reader returns ``None`` when what it reads is not there —
 a program without these marks (an earlier commit) then simply leaves the
@@ -150,13 +150,6 @@ def host_busy_share_pct(run) -> Optional[float]:
     if not hs or not hs["window_s"]:
         return None
     return 100.0 * hs["engine_busy_s"] / hs["window_s"]
-
-
-def idle_attributed_share_pct(run) -> Optional[float]:
-    hs = host_spans(run)
-    if not hs or not hs["idle_gap_s"]:
-        return None
-    return 100.0 * hs["idle_attributed_s"] / hs["idle_gap_s"]
 
 
 def kv_copy_share_pct(run) -> Optional[float]:

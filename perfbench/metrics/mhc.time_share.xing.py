@@ -14,4 +14,4 @@ MOVES = "out_tok_s"
 
 
 def read(run):
-    return scopes_mhc.share_pct(run)
+    return scopes_mhc.mhc_share_pct(run)

@@ -6,7 +6,9 @@ Benchmark process (never imports jax) -> framed RPC -> ``cli.coordinator``
 child -> ``cli.worker`` child(ren) holding the chip(s) -> ContinuousEngine.
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
 ``failed``, ``metrics`` (the cell's end-to-end metrics, or with ``--trace 1``
-its per-layer metrics), ``device`` and with ``--trace 1`` ``breakdown``.
+its per-layer metrics), ``device``, with ``--trace 1`` ``breakdown``, and last
+``compared``: every number ``correct`` was decided by beside its limit (the
+same lines end standard error).
 Any failure to start, to find the device the cell asks for, or to finish
 exits non-zero with no result line.
 """
@@ -22,6 +24,8 @@ import asyncio
 import importlib.util
 import json
 import os
+import pickle
+import re
 import sys
 import traceback
 from typing import Any, Dict, List, Optional
@@ -115,10 +119,32 @@ async def drive(sess: session.Session, mix: Dict[str, Any],
     return {"run": run, "cases": cases}
 
 
+def reference_numbers(log: str, ended_well: bool) -> Dict[str, Any]:
+    """Every number ``reference/check.py`` compared, beside its limit, from
+    the child's own lines (``reference: ... min_strict_share=S`` and one
+    ``<label>: {json}`` a chain)."""
+    out: Dict[str, Any] = {
+        "reference_ran_to_its_end": {"value": 0, "at_least": 1}}
+    share = re.search(r"min_strict_share=([0-9.]+)", log)
+    for label, doc in re.findall(r"^\s+(\S+): (\{.*\})\s*$", log, re.M):
+        res = json.loads(doc)
+        out[f"{label}.outside_tie_band"] = {"value": res["outside"],
+                                            "at_most": 0}
+        out[f"{label}.strict_share"] = {
+            "value": res["strict"] / max(1, res["n"]),
+            "at_least": float(share.group(1)) if share else None}
+        out[f"{label}.worst_tie"] = {"value": res["worst_tie"], "below": 1.0}
+    ran = "chains verified" in log
+    out["reference_ran_to_its_end"]["value"] = int(ran)
+    out["reference_verdict"] = {"value": int(ended_well), "at_least": 1}
+    return out
+
+
 def after_servers(sess: session.Session, out: Dict[str, Any], seed: int
-                  ) -> bool:
+                  ) -> Dict[str, Any]:
     """Traced run, servers stopped, chip free: reduce the traces and judge
-    the served chains against the plain reference. Returns its verdict."""
+    the served chains against the plain reference. Returns every number it
+    compared beside its limit; ``reference_verdict`` is its verdict."""
     run: session.RunData = out["run"]
     cfg = dict(sess.config)
     # the worker's count of int4 tensors, fused or not as it runs them; a
@@ -153,6 +179,10 @@ def after_servers(sess: session.Session, out: Dict[str, Any], seed: int
     reduced = [r for r in reduced if r.get("devices")]
     if reduced:
         run.trace = tracered.average(reduced)
+    # what the readers take, kept beside the traces: ``tools/reread.py``
+    # reads a finished run again (another tree's readers on the same run)
+    with open(os.path.join(sess.work_dir, "run.pkl"), "wb") as f:
+        pickle.dump(run, f)
     job = os.path.join(sess.work_dir, "reference_job.json")
     with open(job, "w") as f:
         json.dump({"config": sess.config,
@@ -163,7 +193,8 @@ def after_servers(sess: session.Session, out: Dict[str, Any], seed: int
                       os.path.join(HERE, "reference", "check.py"), job],
         procs.child_env(sess.platform), timeout=300.0)
     sys.stderr.write(child.tail(6))
-    return child.proc.returncode == 0
+    with open(child.log_path, errors="replace") as f:
+        return reference_numbers(f.read(), child.proc.returncode == 0)
 
 
 def main(argv: Optional[List[str]] = None) -> int:
@@ -187,9 +218,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         out = asyncio.run(drive(sess, mix, args))
         sess.stop()                       # frees the chip(s)
         run: session.RunData = out["run"]
-        reference_ok = True
+        compared: Dict[str, Any] = {}
         if args.trace:
-            reference_ok = after_servers(sess, out, args.seed)
+            compared = after_servers(sess, out, args.seed)
     except BaseException as e:
         sess.stop()
         if not isinstance(e, (procs.BenchFailure, SystemExit)):
@@ -230,9 +261,14 @@ def main(argv: Optional[List[str]] = None) -> int:
                 return 1
             metrics[m["name"]] = {"value": values[m["name"]],
                                   "unit": m["unit"]}
+    compared = {"failed_requests": {"value": len(failures) - bool(hits),
+                                    "at_most": 0},
+                "prefix_cache_hits": {"value": int(hits or 0), "at_most": 0},
+                **compared}
     device = dict(run.device)
     result: Dict[str, Any] = {
-        "correct": not failures and reference_ok,
+        "correct": not failures and compared.get(
+            "reference_verdict", {"value": 1})["value"] == 1,
         "attempted": len(run.judged()), "failed": len(failures),
         "metrics": metrics, "device": device,
         "workload": cell["name"], "seed": args.seed,
@@ -243,6 +279,10 @@ def main(argv: Optional[List[str]] = None) -> int:
         result["breakdown"] = {
             "device_ops": tracered.top(run.trace["classes"]),
             "idle_gaps": tracered.top(run.trace["idle_gaps"])}
+    result["compared"] = compared          # last in the line, and on stderr
+    for name, doc in compared.items():
+        print(f"compared {name}: {json.dumps(doc)}", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

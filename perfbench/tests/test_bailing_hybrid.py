@@ -161,7 +161,8 @@ def made_scoped_ops():
              [d + "attn.kda.step/mul:", 180 * US, 120 * US],
              [d + "state.update/select_n:", 300 * US, 20 * US],
              [d + "attn.mla/attn.kv_update/select_n:", 320 * US, 10 * US],
-             [d + "dot_general:", 330 * US, 70 * US],
+             [d + "head.unembed/dot_general:", 330 * US, 20 * US],
+             [d + "dot_general:", 350 * US, 50 * US],
              [p + "attn.kda.prefill/dot_general:", 500 * US, 60 * US],
              [p + "moe.experts/gmm/pallas_call:", 560 * US, 40 * US]]]
 
@@ -180,10 +181,21 @@ def test_scope_reduction_on_a_made_trace():
 
 
 def made_run(tmp_path):
-    """A traced run of the cell: 10 decode programs of 8 steps in the
-    slice; over the window 800 steps in 100 chunks touched 64,000 experts."""
+    """A traced run of the cell: 80 decode steps between the slice's two
+    stamps, which touched 6,400 experts for 1,280 held rows; over the window
+    800 steps in 100 chunks touched 64,000 experts."""
     trace_dir = tmp_path / "trace-w0"
     trace_dir.mkdir()
+
+    def stamp(steps, touched, rows):
+        return {"models": {procs.MODEL: {
+            "decode_steps": steps, "moe": {
+                "experts_touched": touched,
+                "decode_assignments_held": rows}}}}
+
+    (trace_dir / "counters.json").write_text(json.dumps({
+        "start": stamp(1500, 40000, 9000),
+        "stop": stamp(1580, 46400, 10280)}))
     (tmp_path / "scopes-w0.json").write_text(json.dumps(
         scopes.reduce_scopes(made_scoped_ops())))
 
@@ -215,34 +227,36 @@ def reader(name):
 def ling_metrics():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return [m["name"] for m in json.load(f)["per_layer"]
-                if m.get("workloads") == [CELL]]
+                if CELL in m.get("workloads", [])]
 
 
 def test_the_readers_on_a_made_run(tmp_path):
     run = made_run(tmp_path)
     assert scopes.decode_steps_in_slice(run) == pytest.approx(80.0)
-    assert reader("model.decode_step_ms.ling")(run) == pytest.approx(5e-3)
-    assert reader("moe.experts_time_share.ling")(run) == pytest.approx(38.0)
+    assert reader("model.decode_step_ms.overload")(run) == pytest.approx(5e-3)
+    assert reader("moe.experts_time_share.overload")(run) == pytest.approx(38.0)
     assert reader("kda.time_share.ling")(run) == pytest.approx(36.0)
-    assert reader("state.update_time_share.ling")(run) == pytest.approx(4.0)
-    assert reader("moe.held_assignment_share.ling")(run) == pytest.approx(25.)
-    assert reader("moe.experts_touched_per_step.ling")(run) == \
+    assert reader("state.update_time_share.overload")(run) == pytest.approx(4.0)
+    assert reader("moe.held_assignment_share.overload")(run) == pytest.approx(25.)
+    assert reader("moe.experts_touched_per_step.overload")(run) == \
         pytest.approx(80.0)
     # 80 experts a step x 80 steps in the slice x 11.8 MB at 819 GB/s, over
     # the 150 us (scope) or 100 us (kernel) the made trace gives them
     counts = families.counts(run.config)
     cost = counts.expert_stream_cost(run.config, 80 * 80, 16 * 80)
     least = cost["bytes"] / 819e9
-    assert reader("moe.expert_stream_roofline.ling")(run) == \
+    assert reader("moe.expert_stream_roofline.overload")(run) == \
         pytest.approx(100 * least / 150e-6)
-    assert reader("moe_gmm_roofline.ling")(run) == \
+    assert reader("moe_gmm_roofline.overload")(run) == \
         pytest.approx(100 * least / 100e-6)
+    assert reader("head.time_share.overload")(run) == pytest.approx(4.0)
     assert reader("device.idle_share.overload")(run) == pytest.approx(50.0)
 
 
 def test_the_readers_read_nothing_from_another_program(tmp_path):
-    """A traced Mistral run (the parent's program: no such scope, no such
-    counter): every reader of the new cell returns None and none raises."""
+    """A traced run of another program (the dense decoder's: no such scope,
+    no such counter, no stamps): every reader of the cell's own mechanism
+    returns None and none raises."""
     trace_dir = tmp_path / "trace-w0"
     trace_dir.mkdir()
     (tmp_path / "scopes-w0.json").write_text(json.dumps(scopes.reduce_scopes(
@@ -251,7 +265,7 @@ def test_the_readers_read_nothing_from_another_program(tmp_path):
     old = {"models": {procs.MODEL: {"live_slots": 3,
                                     "kv": {"utilization": 0.4}}}}
     run = RunData(
-        config=session.load_config("mistral-7b-int4"), mix={}, records=[],
+        config=cut(), mix={}, records=[],
         t_open=0.0, t_close=51.0, setup={}, device={"kind": "TPU v5 lite"},
         workers_before={"w0": old}, workers_after={"w0": old},
         trace_dirs={"w0": str(trace_dir)},

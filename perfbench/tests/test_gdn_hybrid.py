@@ -384,12 +384,12 @@ def reader(name):
     return mod.read
 
 
-NEW_HERE = ["model.decode_step_ms.olmo", "model.prefill_time_share.olmo",
+NEW_HERE = ["model.decode_step_ms.overload", "model.prefill_time_share.overload",
             "gdn.time_share.olmo", "gdn.prefill_time_share.olmo",
-            "attn.full_time_share.olmo", "state.update_time_share.olmo",
-            "mlp.time_share.olmo", "head.time_share.olmo",
-            "attn.full_table_live_share.olmo",
-            "model.decode_stream_roofline.olmo",
+            "attn.full_time_share.overload", "state.update_time_share.overload",
+            "mlp.time_share.olmo", "head.time_share.overload",
+            "attn.full_table_live_share.overload",
+            "model.decode_stream_roofline.overload",
             "attn.full_decode_roofline.olmo", "gdn.state_roofline.olmo"]
 
 
@@ -398,24 +398,24 @@ def test_the_readers_on_a_made_run(tmp_path):
     # the steps are the kernel's calls in the slice, not whole programs
     assert scopes.decode_steps_in_slice(run) == pytest.approx(160.0)
     assert scopes_gdn.steps_in_slice(run) == pytest.approx(150.0)
-    assert reader("model.decode_step_ms.olmo")(run) == pytest.approx(16.0)
-    assert reader("model.prefill_time_share.olmo")(run) == pytest.approx(10.)
+    assert reader("model.decode_step_ms.overload")(run) == pytest.approx(16.0)
+    assert reader("model.prefill_time_share.overload")(run) == pytest.approx(10.)
     assert reader("gdn.time_share.olmo")(run) == pytest.approx(34.0)
     assert reader("gdn.prefill_time_share.olmo")(run) == pytest.approx(16.0)
-    assert reader("attn.full_time_share.olmo")(run) == pytest.approx(18.0)
-    assert reader("state.update_time_share.olmo")(run) == pytest.approx(2.0)
+    assert reader("attn.full_time_share.overload")(run) == pytest.approx(18.0)
+    assert reader("state.update_time_share.overload")(run) == pytest.approx(2.0)
     assert reader("mlp.time_share.olmo")(run) == pytest.approx(36.0)
-    assert reader("head.time_share.olmo")(run) == pytest.approx(8.0)
-    assert reader("attn.full_table_live_share.olmo")(run) == \
+    assert reader("head.time_share.overload")(run) == pytest.approx(8.0)
+    assert reader("attn.full_table_live_share.overload")(run) == \
         pytest.approx(100.0 * 16000 / (8 * 2064))
     counts = families.counts(run.config)
     # the slice's own rows a step (12,000), not the window's (16,000)
     assert scopes_gdn.per_slice_step(run, "attn", "full_context_rows") == \
         pytest.approx(12000.0)
     whole = counts.decode_stream_cost(run.config, 150, 12000 * 150, 8 * 150)
-    assert reader("model.decode_stream_roofline.olmo")(run) == \
+    assert reader("model.decode_stream_roofline.overload")(run) == \
         pytest.approx(100 * whole["bytes"] / 819e9 / 2.4)
-    assert 60 < reader("model.decode_stream_roofline.olmo")(run) < 70
+    assert 60 < reader("model.decode_stream_roofline.overload")(run) < 70
     kv = counts.full_decode_cost(run.config, 12000 * 150)
     assert reader("attn.full_decode_roofline.olmo")(run) == \
         pytest.approx(100 * kv["bytes"] / 819e9 / 25e-6)
@@ -425,7 +425,7 @@ def test_the_readers_on_a_made_run(tmp_path):
     assert reader("device.idle_share.overload")(run) == pytest.approx(25.0)
     # without the worker's stamps (an earlier program): no share of a peak
     os.remove(os.path.join(run.trace_dirs["w0"], "counters.json"))
-    for name in ("model.decode_stream_roofline.olmo",
+    for name in ("model.decode_stream_roofline.overload",
                  "attn.full_decode_roofline.olmo", "gdn.state_roofline.olmo"):
         assert reader(name)(run) is None
 
@@ -452,7 +452,7 @@ def test_the_readers_read_nothing_from_another_program(tmp_path):
         (tmp_path / "scopes-gdn-w0.json").write_text(json.dumps(
             scopes_gdn.reduce_scopes(ops)))
         run = RunData(
-            config=session.load_config(cfg_name), mix={}, records=[],
+            config=cut(), mix={}, records=[],
             t_open=0.0, t_close=51.0, setup={},
             device={"kind": "TPU v5 lite"},
             workers_before={"w0": old}, workers_after={"w0": old},

@@ -214,4 +214,3 @@ def test_readers_return_nothing_for_a_program_without_the_marks():
     assert spanreaders.compile_delta(run, "backend_compiles") is None
     assert spanreaders.compile_at_open(run, "backend_compile_s") is None
     assert spanreaders.host_busy_share_pct(run) is None
-    assert spanreaders.idle_attributed_share_pct(run) is None

@@ -32,7 +32,9 @@ SHARED = [
     "pump.in_flight_mean", "pump.inbox_wait_p50_ms", "worker.shed",
     "engine.occupancy", "engine.host_busy_share", "kv.pool_used_share",
     "kv.copy_time_share", "device.idle_share",
-    "device.between_programs_idle_share", "device.idle_attributed_share"]
+    "device.between_programs_idle_share"]
+FOLDED = re.compile(r'scope_reading\(run, "(\w+)"(?:,\s*(.*?))?\)\s*$',
+                    re.S | re.M)
 
 
 def manifest():
@@ -115,6 +117,57 @@ def test_a_shared_layers_reader_has_one_entry_and_one_file(name):
     listed = {m["name"] for m in man["per_layer"]
               if m["name"] == name or m["name"].startswith(name + ".")}
     assert listed <= {name, f"{name}.overload", f"{name}.steady"}
+
+
+def folded_readers(man):
+    """(entry, function, scope names or None) of every reader that asks the
+    run's own family for its reading (``lib/families.py``
+    ``scope_reading``)."""
+    out = []
+    for m in man["per_layer"]:
+        path = os.path.join(ROOT, "perfbench", "metrics", f"{m['name']}.py")
+        with open(path) as f:
+            found = FOLDED.search(f.read())
+        if found:
+            names = eval(found.group(2)) if found.group(2) else None
+            out.append((m, found.group(1),
+                        (names,) if isinstance(names, str) else names))
+    return out
+
+
+def test_a_folded_reader_lists_the_cells_whose_family_has_its_scopes():
+    """One reader a reading (PR 49): an entry whose reader asks the run's
+    family for ``fn`` lists exactly the ``out_tok_s`` cells whose family's
+    module (``counts/<family>.py`` ``SCOPE_READERS``) has ``fn`` and, for a
+    share of the device's time, names the scopes among its own. A cell a
+    later PR appends is listed under every reading its family has, and
+    under no other."""
+    man = manifest()
+    (tok_s,) = [m for m in man["end_to_end"] if m["name"] == "out_tok_s"]
+    cfg_of = {w["name"]: session.load_config(w["config"])
+              for w in man["workloads"]}
+    folded = folded_readers(man)
+    assert len(folded) >= 15
+    for m, fn, names in folded:
+        assert m["name"].endswith(".overload") and m["moves"] == "out_tok_s"
+        have = []
+        for cell in tok_s["workloads"]:
+            mod = families.scopes(cfg_of[cell])
+            own = getattr(mod, "SHARE_SCOPES", getattr(mod, "SCOPES", ()))
+            if hasattr(mod, fn) and (fn != "share_pct"
+                                     or all(n in own for n in names)):
+                have.append(cell)
+        assert m["workloads"] == have, (m["name"], fn, names)
+
+
+def test_no_reading_is_kept_under_two_names():
+    """A reading several families have stands under ONE name: no entry is
+    another's name with a cell's suffix in place of ``.overload``."""
+    names = {m["name"] for m in manifest()["per_layer"]}
+    for n in names:
+        base, _, suffix = n.rpartition(".")
+        if suffix not in ("overload", "steady") and base:
+            assert f"{base}.overload" not in names, n
 
 
 def test_one_file_for_each_entry():

@@ -352,30 +352,24 @@ def reader(name):
     return mod.read
 
 
-NEW_HERE = ["model.decode_step_ms.kimi", "model.prefill_time_share.kimi",
-            "mla.time_share.kimi", "mla.table_live_share.kimi",
-            "moe.experts_time_share.kimi", "moe.route_time_share.kimi",
-            "moe.experts_touched_per_step.kimi",
-            "moe.held_assignment_share.kimi", "head.time_share.kimi",
-            "model.decode_stream_roofline.kimi", "mla.decode_roofline.kimi",
-            "moe.expert_stream_roofline.kimi", "moe_gmm_roofline.kimi"]
+NEW_HERE = ["model.decode_step_ms.overload", "model.prefill_time_share.overload",
+            "mla.time_share.overload", "mla.table_live_share.overload",
+            "moe.experts_time_share.overload", "moe.route_time_share.overload",
+            "moe.experts_touched_per_step.overload",
+            "moe.held_assignment_share.overload", "head.time_share.overload",
+            "model.decode_stream_roofline.overload", "mla.decode_roofline.overload",
+            "moe.expert_stream_roofline.overload", "moe_gmm_roofline.overload"]
 
 
-def test_the_cells_own_entries_and_no_shared_reader_under_its_suffix():
+def test_the_cells_readings_stand_under_the_shared_names():
+    """Every reading of the family is an entry WITHOUT the family's suffix
+    that lists the cell (PR 49: one reader a reading); none is left under
+    ``.kimi``."""
     man = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    own = [m for m in man["per_layer"] if m["name"].endswith(".kimi")]
-    assert [m["name"] for m in own] == NEW_HERE
-    assert all(m["workloads"] == [CELL] and m["moves"] == "out_tok_s"
-               for m in own)
-    shared = [m["name"] for m in man["per_layer"]
-              if m["name"].endswith(".overload")
-              and CELL in m.get("workloads", ())]
-    assert len(shared) == 17
-    assert not [n for n in NEW_HERE
-                if n.rsplit(".", 1)[0] + ".overload" in shared]
-    # (no assertion on WHERE in a list the cell stands: the next cell is
-    # appended after it; ``test_swa_moe.py`` asks its own to be the last
-    # workload and fails since this cell was appended, PERF.md section 7)
+    by = {m["name"]: m for m in man["per_layer"]}
+    assert all(CELL in by[n]["workloads"] and by[n]["moves"] == "out_tok_s"
+               for n in NEW_HERE)
+    assert not [n for n in by if n.endswith(".kimi")]
 
 
 def test_the_readers_on_a_made_run(tmp_path):
@@ -383,17 +377,17 @@ def test_the_readers_on_a_made_run(tmp_path):
     # the steps are the kernel's calls in the slice, not whole programs
     assert scopes.decode_steps_in_slice(run) == pytest.approx(160.0)
     assert scopes_mla_share.steps_in_slice(run) == pytest.approx(150.0)
-    assert reader("model.decode_step_ms.kimi")(run) == pytest.approx(12.0)
-    assert reader("model.prefill_time_share.kimi")(run) == pytest.approx(30.0)
-    assert reader("mla.time_share.kimi")(run) == pytest.approx(37.2)
-    assert reader("moe.experts_time_share.kimi")(run) == pytest.approx(38.0)
-    assert reader("moe.route_time_share.kimi")(run) == pytest.approx(4.8)
-    assert reader("head.time_share.kimi")(run) == pytest.approx(8.0)
-    assert reader("moe.experts_touched_per_step.kimi")(run) == \
+    assert reader("model.decode_step_ms.overload")(run) == pytest.approx(12.0)
+    assert reader("model.prefill_time_share.overload")(run) == pytest.approx(30.0)
+    assert reader("mla.time_share.overload")(run) == pytest.approx(37.2)
+    assert reader("moe.experts_time_share.overload")(run) == pytest.approx(38.0)
+    assert reader("moe.route_time_share.overload")(run) == pytest.approx(4.8)
+    assert reader("head.time_share.overload")(run) == pytest.approx(8.0)
+    assert reader("moe.experts_touched_per_step.overload")(run) == \
         pytest.approx(35.0)
-    assert reader("moe.held_assignment_share.kimi")(run) == \
+    assert reader("moe.held_assignment_share.overload")(run) == \
         pytest.approx(3.125)
-    assert reader("mla.table_live_share.kimi")(run) == \
+    assert reader("mla.table_live_share.overload")(run) == \
         pytest.approx(100.0 * 80000 / (32 * 21 * 128 + 32 * 16))
     counts = families.counts(run.config)
     # the slice's own rows a step (64,000), not the window's (80,000)
@@ -401,16 +395,16 @@ def test_the_readers_on_a_made_run(tmp_path):
         run, "mla", "decode_context_rows") == pytest.approx(64000.0)
     whole = counts.decode_stream_cost(
         run.config, 150, 36 * 150, 48 * 150, 64000 * 150, 32 * 150)
-    assert reader("model.decode_stream_roofline.kimi")(run) == \
+    assert reader("model.decode_stream_roofline.overload")(run) == \
         pytest.approx(100 * whole["bytes"] / 819e9 / 1.8)
-    assert 60 < reader("model.decode_stream_roofline.kimi")(run) < 75
+    assert 60 < reader("model.decode_stream_roofline.overload")(run) < 75
     kv = counts.mla_decode_cost(run.config, 64000 * 150)
-    assert reader("mla.decode_roofline.kimi")(run) == \
+    assert reader("mla.decode_roofline.overload")(run) == \
         pytest.approx(100 * kv["bytes"] / 819e9 / 0.2)
     ex = counts.expert_stream_cost(run.config, 36 * 150, 48 * 150)
-    assert reader("moe.expert_stream_roofline.kimi")(run) == \
+    assert reader("moe.expert_stream_roofline.overload")(run) == \
         pytest.approx(100 * ex["bytes"] / 819e9 / 90e-6)
-    assert reader("moe_gmm_roofline.kimi")(run) == \
+    assert reader("moe_gmm_roofline.overload")(run) == \
         pytest.approx(100 * ex["bytes"] / 819e9 / 80e-6)
     # without the worker's stamps (an earlier program): no share of a peak
     os.remove(os.path.join(run.trace_dirs["w0"], "counters.json"))
@@ -445,7 +439,7 @@ def test_the_readers_read_nothing_from_another_program(tmp_path):
         (tmp_path / "scopes-w0.json").write_text(json.dumps(
             scopes.reduce_scopes(ops)))
         run = RunData(
-            config=session.load_config(cfg_name), mix={}, records=[],
+            config=cut(), mix={}, records=[],
             t_open=0.0, t_close=51.0, setup={},
             device={"kind": "TPU v5 lite"},
             workers_before={"w0": old}, workers_after={"w0": old},

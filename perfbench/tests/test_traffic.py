@@ -172,3 +172,46 @@ def test_strata_steady_what_the_head_of_the_queue_asks_for():
     with_strata, without = heads(base), heads(dict(base, strata=0))
     assert with_strata < 0.05 < without
     assert without > 2 * with_strata
+
+
+OVERLOAD = [n for n in MIXES if "overload" in n]
+
+
+@pytest.mark.parametrize("name", OVERLOAD)
+def test_every_overload_mix_offers_every_seed_the_same_head(name):
+    """All seven overload mixes carry ``strata`` 6 (PR 49): at the mix's own
+    rate and the benchmark's 51 s every six consecutive arrivals of a phase
+    hold one prompt and one output length of each sextile of that phase's
+    lengths; any two seeds have offered the same tokens after any whole
+    number of sixes to within two of the mix's largest requests (the
+    lengths differ inside a sextile, and the sum of that walks), and the
+    same tokens exactly by the window's end."""
+    mix = mix_for(name)
+    assert len(OVERLOAD) == 7 and mix["strata"] == 6
+    k, by_seed = 6, {}
+    for seed in (1, 3000000019, 2 ** 31 + 11):
+        reqs = traffic.schedule(mix, seed, 51.0, 1000)
+        for phase in traffic.PHASES:
+            rs = [r for r in reqs if r.phase == phase]
+            n = len(rs)
+            for what, dist in ((lambda r: len(r.prompt), mix["prompt"]),
+                               (lambda r: r.output_len, mix["output"])):
+                q = traffic.lognormal_quantiles(dist, n)
+                bands = [q[i * n // k:(i + 1) * n // k] for i in range(k)]
+                got = [what(r) for r in rs]
+                assert sorted(got) == q
+                for b in range(min(len(band) for band in bands)):
+                    block = sorted(got[b * k:(b + 1) * k])
+                    assert all(band[0] <= x <= band[-1]
+                               for x, band in zip(block, bands)), (seed, b)
+        by_seed[seed] = [r for r in reqs if r.phase == "window"]
+    most = mix["prompt"]["max"] + mix["output"]["max"]
+    (first, *others) = by_seed.values()
+    for other in others:
+        assert len(other) == len(first)
+        for upto in range(k, len(first) + 1, k):
+            a, b = (sum(len(r.prompt) + r.output_len for r in w[:upto])
+                    for w in (first, other))
+            assert abs(a - b) <= 2 * most, (name, upto)
+        assert sum(len(r.prompt) + r.output_len for r in first) == \
+            sum(len(r.prompt) + r.output_len for r in other)

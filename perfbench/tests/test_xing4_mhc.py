@@ -325,10 +325,23 @@ def test_the_programs_name_the_scope_the_helper_reads():
 
 
 def made_run(tmp_path):
-    """A traced run of the cell: 10 decode programs of 16 steps in the
-    slice; over the window 1,600 steps in 100 chunks."""
+    """A traced run of the cell: 160 decode steps between the slice's two
+    stamps (150 experts, 192 held rows and 28,000 latent rows a step); over
+    the window 1,600 steps in 100 chunks."""
     trace_dir = tmp_path / "trace-w0"
     trace_dir.mkdir()
+
+    def stamp(steps, touched, rows, ctx):
+        return {"models": {procs.MODEL: {
+            "decode_steps": steps,
+            "moe": {"experts_touched": touched,
+                    "decode_assignments_held": rows},
+            "mla": {"decode_context_rows": ctx}}}}
+
+    (trace_dir / "counters.json").write_text(json.dumps({
+        "start": stamp(2000, 5000, 7000, 10 ** 6),
+        "stop": stamp(2160, 5000 + 150 * 160, 7000 + 192 * 160,
+                      10 ** 6 + 28000 * 160)}))
     (tmp_path / "scopes-w0.json").write_text(json.dumps(
         scopes.reduce_scopes(RECORDED)))
     (tmp_path / "scopes-mhc-w0.json").write_text(json.dumps(
@@ -368,51 +381,52 @@ def reader(name):
 def test_the_readers_on_a_made_run(tmp_path):
     run = made_run(tmp_path)
     assert scopes.decode_steps_in_slice(run) == pytest.approx(160.0)
-    assert reader("model.decode_step_ms.xing")(run) == pytest.approx(1e-2)
-    assert reader("model.prefill_time_share.xing")(run) == pytest.approx(30.)
+    assert reader("model.decode_step_ms.overload")(run) == pytest.approx(1e-2)
+    assert reader("model.prefill_time_share.overload")(run) == pytest.approx(30.)
     assert reader("mhc.time_share.xing")(run) == pytest.approx(24.0)
-    assert reader("head.time_share.xing")(run) == pytest.approx(8.0)
+    assert reader("head.time_share.overload")(run) == pytest.approx(8.0)
     assert reader("mhc.decode_step_ms.xing")(run) == \
         pytest.approx(1e3 * 60e-6 / 160)
-    assert reader("mla.time_share.xing")(run) == pytest.approx(32.0)
-    assert reader("moe.experts_time_share.xing")(run) == pytest.approx(28.0)
-    assert reader("moe.route_time_share.xing")(run) == pytest.approx(4.0)
-    assert reader("moe.experts_touched_per_step.xing")(run) == \
+    assert reader("mla.time_share.overload")(run) == pytest.approx(32.0)
+    assert reader("moe.experts_time_share.overload")(run) == pytest.approx(28.0)
+    assert reader("moe.route_time_share.overload")(run) == pytest.approx(4.0)
+    assert reader("moe.experts_touched_per_step.overload")(run) == \
         pytest.approx(150.0)
-    assert reader("mla.table_live_share.xing")(run) == \
+    assert reader("mla.table_live_share.overload")(run) == \
         pytest.approx(100.0 * 28000 / (8 * 8704))
     counts = families.counts(run.config)
     # 28,000 live rows a step x 160 steps in the slice, over the 60 us the
     # recorded paths give attn.mla in decode programs
     mla = counts.mla_decode_cost(run.config, 28000 * 160)
-    assert reader("mla.decode_roofline.xing")(run) == \
+    assert reader("mla.decode_roofline.overload")(run) == \
         pytest.approx(100 * mla["bytes"] / 819e9 / 60e-6)
-    cost = counts.expert_stream_cost(run.config, 150 * 160,
-                                     307300 / 1600 * 160)
-    assert reader("moe_gmm_roofline.xing")(run) == \
+    cost = counts.expert_stream_cost(run.config, 150 * 160, 192 * 160)
+    assert reader("moe_gmm_roofline.overload")(run) == \
         pytest.approx(100 * cost["bytes"] / 819e9 / 100e-6)
-    assert reader("moe.expert_stream_roofline.xing")(run) == \
-        reader("moe_gmm_roofline.xing")(run)
+    assert reader("moe.expert_stream_roofline.overload")(run) == \
+        reader("moe_gmm_roofline.overload")(run)
     assert reader("device.idle_share.overload")(run) == pytest.approx(50.0)
 
 
 def test_the_readers_read_nothing_from_another_program(tmp_path):
-    """A traced Ling run of the PARENT's program (its scopes, no
-    ``resid.mhc``, no ``mla`` counters): the readers this PR brings return
-    None and none raises; so does a traced Mistral run."""
+    """Another program's trace under this family's configuration (the Ling
+    program's scopes, then the dense decoder's: no ``resid.mhc``, no ``mla``
+    counters, no stamps): the family's own readers return None and none
+    raises."""
     trace_dir = tmp_path / "trace-w0"
     trace_dir.mkdir()
     old = {"models": {procs.MODEL: {
         "decode_steps": 10, "decode_chunks": 1, "live_slots": 3,
         "kv": {"utilization": 0.4}}}}
     new_here = ["mhc.time_share.xing", "mhc.decode_step_ms.xing",
-                "model.prefill_time_share.xing", "mla.decode_roofline.xing",
-                "mla.table_live_share.xing"]
+                "model.prefill_time_share.overload",
+                "mla.decode_roofline.overload",
+                "mla.table_live_share.overload"]
     for cfg_name, ops in (
-            ("ling-3.0-flash-ep4",
+            ("xing4.0-29b-a4b-pp1",
              [[[D + "attn.mla/dot_general:", 0, 9.0],
                [D + "moe.experts/gmm/pallas_call:", 10.0, 5.0]]]),
-            ("mistral-7b-int4",
+            ("xing4.0-29b-a4b-pp1",
              [[[D + "attn.kv_update/scatter:", 0, 9.0], ["", 10.0, 5.0]]])):
         for f in os.listdir(tmp_path):
             if f.startswith("scopes-"):
@@ -434,4 +448,4 @@ def test_the_readers_read_nothing_from_another_program(tmp_path):
             assert reader(name)(run) is None, (cfg_name, name)
     # (the last run: no op under any of lib/scopes.py's scopes, the head's
     # two among them, so nothing to take a share of)
-    assert reader("head.time_share.xing")(run) is None
+    assert reader("head.time_share.overload")(run) is None
