@@ -412,6 +412,12 @@ def _scanned(params: Params):
 MOE_ROWS = 6144
 
 
+def moe_parts(n: int) -> int:
+    """The equal parts a prefill of ``n`` tokens runs its experts in: the
+    fewest of at most ``MOE_ROWS`` tokens each that divide ``n``."""
+    return next(k for k in range(-(-n // MOE_ROWS), n + 1) if n % k == 0)
+
+
 def _moe(spec: ModelSpec, blk: Params, experts: Params, p, x, valid,
          moe_impl):
     """The routed experts of period ``p`` over RMSNorm(x), x [N, D] ->
@@ -420,7 +426,7 @@ def _moe(spec: ModelSpec, blk: Params, experts: Params, p, x, valid,
     blk = dict(blk, **experts)
     offset = p * spec.experts_held[1]
     n = x.shape[0]
-    parts = next(k for k in range(-(-n // MOE_ROWS), n + 1) if n % k == 0)
+    parts = moe_parts(n)
     if parts == 1:
         return moe_block(spec, blk, h, valid, moe_impl, expert_offset=offset)
     y, c = lax.map(

@@ -17,7 +17,12 @@ add is left out. No capacity, nothing
 dropped. The product is GROUPED: the (token, choice) pairs that landed on a
 held expert are sorted by expert and each expert multiplies only its own
 rows (``grouped_matmul``), so operations follow the routed tokens and, in
-decode, the weight bytes read follow the experts that got one.
+decode, the weight bytes read follow the experts that got one. In a prefill
+an expert gets hundreds of rows and the product is bound by operations, if
+the expert's matrix crosses HBM once and not once for every row tile: the
+kernel's tiles follow the rows an expert can expect (``gmm_tiling``: from
+the call's shapes, K whole inside a VMEM budget where a prefill's rows
+arrive, the decode step's tiles unchanged where few do).
 
 Two bodies, chosen from the spec (``moe_body``). ``moe_block`` gathers and
 multiplies rows for ALL ``N x k`` assignments and masks the ones not held:
@@ -63,10 +68,64 @@ def route(spec, x: jnp.ndarray, w_router: jnp.ndarray, bias=None
     return idx.astype(jnp.int32), g
 
 
-def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
-    """Tiles of the Mosaic grouped matmul: whole row blocks up to 128, the
-    largest K and N tiles (multiples of 128 that divide) whose weight tile
-    stays near 2 MB, so a step's DMA is long against its issue cost."""
+# The scoped VMEM a Mosaic kernel gets on this chip unless it asks for more
+# (megablox asks for nothing), and the part of it the grouped product's
+# tiles may take: the compiler keeps vector temporaries of its own there (a
+# spilled operand tile, the float32 product before it is added), which
+# ``gmm_vmem_bytes`` does not see: (256, 7168, 256) counts 14.75 MiB and is
+# refused at 18. ``tests/test_tpu_compile.py`` compiles every served width
+# pair's tiles for the v5e.
+GMM_SCOPED_VMEM = 16 * 2 ** 20
+GMM_VMEM_BUDGET = GMM_SCOPED_VMEM * 3 // 4
+# rows an expert (a call's rows over the experts that can hold them) from
+# which a call is a prefill's: two row tiles' worth. Alone on the chip K
+# whole wins from 64 rows an expert up
+# (``docs/sweeps/pr52-gmm-prefill-tiles.txt``); 256 is the least that leaves
+# every call with few REAL rows an expert as it was: a decode step, Ling's
+# prefills (128 by the shapes, a quarter of them held), Kimi's held blocks
+# (171 at most).
+GMM_PREFILL_ROWS = 256
+
+
+def gmm_vmem_bytes(tm: int, tk: int, tn: int) -> int:
+    """VMEM the Mosaic grouped matmul's pipeline holds at these tiles: the
+    bfloat16 lhs and rhs tiles and the float32 out tile, two buffers each,
+    and the float32 accumulator."""
+    return 2 * (2 * tm * tk + 2 * tk * tn + 4 * tm * tn) + 4 * tm * tn
+
+
+def gmm_widest_n_tile(tm: int, k: int, n: int) -> int:
+    """The widest N tile (a multiple of 128 that divides ``n``) that fits
+    ``GMM_VMEM_BUDGET`` beside K whole and a row tile of ``tm``; 0 where
+    none does."""
+    return max((t for t in range(128, n + 1, 128) if n % t == 0
+                and gmm_vmem_bytes(tm, k, t) <= GMM_VMEM_BUDGET), default=0)
+
+
+def gmm_tiling(m: int, k: int, n: int, groups: int) -> Tuple[int, int, int]:
+    """Tiles ``(tm, tk, tn)`` of the Mosaic grouped matmul (grid: N tiles x
+    the row tiles that hold rows x K tiles, K innermost) for ``m`` sorted
+    rows over ``groups`` experts, from the shapes alone. Whole row blocks up
+    to 128 in every case (what the callers pad and block to).
+
+    Few rows an expert (a decode step; a prefill that sends a held expert a
+    handful): the product is bound by the weight bytes of the experts
+    touched, every tile of which is read once. The largest K tile up to
+    1,280 and N tile up to 768 (multiples of 128 that divide), so a step's
+    DMA (0.6 MB of weights at Mellum's widths, 2 MB at Ling's) is long
+    against its issue cost.
+
+    ``GMM_PREFILL_ROWS`` rows an expert or more (a prefill): an expert's
+    rows span several row tiles, and the pipeline fetches a weight tile
+    again at every grid step whose block index differs from the last one's,
+    which with two K tiles or more is every step: the expert's matrix would
+    cross HBM once for every 128 rows. So K is taken whole (the weight block
+    then repeats across the expert's row tiles and is fetched once) with the
+    widest N tile that fits ``GMM_VMEM_BUDGET`` (the lhs tile is re-read
+    once for every N tile). The row tile stays 128: alone on the chip 256
+    rows read within 2 % of 128 at the same N tile and lose where the budget
+    then narrows it, 512 lose 15 %. Where K whole fits beside no N tile the
+    few-rows tiles stand."""
     def tile(dim: int, cap: int) -> int:
         best = 128 if dim % 128 == 0 else dim
         for t in range(128, min(dim, cap) + 1, 128):
@@ -74,14 +133,22 @@ def gmm_tiling(m: int, k: int, n: int) -> Tuple[int, int, int]:
                 best = t
         return best
 
-    return min(m, 128), tile(k, 1280), tile(n, 768)
+    tm = min(m, 128)
+    if m // groups >= GMM_PREFILL_ROWS:
+        tn = gmm_widest_n_tile(tm, k, n)
+        if tn:
+            return tm, k, tn
+    return tm, tile(k, 1280), tile(n, 768)
 
 
 def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
-                   group_sizes: jnp.ndarray, impl: str) -> jnp.ndarray:
+                   group_sizes: jnp.ndarray, impl: str,
+                   experts: int = 0) -> jnp.ndarray:
     """Rows of ``lhs`` [M, K] sorted by group; group g's rows times
     ``rhs[g]`` [G, K, N]. Rows past ``sum(group_sizes)`` come back as
-    unspecified values (the caller masks them). float32 out.
+    unspecified values (the caller masks them). float32 out. ``experts``:
+    how many of the G groups can hold rows (a stacked tree's other layers
+    hold none); 0 = all. ``M`` is whole blocks of 128 rows, or fewer rows.
 
     ``impl``: "xla" = ``jax.lax.ragged_dot``; "gmm" = the Mosaic grouped
     matmul (``jax.experimental.pallas.ops.tpu.megablox``), whose grid visits
@@ -95,7 +162,7 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
     m, k = lhs.shape
     with jax.named_scope("gmm"):
         return gmm(lhs, rhs, group_sizes.astype(jnp.int32), jnp.float32,
-                   gmm_tiling(m, k, rhs.shape[2]),
+                   gmm_tiling(m, k, rhs.shape[2], experts or rhs.shape[0]),
                    interpret=impl == "gmm_interpret")
 
 
@@ -149,10 +216,12 @@ def moe_block(spec, blk: Dict[str, jnp.ndarray], x: jnp.ndarray,
                 (expert_offset,))
     with jax.named_scope("moe.experts"):
         rows = x[tok]                                          # [M, D]
-        gu = grouped_matmul(rows, blk["w_gate_up"], groups, impl)
+        held = sizes.shape[0]     # ``groups`` may count other layers' too
+        gu = grouped_matmul(rows, blk["w_gate_up"], groups, impl, held)
         gate, up = jnp.split(gu, 2, axis=-1)
         h = jnp.where(row_ok[:, None], jax.nn.silu(gate) * up, 0.0)
-        y = grouped_matmul(h.astype(x.dtype), blk["w_down"], groups, impl)
+        y = grouped_matmul(h.astype(x.dtype), blk["w_down"], groups, impl,
+                           held)
         g_sorted = jnp.pad(gates.reshape(-1)[order], (0, pad))
         y = jnp.where(row_ok[:, None], y * g_sorted[:, None], 0.0)
         # back to (token, choice) order, then the k choices of a token add

@@ -13,7 +13,9 @@ of a per-layer family (``fused``: heads, pages a row, side window, stacked
 layers: 30 MHA heads of 128, 8 rows of up to 6,144 positions, timed alone);
 the latent rows' decode read of both MLA families (``latent``: 32 heads over
 rows of 640 lanes, 8 rows of up to 8,704 positions in a 7-layer pool, timed
-alone per pages a block); the five int4
+alone per pages a block); the grouped expert product alone at a
+prefill's rows (``gmm_prefill``: the five served width pairs, 64-2,048 rows
+an expert, few-rows tiles against K whole); the five int4
 payload shapes of mistral-7b (N=32,768 for the lm_head) at M=128 (the
 prefill bucket of ``ops.int4_matmul.blocks_for``) and, for the 2-D and
 stacked legs, at M=8 (the decode bucket: what a served decode step runs). Tolerances are the ones the CPU parity tests use
@@ -73,7 +75,14 @@ FULL = dict(B=128, H=32, Hkv=8, Dh=128, P=128, ctx=256, W=8, M=128, L=2,
             # sit in the v5e's 128 MiB of VMEM, and then reads above the
             # HBM peak)
             delta=((12, 30, 96, 192, 1), (6, 32, 128, 128, 128),
-                   (12, 32, 128, 128, 128)))
+                   (12, 32, 128, 128, 128)),
+            # the grouped expert product in a prefill: (D, F, experts a
+            # layer) of Mellum, Keye, Xing, Ling (held) and Kimi (held); rows
+            # an expert; the rows at which (row tile, N tile) is swept
+            gmm_widths=((2304, 896, 64), (2048, 768, 128), (3584, 1024, 64),
+                        (2560, 768, 128), (7168, 2048, 12)),
+            gmm_rows=(64, 128, 256, 512, 1024, 2048), gmm_sweep_rows=512,
+            gmm_row_tiles=(128, 256, 512))
 TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
             int4_rows=(32, 3),
             int4_shapes=((128, 256), (256, 128)),
@@ -83,7 +92,9 @@ TINY = dict(B=4, H=4, Hkv=2, Dh=64, P=8, ctx=16, W=4, M=16, L=2,
             kv_prefill_timed=((4, 2, 600, 1024, 700),),
             kv_prefill_sweep=((256, 512, 4),),
             fused=(2, 6, 4, 3), latent=(4, 32, 8, 6, 4, 2),
-            delta=((3, 4, 16, 32, 1), (2, 4, 16, 16, 16)))
+            delta=((3, 4, 16, 32, 1), (2, 4, 16, 16, 16)),
+            gmm_widths=((256, 128, 4),), gmm_rows=(128, 256),
+            gmm_sweep_rows=256, gmm_row_tiles=(128, 256))
 OUT = os.path.join("chiprun_out", "chip_kernels.json")
 
 
@@ -671,6 +682,105 @@ def check_kda_step_inplace(cfg, interpret):
     return " | ".join(details)
 
 
+def check_gmm_prefill(cfg, interpret):
+    """The grouped expert product (``ops/moe_routed.py`` ``grouped_matmul``,
+    jax's Mosaic ``megablox.gmm``) ALONE at a prefill's rows: for each
+    served width pair ``(D, F, experts)`` the gate|up product ``[M, D] x
+    [E, D, 2F]`` and the down product ``[M, F] x [E, F, D]``, M = rows an
+    expert x E sorted rows dealt to the experts by a multinomial draw,
+    bfloat16 in, float32 out. First the tiles ``gmm_tiling`` resolves
+    against a plain product of three experts' rows; then us a call (the host's
+    clock over calls ended by ``block_until_ready``) and the share of 197
+    TFLOP/s for the rows' own operations, under the few-rows tiles (what
+    every call ran before PR 52), the tiles resolved for these rows, K
+    whole with the widest N tile inside the budget at each row tile whatever
+    the rows (where it crosses the few-rows tiles is what
+    ``GMM_PREFILL_ROWS`` is set against), and at ``gmm_sweep_rows`` every
+    (row tile, N tile) the scoped VMEM could hold with K whole."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from distributed_inference_engine_tpu.ops import moe_routed as mr
+
+    rng = np.random.default_rng(52)
+    reps = 1 if interpret else 5
+
+    def inputs(m, k, n, e):
+        ks = jax.random.split(jax.random.key(k + n + m), 2)
+        lhs = jax.random.normal(ks[0], (m, k), jnp.bfloat16)
+        rhs = 0.02 * jax.random.normal(ks[1], (e, k, n), jnp.bfloat16)
+        sizes = jnp.asarray(rng.multinomial(m, np.full(e, 1 / e)), jnp.int32)
+        return lhs, rhs, sizes
+
+    def us_a_call(tiles, args):
+        fn = jax.jit(lambda *a: gmm(*a, jnp.float32, tiles,
+                                    interpret=interpret))
+        jax.block_until_ready(fn(*args))
+        jax.block_until_ready(fn(*args))
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        return 1e6 * (time.perf_counter() - t0) / reps
+
+    errs, lines = [], []
+    for d, f, e in cfg["gmm_widths"]:
+        for name, (k, n) in (("gate|up", (d, 2 * f)), ("down", (f, d))):
+            # parity at the resolved prefill tiles: three experts' rows
+            # against a plain product (``ragged_dot`` on the CPU only: what
+            # XLA makes of it on a TPU at these sizes is not this leg's)
+            m = cfg["gmm_rows"][-1] * e
+            args = inputs(m, k, n, e)
+            got = jax.jit(lambda *a: mr.grouped_matmul(
+                *a, "gmm_interpret" if interpret else "gmm", e))(*args)
+            ends = np.cumsum(np.asarray(args[2]))
+            for g in (0, e // 2, e - 1):
+                rows_g = slice(int(ends[g] - args[2][g]), int(ends[g]))
+                ref = jnp.dot(args[0][rows_g], args[1][g],
+                              preferred_element_type=jnp.float32)
+                errs.append(_close(got[rows_g], ref, 2e-2))
+            del got, ref
+            for rows in cfg["gmm_rows"]:
+                m = rows * e
+                args = inputs(m, k, n, e)
+                flop = 2.0 * m * k * n
+                cands = [("few-rows", mr.gmm_tiling(128, k, n, 1)),
+                         ("resolved", mr.gmm_tiling(m, k, n, e))]
+                cands += [("k-whole", (tm, k, mr.gmm_widest_n_tile(tm, k, n)))
+                          for tm in cfg["gmm_row_tiles"] if m % tm == 0]
+                if rows == cfg["gmm_sweep_rows"]:
+                    cands += [
+                        ("sweep", (tm, k, tn))
+                        for tm in cfg["gmm_row_tiles"]
+                        for tn in range(n, 255, -128)
+                        if n % tn == 0 and m % tm == 0 and
+                        mr.gmm_vmem_bytes(tm, k, tn) <= mr.GMM_SCOPED_VMEM]
+                line = (f"D {d} F {f} E {e} {name} [{m}, {k}] x [{k}, {n}], "
+                        f"{rows} rows an expert:")
+                timed = set()
+                for tag, t in cands:
+                    if t in timed or not t[2]:   # no N tile beside K whole
+                        continue
+                    timed.add(t)
+                    try:
+                        us = us_a_call(t, args)
+                        line += (f" {tag} {t} {us:.0f} us "
+                                 f"{100 * flop / us / 197e6:.1f} %;")
+                    except Exception as exc:   # refused tiles: record, go on
+                        line += f" {tag} {t} refused ({str(exc)[-60:]!r});"
+                print("gmm_prefill: " + line, flush=True)
+                lines.append(line)
+    detail = (f"{len(errs)} experts' rows at the resolved tiles against a "
+              f"plain product, max|err| {max(errs):.2e}")
+    if interpret:
+        detail += " (interpreted: the times are the host's)"
+    with open(os.path.join("chiprun_out", "gmm_prefill.txt"), "w") as fh:
+        fh.write("\n".join([detail] + lines) + "\n")
+    return detail + " | " + " | ".join(lines)
+
+
 # name -> (check, on the default serving path?)
 CHECKS = {
     "int4_matmul_2d": (check_int4_2d, True),
@@ -683,6 +793,7 @@ CHECKS = {
     "mla_prefill": (check_mla_prefill, True),
     "kv_prefill": (check_kv_prefill, True),
     "kda_step_inplace": (check_kda_step_inplace, True),
+    "gmm_prefill": (check_gmm_prefill, True),
 }
 
 

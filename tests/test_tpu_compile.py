@@ -737,3 +737,33 @@ def test_the_expert_share_family_fits_with_32_slots_and_its_held_rows(
     text = pre.as_text()
     assert "[49152,7168]" not in text and "[49152,4096]" not in text
     assert "[2048,7168]" in text
+
+
+# D, F, experts a layer holds, rows an expert of the call
+@pytest.mark.parametrize("d,f,e,rows", [
+    (2304, 896, 64, 512), (2304, 896, 64, 256),     # Mellum: a 4,096 / 2,048
+    (2048, 768, 128, 2048), (2048, 768, 128, 256),  # Keye: 32,768 / 4,096
+    (3584, 1024, 64, 512), (3584, 1024, 64, 256),   # Xing: 8,192 / 4,096
+    (2560, 768, 128, 512), (7168, 2048, 12, 512),   # Ling's, Kimi's widths
+    (2304, 896, 64, 1), (7168, 2048, 12, 10),       # decode steps
+])
+def test_the_grouped_products_tiles_compile_for_v5e(one_chip, d, f, e, rows):
+    """The Mosaic grouped matmul at the tiles ``gmm_tiling`` resolves, both
+    products of every served width pair: the compiler's own VMEM (a spilled
+    operand tile, the float32 product) has to fit beside what
+    ``gmm_vmem_bytes`` counts, inside the default scoped limit."""
+    from distributed_inference_engine_tpu.ops import moe_routed as mr
+
+    m = -(-rows * e // 128) * 128
+    for k, n in ((d, 2 * f), (f, d)):
+        tm, tk, tn = mr.gmm_tiling(m, k, n, e)
+        if rows >= mr.GMM_PREFILL_ROWS:
+            assert tk == k, (tm, tk, tn)
+        compiled = jax.jit(
+            lambda lhs, rhs, sizes: mr.grouped_matmul(lhs, rhs, sizes, "gmm",
+                                                      e)).lower(
+            jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((e, k, n), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((e,), jnp.int32, sharding=one_chip)
+        ).compile()
+        assert "tpu_custom_call" in compiled.as_text()
