@@ -986,8 +986,10 @@ class ContinuousEngine:
         with self._span("engine.first_tokens",
                         rows=sum(len(r[2]) for r in reads)):
             for first_dev, t_sent, rows in reads:
-                # graftlint: ok[host-sync-hot-path] ONE read per prefill dispatch, after the next decode chunk is on the device
-                fp = np.asarray(first_dev)       # [2, bb]: tokens; lp bits
+                # the blocking read alone, as the decode harvest's
+                with self._span("engine.first_tokens.wait"):
+                    # graftlint: ok[host-sync-hot-path] ONE read per prefill dispatch, after the next decode chunk is on the device
+                    fp = np.asarray(first_dev)   # [2, bb]: tokens; lp bits
                 toks = fp[0].tolist()
                 lps = fp[1].view(np.float32).tolist()
                 now = time.perf_counter()
@@ -1941,10 +1943,14 @@ class ContinuousEngine:
                 chunk["moe.decode_assignments_held"] = chunk[
                     "moe.assignments_held"]
             self._count(chunk.items())
-            # prefills dispatched before this chunk have finished
+            # the counters of the prefills dispatched since the last
+            # harvest. One admitted in THIS step runs behind the chunk just
+            # read: its read blocks until it ends, a wait under a span of
+            # its own, as the first tokens' is
             while self._prefill_counters:
-                # graftlint: ok[host-sync-hot-path] 3 ints of a program that ended before the chunk just read
-                done = np.asarray(self._prefill_counters.pop()).tolist()
+                with self._span("engine.prefill_counters.wait"):
+                    # graftlint: ok[host-sync-hot-path] 3 ints a prefill dispatch, read where the host has nothing else to do before that prefill's first tokens
+                    done = np.asarray(self._prefill_counters.pop()).tolist()
                 self._count(zip(self._family.PREFILL_COUNTERS, done))
         tok_cols = toks_np.T.tolist()
         lp_cols = lps_np.T.tolist()

@@ -110,8 +110,9 @@ def _dense_body(spec: ModelSpec, page_size: int, max_seq_len: int
         # each slot's fresh KV sits at [start, start + produced-this-chunk)
         # in its dense row; the count mask drops everything past it
         ctx_k, ctx_v = cache
-        bi = jnp.arange(lengths.shape[0])[:, None]
-        idx = start_lengths[:, None] + jnp.arange(n_steps)[None, :]
+        with jax.named_scope("chunk.end"):
+            bi = jnp.arange(lengths.shape[0])[:, None]
+            idx = start_lengths[:, None] + jnp.arange(n_steps)[None, :]
         with jax.named_scope("attn.kv_update"):
             return write_prefill_pages(
                 kp, vp, ctx_k[:, bi, idx], ctx_v[:, bi, idx], page_table,
@@ -128,10 +129,12 @@ def _window_body(spec: ModelSpec, interpret: bool) -> DecodeBody:
     fwd_window = partial(forward_decode_window, interpret=interpret)
 
     def begin(kp, vp, page_table, start_lengths, n_steps, n_ctx_pages):
-        side_k = jnp.zeros(
-            (spec.n_layers, start_lengths.shape[0], n_steps,
-             spec.n_kv_heads, spec.head_dim), spec.jnp_dtype)
-        return page_table, (kp, vp, side_k, jnp.zeros_like(side_k))
+        with jax.named_scope("chunk.begin"):
+            side_k = jnp.zeros(
+                (spec.n_layers, start_lengths.shape[0], n_steps,
+                 spec.n_kv_heads, spec.head_dim), spec.jnp_dtype)
+            side_v = jnp.zeros_like(side_k)
+        return page_table, (kp, vp, side_k, side_v)
 
     def step(params, last, lengths, start_lengths, page_table, cache,
              active):
@@ -185,9 +188,10 @@ def _family_body(spec: ModelSpec, fam, attn_impl: str) -> DecodeBody:
         # a row a step of every layer that keeps K|V or latent rows, the
         # window layers' (a pool of their own, in the state) first; with an
         # indexer the token's index key rides in the row's last lanes
-        side = jnp.zeros((spec.window_layers + spec.paged_layers,
-                          start_lengths.shape[0], n_steps,
-                          kp.shape[-1] + spec.index_head_dim), kp.dtype)
+        with jax.named_scope("chunk.begin"):
+            side = jnp.zeros((spec.window_layers + spec.paged_layers,
+                              start_lengths.shape[0], n_steps,
+                              kp.shape[-1] + spec.index_head_dim), kp.dtype)
         return ctx, (side, vp)
 
     def step(params, last, lengths, start_lengths, ctx, cache, active):
@@ -197,8 +201,10 @@ def _family_body(spec: ModelSpec, fam, attn_impl: str) -> DecodeBody:
 
     def end(_ctx, cache, kp, _vp, page_table, lengths, start_lengths):
         side, state = cache
-        return fam.write_side(kp, state, side, page_table,
-                              lengths - start_lengths, start_lengths)
+        with jax.named_scope("chunk.end"):
+            counts = lengths - start_lengths
+        return fam.write_side(kp, state, side, page_table, counts,
+                              start_lengths)
 
     return DecodeBody(begin, step, end, tuple(fam.DECODE_COUNTERS))
 
@@ -225,11 +231,13 @@ def build_programs(spec: ModelSpec, body: str, attn_impl: str, fam,
         and BOTH admission programs stay in sync). Sampling happens
         in-program because eager sampling is a chain of separate
         dispatches whose launch latencies all land in TTFT."""
-        last = hidden[jnp.arange(hidden.shape[0]), seq_lens - 1]
+        with jax.named_scope("head.firsts"):
+            last = hidden[jnp.arange(hidden.shape[0]), seq_lens - 1]
         logits = unembed(spec, params, last)
         first, lp = sample_tokens_with_logprobs(logits, sampling, key)
-        return jnp.stack(
-            [first, jax.lax.bitcast_convert_type(lp, jnp.int32)])
+        with jax.named_scope("head.firsts"):
+            return jnp.stack(
+                [first, jax.lax.bitcast_convert_type(lp, jnp.int32)])
 
     @jax.jit
     def _prefill(params, tokens, seq_lens, sampling, key):
@@ -319,11 +327,13 @@ def build_programs(spec: ModelSpec, body: str, attn_impl: str, fam,
         advance = partial(_advance, cap=cap, max_new=max_new,
                           eos_ids=eos_ids, stop_mat=stop_mat,
                           use_stops=use_stops)
-        keys = jax.random.split(key, n_steps)
+        with jax.named_scope("chunk.begin"):
+            keys = jax.random.split(key, n_steps)
         frozen, cache = body.begin(kp, vp, page_table, start_lengths,
                                    n_steps, n_ctx_pages)
-        counters = (jnp.zeros((len(body.counters),), jnp.int32)
-                    if body.counters else None)
+        with jax.named_scope("chunk.begin"):
+            counters = (jnp.zeros((len(body.counters),), jnp.int32)
+                        if body.counters else None)
 
         def step(carry, step_key):
             cache, lengths, last, active, produced, counters = carry
@@ -332,10 +342,11 @@ def build_programs(spec: ModelSpec, body: str, attn_impl: str, fam,
             logits = unembed(spec, params, hidden)
             next_tok, lp = sample_tokens_with_logprobs(
                 logits, sampling, step_key)
-            new_len, last, active, produced, emitted, lp = advance(
-                next_tok, lp, lengths, last, active, produced)
-            if body.counters:
-                counters = counters + counted
+            with jax.named_scope("chunk.advance"):
+                new_len, last, active, produced, emitted, lp = advance(
+                    next_tok, lp, lengths, last, active, produced)
+                if body.counters:
+                    counters = counters + counted
             return ((cache, new_len, last, active, produced, counters),
                     (emitted, lp))
 
@@ -349,12 +360,14 @@ def build_programs(spec: ModelSpec, body: str, attn_impl: str, fam,
         # body's counters for the chunk, a row each) into ONE output
         # buffer: the host makes exactly one blocking read per chunk (each
         # sync is a full round trip on remote devices)
-        rows = [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
-                active[None].astype(jnp.int32), lengths[None]]
-        if body.counters:
-            rows.append(jnp.broadcast_to(
-                counters[:, None], (counters.shape[0], lengths.shape[0])))
-        packed = jnp.concatenate(rows, axis=0)
+        with jax.named_scope("chunk.pack"):
+            rows = [toks, jax.lax.bitcast_convert_type(lps, jnp.int32),
+                    active[None].astype(jnp.int32), lengths[None]]
+            if body.counters:
+                rows.append(jnp.broadcast_to(
+                    counters[:, None],
+                    (counters.shape[0], lengths.shape[0])))
+            packed = jnp.concatenate(rows, axis=0)
         return (kp, vp, lengths, last, active, produced), packed
 
     def _set_slots(state, slots, vals, first, live):
@@ -366,8 +379,9 @@ def build_programs(spec: ModelSpec, body: str, attn_impl: str, fam,
         values = (vals["prompt_len"], first, live, 1, vals["max_new"],
                   vals["eos"], vals["temp"], vals["top_k"], vals["top_p"],
                   vals["min_p"], vals["stops"])
-        return tuple(a.at[slots].set(v, mode="drop")
-                     for a, v in zip(state, values))
+        with jax.named_scope("slots.install"):
+            return tuple(a.at[slots].set(v, mode="drop")
+                         for a, v in zip(state, values))
 
     @partial(jax.jit, donate_argnums=tuple(range(11)))
     def _install(*args):
@@ -387,11 +401,12 @@ def build_programs(spec: ModelSpec, body: str, attn_impl: str, fam,
         decode state directly; the host reads them from ``first_dev`` after
         the next decode dispatch."""
         *state, slots, vals, first_dev, cols = args
-        first = first_dev[0, cols]
-        # a prefill-sampled first token that IS eos must not decode:
-        # the device sees it first, so the slot comes up inactive (the
-        # host retires it when it reads the token)
-        live = (first != vals["eos"]) | (vals["eos"] < 0)
+        with jax.named_scope("slots.install"):
+            first = first_dev[0, cols]
+            # a prefill-sampled first token that IS eos must not decode:
+            # the device sees it first, so the slot comes up inactive (the
+            # host retires it when it reads the token)
+            live = (first != vals["eos"]) | (vals["eos"] < 0)
         return _set_slots(state, slots, vals, first, live)
 
     return (_prefill, _prefill_pages, _prefill_suffix, _decode_chunk,

@@ -650,34 +650,39 @@ def _out_proj(spec: ModelSpec, blk: Params, attn_out):
 
 
 def _qkv_norm(spec: ModelSpec, blk: Params, x, positions):
-    """ln1 + QKV of one decode step."""
-    h = _norm(spec, x, blk["ln1_scale"], blk.get("ln1_bias"))
-    return _qkv(spec, blk, h, positions)
+    """ln1 + QKV (+ RoPE) of one layer."""
+    with jax.named_scope("attn.qkv"):
+        h = _norm(spec, x, blk["ln1_scale"], blk.get("ln1_bias"))
+        return _qkv(spec, blk, h, positions)
 
 
 def _out_residual(spec: ModelSpec, blk: Params, attn_out, x):
     """x + out_proj(attn)."""
-    return x + _out_proj(spec, blk, attn_out)
+    with jax.named_scope("attn.out"):
+        return x + _out_proj(spec, blk, attn_out)
 
 
-def _mlp_residual(spec: ModelSpec, blk: Params, x):
+def _mlp_residual(spec: ModelSpec, blk: Params, x, exact_moe: bool = True):
     """ln2 + MLP + residual -> (new_x, moe_aux)."""
-    h2 = _norm(spec, x, blk["ln2_scale"], blk.get("ln2_bias"))
-    m, aux = _mlp(spec, blk, h2)
-    return x + m, aux
+    with (jax.named_scope("mlp.moe") if spec.n_experts
+          else jax.named_scope("mlp.dense")):
+        h2 = _norm(spec, x, blk["ln2_scale"], blk.get("ln2_bias"))
+        m, aux = _mlp(spec, blk, h2, exact_moe=exact_moe)
+        return x + m, aux
 
 
 def embed(spec: ModelSpec, params: Params, tokens: jnp.ndarray,
           positions: jnp.ndarray) -> jnp.ndarray:
     """[B, T] tokens -> [B, T, D] activations."""
-    x = params["tok_emb"][tokens]
-    if spec.emb_scale:
-        # Gemma: normalizer cast to the activation dtype before the multiply
-        # (matches the family's published numerics)
-        x = x * jnp.asarray(spec.d_model ** 0.5, dtype=x.dtype)
-    if spec.pos_emb == "learned":
-        x = x + params["pos_emb"][positions]
-    return x
+    with jax.named_scope("embed"):
+        x = params["tok_emb"][tokens]
+        if spec.emb_scale:
+            # Gemma: normalizer cast to the activation dtype before the
+            # multiply (matches the family's published numerics)
+            x = x * jnp.asarray(spec.d_model ** 0.5, dtype=x.dtype)
+        if spec.pos_emb == "learned":
+            x = x + params["pos_emb"][positions]
+        return x
 
 
 def unembed(spec: ModelSpec, params: Params, hidden: jnp.ndarray) -> jnp.ndarray:
@@ -722,12 +727,12 @@ def transformer_block(
     (x_out, k, v, moe_aux). The single definition of the block math for
     every full-sequence path — dense prefill, pipeline stages, and the
     sequence-parallel prefill differ only in ``attn_fn``."""
-    h = _norm(spec, x, blk["ln1_scale"], blk.get("ln1_bias"))
-    q, k, v = _qkv(spec, blk, h, positions)
-    x = x + _out_proj(spec, blk, attn_fn(q, k, v))
-    h2 = _norm(spec, x, blk["ln2_scale"], blk.get("ln2_bias"))
-    m, aux = _mlp(spec, blk, h2, exact_moe=exact_moe)
-    return x + m, k, v, aux
+    q, k, v = _qkv_norm(spec, blk, x, positions)
+    with jax.named_scope("attn.core"):
+        attn = attn_fn(q, k, v)
+    x = _out_residual(spec, blk, attn, x)
+    x, aux = _mlp_residual(spec, blk, x, exact_moe=exact_moe)
+    return x, k, v, aux
 
 
 def forward_prefill(
@@ -810,15 +815,16 @@ def forward_prefill_into_pages(
         return causal_attention(q, k, v, seq_lens,
                                 window=spec.sliding_window)
 
-    valid = positions < seq_lens[:, None]
-    logical = positions // p
-    offset = positions % p
-    phys = jnp.take_along_axis(
-        page_table, jnp.minimum(logical, page_table.shape[1] - 1), axis=1)
-    base_idx = phys * p + offset                               # [B, T]
-
-    kp_flat = k_pages.reshape(L * n * p, fused)
-    vp_flat = v_pages.reshape(L * n * p, fused)
+    with jax.named_scope("step.setup"):
+        valid = positions < seq_lens[:, None]
+        logical = positions // p
+        offset = positions % p
+        phys = jnp.take_along_axis(
+            page_table, jnp.minimum(logical, page_table.shape[1] - 1),
+            axis=1)
+        base_idx = phys * p + offset                           # [B, T]
+        kp_flat = k_pages.reshape(L * n * p, fused)
+        vp_flat = v_pages.reshape(L * n * p, fused)
     xs_blocks, rebuild = split_indexed_blocks(params["blocks"])
 
     def body(carry, per_layer):
@@ -865,14 +871,12 @@ def forward_prefill_suffix(
     def body(x, per_layer):
         xs_blk, l, ck, cv = per_layer
         blk = rebuild(xs_blk, l)
-        h = _norm(spec, x, blk["ln1_scale"], blk.get("ln1_bias"))
-        q, k, v = _qkv(spec, blk, h, positions)
-        attn = suffix_attention(q, ck, cv, n_ctx, k, v, suffix_lens,
-                                window=spec.sliding_window)
-        x = x + _out_proj(spec, blk, attn)
-        h2 = _norm(spec, x, blk["ln2_scale"], blk.get("ln2_bias"))
-        m, _ = _mlp(spec, blk, h2)
-        x = x + m
+        q, k, v = _qkv_norm(spec, blk, x, positions)
+        with jax.named_scope("attn.core"):
+            attn = suffix_attention(q, ck, cv, n_ctx, k, v, suffix_lens,
+                                    window=spec.sliding_window)
+        x = _out_residual(spec, blk, attn, x)
+        x, _ = _mlp_residual(spec, blk, x)
         return x, (k, v)
 
     x, (ks, vs) = lax.scan(
@@ -988,8 +992,9 @@ def forward_decode(
         with jax.named_scope("attn.kv_gather"):
             ck = lax.dynamic_index_in_dim(ck_full, l, axis=0, keepdims=False)
             cv = lax.dynamic_index_in_dim(cv_full, l, axis=0, keepdims=False)
-        attn = cached_attention(q, ck, cv, lengths + 1,
-                                window=spec.sliding_window)
+        with jax.named_scope("attn.core"):
+            attn = cached_attention(q, ck, cv, lengths + 1,
+                                    window=spec.sliding_window)
         x = _out_residual(spec, blk, attn, x)
         x, _ = _mlp_residual(spec, blk, x)
         return (x, ck_full, cv_full), None
@@ -1042,21 +1047,22 @@ def forward_decode_window(
     w = side_k.shape[2]
     positions = lengths[:, None]                         # [B, 1]
     x = embed(spec, params, tokens[:, None], positions)  # [B, 1, D]
-    # per-slot side write index: how many side entries this slot has
-    idx = lengths - start_lengths
-    onehot = (jnp.arange(w)[None, :] == idx[:, None]) & active[:, None]
-    n_side = idx + active.astype(idx.dtype)              # valid AFTER write
-    # a row that is not live (never admitted, or finished earlier in this
-    # chunk) has its output discarded: the kernel gets length 0 for it and
-    # moves none of its pages
-    live_prefix = jnp.where(active, start_lengths, 0)
-    live_side = jnp.where(active, n_side, 0)
-    # stacked view: the kernel indexes pages as layer·N + table[i, p], so
-    # the scan hands it the WHOLE pool — slicing a layer out per step would
-    # materialize a pool-sized copy (custom-call operands can't fuse a
-    # dynamic slice)
-    kp_flat = k_pages.reshape(L * n_pages, page_size, fused)
-    vp_flat = v_pages.reshape(L * n_pages, page_size, fused)
+    with jax.named_scope("step.setup"):
+        # per-slot side write index: how many side entries this slot has
+        idx = lengths - start_lengths
+        onehot = (jnp.arange(w)[None, :] == idx[:, None]) & active[:, None]
+        n_side = idx + active.astype(idx.dtype)          # valid AFTER write
+        # a row that is not live (never admitted, or finished earlier in
+        # this chunk) has its output discarded: the kernel gets length 0
+        # for it and moves none of its pages
+        live_prefix = jnp.where(active, start_lengths, 0)
+        live_side = jnp.where(active, n_side, 0)
+        # stacked view: the kernel indexes pages as layer·N + table[i, p],
+        # so the scan hands it the WHOLE pool — slicing a layer out per
+        # step would materialize a pool-sized copy (custom-call operands
+        # can't fuse a dynamic slice)
+        kp_flat = k_pages.reshape(L * n_pages, page_size, fused)
+        vp_flat = v_pages.reshape(L * n_pages, page_size, fused)
 
     xs_blocks, rebuild = split_indexed_blocks(params["blocks"])
 
@@ -1071,11 +1077,12 @@ def forward_decode_window(
         with jax.named_scope("attn.kv_update"):
             sk = jnp.where(onehot[:, :, None, None], k[:, 0][:, None], sk)
             sv = jnp.where(onehot[:, :, None, None], v[:, 0][:, None], sv)
-        attn = flash_decode_attention_pallas(
-            q[:, 0], kp_flat, vp_flat, page_table, live_prefix,
-            sk, sv, live_side, n_kv_heads=spec.n_kv_heads,
-            interpret=interpret, layer=l, n_pages_per_layer=n_pages,
-        )
+        with jax.named_scope("attn.core"):
+            attn = flash_decode_attention_pallas(
+                q[:, 0], kp_flat, vp_flat, page_table, live_prefix,
+                sk, sv, live_side, n_kv_heads=spec.n_kv_heads,
+                interpret=interpret, layer=l, n_pages_per_layer=n_pages,
+            )
         with jax.named_scope("attn.kv_update"):
             side_k = lax.dynamic_update_index_in_dim(side_k, sk, l, 0)
             side_v = lax.dynamic_update_index_in_dim(side_v, sv, l, 0)
@@ -1118,12 +1125,13 @@ def forward_decode_paged(
     page_size = k_pages.shape[2]
     positions = lengths[:, None]                         # [B, 1]
     x = embed(spec, params, tokens[:, None], positions)  # [B, 1, D]
-    batch_idx = jnp.arange(b)
-    logical = lengths // page_size
-    offset = lengths % page_size
-    phys = page_table[batch_idx, logical]                # [B]
-    if write_mask is not None:
-        phys = jnp.where(write_mask, phys, n_pages)      # oob -> dropped
+    with jax.named_scope("step.setup"):
+        batch_idx = jnp.arange(b)
+        logical = lengths // page_size
+        offset = lengths % page_size
+        phys = page_table[batch_idx, logical]            # [B]
+        if write_mask is not None:
+            phys = jnp.where(write_mask, phys, n_pages)  # oob -> dropped
 
     # full page pools ride the carry (see forward_decode: stacked scan
     # outputs would copy the whole multi-GiB pool every step)
@@ -1145,10 +1153,11 @@ def forward_decode_paged(
         with jax.named_scope("attn.kv_gather"):
             kp = lax.dynamic_index_in_dim(kp_full, l, axis=0, keepdims=False)
             vp = lax.dynamic_index_in_dim(vp_full, l, axis=0, keepdims=False)
-        attn = paged_attention_xla(
-            q[:, 0], kp, vp, page_table, lengths + 1,
-            n_kv_heads=spec.n_kv_heads, window=spec.sliding_window,
-        )
+        with jax.named_scope("attn.core"):
+            attn = paged_attention_xla(
+                q[:, 0], kp, vp, page_table, lengths + 1,
+                n_kv_heads=spec.n_kv_heads, window=spec.sliding_window,
+            )
         x = _out_residual(spec, blk, attn[:, None], x)
         x, _ = _mlp_residual(spec, blk, x)
         return (x, kp_full, vp_full), None

@@ -406,8 +406,9 @@ def forward_prefill_into_pages(
     state, MoE counters [3])."""
     del slot_ids
     b, t = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-    valid = (positions < seq_lens[:, None]).reshape(-1)
+    with jax.named_scope("step.setup"):
+        positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+        valid = (positions < seq_lens[:, None]).reshape(-1)
     x = embed(spec, params, tokens, positions)
     (light,), (heavy,) = _scanned(params)
 
@@ -416,15 +417,18 @@ def forward_prefill_into_pages(
         blk, p = xs
         att, rows, keys = attn_layer_prefill(spec, blk, x, positions,
                                              seq_lens)
-        x = x + att
+        with jax.named_scope("resid.add"):
+            x = x + att
         y, c = _moe(spec, blk, heavy, p, x.reshape(b * t, -1), valid,
                     moe_impl)
-        return (x + y.reshape(b, t, -1), counters + c), (rows, keys)
+        with jax.named_scope("resid.add"):
+            return (x + y.reshape(b, t, -1), counters + c), (rows, keys)
 
     (x, counters), (rows, keys) = lax.scan(
         layer, (x, jnp.zeros((3,), jnp.int32)),
         (light, jnp.arange(spec.n_layers)))
-    start = jnp.zeros_like(seq_lens)
+    with jax.named_scope("attn.kv_index"):
+        start = jnp.zeros_like(seq_lens)
     pages = write_rows_into_pages(pages, rows, page_table, seq_lens, start)
     state = dict(state, index_pages=write_rows_into_pages(
         state["index_pages"], keys, page_table, seq_lens, start))
@@ -449,7 +453,8 @@ def forward_decode_step(
     ``"xla"``'s gathers."""
     index_pool = state["index_pages"]
     x = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
-    side_idx = lengths - start_lengths
+    with jax.named_scope("step.setup"):
+        side_idx = lengths - start_lengths
     (light,), (heavy,) = _scanned(params)
 
     def layer(carry, xs):
@@ -462,16 +467,19 @@ def forward_decode_step(
             side_idx, active)
         with jax.named_scope("attn.kv_update"):
             side = lax.dynamic_update_index_in_dim(side, side_l, p, 0)
-        x = x + att
+        with jax.named_scope("resid.add"):
+            x = x + att
         y, c = _moe(spec, blk, heavy, p, x, active, moe_impl)
-        return (x + y, side, counters + c, rows + jnp.stack(r)), None
+        with jax.named_scope("resid.add"):
+            return (x + y, side, counters + c, rows + jnp.stack(r)), None
 
     (x, side, moe, rows), _ = lax.scan(
         layer, (x, side, jnp.zeros((3,), jnp.int32),
                 jnp.zeros((3,), jnp.int32)),
         (light, jnp.arange(spec.n_layers)))
-    rows = rows // spec.n_layers       # index keys read, selected, K|V read
-    return x, side, state, jnp.concatenate([rows[:1], moe, rows[1:]])
+    with jax.named_scope("step.counters"):
+        rows = rows // spec.n_layers   # index keys read, selected, K|V read
+        return x, side, state, jnp.concatenate([rows[:1], moe, rows[1:]])
 
 
 def write_side(pages, state: State, side, page_table, counts, start):
@@ -479,7 +487,9 @@ def write_side(pages, state: State, side, page_table, counts, start):
     K|V lanes into the pages, the index keys' lanes into theirs, through
     the one table. Returns (pages, state)."""
     width = pages.shape[-1]
+    with jax.named_scope("attn.kv_index"):
+        keys = side[..., width:]
     state = dict(state, index_pages=write_rows_into_pages(
-        state["index_pages"], side[..., width:], page_table, counts, start))
+        state["index_pages"], keys, page_table, counts, start))
     return (write_rows_into_pages(pages, side, page_table, counts, start),
             state)

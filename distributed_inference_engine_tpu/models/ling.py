@@ -252,17 +252,19 @@ def write_rows_into_pages(pages, rows, page_table, counts, start):
     latent row's zero lanes go, ``latent_row``)."""
     lm, n, p, w = pages.shape
     _lm, b, t, _w = rows.shape
-    if _w > w:
-        rows = rows[..., :w]
-    local = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-    pos = local + start[:, None]
-    phys = jnp.take_along_axis(
-        page_table, jnp.minimum(pos // p, page_table.shape[1] - 1), axis=1)
-    idx = jnp.where(local < counts[:, None], phys * p + pos % p,
-                    lm * n * p)                                  # [B, T]
-    idx = jnp.where(idx < lm * n * p,
-                    idx[None] + (jnp.arange(lm) * n * p)[:, None, None],
-                    lm * n * p)                                  # [L, B, T]
+    with jax.named_scope("attn.kv_index"):
+        if _w > w:
+            rows = rows[..., :w]
+        local = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+        pos = local + start[:, None]
+        phys = jnp.take_along_axis(
+            page_table, jnp.minimum(pos // p, page_table.shape[1] - 1),
+            axis=1)
+        idx = jnp.where(local < counts[:, None], phys * p + pos % p,
+                        lm * n * p)                              # [B, T]
+        idx = jnp.where(idx < lm * n * p,
+                        idx[None] + (jnp.arange(lm) * n * p)[:, None, None],
+                        lm * n * p)                              # [L, B, T]
     with jax.named_scope("attn.kv_update"):
         flat = pages.reshape(lm * n * p, w).at[idx.reshape(-1)].set(
             rows.reshape(-1, w).astype(pages.dtype), mode="drop")
@@ -451,13 +453,15 @@ def mla_layer_step(spec: ModelSpec, blk: Params, x, positions, ctx, layer,
 def mlp_block(spec: ModelSpec, blk: Params, kind: str, x, valid,
               moe_impl: str = ""):
     """ln2 + the layer's MLP over x [N, D] -> (out, counters int32 [3])."""
-    h = rms_norm(x, blk["ln2_scale"], spec.norm_eps)
+    with jax.named_scope("mlp.norm"):
+        h = rms_norm(x, blk["ln2_scale"], spec.norm_eps)
     if kind == "moe":
         return moe_block(spec, blk, h, valid, moe_impl)
-    gu = _proj(h, blk["w_gate_up"], jnp.float32)
-    gate, up = jnp.split(gu, 2, axis=-1)
-    out = _proj((jax.nn.silu(gate) * up).astype(x.dtype), blk["w_down"])
-    return out, jnp.zeros((3,), jnp.int32)
+    with jax.named_scope("mlp.dense"):
+        gu = _proj(h, blk["w_gate_up"], jnp.float32)
+        gate, up = jnp.split(gu, 2, axis=-1)
+        out = _proj((jax.nn.silu(gate) * up).astype(x.dtype), blk["w_down"])
+        return out, jnp.zeros((3,), jnp.int32)
 
 
 # --------------------------------------------------------------- programs
@@ -478,12 +482,14 @@ def forward_prefill_into_pages(
     TRUE END in the row's slot (pad positions move nothing). Returns
     (hidden [B, T, D], pages, state, MoE counters [3])."""
     b, t = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-    valid = positions < seq_lens[:, None]
+    with jax.named_scope("step.setup"):
+        positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+        valid = positions < seq_lens[:, None]
     x = embed(spec, params, tokens, positions)
     S_all, conv_all = state["S"], state["conv"]
     rows: List[jnp.ndarray] = []
-    counters = jnp.zeros((3,), jnp.int32)
+    with jax.named_scope("step.setup"):
+        counters = jnp.zeros((3,), jnp.int32)
     i_kda = 0
     for blk, (kind, mlp, _i) in zip(params["layers"], spec.layer_plan):
         if kind == "kda":
@@ -496,15 +502,18 @@ def forward_prefill_into_pages(
         else:
             att, r = mla_layer_prefill(spec, blk, x, positions, seq_lens)
             rows.append(r)
-        x = x + att
+        with jax.named_scope("resid.add"):
+            x = x + att
         m, c = mlp_block(spec, blk, mlp, x.reshape(b * t, -1),
                          valid.reshape(-1), moe_impl)
-        x = x + m.reshape(x.shape)
-        counters = counters + c
+        with jax.named_scope("resid.add"):
+            x = x + m.reshape(x.shape)
+            counters = counters + c
     if rows:
-        pages = write_rows_into_pages(
-            pages, jnp.stack(rows), page_table, seq_lens,
-            jnp.zeros_like(seq_lens))
+        with jax.named_scope("attn.kv_index"):
+            stacked, zero = jnp.stack(rows), jnp.zeros_like(seq_lens)
+        pages = write_rows_into_pages(pages, stacked, page_table, seq_lens,
+                                      zero)
     return x, pages, {"S": S_all, "conv": conv_all}, counters
 
 
@@ -525,9 +534,10 @@ def forward_decode_step(
     alone."""
     x = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
     S_all, conv_all = state["S"], state["conv"]
-    side_idx = lengths - start_lengths
-    counters = jnp.zeros((3,), jnp.int32)
-    rows_read = jnp.int32(0)
+    with jax.named_scope("step.setup"):
+        side_idx = lengths - start_lengths
+        counters = jnp.zeros((3,), jnp.int32)
+        rows_read = jnp.int32(0)
     i_kda = i_mla = 0
     for blk, (kind, mlp, _i) in zip(params["layers"], spec.layer_plan):
         if kind == "kda":
@@ -540,12 +550,16 @@ def forward_decode_step(
             att, s, read = mla_layer_step(
                 spec, blk, x, lengths, ctx, i_mla, start_lengths,
                 side[i_mla], side_idx, active)
-            side = side.at[i_mla].set(s)
-            rows_read = rows_read + read
+            with jax.named_scope("attn.kv_side"):
+                side = side.at[i_mla].set(s)
+                rows_read = rows_read + read
             i_mla += 1
-        x = x + att
+        with jax.named_scope("resid.add"):
+            x = x + att
         m, c = mlp_block(spec, blk, mlp, x, active, moe_impl)
-        x = x + m
-        counters = counters + c
-    counters = jnp.append(counters, rows_read // max(i_mla, 1))
+        with jax.named_scope("resid.add"):
+            x = x + m
+            counters = counters + c
+    with jax.named_scope("step.counters"):
+        counters = jnp.append(counters, rows_read // max(i_mla, 1))
     return x, side, {"S": S_all, "conv": conv_all}, counters

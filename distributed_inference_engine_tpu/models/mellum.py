@@ -422,9 +422,10 @@ def _moe(spec: ModelSpec, blk: Params, experts: Params, p, x, valid,
          moe_impl):
     """The routed experts of period ``p`` over RMSNorm(x), x [N, D] ->
     (out, counters [3])."""
-    h = rms_norm(x, blk["mlp_norm"], spec.norm_eps)
+    with jax.named_scope("mlp.norm"):
+        h = rms_norm(x, blk["mlp_norm"], spec.norm_eps)
+        offset = p * spec.experts_held[1]
     blk = dict(blk, **experts)
-    offset = p * spec.experts_held[1]
     n = x.shape[0]
     parts = moe_parts(n)
     if parts == 1:
@@ -432,7 +433,8 @@ def _moe(spec: ModelSpec, blk: Params, experts: Params, p, x, valid,
     y, c = lax.map(
         lambda hv: moe_block(spec, blk, *hv, moe_impl, expert_offset=offset),
         (h.reshape(parts, n // parts, -1), valid.reshape(parts, -1)))
-    return y.reshape(n, -1), c.sum(axis=0)
+    with jax.named_scope("moe.combine"):
+        return y.reshape(n, -1), c.sum(axis=0)
 
 
 # --------------------------------------------------------------- programs
@@ -454,23 +456,27 @@ def forward_prefill_into_pages(
     slot holds. Returns (hidden [B, T, D], pages, state, MoE counters
     [3])."""
     b, t = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-    valid = (positions < seq_lens[:, None]).reshape(-1)
+    with jax.named_scope("step.setup"):
+        positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+        valid = (positions < seq_lens[:, None]).reshape(-1)
     x = embed(spec, params, tokens, positions)
     # what of a sliding layer's rows stays: [first, seq_len) of each row
     keep = min(t, spec.sliding_window)
-    first = jnp.maximum(seq_lens - (spec.sliding_window - 1), 0)
-    kept_pos = first[:, None] + jnp.arange(keep)[None, :]        # [B, keep]
+    with jax.named_scope("step.setup"):
+        first = jnp.maximum(seq_lens - (spec.sliding_window - 1), 0)
+        kept_pos = first[:, None] + jnp.arange(keep)[None, :]    # [B, keep]
 
     light, heavy = _scanned(params)
 
     def layer(kind, blk, experts, p, x, counters):
         att, rows = attn_layer_prefill(spec, kind, blk, x, positions,
                                        seq_lens)
-        x = x + att
+        with jax.named_scope("resid.add"):
+            x = x + att
         y, c = _moe(spec, blk, experts, p, x.reshape(b * t, -1), valid,
                     moe_impl)
-        return x + y.reshape(b, t, -1), counters + c, rows
+        with jax.named_scope("resid.add"):
+            return x + y.reshape(b, t, -1), counters + c, rows
 
     def period(carry, xs):
         x, counters = carry
@@ -478,22 +484,27 @@ def forward_prefill_into_pages(
         kept = []
         for blk, experts in zip(blks[:-1], heavy):
             x, counters, rows = layer("swa", blk, experts, p, x, counters)
-            kept.append(jnp.take_along_axis(
-                rows, jnp.minimum(kept_pos, t - 1)[..., None], axis=1))
+            with jax.named_scope("attn.window_keep"):
+                kept.append(jnp.take_along_axis(
+                    rows, jnp.minimum(kept_pos, t - 1)[..., None], axis=1))
         x, counters, rows = layer("full", blks[-1], heavy[-1], p, x,
                                   counters)
-        return (x, counters), (jnp.stack(kept), rows)
+        with jax.named_scope("attn.window_keep"):
+            return (x, counters), (jnp.stack(kept), rows)
 
     (x, counters), (kept, rows) = lax.scan(
         period, (x, jnp.zeros((3,), jnp.int32)),
         (light, jnp.arange(_n_periods(spec))))
-    pages = write_rows_into_pages(pages, rows, page_table, seq_lens,
-                                  jnp.zeros_like(seq_lens))
-    window_table = state["window_table"][
-        jnp.minimum(slot_ids, state["window_table"].shape[0] - 1)]
+    with jax.named_scope("attn.kv_index"):
+        zero = jnp.zeros_like(seq_lens)
+    pages = write_rows_into_pages(pages, rows, page_table, seq_lens, zero)
+    with jax.named_scope("attn.kv_index"):
+        window_table = state["window_table"][
+            jnp.minimum(slot_ids, state["window_table"].shape[0] - 1)]
+        kept = kept.reshape(-1, *kept.shape[2:])
+        n_kept = seq_lens - first
     state = dict(state, window_pages=write_rows_into_pages(
-        state["window_pages"], kept.reshape(-1, *kept.shape[2:]),
-        window_table, seq_lens - first, first))
+        state["window_pages"], kept, window_table, n_kept, first))
     return x, pages, state, counters
 
 
@@ -516,7 +527,8 @@ def forward_decode_step(
     pages, page_table, impl = ctx
     window_pages, window_table = state["window_pages"], state["window_table"]
     x = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
-    side_idx = lengths - start_lengths
+    with jax.named_scope("step.setup"):
+        side_idx = lengths - start_lengths
     n_swa = len(_period(spec)) - 1
     n_full = pages.shape[0]
     n_window = n_swa * n_full                # the full layers' side follows
@@ -532,30 +544,36 @@ def forward_decode_step(
             start_lengths, side_l, side_idx, active)
         with jax.named_scope("attn.kv_update"):
             side = lax.dynamic_update_index_in_dim(side, side_l, si, 0)
-        x = x + att
+        with jax.named_scope("resid.add"):
+            x = x + att
         y, c = _moe(spec, blk, experts, p, x, active, moe_impl)
-        return x + y, side, counters + c, read + r
+        with jax.named_scope("resid.add"):
+            return x + y, side, counters + c, read + r
 
     def period(carry, xs):
         x, side, counters, full_read, window_read = carry
         blks, p = xs
         for j, blk in enumerate(blks[:-1]):
-            li = p * n_swa + j
+            with jax.named_scope("step.setup"):
+                li = p * n_swa + j
             x, side, counters, window_read = layer(
                 "swa", blk, heavy[j], p, (x, side, counters, window_read),
                 window_pages, window_table, li, li)
+        with jax.named_scope("step.setup"):
+            si_full = n_window + p
         x, side, counters, full_read = layer(
             "full", blks[-1], heavy[-1], p,
-            (x, side, counters, full_read), pages, page_table, p,
-            n_window + p)
+            (x, side, counters, full_read), pages, page_table, p, si_full)
         return (x, side, counters, full_read, window_read), None
 
     (x, side, moe, full_read, window_read), _ = lax.scan(
         period, (x, side, jnp.zeros((3,), jnp.int32), jnp.int32(0),
                  jnp.int32(0)),
         (light, jnp.arange(n_full)))
-    counters = jnp.concatenate([
-        (full_read // n_full)[None], moe, (window_read // n_window)[None]])
+    with jax.named_scope("step.counters"):
+        counters = jnp.concatenate([
+            (full_read // n_full)[None], moe,
+            (window_read // n_window)[None]])
     return x, side, state, counters
 
 

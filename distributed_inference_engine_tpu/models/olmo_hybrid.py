@@ -400,6 +400,15 @@ def _mlp(spec: ModelSpec, blk: Params, x):
         return rms_norm(y, blk["mlp_norm"], spec.norm_eps)
 
 
+def _residual(spec: ModelSpec, blk: Params, x, att):
+    """The layer's two residual adds around its MLP."""
+    with jax.named_scope("resid.add"):
+        x = x + att
+    m = _mlp(spec, blk, x)
+    with jax.named_scope("resid.add"):
+        return x + m
+
+
 # --------------------------------------------------------------- programs
 
 
@@ -429,12 +438,11 @@ def forward_prefill_into_pages(
             att, S, tail = gdn_layer_prefill(spec, blk, x, seq_lens)
             Ss.append(S)
             tails.append(tail)
-            x = x + att
-            x = x + _mlp(spec, blk, x)
+            x = _residual(spec, blk, x, att)
         att, rows = full_layer_prefill(spec, blks[-1], x, seq_lens)
-        x = x + att
-        x = x + _mlp(spec, blks[-1], x)
-        return x, (jnp.stack(Ss), jnp.stack(tails), rows)
+        x = _residual(spec, blks[-1], x, att)
+        with jax.named_scope("state.stack"):
+            return x, (jnp.stack(Ss), jnp.stack(tails), rows)
 
     x, (S, tails, rows) = lax.scan(period, x, params["period"])
     with jax.named_scope("state.update"):
@@ -443,9 +451,11 @@ def forward_prefill_into_pages(
                 kda.pack_states(S, _lane_pack(spec)), mode="drop"),
             "conv": state["conv"].at[:, :, slot_ids].set(
                 tails.astype(state["conv"].dtype), mode="drop")}
-    pages = write_rows_into_pages(pages, rows, page_table, seq_lens,
-                                  jnp.zeros_like(seq_lens))
-    return x, pages, state, jnp.zeros((3,), jnp.int32)
+    with jax.named_scope("attn.kv_index"):
+        zero = jnp.zeros_like(seq_lens)
+    pages = write_rows_into_pages(pages, rows, page_table, seq_lens, zero)
+    with jax.named_scope("step.counters"):
+        return x, pages, state, jnp.zeros((3,), jnp.int32)
 
 
 def forward_decode_step(
@@ -464,7 +474,8 @@ def forward_decode_step(
     layer); rows not ``active`` leave side and state alone."""
     del moe_impl
     x = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
-    side_idx = lengths - start_lengths
+    with jax.named_scope("step.setup"):
+        side_idx = lengths - start_lengths
     # the states ride the scan as ONE list of layers: the step's kernel
     # takes the array whole and moves layer p * n_gdn + j of it
     S = state["S"]
@@ -473,14 +484,16 @@ def forward_decode_step(
     def period(carry, xs):
         x, side, S_all, conv_all, rows_read = carry
         blks, p = xs
-        conv_p = lax.dynamic_index_in_dim(conv_all, p, 0, keepdims=False)
+        with jax.named_scope("state.read"):
+            conv_p = lax.dynamic_index_in_dim(conv_all, p, 0, keepdims=False)
         tails = []
         for j, blk in enumerate(blks[:-1]):
+            with jax.named_scope("state.read"):
+                layer, tail_j = p * n_gdn + j, conv_p[j]
             att, S_all, tail = gdn_layer_step(
-                spec, blk, x, S_all, p * n_gdn + j, conv_p[j], active)
+                spec, blk, x, S_all, layer, tail_j, active)
             tails.append(tail)
-            x = x + att
-            x = x + _mlp(spec, blk, x)
+            x = _residual(spec, blk, x, att)
         with jax.named_scope("state.update"):
             conv_all = lax.dynamic_update_index_in_dim(
                 conv_all, jnp.stack(tails), p, 0)
@@ -491,14 +504,16 @@ def forward_decode_step(
             active)
         with jax.named_scope("attn.kv_update"):
             side = lax.dynamic_update_index_in_dim(side, side_p, p, 0)
-        x = x + att
-        x = x + _mlp(spec, blks[-1], x)
-        return (x, side, S_all, conv_all, rows_read + read), None
+        x = _residual(spec, blks[-1], x, att)
+        with jax.named_scope("step.counters"):
+            rows_read = rows_read + read
+        return (x, side, S_all, conv_all, rows_read), None
 
     n = side.shape[0]
     (x, side, S_flat, conv, rows_read), _ = lax.scan(
         period, (x, side, S.reshape(-1, *S.shape[2:]), state["conv"],
                  jnp.int32(0)),
         (params["period"], jnp.arange(n)))
-    return (x, side, {"S": S_flat.reshape(S.shape), "conv": conv},
-            (rows_read // n)[None])
+    with jax.named_scope("step.counters"):
+        return (x, side, {"S": S_flat.reshape(S.shape), "conv": conv},
+                (rows_read // n)[None])

@@ -351,8 +351,11 @@ def _sublayer(spec: ModelSpec, hc, scale, x, fn):
     sublayer's three tensors), or with ``hc_mult`` 0 the plain pre-norm
     residual ``x + fn(RMSNorm(x))`` over x [N, D] in the activation dtype."""
     if not spec.hc_mult:
-        y, extra = fn(rms_norm(x, scale, spec.norm_eps))
-        return x + y, extra
+        with jax.named_scope("resid.norm"):
+            h = rms_norm(x, scale, spec.norm_eps)
+        y, extra = fn(h)
+        with jax.named_scope("resid.add"):
+            return x + y, extra
     with jax.named_scope("resid.mhc"):
         pre, post, res = mhc.hc_maps(spec, hc, x)
         h = rms_norm(mhc.hc_read(x, pre), scale,
@@ -396,12 +399,14 @@ def forward_prefill_into_pages(
     the pages. Returns (hidden [B, T, D], pages, state, MoE counters [3])."""
     del slot_ids
     b, t = tokens.shape
-    positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
-    valid = (positions < seq_lens[:, None]).reshape(-1)
+    with jax.named_scope("step.setup"):
+        positions = jnp.broadcast_to(jnp.arange(t)[None, :], (b, t))
+        valid = (positions < seq_lens[:, None]).reshape(-1)
     emb = embed(spec, params, tokens, positions)
     x = _streams(spec, emb.reshape(b * t, -1))
     rows: List[jnp.ndarray] = []
-    counters = jnp.zeros((3,), jnp.int32)
+    with jax.named_scope("step.setup"):
+        counters = jnp.zeros((3,), jnp.int32)
     for blk, (_kind, mlp, _i) in zip(params["layers"], spec.layer_plan):
         def attn(h, blk=blk):
             att, r = mla_layer_prefill(spec, blk, h.reshape(b, t, -1),
@@ -414,9 +419,11 @@ def forward_prefill_into_pages(
             spec, blk.get("hc_mlp"), blk["ln2_scale"], x,
             lambda h, blk=blk, mlp=mlp: _mlp(spec, blk, mlp, h, valid,
                                              moe_impl))
-        counters = counters + c
-    pages = write_rows_into_pages(pages, jnp.stack(rows), page_table,
-                                  seq_lens, jnp.zeros_like(seq_lens))
+        with jax.named_scope("step.counters"):
+            counters = counters + c
+    with jax.named_scope("attn.kv_index"):
+        stacked, zero = jnp.stack(rows), jnp.zeros_like(seq_lens)
+    pages = write_rows_into_pages(pages, stacked, page_table, seq_lens, zero)
     return _collapse(spec, x).reshape(b, t, -1), pages, state, counters
 
 
@@ -436,9 +443,10 @@ def forward_decode_step(
     attention read, a layer); rows not ``active`` leave side alone."""
     emb = embed(spec, params, tokens[:, None], lengths[:, None])[:, 0]
     x = _streams(spec, emb)
-    side_idx = lengths - start_lengths
-    counters = jnp.zeros((3,), jnp.int32)
-    rows_read = jnp.int32(0)
+    with jax.named_scope("step.setup"):
+        side_idx = lengths - start_lengths
+        counters = jnp.zeros((3,), jnp.int32)
+        rows_read = jnp.int32(0)
     for i, (blk, (_kind, mlp, _id)) in enumerate(
             zip(params["layers"], spec.layer_plan)):
         x, (s, read) = _sublayer(
@@ -446,14 +454,17 @@ def forward_decode_step(
             lambda h, blk=blk, i=i: mla_layer_step(
                 spec, blk, h, lengths, ctx, i, start_lengths, side[i],
                 side_idx, active))
-        side = side.at[i].set(s)
-        rows_read = rows_read + read
+        with jax.named_scope("attn.kv_side"):
+            side = side.at[i].set(s)
+            rows_read = rows_read + read
         x, c = _sublayer(
             spec, blk.get("hc_mlp"), blk["ln2_scale"], x,
             lambda h, blk=blk, mlp=mlp: _mlp(spec, blk, mlp, h, active,
                                              moe_impl))
-        counters = counters + c
-    counters = jnp.append(counters, rows_read // len(spec.layer_plan))
+        with jax.named_scope("step.counters"):
+            counters = counters + c
+    with jax.named_scope("step.counters"):
+        counters = jnp.append(counters, rows_read // len(spec.layer_plan))
     return _collapse(spec, x), side, state, counters
 
 
@@ -462,6 +473,8 @@ def _mlp(spec: ModelSpec, blk: Params, kind: str, h, valid, moe_impl):
     counters int32 [3])."""
     if kind == "moe":
         return moe_body(spec)(spec, blk, h, valid, moe_impl)
-    gate, up = jnp.split(_proj(h, blk["w_gate_up"], jnp.float32), 2, axis=-1)
-    out = _proj((jax.nn.silu(gate) * up).astype(h.dtype), blk["w_down"])
-    return out, jnp.zeros((3,), jnp.int32)
+    with jax.named_scope("mlp.dense"):
+        gate, up = jnp.split(_proj(h, blk["w_gate_up"], jnp.float32), 2,
+                             axis=-1)
+        out = _proj((jax.nn.silu(gate) * up).astype(h.dtype), blk["w_down"])
+        return out, jnp.zeros((3,), jnp.int32)

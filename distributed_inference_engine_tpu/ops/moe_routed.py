@@ -251,11 +251,12 @@ def _with_shared(spec, blk, x, routed, on, valid, sizes):
     if spec.shared_d_ff:
         with jax.named_scope("moe.shared"):
             shared = _swiglu(x, blk["ws_gate_up"], blk["ws_down"])
-    counters = jnp.stack([
-        jnp.sum(on), jnp.sum(valid) * spec.experts_per_token,
-        jnp.sum(sizes > 0)]).astype(jnp.int32)
-    out = routed if shared is None else routed + shared
-    return out.astype(x.dtype), counters
+    with jax.named_scope("moe.combine"):
+        counters = jnp.stack([
+            jnp.sum(on), jnp.sum(valid) * spec.experts_per_token,
+            jnp.sum(sizes > 0)]).astype(jnp.int32)
+        out = routed if shared is None else routed + shared
+        return out.astype(x.dtype), counters
 
 
 def held_fraction_of_one_group(spec) -> bool:

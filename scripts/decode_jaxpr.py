@@ -46,7 +46,10 @@ def _equations(text: str):
     return _EQUATION.sub("\n", flat).splitlines()
 
 
-def _dump_engine(out: str, name: str, eng) -> None:
+def programs(eng):
+    """``(kind, function, arguments)`` of the engine's decode chunk (8
+    steps) and of one prefill (2 rows of a 32-token bucket), as
+    ``jax.make_jaxpr`` takes them."""
     import jax
     import jax.numpy as jnp
 
@@ -64,12 +67,10 @@ def _dump_engine(out: str, name: str, eng) -> None:
     # a package before PR 44's second commit passes a buffer of first
     # tokens through the chunk
     firsts = [eng._firsts_dev] if hasattr(eng, "_firsts_dev") else []
-    text = jax.make_jaxpr(decode)(
+    yield "decode", decode, [
         eng.params, *kv.pools, eng._lengths, eng._last, eng._active,
         eng._produced, kv.page_table, jnp.zeros((n,), jnp.int32),
-        eng._max_new, sampling, eng._eos, eng._stops_dev, *firsts, key)
-    with open(os.path.join(out, f"{name}.decode.txt"), "w") as f:
-        f.write(str(text))
+        eng._max_new, sampling, eng._eos, eng._stops_dev, *firsts, key]
     bb, tb = 2, 32
     args = [eng.params, jnp.zeros((bb, tb), jnp.int32),
             jnp.ones((bb,), jnp.int32), *kv.pools,
@@ -78,12 +79,20 @@ def _dump_engine(out: str, name: str, eng) -> None:
                            jnp.ones((bb,)), jnp.zeros((bb,))), key]
     if eng.spec.layer_kinds:
         args.append(jnp.zeros((bb,), jnp.int32))       # slot ids
-    text = jax.make_jaxpr(lambda *a: eng._prefill_pages(*a))(*args)
-    with open(os.path.join(out, f"{name}.prefill.txt"), "w") as f:
-        f.write(str(text))
+    yield "prefill", lambda *a: eng._prefill_pages(*a), args
 
 
-def dump(out: str) -> None:
+def _dump_engine(out: str, name: str, eng) -> None:
+    import jax
+
+    for kind, fn, args in programs(eng):
+        with open(os.path.join(out, f"{name}.{kind}.txt"), "w") as f:
+            f.write(str(jax.make_jaxpr(fn)(*args)))
+
+
+def engines(only=None):
+    """``(name, engine)`` of every entry (of those named in ``only``),
+    built one at a time."""
     import jax
 
     from distributed_inference_engine_tpu.config import EngineConfig
@@ -104,7 +113,9 @@ def dump(out: str) -> None:
         random_quantized_params,
     )
 
-    os.makedirs(out, exist_ok=True)
+    def skip(name):
+        return only is not None and name not in only
+
     spec = mistral_spec("mistral-tiny", sliding_window=0, max_seq_len=128)
     params = random_quantized_params(spec, jax.random.key(0), bits=4)
     for name, impl, window in (
@@ -114,34 +125,42 @@ def dump(out: str) -> None:
         cfg = EngineConfig(max_slots=8, max_seq_len=128, page_size=16,
                            num_pages=72, prefill_buckets=[32, 64, 96],
                            decode_steps_per_call=8, attention_impl=impl)
-        _dump_engine(out, name, ContinuousEngine(
-            spec.replace(sliding_window=window), params=params, config=cfg))
+        if skip(name):
+            continue
+        yield name, ContinuousEngine(
+            spec.replace(sliding_window=window), params=params, config=cfg)
     cfg = EngineConfig(max_slots=4, max_seq_len=128, page_size=16,
                        num_pages=40, prefill_buckets=[32, 64],
                        decode_steps_per_call=8)
-    _dump_engine(out, "ling_tiny",
-                 ContinuousEngine(ling_spec("ling-tiny"), config=cfg))
+    if not skip("ling_tiny"):
+        yield "ling_tiny", ContinuousEngine(ling_spec("ling-tiny"),
+                                            config=cfg)
     for name, impl in (("olmo_tiny_kernel", "pallas-decode_interpret"),
                        ("olmo_tiny_xla", "xla")):
         cfg = EngineConfig(max_slots=4, max_seq_len=128, page_size=16,
                            num_pages=40, prefill_buckets=[32, 64],
                            decode_steps_per_call=8, attention_impl=impl)
-        _dump_engine(out, name, ContinuousEngine(
-            olmo_hybrid_spec("olmo-hybrid-tiny"), config=cfg))
-        _dump_engine(out, name.replace("olmo", "xing"), ContinuousEngine(
-            xing_spec("xing-tiny", max_seq_len=128), config=cfg))
+        if not skip(name):
+            yield name, ContinuousEngine(
+                olmo_hybrid_spec("olmo-hybrid-tiny"), config=cfg)
+        if not skip(name.replace("olmo", "xing")):
+            yield name.replace("olmo", "xing"), ContinuousEngine(
+                xing_spec("xing-tiny", max_seq_len=128), config=cfg)
         cfg = EngineConfig(max_slots=4, max_seq_len=256, page_size=8,
                            num_pages=128, prefill_buckets=[32, 64],
                            decode_steps_per_call=8, attention_impl=impl)
-        _dump_engine(out, name.replace("olmo", "mellum"), ContinuousEngine(
-            mellum_spec("mellum-tiny", max_seq_len=256), config=cfg))
-        if not hasattr(xing, "kimi_spec"):      # a parent before PR 41
+        if not skip(name.replace("olmo", "mellum")):
+            yield name.replace("olmo", "mellum"), ContinuousEngine(
+                mellum_spec("mellum-tiny", max_seq_len=256), config=cfg)
+        # (a parent before PR 41 has no such spec)
+        if not hasattr(xing, "kimi_spec") or skip(
+                name.replace("olmo", "kimi")):
             continue
         cfg = EngineConfig(max_slots=12, max_seq_len=128, page_size=16,
                            num_pages=96, prefill_buckets=[32, 64],
                            decode_steps_per_call=8, attention_impl=impl)
-        _dump_engine(out, name.replace("olmo", "kimi"), ContinuousEngine(
-            xing.kimi_spec("kimi-tiny", max_seq_len=128), config=cfg))
+        yield name.replace("olmo", "kimi"), ContinuousEngine(
+            xing.kimi_spec("kimi-tiny", max_seq_len=128), config=cfg)
     try:
         from distributed_inference_engine_tpu.models.keye import keye_spec
     except ImportError:                         # a parent before PR 45
@@ -151,12 +170,20 @@ def dump(out: str) -> None:
         cfg = EngineConfig(max_slots=4, max_seq_len=256, page_size=8,
                            num_pages=128, prefill_buckets=[32, 64],
                            decode_steps_per_call=8, attention_impl=impl)
+        if skip(name):
+            continue
         try:
             engine = ContinuousEngine(
                 keye_spec("keye-tiny", max_seq_len=256), config=cfg)
         except ValueError:      # a parent before PR 47: one body, XLA
             continue
-        _dump_engine(out, name, engine)
+        yield name, engine
+
+
+def dump(out: str) -> None:
+    os.makedirs(out, exist_ok=True)
+    for name, eng in engines():
+        _dump_engine(out, name, eng)
 
 
 def compare(a: str, b: str, verbose: bool = False) -> bool:

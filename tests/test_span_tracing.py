@@ -247,11 +247,80 @@ def test_a_decode_chunk_adds_a_bounded_number_of_records(slots, steps, n_new):
     # span of its own, outside the brackets)
     flips = [e for e in events if e["name"] == "engine.set_active"]
     assert all(e["parent"] == "engine.process_packed" for e in flips)
+    # (each read of first tokens holds its blocking read as a span of its
+    # own, one a prefill dispatch)
+    waits = [e for e in events if e["name"] == "engine.first_tokens.wait"]
     assert len(events) == (4 * len(chunks) + len(admits) + len(steps)
-                           + len(flips) + len(firsts))
+                           + len(flips) + len(firsts) + len(waits))
     # only the dispatch brackets count as busy time
     split = busy_gap_split(events)
     assert split["n_events"] == sum(1 for e in events if e["dispatch"])
+
+
+@pytest.mark.parametrize("slots,rounds", [(2, 1), (2, 3), (4, 2)])
+def test_a_prefill_read_waits_under_a_span_of_its_own(slots, rounds):
+    """``engine.first_tokens.wait`` holds the blocking read of one prefill's
+    first tokens and nothing else: opened once a prefill dispatch, inside
+    ``engine.first_tokens`` (which keeps its name and its ``rows``), and
+    closed before the bookkeeping, whose first act a streamed request sees
+    is its first token."""
+    engine = ContinuousEngine(SPEC, config=_cfg(max_slots=slots), seed=0)
+    seen = {}
+    for r in range(rounds):
+        for i in range(slots):
+            req = _req(10 * r + i, 3)
+            engine.submit(req, on_tokens=lambda toks, rid=req.request_id:
+                          seen.setdefault(rid, time.perf_counter()))
+        engine.run_until_idle()
+    events = engine.timeline.events()
+    firsts = [e for e in events if e["name"] == "engine.first_tokens"]
+    waits = [e for e in events if e["name"] == "engine.first_tokens.wait"]
+    prefills = [e for e in events if e["name"] == "engine.prefill.dispatch"]
+    assert len(waits) == len(prefills) >= rounds
+    assert sum(e["args"]["rows"] for e in firsts) == slots * rounds
+    assert all(w["parent"] == "engine.first_tokens" for w in waits)
+    for w in waits:
+        (f,) = [f for f in firsts
+                if f["t"] <= w["t"] and w["t"] + w["dur"] <= f["t"] + f["dur"]]
+        # the bookkeeping follows the wait, inside the outer span
+        assert w["dur"] < f["dur"]
+    # every request's first token left after some read had ended
+    assert len(seen) == slots * rounds
+    first_read_end = min(w["t"] + w["dur"] for w in waits)
+    assert all(t >= first_read_end for t in seen.values())
+
+
+def test_a_familys_prefill_counters_are_read_under_a_wait_span():
+    """A per-layer family reads each prefill's counters in the harvest of the
+    chunk dispatched behind it: that blocking read is
+    ``engine.prefill_counters.wait``, once a prefill dispatch, inside
+    ``engine.harvest.book``, so that a reader tells the wait for a prefill
+    from the booking around it."""
+    from distributed_inference_engine_tpu.config import EngineConfig
+    from distributed_inference_engine_tpu.models import ling_spec
+
+    spec = ling_spec("ling-tiny")
+    engine = ContinuousEngine(spec, config=EngineConfig(
+        max_slots=4, max_seq_len=128, page_size=16, num_pages=40,
+        prefill_buckets=[32, 64], decode_steps_per_call=8), seed=0)
+    rs = np.random.RandomState(0)
+    for r in range(2):
+        for i in range(2):
+            engine.submit(GenerationRequest(
+                prompt=rs.randint(1, spec.vocab_size, size=8).tolist(),
+                max_new_tokens=12, temperature=0.0, request_id=f"r{r}-{i}"))
+        engine.run_until_idle()
+    events = engine.timeline.events()
+    waits = [e for e in events
+             if e["name"] == "engine.prefill_counters.wait"]
+    prefills = [e for e in events if e["name"] == "engine.prefill.dispatch"]
+    books = [e for e in events if e["name"] == "engine.harvest.book"]
+    assert len(waits) == len(prefills) >= 2
+    assert all(w["parent"] == "engine.harvest.book" for w in waits)
+    for w in waits:
+        assert any(b["t"] <= w["t"] and w["t"] + w["dur"] <= b["t"] + b["dur"]
+                   for b in books)
+    assert engine.get_metrics()["moe"]["assignments_total"] > 0
 
 
 def test_compile_flag_follows_the_compilers_counter():
